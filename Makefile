@@ -40,13 +40,19 @@
 #                     build_sharded_plan argsorts), chaos containment
 #                     via the sharded -> jax -> cpu_ref ladder
 #                     (docs/sharding.md)
+#   make chip-smoke-rehearse  chip_smoke.py --rehearse-cpu: every phase
+#                     of the on-chip smoke at tiny sizes on the CPU (4
+#                     virtual devices so the sharded phase runs too),
+#                     Pallas under the interpreter by explicit mode.
+#                     The real thing is `python chip_smoke.py` on a
+#                     machine with a TPU; it refuses to start without one
 #   make bench-gate   check BENCH_TRAJECTORY.jsonl: fail if any config's
 #                     newest p50 regressed >15% vs its previous entry,
 #                     or its supersteps_p50 regressed >25% (+8 slack)
 #                     for series that carry it — the churn/event path
 #                     (tools/bench_compare.py; append runs with
 #                     `python tools/bench_compare.py append ... --from-bench`)
-#   make verify       lint, then tests, then the chaos + obs smokes
+#   make verify       lint, then tests, then the smokes
 #   make baseline     re-accept current lint violations (ratchet; avoid —
 #                     fix or suppress inline instead, docs/static_analysis.md)
 
@@ -55,7 +61,7 @@ SHELL := /bin/bash
 PY ?= python
 LINT_PATHS = ksched_tpu tools bench.py
 
-.PHONY: lint test chaos-smoke obs-smoke pipeline-smoke tenant-smoke recovery-smoke shard-smoke bench-gate verify baseline
+.PHONY: lint test chaos-smoke obs-smoke pipeline-smoke tenant-smoke recovery-smoke shard-smoke chip-smoke-rehearse bench-gate verify baseline
 
 lint:
 	$(PY) -m tools.kschedlint --coverage $(LINT_PATHS)
@@ -91,6 +97,10 @@ shard-smoke:
 	timeout -k 10 300 env JAX_PLATFORMS=cpu $(PY) tools/shard_smoke.py \
 	  --machines 6 --tasks 48 --rounds 24 --warmup 4 --devices 8 --seed 7
 
+chip-smoke-rehearse:
+	timeout -k 10 300 env XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+	  $(PY) chip_smoke.py --rehearse-cpu
+
 bench-gate:
 	$(PY) tools/bench_compare.py gate BENCH_TRAJECTORY.jsonl
 
@@ -103,7 +113,7 @@ test:
 	echo DOTS_PASSED=$$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$$' /tmp/_t1.log | tr -cd . | wc -c); \
 	exit $$rc
 
-verify: lint test chaos-smoke obs-smoke pipeline-smoke tenant-smoke recovery-smoke shard-smoke
+verify: lint test chaos-smoke obs-smoke pipeline-smoke tenant-smoke recovery-smoke shard-smoke chip-smoke-rehearse
 
 baseline:
 	$(PY) -m tools.kschedlint --write-baseline $(LINT_PATHS)

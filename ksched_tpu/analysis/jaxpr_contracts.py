@@ -264,7 +264,8 @@ def estimate_mega_vmem(closed) -> MegaVmemEstimate:
     for bm in grid_mapping.block_mappings:
         space = str(getattr(bm, "block_aval", "")).lower()
         if "vmem" in space:
-            vmem_shapes.append(tuple(bm.block_shape))
+            # each dim is a pallas `Blocked(block_size=n)`
+            vmem_shapes.append(tuple(int(b.block_size) for b in bm.block_shape))
         elif "smem" in space:
             smem += 1
         else:

@@ -227,7 +227,7 @@ _SPECS = (
         name="csr_solve", module="ksched_tpu.solver.jax_solver", kind="solve",
         tracer="trace_jax", trace=call(20, 100),
         extra=(call(12, 40), call(40, 220)),
-        telemetry_off_hash="92aa144400bd8869", telemetry_knob="telemetry_cap",
+        telemetry_off_hash="75d13078bf6fc412", telemetry_knob="telemetry_cap",
         hash_stability=HashStability("pow2-bucket", same=_CSR_SAME, cross=_CSR_CROSS),
         gathers=GatherBudget(hbm_loop_min=1),
         collectives=CollectiveBudget(forbidden=_ALL_COLLECTIVES),
@@ -289,7 +289,7 @@ _SPECS = (
         name="ell_solve", module="ksched_tpu.solver.ell_solver", kind="solve",
         tracer="trace_ell", trace=call(20, 100),
         extra=(call(12, 40), call(40, 220)),
-        telemetry_off_hash="9e101ad7b1bac615", telemetry_knob="telemetry_cap",
+        telemetry_off_hash="3e06106007252062", telemetry_knob="telemetry_cap",
         hash_stability=HashStability(
             "exempt",
             reason="entry-table shapes depend on degree buckets; the "
@@ -301,7 +301,7 @@ _SPECS = (
         name="mega_solve", module="ksched_tpu.ops.mcmf_pallas", kind="solve",
         tracer="trace_mega", trace=call(20, 100),
         extra=(call(12, 40), call(40, 220)),
-        telemetry_off_hash="2713247f0ce0fa0b", telemetry_knob="telemetry_cap",
+        telemetry_off_hash="39ad760141b7be72", telemetry_knob="telemetry_cap",
         hash_stability=HashStability("pow2-bucket", same=_CSR_SAME, cross=_MEGA_CROSS),
         gathers=GatherBudget(hbm_loop=0, kernel=6),
         collectives=CollectiveBudget(forbidden=_ALL_COLLECTIVES),
@@ -313,7 +313,7 @@ _SPECS = (
         name="layered_solve", module="ksched_tpu.solver.layered", kind="solve",
         tracer="trace_layered", trace=call(20, 100),
         extra=(call(12, 40), call(40, 220)),
-        telemetry_off_hash="efaf297e81829bd2", telemetry_knob="telemetry_cap",
+        telemetry_off_hash="d9971a009af01491", telemetry_knob="telemetry_cap",
         hash_stability=HashStability(
             "pow2-bucket", same=_LAYERED_SAME, cross=_LAYERED_CROSS
         ),
@@ -323,7 +323,7 @@ _SPECS = (
         name="sharded_solve", module="ksched_tpu.parallel.sharded_solver",
         kind="solve", tracer="trace_sharded", trace=call(20, 100),
         extra=(call(12, 40), call(40, 220)),
-        telemetry_off_hash="b2c5ad0884934f47", telemetry_knob="telemetry_cap",
+        telemetry_off_hash="3d9cf1c3ee42486b", telemetry_knob="telemetry_cap",
         hash_stability=HashStability(
             "exempt",
             reason="legacy ShardedPlan shapes depend on per-shard maxima; "

@@ -1101,7 +1101,7 @@ def _run_multi_tenant(args, span_tracer, metrics_server) -> int:
             metrics_server.stop()
 
 
-def main(argv=None) -> int:
+def build_arg_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(
         prog="ksched-tpu", description="TPU-native flow-network cluster scheduler"
     )
@@ -1192,6 +1192,35 @@ def main(argv=None) -> int:
         "-addr; see cluster/http_api.py) instead of the in-process "
         "synthetic API; --podgen then posts pods to the server",
     )
+    return ap
+
+
+def build_service(
+    args, api: ClusterAPI, *, tracer=None, flight=None, span_tracer=None
+) -> SchedulerService:
+    """The service for parsed flags `args`, exactly as `main` serves it
+    (chip_smoke.py drives this same construction round by round)."""
+    from .solver.select import make_backend
+
+    return SchedulerService(
+        api,
+        max_tasks_per_pu=args.max_tasks_per_pu,
+        cost_model=CostModelType[args.cost_model.upper()],
+        backend=make_backend(args.backend),
+        backend_name=args.backend,
+        degrade=not args.no_degrade,
+        round_deadline_s=args.round_deadline,
+        tracer=tracer,
+        flight=flight,
+        span_tracer=span_tracer,
+        pipeline=args.pipeline,
+        device_resident=args.device_resident,
+        audit_every=args.audit_every,
+    )
+
+
+def main(argv=None) -> int:
+    ap = build_arg_parser()
     args = ap.parse_args(argv)
     if args.one_shot and args.podgen <= 0:
         ap.error("--one-shot needs --podgen N: the pod wait blocks until a first pod arrives")
@@ -1214,9 +1243,11 @@ def main(argv=None) -> int:
 
     signal.signal(signal.SIGTERM, lambda signum, frame: sys.exit(143))
 
-    from .solver.select import make_backend
+    # one persistent compilation cache per checkout, placed before the
+    # first trace (utils/platform.py)
+    from .utils import enable_compile_cache
 
-    backend = make_backend(args.backend)
+    enable_compile_cache()
 
     # -- observability setup (before any instrumented object resolves
     # its metric handles) ------------------------------------------------
@@ -1273,20 +1304,8 @@ def main(argv=None) -> int:
         )
     else:
         api = SyntheticClusterAPI(pod_chan_size=args.pod_chan_size)
-    svc = SchedulerService(
-        api,
-        max_tasks_per_pu=args.max_tasks_per_pu,
-        cost_model=CostModelType[args.cost_model.upper()],
-        backend=backend,
-        backend_name=args.backend,
-        degrade=not args.no_degrade,
-        round_deadline_s=args.round_deadline,
-        tracer=tracer,
-        flight=flight,
-        span_tracer=span_tracer,
-        pipeline=args.pipeline,
-        device_resident=args.device_resident,
-        audit_every=args.audit_every,
+    svc = build_service(
+        args, api, tracer=tracer, flight=flight, span_tracer=span_tracer
     )
     if args.machine_timeout > 0:
         svc.enable_heartbeats(machine_timeout_s=args.machine_timeout)

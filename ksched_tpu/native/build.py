@@ -2,13 +2,17 @@
 
 Equivalent in role to the reference's build/Dockerfile:5-12 step that
 builds Flowlessly via cmake — except the artifact is a shared library
-loaded in-process, rebuilt automatically when mcmf.cpp is newer than the
-cached .so. Thread-safe via an atomic rename.
+loaded in-process. The library's file name carries a hash of mcmf.cpp's
+bytes, so only a build of the source that is on disk can be loaded: a
+`_build/` left behind by another source (copied along with the
+checkout, mtimes and all) is never trusted. Thread-safe via an atomic
+rename.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -16,16 +20,24 @@ import threading
 
 _SRC = os.path.join(os.path.dirname(__file__), "mcmf.cpp")
 _BUILD_DIR = os.path.join(os.path.dirname(__file__), "_build")
-_LIB = os.path.join(_BUILD_DIR, "libksched_mcmf.so")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 
 
+def _lib_name(src_path: str) -> str:
+    """`libksched_mcmf-<sha256 of the source bytes, 16 hex>.so`."""
+    with open(src_path, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return f"libksched_mcmf-{digest}.so"
+
+
 def library_path() -> str:
-    """Path to the compiled library, building it if missing or stale."""
-    if os.path.exists(_LIB) and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC):
-        return _LIB
+    """Path to the library built from mcmf.cpp as it is on disk,
+    building it if no build of exactly these bytes exists."""
+    lib = os.path.join(_BUILD_DIR, _lib_name(_SRC))
+    if os.path.exists(lib):
+        return lib
     os.makedirs(_BUILD_DIR, exist_ok=True)
     fd, tmp = tempfile.mkstemp(suffix=".so", dir=_BUILD_DIR)
     os.close(fd)
@@ -36,13 +48,13 @@ def library_path() -> str:
             capture_output=True,
             text=True,
         )
-        os.replace(tmp, _LIB)
+        os.replace(tmp, lib)
     except subprocess.CalledProcessError as e:
         raise RuntimeError(f"native solver build failed:\n{e.stderr}") from e
     finally:
         if os.path.exists(tmp):
             os.unlink(tmp)
-    return _LIB
+    return lib
 
 
 def load_library() -> ctypes.CDLL:

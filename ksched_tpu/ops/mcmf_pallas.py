@@ -437,6 +437,42 @@ def mcmf_loop_pallas(
     return base
 
 
+@functools.lru_cache(maxsize=None)
+def mega_compiler_refusal() -> str:
+    """'' when the Pallas TPU compiler takes the kernel, else its words.
+
+    Lowers one minimal instance ([1, MEGA_LANES] entry tiling) for the
+    TPU platform — Pallas lowers kernel bodies to Mosaic MLIR at this
+    stage, on any host — and, when a TPU backs JAX, compiles it too, so
+    Mosaic's own refusals are seen. The dispatch seams consult this
+    before attaching the compiled kernel (solver/select.py): a kernel
+    the compiler refuses is reported and detached, never handed to the
+    interpreter or to scan-CSR behind the caller's back."""
+    import traceback
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    m, n, e = 256, 256, MEGA_LANES
+    try:
+        lowered = mcmf_loop_pallas.trace(
+            sds(m), sds(m), sds(n), sds(m), sds(),
+            *(sds(e) for _ in range(7)), sds(m),
+            R=1, L=MEGA_LANES,
+        ).lower(lowering_platforms=("tpu",))
+        if jax.default_backend() == "tpu":
+            lowered.compile()
+    except Exception as err:  # the refusal IS the result: callers raise or print it
+        where = traceback.extract_tb(err.__traceback__)[-1]
+        text = str(err).strip().split("\n\nThe MLIR operation involved")[0]
+        return (
+            f"{type(err).__name__}: {text or where.line} "
+            f"({where.filename.split('site-packages/')[-1]}:{where.lineno} "
+            f"in {where.name})"
+        )
+    return ""
+
+
 # Level-3 registry ownership (ksched_tpu/analysis/program_registry.py)
 from ..analysis.program_registry import declare_programs as _declare_programs
 

@@ -182,9 +182,6 @@ def make_sharded_solver(mesh: Mesh, axis: str, alpha: int, max_supersteps: int, 
     psum-combined, so the rows are GLOBAL — identical on every shard —
     and cap=0 traces the exact pre-telemetry program."""
     from ..obs.soltel import SOLTEL_WIDTH
-    from ._compat import SHARD_MAP_KWARGS as shard_map_kwargs, shard_map, warn_if_fallback
-
-    warn_if_fallback()
     spec_sharded = P(axis)
     spec_repl = P()
 
@@ -373,9 +370,8 @@ def make_sharded_solver(mesh: Mesh, axis: str, alpha: int, max_supersteps: int, 
     out_specs = (spec_repl, spec_repl, spec_repl, spec_repl)
     if telemetry_cap:
         out_specs = out_specs + (spec_repl,)
-    fn = shard_map(  # kschedlint: program=sharded_solve
+    fn = jax.shard_map(  # kschedlint: program=sharded_solve
         solve_shard, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **shard_map_kwargs,
     )
     return jax.jit(fn)  # kschedlint: program=sharded_solve
 
@@ -503,9 +499,6 @@ def make_sharded_slot_solver(
     potentials over ICI each superstep"), countable from the traced
     program (analysis/jaxpr_contracts.count_collectives)."""
     from ..obs.soltel import SOLTEL_WIDTH
-    from ._compat import SHARD_MAP_KWARGS as shard_map_kwargs, shard_map, warn_if_fallback
-
-    warn_if_fallback()
     D = int(mesh.shape[axis])
 
     def solve_shard(*args):
@@ -709,9 +702,8 @@ def make_sharded_slot_solver(
     out_specs = (P(), P(), P(), P(), P())
     if telemetry_cap:
         out_specs = out_specs + (P(),)
-    fn = shard_map(  # kschedlint: program=sharded_slot_solve
+    fn = jax.shard_map(  # kschedlint: program=sharded_slot_solve
         solve_shard, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **shard_map_kwargs,
     )
     return jax.jit(fn)  # kschedlint: program=sharded_slot_solve
 
@@ -810,10 +802,6 @@ def sharded_plan_apply_fn(mesh: Mesh, axis: str):
     key = (mesh, axis)
     fn = _SHARDED_PLAN_APPLY.get(key)
     if fn is None:
-        from ._compat import SHARD_MAP_KWARGS as shard_map_kwargs, shard_map, warn_if_fallback
-
-        warn_if_fallback()
-
         def body(p_arc, p_sign, p_src, p_dst, seg, isstart, row_rec, seg_rec):
             (p_arc, p_sign, p_src, p_dst, seg, isstart, row_rec, seg_rec) = (
                 x[0] for x in (p_arc, p_sign, p_src, p_dst, seg, isstart, row_rec, seg_rec)
@@ -830,10 +818,9 @@ def sharded_plan_apply_fn(mesh: Mesh, axis: str):
                 x[None] for x in (p_arc, p_sign, p_src, p_dst, seg, isstart)
             )
 
-        inner = shard_map(  # kschedlint: program=sharded_plan_apply
+        inner = jax.shard_map(  # kschedlint: program=sharded_plan_apply
             body, mesh=mesh,
             in_specs=(P(axis),) * 8, out_specs=(P(axis),) * 6,
-            **shard_map_kwargs,
         )
         fn = jax.jit(inner, donate_argnums=(0, 1, 2, 3, 4, 5))  # kschedlint: program=sharded_plan_apply
         _SHARDED_PLAN_APPLY[key] = fn
@@ -880,9 +867,6 @@ def sharded_plan_fingerprint_fn(mesh: Mesh, axis: str):
     fn = _SHARDED_PLAN_FP.get(key)
     if fn is None:
         from ..runtime.integrity import _FP_ADD, _FP_MUL, _device_fp1
-        from ._compat import SHARD_MAP_KWARGS as shard_map_kwargs, shard_map, warn_if_fallback
-
-        warn_if_fallback()
         i32 = jnp.int32
 
         def body(p_arc, p_sign, p_src, p_dst, seg, isstart):
@@ -896,9 +880,8 @@ def sharded_plan_fingerprint_fn(mesh: Mesh, axis: str):
                 outs.append(lax.psum(jnp.sum(v.astype(i32) * w), axis))
             return jnp.stack(outs)
 
-        entry_fp = shard_map(  # kschedlint: program=sharded_plan_fingerprint
+        entry_fp = jax.shard_map(  # kschedlint: program=sharded_plan_fingerprint
             body, mesh=mesh, in_specs=(P(axis),) * 6, out_specs=P(),
-            **shard_map_kwargs,
         )
 
         def _fp(p_arc, p_sign, p_src, p_dst, inv, seg, isstart, first, last, nonempty):

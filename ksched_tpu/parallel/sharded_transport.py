@@ -49,31 +49,11 @@ from ..solver.layered import (
 
 AXIS = "x"
 
-from ._compat import (  # noqa: E402  (see _compat.py for the version story)
-    IS_EXPERIMENTAL as _SHARD_MAP_EXPERIMENTAL,
-    SHARD_MAP_KWARGS as _SHARD_MAP_KWARGS,
-    shard_map as _shard_map_native,
-    warn_if_fallback as _warn_if_fallback,
-)
-
-
-def _shard_map(*args, **kwargs):
-    # the one-time fallback RuntimeWarning fires at program-build time
-    # (not import time), so logs attribute it to the process that
-    # actually ran a sharded program
-    _warn_if_fallback()
-    return _shard_map_native(*args, **kwargs)  # kschedlint: disable=unregistered-program -- version-compat wrapper; the real program sites are its callers
 
 
 def _pcast_varying(x):
-    """`lax.pcast(..., to="varying")` on the modern shard_map; under
-    the experimental one (check_rep=False, _compat.py) there is no
-    varying-ness tracking to satisfy, so identity is correct. Keyed on
-    WHICH shard_map was selected — not on pcast's presence — so a jax
-    with modern shard_map but no pcast fails loudly at trace time
-    instead of silently skipping the varying mark."""
-    if _SHARD_MAP_EXPERIMENTAL:
-        return x
+    # loop carries built inside shard_map start replicated; mark them
+    # device-varying so the while_loop carry types match its body's
     return lax.pcast(x, (AXIS,), to="varying")
 
 
@@ -233,14 +213,13 @@ def sharded_transport_solve(
     col_cap int32[Mp]; Mp must be divisible by the mesh size.
     Returns (y [C, Mp], steps, converged), bit-identical to the
     single-device solve."""
-    fn = _shard_map(  # kschedlint: disable=unregistered-program -- sharded transport research path, bit-parity gated by tests/test_sharded_transport.py
+    fn = jax.shard_map(  # kschedlint: disable=unregistered-program -- sharded transport research path, bit-parity gated by tests/test_sharded_transport.py
         functools.partial(
             _sharded_transport_fn, alpha=alpha, max_supersteps=max_supersteps
         ),
         mesh=mesh,
         in_specs=(P(None, AXIS), P(None), P(AXIS), P()),
         out_specs=(P(None, AXIS), P(), P()),
-        **_SHARD_MAP_KWARGS,
     )
     return fn(wS, supply, col_cap, eps0)
 
@@ -529,7 +508,7 @@ def sharded_transport_solve_tiered(
     preemption runs refine_waves=8 — pass it here too for the same
     superstep counts; the host-solver bit-parity convention keeps 0
     the default)."""
-    fn = _shard_map(  # kschedlint: disable=unregistered-program -- sharded transport research path, bit-parity gated by tests/test_sharded_transport.py
+    fn = jax.shard_map(  # kschedlint: disable=unregistered-program -- sharded transport research path, bit-parity gated by tests/test_sharded_transport.py
         functools.partial(
             _sharded_transport_tiered_fn,
             alpha=alpha, max_supersteps=max_supersteps,
@@ -539,6 +518,5 @@ def sharded_transport_solve_tiered(
         in_specs=(P(None, AXIS), P(None, AXIS), P(None, AXIS), P(None),
                   P(AXIS), P()),
         out_specs=(P(None, AXIS), P(), P()),
-        **_SHARD_MAP_KWARGS,
     )
     return fn(wLo, wHi, R, supply, col_cap, eps0)

@@ -117,9 +117,9 @@ class MegaSolver(FlowSolver):
     """VMEM-resident megakernel push-relabel, warm-started across
     rounds — drop-in for JaxSolver on graphs that fit VMEM.
 
-    interpret: None = auto (compiled on TPU, Pallas interpreter
-    elsewhere, honoring set_pallas_mode("interpret")); True/False
-    force. fallback: optional CSR FlowSolver for graphs `fits()`
+    interpret: None = compiled unless set_pallas_mode("interpret")
+    asks for the Pallas interpreter by name (never inferred from the
+    backend); True/False force. fallback: optional CSR FlowSolver for graphs `fits()`
     refuses (oversized / degenerate); without one, refused solves
     raise."""
 
@@ -177,14 +177,7 @@ class MegaSolver(FlowSolver):
             return bool(self.interpret)
         from ..ops import get_pallas_mode
 
-        mode = get_pallas_mode()
-        if mode == "interpret":
-            return True
-        if mode == "on":
-            return False
-        import jax
-
-        return jax.default_backend() != "tpu"
+        return get_pallas_mode() == "interpret"
 
     def fits(self, problem: FlowProblem) -> bool:
         """Whether the megakernel can take this solve; on refusal

@@ -48,12 +48,24 @@ def make_backend(name: str, warm_start: bool = True, fallback: bool = True) -> F
     if name == "mega":
         # the Pallas megakernel (ops/mcmf_pallas.py): the whole
         # push-relabel loop in one kernel launch, tables VMEM-resident
-        # for the solve — compiled on TPU, interpreter elsewhere.
-        # Graphs beyond the VMEM tiling budget delegate to the
-        # scan-based CSR solver so the backend stays total.
+        # for the solve. Compiled unless the interpreter is asked for
+        # by name (set_pallas_mode("interpret")); a kernel the Pallas
+        # TPU compiler refuses is an error here, with its message —
+        # never a hand-over to the interpreter or to scan-CSR. Graphs
+        # beyond the VMEM tiling budget delegate to the scan-based CSR
+        # solver so the backend stays total.
+        from ..ops import get_pallas_mode
         from .jax_solver import JaxSolver
         from .mega_solver import MegaSolver
 
+        if get_pallas_mode() != "interpret":
+            from ..ops.mcmf_pallas import mega_compiler_refusal
+
+            refusal = mega_compiler_refusal()
+            if refusal:
+                raise RuntimeError(
+                    f"megakernel refused by the Pallas TPU compiler: {refusal}"
+                )
         return MegaSolver(
             warm_start=warm_start,
             fallback=JaxSolver(warm_start=warm_start),
@@ -88,8 +100,9 @@ def make_backend(name: str, warm_start: bool = True, fallback: bool = True) -> F
         # chip, the sharded multi-chip backend beyond that — per
         # solve, automatically. The mega rung is attached only when
         # Pallas dispatch is live (TPU backend, or a forced
-        # "on"/"interpret" mode): interpreting the kernel on CPU would
-        # be strictly slower than the XLA scan path it replaces. The
+        # "on"/"interpret" mode) and, where it would run compiled, the
+        # compiler takes the kernel (a refusal detaches the rung with
+        # a RuntimeWarning carrying the compiler's words). The
         # sharded rung is attached (lazily — no mesh or shard_map
         # compile until the fitting gate escalates) whenever the
         # process sees more than one device.
@@ -97,7 +110,20 @@ def make_backend(name: str, warm_start: bool = True, fallback: bool = True) -> F
         from .graph_collapse import AutoSolver
 
         mega = None
-        if resolve_pallas()[0]:
+        use_pallas, interpret = resolve_pallas()
+        if use_pallas and not interpret:
+            from ..ops.mcmf_pallas import mega_compiler_refusal
+
+            refusal = mega_compiler_refusal()
+            if refusal:
+                warnings.warn(
+                    "megakernel rung not attached — refused by the "
+                    f"Pallas TPU compiler: {refusal}",
+                    RuntimeWarning,
+                    stacklevel=2,
+                )
+                use_pallas = False
+        if use_pallas:
             from .mega_solver import MegaSolver
 
             mega = MegaSolver(warm_start=warm_start)

@@ -12,7 +12,7 @@ from .ids import (
     seed_rng,
 )
 from .maps import JobMap, ResourceMap, ResourceStatus, TaskMap
-from .platform import force_cpu_platform
+from .platform import device_stamp, enable_compile_cache, require_accelerator
 
 __all__ = [
     "ExpBackoff",
@@ -30,5 +30,7 @@ __all__ = [
     "ResourceMap",
     "ResourceStatus",
     "TaskMap",
-    "force_cpu_platform",
+    "device_stamp",
+    "enable_compile_cache",
+    "require_accelerator",
 ]

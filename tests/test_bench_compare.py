@@ -1,8 +1,7 @@
 """The bench-trajectory ratchet (tools/bench_compare.py + `make
 bench-gate`): append normalizes bench records into trajectory entries,
 gate fails on >tolerance p50 regression within a (config, platform)
-series and never compares across platforms or against cpu-fallback
-readings."""
+series and never compares across platforms."""
 
 import json
 import subprocess
@@ -64,20 +63,6 @@ def test_gate_ignores_cross_platform_series(tmp_path):
     _write(p, [_entry("a", 2.0, platform="tpu"), _entry("a", 50.0)])
     r = _gate(p)
     assert r.returncode == 0  # different platforms: no comparison
-
-
-def test_gate_skips_fallback_vs_device_baseline(tmp_path):
-    p = tmp_path / "traj.jsonl"
-    _write(p, [
-        _entry("a", 2.0),
-        _entry("a", 50.0, accelerator_unreachable=True),
-    ])
-    # same platform label but one is a cpu-fallback stamp: skipped
-    _write(p, [
-        {**_entry("a", 2.0)},
-        {**_entry("a", 50.0), "accelerator_unreachable": True},
-    ])
-    assert _gate(p).returncode == 0
 
 
 def test_gate_ratchets_supersteps_p50(tmp_path):
@@ -145,11 +130,10 @@ def test_entry_from_record_normalizes():
     assert "utc" in e and "commit" in e
 
 
-def test_entry_marks_fallback():
+def test_entry_platform_comes_from_the_device_stamp():
     rec = {"metric": "p50 ... backend=device/cpu", "value": 1.0,
-           "accelerator_unreachable": True}
-    e = entry_from_record(rec, config="x")
-    assert e["accelerator_unreachable"] and e["platform"] == "cpu-fallback"
+           "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+    assert entry_from_record(rec, config="x")["platform"] == "tpu"
 
 
 def test_mesh_shape_is_part_of_the_series_key():

@@ -237,7 +237,14 @@ def test_backend_mega_fallback_delegation():
     )
     want = ReferenceSolver().solve(p).objective
 
-    mg = make_backend("mega")
+    from ksched_tpu.ops import get_pallas_mode, set_pallas_mode
+
+    prev = get_pallas_mode()
+    try:
+        set_pallas_mode("interpret")  # by name: never inferred from the backend
+        mg = make_backend("mega")
+    finally:
+        set_pallas_mode(prev)
     assert isinstance(mg, MegaSolver) and mg.fallback is not None
     mg.interpret = True
     assert mg.solve(p).objective == want

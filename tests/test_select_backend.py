@@ -54,12 +54,40 @@ def test_jax_and_ell_and_mega_resolve():
     from ksched_tpu.solver.jax_solver import JaxSolver
     from ksched_tpu.solver.mega_solver import MegaSolver
 
+    from ksched_tpu.ops import get_pallas_mode, set_pallas_mode
+
     assert isinstance(make_backend("jax"), JaxSolver)
     assert isinstance(make_backend("ell"), EllSolver)
-    mega = make_backend("mega")
+    prev = get_pallas_mode()
+    try:
+        # the interpreter is taken only when asked for by name
+        set_pallas_mode("interpret")
+        mega = make_backend("mega")
+    finally:
+        set_pallas_mode(prev)
     assert isinstance(mega, MegaSolver)
     # --backend mega stays total: oversized graphs delegate to a CSR fallback
     assert isinstance(mega.fallback, JaxSolver)
+
+
+def test_compiled_mega_is_refused_with_the_compilers_words():
+    """Mosaic (jax 0.9.0) refuses the kernel's 2-D partner gather.
+    The compiled backend must say so by name — never hand over to the
+    interpreter or to scan-CSR — and 'auto' must detach the rung with
+    a warning carrying the same message. (Flip this test when S2
+    lands a kernel the compiler takes.)"""
+    from ksched_tpu.ops import get_pallas_mode, set_pallas_mode
+
+    with pytest.raises(RuntimeError, match="refused by the Pallas TPU compiler.*_gather_lowering_rule"):
+        make_backend("mega")
+    prev = get_pallas_mode()
+    try:
+        set_pallas_mode("on")  # what "auto" resolves to on a TPU
+        with pytest.warns(RuntimeWarning, match="megakernel rung not attached"):
+            auto = make_backend("auto")
+    finally:
+        set_pallas_mode(prev)
+    assert auto.mega is None
 
 
 class _WorkingNativeSolver:

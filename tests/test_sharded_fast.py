@@ -246,9 +246,9 @@ def test_ladder_degrades_sharded_to_jax(mesh2):
 #: pinned telemetry-OFF hash of the 2-device slot-stable sharded solve
 #: at bucket (20, 100) — the "no cost when off" contract extended to
 #: the multi-chip rung (the SOLTEL_OFF_BASELINE_HASHES convention of
-#: tests/test_static_analysis.py: normalized jaxpr hash, jax 0.4.37;
+#: tests/test_static_analysis.py: normalized jaxpr hash, jax 0.9.0;
 #: re-capture in the same commit as any jax upgrade)
-SHARDED_SLOT_OFF_HASH_2DEV = "c08b45189b949d42"
+SHARDED_SLOT_OFF_HASH_2DEV = "45257a8ad63fe611"
 
 
 def test_sharded_slot_telemetry_off_hash_pinned():
@@ -261,26 +261,3 @@ def test_sharded_slot_telemetry_off_hash_pinned():
         "intentional program change must re-pin this hash "
         f"(got {got})"
     )
-
-
-def test_compat_fallback_warning_fires_once():
-    """The shard_map fallback is no longer silent: exactly one
-    RuntimeWarning naming the jax version and check_rep=False, then
-    quiet."""
-    from ksched_tpu.parallel import _compat
-
-    if not _compat.IS_EXPERIMENTAL:
-        pytest.skip("native jax.shard_map: no fallback in play")
-    old = _compat._WARNED
-    try:
-        _compat._WARNED = False
-        with warnings.catch_warnings(record=True) as caught:
-            warnings.simplefilter("always")
-            _compat.warn_if_fallback()
-            _compat.warn_if_fallback()
-        msgs = [w for w in caught if issubclass(w.category, RuntimeWarning)]
-        assert len(msgs) == 1
-        text = str(msgs[0].message)
-        assert jax.__version__ in text and "check_rep=False" in text
-    finally:
-        _compat._WARNED = old

@@ -22,9 +22,9 @@ a-few is quantization, not regression.
 Cross-platform readings don't gate each other: entries compare only
 within the same (config, platform, mesh_devices) series — the mesh
 shape (device count) is part of the series identity, so a 2-dev CPU
-sharded reading never baselines an 8-dev one — and entries stamped
-`accelerator_unreachable` are never used as a baseline for device
-readings.
+sharded reading never baselines an 8-dev one. The platform is the
+record's `device` stamp (bench.py stamps every record; there is no
+CPU fallback to tell apart any more).
 
 Usage:
     python tools/bench_compare.py append TRAJ.jsonl --from-bench out.json \
@@ -63,8 +63,9 @@ def _git_commit() -> str:
 
 
 def _platform_of(record: dict) -> str:
-    if record.get("accelerator_unreachable"):
-        return "cpu-fallback"
+    device = record.get("device")
+    if device:
+        return device["platform"]
     metric = record.get("metric", "")
     if "backend=" in metric:
         return metric.rsplit("/", 1)[-1].strip()
@@ -109,8 +110,6 @@ def entry_from_record(record: dict, config: Optional[str] = None,
             entry["p50_improvement_vs_full_rebuild"] = detail[
                 "p50_improvement_vs_full_rebuild"
             ]
-    if record.get("accelerator_unreachable"):
-        entry["accelerator_unreachable"] = True
     if note:
         entry["note"] = note
     return entry
@@ -196,12 +195,6 @@ def gate_cmd(args) -> int:
         if len(es) < 2:
             continue
         prev, last = es[-2], es[-1]
-        # a cpu-fallback reading must not gate (or baseline) a device
-        # series; same-platform by key, but double-check the stamp
-        if prev.get("accelerator_unreachable") != last.get(
-            "accelerator_unreachable"
-        ):
-            continue
         checked += 1
         p_prev, p_last = float(prev["p50_ms"]), float(last["p50_ms"])
         ratio = (p_last - p_prev) / max(p_prev, 1e-9)

@@ -36,9 +36,6 @@ def main():
 
     if args.cpu:
         os.environ["JAX_PLATFORMS"] = "cpu"
-        from ksched_tpu.utils import force_cpu_platform
-
-        force_cpu_platform()
 
     import jax
     import jax.numpy as jnp

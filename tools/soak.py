@@ -992,19 +992,21 @@ def main() -> int:
     if args.machines is None:  # per-mode default (device soak vs chaos)
         args.machines = 10 if args.chaos else 500
 
-    if args.tenants:
+    # platform first (the env var must precede `import jax`), then the
+    # shared compilation cache, before any mode's first trace
+    if args.tenants or args.chaos:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    elif args.cpu:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+    from ksched_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
+
+    if args.tenants:
         return run_tenant_soak(args)
 
     if args.chaos:
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         return chaos_main(args)
-
-    if args.cpu:
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        from ksched_tpu.utils import force_cpu_platform
-
-        force_cpu_platform()
 
     import jax
     import jax.numpy as jnp
