@@ -26,7 +26,7 @@ from .cluster.api import RETRY_STAT_KEYS
 from .costmodels import MODEL_REGISTRY, CostModelType
 from .obs import metrics as obs_metrics
 from .obs.flight import FlightRecorder
-from .obs.spans import SpanTracer, span
+from .obs.spans import SpanTracer, active_tracer, span
 from .drivers.synthetic import (
     add_machine,
     add_task_to_job,
@@ -325,6 +325,27 @@ class SchedulerService:
         self.pod_to_task[pod.pod_id] = td.uid
         self.task_to_pod[td.uid] = pod.pod_id
 
+    def _admit_pods(self, pods) -> None:
+        """The batch's pods become tasks of the one job that shelters
+        them, and the job is (re-)offered to the scheduler."""
+        with span("pods_admit", pods=len(pods)):
+            for pod in pods:
+                self._add_pod(pod)
+            jd = self.job_map.find(self.job_id)
+            if jd is not None:
+                self.scheduler.add_job(jd)
+
+    def _queue_wait_ms(self, pods, round_t0_s: float) -> Tuple[float, float]:
+        """Mean and max (ms) of how long the batch's pods sat in the
+        channel before their round opened. A re-delivered pod counts
+        like any other: it waited there too. One pass over the batch,
+        and only when someone reads the result (a RoundTracer attached
+        or a SpanTracer installed); 0.0 for a round without pods."""
+        if not pods or (self.tracer is None and active_tracer() is None):
+            return 0.0, 0.0
+        waits = [round_t0_s - pod.received_s for pod in pods]
+        return sum(waits) / len(waits) * 1e3, max(waits) * 1e3
+
     def _find_parent_machine(self, pu_rid: int) -> Optional[int]:
         """Walk a PU up the topology to its machine (reference :379-398)."""
         rs = self.resource_map.find(pu_rid)
@@ -341,20 +362,29 @@ class SchedulerService:
     def _collect_bindings(self) -> List[Binding]:
         """Diff the scheduler's bindings against what was last emitted
         and translate new/changed ones into pod→node bindings."""
-        new_bindings = self.scheduler.get_task_bindings()
-        out = []
-        for task_id, pu_rid in new_bindings.items():
-            if self.old_bindings.get(task_id) == pu_rid:
-                continue
-            machine_rid = self._find_parent_machine(pu_rid)
-            if machine_rid is None:
-                continue
-            pod_id = self.task_to_pod.get(task_id)
-            if pod_id is None:
-                continue
-            out.append(Binding(pod_id=pod_id, node_id=self.machine_to_node[machine_rid]))
-        self.old_bindings = dict(new_bindings)
+        with span("bindings_collect") as sp:
+            new_bindings = self.scheduler.get_task_bindings()
+            out = []
+            for task_id, pu_rid in new_bindings.items():
+                if self.old_bindings.get(task_id) == pu_rid:
+                    continue
+                machine_rid = self._find_parent_machine(pu_rid)
+                if machine_rid is None:
+                    continue
+                pod_id = self.task_to_pod.get(task_id)
+                if pod_id is None:
+                    continue
+                out.append(Binding(pod_id=pod_id, node_id=self.machine_to_node[machine_rid]))
+            self.old_bindings = dict(new_bindings)
+            sp.set("resident", len(new_bindings))
+            sp.set("new", len(out))
         return out
+
+    def _post_bindings(self, out: List[Binding]) -> None:
+        """The POST, under the one name it has on every path."""
+        if out:
+            with span("bindings_post", n=len(out)):
+                self.api.assign_bindings(out)
 
     def flush_pending_bindings(self) -> int:
         """POST the previous pipelined round's bindings. Called inside
@@ -365,31 +395,24 @@ class SchedulerService:
         unposted. A failed POST restores the batch for retry at the
         next flush point instead of dropping it."""
         out, self._pending_bindings = self._pending_bindings, []
-        if out:
-            try:
-                with span("bindings_post", n=len(out)):
-                    self.api.assign_bindings(out)
-            except BaseException:
-                self._pending_bindings = out + self._pending_bindings
-                raise
+        try:
+            self._post_bindings(out)
+        except BaseException:
+            self._pending_bindings = out + self._pending_bindings
+            raise
         return len(out)
 
     def run_once(self, pods) -> int:
         """One iteration of the reference loop body (:120-187). Returns
         the number of new bindings pushed (queued, in pipeline mode)."""
-        for pod in pods:
-            self._add_pod(pod)
-        jd = self.job_map.find(self.job_id)
-        if jd is not None:
-            self.scheduler.add_job(jd)
+        self._admit_pods(pods)
         if self.pipeline:
             return self._run_once_pipelined()
         t0 = time.perf_counter()
         self.scheduler.schedule_all_jobs()
         self.round_latencies_s.append(time.perf_counter() - t0)
         out = self._collect_bindings()
-        if out:
-            self.api.assign_bindings(out)
+        self._post_bindings(out)
         return len(out)
 
     def _run_once_pipelined(self) -> int:
@@ -461,8 +484,11 @@ class SchedulerService:
         if self.tenant:
             span_args["tenant"] = self.tenant
         rec = None
-        with span("service_round", **span_args):
-            rec, bound = self._run_round_body(pods, now, solve)
+        with span("service_round", **span_args) as sp:
+            queue_wait = self._queue_wait_ms(pods, sp.t0_s)
+            sp.set("queue_wait_ms", queue_wait[0])
+            sp.set("queue_wait_max_ms", queue_wait[1])
+            rec, bound = self._run_round_body(pods, now, solve, queue_wait)
         self._note_flight(rec, span_mark)
         return bound
 
@@ -477,7 +503,7 @@ class SchedulerService:
                 events = list(span_prefix) + (events or [])
             self.flight.note_round(rec, events)
 
-    def _run_round_body(self, pods, now, solve):
+    def _run_round_body(self, pods, now, solve, queue_wait):
         deg_mark = self.ladder.degradations_total if self.ladder is not None else 0
         noop = False
         bound = 0
@@ -507,7 +533,9 @@ class SchedulerService:
             # idle sweep IS the flush point (pipeline mode only; the
             # list is always empty otherwise)
             self.flush_pending_bindings()
-        rec = self._round_accounting(noop, bound, deadline_miss, now, solve, deg_mark)
+        rec = self._round_accounting(
+            noop, bound, deadline_miss, now, solve, deg_mark, queue_wait
+        )
         return rec, bound
 
     # -- split rounds: the multi-tenant loop's dispatch/complete seam ------
@@ -528,13 +556,10 @@ class SchedulerService:
             "t0": time.perf_counter(),
             "pods": len(pods),
         }
+        st["queue_wait"] = self._queue_wait_ms(pods, st["t0"])
         self.watchdog.__enter__()
         try:
-            for pod in pods:
-                self._add_pod(pod)
-            jd = self.job_map.find(self.job_id)
-            if jd is not None:
-                self.scheduler.add_job(jd)
+            self._admit_pods(pods)
             st["token"] = self.scheduler.schedule_all_jobs_async()
         except BaseException:
             self.watchdog.__exit__(*sys.exc_info())
@@ -588,8 +613,8 @@ class SchedulerService:
                 # per-tenant dispatch window: the POSTs ride the NEXT
                 # round's batched-solve window (cell.post_window)
                 self._pending_bindings.extend(out)
-            elif out:
-                self.api.assign_bindings(out)
+            else:
+                self._post_bindings(out)
             bound = len(out)
         # a round with no runnable work (token None) dispatched no
         # solve: record it as an idle sweep (solver_rung -1, zeroed
@@ -598,84 +623,89 @@ class SchedulerService:
         # loaded tenant's published p50 toward zero
         rec = self._round_accounting(
             noop, bound, deadline_miss, now, st["token"] is not None,
-            st["deg_mark"],
+            st["deg_mark"], st["queue_wait"],
         )
         self._note_flight(rec, span_mark, span_prefix)
         return bound
 
-    def _round_accounting(self, noop, bound, deadline_miss, now, solve, deg_mark):
+    def _round_accounting(
+        self, noop, bound, deadline_miss, now, solve, deg_mark, queue_wait
+    ):
         """The post-solve tail every round shape shares (run_round's
         body and the split complete_round): heartbeat sweep, backlog
         flag maintenance, service gauges, and the round's trace record
         with fault/retry/degradation attribution."""
-        lost: List[int] = []
-        failed: List[int] = []
-        if self.monitor is not None:
-            lost, failed = self.monitor.check(now)
-            for rid in lost:
-                self._forget_machine(rid)
-        # NOOP rounds and evictions leave runnable work behind; a clean
-        # full solve clears it. An idle sweep must not clear the flag —
-        # it did not schedule anything.
-        if noop or lost or failed:
-            self.backlog_dirty = True
-        elif solve:
-            self.backlog_dirty = False
-        self._g_pods.set(len(self.pod_to_task))
-        self._g_bound.set(len(self.scheduler.task_bindings))
-        self._g_machines.set(len(self.node_to_machine))
-        # a state divergence this round already deposited its
-        # structured soltel event; make sure a flight dump carries it
-        # (rate-limited by the recorder, like the other triggers). The
-        # flag is CONSUMED here — idle sweeps never run the gate, so a
-        # stale flag would re-trigger dumps for a long-repaired event.
-        sol = self.scheduler.solver
-        if getattr(sol, "last_divergence", None):
-            if self.flight is not None:
-                self.flight.trigger("state_divergence")
-            sol.last_divergence = None
-        rec = None
-        if self.tracer is not None:
-            faults = {}
-            if self.injector is not None:
-                snap = self.injector.snapshot()
-                faults = delta_counters(self._fault_mark, snap)
-                self._fault_mark = snap
-            api_stats = self.api.stats() if hasattr(self.api, "stats") else {}
-            # Only retry/re-post counters belong in `retries`; the stats
-            # surface also carries drop counters (binding_drops), which
-            # are a different signal and would silently inflate it.
-            retries = sum(
-                api_stats.get(k, 0) - self._api_stats_mark.get(k, 0)
-                for k in RETRY_STAT_KEYS
-            )
-            self._api_stats_mark = api_stats
-            rec = self.tracer.record_flow_round(
-                self.scheduler,
-                bound,
-                # idle sweeps must not re-report the previous solve's
-                # graph-delta stats and solver work (a NOOP round's
-                # graph update DID run, so it still reports)
-                solved=solve,
-                extra=dict(
-                    faults_injected=faults,
-                    retries=retries,
-                    degradations=(
-                        self.ladder.degradations_total - deg_mark
-                        if self.ladder is not None
-                        else 0
+        with span("round_accounting"):
+            lost: List[int] = []
+            failed: List[int] = []
+            if self.monitor is not None:
+                lost, failed = self.monitor.check(now)
+                for rid in lost:
+                    self._forget_machine(rid)
+            # NOOP rounds and evictions leave runnable work behind; a clean
+            # full solve clears it. An idle sweep must not clear the flag —
+            # it did not schedule anything.
+            if noop or lost or failed:
+                self.backlog_dirty = True
+            elif solve:
+                self.backlog_dirty = False
+            self._g_pods.set(len(self.pod_to_task))
+            self._g_bound.set(len(self.scheduler.task_bindings))
+            self._g_machines.set(len(self.node_to_machine))
+            # a state divergence this round already deposited its
+            # structured soltel event; make sure a flight dump carries it
+            # (rate-limited by the recorder, like the other triggers). The
+            # flag is CONSUMED here — idle sweeps never run the gate, so a
+            # stale flag would re-trigger dumps for a long-repaired event.
+            sol = self.scheduler.solver
+            if getattr(sol, "last_divergence", None):
+                if self.flight is not None:
+                    self.flight.trigger("state_divergence")
+                sol.last_divergence = None
+            rec = None
+            if self.tracer is not None:
+                faults = {}
+                if self.injector is not None:
+                    snap = self.injector.snapshot()
+                    faults = delta_counters(self._fault_mark, snap)
+                    self._fault_mark = snap
+                api_stats = self.api.stats() if hasattr(self.api, "stats") else {}
+                # Only retry/re-post counters belong in `retries`; the stats
+                # surface also carries drop counters (binding_drops), which
+                # are a different signal and would silently inflate it.
+                retries = sum(
+                    api_stats.get(k, 0) - self._api_stats_mark.get(k, 0)
+                    for k in RETRY_STAT_KEYS
+                )
+                self._api_stats_mark = api_stats
+                rec = self.tracer.record_flow_round(
+                    self.scheduler,
+                    bound,
+                    # idle sweeps must not re-report the previous solve's
+                    # graph-delta stats and solver work (a NOOP round's
+                    # graph update DID run, so it still reports)
+                    solved=solve,
+                    extra=dict(
+                        faults_injected=faults,
+                        retries=retries,
+                        degradations=(
+                            self.ladder.degradations_total - deg_mark
+                            if self.ladder is not None
+                            else 0
+                        ),
+                        solver_rung=(
+                            -1 if (noop or not solve)
+                            else (self.ladder.last_rung if self.ladder is not None else 0)
+                        ),
+                        noop_round=noop,
+                        deadline_miss=deadline_miss,
+                        machines_lost=len(lost),
+                        tasks_failed=len(failed),
+                        tenant=self.tenant,
+                        queue_wait_ms=queue_wait[0],
+                        queue_wait_max_ms=queue_wait[1],
                     ),
-                    solver_rung=(
-                        -1 if (noop or not solve)
-                        else (self.ladder.last_rung if self.ladder is not None else 0)
-                    ),
-                    noop_round=noop,
-                    deadline_miss=deadline_miss,
-                    machines_lost=len(lost),
-                    tasks_failed=len(failed),
-                    tenant=self.tenant,
-                ),
-            )
+                )
         return rec
 
     def run(self, pod_batch_timeout_s: float = 2.0, max_rounds: Optional[int] = None) -> None:

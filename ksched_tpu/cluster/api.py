@@ -11,7 +11,8 @@ Reference shape: k8s/k8sclient/client.go —
 from __future__ import annotations
 
 import abc
-from dataclasses import dataclass
+import time
+from dataclasses import dataclass, field
 from typing import List, Optional
 
 
@@ -25,6 +26,11 @@ class PodEvent:
     cpu_request: float = 0.0
     net_bw_request: int = 0
     task_class: int = 0
+    #: perf_counter stamp of the moment the control plane surfaced the
+    #: pod (every source constructs the event then); the service round
+    #: that admits it reads its queue wait from this. Not part of the
+    #: pod's identity: equality and hashing ignore it.
+    received_s: float = field(default_factory=time.perf_counter, compare=False)
 
 
 @dataclass(frozen=True)

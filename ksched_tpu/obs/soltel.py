@@ -569,8 +569,10 @@ def publish(tel: Optional[SolveTelemetry], sp=None) -> Optional[dict]:
 
 
 def _synthesize_spans(tel: SolveTelemetry, sp) -> None:
-    """Per-superstep child spans under the (still-open) backend_solve
-    span. The device gives counts, not wall times, so the parent span's
+    """Per-superstep child spans under `sp`: the still-open
+    backend_solve span (they then end now), or a closed span around the
+    kernel call alone (AutoSolver's `transport`; they end with it). The
+    device gives counts, not wall times, so the parent span's
     elapsed wall is apportioned across kept supersteps proportionally
     to their work column — the trace shows the convergence SHAPE (which
     supersteps were heavy, where eps phases turned over), which is the
@@ -581,7 +583,7 @@ def _synthesize_spans(tel: SolveTelemetry, sp) -> None:
     if tracer is None or sp is None or not getattr(sp, "sid", 0):
         return
     t0 = sp.t0_s
-    t1 = time.perf_counter()
+    t1 = sp.t1_s or time.perf_counter()
     span_s = max(t1 - t0, 1e-9)
     work = tel.col("work").astype(np.float64) + tel.col("pushed") + 1.0  # kschedlint: host-only (host-side span-time apportioning over <=cap rows)
     frac = work / work.sum()

@@ -55,6 +55,11 @@ class RoundRecord:
     #: zero-cross-tenant-interference check relies on fault/degradation
     #: counters landing ONLY in the chaos tenant's records
     tenant: str = ""
+    #: how long the pods this round admitted sat in the channel before
+    #: it opened (PodEvent.received_s to the service_round span's
+    #: start): mean and max over the batch, 0.0 for a round without pods
+    queue_wait_ms: float = 0.0
+    queue_wait_max_ms: float = 0.0
 
 
 class RoundTracer:
@@ -112,6 +117,11 @@ class RoundTracer:
             "solver supersteps/iterations per solved round",
             buckets=log_buckets(1, 1 << 20, 2.0),
         )
+        self._m_queue_wait = reg.histogram(
+            "ksched_pod_queue_wait_ms",
+            "longest wait in the pod channel among the pods a solved round "
+            "admitted (rounds without pods are not observed)",
+        )
 
     def _publish(self, rec: RoundRecord) -> None:
         """Mirror one record onto the metrics registry. Called for every
@@ -126,6 +136,8 @@ class RoundTracer:
                 self._m_phase.labels(phase=phase).observe(ms)
             if rec.solver_work:
                 self._m_work.observe(rec.solver_work)
+            if rec.queue_wait_max_ms > 0:  # the round admitted pods
+                self._m_queue_wait.observe(rec.queue_wait_max_ms)
         if rec.num_scheduled:
             self._m_scheduled.inc(rec.num_scheduled)
         for k, v in rec.faults_injected.items():

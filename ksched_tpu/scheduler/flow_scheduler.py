@@ -270,11 +270,18 @@ class FlowScheduler:
 
     def schedule_all_jobs(self):
         """Reference: flowscheduler/scheduler.go:309-318."""
-        jds = [
-            jd for jd in self.jobs_to_schedule.values()
-            if len(self._compute_runnable_tasks_for_job(jd)) > 0
-        ]
-        return self.schedule_jobs(jds)
+        return self.schedule_jobs(self._runnable_jobs())
+
+    def _runnable_jobs(self):
+        """The jobs with at least one runnable task: a walk of every
+        job's whole task tree, before `round` opens."""
+        with span("runnable_scan") as sp:
+            jds = [
+                jd for jd in self.jobs_to_schedule.values()
+                if len(self._compute_runnable_tasks_for_job(jd)) > 0
+            ]
+            sp.set("jobs", len(jds))
+        return jds
 
     # ------------------------------------------------------------------
     # Pipelined rounds: dispatch the solve, overlap host work, finish
@@ -294,10 +301,7 @@ class FlowScheduler:
         called)."""
         if self._round_in_flight is not None:
             raise RuntimeError("a scheduling round is already in flight")
-        jds = [
-            jd for jd in self.jobs_to_schedule.values()
-            if len(self._compute_runnable_tasks_for_job(jd)) > 0
-        ]
+        jds = self._runnable_jobs()
         if not jds:
             return None
         timing, round_span = self._begin_round(jds)

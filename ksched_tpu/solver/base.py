@@ -81,7 +81,8 @@ class FlowSolver(abc.ABC):
         after a solve — the compiled jax/ell/mega/layered/sharded
         loops) additionally get their buffer decoded here: superstep
         histograms onto the registry, per-superstep child spans under
-        this span (Perfetto shows the convergence shape), and the
+        this span, or under the backend's own ``last_solve_span`` when
+        it keeps one (Perfetto shows the convergence shape), and the
         stall detector (obs/soltel.py). ``native``/``cpu_ref`` expose
         no interior telemetry and skip all of it."""
         from ..obs import soltel
@@ -103,7 +104,10 @@ class FlowSolver(abc.ABC):
                 sp.set("supersteps", work)
             tel = getattr(self, "last_telemetry", None)
             if tel is not None:
-                soltel.publish(tel, sp)
+                # a backend that timed its own kernel call (AutoSolver's
+                # `transport`) gets its supersteps laid over that span,
+                # not over the host work around it
+                soltel.publish(tel, getattr(self, "last_solve_span", None) or sp)
         return result
 
     def reset(self) -> None:

@@ -60,6 +60,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from ..graph.flowgraph import NodeType
+from ..obs.spans import span
 from .base import FlowResult, FlowSolver, lower_bound_cost
 
 _TASK_TYPES = (
@@ -706,6 +707,10 @@ class AutoSolver(FlowSolver):
         #: solver-interior telemetry of the rung that produced the last
         #: solve (obs/soltel.py); solve_traced publishes it
         self.last_telemetry = None
+        #: the closed `transport` span of the last dense solve (None on
+        #: every other path): the interval the kernel ran in, over which
+        #: solve_traced lays the synthesized superstep events
+        self.last_solve_span = None
 
     @property
     def sharded(self):
@@ -752,7 +757,12 @@ class AutoSolver(FlowSolver):
         return sharded_fits_hbm(n_cap, m_cap, num_shards, budget)
 
     def solve(self, problem) -> FlowResult:
-        collapse, reason = try_collapse(problem)
+        self.last_solve_span = None
+        with span("collapse_audit") as sp:
+            collapse, reason = try_collapse(problem)
+            sp.set("collapsed", collapse is not None)
+            if collapse is None:
+                sp.set("reason", reason)
         if collapse is None:
             mega = self.mega
             if mega is not None and mega.fits(problem):
@@ -810,16 +820,28 @@ class AutoSolver(FlowSolver):
         solver = LayeredTransportSolver(
             alpha=self.alpha, max_supersteps=self.max_supersteps
         )
-        res = solver.solve_layered(LayeredProblem(
-            supply=gc.supply,
-            col_cap=gc.col_cap,
-            cost_cm=gc.cost_cm.astype(np.int32),
-            unsched_cost=0,
-            ec_cost=0,
-            row_unsched_cost=gc.row_unsched,
-        ))
+        with span(
+            "transport", rows=len(gc.supply), cols=len(gc.col_cap)
+        ) as sp:
+            res = solver.solve_layered(LayeredProblem(
+                supply=gc.supply,
+                col_cap=gc.col_cap,
+                cost_cm=gc.cost_cm.astype(np.int32),
+                unsched_cost=0,
+                ec_cost=0,
+                row_unsched_cost=gc.row_unsched,
+            ))
+            sp.set("supersteps", int(res.supersteps))
+        self.last_solve_span = sp
         self.last_supersteps = res.supersteps
         self.last_telemetry = solver.last_telemetry
+        with span("flow_reconstruct", tasks=len(gc.task_ids)):
+            return self._reconstruct_flow(problem, gc, res)
+
+    @staticmethod
+    def _reconstruct_flow(problem, gc: GraphCollapse, res) -> FlowResult:
+        """The per-arc flow of the original graph from the transport's
+        granted cells `res.y`, task by task."""
         y = np.asarray(res.y, np.int64)
 
         # ---- exact per-arc flow reconstruction ----

@@ -752,3 +752,263 @@ def test_service_noop_round_trips_flight_dump(tmp_path):
         assert doc["rounds"][0]["record"]["noop_round"] is True
         assert reg.value("ksched_rounds_total", kind="noop") == 1
         assert reg.value("ksched_ladder_exhausted_total") == 1
+
+
+# ---------------------------------------------------------------------------
+# the service loop's own spans, the AutoSolver's, and pod queue wait
+# ---------------------------------------------------------------------------
+
+SERVICE_SPANS = (
+    "pods_admit", "runnable_scan", "bindings_collect", "bindings_post",
+    "round_accounting",
+)
+AUTO_SPANS = ("collapse_audit", "transport", "flow_reconstruct")
+
+
+def _auto_service(**svc_kw):
+    """A two-machine service whose every round collapses onto the dense
+    transport (AutoSolver over the trivial cost model)."""
+    from ksched_tpu.cli import SchedulerService
+    from ksched_tpu.cluster import SyntheticClusterAPI
+    from ksched_tpu.solver.cpu_ref import ReferenceSolver
+    from ksched_tpu.solver.graph_collapse import AutoSolver
+
+    api = SyntheticClusterAPI()
+    svc = SchedulerService(
+        api, max_tasks_per_pu=4, backend=AutoSolver(ReferenceSolver()),
+        backend_name="auto", **svc_kw,
+    )
+    svc.init_topology(fake_machines=2)
+    return svc, api
+
+
+def _pods(prefix, n, waited_s=()):
+    import time
+
+    from ksched_tpu.cluster import PodEvent
+
+    now = time.perf_counter()
+    return [
+        PodEvent(pod_id=f"{prefix}{i}", received_s=now - waited_s[i])
+        if i < len(waited_s) else PodEvent(pod_id=f"{prefix}{i}")
+        for i in range(n)
+    ]
+
+
+def _by_name(events):
+    out = {}
+    for ev in events:
+        out.setdefault(ev["name"], []).append(ev)
+    return out
+
+
+@pytest.fixture(scope="module")
+def traced_auto_rounds():
+    """Two solved rounds with pods, a re-solve without pods and an idle
+    sweep, under a SpanTracer and a RoundTracer."""
+    with scoped_registry() as reg:
+        st = SpanTracer().install()
+        try:
+            svc, api = _auto_service(span_tracer=st, tracer=RoundTracer())
+            bound = [
+                svc.run_round(_pods("a", 3, waited_s=(0.3, 0.1))),
+                svc.run_round(_pods("b", 2)),
+                svc.run_round([], solve=True),
+                svc.run_round([], solve=False),
+            ]
+            api.close()
+        finally:
+            st.uninstall()
+        yield svc, st.events(), bound, reg
+
+
+@pytest.mark.parametrize("name", SERVICE_SPANS + AUTO_SPANS)
+def test_new_span_opens_once_per_solved_round_under_its_parent(traced_auto_rounds, name):
+    svc, events, bound, _reg = traced_auto_rounds
+    assert bound == [3, 2, 0, 0]
+    by_name = _by_name(events)
+    rounds = sorted(by_name["service_round"], key=lambda e: e["ts"])
+    assert len(rounds) == 4
+    got = sorted(by_name[name], key=lambda e: e["ts"])
+    # the two rounds with pods ran every piece of work once; the re-solve
+    # found nothing runnable (no `round`, nothing to post), the sweep
+    # only accounts
+    want_rounds = {
+        "pods_admit": [0, 1, 2], "runnable_scan": [0, 1, 2],
+        "bindings_collect": [0, 1, 2], "bindings_post": [0, 1],
+        "round_accounting": [0, 1, 2, 3],
+    }.get(name, [0, 1])
+    assert len(got) == len(want_rounds)
+    parent = "backend_solve" if name in AUTO_SPANS else "service_round"
+    for ev, r in zip(got, want_rounds):
+        assert ev["args"]["parent"] == parent
+        outer = rounds[r]
+        assert outer["ts"] <= ev["ts"] and ev["ts"] + ev["dur"] <= outer["ts"] + outer["dur"] + 1e-3
+        if parent == "service_round":
+            assert ev["args"]["parent_sid"] == outer["args"]["sid"]
+
+
+def test_new_spans_carry_their_args(traced_auto_rounds):
+    _svc, events, _bound, _reg = traced_auto_rounds
+    by_name = _by_name(events)
+    first = {n: min(by_name[n], key=lambda e: e["ts"])["args"] for n in by_name}
+    assert first["pods_admit"]["pods"] == 3
+    assert first["runnable_scan"]["jobs"] == 1
+    assert (first["bindings_collect"]["resident"], first["bindings_collect"]["new"]) == (3, 3)
+    assert first["bindings_post"]["n"] == 3
+    assert first["collapse_audit"]["collapsed"] is True and "reason" not in first["collapse_audit"]
+    assert first["transport"]["rows"] >= 1 and first["transport"]["cols"] == 2
+    assert "supersteps" in first["transport"]
+    assert first["flow_reconstruct"]["tasks"] == 3
+    second = sorted(by_name["bindings_collect"], key=lambda e: e["ts"])[1]["args"]
+    assert (second["resident"], second["new"]) == (5, 2)
+    # the re-solve without pods found no runnable job
+    assert sorted(by_name["runnable_scan"], key=lambda e: e["ts"])[2]["args"]["jobs"] == 0
+
+
+def test_a_refused_collapse_says_why():
+    """The csr branch keeps `backend_solve` minus `collapse_audit` as the
+    rung: no transport, no reconstruction, and the audit's reason."""
+    from tests.test_scheduler_backends import add_job
+    from ksched_tpu.drivers import build_cluster
+    from ksched_tpu.solver.cpu_ref import ReferenceSolver
+    from ksched_tpu.solver.graph_collapse import AutoSolver
+
+    auto = AutoSolver(ReferenceSolver())
+    sched, _rmap, jmap, tmap, _root = build_cluster(
+        num_machines=3, num_cores=2, backend=auto, preemption=True,
+    )
+    with SpanTracer() as st:
+        add_job(sched, jmap, tmap, num_tasks=4)
+        sched.schedule_all_jobs()  # collapses
+        assert auto.last_path == "dense" and auto.last_solve_span.name == "transport"
+        add_job(sched, jmap, tmap, num_tasks=4)
+        sched.schedule_all_jobs()  # running tasks keep arcs to their leaves: refused
+    assert auto.last_path == "csr" and auto.last_solve_span is None
+    by_name = _by_name(st.events())
+    audits = sorted(by_name["collapse_audit"], key=lambda e: e["ts"])
+    assert [e["args"]["collapsed"] for e in audits] == [True, False]
+    assert audits[1]["args"]["reason"] == auto.last_refusal != ""
+    assert len(by_name["transport"]) == len(by_name["flow_reconstruct"]) == 1
+
+
+def _raise(*_a, **_k):
+    raise RuntimeError("boom")
+
+
+@pytest.mark.parametrize("name", SERVICE_SPANS + AUTO_SPANS)
+def test_new_span_closes_with_the_error_when_its_body_raises(monkeypatch, name):
+    from ksched_tpu.runtime import LadderExhausted
+    from ksched_tpu.solver import graph_collapse, layered
+
+    svc, api = _auto_service(degrade=name not in AUTO_SPANS)
+    svc.run_round(_pods("w", 1))  # a healthy round first: something is resident
+    target, attr = {
+        "pods_admit": (svc, "_add_pod"),
+        "runnable_scan": (svc.scheduler, "_compute_runnable_tasks_for_job"),
+        "bindings_collect": (svc.scheduler, "get_task_bindings"),
+        "bindings_post": (api, "assign_bindings"),
+        "round_accounting": (svc._g_pods, "set"),
+        "collapse_audit": (graph_collapse, "try_collapse"),
+        "transport": (layered.LayeredTransportSolver, "solve_layered"),
+        "flow_reconstruct": (graph_collapse.AutoSolver, "_reconstruct_flow"),
+    }[name]
+    monkeypatch.setattr(target, attr, _raise)
+    with SpanTracer() as st:
+        with pytest.raises((RuntimeError, LadderExhausted), match="boom"):
+            svc.run_round(_pods("x", 2))
+    by_name = _by_name(st.events())
+    (ev,) = by_name[name]
+    assert ev["args"]["error"] == "RuntimeError: boom"
+    # the error closed every span above it too, and the tree is whole
+    (outer,) = by_name["service_round"]
+    assert "boom" in outer["args"]["error"]
+    assert ev["ts"] + ev["dur"] <= outer["ts"] + outer["dur"] + 1e-3
+    # the next span on this thread parents at the root again
+    with SpanTracer() as st2:
+        with span("after"):
+            pass
+    assert "parent" not in st2.events()[0]["args"]
+
+
+def test_without_a_tracer_nothing_is_recorded_and_the_round_returns_its_bindings():
+    from ksched_tpu.obs.spans import active_tracer
+
+    assert active_tracer() is None
+    idle = SpanTracer()  # constructed, never installed
+    svc, api = _auto_service()
+    pods = _pods("n", 3, waited_s=(0.2,))
+    assert svc._queue_wait_ms(pods, 1e9) == (0.0, 0.0)  # nobody reads it: not computed
+    assert svc.run_round(pods) == 3
+    assert svc.run_round([], solve=False) == 0
+    assert idle.events() == [] and idle.total == 0
+    assert sorted(api.bindings()) == ["n0", "n1", "n2"]
+    assert svc.scheduler.solver.backend.primary.last_solve_span.dur_s > 0  # spans still time
+
+
+def test_received_s_is_not_part_of_a_pods_identity():
+    from ksched_tpu.cluster import PodEvent
+
+    a, b = PodEvent("p", task_class=2, received_s=1.0), PodEvent("p", task_class=2, received_s=9.0)
+    assert a == b and hash(a) == hash(b) and len({a, b}) == 1
+    assert a != PodEvent("p", task_class=3, received_s=1.0)
+    import time
+
+    t0 = time.perf_counter()
+    assert t0 <= PodEvent("q").received_s <= time.perf_counter()
+
+
+def test_queue_wait_is_the_round_start_minus_the_pods_stamps(traced_auto_rounds):
+    svc, events, _bound, _reg = traced_auto_rounds
+    rounds = sorted(_by_name(events)["service_round"], key=lambda e: e["ts"])
+    recs = svc.tracer.records
+    assert len(recs) == 4
+    # round 0: pods stamped 0.3 s and 0.1 s before `now`, the third at `now`;
+    # the round opened a little after, and that little is the same for all
+    args = rounds[0]["args"]
+    late_ms = args["queue_wait_max_ms"] - 300.0
+    assert 0.0 <= late_ms < 250.0
+    assert args["queue_wait_ms"] == pytest.approx((400.0 + 3 * late_ms) / 3, abs=1.0)
+    for ev, rec in zip(rounds, recs):
+        assert rec.queue_wait_ms == ev["args"]["queue_wait_ms"]
+        assert rec.queue_wait_max_ms == ev["args"]["queue_wait_max_ms"]
+    assert 0.0 < recs[1].queue_wait_ms <= recs[1].queue_wait_max_ms
+    # a solve without pods and an idle sweep waited for nobody
+    for rec in recs[2:]:
+        assert (rec.queue_wait_ms, rec.queue_wait_max_ms) == (0.0, 0.0)
+
+
+def test_queue_wait_histogram_counts_solved_rounds_that_admitted_pods(traced_auto_rounds):
+    svc, _events, _bound, reg = traced_auto_rounds
+    assert reg.value("ksched_rounds_total", kind="sched") == 3
+    assert reg.value("ksched_rounds_total", kind="idle") == 1
+    assert reg.value("ksched_pod_queue_wait_ms") == 2
+    assert "ksched_pod_queue_wait_ms_count 2" in render_prometheus(reg)  # on /metricsz
+
+
+def test_a_redelivered_pod_waited_too():
+    svc, api = _auto_service(tracer=RoundTracer())
+    svc.run_round(_pods("r", 2))
+    again = _pods("r", 1, waited_s=(0.5,))  # same id: the early return of _add_pod
+    svc.run_round(again)
+    assert len(svc.pod_to_task) == 2
+    assert svc.tracer.records[1].queue_wait_max_ms >= 500.0
+
+
+def test_a_split_round_carries_queue_wait_to_its_record():
+    with scoped_registry() as reg:
+        svc, api = _auto_service(tracer=RoundTracer())
+        with SpanTracer() as st:
+            assert svc.dispatch_round(_pods("s", 3, waited_s=(0.25, 0.05))) is True
+            assert svc.complete_round() == 3
+            svc.dispatch_round([])
+            svc.complete_round()
+        first, second = svc.tracer.records
+        assert 250.0 <= first.queue_wait_max_ms < 500.0
+        assert 100.0 <= first.queue_wait_ms < first.queue_wait_max_ms
+        assert (second.queue_wait_ms, second.queue_wait_max_ms) == (0.0, 0.0)
+        assert reg.value("ksched_pod_queue_wait_ms") == 1
+        by_name = _by_name(st.events())
+        for name in SERVICE_SPANS + AUTO_SPANS:
+            assert name in by_name, name  # the split round opens the same spans
+        assert "service_round" not in by_name

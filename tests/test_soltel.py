@@ -380,6 +380,56 @@ def test_solve_traced_publishes_histograms_and_spans():
         # steps are consecutive and end at the last superstep
         idx = [ev["args"]["step"] for ev in steps_ev]
         assert idx == list(range(steps - len(steps_ev), steps))
+        # a backend with no span of its own: laid over the (then open)
+        # backend_solve, from its start to the moment of publication
+        assert getattr(s, "last_solve_span", None) is None
+        assert steps_ev[0]["ts"] == pytest.approx(parent["ts"], abs=1e-3)
+        last = steps_ev[-1]
+        assert last["ts"] + last["dur"] <= parent["ts"] + parent["dur"] + 1e-3
+
+
+def test_auto_solver_lays_its_supersteps_over_the_transport_span(monkeypatch):
+    """Under AutoSolver `backend_solve` is the audit, the kernel call and
+    the reconstruction; the supersteps ran in the second of them only."""
+    from ksched_tpu.cli import SchedulerService
+    from ksched_tpu.cluster import PodEvent, SyntheticClusterAPI
+    from ksched_tpu.costmodels import CostModelType
+    from ksched_tpu.obs.spans import SpanTracer
+    from ksched_tpu.solver.cpu_ref import ReferenceSolver
+    from ksched_tpu.solver.graph_collapse import AutoSolver
+
+    monkeypatch.setattr(soltel, "_enabled", True)  # conftest turns it off for the suite
+    auto = AutoSolver(ReferenceSolver())
+    svc = SchedulerService(
+        SyntheticClusterAPI(), max_tasks_per_pu=4, cost_model=CostModelType.COCO,
+        backend=auto, backend_name="auto", degrade=False,
+    )
+    svc.init_topology(fake_machines=6, pus_per_core=2)
+    tracer = SpanTracer()
+    with scoped_registry() as reg:
+        # the second round sees the first's interference: several cost rows
+        svc.run_round([PodEvent(pod_id=f"a{i}", task_class=i % 3) for i in range(5)])
+        with tracer:
+            svc.run_round([PodEvent(pod_id=f"b{i}", task_class=i % 3) for i in range(5)])
+        assert auto.last_path == "dense" and auto.last_supersteps > 0
+        assert reg.value("ksched_solve_supersteps", backend="layered") == 1
+    events = tracer.events()
+    (transport,) = [e for e in events if e["name"] == "transport"]
+    (audit,) = [e for e in events if e["name"] == "collapse_audit"]
+    (rebuild,) = [e for e in events if e["name"] == "flow_reconstruct"]
+    steps_ev = [e for e in events if e["name"] == "superstep"]
+    assert len(steps_ev) == auto.last_supersteps == transport["args"]["supersteps"]
+    t0, t1 = transport["ts"], transport["ts"] + transport["dur"]
+    for ev in steps_ev:
+        assert ev["args"]["parent"] == "transport"
+        assert ev["args"]["parent_sid"] == transport["args"]["sid"]
+        assert t0 - 1e-3 <= ev["ts"] and ev["ts"] + ev["dur"] <= t1 + 1e-3
+    # they fill the transport span exactly, and none runs during the
+    # audit before it or the reconstruction after it
+    assert steps_ev[0]["ts"] == pytest.approx(t0, abs=1e-3)
+    assert steps_ev[-1]["ts"] + steps_ev[-1]["dur"] == pytest.approx(t1, abs=1e-3)
+    assert audit["ts"] + audit["dur"] <= steps_ev[0]["ts"] + 1e-3
+    assert rebuild["ts"] >= t1 - 1e-3
 
 
 def test_publish_round_supersteps_device_path():
