@@ -43,8 +43,31 @@ CLUSTER_AGGREGATOR_EC = equiv_class_from_bytes(b"CLUSTER_AGG")
 Cost = int
 
 
+#: the methods through which a model could reach a pinned task's node
+_PINNED_TASK_METHODS = (
+    "task_continuation_cost", "prepare_stats", "gather_stats", "update_stats",
+)
+
+
 class CostModeler(abc.ABC):
     """Reference: costmodel/interface.go:54-136."""
+
+    #: What a model says about itself so the graph manager can leave
+    #: pinned tasks (preemption off: one arc, cap_lower == cap_upper ==
+    #: 1) off its per-round work list: ``task_continuation_cost`` is a
+    #: constant, and ``prepare_stats`` / ``gather_stats`` /
+    #: ``update_stats`` do nothing for an accumulator that is not a
+    #: resource node. False keeps the visit of every task every round. A
+    #: subclass that overrides one of those four methods has to say it
+    #: again for itself; it does not inherit the claim.
+    pinned_tasks_are_inert: bool = False
+
+    def __init_subclass__(cls, **kwargs) -> None:
+        super().__init_subclass__(**kwargs)
+        if "pinned_tasks_are_inert" not in cls.__dict__ and any(
+            m in cls.__dict__ for m in _PINNED_TASK_METHODS
+        ):
+            cls.pinned_tasks_are_inert = False
 
     # -- arc costs --------------------------------------------------------
 

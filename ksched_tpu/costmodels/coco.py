@@ -89,6 +89,10 @@ class CocoCostModel(CostModeler):
     """Interference-aware placement (TPU-rebuild implementation of the
     reference's planned COCO model, costmodel/interface.go:39)."""
 
+    # continuation cost is the constant 0 and the census ignores a
+    # non-resource accumulator (base.py)
+    pinned_tasks_are_inert = True
+
     def __init__(
         self,
         resource_map: ResourceMap,

@@ -44,6 +44,11 @@ UNSCHEDULED_COST = 2 * CONGESTION_SCALE
 
 
 class NetCostModel(TrivialCostModel):
+    # restated because the stats hooks are overridden here: both still
+    # act on resource accumulators only, and a PU's reserved bandwidth
+    # is summed from current_running_tasks, not from task nodes
+    pinned_tasks_are_inert = True
+
     def __init__(
         self,
         resource_map: ResourceMap,

@@ -84,6 +84,10 @@ class WhareMapCostModel(CostModeler):
     """Observed-slowdown placement (TPU-rebuild implementation of the
     reference's planned WHARE model, costmodel/interface.go:37)."""
 
+    # continuation cost is the constant 0 and the census ignores a
+    # non-resource accumulator (base.py)
+    pinned_tasks_are_inert = True
+
     def __init__(
         self,
         resource_map: ResourceMap,

@@ -109,6 +109,8 @@ class Node:
     outgoing: Dict[int, Arc] = field(default_factory=dict)
     incoming: Dict[int, Arc] = field(default_factory=dict)
     visited: int = 0
+    #: a task node's place in its job's tree (graph_manager.task_runnable)
+    tree_path: tuple = ()
 
     @property
     def is_task_node(self) -> bool:

@@ -14,6 +14,12 @@ system, 1338 lines). Behavior parity notes:
   :855-888);
 - AddOrUpdateJobNodes drives a worklist BFS (updateFlowGraph :1012-1033)
   that touches task, EC, and resource nodes exactly once per round.
+  Where the reference finds the tasks by walking every job's tree from
+  its root each round, the tasks here come from a work list the events
+  keep (task_runnable, task_evicted, pin, completion): the tasks that
+  have or need a node and whose arcs can change. Their order, and the
+  place of the EC and resource nodes among them, is the reference
+  walk's, so node ids and the change journal are the same.
 """
 
 from __future__ import annotations
@@ -71,35 +77,109 @@ class GraphManager:
         self.leaf_node_ids: Set[int] = set()
         self._cur_traversal_counter = 0
         self._ec_purge_candidates: Set[int] = set()  # unconnected last purge
+        #: the cost model can neither re-price a pinned task's one arc
+        #: nor learn anything from a task node in the statistics walk
+        self._tasks_inert = cost_model.pinned_tasks_are_inert
+        #: job id -> task uid -> (tree path, descriptor): the tasks the
+        #: per-round update visits. A task is listed while it has or
+        #: needs a node, unless it is pinned and the model calls pinned
+        #: tasks inert. The path (child indices from the job's root)
+        #: orders the list as the root-down walk met the tasks.
+        self._worklist: Dict[int, Dict[int, Tuple[tuple, TaskDescriptor]]] = {}
+        #: job id -> pinned tasks that hold a node and are off the list
+        self._pinned_unlisted: Dict[int, int] = {}
+        #: the last add_or_update_job_nodes: task nodes updated, and
+        #: pinned tasks of the same jobs that were left alone
+        self.tasks_visited = 0
+        self.tasks_skipped = 0
 
     # ------------------------------------------------------------------
     # Public lifecycle API (reference interface graph_manager.go:32-86)
     # ------------------------------------------------------------------
 
+    def task_runnable(self, td: TaskDescriptor, path: tuple) -> None:
+        """The scheduler promoted ``td`` to RUNNABLE: it needs a node
+        from the next update of its job on. ``path`` is the task's place
+        in its job's tree, the index in each ``spawned`` list from the
+        root task down (() for the root). No reference counterpart: its
+        walk finds such a task by itself."""
+        self._worklist.setdefault(job_id_from_string(td.job_id), {})[td.uid] = (path, td)
+
     def add_or_update_job_nodes(self, jobs: List[JobDescriptor]) -> None:
-        """Reference: graph_manager.go:166-208."""
-        node_queue: Deque[Tuple[Optional[Node], TaskDescriptor]] = deque()
-        marked: Set[int] = set()
-        for job in jobs:
+        """Reference: graph_manager.go:166-208, over the work list
+        instead of every task under each job's root.
+
+        The reference walks one FIFO breadth-first: tasks level by
+        level, a new child's node added in its parent's turn, and an EC
+        or resource node queued where it is first met. An event here is
+        a listed task's turn (phase 0: update its arcs) or its parent's
+        (phase 1: add its node), keyed (depth, job position, path), and
+        the sorted events give the tasks' order. A node queued during
+        the turn at key (d, j, p) entered the FIFO behind every child of
+        the earlier turns and ahead of this turn's children, so it is
+        due before the first event at or beyond (d + 1, j, p)."""
+        # (key, phase, index among its siblings, uid, descriptor): the
+        # uid only keeps the sort from ever comparing descriptors
+        events: List[Tuple[tuple, int, int, int, TaskDescriptor]] = []
+        skipped = 0
+        for jpos, job in enumerate(jobs):
             jid = job_id_from_string(job.uuid)
             if jid not in self.job_unsched_to_node:
                 self._add_unscheduled_agg_node(jid)
+            skipped += self._pinned_unlisted.get(jid, 0)
+            listed = self._worklist.get(jid)
+            if not listed:
+                continue
             root_td = job.root_task
             assert root_td is not None, f"job {job.uuid} has no root task"
-            root_node = self.task_to_node.get(root_td.uid)
-            if root_node is not None:
-                node_queue.append((root_node, root_td))
-                marked.add(root_node.id)
-                continue
-            if task_needs_node(root_td):
-                root_node = self._add_task_node(jid, root_td)
-                self._update_unscheduled_agg_node(self.job_unsched_to_node[jid], 1)
-                node_queue.append((root_node, root_td))
-                marked.add(root_node.id)
+            if root_td.uid in listed and root_td.uid not in self.task_to_node:
+                # a root's node is added before the walk starts
+                self._add_listed_task_node(jid, root_td)
+            for path, td in listed.values():
+                depth = len(path)
+                events.append(((depth, jpos, path), 0, 0, td.uid, td))
+                if depth and td.uid not in self.task_to_node:
+                    events.append(((depth - 1, jpos, path[:-1]), 1, path[-1], td.uid, td))
+        events.sort()
+        node_queue: Deque[Tuple[Node, Optional[TaskDescriptor]]] = deque()
+        due: Deque[tuple] = deque()  # node_queue[i] comes before events at or beyond due[i]
+        marked: Set[int] = set()
+        visited = 0
+        i, n = 0, len(events)
+        while i < n or node_queue:
+            if node_queue and (i == n or due[0] <= events[i][0]):
+                depth, jpos, path = due.popleft()
+                node, _ = node_queue.popleft()
+                if node.is_equiv_class_node:
+                    self._update_equiv_class_node(node, node_queue, marked)
+                elif node.is_resource_node:
+                    self._update_res_outgoing_arcs(node, node_queue, marked)
+                else:
+                    raise ValueError(f"unexpected node type in worklist: {node.type}")
             else:
-                # No node yet; still traverse for schedulable children.
-                node_queue.append((None, root_td))
-        self._update_flow_graph(node_queue, marked)
+                (depth, jpos, path), phase, _, _, td = events[i]
+                i += 1
+                if phase:
+                    self._add_listed_task_node(job_id_from_string(td.job_id), td)
+                else:
+                    task_node = self.task_to_node.get(td.uid)
+                    if task_node is not None:
+                        self._update_task_node(task_node, node_queue, marked)
+                        visited += 1
+            if len(node_queue) > len(due):
+                due.extend([(depth + 1, jpos, path)] * (len(node_queue) - len(due)))
+        self.tasks_visited = visited
+        self.tasks_skipped = skipped
+
+    def _add_listed_task_node(self, job_id: int, td: TaskDescriptor) -> None:
+        """Reference: graph_manager.go:895-929, one child. A listed task
+        whose state moved on before it ever got a node leaves the list."""
+        if not task_needs_node(td):
+            self._worklist[job_id].pop(td.uid, None)
+            return
+        node = self._add_task_node(job_id, td)
+        node.tree_path = self._worklist[job_id][td.uid][0]
+        self._update_unscheduled_agg_node(self.job_unsched_to_node[job_id], 1)
 
     def update_time_dependent_costs(self, jobs: List[JobDescriptor]) -> None:
         self.add_or_update_job_nodes(jobs)
@@ -161,6 +241,9 @@ class GraphManager:
         """Reference: graph_manager.go:341-345."""
         node = self.job_unsched_to_node.pop(job_id)
         self.cm.delete_node(node, ChangeType.DEL_UNSCHED_JOB_NODE, "JobCompleted")
+        if not self._worklist.get(job_id) and not self._pinned_unlisted.get(job_id):
+            self._worklist.pop(job_id, None)
+            self._pinned_unlisted.pop(job_id, None)
 
     def purge_unconnected_equiv_class_nodes(self) -> None:
         """Remove equivalence-class nodes nothing points at (reference
@@ -211,6 +294,10 @@ class GraphManager:
         if not self.preemption:
             jid = job_id_from_string(task_node.task.job_id)
             self._update_unscheduled_agg_node(self.job_unsched_to_node[jid], 1)
+            listed = self._worklist[task_node.job_id]
+            if task_id not in listed:  # it was pinned and left alone
+                listed[task_id] = (task_node.tree_path, task_node.task)
+                self._pinned_unlisted[task_node.job_id] -= 1
 
     def task_failed(self, task_id: int) -> None:
         """Reference: graph_manager.go:435-448."""
@@ -246,15 +333,21 @@ class GraphManager:
 
     def compute_topology_statistics(self, start: Node) -> None:
         """Reverse BFS from the sink, gathering usage statistics; correct
-        only for tree topologies (reference: graph_manager.go:478-511)."""
+        only for tree topologies (reference: graph_manager.go:478-511).
+        Where the model calls tasks inert, a task node is passed over:
+        the three hooks would return at once for it, and it has no
+        incoming arc, so nothing lies behind it."""
         self._cur_traversal_counter += 1
         counter = self._cur_traversal_counter
+        skip_tasks = self._tasks_inert
         to_visit: Deque[Node] = deque([start])
         start.visited = counter
         while to_visit:
             cur = to_visit.popleft()
             for arc in cur.incoming.values():
                 src = arc.src_node
+                if skip_tasks and src.task is not None:
+                    continue
                 if src.visited != counter:
                     self.cost_model.prepare_stats(src)
                     to_visit.append(src)
@@ -361,6 +454,8 @@ class GraphManager:
         node.excess = 0
         self.sink_node.excess += 1
         del self.task_to_node[node.task.uid]
+        if self._worklist[node.job_id].pop(node.task.uid, None) is None:
+            self._pinned_unlisted[node.job_id] -= 1
         self.cm.delete_node(node, ChangeType.DEL_TASK_NODE, "RemoveTaskNode")
         return node_id
 
@@ -478,44 +573,6 @@ class GraphManager:
     # ------------------------------------------------------------------
     # Private: worklist update (the per-round hot path)
     # ------------------------------------------------------------------
-
-    def _update_flow_graph(
-        self, node_queue: Deque[Tuple[Optional[Node], TaskDescriptor]], marked: Set[int]
-    ) -> None:
-        """Reference: graph_manager.go:1012-1033."""
-        while node_queue:
-            node, task = node_queue.popleft()
-            if node is None:
-                self._update_children_tasks(task, node_queue, marked)
-            elif node.is_task_node:
-                self._update_task_node(node, node_queue, marked)
-                self._update_children_tasks(task, node_queue, marked)
-            elif node.is_equiv_class_node:
-                self._update_equiv_class_node(node, node_queue, marked)
-            elif node.is_resource_node:
-                self._update_res_outgoing_arcs(node, node_queue, marked)
-            else:
-                raise ValueError(f"unexpected node type in worklist: {node.type}")
-
-    def _update_children_tasks(
-        self, td: TaskDescriptor, node_queue: Deque, marked: Set[int]
-    ) -> None:
-        """Reference: graph_manager.go:895-929."""
-        for child in td.spawned:
-            child_node = self.task_to_node.get(child.uid)
-            if child_node is not None:
-                if child_node.id not in marked:
-                    node_queue.append((child_node, child))
-                    marked.add(child_node.id)
-                continue
-            if not task_needs_node(child):
-                node_queue.append((None, child))
-                continue
-            jid = job_id_from_string(child.job_id)
-            child_node = self._add_task_node(jid, child)
-            self._update_unscheduled_agg_node(self.job_unsched_to_node[jid], 1)
-            node_queue.append((child_node, child))
-            marked.add(child_node.id)
 
     def _update_task_node(self, task_node: Node, node_queue: Deque, marked: Set[int]) -> None:
         """Reference: graph_manager.go:1183-1192."""
@@ -813,3 +870,9 @@ class GraphManager:
             )
             assert task_id not in self.task_to_running_arc
             self.task_to_running_arc[task_id] = arc
+        if self._tasks_inert:
+            # one arc that nothing re-prices: off the work list until
+            # the task is evicted
+            jid = task_node.job_id
+            if self._worklist[jid].pop(task_id, None) is not None:
+                self._pinned_unlisted[jid] = self._pinned_unlisted.get(jid, 0) + 1

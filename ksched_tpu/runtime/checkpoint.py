@@ -29,8 +29,10 @@ from ..utils import JobMap, ResourceMap, ResourceStatus, TaskMap, resource_id_fr
 
 CHECKPOINT_VERSION = 1
 #: warm-restore manifest (the ".wal" companion): version of the framed
-#: record stream save_warm_manifest writes
-WARM_MANIFEST_VERSION = 1
+#: record stream save_warm_manifest writes. 2: the pickled GraphManager
+#: carries its work list (a v1 manifest's has none; restore falls back
+#: to the cold replay, which rebuilds it from the events)
+WARM_MANIFEST_VERSION = 2
 
 
 class CheckpointError(RuntimeError):

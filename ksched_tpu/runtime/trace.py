@@ -60,6 +60,12 @@ class RoundRecord:
     #: start): mean and max over the batch, 0.0 for a round without pods
     queue_wait_ms: float = 0.0
     queue_wait_max_ms: float = 0.0
+    #: the round's graph update (GraphManager.add_or_update_job_nodes):
+    #: task nodes it updated, and pinned tasks of the same jobs that it
+    #: left alone because nothing can change their one arc (from the
+    #: round's RoundTiming, so 0 wherever the phase timings are)
+    graph_tasks_visited: int = 0
+    graph_tasks_skipped: int = 0
 
 
 class RoundTracer:
@@ -203,6 +209,8 @@ class RoundTracer:
             arcs_added=stats.arcs_added if stats else 0,
             arcs_changed=stats.arcs_changed if stats else 0,
             arcs_removed=stats.arcs_removed if stats else 0,
+            graph_tasks_visited=t.graph_tasks_visited,
+            graph_tasks_skipped=t.graph_tasks_skipped,
         )
         for k, v in (extra or {}).items():
             if not hasattr(rec, k):

@@ -37,13 +37,17 @@ from ..solver.placement import PlacementSolver
 from ..utils import JobMap, ResourceMap, TaskMap, job_id_from_string, resource_id_from_string
 
 
+#: the states _compute_runnable_tasks_for_job promotes to RUNNABLE
+_PROMOTED_STATES = (TaskState.CREATED, TaskState.BLOCKING)
+
+
 @dataclass
 class RoundTiming:
     """Per-phase wall-clock breakdown of one scheduling round (the
     reference only times the whole round ad hoc in its CLI,
     cmd/k8sscheduler/scheduler.go:146-150; we make phases first-class).
 
-    Every field is the duration of an obs span (`round` → `stats`,
+    Every `_s` field is the duration of an obs span (`round` → `stats`,
     `graph_update`, `solve`, `deltas`, `apply`), so the RoundRecord
     JSONL (runtime/trace.py) and a captured Perfetto trace are two
     views of the same measurement and can never disagree."""
@@ -54,6 +58,10 @@ class RoundTiming:
     deltas_s: float = 0.0
     apply_s: float = 0.0
     total_s: float = 0.0
+    #: what `graph_update` did: task nodes updated, and pinned tasks of
+    #: the same jobs left alone (GraphManager.add_or_update_job_nodes)
+    graph_tasks_visited: int = 0
+    graph_tasks_skipped: int = 0
 
 
 class FlowScheduler:
@@ -356,6 +364,10 @@ class FlowScheduler:
             timing.stats_s = sp.dur_s
             with span("graph_update") as sp:
                 self.gm.add_or_update_job_nodes(jds)
+                timing.graph_tasks_visited = self.gm.tasks_visited
+                timing.graph_tasks_skipped = self.gm.tasks_skipped
+                sp.set("graph_tasks_visited", timing.graph_tasks_visited)
+                sp.set("graph_tasks_skipped", timing.graph_tasks_skipped)
             timing.graph_update_s = sp.dur_s
         except BaseException:
             round_span.__exit__(*sys.exc_info())
@@ -495,24 +507,34 @@ class FlowScheduler:
 
     def _compute_runnable_tasks_for_job(self, jd: JobDescriptor) -> Set[int]:
         """Dependency-free lazy graph reduction (reference:
-        flowscheduler/scheduler.go:493-529)."""
+        flowscheduler/scheduler.go:493-529). A promoted task is handed
+        to the graph manager with its place in the tree, which is why
+        a child is looked at from its parent: that is where its index
+        is known."""
         job_id = job_id_from_string(jd.uuid)
         root = jd.root_task
-        queue: List[TaskDescriptor] = []
         if root.state in (
             TaskState.CREATED,
             TaskState.RUNNING,
             TaskState.RUNNABLE,
             TaskState.COMPLETED,
         ):
-            queue.append(root)
-        while queue:
-            cur = queue.pop()
-            queue.extend(cur.spawned)
-            if cur.state in (TaskState.CREATED, TaskState.BLOCKING):
-                cur.state = TaskState.RUNNABLE
-                self._insert_task_into_runnables(job_id_from_string(cur.job_id), cur.uid)
+            if root.state == TaskState.CREATED:
+                self._promote_task(root, ())
+            parents = [(root, ())]
+            while parents:
+                parent, path = parents.pop()
+                for i, child in enumerate(parent.spawned):
+                    if child.state in _PROMOTED_STATES:
+                        self._promote_task(child, path + (i,))
+                    if child.spawned:
+                        parents.append((child, path + (i,)))
         return self.runnable_tasks.setdefault(job_id, set())
+
+    def _promote_task(self, td: TaskDescriptor, path: tuple) -> None:
+        td.state = TaskState.RUNNABLE
+        self._insert_task_into_runnables(job_id_from_string(td.job_id), td.uid)
+        self.gm.task_runnable(td, path)
 
     # ------------------------------------------------------------------
     # Resource removal helpers
