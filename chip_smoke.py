@@ -27,6 +27,20 @@ by the repo's own means:
   sharded  with >= 4 devices: dryrun_multichip(4) and one
            make_backend("sharded") solve bit-equal to the single-chip
            solve; otherwise `sharded: not_run (N device)`.
+  resident the benchmark's two `trivial-10kx1k` deployments side by
+           side: one service from benchmarks/configs/trivial-10kx1k.json's
+           argv, one from trivial-10kx1k-resident.json's (`--device-resident
+           --pipeline`), the same fill and the same batches through
+           `run_round` (a trickle-shaped stretch of tens of pods with
+           completions, a waves-shaped stretch of 1,000). Per round:
+           each objective equals the native C++ solver's on
+           `state.problem()`. At the end: the device mirror and the
+           plan mirror equal the host's arrays, and the resident
+           service compiled nothing after the first round of each
+           stretch. Whether its Bindings are the synchronous loop's pod
+           for pod is reported either way.
+
+`--only PHASE` (repeatable) runs the named phases alone.
 
 It refuses to start unless jax.devices()[0].platform == "tpu". No phase
 is wrapped in a handler: a failure is a traceback and a non-zero exit.
@@ -58,12 +72,14 @@ FULL = dict(
     array=dict(tasks=50_000, machines=1_000, decode_width=1024, chunks=3, rounds=32),
     kernels=dict(classes=4, machines=1_000),
     general=dict(tasks=10_000, machines=1_000),
+    resident=dict(scale=1, trickle=(26, 31, 12, 58, 40, 9, 60, 22), waves=4),
 )
 TINY = dict(
     served=dict(machines=20, pods=200, churn=10),
     array=dict(tasks=600, machines=12, decode_width=256, chunks=3, rounds=4),
     kernels=dict(classes=4, machines=40),
     general=dict(tasks=400, machines=40),
+    resident=dict(scale=40, trickle=(2, 5, 1, 9, 3), waves=3),
 )
 
 
@@ -466,6 +482,122 @@ class Smoke:
             f"(objective={one.objective}, supersteps={one.iterations})"
         )
 
+    # -- the resident deployment against its synchronous control ------------
+
+    def _serve_config(self, name: str, compiles: list) -> dict:
+        """One benchmark deployment, built from its file's argv as
+        cli.main builds it, driven through the leg's fixed batches."""
+        from ksched_tpu import cli
+        from ksched_tpu.cluster import SyntheticClusterAPI
+        from ksched_tpu.cluster.api import PodEvent
+        from ksched_tpu.solver.select import make_backend
+        from ksched_tpu.utils import seed_rng
+
+        sz = self.sizes["resident"]
+        here = os.path.dirname(os.path.abspath(__file__))
+        with open(os.path.join(here, "benchmarks", "configs", name + ".json")) as f:
+            config = json.load(f)
+        argv = list(config["argv"])
+        i = argv.index("--num-machines") + 1
+        argv[i] = str(int(argv[i]) // sz["scale"])
+        fill = config["resident_pods"] // sz["scale"]
+        wave = config["wave_pods"] // sz["scale"]
+        seed_rng(self.seed)
+        args = cli.build_arg_parser().parse_args(argv)
+        api = SyntheticClusterAPI(pod_chan_size=args.pod_chan_size)
+        svc = cli.build_service(args, api)
+        svc.init_topology(
+            fake_machines=args.num_machines,
+            cores_per_machine=args.cores_per_machine,
+            pus_per_core=args.pus_per_core,
+        )
+        solver = svc.scheduler.solver
+        rung = solver.backend.primary if svc.ladder is not None else solver.backend
+        native = make_backend("native", warm_start=False, fallback=False)
+        live: list = []
+        rounds: list = []
+        late = {"trickle": 0, "waves": 0}  # compiles after a stretch's first round
+        plan = [("fill", fill, 0)]
+        plan += [("trickle", k, k) for k in sz["trickle"]]
+        plan += [("waves", wave, wave)] * sz["waves"]
+        seen = ""
+        for stretch, arrivals, completions in plan:
+            for pod_id in live[:completions]:  # the oldest complete first
+                check(svc.complete_pod(pod_id), f"{name}: {pod_id} was not bound")
+            del live[:completions]
+            for _ in range(arrivals):
+                live.append(f"pod_{len(rounds)}_{len(live)}")
+                api.submit_pod(PodEvent(pod_id=live[-1]))
+            pods = api.get_pod_batch(0.2)
+            check(len(pods) == arrivals, f"{name}: {len(pods)} pods arrived, {arrivals} sent")
+            before, mark = api.bindings(), len(compiles)
+            t0 = time.perf_counter()
+            bound = svc.run_round(pods)
+            wall = time.perf_counter() - t0
+            svc.run_round([], solve=False)  # the idle sweep POSTs what a pipeline deferred
+            if stretch == seen:
+                late[stretch] += len(compiles) - mark
+            seen = stretch
+            check(bound == arrivals, f"{name} {stretch}: bound {bound} of {arrivals}")
+            check(svc.noop_rounds == 0, f"{name} {stretch}: a NOOP round")
+            ours = int(solver.last_result.objective)
+            theirs = int(native.solve(solver.state.problem()).objective)
+            check(ours == theirs, f"{name} {stretch} round {len(rounds)}: objective {ours} != native {theirs}")
+            res = solver.resident
+            rounds.append(dict(
+                stretch=stretch, wall_ms=round(wall * 1e3, 1), objective=ours,
+                new={p: n for p, n in api.bindings().items() if before.get(p) != n},
+                supersteps=int(rung.last_supersteps), scope=rung.last_warm_scope,
+                upload=(res.last_upload_kind, res.last_record_bucket, res.last_plan_kind,
+                        res.last_plan_bucket, res.last_upload_bytes,
+                        res.last_plan_relocations) if res is not None else None,
+            ))
+        check(svc.ladder is None or svc.ladder.degradations_total == 0, f"{name}: a step down the ladder")
+        if solver.resident is not None:
+            solver.resident.parity_check()
+            solver.resident.plan_parity_check()
+        api.close()
+        return dict(rounds=rounds, late=late, state=solver.state)
+
+    def resident(self) -> str:
+        import jax
+
+        compiles: list = []
+
+        def on_duration(event, _duration, **_kw):
+            if event == "/jax/core/compile/backend_compile_duration":
+                compiles.append(event)
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        sync = self._serve_config("trivial-10kx1k", compiles)
+        res = self._serve_config("trivial-10kx1k-resident", compiles)
+        check(res["late"] == {"trickle": 0, "waves": 0},
+              f"the resident service compiled after a stretch's first round: {res['late']}")
+        pairs = list(zip(sync["rounds"], res["rounds"]))
+        check(all(a["objective"] == b["objective"] for a, b in pairs),
+              "resident and synchronous objectives differ (both equal native's: the inputs differ)")
+        differ = [i for i, (a, b) in enumerate(pairs) if a["new"] != b["new"]]
+        for i, r in enumerate(res["rounds"]):
+            kind, bucket, plan_kind, plan_bucket, nbytes, moved = r["upload"]
+            self.say(
+                f"resident round {i} {r['stretch']}: wall_ms sync={sync['rounds'][i]['wall_ms']} "
+                f"resident={r['wall_ms']} supersteps sync={sync['rounds'][i]['supersteps']} "
+                f"resident={r['supersteps']} scope={r['scope']} upload={kind}/{bucket} "
+                f"plan={plan_kind}/{plan_bucket} bytes={nbytes} relocations={moved}"
+            )
+        st = res["state"]
+        return (
+            f"nodes={st.n_cap} arcs={st.m_cap} entries={st.plan.entry_cap} "
+            f"rounds={len(pairs)} objectives==native in both; mirror and plan mirror equal the host's; "
+            f"compiles after a stretch's first round: resident={res['late']} synchronous={sync['late']}; "
+            f"bindings_identical={not differ}"
+            + (f" (first differing round {differ[0]}, {len(differ)} in all)" if differ else "")
+            + f"; warm scopes resident={sorted(set(r['scope'] for r in res['rounds']))}"
+        )
+
+
+PHASES = ("served", "array", "kernels", "general", "sharded", "resident")
+
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
@@ -473,6 +605,8 @@ def main(argv=None) -> int:
                     help="same phases, tiny sizes, Pallas under the interpreter, "
                     "on the CPU — the only way this script runs without a chip")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--only", action="append", choices=PHASES, metavar="PHASE",
+                    help=f"run this phase alone (repeatable): {', '.join(PHASES)}")
     args = ap.parse_args(argv)
     if args.rehearse_cpu:
         os.environ["JAX_PLATFORMS"] = "cpu"  # before `import jax`
@@ -507,12 +641,10 @@ def main(argv=None) -> int:
         f"({'warm: walls of the run that filled it on file' if smoke.cold_walls else 'cold'})"
     )
     t0 = time.perf_counter()
-    smoke.phase("served", smoke.served)
-    smoke.phase("array", smoke.array)
-    smoke.phase("kernels", smoke.kernels)
-    smoke.phase("general", smoke.general)
-    smoke.phase("sharded", smoke.sharded)
-    smoke.save_walls()
+    for name in args.only or PHASES:
+        smoke.phase(name, getattr(smoke, name))
+    if not args.only:
+        smoke.save_walls()
     smoke.say(f"all phases passed in {time.perf_counter() - t0:.1f}s")
     result = {"ok": True, "device": smoke.device}
     if args.rehearse_cpu:

@@ -623,11 +623,12 @@ def trace_plan_apply(
     ks_raw: int = 0, kn_raw: int = 0,
 ):
     """Abstract trace of the SECOND (and last) scatter-exempt program:
-    the slot-stable plan-row + boundary-static apply over pow2-bucketed
-    record counts (graph/slot_plan.plan_apply_fn). The seg/node static
-    streams carry real dirt only on region-relocation rounds; on
-    ordinary churn rounds they are minimum-bucket idempotent pads, so
-    the common-case program is the (kp, ki, 1, 1)-bucket one."""
+    the slot-stable plan-row + boundary-static apply
+    (graph/slot_plan.plan_apply_fn). Its four record streams share ONE
+    pow2 bucket (the largest stream's), so the shapes it can be called
+    with are the closed set `graph/device_export.record_buckets` lists;
+    the seg/node static streams carry real dirt only on
+    region-relocation rounds and are idempotent pads otherwise."""
     from ..graph.device_export import pad_record_count
     from ..graph.slot_plan import (
         INV_RECORD_COLS,
@@ -639,16 +640,13 @@ def trace_plan_apply(
 
     n, m = bucketed_sizes(n_raw, m_raw)
     e = slot_stable_entry_cap(m)
-    kp = pad_record_count(kp_raw)
-    ki = pad_record_count(ki_raw)
-    ks = pad_record_count(ks_raw)
-    kn = pad_record_count(kn_raw)
+    k = pad_record_count(kp_raw, ki_raw, ks_raw, kn_raw)
     return jax.make_jaxpr(plan_apply_fn())(
         _sds((e,)), _sds((e,)), _sds((e,)), _sds((e,)), _sds((2 * m,)),
         _sds((e,)), _sds((e,), jnp.bool_),
         _sds((n,)), _sds((n,)), _sds((n,), jnp.bool_),
-        _sds((kp, PLAN_RECORD_COLS)), _sds((ki, INV_RECORD_COLS)),
-        _sds((ks, SEG_RECORD_COLS)), _sds((kn, NODE_RECORD_COLS)),
+        _sds((k, PLAN_RECORD_COLS)), _sds((k, INV_RECORD_COLS)),
+        _sds((k, SEG_RECORD_COLS)), _sds((k, NODE_RECORD_COLS)),
     )
 
 
@@ -690,8 +688,8 @@ def trace_stacked(
 
 def trace_delta_apply(ka_raw: int, kn_raw: int, n_raw: int = 20, m_raw: int = 100):
     """Abstract trace of the FIRST scatter-exempt program: the
-    device-resident delta apply over pow2-bucketed record counts
-    (graph/device_export.delta_apply_fn)."""
+    device-resident delta apply (graph/device_export.delta_apply_fn),
+    its arc and node records padded to ONE joint pow2 bucket."""
     from ..graph.device_export import (
         ARC_RECORD_COLS,
         NODE_RECORD_COLS,
@@ -700,11 +698,10 @@ def trace_delta_apply(ka_raw: int, kn_raw: int, n_raw: int = 20, m_raw: int = 10
     )
 
     n, m = bucketed_sizes(n_raw, m_raw)
-    ka = pad_record_count(ka_raw)
-    kn = pad_record_count(kn_raw)
+    k = pad_record_count(ka_raw, kn_raw)
     return jax.make_jaxpr(delta_apply_fn())(
         _sds((n,)), _sds((m,)), _sds((m,)), _sds((m,)), _sds((m,)),
-        _sds((ka, ARC_RECORD_COLS)), _sds((kn, NODE_RECORD_COLS)),
+        _sds((k, ARC_RECORD_COLS)), _sds((k, NODE_RECORD_COLS)),
     )
 
 
@@ -831,11 +828,10 @@ def aot_delta_apply(ka_raw: int = 5, kn_raw: int = 3, n_raw: int = 20, m_raw: in
     )
 
     n, m = bucketed_sizes(n_raw, m_raw)
-    ka = pad_record_count(ka_raw)
-    kn = pad_record_count(kn_raw)
+    k = pad_record_count(ka_raw, kn_raw)
     return delta_apply_fn(), (
         _sds((n,)), _sds((m,)), _sds((m,)), _sds((m,)), _sds((m,)),
-        _sds((ka, ARC_RECORD_COLS)), _sds((kn, NODE_RECORD_COLS)),
+        _sds((k, ARC_RECORD_COLS)), _sds((k, NODE_RECORD_COLS)),
     )
 
 
@@ -851,16 +847,13 @@ def aot_plan_apply(kp_raw: int = 5, ki_raw: int = 3, n_raw: int = 20, m_raw: int
 
     n, m = bucketed_sizes(n_raw, m_raw)
     e = slot_stable_entry_cap(m)
-    kp = pad_record_count(kp_raw)
-    ki = pad_record_count(ki_raw)
-    ks = pad_record_count(0)
-    kn = pad_record_count(0)
+    k = pad_record_count(kp_raw, ki_raw)
     return plan_apply_fn(), (
         _sds((e,)), _sds((e,)), _sds((e,)), _sds((e,)), _sds((2 * m,)),
         _sds((e,)), _sds((e,), jnp.bool_),
         _sds((n,)), _sds((n,)), _sds((n,), jnp.bool_),
-        _sds((kp, PLAN_RECORD_COLS)), _sds((ki, INV_RECORD_COLS)),
-        _sds((ks, SEG_RECORD_COLS)), _sds((kn, NODE_RECORD_COLS)),
+        _sds((k, PLAN_RECORD_COLS)), _sds((k, INV_RECORD_COLS)),
+        _sds((k, SEG_RECORD_COLS)), _sds((k, NODE_RECORD_COLS)),
     )
 
 

@@ -378,7 +378,10 @@ _SPECS = (
         ),
         collectives=CollectiveBudget(forbidden=_ALL_COLLECTIVES),
         notes="O(churn) once-per-round problem-delta scatter; excess/cap/"
-        "cost donated in place (measured 498 -> 8.7 us/apply at 256k rows)",
+        "cost donated in place (measured 498 -> 8.7 us/apply at 256k rows); "
+        "arc and node records share one pow2 bucket, and the buckets of a "
+        "mirror (graph/device_export.record_buckets) are compiled where its "
+        "buffers are allocated",
     ),
     ProgramSpec(
         name="plan_apply", module="ksched_tpu.graph.slot_plan",
@@ -394,7 +397,8 @@ _SPECS = (
         ),
         collectives=CollectiveBudget(forbidden=_ALL_COLLECTIVES),
         notes="slot-stable plan-row + boundary-static apply; all ten plan "
-        "tensors donated",
+        "tensors donated; the four record streams share one pow2 bucket of "
+        "the same closed set",
     ),
     ProgramSpec(
         name="sharded_plan_apply", module="ksched_tpu.parallel.sharded_solver",

@@ -109,6 +109,10 @@ class SchedulerService:
         self.pipeline = pipeline
         self.device_resident = device_resident
         self._pending_bindings: List[Binding] = []
+        #: when the oldest of them left `_collect_bindings`, and how long
+        #: the last batch POSTed had waited (RoundRecord.post_defer_ms)
+        self._pending_since = 0.0
+        self._post_defer_ms = 0.0
         # service-level gauges (inert singletons when obs is disabled)
         reg = obs_metrics.get_registry()
         self._g_pods = reg.gauge("ksched_live_pods", "pods the service tracks")
@@ -400,7 +404,15 @@ class SchedulerService:
         except BaseException:
             self._pending_bindings = out + self._pending_bindings
             raise
+        if out:
+            self._post_defer_ms = (time.perf_counter() - self._pending_since) * 1e3
         return len(out)
+
+    def _defer_bindings(self, out: List[Binding]) -> None:
+        """Queue a pipelined round's Bindings for the next flush point."""
+        if out and not self._pending_bindings:
+            self._pending_since = time.perf_counter()
+        self._pending_bindings.extend(out)
 
     def run_once(self, pods) -> int:
         """One iteration of the reference loop body (:120-187). Returns
@@ -454,7 +466,7 @@ class SchedulerService:
             raise
         self.round_latencies_s.append(time.perf_counter() - t0)
         out = self._collect_bindings()
-        self._pending_bindings.extend(out)
+        self._defer_bindings(out)
         if flush_err is not None:
             raise flush_err
         return len(out)
@@ -612,7 +624,7 @@ class SchedulerService:
             if self.pipeline:
                 # per-tenant dispatch window: the POSTs ride the NEXT
                 # round's batched-solve window (cell.post_window)
-                self._pending_bindings.extend(out)
+                self._defer_bindings(out)
             else:
                 self._post_bindings(out)
             bound = len(out)
@@ -704,8 +716,13 @@ class SchedulerService:
                         tenant=self.tenant,
                         queue_wait_ms=queue_wait[0],
                         queue_wait_max_ms=queue_wait[1],
+                        post_defer_ms=self._post_defer_ms if solve else 0.0,
                     ),
                 )
+            if solve:
+                # consumed by the solved round's record: an idle sweep
+                # that flushed leaves it for the round that follows
+                self._post_defer_ms = 0.0
         return rec
 
     def run(self, pod_batch_timeout_s: float = 2.0, max_rounds: Optional[int] = None) -> None:
@@ -1179,7 +1196,10 @@ def build_arg_parser() -> argparse.ArgumentParser:
                     "post the previous round's bindings while it is in "
                     "flight, then synchronize/decode (docs/round_pipeline"
                     ".md); placements are bit-identical to the "
-                    "synchronous loop")
+                    "synchronous loop. The Bindings of round N are POSTed "
+                    "in round N+1's dispatch window (or by the idle sweep "
+                    "after a quiet poll): a pod waits that much longer "
+                    "for its Binding (RoundRecord.post_defer_ms)")
     ap.add_argument("--device-resident", action="store_true",
                     help="keep the flow problem's arrays live on device "
                     "between rounds: after the first full upload only "

@@ -427,12 +427,60 @@ NODE_RECORD_COLS = 2
 #: smallest padded record count — one compiled scatter program per pow2
 #: record bucket, so tiny deltas share one executable
 MIN_RECORD_BUCKET = 8
+#: a delta whose bucket would pass this share of the buffer's rows goes
+#: up whole instead: a record is 20 B against 16 B of arrays per slot,
+#: so the next bucket up would ship more bytes than the arrays and
+#: still need the serialized scatter
+FULL_UPLOAD_SHARE = 2
 
 
-def pad_record_count(k: int) -> int:
-    """Pow2 bucket for a delta-record count (>= 1 so an empty delta
-    still has a well-formed — idempotent — record to ship)."""
-    return max(next_pow2(max(k, 1)), MIN_RECORD_BUCKET)
+def pad_record_count(*counts: int) -> int:
+    """The ONE pow2 bucket all of a scatter program's record streams
+    are padded to (>= 1 so an empty delta still has a well-formed —
+    idempotent — record to ship). One joint bucket per program, not
+    one per stream: the shapes a program can be called with are then
+    a short list (`record_buckets`), not a product of lists."""
+    return max(next_pow2(max(max(counts), 1)), MIN_RECORD_BUCKET)
+
+
+def record_buckets(extent: int) -> Tuple[int, ...]:
+    """The closed set of record buckets of a scatter program over a
+    buffer of `extent` rows: the pow2s from MIN_RECORD_BUCKET up to
+    extent / FULL_UPLOAD_SHARE. A delta past the last one goes up
+    whole on the full-upload path."""
+    top = max(extent // FULL_UPLOAD_SHARE, MIN_RECORD_BUCKET)
+    return tuple(
+        MIN_RECORD_BUCKET << i
+        for i in range((top // MIN_RECORD_BUCKET).bit_length())
+    )
+
+
+#: (program, buffer shapes) -> {bucket: compiled executable}
+_CLOSED_SETS: Dict[Tuple, Dict[int, object]] = {}
+
+
+def closed_set(program, buffers, record_cols, extent: int) -> Dict[int, object]:
+    """`program` compiled ahead of time for every bucket of
+    `record_buckets(extent)`, keyed by bucket. Called where the
+    persistent buffers are (re)allocated — first full upload, pow2
+    growth, layout rebuild — so that no later round meets a shape that
+    has not been compiled: a served round that compiles makes its pods
+    wait tenths of a second to seconds on a TPU. Shared by every mirror
+    of the same buffer shapes in the process, like a jit cache."""
+    import jax
+
+    shapes = tuple(jax.ShapeDtypeStruct(b.shape, b.dtype) for b in buffers)
+    key = (program, tuple((b.shape, b.dtype.name) for b in shapes))
+    programs = _CLOSED_SETS.get(key)
+    if programs is None:
+        programs = _CLOSED_SETS[key] = {
+            k: program.lower(
+                *shapes,
+                *(jax.ShapeDtypeStruct((k, c), np.int32) for c in record_cols),
+            ).compile()
+            for k in record_buckets(extent)
+        }
+    return programs
 
 
 _DELTA_APPLY = None
@@ -628,6 +676,11 @@ class DeviceResidentState:
         self.last_upload_kind = "full_build"
         self.last_arc_records = 0
         self.last_node_records = 0
+        #: the joint record bucket of the last delta (0: went up whole)
+        self.last_record_bucket = 0
+        #: bucket -> compiled delta apply for the buffers as allocated
+        #: (closed_set): a delta whose bucket is not here goes up whole
+        self._delta_set: Dict[int, object] = {}
         self._scaled = None  # (version, jax scaled-cost buffer)
         # ---- slot-stable plan mirror (graph/slot_plan.py) ------------
         self.d_p_arc = None
@@ -648,6 +701,12 @@ class DeviceResidentState:
         self.last_plan_kind = "none"  # none | rebuild | delta | clean
         self.last_plan_bytes = 0
         self.last_plan_records = 0
+        self.last_plan_bucket = 0  # joint record bucket of a plan delta
+        #: regions the plan relocated since the previous sync
+        self.last_plan_relocations = 0
+        self._relocations_seen = 0
+        #: bucket -> compiled plan apply for the layout as uploaded
+        self._plan_set: Dict[int, object] = {}
         #: sharded plan mirror mode (enable_sharded_plan): the entry-
         #: shaped plan tensors are maintained as [D, Es] stacked
         #: per-shard tables and the round's records route to their
@@ -656,10 +715,10 @@ class DeviceResidentState:
 
     # -- packing -----------------------------------------------------------
 
-    def _pack_arcs(self, slots: np.ndarray) -> np.ndarray:
+    def _pack_arcs(self, slots: np.ndarray, bucket: int) -> np.ndarray:
         st = self.state
         ka = len(slots)
-        rec = np.zeros((pad_record_count(ka), ARC_RECORD_COLS), np.int32)
+        rec = np.zeros((bucket, ARC_RECORD_COLS), np.int32)
         if ka:
             low = st.low[slots]
             rec[:ka, 0] = slots
@@ -675,10 +734,10 @@ class DeviceResidentState:
             rec[:, 4] = st.cost[0]
         return rec
 
-    def _pack_nodes(self, nodes: np.ndarray) -> np.ndarray:
+    def _pack_nodes(self, nodes: np.ndarray, bucket: int) -> np.ndarray:
         st = self.state
         kn = len(nodes)
-        rec = np.zeros((pad_record_count(kn), NODE_RECORD_COLS), np.int32)
+        rec = np.zeros((bucket, NODE_RECORD_COLS), np.int32)
         folded0 = st.excess[nodes] + st.fold[nodes] if kn else None
         if kn:
             rec[:kn, 0] = nodes
@@ -707,6 +766,39 @@ class DeviceResidentState:
             )
         return nbytes
 
+    def ship_records(self, slots: np.ndarray, nodes: np.ndarray) -> int:
+        """Bring the given arc slots and nodes of the mirror to the
+        host's values and return the bytes that took: packed records
+        through the scatter where their joint bucket is in the closed
+        set (`delta_pack`, `delta_upload` spans), the arrays whole
+        where it is past the largest one (`last_record_bucket` 0).
+        A delta round's export, and the integrity ladder's re-scatter
+        rung."""
+        import jax.numpy as jnp
+
+        from ..obs.spans import span
+
+        bucket = pad_record_count(len(slots), len(nodes))
+        apply_delta = self._delta_set.get(bucket)
+        if apply_delta is None:
+            # slots are stable, so the solvers' warm flow survives
+            self.last_record_bucket = 0
+            with span("delta_upload", kind="oversize_delta"):
+                return self._full_upload(self.state.problem(), arcs_too=True)
+        self.last_record_bucket = bucket
+        with span("delta_pack", arcs=len(slots), nodes=len(nodes)):
+            arc_rec = self._pack_arcs(slots, bucket)
+            node_rec = self._pack_nodes(nodes, bucket)
+        nbytes = arc_rec.nbytes + node_rec.nbytes
+        with span("delta_upload", bytes=nbytes, bucket=bucket):
+            (
+                self.d_excess, self.d_src, self.d_dst, self.d_cap, self.d_cost,
+            ) = apply_delta(
+                self.d_excess, self.d_src, self.d_dst, self.d_cap, self.d_cost,
+                jnp.asarray(arc_rec), jnp.asarray(node_rec),
+            )
+        return nbytes
+
     def refresh(self) -> DeviceResidentProblem:
         """Sync the mirror with the host state and return the
         device-resident problem handle for this round's solve."""
@@ -718,44 +810,32 @@ class DeviceResidentState:
         rebuilt = self._rebuild_count != st.rebuild_count
         arcs_stale = rebuilt or self._m_cap != st.m_cap or self.d_src is None
         nodes_stale = rebuilt or self._n_cap != st.n_cap or self.d_excess is None
+        self.last_arc_records = len(slots)
+        self.last_node_records = len(nodes)
+        nbytes = 0
         if arcs_stale or nodes_stale:
             with span(
                 "delta_upload",
                 kind="full_build" if arcs_stale else "node_rebuild",
             ):
                 nbytes = self._full_upload(problem, arcs_too=arcs_stale)
-                if not arcs_stale:
-                    # node bucket grew, arc side still delta-sized: the
-                    # endpoint geometry survives, so warm flow does too
-                    arc_rec = self._pack_arcs(slots)
-                    self._scatter_arcs(arc_rec)
-                    nbytes += arc_rec.nbytes
-            self.last_upload_kind = "full_build"
-            self.last_upload_bytes = nbytes
-            self.last_arc_records = len(slots)
-            self.last_node_records = len(nodes)
-        else:
-            with span("delta_pack", arcs=len(slots), nodes=len(nodes)):
-                arc_rec = self._pack_arcs(slots)
-                node_rec = self._pack_nodes(nodes)
-            with span(
-                "delta_upload", bytes=arc_rec.nbytes + node_rec.nbytes
-            ):
-                import jax.numpy as jnp
-
-                apply_delta = delta_apply_fn()
-                (
-                    self.d_excess, self.d_src, self.d_dst,
-                    self.d_cap, self.d_cost,
-                ) = apply_delta(
-                    self.d_excess, self.d_src, self.d_dst,
-                    self.d_cap, self.d_cost,
-                    jnp.asarray(arc_rec), jnp.asarray(node_rec),
+                # the buffers were (re)allocated: compile, now, every
+                # shape a later delta can have
+                self._delta_set = closed_set(
+                    delta_apply_fn(),
+                    (self.d_excess, self.d_src, self.d_dst, self.d_cap, self.d_cost),
+                    (ARC_RECORD_COLS, NODE_RECORD_COLS),
+                    st.m_cap,
                 )
-            self.last_upload_kind = "delta"
-            self.last_upload_bytes = arc_rec.nbytes + node_rec.nbytes
-            self.last_arc_records = len(slots)
-            self.last_node_records = len(nodes)
+            self.last_record_bucket = 0
+            nodes = nodes[:0]  # every node went up
+        if not arcs_stale:
+            # the arc side is delta-sized also when the node bucket
+            # grew: the endpoint geometry survives, so warm flow does
+            nbytes += self.ship_records(slots, nodes)
+        whole = arcs_stale or nodes_stale or not self.last_record_bucket
+        self.last_upload_kind = "full_build" if whole else "delta"
+        self.last_upload_bytes = nbytes
         self._rebuild_count = st.rebuild_count
         self._n_cap = st.n_cap
         self._m_cap = st.m_cap
@@ -836,32 +916,27 @@ class DeviceResidentState:
         from ..obs.spans import span
 
         if self._shard is None:
-            from .slot_plan import plan_apply_fn
-
             row_rec, inv_rec, seg_rec, node_rec = plan.drain_records()
             rec_bytes = (
                 row_rec.nbytes + inv_rec.nbytes
                 + seg_rec.nbytes + node_rec.nbytes
             )
-            with span("plan_upload", kind="delta", bytes=rec_bytes):
-                apply_plan = plan_apply_fn()
+            self.last_plan_bucket = len(row_rec)
+            with span(
+                "plan_upload", kind="delta", bytes=rec_bytes,
+                bucket=len(row_rec),
+            ):
                 (
                     self.d_p_arc, self.d_p_sign, self.d_p_src,
                     self.d_p_dst, self.d_inv,
                     self.d_seg, self.d_isstart,
                     self.d_first, self.d_last, self.d_nonempty,
-                ) = apply_plan(
-                    self.d_p_arc, self.d_p_sign, self.d_p_src,
-                    self.d_p_dst, self.d_inv,
-                    self.d_seg, self.d_isstart,
-                    self.d_first, self.d_last, self.d_nonempty,
+                ) = self._plan_set[len(row_rec)](
+                    *self._plan_buffers(),
                     jnp.asarray(row_rec), jnp.asarray(inv_rec),
                     jnp.asarray(seg_rec), jnp.asarray(node_rec),
                 )
-            records = (
-                len(row_rec) + len(inv_rec) + len(seg_rec) + len(node_rec)
-            )
-            return rec_bytes, records
+            return rec_bytes, 4 * len(row_rec)
         from ..parallel.sharded_solver import (
             replicated_plan_apply_fn,
             sharded_plan_apply_fn,
@@ -896,16 +971,21 @@ class DeviceResidentState:
         )
         return rec_bytes, records
 
+    def _plan_buffers(self) -> Tuple:
+        """The ten mirrored plan tensors in `plan_apply_fn`'s argument
+        order (FP_PLAN_ARRAYS order)."""
+        return (
+            self.d_p_arc, self.d_p_sign, self.d_p_src, self.d_p_dst,
+            self.d_inv, self.d_seg, self.d_isstart,
+            self.d_first, self.d_last, self.d_nonempty,
+        )
+
     def plan_fingerprints(self) -> np.ndarray:
         """uint32 checksum per mirrored plan tensor, FP_PLAN_ARRAYS
         order — the sharded mirror psums per-shard partials with
         global-index weights, so both modes compare against the SAME
         host twins (runtime/integrity.StateAuditor)."""
-        bufs = (
-            self.d_p_arc, self.d_p_sign, self.d_p_src, self.d_p_dst,
-            self.d_inv, self.d_seg, self.d_isstart,
-            self.d_first, self.d_last, self.d_nonempty,
-        )
+        bufs = self._plan_buffers()
         if self._shard is None:
             from ..runtime.integrity import device_fingerprints
 
@@ -936,12 +1016,28 @@ class DeviceResidentState:
         if plan is None or not plan.enabled:
             return None
         plan.ensure_built()
-        if self._plan_gen != plan.layout_gen:
+        self.last_plan_relocations = plan.region_relocations - self._relocations_seen
+        self._relocations_seen = plan.region_relocations
+        self.last_plan_bucket = 0
+        rebuilt = self._plan_gen != plan.layout_gen
+        if rebuilt or (
+            # a delta past the largest compiled bucket goes up whole
+            self._shard is None and plan.record_bucket() not in self._plan_set
+        ):
             # layout rebuilt: fresh buffers all around (they will be
             # donated by later scatters, so never share the plan's own
             # full-upload cache)
-            with span("plan_upload", kind="rebuild"):
+            with span("plan_upload", kind="rebuild" if rebuilt else "oversize_delta"):
                 self._upload_plan_full(plan)
+                if rebuilt and self._shard is None:
+                    # new buffers: compile, now, every shape a later
+                    # plan delta can have
+                    from .slot_plan import PLAN_STREAM_COLS, plan_apply_fn
+
+                    self._plan_set = closed_set(
+                        plan_apply_fn(), self._plan_buffers(),
+                        PLAN_STREAM_COLS, plan.entry_cap,
+                    )
             plan.clear_pending()
             self._plan_gen = plan.layout_gen
             self._plan_ver = plan.value_version
@@ -961,20 +1057,6 @@ class DeviceResidentState:
             self.d_p_arc, self.d_p_sign, self.d_p_src, self.d_p_dst,
             self.d_seg, self.d_isstart, self.d_inv,
             self.d_first, self.d_last, self.d_nonempty,
-        )
-
-    def _scatter_arcs(self, arc_rec: np.ndarray) -> None:
-        """Arc-side-only scatter (node-rebuild refreshes): reuses the
-        one delta program with an empty — idempotent — node record."""
-        import jax.numpy as jnp
-
-        node_rec = self._pack_nodes(np.zeros(0, np.int32))
-        apply_delta = delta_apply_fn()
-        (
-            self.d_excess, self.d_src, self.d_dst, self.d_cap, self.d_cost,
-        ) = apply_delta(
-            self.d_excess, self.d_src, self.d_dst, self.d_cap, self.d_cost,
-            jnp.asarray(arc_rec), jnp.asarray(node_rec),
         )
 
     def scaled_cost(self, problem: DeviceResidentProblem):

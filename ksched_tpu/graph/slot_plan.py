@@ -136,12 +136,16 @@ SEG_RECORD_COLS = 3
 #: int32 columns of one packed node-static record (relocations):
 #: (node, node_first, node_last, node_nonempty flag)
 NODE_RECORD_COLS = 4
+#: the record streams of `plan_apply_fn`, in argument order
+PLAN_STREAM_COLS = (
+    PLAN_RECORD_COLS, INV_RECORD_COLS, SEG_RECORD_COLS, NODE_RECORD_COLS,
+)
 
 
-def _pad_records(k: int) -> int:
+def _pad_records(*counts: int) -> int:
     from .device_export import pad_record_count
 
-    return pad_record_count(k)
+    return pad_record_count(*counts)
 
 
 def shard_owner(node_ids, num_nodes: int, num_shards: int) -> np.ndarray:
@@ -858,11 +862,20 @@ class SlotPlanState:
             or self._dirty_seg or self._dirty_node
         )
 
+    def record_bucket(self) -> int:
+        """The joint pow2 bucket `drain_records` would pad the pending
+        dirt to: the one number that picks `plan_apply_fn`'s shape."""
+        return _pad_records(
+            len(self._dirty_pos), len(self._dirty_inv),
+            len(self._dirty_seg), len(self._dirty_node),
+        )
+
     def drain_records(
         self,
     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
         """Pack the dirty plan rows / inv entries / relocated segment
-        and node statics into pow2-padded int32 records and clear the
+        and node statics into int32 records, all four streams padded
+        to the one `record_bucket`, and clear the
         journal. Positions are coalesced (a position written twice
         this round ships once, final value) and sorted, so the packed
         records are deterministic and duplicate-free — scatter
@@ -874,7 +887,8 @@ class SlotPlanState:
         segs = np.sort(np.fromiter(self._dirty_seg, np.int32, len(self._dirty_seg)))
         nids = np.sort(np.fromiter(self._dirty_node, np.int32, len(self._dirty_node)))
         kp, ki, ks, kn = len(pos), len(ents), len(segs), len(nids)
-        row_rec = np.zeros((_pad_records(kp), PLAN_RECORD_COLS), np.int32)
+        bucket = self.record_bucket()
+        row_rec = np.zeros((bucket, PLAN_RECORD_COLS), np.int32)
         if kp:
             row_rec[:kp, 0] = pos
             row_rec[:kp, 1] = self.p_arc[pos]
@@ -884,14 +898,14 @@ class SlotPlanState:
             row_rec[kp:] = row_rec[0]
         # else: all-zero rows rewrite the reserved dead position 0 with
         # its permanent (0, 0, 0, 0) values — idempotent by invariant
-        inv_rec = np.zeros((_pad_records(ki), INV_RECORD_COLS), np.int32)
+        inv_rec = np.zeros((bucket, INV_RECORD_COLS), np.int32)
         if ki:
             inv_rec[:ki, 0] = ents
             inv_rec[:ki, 1] = self.inv_order[ents]
             inv_rec[ki:] = inv_rec[0]
         else:
             inv_rec[:, 1] = self.inv_order[0]  # rewrite entry 0 as-is
-        seg_rec = np.zeros((_pad_records(ks), SEG_RECORD_COLS), np.int32)
+        seg_rec = np.zeros((bucket, SEG_RECORD_COLS), np.int32)
         if ks:
             seg_rec[:ks, 0] = segs
             seg_rec[:ks, 1] = self.seg_start[segs]
@@ -900,7 +914,7 @@ class SlotPlanState:
         else:
             seg_rec[:, 1] = self.seg_start[0]
             seg_rec[:, 2] = self.is_start[0]
-        node_rec = np.zeros((_pad_records(kn), NODE_RECORD_COLS), np.int32)
+        node_rec = np.zeros((bucket, NODE_RECORD_COLS), np.int32)
         if kn:
             node_rec[:kn, 0] = nids
             node_rec[:kn, 1] = self.node_first[nids]

@@ -518,10 +518,8 @@ class StateAuditor:
 
     def _repair_rescatter(self, diverged: List[str]) -> None:
         """Re-scatter exactly the diverged rows through the existing
-        delta program (O(diff), the cheapest rung)."""
-        from ..graph.device_export import delta_apply_fn
-        import jax.numpy as jnp
-
+        delta program (O(diff), the cheapest rung; a diff past the
+        mirror's largest record bucket goes up whole)."""
         r = self.resident
         host = self.expected_state()
         slots: set = set()
@@ -530,11 +528,9 @@ class StateAuditor:
             dev = np.asarray(getattr(r, "d_" + name))
             bad = np.nonzero(dev != host[name])[0]
             (nodes if name == "excess" else slots).update(int(i) for i in bad)
-        arc_rec = r._pack_arcs(np.sort(np.fromiter(slots, np.int32, len(slots))))
-        node_rec = r._pack_nodes(np.sort(np.fromiter(nodes, np.int32, len(nodes))))
-        (r.d_excess, r.d_src, r.d_dst, r.d_cap, r.d_cost) = delta_apply_fn()(
-            r.d_excess, r.d_src, r.d_dst, r.d_cap, r.d_cost,
-            jnp.asarray(arc_rec), jnp.asarray(node_rec),
+        r.ship_records(
+            np.sort(np.fromiter(slots, np.int32, len(slots))),
+            np.sort(np.fromiter(nodes, np.int32, len(nodes))),
         )
         r._scaled = None
 

@@ -66,6 +66,20 @@ class RoundRecord:
     #: round's RoundTiming, so 0 wherever the phase timings are)
     graph_tasks_visited: int = 0
     graph_tasks_skipped: int = 0
+    #: the device-resident export of the round (from its RoundTiming; 0
+    #: on a service without --device-resident): exact host-to-device
+    #: bytes (problem records or arrays, plus plan records or plan), 1
+    #: if the arrays or the plan went up whole (first build, pow2
+    #: growth, layout rebuild, or a delta past the largest compiled
+    #: record bucket), and plan regions relocated since the last round
+    upload_bytes: int = 0
+    upload_full: int = 0
+    plan_relocations: int = 0
+    #: --pipeline: how long the PREVIOUS round's Bindings waited from
+    #: their `bindings_collect` to the flush that POSTed them (this
+    #: round's dispatch window, or an idle sweep in between); stamped on
+    #: solved rounds, 0.0 on the synchronous path
+    post_defer_ms: float = 0.0
 
 
 class RoundTracer:
@@ -211,6 +225,9 @@ class RoundTracer:
             arcs_removed=stats.arcs_removed if stats else 0,
             graph_tasks_visited=t.graph_tasks_visited,
             graph_tasks_skipped=t.graph_tasks_skipped,
+            upload_bytes=t.upload_bytes,
+            upload_full=t.upload_full,
+            plan_relocations=t.plan_relocations,
         )
         for k, v in (extra or {}).items():
             if not hasattr(rec, k):

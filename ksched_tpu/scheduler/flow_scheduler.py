@@ -62,6 +62,13 @@ class RoundTiming:
     #: the same jobs left alone (GraphManager.add_or_update_job_nodes)
     graph_tasks_visited: int = 0
     graph_tasks_skipped: int = 0
+    #: what the device-resident export shipped (0 without a mirror):
+    #: exact host-to-device bytes of the round (problem plus plan),
+    #: 1 if the arrays or the plan went up whole, and the plan regions
+    #: relocated since the previous round (DeviceResidentState)
+    upload_bytes: int = 0
+    upload_full: int = 0
+    plan_relocations: int = 0
 
 
 class FlowScheduler:
@@ -317,6 +324,7 @@ class FlowScheduler:
             with span("solve_dispatch") as sp:
                 token = self.solver.solve_async()
             timing.solve_s = sp.dur_s  # dispatch only
+            self._note_upload(timing)
         except BaseException:
             round_span.__exit__(*sys.exc_info())
             raise
@@ -373,6 +381,17 @@ class FlowScheduler:
             round_span.__exit__(*sys.exc_info())
             raise
         return timing, round_span
+
+    def _note_upload(self, timing: RoundTiming) -> None:
+        """What the device-resident mirror shipped for this round."""
+        res = self.solver.resident
+        if res is not None:
+            timing.upload_bytes = res.last_upload_bytes
+            timing.upload_full = int(
+                res.last_upload_kind == "full_build"
+                or res.last_plan_kind == "rebuild"
+            )
+            timing.plan_relocations = res.last_plan_relocations
 
     def _finish_round(self, task_mappings, timing, round_span):
         """The post-solve half of a round, shared by the synchronous
@@ -438,6 +457,7 @@ class FlowScheduler:
             with span("solve") as sp:
                 task_mappings = self.solver.solve()
             timing.solve_s = sp.dur_s
+            self._note_upload(timing)
             return self._finish_round(task_mappings, timing, round_span)
         except BaseException:
             round_span.__exit__(*sys.exc_info())

@@ -88,6 +88,17 @@ class PlacementSolver:
             # graph_manager.go:636-640); sync it before each solve.
             self.state.set_excess(gm.sink_node.id, gm.sink_node.excess)
             if self.resident is not None:
+                if full:
+                    # a slot-stable rung enables the plan at its first
+                    # solve, a round too late for the mirror: enable it
+                    # now, so that the plan goes up, and its scatter
+                    # shapes compile, in the round that allocates the
+                    # rest
+                    from ..runtime.checkpoint import find_jax_solver
+
+                    jaxs = find_jax_solver(self.backend)
+                    if jaxs is not None and jaxs.slot_stable:
+                        self.state.plan.ensure_built()
                 # pack + scatter this round's delta into the persistent
                 # device buffers (delta_pack / delta_upload child spans)
                 problem = self.resident.refresh()

@@ -36,7 +36,7 @@ def test_chip_smoke_rehearsal_runs_green():
     assert result["device"]["platform"] == "cpu"
     assert all("cpu" in line for line in lines)
     phases = [ln.split("phase ")[1].split(":")[0] for ln in lines if " phase " in ln]
-    assert phases == ["served", "array", "kernels", "general", "sharded"]
+    assert phases == ["served", "array", "kernels", "general", "sharded", "resident"]
     assert all(": pass " in ln for ln in lines if " phase " in ln)
     assert "mega(interpret): flows bit-equal" in r.stdout
     assert "sharded: not_run (1 device)" in r.stdout
