@@ -27,6 +27,8 @@ from __future__ import annotations
 from collections import deque
 from typing import Deque, Dict, List, Optional, Set, Tuple
 
+import numpy as np
+
 from ..costmodels.base import CostModeler
 from ..data import (
     DeltaType,
@@ -92,6 +94,41 @@ class GraphManager:
         #: pinned tasks of the same jobs that were left alone
         self.tasks_visited = 0
         self.tasks_skipped = 0
+        #: node id -> the task node is pinned: _pin_task_to_node left it
+        #: one arc, to its PU, with lower bound 1, so every feasible
+        #: flow carries its unit there and no solve can change its
+        #: binding. Set and cleared by events (pin; eviction, removal),
+        #: for any cost model; never set under preemption.
+        self._pinned = np.zeros(1024, dtype=bool)
+        self.num_pinned = 0
+        #: node ids of the other task nodes: what a solve can place,
+        #: migrate or preempt, and all the decode has to map
+        self.unpinned_task_nodes: Set[int] = set()
+
+    def _set_pinned(self, task_node: Node, pinned: bool) -> None:
+        node_id = task_node.id
+        if node_id >= len(self._pinned):
+            grown = np.zeros(max(2 * len(self._pinned), node_id + 1), dtype=bool)
+            grown[: len(self._pinned)] = self._pinned
+            self._pinned = grown
+        if bool(self._pinned[node_id]) != pinned:
+            self._pinned[node_id] = pinned
+            self.num_pinned += 1 if pinned else -1
+
+    def pinned_mask(self, num_nodes: int) -> np.ndarray:
+        """A copy of the pinned mask over node ids [0, num_nodes): a
+        dispatched solve's decode keeps it while events move on."""
+        mask = np.zeros(num_nodes, dtype=bool)
+        n = min(num_nodes, len(self._pinned))
+        mask[:n] = self._pinned[:n]
+        return mask
+
+    @property
+    def unpinned_running_tasks(self) -> int:
+        """Running tasks a solve may preempt or migrate: those with a
+        running arc that is not a pin (all of them under preemption,
+        none without)."""
+        return len(self.task_to_running_arc) - self.num_pinned
 
     # ------------------------------------------------------------------
     # Public lifecycle API (reference interface graph_manager.go:32-86)
@@ -291,6 +328,8 @@ class GraphManager:
         task_node.type = NodeType.UNSCHEDULED_TASK
         arc = self.task_to_running_arc.pop(task_id)
         self.cm.delete_arc(arc, ChangeType.DEL_ARC_EVICTED_TASK, "TaskEvicted: delete running arc")
+        self._set_pinned(task_node, False)
+        self.unpinned_task_nodes.add(task_node.id)
         if not self.preemption:
             jid = job_id_from_string(task_node.task.job_id)
             self._update_unscheduled_agg_node(self.job_unsched_to_node[jid], 1)
@@ -427,6 +466,7 @@ class GraphManager:
         self.sink_node.excess -= 1
         assert td.uid not in self.task_to_node
         self.task_to_node[td.uid] = node
+        self.unpinned_task_nodes.add(node.id)
         return node
 
     def _add_unscheduled_agg_node(self, job_id: int) -> Node:
@@ -454,6 +494,9 @@ class GraphManager:
         node.excess = 0
         self.sink_node.excess += 1
         del self.task_to_node[node.task.uid]
+        # node ids are reused: the next holder of this one starts unpinned
+        self._set_pinned(node, False)
+        self.unpinned_task_nodes.discard(node_id)
         if self._worklist[node.job_id].pop(node.task.uid, None) is None:
             self._pinned_unlisted[node.job_id] -= 1
         self.cm.delete_node(node, ChangeType.DEL_TASK_NODE, "RemoveTaskNode")
@@ -870,6 +913,8 @@ class GraphManager:
             )
             assert task_id not in self.task_to_running_arc
             self.task_to_running_arc[task_id] = arc
+        self._set_pinned(task_node, True)
+        self.unpinned_task_nodes.discard(task_node.id)
         if self._tasks_inert:
             # one arc that nothing re-prices: off the work list until
             # the task is evicted

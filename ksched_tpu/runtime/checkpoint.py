@@ -31,8 +31,10 @@ CHECKPOINT_VERSION = 1
 #: warm-restore manifest (the ".wal" companion): version of the framed
 #: record stream save_warm_manifest writes. 2: the pickled GraphManager
 #: carries its work list (a v1 manifest's has none; restore falls back
-#: to the cold replay, which rebuilds it from the events)
-WARM_MANIFEST_VERSION = 2
+#: to the cold replay, which rebuilds it from the events). 3: it also
+#: carries the pinned mask and the unpinned task nodes, and the
+#: scheduler the departures its next `deltas` phase drops
+WARM_MANIFEST_VERSION = 3
 
 
 class CheckpointError(RuntimeError):

@@ -66,6 +66,13 @@ class RoundRecord:
     #: round's RoundTiming, so 0 wherever the phase timings are)
     graph_tasks_visited: int = 0
     graph_tasks_skipped: int = 0
+    #: the round's post-solve half (from its RoundTiming): unpinned task
+    #: nodes handed to `decode` (the batch and the unscheduled backlog;
+    #: every task under preemption), pinned tasks it left alone, and
+    #: mapping entries the `deltas` phase turned into deltas
+    decode_tasks: int = 0
+    decode_pinned_skipped: int = 0
+    deltas_walked: int = 0
     #: the device-resident export of the round (from its RoundTiming; 0
     #: on a service without --device-resident): exact host-to-device
     #: bytes (problem records or arrays, plus plan records or plan), 1
@@ -225,6 +232,9 @@ class RoundTracer:
             arcs_removed=stats.arcs_removed if stats else 0,
             graph_tasks_visited=t.graph_tasks_visited,
             graph_tasks_skipped=t.graph_tasks_skipped,
+            decode_tasks=t.decode_tasks,
+            decode_pinned_skipped=t.decode_pinned_skipped,
+            deltas_walked=t.deltas_walked,
             upload_bytes=t.upload_bytes,
             upload_full=t.upload_full,
             plan_relocations=t.plan_relocations,

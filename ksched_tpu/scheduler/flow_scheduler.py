@@ -62,6 +62,12 @@ class RoundTiming:
     #: the same jobs left alone (GraphManager.add_or_update_job_nodes)
     graph_tasks_visited: int = 0
     graph_tasks_skipped: int = 0
+    #: what the post-solve half worked on: unpinned task nodes handed
+    #: to `decode`, pinned tasks it left alone (their arcs dropped by a
+    #: mask), and mapping entries `deltas` turned into deltas
+    decode_tasks: int = 0
+    decode_pinned_skipped: int = 0
+    deltas_walked: int = 0
     #: what the device-resident export shipped (0 without a mirror):
     #: exact host-to-device bytes of the round (problem plus plan),
     #: 1 if the arrays or the plan went up whole, and the plan regions
@@ -130,6 +136,13 @@ class FlowScheduler:
         self.jobs_to_schedule: Dict[int, JobDescriptor] = {}
         self.runnable_tasks: Dict[int, Set[int]] = {}
         self.last_timing = RoundTiming()
+        #: PU resource id -> tasks that finished, failed or were killed
+        #: there since the last `deltas` phase. They stay in the PU's
+        #: current_running_tasks until that phase drops them, so the
+        #: round in between still counts their slots as taken (`stats`
+        #: and the capacity arcs read the list's length): the reference
+        #: rebuilds the list once a round, at that point.
+        self._departed: Dict[int, Set[int]] = {}
         #: pipelined-round state: (solver token, timing, round span)
         #: while a dispatched solve is in flight, else None
         self._round_in_flight = None
@@ -159,7 +172,7 @@ class FlowScheduler:
         self._check_not_in_flight("handle_task_completion")
         rid = self.task_bindings.get(td.uid)
         assert rid is not None, f"task {td.uid} must be bound to a resource"
-        if not self._unbind_task_from_resource(td, rid):
+        if not self._unbind_task_from_resource(td, rid, departed=True):
             raise RuntimeError(f"could not unbind task {td.uid} from resource {rid}")
         td.state = TaskState.COMPLETED
         self.cost_model.record_task_completion(td)
@@ -266,7 +279,7 @@ class FlowScheduler:
         self.gm.task_failed(td.uid)
         rid = self.task_bindings.get(td.uid)
         assert rid is not None, f"failed task {td.uid} should have been bound"
-        self._unbind_task_from_resource(td, rid)
+        self._unbind_task_from_resource(td, rid, departed=True)
         td.state = TaskState.FAILED
 
     def kill_running_task(self, task_id: int) -> None:
@@ -277,6 +290,9 @@ class FlowScheduler:
         assert td is not None, f"unknown task {task_id}"
         if td.state != TaskState.RUNNING or task_id not in self.task_bindings:
             raise RuntimeError(f"task {task_id} not bound or not running")
+        # it keeps its binding (reference: scheduler.go:289-306) and
+        # leaves its PU's list like a finished task
+        self._departed.setdefault(self.task_bindings[task_id], set()).add(task_id)
         td.state = TaskState.ABORTED
 
     # ------------------------------------------------------------------
@@ -401,16 +417,33 @@ class FlowScheduler:
         unscheduled-feedback hook. Closes the `round` span; its
         duration IS timing.total_s."""
         try:
+            timing.decode_tasks = self.solver.decode_tasks
+            timing.decode_pinned_skipped = self.solver.decode_pinned_skipped
             with span("deltas") as sp:
-                deltas = self.gm.scheduling_deltas_for_preempted_tasks(
-                    task_mappings, self.resource_map
-                )
+                if self.gm.unpinned_running_tasks:
+                    # Some running task is not pinned (preemption): the
+                    # mapping holds every running task, any of them may
+                    # have lost its place, and the reference's walk
+                    # finds those: it empties every PU's list and the
+                    # loop below refills it from the mapping.
+                    deltas = self.gm.scheduling_deltas_for_preempted_tasks(
+                        task_mappings, self.resource_map
+                    )
+                    self._departed.clear()
+                else:
+                    # Every running task is pinned and off the mapping:
+                    # none can be preempted, and the lists stand as the
+                    # events left them, less what departed.
+                    deltas = []
+                    self._drop_departed()
                 for task_node_id, res_node_id in task_mappings.items():
                     delta = self.gm.node_binding_to_scheduling_delta(
                         task_node_id, res_node_id, self.task_bindings
                     )
                     if delta is not None:
                         deltas.append(delta)
+                timing.deltas_walked = len(task_mappings)
+                sp.set("deltas_walked", timing.deltas_walked)
             timing.deltas_s = sp.dur_s
 
             with span("apply") as sp:
@@ -501,8 +534,13 @@ class FlowScheduler:
         self.task_bindings[task_id] = rid
         self.resource_bindings.setdefault(rid, set()).add(task_id)
 
-    def _unbind_task_from_resource(self, td: TaskDescriptor, rid: int) -> bool:
-        """Reference: flowscheduler/scheduler.go:443-464."""
+    def _unbind_task_from_resource(
+        self, td: TaskDescriptor, rid: int, departed: bool = False
+    ) -> bool:
+        """Reference: flowscheduler/scheduler.go:443-464. The PU's
+        current_running_tasks is kept here and in _bind_task_to_resource:
+        an evicted or migrated task leaves it now; one that ``departed``
+        (finished, failed) at the next `deltas` phase (_drop_departed)."""
         task_id = td.uid
         rs = self.resource_map.find(rid)
         rd = rs.descriptor
@@ -515,7 +553,25 @@ class FlowScheduler:
             return False
         del self.task_bindings[task_id]
         task_set.discard(task_id)
+        if departed:
+            self._departed.setdefault(rid, set()).add(task_id)
+        elif task_id in rd.current_running_tasks:
+            # absent where the preemption walk emptied the list and the
+            # mapping moved the task elsewhere
+            rd.current_running_tasks.remove(task_id)
         return True
+
+    def _drop_departed(self) -> None:
+        """The `deltas` phase, for the PUs that had a completion, a
+        failure or a kill since the last one: the point of the round at
+        which the reference's rebuild of the lists lets go of them."""
+        for rid, gone in self._departed.items():
+            rs = self.resource_map.find(rid)
+            if rs is None:
+                continue  # the PU left with its machine
+            rd = rs.descriptor
+            rd.current_running_tasks = [t for t in rd.current_running_tasks if t not in gone]
+        self._departed.clear()
 
     def _execute_task(self, td: TaskDescriptor, rd: ResourceDescriptor) -> None:
         """No real executor, as in the reference (scheduler.go:469-474)."""

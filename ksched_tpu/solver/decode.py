@@ -13,11 +13,19 @@ topological order of the positive-flow DAG (longest-distance-from-sink
 strata), which is correct for any acyclic flow. Positive-flow cycles
 cannot appear in a minimal-cost flow from our backends (SSP never creates
 them; the push-relabel backend cancels zero-cost cycles before decode).
+Within a stratum nodes take their turn by node id, so which of several
+equal-cost PUs a task reads, and the order of the mapping, follow from
+the problem and the flow alone, whatever else the graph holds.
+
+The decode covers the tasks whose binding the solve could change. A
+task pinned to its PU (preemption off: one arc, lower bound 1) is known
+without a walk; the caller passes the mask of pinned node ids and the
+set of the other task nodes, both kept by the graph manager's events.
 """
 
 from __future__ import annotations
 
-from typing import Dict, Iterable, List, Set
+from typing import Dict, Iterable, List, Optional, Set
 
 import numpy as np
 
@@ -32,24 +40,42 @@ def flow_to_mapping(
     leaf_node_ids: Iterable[int],
     sink_node_id: int,
     task_node_ids: Iterable[int],
+    pinned: Optional[np.ndarray] = None,
 ) -> TaskMapping:
     """Decode a solved flow into {task node id -> PU node id}.
 
     total_flow must include lower-bound offsets (FlowResult.total_flow).
     Any consistent decomposition of the flow is a valid assignment (flow
     conservation guarantees it); per-node units are matched to incoming
-    arcs in arc order.
+    arcs in arc order, and nodes take their turn by (stratum, node id),
+    which is also the order of the returned dict: a pure function of
+    the problem and the flow.
+
+    ``pinned`` is a boolean mask over node ids (GraphManager.pinned_mask)
+    of the task nodes whose one arc has lower bound 1: each carries its
+    unit to the PU it is bound to in every feasible flow, so its arc is
+    dropped before any loop and ``task_node_ids`` names the other tasks
+    only. A pinned node has no incoming arc and a PU's units are all
+    the PU's own id, so the result is the full decode's, less the
+    pinned tasks, pair for pair.
     """
     src = problem.src
     dst = problem.dst
-    live = np.nonzero(total_flow > 0)[0]
-    task_nodes: Set[int] = set(int(t) for t in task_node_ids)
-    leaf_set: Set[int] = set(int(x) for x in leaf_node_ids)
+    live = np.flatnonzero(total_flow > 0)
+    if pinned is not None:
+        live = live[~pinned[src[live]]]
+    # An arc into the sink passes units on only if flow enters its
+    # source: a PU that holds nothing but pinned tasks needs none.
+    to_sink = dst[live] == sink_node_id
+    fed = np.zeros(problem.num_nodes, dtype=bool)
+    fed[dst[live[~to_sink]]] = True
+    live = live[~to_sink | fed[src[live]]]
+    task_nodes: Set[int] = set(task_node_ids)
 
     # Per-node incoming positive-flow arcs: dst -> [(src, flow), ...].
     incoming: Dict[int, List[tuple]] = {}
-    for i in live:
-        incoming.setdefault(int(dst[i]), []).append((int(src[i]), int(total_flow[i])))
+    for s, d, f in zip(src[live].tolist(), dst[live].tolist(), total_flow[live].tolist()):
+        incoming.setdefault(d, []).append((s, f))
 
     # Stratify the positive-flow DAG by longest distance from the sink,
     # walking backwards. level[v] = 1 + max(level[w] for flow arcs v->w).
@@ -64,21 +90,22 @@ def flow_to_mapping(
         nxt: Set[int] = set()
         for w in frontier:
             lw = level[w]
-            for s, _f in incoming.get(w, []):
+            for s, _f in incoming.get(w, ()):
                 if level.get(s, -1) < lw + 1:
                     level[s] = lw + 1
                     nxt.add(s)
         frontier = nxt
 
     # pu_units[v] = PU ids of the flow units passing through v.
+    leaf_set: Set[int] = set(leaf_node_ids)
     pu_units: Dict[int, List[int]] = {}
-    for s, f in incoming.get(sink_node_id, []):
-        if s in leaf_set and f > 0:
+    for s, f in incoming.get(sink_node_id, ()):
+        if s in leaf_set:
             pu_units[s] = [s] * f
 
     mapping: TaskMapping = {}
-    order = sorted((v for v in level if v != sink_node_id), key=lambda v: level[v])
-    for v in order:
+    del level[sink_node_id]
+    for v in sorted(level, key=lambda v: (level[v], v)):
         units = pu_units.get(v)
         if units is None:
             continue  # e.g. unscheduled aggregators: no PU units flow through
@@ -90,7 +117,7 @@ def flow_to_mapping(
             mapping[v] = units[0]
             continue
         it = 0
-        for s, f in incoming.get(v, []):
+        for s, f in incoming.get(v, ()):
             take = min(f, len(units) - it)
             if take > 0:
                 pu_units.setdefault(s, []).extend(units[it : it + take])

@@ -44,6 +44,10 @@ class PlacementSolver:
         self.resident = DeviceResidentState(self.state) if device_resident else None
         self._started = False
         self.last_result = None
+        #: the last decode: unpinned task nodes it was handed, and
+        #: pinned tasks it left alone (the dispatch's snapshot)
+        self.decode_tasks = 0
+        self.decode_pinned_skipped = 0
         # ---- device-state integrity (runtime/integrity.py) -----------
         #: audit cadence in exports (0 = off); the service sets it from
         #: --audit-every. On due rounds the post-refresh mirror is
@@ -119,15 +123,21 @@ class PlacementSolver:
             )
         else:
             get_profiler().note_export(problem, full=full, changes=changes)
-        # Task nodes captured NOW: the decode must map the snapshot's
-        # tasks, not tasks added while the solve is in flight.
-        task_node_ids = [node.id for node in gm.task_to_node.values()]
+        # What the decode works on, captured NOW: it must map the
+        # snapshot's tasks, not tasks added while the solve is in
+        # flight. Pinned tasks are known without it (their mask drops
+        # their arcs), so the set holds the unpinned task nodes only.
+        decode_set = (
+            set(gm.unpinned_task_nodes),
+            gm.pinned_mask(problem.num_nodes),
+            gm.num_pinned,
+        )
         get_profiler().solve_starting()
         try:
             if hasattr(self.backend, "solve_async"):
                 pending = self.backend.solve_async(problem)
-                return (problem, task_node_ids, pending, True)
-            return (problem, task_node_ids, self.backend.solve_traced(problem), False)
+                return (problem, decode_set, pending, True)
+            return (problem, decode_set, self.backend.solve_traced(problem), False)
         except BaseException:
             get_profiler().solve_failed()  # stop an Nth-solve capture
             raise
@@ -205,7 +215,7 @@ class PlacementSolver:
 
     def complete(self, token) -> TaskMapping:
         """Phase 2: synchronize the solve and decode the task mapping."""
-        problem, task_node_ids, pending, is_async = token
+        problem, (task_node_ids, pinned, num_pinned), pending, is_async = token
         if is_async:
             try:
                 with span("backend_solve", backend=type(self.backend).__name__) as sp:
@@ -227,13 +237,18 @@ class PlacementSolver:
         self.last_result = result
         get_profiler().note_solve(self.backend, problem, result)
         gm = self.gm
-        with span("decode", tasks=len(task_node_ids)):
+        self.decode_tasks = len(task_node_ids)
+        self.decode_pinned_skipped = num_pinned
+        with span(
+            "decode", decode_tasks=len(task_node_ids), decode_pinned_skipped=num_pinned
+        ):
             return flow_to_mapping(
                 problem,
                 result.total_flow(problem),
                 gm.leaf_node_ids,
                 gm.sink_node.id,
                 task_node_ids,
+                pinned,
             )
 
     def solve(self) -> TaskMapping:
