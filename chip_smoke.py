@@ -3,7 +3,10 @@
 
 One process drives the main paths once, through the entry points a user
 would call, at the sizes BASELINE.json names, and checks what comes out
-by the repo's own means:
+by the repo's own means. It is the only on-chip driver of the array,
+kernel, general and sharded paths (the benchmark, benchmarks/run.py,
+times the served path); it states correctness facts and claims no
+speed:
 
   served   cli's SchedulerService over SyntheticClusterAPI, 1,000 fake
            machines x 4 PUs x 4 tasks/PU, 10,000 podgen pods, `--backend
@@ -12,7 +15,8 @@ by the repo's own means:
            C++ solver, the independent reference) on the same seeded
            input. Then round 1 again under `--backend auto`, recording
            which path answered.
-  array    DeviceBulkCluster at the coco50k geometry (bench.py): fill
+  array    DeviceBulkCluster at the coco50k geometry (50,000 tasks on
+           1,000 machines x 4 PUs x 16 slots, decode width 1024): fill
            round + 3 x 32 steady rounds, tools/soak.py's invariants, and
            the same seeded rounds under set_pallas_mode("off"):
            placements, supersteps and pu_running identical (compiled
@@ -66,7 +70,8 @@ import warnings
 
 import numpy as np
 
-#: full sizes (BASELINE.json / bench.py coco50k) and the rehearsal's toys
+#: full sizes (BASELINE.json; the `array` phase's are BENCHMARK.json's
+#: `coco-50kx1k` geometry) and the rehearsal's toys
 FULL = dict(
     served=dict(machines=1_000, pods=10_000, churn=100),
     array=dict(tasks=50_000, machines=1_000, decode_width=1024, chunks=3, rounds=32),

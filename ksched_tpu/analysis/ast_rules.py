@@ -35,7 +35,7 @@ Scoping (see docs/static_analysis.md):
   root that import `jax`. Pure-numpy host modules (graph codecs, cost
   models, the CPU reference solver) legitimately compute in int64.
 - `raw-print` applies to library modules except CLI entry points
-  (`cli.py`, `__main__.py`); tools and benches print by design.
+  (`cli.py`, `__main__.py`); tools print by design.
 - Everything else applies to every linted file.
 """
 
@@ -446,7 +446,7 @@ def rule_bare_except(ctx: FileContext) -> Iterable[Violation]:
 def rule_raw_print(ctx: FileContext) -> Iterable[Violation]:
     """Library code reports through `warnings`/logging/return values so
     callers and tests can capture it; `print` is for CLI entry points
-    (cli.py, tools/, bench.py)."""
+    (cli.py, tools/)."""
     if not ctx.in_library or ctx.is_cli:
         return
     for node in ast.walk(ctx.tree):

@@ -537,8 +537,8 @@ def count_collectives(closed, loop_only: bool = False) -> Dict[str, int]:
     (loop bodies count ONCE — multiply by superstep counts for traffic
     totals). With ``loop_only`` only eqns inside while/scan bodies
     count — the per-superstep ICI reduction budget of a sharded solve
-    (prologue/one-shot collectives excluded). The bench's
-    ICI-reduction assertions read both views."""
+    (prologue/one-shot collectives excluded). The sharded programs'
+    ICI-reduction contracts (analysis/engine.py) read both views."""
     counts: Dict[str, int] = {}
     for eqn, _p, in_loop in walk_eqns(closed.jaxpr):
         if loop_only and not in_loop:

@@ -613,9 +613,8 @@ def publish_round_supersteps(supersteps, backend: str) -> None:
     """Per-round superstep counts from a device-fused path (the
     DeviceBulkCluster scan, trace replay) onto the registry — the
     interior of those solves stays on device, but the per-round
-    superstep series is solver telemetry too, and `bench.py --obs-out`
-    publishes it after the clock stops instead of warning that nothing
-    was recorded."""
+    superstep series is solver telemetry too: a driver of that path
+    publishes it after its clock stops."""
     ss = np.asarray(supersteps).reshape(-1)
     if ss.size == 0:
         return

@@ -4,9 +4,9 @@ The scan-based CSR/ELL backends (solver/jax_solver.py, ell_solver.py)
 pay ~6 full-entry HBM gathers plus 3 global scans per push-relabel
 superstep — measured gather-bound at ~60 ms/solve for the 10k x 1k
 general graph on TPU v5e and CPU alike, with CSR and ELL tying because
-the layouts change nothing about the HBM round-trips (docs/ROUND5.md
-section 5 closed the arithmetic: ~7.6 ns/element per gather pass, 6-10
-ms per superstep). The identified lever, built here, is a megakernel:
+the layouts change nothing about the HBM round-trips (the arithmetic,
+docs/solver_coverage.md: ~7.6 ns/element per gather pass, 6-10 ms per
+superstep). The identified lever, built here, is a megakernel:
 the ENTIRE superstep loop — Bellman-Ford price tightening, the
 cost-scaling phase schedule, every push/relabel superstep — runs inside
 one `pl.pallas_call` with the sorted-entry tables pinned in VMEM for the

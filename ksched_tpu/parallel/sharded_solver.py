@@ -732,7 +732,7 @@ _CSR_LIVE_MVECS = 6
 #: the chip — double-buffered rounds keep two problem generations
 #: live, warm state and telemetry rings persist, and the multi-tenant
 #: service packs many cells per chip (docs/sharding.md derives the
-#: number). Overridable per AutoSolver (and by the bench configs).
+#: number). Overridable per AutoSolver.
 DEFAULT_HBM_BUDGET_BYTES = 1 << 30
 
 

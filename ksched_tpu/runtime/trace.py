@@ -263,12 +263,11 @@ class RoundTracer:
         num_scheduled: int = 0,
         solver_work: int = 0,
     ) -> RoundRecord:
-        """Capture an externally timed round from a `{phase}_s` dict
-        (bench.py's post-measurement publication path). `total_ms`
-        overrides the summed-phases total with a measured wall time.
-        This is the one place the timing-key → phase-name mapping
-        lives, so bench snapshots carry exactly the series the service
-        publishes."""
+        """Capture an externally timed round from a `{phase}_s` dict.
+        `total_ms` overrides the summed-phases total with a measured
+        wall time. This is the one place the timing-key → phase-name
+        mapping lives, so a caller that times its own rounds records
+        exactly the series the service publishes."""
         phases_ms = {k[:-2]: v * 1e3 for k, v in timing.items()}
         phases_ms["total"] = (
             total_ms if total_ms is not None else sum(phases_ms.values())

@@ -7,8 +7,8 @@ for generality with GLOBAL segmented reductions: every superstep runs
 ~4 full-length cumsums plus a `lax.associative_scan` segmented max,
 each O(log n) passes over the 2M sorted residual entries — measured
 gather/scan-bound at ~60 ms/solve for the 10k x 1k graph on TPU v5e
-and JAX-CPU alike (docs/NOTES.md, tools/csr_tpu_bench.py). VERDICT r4
-weak #6 asked for one real lever on that number.
+and JAX-CPU alike (docs/NOTES.md). VERDICT r4 weak #6 asked for one
+real lever on that number.
 
 The lever is the degree distribution: scheduling flow graphs are
 near-bipartite with a handful of aggregator hubs. The 10k x 1k graph
@@ -194,10 +194,10 @@ def build_ell_plan(
 def _g2(table, idx2):
     """2D-indexed gather. Measured equivalent to a flat gather of the
     same element count on TPU (~2.0 ms per 262k int32 elements, i.e.
-    ~7.6 ns/element — tools/tpu_primitives_bench.py with REAL carried
-    dependencies; an earlier flat+optimization_barrier+reshape variant
-    that appeared 13x faster was a dead-code artifact). Kept as a
-    helper so the gather cost model has one grep-able seam."""
+    ~7.6 ns/element, timed with REAL carried dependencies; an earlier
+    flat+optimization_barrier+reshape variant that appeared 13x faster
+    was a dead-code artifact). Kept as a helper so the gather cost
+    model has one grep-able seam."""
     return table[idx2]
 
 @functools.partial(

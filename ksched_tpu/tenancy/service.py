@@ -89,9 +89,8 @@ class TenantCell:
     # (utils/ids.py). Interleaved cells must each consume their OWN
     # continuation of their seed's stream, or ids — and therefore
     # placements — would differ between a multi-tenant run and the same
-    # tenant run in isolation (the bit-parity acceptance). Same pattern
-    # as bench.py's interleaved arms: park/swap the stream around every
-    # cell phase that can create ids.
+    # tenant run in isolation (the bit-parity acceptance). So park/swap
+    # the stream around every cell phase that can create ids.
 
     def _swap_in(self):
         outer = global_rng().getstate()
@@ -434,8 +433,8 @@ class MultiTenantService:
 
     def tenant_summary(self, phase: str = "total") -> Dict[str, dict]:
         """Per-tenant round-latency percentiles (RoundTracer.summary
-        per cell) — the per-tenant p50/p99 surface the soak and bench
-        publish."""
+        per cell) — the per-tenant p50/p99 surface `cli.main`
+        prints."""
         return {
             tid: cell.svc.tracer.summary(phase)
             for tid, cell in self.cells.items()

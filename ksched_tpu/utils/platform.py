@@ -1,11 +1,12 @@
 """Where the program runs and where it keeps compiled code.
 
-Every entry point (cli.main, bench.main and its suite children,
-chip_smoke.py, __graft_entry__, tools/soak.py) calls
-`enable_compile_cache()` before its first trace so that processes of
-one checkout share one persistent XLA cache. Measurement entry points
-call `require_accelerator()` so that a missing chip is an error, never
-a CPU reading under a device metric's name. The CPU path needs only
+Every entry point (cli.main, benchmarks/run.py, chip_smoke.py,
+__graft_entry__, tools/soak.py) calls `enable_compile_cache()` before
+its first trace so that processes of one checkout share one persistent
+XLA cache. A measurement entry point that finds no chip fails, so a
+CPU reading never goes under a device metric's name: benchmarks/run.py
+and chip_smoke.py check the platform themselves, `require_accelerator()`
+is the same check for a caller that does not. The CPU path needs only
 `JAX_PLATFORMS=cpu` set before `import jax`.
 """
 

@@ -7,9 +7,9 @@ solver/jax_solver.py `_solve_mcmf` over the same sorted-entry order, so
 parity here is exact flow equality superstep-for-superstep — stronger
 than the objective parity the ELL suite asserts (MCMF optima are
 non-unique, but these two implementations must pick the SAME one).
-Tests run the kernel under the Pallas interpreter (CPU env); the
-TPU-compiled path is the same kernel code, exercised by
-tools/mcmf_mega_bench.py on hardware.
+Tests run the kernel under the Pallas interpreter (CPU env); compiled,
+the kernel is refused by the TPU's compiler (chip_smoke.py's `general`
+phase states the refusal, ops/mcmf_pallas.mega_compiler_refusal).
 """
 
 import numpy as np

@@ -3,7 +3,8 @@ bit-exact twin of the XLA phase loop (solver/layered.py _transport_loop):
 both run the same synchronous integer push-relabel schedule, so the
 resulting flows — not just objectives — are identical. Tests run the
 kernel under the Pallas interpreter (CPU env); the TPU-compiled path is
-the same kernel code, exercised by bench.py on hardware.
+the same kernel code, exercised on hardware by chip_smoke.py's `array`
+and `kernels` phases and by the `coco-50kx1k` benchmark cells.
 """
 
 import numpy as np

@@ -141,7 +141,7 @@ def test_sharded_superstep_parity_with_single_device():
     # and the count is the real-node-count, oversubscription-aware
     # schedule (choose_eps0): a couple hundred supersteps on this toy,
     # not the ~1.5k that n_scale-from-Mp + a short eps0 start produced
-    # (the MULTICHIP_r01 anomaly; see docs/NOTES.md).
+    # (docs/NOTES.md).
     assert 0 < res_sh.supersteps < 500
 
 

@@ -47,7 +47,7 @@ from ksched_tpu.analysis.program_registry import (
 )
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-LINT_TARGETS = ["ksched_tpu", "tools", "bench.py"]
+LINT_TARGETS = ["ksched_tpu", "tools"]
 BASELINE = os.path.join(REPO_ROOT, "tools", "kschedlint_baseline.json")
 
 
@@ -76,7 +76,7 @@ def test_baseline_is_empty():
 
 def test_cli_exits_zero():
     proc = subprocess.run(
-        [sys.executable, "-m", "tools.kschedlint", "ksched_tpu", "tools", "bench.py"],
+        [sys.executable, "-m", "tools.kschedlint", "ksched_tpu", "tools"],
         cwd=REPO_ROOT, capture_output=True, text=True, timeout=120,
     )
     assert proc.returncode == 0, proc.stdout + proc.stderr
@@ -776,20 +776,20 @@ def test_cli_unknown_rule_exits_2():
 
 
 def test_cli_rules_subset_runs():
-    proc = _run_cli("--rules", "dtype64,raw-print", "tools", "bench.py")
+    proc = _run_cli("--rules", "dtype64,raw-print", "tools")
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "2 rules" in proc.stderr
 
 
 def test_cli_coverage_summary_line():
-    proc = _run_cli("--coverage", "ksched_tpu", "tools", "bench.py")
+    proc = _run_cli("--coverage", "ksched_tpu", "tools")
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert f"kschedlint L3: {len(PROGRAMS)} programs registered" in proc.stderr
     assert "0 unaudited" in proc.stderr
 
 
 def test_cli_json_mode(tmp_path):
-    proc = _run_cli("--json", "--coverage", "ksched_tpu", "tools", "bench.py")
+    proc = _run_cli("--json", "--coverage", "ksched_tpu", "tools")
     assert proc.returncode == 0, proc.stdout + proc.stderr
     payload = json.loads(proc.stdout)
     assert payload["ok"] is True

@@ -1,11 +1,11 @@
 """kschedlint: the repo's AST lint CLI (Levels 1+3 of ksched_tpu.analysis).
 
 Usage:
-    python -m tools.kschedlint ksched_tpu tools bench.py
-    python -m tools.kschedlint --coverage ksched_tpu tools bench.py
+    python -m tools.kschedlint ksched_tpu tools
+    python -m tools.kschedlint --coverage ksched_tpu tools
     python -m tools.kschedlint --rules dtype64,unregistered-program ksched_tpu
-    python -m tools.kschedlint --json ksched_tpu tools bench.py
-    python -m tools.kschedlint --prune-baseline ksched_tpu tools bench.py
+    python -m tools.kschedlint --json ksched_tpu tools
+    python -m tools.kschedlint --prune-baseline ksched_tpu tools
 
 Exit status: 0 when every violation is suppressed inline or recorded in
 the baseline AND the baseline carries no stale entries; 1 when NEW
@@ -65,9 +65,9 @@ def _coverage_summary(cov) -> str:
 
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(prog="kschedlint", description=__doc__)
-    parser.add_argument("paths", nargs="*", default=["ksched_tpu", "tools", "bench.py"],
-                        help="files/directories to lint (default: the library, "
-                        "tools, and bench.py)")
+    parser.add_argument("paths", nargs="*", default=["ksched_tpu", "tools"],
+                        help="files/directories to lint (default: the library "
+                        "and tools)")
     parser.add_argument("--baseline", default=DEFAULT_BASELINE,
                         help="baseline JSON (repo-relative)")
     parser.add_argument("--no-baseline", action="store_true",

@@ -16,10 +16,10 @@ shape — point it at whichever file a run left behind:
   telemetry tails, rendered as convergence tables);
 - **Chrome trace JSON** (`SpanTracer.dump`, `--trace-out`): per-span-
   name duration percentiles over the trace events;
-- **solver telemetry JSON** (`SolveTelemetry.to_dict()`, e.g.
-  `tools/superstep_trace.py --out`): the per-superstep convergence
-  table — eps, active/excess, pushes, relabels, saturated arcs, work
-  per executed superstep (obs/soltel.py taxonomy).
+- **solver telemetry JSON** (`SolveTelemetry.to_dict()`): the
+  per-superstep convergence table — eps, active/excess, pushes,
+  relabels, saturated arcs, work per executed superstep
+  (obs/soltel.py taxonomy).
 
 Usage: python tools/obs_report.py DUMP [--phase total]
 """
@@ -163,7 +163,7 @@ def report_convergence(tel: dict, max_rows: int = 0) -> None:
     """Per-superstep convergence table from a `solver_telemetry` dict
     (obs/soltel.SolveTelemetry.to_dict(), or a stall event's
     `telemetry_tail` re-wrapped). THE one renderer for solver-interior
-    rows — superstep_trace.py and the flight-dump view both call it."""
+    rows — the telemetry-JSON and the flight-dump views both call it."""
     cols = tel.get("cols") or ["eps", "active", "excess", "pushed",
                                "relabels", "saturated", "work"]
     rows = tel.get("rows") or []

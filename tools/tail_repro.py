@@ -1,10 +1,11 @@
 #!/usr/bin/env python
 """Capture and replay superstep-tail rounds of the steady-state configs.
 
-BENCH_SUITE_r02 recorded supersteps_max = 15687 (quincy10k) and 25324
-(whare-hetero) against p50s of 12 and 753: a small minority of rounds
-burn 20-30x the typical superstep budget, and at ~2.6 us/superstep they
-blow the 10 ms target. This tool makes those rounds reproducible:
+On the array path a small minority of steady-state rounds burn 20-30x
+the typical superstep budget (a round-2 sweep recorded supersteps_max =
+15687 for quincy10k and 25324 for whare-hetero against p50s of 12 and
+753), and at ~2.6 us/superstep they blow the 10 ms target. This tool
+makes those rounds reproducible:
 
   capture  run the steady-state loop on JAX-CPU, one round per dispatch,
            snapshotting each round's exact transport instance (cost
@@ -33,7 +34,7 @@ import numpy as np
 
 
 def build_config(name: str):
-    """The bench suite's steady-state configs, scaled for CPU capture."""
+    """The array path's steady-state configs, scaled for CPU capture."""
     from ksched_tpu.costmodels.device_costs import (
         coco_device_cost_fn,
         whare_device_cost_fn,
@@ -105,9 +106,8 @@ def build_config(name: str):
         table.sync(dev)
         dev._tail_repro_groups = (table, groups)  # capture() hooks
     elif name == "multiblock":
-        # bench.py _quincy_multiblock_bench's exact setup (split quanta,
-        # heavy-tailed block sizes, skewed template pool) so captured
-        # tails are THAT config's tails
+        # split quanta, heavy-tailed block sizes, skewed template pool:
+        # the setup whose tails this tool was written to capture
         from ksched_tpu.costmodels.quincy_device import QuincyGroupTable
 
         MBv = 1 << 20
