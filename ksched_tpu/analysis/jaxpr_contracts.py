@@ -563,9 +563,8 @@ def trace_jax_warmp(n_raw: int, m_raw: int, seed: int = 0, telemetry_cap: int = 
     (clipped), so the relaxation touches only the journal-dirty
     frontier. The refit is plain data-parallel relaxation: it must
     stay scatter-free like every solve program. A distinct traced
-    program — the default (warm_p=None, use_warm_p=False) trace stays
-    byte-identical to the pinned pre-warm_p baseline, which
-    test_static_analysis pins."""
+    program — the default (warm_p=None, use_warm_p=False) trace takes
+    no warm_p invar and stays on csr_solve's pinned hash."""
     from ..solver.jax_solver import _solve_mcmf
 
     n, m = bucketed_sizes(n_raw, m_raw)

@@ -143,7 +143,6 @@ class GatherBudget:
     hbm_loop: Optional[int] = None  # exact gathers in loop bodies, off-kernel
     kernel: Optional[int] = None  # exact gathers inside pallas_call bodies
     oneshot: Optional[int] = None  # exact per-solve (outside loops) gathers
-    hbm_loop_min: Optional[int] = None  # lower bound (classifier canary)
 
 
 @dataclass(frozen=True)
@@ -218,6 +217,20 @@ _RECORD_CROSS = ((call(3, 2), call(100, 2)),)
 _RECORD_GRAPH_SAME = ((call(3, 2, n_raw=20, m_raw=100), call(3, 2, n_raw=24, m_raw=110)),)
 _RECORD_GRAPH_CROSS = ((call(3, 2, n_raw=20, m_raw=100), call(3, 2, n_raw=20, m_raw=300)),)
 
+#: what the scan-CSR solve gathers (solver/jax_solver.py `_solve_mcmf`,
+#: both layouts; every gather a row gather, `_rows`). In loop bodies:
+#: the superstep's six (four over the plan rows: a row's head-node
+#: values, its tail node's potential, the prefix base, the partner's
+#: push; two over the nodes: their first and last rows), the phase
+#: change's four (`saturate`'s two potentials, the excess's two
+#: boundary reads) and `tighten`'s two a sweep. Once a solve: the rows'
+#: capacity/cost/warm flow, the partner rows, the prologue's saturate
+#: and its two excess evaluations (the refit seeds `tighten` from the
+#: carried prices and skips one), the flow read back. PR 28's program
+#: had 24 / 15 (11 for the refit), all of scalars.
+_CSR_GATHERS = GatherBudget(hbm_loop=12, oneshot=9)
+_CSR_REFIT_GATHERS = GatherBudget(hbm_loop=12, oneshot=7)
+
 #: every collective family jaxpr_contracts counts — "forbid all"
 _ALL_COLLECTIVES = ("psum", "pmin", "pmax", "all_gather", "all_to_all", "ppermute")
 
@@ -227,12 +240,14 @@ _SPECS = (
         name="csr_solve", module="ksched_tpu.solver.jax_solver", kind="solve",
         tracer="trace_jax", trace=call(20, 100),
         extra=(call(12, 40), call(40, 220)),
-        telemetry_off_hash="75d13078bf6fc412", telemetry_knob="telemetry_cap",
+        telemetry_off_hash="c3cd4c121a78d56a", telemetry_knob="telemetry_cap",
         hash_stability=HashStability("pow2-bucket", same=_CSR_SAME, cross=_CSR_CROSS),
-        gathers=GatherBudget(hbm_loop_min=1),
+        gathers=_CSR_GATHERS,
         collectives=CollectiveBudget(forbidden=_ALL_COLLECTIVES),
-        notes="scan-CSR push-relabel; hbm_loop_min=1 is the gather-"
-        "classifier canary (CSR pays per-superstep HBM gathers by design)",
+        notes="scan-CSR push-relabel, state carried in entry space (PR 29); the "
+        "exact loop-gather count is the budget (a gather put back into the "
+        "loop fails here, on the CPU) and, being > 0, the gather-classifier "
+        "canary as well (CSR pays per-superstep HBM gathers by design)",
     ),
     ProgramSpec(
         name="csr_solve_warmp", module="ksched_tpu.solver.jax_solver", kind="solve",
@@ -242,8 +257,9 @@ _SPECS = (
             cross=((call(20, 100), call(20, 300)),),
         ),
         distinct_from=("csr_solve",),
-        notes="dirty-frontier warm-price refit; the DEFAULT trace staying "
-        "on the pre-warm_p pin is csr_solve's telemetry_off_hash",
+        gathers=_CSR_REFIT_GATHERS,
+        notes="dirty-frontier warm-price refit; that the DEFAULT trace takes "
+        "no warm_p invar is csr_solve's telemetry_off_hash",
     ),
     ProgramSpec(
         name="csr_solve_slot", module="ksched_tpu.solver.jax_solver", kind="solve",
@@ -253,12 +269,14 @@ _SPECS = (
             cross=((call(20, 100), call(20, 300)),),
         ),
         distinct_from=("csr_solve",),
+        gathers=_CSR_GATHERS,
         notes="slot-stable layout: dead rows masked through the sign column",
     ),
     ProgramSpec(
         name="csr_refit_slot", module="ksched_tpu.solver.jax_solver", kind="solve",
         tracer="trace_jax_warmp", trace=call(20, 100, slot_stable=True),
         site="csr_solve", distinct_from=("csr_solve_warmp",),
+        gathers=_CSR_REFIT_GATHERS,
         notes="the production event-path program: refit ON TOP of the "
         "slot-stable plan",
     ),

@@ -160,7 +160,12 @@ def build_sharded_plan(src: np.ndarray, dst: np.ndarray, num_nodes: int, num_sha
     )
 
 
-from ..solver.jax_solver import _seg_sum as _seg_sum_local  # same CSR layout
+def _seg_sum_local(vals, node_first, node_last, node_nonempty):
+    """Per-node sum over a sorted-entry array: cumsum + boundary gathers."""
+    c = jnp.cumsum(vals)
+    excl_first = c[node_first] - vals[node_first]
+    seg = c[node_last] - excl_first
+    return jnp.where(node_nonempty, seg, 0)
 
 
 def _seg_scan(vals, isstart, combine_val):

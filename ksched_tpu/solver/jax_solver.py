@@ -23,7 +23,34 @@ TPU-shaped implementation notes:
   ~68 ms), so all segment reductions are expressed over a host-prebuilt
   CSR ordering of the residual entries as cumsum + gather
   (diff-at-row-boundaries) and a segmented max via
-  lax.associative_scan — each tens of microseconds at 64k entries.
+  lax.associative_scan.
+- Gathers are the price (78% of the device time before PR 29), so the
+  loop does as few as the algorithm needs and does each as a gather of
+  ROWS. Measured on a v5e (PERF.md section 6, PR 29): XLA's gather of
+  scalars out of a 1-D table costs ~7 ns per OUTPUT element whatever
+  the table and the order of the indices (0.94-1.13 ms into the
+  131,072 plan rows of the 10k x 1k cluster, where a streaming fusion
+  of that size is 12 us); a gather of rows two to eight wide costs
+  ~1.8 ns a row (0.23 ms). Therefore:
+  * the phase loop CARRIES its state in the sorted entry space, where
+    the superstep reads it — the residual of every row and the excess
+    of every node — and never the per-arc flow. A push leaves its row
+    and arrives at its partner (the row of the same arc's opposite
+    entry), so a superstep's update is r' = r - delta + delta[partner]
+    and excess' = excess - pushed + seg_sum(delta[partner]). The flow is
+    read back once, after the loop (an arc's flow is its backward
+    row's residual);
+  * what no loop changes is LIFTED and gathered once a solve: each
+    row's capacity, its signed cost, its partner;
+  * every gather goes through `_rows`: the tables that share an index
+    are stacked into one table of rows, a lone table is doubled. A
+    superstep gathers into the plan rows four times (p and excess at
+    the row's own node, p at its far end, the segment's prefix
+    base, the partner's push) and into the nodes twice (every
+    per-node sum and the relabel candidate, read at the nodes' first
+    and last rows), where the arc-space loop state took eight, one
+    over the arcs and thirteen, all of scalars: 11.7 ms a superstep
+    became 2.3.
 - The CSR ordering depends only on arc endpoints. For plain array
   problems it is cached and rebuilt on the host (numpy argsort) when
   the structure changes; problems that carry a slot-stable plan
@@ -133,36 +160,44 @@ def build_csr_plan(src: np.ndarray, dst: np.ndarray, num_nodes: int) -> CsrPlan:
     )
 
 
-def _seg_sum(vals, node_first, node_last, node_nonempty):
-    """Per-node sum over a sorted-entry array: cumsum + boundary gathers."""
-    c = jnp.cumsum(vals)
-    excl_first = c[node_first] - vals[node_first]
-    seg = c[node_last] - excl_first
-    return jnp.where(node_nonempty, seg, 0)
+def _rows(idx, *cols):
+    """``tuple(c[idx] for c in cols)`` for 1-D tables of one length,
+    done as ONE gather of rows two or more wide (a lone column is
+    doubled). The shape is the point: XLA's TPU gather of scalars out
+    of a 1-D table goes element by element, ~7 ns an output element on
+    a v5e whatever the table's size or the order of the indices, while
+    a gather of rows moves a tile row an index, ~1.8 ns a row up to at
+    least 8 columns (PERF.md section 6, PR 29: 0.955 ms against 0.23
+    ms for the 131,072 plan rows). The rows come back padded to the
+    lane width, which is the temporary memory this costs (64 MB a
+    gather into the plan rows of the 10k x 1k cluster)."""
+    got = jnp.stack(cols * 2 if len(cols) == 1 else cols, axis=1)[idx]
+    return tuple(got[:, i] for i in range(len(cols)))
 
 
-def _seg_max(vals, isstart, node_last, node_nonempty, identity):
-    """Per-node max via a segmented-max associative scan."""
-
-    def combine(a, b):
-        f1, v1 = a
-        f2, v2 = b
-        return f1 | f2, jnp.where(f2, v2, jnp.maximum(v1, v2))
-
-    _, scanned = lax.associative_scan(combine, (isstart, vals))
-    return jnp.where(node_nonempty, scanned[node_last], identity)
-
-
-def _seg_min(vals, isstart, node_last, node_nonempty, identity):
-    """Per-node min via a segmented-min associative scan."""
+def _seg_scan(pick, vals, isstart):
+    """Running ``pick`` (jnp.maximum / jnp.minimum) within each segment
+    of a sorted-entry array, via a segmented associative scan: at a
+    segment's last row stands the segment's reduction."""
 
     def combine(a, b):
         f1, v1 = a
         f2, v2 = b
-        return f1 | f2, jnp.where(f2, v2, jnp.minimum(v1, v2))
+        return f1 | f2, jnp.where(f2, v2, pick(v1, v2))
 
-    _, scanned = lax.associative_scan(combine, (isstart, vals))
-    return jnp.where(node_nonempty, scanned[node_last], identity)
+    return lax.associative_scan(combine, (isstart, vals))[1]
+
+
+def _seg_ends(node_first, node_last, node_nonempty, vals, cums, *at_last):
+    """Per-node sums of the sorted-entry arrays ``vals`` (0 for an
+    empty node) from their cumsums ``cums``, followed by the further
+    arrays ``at_last`` as they stand at each node's last row (the
+    caller masks empty nodes): every boundary read of a pass through
+    two row gathers."""
+    last = _rows(node_last, *cums, *at_last)
+    first = _rows(node_first, *(c - v for c, v in zip(cums, vals)))
+    sums = tuple(jnp.where(node_nonempty, l - f, 0) for l, f in zip(last, first))
+    return sums + last[len(sums):]
 
 
 _BIG_D = 1 << 28  # "unreachable" distance sentinel for price tightening
@@ -186,8 +221,22 @@ def _solve_mcmf(
     layout: obs/soltel.py), written at `step % cap` so the final
     supersteps always survive. The counters read state each superstep
     already computes — flows are bit-identical on/off, and with cap=0
-    this traces the exact pre-telemetry jaxpr (no cost when off;
-    pinned by the jaxpr contracts).
+    no telemetry op is traced (no cost when off; the telemetry-off
+    trace is pinned by the jaxpr contracts).
+
+    What the loop carries and what it lifts (module docstring): the
+    state is (r, excess, p, eps, steps, done): the residual of every
+    sorted entry, the excess of every node, the potentials. `s_cap`,
+    `s_cost` and `s_partner` are gathered once, before any loop, from
+    the tensors this function is handed (no plan tensor is added), and
+    serve `tighten`, `saturate` and the superstep alike. `saturate` is
+    stated on rows (a row with negative reduced cost is emptied, its
+    partner, whose reduced cost is the negative, takes the capacity), so
+    neither the prologue nor a phase change of a cold ladder converts
+    to arc space. Held bit for bit to the arc-state program it replaced
+    (flow, p, steps, converged, p_overflow, every soltel row) by
+    tests/test_csr_entry_state.py, and through that to the ELL and mega
+    solvers' parity suites.
 
     use_warm_p=True REFITS the caller-supplied ``warm_p`` potentials
     (the previous round's device-resident prices) instead of running
@@ -201,18 +250,17 @@ def _solve_mcmf(
     certify last round's flow, violations exist only around the churn,
     which is what kills the warm-start price war: the discharge starts
     eps-optimal-ish and drains in fresh-restart-like superstep counts
-    instead of unit-relabel wars. With the defaults (None, False) the
-    traced program is byte-identical to the pre-warm_p jaxpr: warm_p=
-    None contributes no invars and the tighten branch traces exactly
-    as before (the pinned off-hash contracts depend on that).
+    instead of unit-relabel wars. With the defaults (None, False)
+    warm_p contributes no invars to the traced program.
 
     slot_stable=True consumes a scatter-maintained slot-stable plan
     (graph/slot_plan.py): entry rows live in fixed per-node regions
     with slack, and liveness is encoded in the sign column (s_sign in
-    {+1, -1, 0}) — the residual of a dead row is forced to 0, which
-    makes it inert in every reduction (no separate mask tensor). The
-    default (False) keeps the tightly-packed build_csr_plan layout and
-    traces the exact pre-slot-stable program.
+    {+1, -1, 0}) — a dead row's capacity, cost and residual are forced
+    to 0, which makes it inert in every reduction (no separate mask
+    tensor), and what would arrive at it through its stale partner is
+    masked. The default (False) is the tightly-packed build_csr_plan
+    layout, where every row is live; both layouts run the same code.
 
     Discharging DISPLACED excess through carried flow is structurally
     slow here, and no price seeding fixes it (measured, r12): with the
@@ -229,36 +277,39 @@ def _solve_mcmf(
 
     m = cap.shape[0]
     i32 = jnp.int32
+    seg = (node_first, node_last, node_nonempty)
 
-    def residual(a_flow):
-        """Residual per sorted entry; in slot-stable mode a dead row
-        (sign 0) gets residual 0 and thus cannot push, relabel, carry
-        excess, or consume prefix allocation."""
-        if slot_stable:
-            return jnp.where(
-                s_sign > 0, cap[s_arc] - a_flow,
-                jnp.where(s_sign < 0, a_flow, i32(0)),
-            )
-        return jnp.where(s_sign > 0, cap[s_arc] - a_flow, a_flow)
+    # Lifted: what no loop changes, gathered once a solve, and the
+    # residual per sorted entry of the warm flow. A dead row of the
+    # slot-stable layout (sign 0) gets capacity, cost and residual 0
+    # and keeps them through every formula below; its partner points
+    # anywhere and is masked where it is read.
+    s_cap, a_cost, a_flow0 = _rows(s_arc, cap, cost, flow0)
+    s_cost = s_sign * a_cost
+    (s_partner,) = _rows(jnp.where(s_sign > 0, s_arc + m, s_arc), inv_order)
+    r0 = jnp.where(s_sign > 0, s_cap - a_flow0, a_flow0)
+    if slot_stable:
+        live = s_sign != 0
+        s_cap, r0 = jnp.where(live, s_cap, i32(0)), jnp.where(live, r0, i32(0))
 
-    def excess_of(flow):
-        flow_signed = s_sign * flow[s_arc]
-        return supply - _seg_sum(flow_signed, node_first, node_last, node_nonempty)
+    def excess_of(r):
+        """Node excess of the pseudoflow the rows hold: a forward row
+        carries flow s_cap - r out of its node, a backward row r into
+        it (a dead row 0)."""
+        signed = jnp.where(s_sign > 0, s_cap - r, -r)
+        (out,) = _seg_ends(*seg, (signed,), (jnp.cumsum(signed),))
+        return supply - out
 
-    def saturate(flow, p):
+    def saturate(r, p):
         """Refine step: saturate every residual entry with negative
-        reduced cost, making the pseudoflow 0-optimal for the phase."""
-        rc_fwd = cost + p[cap_src] - p[cap_dst]
-        return jnp.where(rc_fwd < 0, cap, jnp.where(rc_fwd > 0, i32(0), flow))
+        reduced cost (its partner, whose reduced cost is the negative,
+        takes the whole capacity), making the pseudoflow 0-optimal for
+        the phase."""
+        (p_src,), (p_dst,) = _rows(s_src, p), _rows(s_dst, p)
+        rc = s_cost + p_src - p_dst
+        return jnp.where(rc < 0, i32(0), jnp.where(rc > 0, s_cap, r))
 
-    # Per-arc endpoints for the saturate step, recovered from the sorted
-    # entries to avoid shipping src/dst twice: arc j's forward entry sits
-    # at inv_order[j].
-    fwd_pos = inv_order[:m]
-    cap_src = s_src[fwd_pos]
-    cap_dst = s_dst[fwd_pos]
-
-    def tighten(flow, d0=None):
+    def tighten(r, d0=None):
         """Price tightening: p = -(shortest residual-cost distance to a
         demand node), via synchronous Bellman-Ford sweeps over the sorted
         entries. Afterwards every residual arc between reachable nodes
@@ -272,12 +323,8 @@ def _solve_mcmf(
         the `changed` early-exit stops as soon as the frontier drains —
         a bounded Bellman sweep over the journal-touched subgraph,
         expressed data-parallel."""
-        excess0 = excess_of(flow) if d0 is None else None
-        a_flow = flow[s_arc]
-        r = residual(a_flow)
-        s_cost = s_sign * cost[s_arc]
         if d0 is None:
-            d0 = jnp.where(excess0 < 0, i32(0), i32(_BIG_D))
+            d0 = jnp.where(excess_of(r) < 0, i32(0), i32(_BIG_D))
 
         def t_cond(state):
             _d, changed, it = state
@@ -285,8 +332,10 @@ def _solve_mcmf(
 
         def t_body(state):
             d, _, it = state
-            cand = jnp.where(r > 0, s_cost + d[s_dst], i32(_BIG_D))
-            best = _seg_min(cand, s_isstart, node_last, node_nonempty, i32(_BIG_D))
+            (d_dst,) = _rows(s_dst, d)
+            cand = jnp.where(r > 0, s_cost + d_dst, i32(_BIG_D))
+            (best,) = _rows(node_last, _seg_scan(jnp.minimum, cand, s_isstart))
+            best = jnp.where(node_nonempty, best, i32(_BIG_D))
             # Clamp from below: a negative-cost residual cycle (possible
             # transiently with warm flows + changed costs) must not run d
             # toward int32 wraparound; the discharge handles the rest.
@@ -296,12 +345,10 @@ def _solve_mcmf(
         d, _, _ = lax.while_loop(t_cond, t_body, (d0, jnp.bool_(True), i32(0)))
         return -jnp.minimum(d, i32(_BIG_D))
 
-    def superstep(flow, p, eps, excess):
-        a_flow = flow[s_arc]
-        r = residual(a_flow)
-        s_cost = s_sign * cost[s_arc]
-        rc = s_cost + p[s_src] - p[s_dst]
-        e_at = excess[s_src]
+    def superstep(r, excess, p, eps):
+        (p_dst,) = _rows(s_dst, p)
+        p_src, e_at = _rows(s_src, p, excess)
+        rc = s_cost + p_src - p_dst
         admissible = (r > 0) & (rc < 0) & (e_at > 0)
 
         # Maximal push: allocate each node's excess across its admissible
@@ -309,26 +356,37 @@ def _solve_mcmf(
         r_adm = jnp.where(admissible, r, i32(0))
         cum = jnp.cumsum(r_adm)
         excl = cum - r_adm
-        prefix_before = excl - excl[s_segstart]
-        delta = jnp.clip(e_at - prefix_before, 0, r_adm)
+        (base,) = _rows(s_segstart, excl)
+        delta = jnp.clip(e_at - (excl - base), 0, r_adm)
 
-        delta_orig = delta[inv_order]
-        new_flow = flow + delta_orig[:m] - delta_orig[m:]
+        # A push leaves its own row and arrives at the partner row (the
+        # same arc's opposite entry), whose node receives it.
+        (arrived,) = _rows(s_partner, delta)
+        if slot_stable:
+            arrived = jnp.where(live, arrived, i32(0))
+        new_r = r - delta + arrived
+
+        # Per node, from one pair of boundary reads: what its segment
+        # could take (an active node pushes min(excess, that): the
+        # allocation above fills front to back), what arrived, whether a
+        # residual entry is left, and the relabel candidate.
+        cand = jnp.where(r > 0, p_dst - s_cost, -_BIG)
+        adm, arrived_sum, sum_r, best = _seg_ends(
+            *seg, (r_adm, arrived, r), (cum, jnp.cumsum(arrived), jnp.cumsum(r)),
+            _seg_scan(jnp.maximum, cand, s_isstart),
+        )
+        pushed = jnp.clip(excess, 0, adm)
+        new_excess = excess - pushed + arrived_sum
 
         # Relabel nodes that were active but pushed nothing (maximal push
         # guarantees active nodes with an admissible entry push >= 1).
-        pushed = _seg_sum(delta, node_first, node_last, node_nonempty)
-        sum_r = _seg_sum(r, node_first, node_last, node_nonempty)
-        cand = jnp.where(r > 0, p[s_dst] - s_cost, -_BIG)
-        best = _seg_max(cand, s_isstart, node_last, node_nonempty, -_BIG)
+        best = jnp.where(node_nonempty, best, -_BIG)
         relabel = (excess > 0) & (pushed == 0) & (sum_r > 0)
         new_p = jnp.where(relabel, best - eps, p)
         if not telemetry_cap:
-            return new_flow, new_p, ()
+            return new_r, new_excess, new_p, ()
         # counters over state this superstep already computed (soltel
-        # row cols 3..6); purely observational, never fed back — and
-        # appended AFTER the original dataflow so the telemetry-off
-        # trace keeps the exact pre-telemetry op order (pinned hash).
+        # row cols 3..6); purely observational, never fed back.
         # Cost discipline: `pushed` is the already-reduced [N] per-node
         # push total (sum == sum(delta) since segments partition the
         # entries), and the saturated mask reuses r/s_sign — the only
@@ -343,7 +401,7 @@ def _solve_mcmf(
             # and r_adm is already materialized for the prefix cumsum
             jnp.sum((r_adm > 0).astype(i32)),
         )
-        return new_flow, new_p, aux
+        return new_r, new_excess, new_p, aux
 
     if telemetry_cap:
         from ..obs import soltel as _soltel
@@ -360,32 +418,29 @@ def _solve_mcmf(
             tel, steps, row, telemetry_cap, _tel_rows_iota
         )
 
+    # loop state: (r, excess, p, eps, steps, done[, tel])
     def phase_cond(state):
-        done = state[4]
-        steps = state[3]
+        steps, done = state[4], state[5]
         return ~done & (steps < max_supersteps)
 
     def phase_body(state):
-        if telemetry_cap:
-            flow, p, eps, steps, done, tel = state
-        else:
-            flow, p, eps, steps, done = state
-        excess = excess_of(flow)
+        r, excess, p, eps, steps, done = state[:6]
+        tel = state[6:]
         any_active = jnp.any(excess > 0)
 
         def do_superstep(_):
-            f2, p2, aux = superstep(flow, p, eps, excess)
+            r2, e2, p2, aux = superstep(r, excess, p, eps)
+            out = (r2, e2, p2, eps, steps + 1, jnp.bool_(False))
             if not telemetry_cap:
-                return f2, p2, eps, steps + 1, jnp.bool_(False)
-            tel2 = tel_write(tel, steps, tel_row(eps, excess, aux))
-            return f2, p2, eps, steps + 1, jnp.bool_(False), tel2
+                return out
+            return out + (tel_write(tel[0], steps, tel_row(eps, excess, aux)),)
 
         def next_phase(_):
             finished = eps <= 1
             new_eps = jnp.maximum(i32(1), eps // alpha)
-            f2 = jnp.where(finished, flow, saturate(flow, p))
-            out = (f2, p, jnp.where(finished, eps, new_eps), steps, finished)
-            return out + ((tel,) if telemetry_cap else ())
+            r2 = jnp.where(finished, r, saturate(r, p))
+            out = (r2, excess_of(r2), p, jnp.where(finished, eps, new_eps), steps, finished)
+            return out + tel
 
         return lax.cond(any_active, do_superstep, next_phase, operand=None)
 
@@ -393,25 +448,21 @@ def _solve_mcmf(
         # dirty-frontier refit: Bellman sweeps seeded from the carried
         # prices (clipped into tighten's distance range so the relax
         # arithmetic cannot overflow int32)
-        p0 = tighten(
-            flow0, d0=jnp.clip(-warm_p, -i32(_BIG_D), i32(_BIG_D))
-        )
+        p0 = tighten(r0, d0=jnp.clip(-warm_p, -i32(_BIG_D), i32(_BIG_D)))
     else:
-        p0 = tighten(flow0)
-    flow1 = saturate(flow0, p0)  # mop up any residual violations
-    state = (flow1, p0, eps_init, i32(0), jnp.bool_(False))
+        p0 = tighten(r0)
+    r1 = saturate(r0, p0)  # mop up any residual violations
+    state = (r1, excess_of(r1), p0, eps_init, i32(0), jnp.bool_(False))
     if telemetry_cap:
         state = state + (jnp.zeros((telemetry_cap, SOLTEL_WIDTH), i32),)
-        flow, p, eps, steps, done, tel = lax.while_loop(
-            phase_cond, phase_body, state
-        )
-    else:
-        flow, p, eps, steps, done = lax.while_loop(phase_cond, phase_body, state)
-    converged = done & (jnp.max(jnp.abs(excess_of(flow))) == 0)
+    r, excess, p, _eps, steps, done, *tel = lax.while_loop(
+        phase_cond, phase_body, state
+    )
+    # the flow, read back once: an arc's flow is its backward row's residual
+    (flow,) = _rows(inv_order[m:], r)
+    converged = done & (jnp.max(jnp.abs(excess)) == 0)
     p_overflow = jnp.max(jnp.abs(p)) >= _P_GUARD
-    if telemetry_cap:
-        return flow, p, steps, converged, p_overflow, tel
-    return flow, p, steps, converged, p_overflow
+    return (flow, p, steps, converged, p_overflow, *tel)
 
 
 # ---------------------------------------------------------------------------

@@ -444,12 +444,15 @@ def test_registry_pins_are_the_pretelemetry_baselines():
     on jax 0.9.0) live in the registry; this literal copy guards
     against an accidental registry edit re-pinning them.
     A jax upgrade that changes jaxpr printing re-pins BOTH in the same
-    commit (verify the off-trace is otherwise unchanged first)."""
+    commit (verify the off-trace is otherwise unchanged first), and so
+    does a PR that changes a traced program on purpose (PR 29:
+    csr_solve, whose telemetry-off trace still holds no telemetry op,
+    the engine's `telemetry` check)."""
     assert {
         n: s.telemetry_off_hash
         for n, s in PROGRAMS.items() if s.telemetry_off_hash
     } == {
-        "csr_solve": "75d13078bf6fc412",
+        "csr_solve": "c3cd4c121a78d56a",  # PR 29: state carried in entry space
         "ell_solve": "3e06106007252062",
         "mega_solve": "39ad760141b7be72",
         # sharded traces over the conftest 8-virtual-device mesh; its
@@ -494,10 +497,11 @@ def test_csr_backend_shows_the_contrast():
     """The scan-CSR backend pays per-superstep HBM gathers (that is
     the megakernel's whole reason to exist) — if this ever reads 0 the
     gather classifier is broken, not the solver fixed. (The registry
-    pins this as csr_solve's hbm_loop_min=1 canary; asserted directly
+    pins csr_solve's exact count, 12 since PR 29; asserted directly
     here so a GatherBudget refactor can't drop it.)"""
     report = engine.report(PROGRAMS["csr_solve"])
     assert report.hbm_loop_gathers > 0
+    assert PROGRAMS["csr_solve"].gathers.hbm_loop == report.hbm_loop_gathers
 
 
 def test_mega_gate_refuses_exactly_where_estimate_exceeds_budget():
