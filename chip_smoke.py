@@ -43,6 +43,16 @@ speed:
            service compiled nothing after the first round of each
            stretch. Whether its Bindings are the synchronous loop's pod
            for pod is reported either way.
+  antiaffinity  the benchmark's `k8s-5000-antiaffinity` deployment from
+           its file's argv (5,000 machines x 110 slots, `--cost-model
+           k8s_antiaffinity --backend jax`): the fill of 50,000 pods of
+           16 workloads and 20 trickle-sized rounds with completions.
+           Per round: every pod bound, the objective equal to the
+           native C++ solver's on `state.problem()`. At the end: the
+           replay of the Bindings and completions
+           (benchmarks/reference_antiaffinity.check_anti_affinity) finds
+           no node that ever held two pods of one workload, and nothing
+           compiled after the first trickle round.
 
 `--only PHASE` (repeatable) runs the named phases alone.
 
@@ -78,6 +88,7 @@ FULL = dict(
     kernels=dict(classes=4, machines=1_000),
     general=dict(tasks=10_000, machines=1_000),
     resident=dict(scale=1, trickle=(26, 31, 12, 58, 40, 9, 60, 22), waves=4),
+    antiaffinity=dict(scale=1, trickle=(30, 55, 12, 80, 41, 9, 64, 22, 50, 37) * 2),
 )
 TINY = dict(
     served=dict(machines=20, pods=200, churn=10),
@@ -85,6 +96,7 @@ TINY = dict(
     kernels=dict(classes=4, machines=40),
     general=dict(tasks=400, machines=40),
     resident=dict(scale=40, trickle=(2, 5, 1, 9, 3), waves=3),
+    antiaffinity=dict(scale=40, trickle=(3, 6, 1, 9, 4)),
 )
 
 
@@ -489,33 +501,54 @@ class Smoke:
 
     # -- the resident deployment against its synchronous control ------------
 
-    def _serve_config(self, name: str, compiles: list) -> dict:
-        """One benchmark deployment, built from its file's argv as
-        cli.main builds it, driven through the leg's fixed batches."""
+    def _config_service(self, name: str, scale: int, api_cls):
+        """One benchmark deployment at 1/scale, built from its file's
+        argv as cli.main builds it: (config, args, api, service)."""
         from ksched_tpu import cli
-        from ksched_tpu.cluster import SyntheticClusterAPI
-        from ksched_tpu.cluster.api import PodEvent
-        from ksched_tpu.solver.select import make_backend
         from ksched_tpu.utils import seed_rng
 
-        sz = self.sizes["resident"]
         here = os.path.dirname(os.path.abspath(__file__))
         with open(os.path.join(here, "benchmarks", "configs", name + ".json")) as f:
             config = json.load(f)
         argv = list(config["argv"])
         i = argv.index("--num-machines") + 1
-        argv[i] = str(int(argv[i]) // sz["scale"])
-        fill = config["resident_pods"] // sz["scale"]
-        wave = config["wave_pods"] // sz["scale"]
+        argv[i] = str(int(argv[i]) // scale)
         seed_rng(self.seed)
         args = cli.build_arg_parser().parse_args(argv)
-        api = SyntheticClusterAPI(pod_chan_size=args.pod_chan_size)
+        api = api_cls(pod_chan_size=args.pod_chan_size)
         svc = cli.build_service(args, api)
         svc.init_topology(
             fake_machines=args.num_machines,
             cores_per_machine=args.cores_per_machine,
             pus_per_core=args.pus_per_core,
         )
+        return config, args, api, svc
+
+    @staticmethod
+    def _compile_events() -> list:
+        """A list that grows by one with every program JAX compiles."""
+        import jax
+
+        compiles: list = []
+
+        def on_duration(event, _duration, **_kw):
+            if event == "/jax/core/compile/backend_compile_duration":
+                compiles.append(event)
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        return compiles
+
+    def _serve_config(self, name: str, compiles: list) -> dict:
+        """One benchmark deployment, driven through the leg's fixed
+        batches."""
+        from ksched_tpu.cluster import SyntheticClusterAPI
+        from ksched_tpu.cluster.api import PodEvent
+        from ksched_tpu.solver.select import make_backend
+
+        sz = self.sizes["resident"]
+        config, _args, api, svc = self._config_service(name, sz["scale"], SyntheticClusterAPI)
+        fill = config["resident_pods"] // sz["scale"]
+        wave = config["wave_pods"] // sz["scale"]
         solver = svc.scheduler.solver
         rung = solver.backend.primary if svc.ladder is not None else solver.backend
         native = make_backend("native", warm_start=False, fallback=False)
@@ -565,15 +598,7 @@ class Smoke:
         return dict(rounds=rounds, late=late, state=solver.state)
 
     def resident(self) -> str:
-        import jax
-
-        compiles: list = []
-
-        def on_duration(event, _duration, **_kw):
-            if event == "/jax/core/compile/backend_compile_duration":
-                compiles.append(event)
-
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        compiles = self._compile_events()
         sync = self._serve_config("trivial-10kx1k", compiles)
         res = self._serve_config("trivial-10kx1k-resident", compiles)
         check(res["late"] == {"trickle": 0, "waves": 0},
@@ -601,7 +626,77 @@ class Smoke:
         )
 
 
-PHASES = ("served", "array", "kernels", "general", "sharded", "resident")
+    # -- required hostname anti-affinity, at the benchmark's size -----------
+
+    def antiaffinity(self) -> str:
+        """`k8s-5000-antiaffinity` as cli.main builds it: the fill and
+        trickle-sized rounds (as many of the oldest pods complete as
+        arrive), every round's objective against native C++ on the same
+        problem, and the replay of the Binding log against the rule."""
+        from benchmarks.client import BenchClusterAPI
+        from benchmarks.reference_antiaffinity import check_anti_affinity
+        from ksched_tpu.cluster.api import PodEvent
+        from ksched_tpu.solver.select import make_backend
+
+        compiles = self._compile_events()
+        sz = self.sizes["antiaffinity"]
+        name = "k8s-5000-antiaffinity"
+        config, args, api, svc = self._config_service(name, sz["scale"], BenchClusterAPI)
+        api.svc = svc
+        rng = np.random.default_rng([self.seed, 30])
+        solver = svc.scheduler.solver
+        rung = solver.backend.primary if svc.ladder is not None else solver.backend
+        native = make_backend("native", warm_start=False, fallback=False)
+        group_of: dict = {}
+        live: list = []
+        late = 0  # compiles after the first trickle round
+        plan = [config["resident_pods"] // sz["scale"], *sz["trickle"]]
+        for r, arrivals in enumerate(plan):
+            completions = arrivals if r else 0
+            api.complete_later(live[:completions])  # the oldest complete first
+            del live[:completions]
+            for _ in range(arrivals):
+                pod = f"pod_{len(group_of)}"
+                group_of[pod] = int(rng.integers(0, config["task_classes"]))
+                live.append(pod)
+                api.submit_pod(PodEvent(pod_id=pod, task_class=group_of[pod]))
+            pods = api.poll_pod_batch(0.2)
+            check(len(pods) == arrivals, f"{name}: {len(pods)} pods arrived, {arrivals} sent")
+            mark = len(compiles)
+            t0 = time.perf_counter()
+            bound = svc.run_round(pods)
+            wall = time.perf_counter() - t0
+            if r > 1:
+                late += len(compiles) - mark
+            check(bound == arrivals, f"{name} round {r}: bound {bound} of {arrivals}")
+            check(api.completions_refused == 0, f"{name} round {r}: a completion was refused")
+            check(svc.noop_rounds == 0, f"{name} round {r}: a NOOP round")
+            ours = int(solver.last_result.objective)
+            theirs = int(native.solve(solver.state.problem()).objective)
+            check(ours == theirs, f"{name} round {r}: objective {ours} != native {theirs}")
+            t = svc.scheduler.last_timing
+            self.say(
+                f"antiaffinity round {r}: pods={arrivals} wall_ms={wall * 1e3:.1f} "
+                f"graph_update_ms={t.graph_update_s * 1e3:.1f} solve_ms={t.solve_s * 1e3:.1f} "
+                f"supersteps={int(rung.last_supersteps)} objective={ours} ec_nodes={t.ec_nodes} "
+                f"ec_arcs={t.ec_arcs} ec_arcs_changed={t.ec_arcs_changed} "
+                f"unscheduled_by_rule={t.unscheduled_by_rule}"
+            )
+        check(svc.ladder is None or svc.ladder.degradations_total == 0, f"{name}: a step down the ladder")
+        check(late == 0, f"{name}: {late} programs compiled after the first trickle round")
+        fault = check_anti_affinity(api.log, group_of)
+        check(fault is None, f"{name}: {fault}")
+        api.close()
+        st = solver.state
+        return (
+            f"machines={args.num_machines} nodes={st.n_cap} arcs={st.m_cap} "
+            f"entries={st.plan.entry_cap} rounds={len(plan)} objectives==native in every round; "
+            f"{len(api.log)} Bindings and completions replayed: no node held two pods of a workload; "
+            f"compiles after the first trickle round: {late}"
+        )
+
+
+PHASES = ("served", "array", "kernels", "general", "sharded", "resident", "antiaffinity")
 
 
 def main(argv=None) -> int:

@@ -294,6 +294,11 @@ class SchedulerService:
     # -- pod → task -------------------------------------------------------
 
     def _add_pod(self, pod: PodEvent) -> None:
+        try:
+            # what the class is on a descriptor is the cost model's to say
+            of_class = self.scheduler.cost_model.task_class_fields(pod.task_class)
+        except ValueError as e:
+            raise ValueError(f"pod {pod.pod_id}: {e}") from None
         existing = self.pod_to_task.get(pod.pod_id)
         if existing is not None:
             # Re-delivered pod: keep the existing task — a duplicate
@@ -306,13 +311,14 @@ class SchedulerService:
             # the next round reschedules under the new request.
             td = self.task_map.find(existing)
             if td is not None and (
-                td.resource_request.cpu_cores,
-                td.resource_request.net_bw,
-                int(td.task_type),
-            ) != (pod.cpu_request, pod.net_bw_request, pod.task_class):
+                (td.resource_request.cpu_cores, td.resource_request.net_bw)
+                != (pod.cpu_request, pod.net_bw_request)
+                or any(getattr(td, k) != v for k, v in of_class.items())
+            ):
                 td.resource_request.cpu_cores = pod.cpu_request
                 td.resource_request.net_bw = pod.net_bw_request
-                td.task_type = type(td.task_type)(pod.task_class)
+                for k, v in of_class.items():
+                    setattr(td, k, v)
                 rid = self.scheduler.task_bindings.get(existing)
                 if rid is not None:
                     rs = self.resource_map.find(rid)
@@ -322,7 +328,8 @@ class SchedulerService:
         td = add_task_to_job(self.job_id, self.job_map, self.task_map, name=pod.pod_id)
         td.resource_request.cpu_cores = pod.cpu_request
         td.resource_request.net_bw = pod.net_bw_request
-        td.task_type = type(td.task_type)(pod.task_class)
+        for k, v in of_class.items():
+            setattr(td, k, v)
         # Leave state CREATED: the scheduler's runnable-task computation
         # promotes CREATED→RUNNABLE and registers the task (reference:
         # flowscheduler/scheduler.go:487-529).

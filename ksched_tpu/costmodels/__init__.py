@@ -1,6 +1,7 @@
 from .base import CLUSTER_AGGREGATOR_EC, Cost, CostModeler, CostModelType
 from .census import CLASS_ECS, NUM_TASK_CLASSES, ClassCensusKeeper, class_ec, ec_class
 from .coco import CocoCostModel, coco_cost_matrix
+from .k8s_antiaffinity import K8sAntiAffinityCostModel
 from .net import NetCostModel
 from .quincy import BlockRegistry, QuincyCostModel
 from .simple import OctopusCostModel, RandomCostModel, SjfCostModel, VoidCostModel
@@ -8,7 +9,8 @@ from .trivial import TrivialCostModel
 from .whare import WhareMapCostModel, whare_cost_matrix
 
 #: CostModelType -> implementation, the dispatch the reference plans in
-#: costmodel/interface.go:33-43 — here every enumerated model exists.
+#: costmodel/interface.go:33-43 — here every enumerated model exists,
+#: and one the reference does not enumerate (K8S_ANTIAFFINITY).
 MODEL_REGISTRY = {
     CostModelType.TRIVIAL: TrivialCostModel,
     CostModelType.RANDOM: RandomCostModel,
@@ -19,6 +21,7 @@ MODEL_REGISTRY = {
     CostModelType.OCTOPUS: OctopusCostModel,
     CostModelType.VOID: VoidCostModel,
     CostModelType.NET: NetCostModel,
+    CostModelType.K8S_ANTIAFFINITY: K8sAntiAffinityCostModel,
 }
 
 __all__ = [
@@ -34,6 +37,7 @@ __all__ = [
     "MODEL_REGISTRY",
     "BlockRegistry",
     "CocoCostModel",
+    "K8sAntiAffinityCostModel",
     "coco_cost_matrix",
     "NetCostModel",
     "OctopusCostModel",

@@ -13,9 +13,9 @@ from __future__ import annotations
 
 import abc
 import enum
-from typing import List, Optional, Sequence, Tuple, TYPE_CHECKING
+from typing import Dict, List, Optional, Sequence, Tuple, TYPE_CHECKING
 
-from ..data import ResourceDescriptor, ResourceTopologyNodeDescriptor
+from ..data import ResourceDescriptor, ResourceTopologyNodeDescriptor, TaskType
 from ..utils import equiv_class_from_bytes
 
 if TYPE_CHECKING:  # pragma: no cover
@@ -34,6 +34,9 @@ class CostModelType(enum.IntEnum):
     OCTOPUS = 6
     VOID = 7
     NET = 8
+    #: not of the reference's enumeration: required hostname
+    #: anti-affinity (costmodels/k8s_antiaffinity.py)
+    K8S_ANTIAFFINITY = 9
 
 
 # The wildcard equivalence class every task points at in aggregate-style
@@ -154,6 +157,47 @@ class CostModeler(abc.ABC):
     def record_task_completion(self, td) -> None:
         """Called by the scheduler when a task completes; models that
         learn from observed runtimes (SJF, Whare-Map) override this."""
+
+    def task_bound(self, td, pu_resource_id: int) -> None:
+        """Called by the scheduler where a task joins a PU's
+        ``current_running_tasks`` (placement, migration)."""
+
+    def task_unbound(self, task_id: int, pu_resource_id: int) -> None:
+        """Called by the scheduler where a task leaves a PU's
+        ``current_running_tasks``: at once when it is evicted or
+        migrated away, in the next round's `deltas` phase when it
+        completed, failed or was killed."""
+
+    def task_class_fields(self, task_class: int) -> Dict[str, object]:
+        """What ``PodEvent.task_class`` is on a ``TaskDescriptor`` for
+        this model, as the fields to set; ``ValueError`` where the model
+        has no such class. The default: one of the four CoCo classes,
+        ``task_type``."""
+        if not 0 <= task_class < len(TaskType):
+            raise ValueError(
+                f"task_class {task_class} is not one of the {len(TaskType)} CoCo "
+                f"classes (0..{len(TaskType) - 1}) that {type(self).__name__} reads; "
+                "a workload index needs --cost-model k8s_antiaffinity"
+            )
+        return {"task_type": TaskType(task_class)}
+
+    def equiv_class_pref_arc_changes(self, ec: int) -> Optional[List[int]]:
+        """The resources whose arc from ``ec`` may have changed (come,
+        gone, another cost or capacity) since the arcs of ``ec`` were
+        last listed, by this method or by
+        ``get_outgoing_equiv_class_pref_arcs``; the graph manager then
+        asks ``equiv_class_to_resource_node`` about those alone, where
+        capacity 0 means no arc. None (the default): the model keeps no
+        such record and every preferred resource is visited.
+
+        Precondition of answering: the graph manager does not queue the
+        listed resources, so ``_update_res_outgoing_arcs`` does not run
+        for them on this EC's account. A model may answer only if the
+        costs and capacities of its resource -> resource and PU -> sink
+        arcs do not depend on the round (the trivial model's are
+        constants); one whose resource arcs follow a census must return
+        None."""
+        return None
 
     # -- debug ------------------------------------------------------------
 

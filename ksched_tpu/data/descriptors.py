@@ -194,6 +194,9 @@ class TaskDescriptor:
     resource_request: ResourceVector = field(default_factory=ResourceVector)
     priority: int = 0
     task_type: TaskType = TaskType.SHEEP
+    #: index of the workload (Deployment) the task is a replica of, for
+    #: models that place by workload (costmodels/k8s_antiaffinity.py)
+    workload: int = 0
     final_report: Optional[TaskFinalReport] = None
     trace_job_id: int = 0
     trace_task_id: int = 0

@@ -40,6 +40,7 @@ from ..data import (
     TaskDescriptor,
     TaskState,
 )
+from ..obs.spans import span
 from ..utils import ResourceMap, job_id_from_string, resource_id_from_string
 from .changes import ChangeManager, ChangeStats, ChangeType
 from .flowgraph import Arc, ArcType, Node, NodeType, resource_node_type
@@ -78,7 +79,8 @@ class GraphManager:
         self.leaf_resource_ids = leaf_resource_ids  # shared with the cost model
         self.leaf_node_ids: Set[int] = set()
         self._cur_traversal_counter = 0
-        self._ec_purge_candidates: Set[int] = set()  # unconnected last purge
+        self._ec_purge_candidates: Set[int] = set()  # idle at the last purge
+        self._ec_pointed_at: Set[int] = set()  # by a task's update since the last purge
         #: the cost model can neither re-price a pinned task's one arc
         #: nor learn anything from a task node in the statistics walk
         self._tasks_inert = cost_model.pinned_tasks_are_inert
@@ -94,6 +96,9 @@ class GraphManager:
         #: pinned tasks of the same jobs that were left alone
         self.tasks_visited = 0
         self.tasks_skipped = 0
+        #: the last add_or_update_job_nodes: arcs from an EC node to a
+        #: resource that it added, removed or re-priced
+        self.ec_arcs_changed = 0
         #: node id -> the task node is pinned: _pin_task_to_node left it
         #: one arc, to its PU, with lower bound 1, so every feasible
         #: flow carries its unit there and no solve can change its
@@ -182,6 +187,7 @@ class GraphManager:
         due: Deque[tuple] = deque()  # node_queue[i] comes before events at or beyond due[i]
         marked: Set[int] = set()
         visited = 0
+        self.ec_arcs_changed = 0
         i, n = 0, len(events)
         while i < n or node_queue:
             if node_queue and (i == n or due[0] <= events[i][0]):
@@ -287,30 +293,33 @@ class GraphManager:
         declares this, graph_manager.go:347-357, but never calls it;
         the scheduler here runs it per round).
 
-        Debounced: an EC must be unconnected on two consecutive calls
-        before removal, so ECs that are merely transiently unconnected
-        (e.g. every task pinned this round, new arrivals next round)
-        don't churn their wide EC->machine fan-outs through the change
-        journal each cycle. ECs orphaned by a removal within this call
-        (their only in-arcs came from a purged EC) are dead for certain
-        and cascade immediately — the reference's note about multi-call
-        subgraph cleanup (graph_manager.go:348-351) without leaving
-        chains behind if the cluster quiesces."""
+        Debounced: an EC must be idle at two consecutive calls before
+        removal: unconnected, and no task's update pointed at it since
+        the call before (with preemption off a placed task is pinned
+        and its EC arc gone by the time of the purge, so an EC in use
+        every round is unconnected at every purge). ECs that are merely
+        transiently idle (e.g. every task pinned this round, new
+        arrivals next round) then don't churn their wide EC->machine
+        fan-outs through the change journal each cycle. ECs orphaned by
+        a removal within this call (their only in-arcs came from a
+        purged EC) are dead for certain and cascade immediately — the
+        reference's note about multi-call subgraph cleanup
+        (graph_manager.go:348-351) without leaving chains behind if the
+        cluster quiesces."""
 
         def unconnected() -> set:
-            return {
-                ec for ec, node in self.task_ec_to_node.items() if not node.incoming
-            }
+            return {ec for ec, node in self.task_ec_to_node.items() if not node.incoming}
 
         seen = unconnected()
-        doomed = seen & self._ec_purge_candidates
+        doomed = (seen & self._ec_purge_candidates) - self._ec_pointed_at
         while doomed:
             for ec in doomed:
                 self._remove_equiv_class_node(self.task_ec_to_node[ec])
             now = unconnected()
             doomed = now - seen  # newly orphaned by this wave: cascade
             seen |= now
-        self._ec_purge_candidates = unconnected()
+        self._ec_purge_candidates = unconnected() - self._ec_pointed_at
+        self._ec_pointed_at.clear()
 
     def task_completed(self, task_id: int) -> int:
         """Reference: graph_manager.go:389-405."""
@@ -662,30 +671,66 @@ class GraphManager:
     def _update_equiv_to_res_arcs(self, ec_node: Node, node_queue: Deque, marked: Set[int]) -> None:
         """Reference: graph_manager.go:974-1010, vectorized through the
         batch cost-model hook so wide fan-outs (EC → every machine) cost
-        one call."""
-        pref_rids = self.cost_model.get_outgoing_equiv_class_pref_arcs(ec_node.equiv_class)
-        if not pref_rids:
-            self._remove_invalid_pref_res_arcs(ec_node, pref_rids, ChangeType.DEL_ARC_EQUIV_CLASS_TO_RES)
-            return
-        costs, caps = self.cost_model.ec_to_resource_batch(ec_node.equiv_class, pref_rids)
-        for pref_rid, cost, cap_upper in zip(pref_rids, costs, caps):
-            pref_node = self.resource_to_node.get(pref_rid)
-            assert pref_node is not None, "cost model preferred an unknown resource"
-            arc = self.cm.graph.get_arc(ec_node, pref_node)
-            if arc is None:
-                self.cm.add_arc(
-                    ec_node, pref_node, 0, cap_upper, cost, ArcType.OTHER,
-                    ChangeType.ADD_ARC_EQUIV_CLASS_TO_RES, "UpdateEquivToResArcs",
-                )
+        one call. Where the EC has arcs and the model kept a record of
+        what changed since it listed them, only those resources are
+        looked at."""
+        with span("ec_refresh"):
+            ec = ec_node.equiv_class
+            changed = self.cost_model.equiv_class_pref_arc_changes(ec) if ec_node.outgoing else None
+            if changed is None:
+                self._sweep_equiv_to_res_arcs(ec_node, node_queue, marked)
             else:
-                self.cm.change_arc(
-                    arc, arc.cap_lower, cap_upper, cost,
-                    ChangeType.CHG_ARC_EQUIV_CLASS_TO_RES, "UpdateEquivToResArcs",
-                )
-            if pref_node.id not in marked:
-                marked.add(pref_node.id)
-                node_queue.append((pref_node, pref_node.task))
-        self._remove_invalid_pref_res_arcs(ec_node, pref_rids, ChangeType.DEL_ARC_EQUIV_CLASS_TO_RES)
+                self._patch_equiv_to_res_arcs(ec_node, changed)
+
+    def _set_equiv_to_res_arc(self, ec_node: Node, res_node: Node, cost: int, cap_upper: int) -> None:
+        arc = self.cm.graph.get_arc(ec_node, res_node)
+        if arc is None:
+            self.cm.add_arc(
+                ec_node, res_node, 0, cap_upper, cost, ArcType.OTHER,
+                ChangeType.ADD_ARC_EQUIV_CLASS_TO_RES, "UpdateEquivToResArcs",
+            )
+            self.ec_arcs_changed += 1
+        else:
+            if (arc.cap_upper, arc.cost) != (cap_upper, cost):
+                self.ec_arcs_changed += 1
+            self.cm.change_arc(
+                arc, arc.cap_lower, cap_upper, cost,
+                ChangeType.CHG_ARC_EQUIV_CLASS_TO_RES, "UpdateEquivToResArcs",
+            )
+
+    def _sweep_equiv_to_res_arcs(self, ec_node: Node, node_queue: Deque, marked: Set[int]) -> None:
+        pref_rids = self.cost_model.get_outgoing_equiv_class_pref_arcs(ec_node.equiv_class)
+        if pref_rids:
+            costs, caps = self.cost_model.ec_to_resource_batch(ec_node.equiv_class, pref_rids)
+            for pref_rid, cost, cap_upper in zip(pref_rids, costs, caps):
+                pref_node = self.resource_to_node.get(pref_rid)
+                assert pref_node is not None, "cost model preferred an unknown resource"
+                self._set_equiv_to_res_arc(ec_node, pref_node, cost, cap_upper)
+                if pref_node.id not in marked:
+                    marked.add(pref_node.id)
+                    node_queue.append((pref_node, pref_node.task))
+        self.ec_arcs_changed += self._remove_invalid_pref_res_arcs(
+            ec_node, pref_rids, ChangeType.DEL_ARC_EQUIV_CLASS_TO_RES
+        )
+
+    def _patch_equiv_to_res_arcs(self, ec_node: Node, changed: List[int]) -> None:
+        """The arcs from ``ec_node`` to the resources of ``changed``,
+        each as the model now has it (capacity 0: none). The resources
+        are not queued for a visit: nothing about them is known to have
+        changed but this arc."""
+        ec = ec_node.equiv_class
+        for rid in changed:
+            res_node = self.resource_to_node.get(rid)
+            if res_node is None:
+                continue  # the resource left, and the arc with its node
+            cost, cap_upper = self.cost_model.equiv_class_to_resource_node(ec, rid)
+            if cap_upper > 0:
+                self._set_equiv_to_res_arc(ec_node, res_node, cost, cap_upper)
+                continue
+            arc = self.cm.graph.get_arc(ec_node, res_node)
+            if arc is not None:
+                self.cm.delete_arc(arc, ChangeType.DEL_ARC_EQUIV_CLASS_TO_RES, "UpdateEquivToResArcs")
+                self.ec_arcs_changed += 1
 
     def _update_res_outgoing_arcs(self, res_node: Node, node_queue: Deque, marked: Set[int]) -> None:
         """Reference: graph_manager.go:1094-1111."""
@@ -758,6 +803,7 @@ class GraphManager:
             pref_node = self.task_ec_to_node.get(pref_ec)
             if pref_node is None:
                 pref_node = self._add_equiv_class_node(pref_ec)
+            self._ec_pointed_at.add(pref_ec)
             cost = self.cost_model.task_to_equiv_class_aggregator(task_node.task.uid, pref_ec)
             arc = self.cm.graph.get_arc(task_node, pref_node)
             if arc is None:
@@ -844,11 +890,12 @@ class GraphManager:
         for arc in to_delete:
             self.cm.delete_arc(arc, change_type, "RemoveInvalidECPrefArcs")
 
-    def _remove_invalid_pref_res_arcs(self, node: Node, pref_rids: List[int], change_type: ChangeType) -> None:
+    def _remove_invalid_pref_res_arcs(self, node: Node, pref_rids: List[int], change_type: ChangeType) -> int:
         """Reference: graph_manager.go:766-790 — prunes arcs to resources
         no longer preferred, skipping running arcs is NOT done there; the
         running arc always points at the bound resource which the cost
-        model keeps in its preference lists when relevant."""
+        model keeps in its preference lists when relevant. Returns how
+        many it pruned."""
         pref = set(pref_rids)
         to_delete = [
             arc
@@ -857,6 +904,7 @@ class GraphManager:
         ]
         for arc in to_delete:
             self.cm.delete_arc(arc, change_type, "RemoveInvalidPrefResArcs")
+        return len(to_delete)
 
     # -- scheduled-task arc handling ---------------------------------------
 

@@ -82,6 +82,15 @@ class RoundRecord:
     upload_bytes: int = 0
     upload_full: int = 0
     plan_relocations: int = 0
+    #: the round's equivalence classes (from its RoundTiming): EC nodes
+    #: and EC -> resource arcs live after `graph_update`, those arcs
+    #: added, removed or re-priced by it, and runnable tasks the round
+    #: left unplaced while the cluster had a free slot for each (what a
+    #: placement rule kept out, not a full cluster)
+    ec_nodes: int = 0
+    ec_arcs: int = 0
+    ec_arcs_changed: int = 0
+    unscheduled_by_rule: int = 0
     #: --pipeline: how long the PREVIOUS round's Bindings waited from
     #: their `bindings_collect` to the flush that POSTed them (this
     #: round's dispatch window, or an idle sweep in between); stamped on
@@ -238,6 +247,10 @@ class RoundTracer:
             upload_bytes=t.upload_bytes,
             upload_full=t.upload_full,
             plan_relocations=t.plan_relocations,
+            ec_nodes=t.ec_nodes,
+            ec_arcs=t.ec_arcs,
+            ec_arcs_changed=t.ec_arcs_changed,
+            unscheduled_by_rule=t.unscheduled_by_rule,
         )
         for k, v in (extra or {}).items():
             if not hasattr(rec, k):

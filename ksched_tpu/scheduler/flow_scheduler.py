@@ -75,6 +75,16 @@ class RoundTiming:
     upload_bytes: int = 0
     upload_full: int = 0
     plan_relocations: int = 0
+    #: equivalence classes in the graph after `graph_update`: EC nodes
+    #: live, their arcs to resources live, and those arcs added, removed
+    #: or re-priced this round (GraphManager.ec_arcs_changed)
+    ec_nodes: int = 0
+    ec_arcs: int = 0
+    ec_arcs_changed: int = 0
+    #: runnable tasks the round left unplaced while the cluster had a
+    #: free slot for each: what a placement rule (or a cost above the
+    #: unscheduled cost) kept out, not a full cluster
+    unscheduled_by_rule: int = 0
 
 
 class FlowScheduler:
@@ -143,6 +153,9 @@ class FlowScheduler:
         #: and the capacity arcs read the list's length): the reference
         #: rebuilds the list once a round, at that point.
         self._departed: Dict[int, Set[int]] = {}
+        #: free slots under the root as the round's `stats` phase counted
+        #: them: what the solve could hand out
+        self._free_slots_at_solve = 0
         #: pipelined-round state: (solver token, timing, round span)
         #: while a dispatched solve is in flight, else None
         self._round_in_flight = None
@@ -386,17 +399,29 @@ class FlowScheduler:
             with span("stats") as sp:
                 self.gm.compute_topology_statistics(self.gm.sink_node)
             timing.stats_s = sp.dur_s
+            self._free_slots_at_solve = self._free_slots()
             with span("graph_update") as sp:
                 self.gm.add_or_update_job_nodes(jds)
                 timing.graph_tasks_visited = self.gm.tasks_visited
                 timing.graph_tasks_skipped = self.gm.tasks_skipped
                 sp.set("graph_tasks_visited", timing.graph_tasks_visited)
                 sp.set("graph_tasks_skipped", timing.graph_tasks_skipped)
+                ec_nodes = self.gm.task_ec_to_node.values()
+                timing.ec_nodes = len(ec_nodes)
+                timing.ec_arcs = sum(len(node.outgoing) for node in ec_nodes)
+                timing.ec_arcs_changed = self.gm.ec_arcs_changed
+                sp.set("ec_nodes", timing.ec_nodes)
+                sp.set("ec_arcs", timing.ec_arcs)
+                sp.set("ec_arcs_changed", timing.ec_arcs_changed)
             timing.graph_update_s = sp.dur_s
         except BaseException:
             round_span.__exit__(*sys.exc_info())
             raise
         return timing, round_span
+
+    def _free_slots(self) -> int:
+        rd = self.resource_topology.resource_desc
+        return rd.num_slots_below - rd.num_running_tasks_below
 
     def _note_upload(self, timing: RoundTiming) -> None:
         """What the device-resident mirror shipped for this round."""
@@ -429,7 +454,7 @@ class FlowScheduler:
                     deltas = self.gm.scheduling_deltas_for_preempted_tasks(
                         task_mappings, self.resource_map
                     )
-                    self._departed.clear()
+                    self._drop_departed(lists_rebuilt=True)
                 else:
                     # Every running task is pinned and off the mapping:
                     # none can be preempted, and the lists stand as the
@@ -461,6 +486,9 @@ class FlowScheduler:
                 if t not in self.task_bindings
             ]
             self.cost_model.note_round(unscheduled)
+            timing.unscheduled_by_rule = max(
+                0, min(len(unscheduled), self._free_slots_at_solve - num_scheduled)
+            )
         except BaseException:
             round_span.__exit__(*sys.exc_info())
             raise
@@ -533,6 +561,7 @@ class FlowScheduler:
         assert task_id not in self.task_bindings, f"task {task_id} already bound"
         self.task_bindings[task_id] = rid
         self.resource_bindings.setdefault(rid, set()).add(task_id)
+        self.cost_model.task_bound(td, rid)
 
     def _unbind_task_from_resource(
         self, td: TaskDescriptor, rid: int, departed: bool = False
@@ -555,19 +584,25 @@ class FlowScheduler:
         task_set.discard(task_id)
         if departed:
             self._departed.setdefault(rid, set()).add(task_id)
-        elif task_id in rd.current_running_tasks:
+            return True
+        if task_id in rd.current_running_tasks:
             # absent where the preemption walk emptied the list and the
             # mapping moved the task elsewhere
             rd.current_running_tasks.remove(task_id)
+        self.cost_model.task_unbound(task_id, rid)
         return True
 
-    def _drop_departed(self) -> None:
+    def _drop_departed(self, lists_rebuilt: bool = False) -> None:
         """The `deltas` phase, for the PUs that had a completion, a
         failure or a kill since the last one: the point of the round at
-        which the reference's rebuild of the lists lets go of them."""
+        which the reference's rebuild of the lists lets go of them
+        (``lists_rebuilt``: the preemption walk just did that, and only
+        the cost model is still to be told)."""
         for rid, gone in self._departed.items():
+            for task_id in gone:
+                self.cost_model.task_unbound(task_id, rid)
             rs = self.resource_map.find(rid)
-            if rs is None:
+            if rs is None or lists_rebuilt:
                 continue  # the PU left with its machine
             rd = rs.descriptor
             rd.current_running_tasks = [t for t in rd.current_running_tasks if t not in gone]

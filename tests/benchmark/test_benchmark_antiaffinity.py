@@ -1,0 +1,166 @@
+"""The `k8s-5000-antiaffinity` deployment and its cell: the configuration is
+scheduler_perf's `SchedulingPodAntiAffinity` `5000Nodes` at its source's
+shapes, the cell rehearses `correct` at 1/40 scale (125 machines, 1,250
+pods, 16 workloads) traced and untraced with no program compiled in the
+window, every per-layer metric without a cell list reads a number there,
+and a stream served the way the harness serves it, at the rehearsal's
+size, passes `check_anti_affinity`: the replay a `benchmark` PR wires into
+`correct` (PERF.md section 7)."""
+
+import json
+import os
+import subprocess
+import sys
+import threading
+
+import pytest
+
+from benchmarks import spec
+from benchmarks.reference_antiaffinity import check_anti_affinity
+
+ROOT = spec.ROOT
+BENCH = spec.load_benchmark()
+CONFIG = "k8s-5000-antiaffinity"
+CELL = CONFIG + ".trickle"
+SEED = 2147483659  # more than 32 signed bits hold, as the driver's are
+
+
+def _config(name=CONFIG):
+    with open(os.path.join(ROOT, "benchmarks", "configs", name + ".json")) as f:
+        return json.load(f)
+
+
+def _rehearse(trace):
+    env = {
+        k: v for k, v in os.environ.items()
+        if k not in ("XLA_FLAGS", "JAX_COMPILATION_CACHE_DIR") and not k.startswith("KSCHED_")
+    }
+    env["JAX_PLATFORMS"] = "cpu"
+    r = subprocess.run(
+        [sys.executable, *BENCH["command"][1:], "--workload", CELL, "--seed", str(SEED),
+         "--seconds", "3", "--trace", str(trace), "--rehearse-cpu"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-3000:]
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+@pytest.fixture(scope="module")
+def traced():
+    return _rehearse(1)
+
+
+def test_the_configuration_is_the_sources_shapes():
+    c = _config()
+    assert c["argv"] == (
+        "--fake-machines --num-machines 5000 --cores-per-machine 1 --pus-per-core 1 "
+        "--max-tasks-per-pu 110 --cost-model k8s_antiaffinity --backend jax "
+        "--pod-batch-timeout 0.002 --pod-chan-size 53000"
+    ).split()
+    assert (c["resident_pods"], c["task_classes"], c["wave_pods"]) == (50000, 16, 2500)
+    assert c["reduced"] == [] and len(c["assumed"]) >= 5
+    assert list(c["guarantees"]) == ["binding", "capacity", "answer", "anti_affinity"]
+    others = _config("coco-50kx1k")["guarantees"]
+    assert {k: c["guarantees"][k] for k in others} == others
+    assert "n(g, m) <= 1" in c["guarantees"]["anti_affinity"]
+    entry = next(e for e in BENCH["configs"] if e["name"] == CONFIG)
+    assert entry["file"] == f"benchmarks/configs/{CONFIG}.json"
+    assert entry["source"] == c["source"] and entry["reduced"] == [] and entry["why"] == c["why"]
+    assert len(entry["source"]) <= 200 and len(entry["why"]) <= 200
+    for word in ("scheduler_perf", "SchedulingPodAntiAffinity", "5000Nodes", "large clusters"):
+        assert word in entry["source"]
+
+
+def test_the_cell_takes_one_chip_and_the_trickle_as_it_stands():
+    w = next(e for e in BENCH["workloads"] if e["name"] == CELL)
+    assert (w["config"], w["traffic"], w["chips"]) == (CONFIG, "trickle", 1)
+    assert len(w["why"]) <= 200
+    cell = spec.load_cell(CELL)
+    assert cell.traffic == spec.load_cell("coco-50kx1k.trickle").traffic
+    assert {m["name"] for m in cell.end_to_end} >= {"bind_p50_ms", "setup_s"}
+    everywhere = {m["name"] for m in BENCH["per_layer"] if "workloads" not in m}
+    assert {m["name"] for m in cell.per_layer} >= everywhere
+
+
+def test_the_rehearsal_is_the_fortieth(traced):
+    r = spec.rehearsal_config(_config())
+    assert r["argv"][r["argv"].index("--num-machines") + 1] == "125"
+    assert (r["resident_pods"], r["task_classes"]) == (1250, 16)
+    assert traced["facts"]["shapes"] == {
+        "nodes": 2048, "arcs": 8192, "machines": 125, "task_classes": 16, "path": "csr",
+    }
+
+
+def test_the_traced_rehearsal_is_correct_and_every_metric_reads_a_number(traced):
+    out = traced
+    assert out["correct"] is True, out["facts"]["faults"]
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert out["facts"]["warmup_extensions"] == 0
+    assert out["facts"]["closing"]["objective"] == out["facts"]["closing"]["native_objective"]
+    metrics = {k: v["value"] for k, v in out["metrics"].items()}
+    assert metrics["compiles_in_window"] == 0.0 and metrics["device_round_share"] == 100.0
+    # all but the roofline share, which the host has no peaks for
+    everywhere = {m["name"] for m in BENCH["per_layer"] if "workloads" not in m}
+    for name in everywhere - {"solve_roofline"}:
+        assert isinstance(metrics[name], float) and metrics[name] == metrics[name], name
+    for name in ("round_p50_ms", "graph_update_ms", "backend_solve_ms", "solve_device_ms",
+                 "supersteps_p50", "apply_ms", "queue_wait_ms", "round_accounted_share"):
+        assert metrics[name] > 0.0, name
+
+
+def test_the_untraced_rehearsal_is_correct_and_reports_the_two_end_to_end_metrics():
+    out = _rehearse(0)
+    assert out["correct"] is True, out["facts"]["faults"]
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert set(out["metrics"]) >= {"bind_p50_ms", "setup_s"}
+    assert all(m["value"] > 0 for m in out["metrics"].values())
+
+
+def test_a_stream_served_as_the_harness_serves_it_passes_the_replay():
+    """The harness's own pieces in this process (the service from the
+    rehearsal's argv, the plan, the traffic driver, `svc.run` in this
+    thread), then the replay over the log it kept, with each pod's
+    workload from the plan."""
+    from benchmarks import correct, run
+    from benchmarks.client import CompileWatch, TrafficDriver
+    from benchmarks.traffic import build_plan
+    from ksched_tpu.cluster.api import PodEvent
+    from ksched_tpu.utils import seed_rng
+
+    cell = spec.load_cell(CELL)
+    config = spec.rehearsal_config(cell.config)
+    seed_rng(SEED)
+    plan = build_plan(cell.traffic, config, SEED, 2.0)
+    svc, api, svc_args, _spans, _rounds = run.build_service(config, traced=False)
+    api.expect(len(plan.resident))
+    for pod_id, task_class in plan.resident:
+        api.submit_pod(PodEvent(pod_id=pod_id, task_class=task_class))
+    driver = TrafficDriver(api, plan, 2.0, CompileWatch())
+    driver.start()
+    watchdog = threading.Timer(120.0, api.close)  # a hung loop ends, and the test fails below
+    watchdog.start()
+    try:
+        svc.run(pod_batch_timeout_s=svc_args.pod_batch_timeout)
+    finally:
+        watchdog.cancel()
+        api.close()
+        driver.join(timeout=30.0)
+    assert not driver.is_alive() and driver.error is None, driver.error
+    group_of = dict(plan.resident + plan.closing)
+    for burst in plan.class_sweep:
+        group_of.update(burst)
+    group_of.update(plan.arrival(i) for i in range(len(plan.arrival_classes)))
+    binds = [e for e in api.log if e[0] == "bind"]
+    assert len(binds) >= len(plan.resident) + len(driver.due) > len(plan.resident)
+    assert any(e[0] == "done" for e in api.log)
+    assert len({group_of[e[1]] for e in binds}) == 16
+    assert check_anti_affinity(api.log, group_of) is None
+    assert correct.check_bindings(driver.due, api.bind_stamps) == []
+    assert correct.check_capacity(api.log, 110) == []
+    # the replay does find what the rule forbids
+    pod, node = next((e[1], e[2]) for e in binds)
+    twin = next(p for p, g in group_of.items() if g == group_of[pod] and p != pod)
+    fault = check_anti_affinity([("bind", pod, node, 0.0), ("bind", twin, node, 1.0)], group_of)
+    assert fault is not None and f"workload {group_of[pod]}" in fault
+    done_first = [("bind", pod, node, 0.0), ("done", pod, "", 0.5), ("bind", twin, node, 1.0)]
+    assert check_anti_affinity(done_first, group_of) is None

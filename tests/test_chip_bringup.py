@@ -55,6 +55,12 @@ PHASE_FACTS = {
         r"compiles after a stretch's first round: resident=\{'trickle': 0, 'waves': 0\} "
         r"synchronous=\{'trickle': 0, 'waves': 0\}",
     ),
+    "antiaffinity": (
+        r"machines=125 nodes=2048 arcs=8192 ",
+        r"objectives==native in every round",
+        r"\d+ Bindings and completions replayed: no node held two pods of a workload",
+        r"compiles after the first trickle round: 0",
+    ),
 }
 
 

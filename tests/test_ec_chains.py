@@ -103,10 +103,11 @@ def test_stale_ec_chain_is_pruned():
 def test_pinned_round_purges_idle_ecs_with_debounce():
     """With preemption OFF, placed tasks are pinned (their EC arcs
     deleted), leaving the chain ECs unconnected. The purge is
-    debounced: one round of being unconnected marks them, a second
-    purge removes them — transiently idle aggregators don't churn, and
-    persistently idle ones don't accumulate. The cascade (RACK_EC
-    orphaned by JOB_EC's removal) resolves in the same call."""
+    debounced: the round's own purge does not count (tasks pointed at
+    the ECs in it), one idle purge marks them, a second removes them:
+    transiently idle aggregators don't churn, and persistently idle
+    ones don't accumulate. The cascade (RACK_EC orphaned by JOB_EC's
+    removal) resolves in the same call."""
     sched, rmap, jmap, tmap, root = build_cluster(
         num_machines=2, pus_per_core=2,
         cost_model_factory=TwoLevelECModel,
@@ -114,7 +115,8 @@ def test_pinned_round_purges_idle_ecs_with_debounce():
     add_job(sched, jmap, tmap, num_tasks=3)
     n, _ = sched.schedule_all_jobs()
     assert n == 3
-    # everyone pinned; the round's purge only MARKED the idle ECs
+    # everyone pinned, but the ECs were in use this round: not marked
+    sched.gm.purge_unconnected_equiv_class_nodes()  # idle: marked
     assert JOB_EC in sched.gm.task_ec_to_node
     sched.gm.purge_unconnected_equiv_class_nodes()  # second observation
     assert not sched.gm.task_ec_to_node  # JOB_EC purged, RACK_EC cascaded

@@ -204,8 +204,10 @@ def test_purge_unconnected_equiv_class_nodes():
     sched.schedule_all_jobs()  # sees pre-completion stats (1-round lag)
     sched.schedule_all_jobs()  # places + pins the waiter
     assert len(sched.task_bindings) == 1
-    # everyone pinned -> the round's purge marked the idle EC
-    # (debounce); a second observation removes it
+    # everyone pinned, but the waiter pointed at the EC this round; an
+    # idle purge marks it (debounce) and a second observation removes it
+    sched.gm.purge_unconnected_equiv_class_nodes()
+    assert sched.gm.task_ec_to_node
     sched.gm.purge_unconnected_equiv_class_nodes()
     assert not sched.gm.task_ec_to_node
 
