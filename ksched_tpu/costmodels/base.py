@@ -60,9 +60,15 @@ class CostModeler(abc.ABC):
     #: 1) off its per-round work list: ``task_continuation_cost`` is a
     #: constant, and ``prepare_stats`` / ``gather_stats`` /
     #: ``update_stats`` do nothing for an accumulator that is not a
-    #: resource node. False keeps the visit of every task every round. A
-    #: subclass that overrides one of those four methods has to say it
-    #: again for itself; it does not inherit the claim.
+    #: resource node. False keeps the visit of every task every round.
+    #: The statistics pass relies on it for more (GraphManager.
+    #: compute_topology_statistics gathers only the PUs whose lists
+    #: changed and their ancestors): for a resource accumulator the
+    #: three hooks read only the PU's ``current_running_tasks``,
+    #: per-task facts that never change after admission, and the
+    #: children's aggregates. A subclass that overrides one of those
+    #: four methods has to say it again for itself; it does not inherit
+    #: the claim.
     pinned_tasks_are_inert: bool = False
 
     def __init_subclass__(cls, **kwargs) -> None:

@@ -109,6 +109,22 @@ class GraphManager:
         #: node ids of the other task nodes: what a solve can place,
         #: migrate or preempt, and all the decode has to map
         self.unpinned_task_nodes: Set[int] = set()
+        #: resource ids of the PUs whose current_running_tasks changed
+        #: since the last statistics pass: fed by running_tasks_changed,
+        #: drained by compute_topology_statistics
+        self._stats_dirty_pus: Set[int] = set()
+        #: the resource topology changed since the last statistics pass
+        self._stats_topology_changed = False
+        #: the `deltas` phase since the last statistics pass vouched for
+        #: the set (running_tasks_kept_by_events): it left the lists to
+        #: the events instead of rebuilding them. Never before a first
+        #: round, nor after one that raised or rebuilt the lists.
+        self._stats_lists_kept = False
+        #: the last compute_topology_statistics: PUs on the dirty set,
+        #: resource nodes it prepared, and whether it walked every node
+        self.stats_pus_dirty = 0
+        self.stats_nodes_visited = 0
+        self.stats_full_walk = 0
 
     def _set_pinned(self, task_node: Node, pinned: bool) -> None:
         node_id = task_node.id
@@ -230,6 +246,7 @@ class GraphManager:
     def add_resource_topology(self, rtnd: ResourceTopologyNodeDescriptor) -> None:
         """Reference: graph_manager.go:238-251."""
         rd = rtnd.resource_desc
+        self._stats_topology_changed = True
         self._add_resource_topology_dfs(rtnd)
         if rtnd.parent_id:
             curr = self.resource_to_node[resource_id_from_string(rtnd.parent_id)]
@@ -261,6 +278,7 @@ class GraphManager:
         r_node = self.resource_to_node.get(resource_id_from_string(rd.uuid))
         if r_node is None:
             raise KeyError(f"no node for resource {rd.uuid}")
+        self._stats_topology_changed = True
         removed_pus: List[int] = []
         cap_delta = 0
         for arc in list(r_node.outgoing.values()):
@@ -379,12 +397,64 @@ class GraphManager:
                 else:
                     self._update_task_to_unscheduled_agg_arc(arc.src_node)
 
+    def running_tasks_changed(self, resource_id: int) -> None:
+        """The scheduler appended to or took from the
+        ``current_running_tasks`` of the PU ``resource_id``: the next
+        statistics pass has to gather it, and what lies above it, again."""
+        self._stats_dirty_pus.add(resource_id)
+
+    def running_tasks_kept_by_events(self) -> None:
+        """The scheduler's `deltas` phase left every PU's list as the
+        events made it (each told through running_tasks_changed), where
+        the reference's rebuilds them all: the next statistics pass may
+        trust the dirty set. Without this word it walks every node, so a
+        round that rebuilt the lists, raised half-way or came from other
+        code than FlowScheduler's costs a full walk and no stale count."""
+        self._stats_lists_kept = True
+
     def compute_topology_statistics(self, start: Node) -> None:
-        """Reverse BFS from the sink, gathering usage statistics; correct
-        only for tree topologies (reference: graph_manager.go:478-511).
-        Where the model calls tasks inert, a task node is passed over:
-        the three hooks would return at once for it, and it has no
-        incoming arc, so nothing lies behind it."""
+        """Usage statistics of the resource tree, gathered from the PUs
+        up (reference: graph_manager.go:478-511, which walks every node
+        every round). Where the model calls tasks inert, what the three
+        hooks leave on a resource node is a function of the PUs'
+        ``current_running_tasks`` below it, so only the PUs whose lists
+        changed since the last pass and their ancestors are gathered
+        again; every other node keeps what the last pass left. Every
+        node is walked when the set cannot be trusted (no `deltas`
+        phase vouched for it since the last pass, which covers the
+        first pass and a restore's; the topology changed; preemption,
+        whose delta walk rebuilds every list; a model that does not
+        make the claim) and when the dirty PUs' paths to the root
+        would visit as many nodes as the tree has."""
+        dirty, self._stats_dirty_pus = self._stats_dirty_pus, set()
+        self.stats_pus_dirty = len(dirty)
+        walk_all = (
+            not self._stats_lists_kept
+            or self._stats_topology_changed
+            or self.preemption
+            or not self._tasks_inert
+            or start is not self.sink_node
+        )
+        self._stats_lists_kept = self._stats_topology_changed = False
+        if not walk_all and dirty:
+            depth = 1
+            node = self.resource_to_node[next(iter(dirty))]
+            while (node := self.node_to_parent_node.get(node.id)) is not None:
+                depth += 1
+            walk_all = len(dirty) * depth >= len(self.resource_to_node)
+        self.stats_full_walk = int(walk_all)
+        if walk_all:
+            self._walk_topology_statistics(start)
+            self.stats_nodes_visited = len(self.resource_to_node)
+        else:
+            self.stats_nodes_visited = self._gather_dirty_statistics(dirty)
+
+    def _walk_topology_statistics(self, start: Node) -> None:
+        """Reverse BFS from the sink over every node; correct only for
+        tree topologies (reference: graph_manager.go:478-511). Where the
+        model calls tasks inert, a task node is passed over: the three
+        hooks would return at once for it, and it has no incoming arc,
+        so nothing lies behind it."""
         self._cur_traversal_counter += 1
         counter = self._cur_traversal_counter
         skip_tasks = self._tasks_inert
@@ -402,6 +472,41 @@ class GraphManager:
                     src.visited = counter
                 self.cost_model.gather_stats(src, cur)
                 self.cost_model.update_stats(src, cur)
+
+    def _gather_dirty_statistics(self, dirty_pus: Set[int]) -> int:
+        """The walk's three hooks for the PUs of ``dirty_pus`` (resource
+        ids) and, level by level up the tree, for each of their
+        ancestors once all its dirty children are done: an ancestor is
+        prepared and gathers from every resource child (the tree's
+        children, so a machine's arcs from EC nodes are not looked at).
+        Returns the resource nodes prepared."""
+        prepare = self.cost_model.prepare_stats
+        gather = self.cost_model.gather_stats
+        update = self.cost_model.update_stats
+        sink = self.sink_node
+        level = [self.resource_to_node[rid] for rid in dirty_pus]
+        for pu in level:
+            prepare(pu)
+            gather(pu, sink)
+            update(pu, sink)
+        visited = len(level)
+        parent_of = self.node_to_parent_node
+        while level:
+            parents: Dict[int, Node] = {}
+            for node in level:
+                parent = parent_of.get(node.id)
+                if parent is not None:
+                    parents[parent.id] = parent
+            level = list(parents.values())
+            for parent in level:
+                prepare(parent)
+                for arc in parent.outgoing.values():
+                    child = arc.dst_node
+                    if child.resource_id != 0:
+                        gather(parent, child)
+                        update(parent, child)
+            visited += len(level)
+        return visited
 
     # ------------------------------------------------------------------
     # Delta generation (reference: graph_manager.go:253-339)

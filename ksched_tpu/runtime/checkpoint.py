@@ -33,8 +33,10 @@ CHECKPOINT_VERSION = 1
 #: carries its work list (a v1 manifest's has none; restore falls back
 #: to the cold replay, which rebuilds it from the events). 3: it also
 #: carries the pinned mask and the unpinned task nodes, and the
-#: scheduler the departures its next `deltas` phase drops
-WARM_MANIFEST_VERSION = 3
+#: scheduler the departures its next `deltas` phase drops. 4: it also
+#: carries the statistics pass's dirty set (the PUs whose lists changed
+#: since the last pass) and its flags
+WARM_MANIFEST_VERSION = 4
 
 
 class CheckpointError(RuntimeError):

@@ -66,6 +66,15 @@ class RoundRecord:
     #: round's RoundTiming, so 0 wherever the phase timings are)
     graph_tasks_visited: int = 0
     graph_tasks_skipped: int = 0
+    #: the round's statistics pass (from its RoundTiming;
+    #: GraphManager.compute_topology_statistics): PUs whose
+    #: current_running_tasks changed since the pass before, resource
+    #: nodes it prepared (those PUs and their ancestors, or every
+    #: resource node), and 1 if it walked every node (that method's
+    #: docstring says when)
+    stats_pus_dirty: int = 0
+    stats_nodes_visited: int = 0
+    stats_full_walk: int = 0
     #: the round's post-solve half (from its RoundTiming): unpinned task
     #: nodes handed to `decode` (the batch and the unscheduled backlog;
     #: every task under preemption), pinned tasks it left alone, and
@@ -241,6 +250,9 @@ class RoundTracer:
             arcs_removed=stats.arcs_removed if stats else 0,
             graph_tasks_visited=t.graph_tasks_visited,
             graph_tasks_skipped=t.graph_tasks_skipped,
+            stats_pus_dirty=t.stats_pus_dirty,
+            stats_nodes_visited=t.stats_nodes_visited,
+            stats_full_walk=t.stats_full_walk,
             decode_tasks=t.decode_tasks,
             decode_pinned_skipped=t.decode_pinned_skipped,
             deltas_walked=t.deltas_walked,

@@ -62,6 +62,12 @@ class RoundTiming:
     #: the same jobs left alone (GraphManager.add_or_update_job_nodes)
     graph_tasks_visited: int = 0
     graph_tasks_skipped: int = 0
+    #: what `stats` did: PUs whose running-task lists changed since the
+    #: last pass, resource nodes it prepared, and 1 if it walked every
+    #: node (GraphManager.compute_topology_statistics)
+    stats_pus_dirty: int = 0
+    stats_nodes_visited: int = 0
+    stats_full_walk: int = 0
     #: what the post-solve half worked on: unpinned task nodes handed
     #: to `decode`, pinned tasks it left alone (their arcs dropped by a
     #: mask), and mapping entries `deltas` turned into deltas
@@ -398,6 +404,12 @@ class FlowScheduler:
             self.dimacs_stats.reset()
             with span("stats") as sp:
                 self.gm.compute_topology_statistics(self.gm.sink_node)
+                timing.stats_pus_dirty = self.gm.stats_pus_dirty
+                timing.stats_nodes_visited = self.gm.stats_nodes_visited
+                timing.stats_full_walk = self.gm.stats_full_walk
+                sp.set("stats_pus_dirty", timing.stats_pus_dirty)
+                sp.set("stats_nodes_visited", timing.stats_nodes_visited)
+                sp.set("stats_full_walk", timing.stats_full_walk)
             timing.stats_s = sp.dur_s
             self._free_slots_at_solve = self._free_slots()
             with span("graph_update") as sp:
@@ -461,6 +473,7 @@ class FlowScheduler:
                     # events left them, less what departed.
                     deltas = []
                     self._drop_departed()
+                    self.gm.running_tasks_kept_by_events()
                 for task_node_id, res_node_id in task_mappings.items():
                     delta = self.gm.node_binding_to_scheduling_delta(
                         task_node_id, res_node_id, self.task_bindings
@@ -561,6 +574,7 @@ class FlowScheduler:
         assert task_id not in self.task_bindings, f"task {task_id} already bound"
         self.task_bindings[task_id] = rid
         self.resource_bindings.setdefault(rid, set()).add(task_id)
+        self.gm.running_tasks_changed(rid)
         self.cost_model.task_bound(td, rid)
 
     def _unbind_task_from_resource(
@@ -589,6 +603,7 @@ class FlowScheduler:
             # absent where the preemption walk emptied the list and the
             # mapping moved the task elsewhere
             rd.current_running_tasks.remove(task_id)
+            self.gm.running_tasks_changed(rid)
         self.cost_model.task_unbound(task_id, rid)
         return True
 
@@ -606,6 +621,7 @@ class FlowScheduler:
                 continue  # the PU left with its machine
             rd = rs.descriptor
             rd.current_running_tasks = [t for t in rd.current_running_tasks if t not in gone]
+            self.gm.running_tasks_changed(rid)
         self._departed.clear()
 
     def _execute_task(self, td: TaskDescriptor, rd: ResourceDescriptor) -> None:
