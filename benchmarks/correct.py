@@ -1,77 +1,74 @@
 """The comparison that decides `correct`, made outside the window.
 
-The configuration's guarantees, as far as a run can show them:
+`correct` is the conjunction of the guarantees the configuration states:
+for each key of its `guarantees`, in the file's order, the module
+`checks/<key>.py` is imported and its `check(ctx)` returns the faults it
+found (none: the guarantee held, as far as a run can show it). Nothing
+here, and nothing in run.py, names a check: a configuration that states a
+new guarantee brings `checks/<guarantee>.py` beside its file, and
+`spec.load_cell` refuses one that names a guarantee without a module
+before the service is built.
 
-  binding   every pod due in the window got exactly one Binding, and no
-            pod of the run got a second;
-  capacity  replaying the Bindings and completions in the order the loop
-            thread made them never puts a node over
-            cores x pus_per_core x max_tasks_per_pu pods;
-  answer    no NOOP round, no step down the ladder, no program compiled
-            inside the window, and the closing round's problem, solved
-            again by the independent C++ solver, has the same objective.
-
-Each check returns the faults it found; `correct` is "no fault".
+What a check gets is `Context`: what run.py holds once the window has
+closed, and nothing with which a check could alter the run.
 """
 
 from __future__ import annotations
 
+import importlib
+import time
+from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Sequence, Tuple
 
+from .traffic import Plan
 
-def check_bindings(due: Iterable[str], bind_stamps: Dict[str, List[float]]) -> List[str]:
-    faults = []
-    missing = [p for p in due if p not in bind_stamps]
-    if missing:
-        faults.append(f"{len(missing)} pods due in the window got no Binding (first: {missing[0]})")
-    twice = [p for p, s in bind_stamps.items() if len(s) > 1]
-    if twice:
-        faults.append(f"{len(twice)} pods got more than one Binding (first: {twice[0]})")
-    return faults
+LogEntry = Tuple[str, str, str, float]  # ("bind" | "done", pod, node, t)
 
 
-def check_capacity(log: Sequence[Tuple[str, str, str, float]], node_capacity: int) -> List[str]:
-    """Replay ("bind", pod, node, t) / ("done", pod, "", t) in order."""
-    where: Dict[str, str] = {}
-    load: Dict[str, int] = {}
-    for kind, pod, node, _t in log:
-        if kind == "bind":
-            old = where.get(pod)
-            if old is not None:
-                load[old] -= 1
-            where[pod] = node
-            load[node] = load.get(node, 0) + 1
-            if load[node] > node_capacity:
-                return [f"node {node} held {load[node]} pods, capacity {node_capacity} (pod {pod})"]
-        elif kind == "done":
-            node = where.pop(pod, None)
-            if node is None:
-                return [f"pod {pod} completed without a Binding on record"]
-            load[node] -= 1
-    return []
+@dataclass(frozen=True)
+class Context:
+    #: the configuration as it was run (under --rehearse-cpu, the fortieth)
+    config: dict
+    #: what traffic.build_plan drew from the seed: every pod's class
+    plan: Plan
+    svc: object  # cli.SchedulerService, after `run` returned
+    svc_args: object  # the configuration's argv, parsed
+    #: pod -> (due, submitted) of every pod due in the window
+    due: Dict[str, Tuple[float, float]]
+    #: pod -> stamps of every Binding posted for it, the whole run
+    bind_stamps: Dict[str, List[float]]
+    #: Bindings and completions of the whole run, in the loop's order
+    log: Sequence[LogEntry]
+    completions_refused: int
+    compiles_in_window: int
+    #: what a check compared, for the last line's `facts`: a check adds
+    #: one entry under a key of its own
+    facts: dict = field(default_factory=dict)
 
 
-def check_service(svc, compiles_in_window: int) -> List[str]:
-    faults = []
-    if svc.noop_rounds:
-        faults.append(f"{svc.noop_rounds} NOOP rounds")
-    if svc.ladder is not None and svc.ladder.degradations_total:
-        faults.append(f"{svc.ladder.degradations_total} steps down the ladder")
-    if compiles_in_window:
-        faults.append(f"{compiles_in_window} programs compiled inside the window")
-    return faults
+def run_checks(ctx: Context) -> Tuple[List[str], List[str]]:
+    """(faults, the names of the checks run, in the configuration's order)."""
+    faults: List[str] = []
+    names = list(ctx.config["guarantees"])
+    seconds = ctx.facts.setdefault("check_seconds", {})
+    for name in names:
+        t0 = time.perf_counter()
+        faults += importlib.import_module(f"benchmarks.checks.{name}").check(ctx)
+        seconds[name] = time.perf_counter() - t0
+    return faults, names
 
 
-def check_closing_objective(svc) -> Tuple[List[str], dict]:
-    """The last solved round's problem against the native C++ solver."""
-    from ksched_tpu.solver.select import make_backend
-
-    solver = svc.scheduler.solver
-    if solver.last_result is None:
-        return ["no round was solved"], {}
-    ours = int(solver.last_result.objective)
-    native = make_backend("native", warm_start=False, fallback=False).solve(solver.state.problem())
-    facts = {"objective": ours, "native_objective": int(native.objective)}
-    if ours != int(native.objective):
-        return [f"closing round objective {ours} != native C++ {int(native.objective)}"], facts
-    return [], facts
+def pod_classes(plan: Plan, log: Iterable[LogEntry]) -> Dict[str, int]:
+    """The class of every pod of the run: the fill, the class sweep, the
+    closing round, and the arrivals (open loop) or the waves the log
+    names (closed loop; wave k's pods are `w<k>_<i>`)."""
+    classes = dict(plan.resident + plan.closing)
+    for burst in plan.class_sweep:
+        classes.update(burst)
+    if plan.arrival_classes is not None:
+        classes.update(plan.arrival(i) for i in range(len(plan.arrival_classes)))
+    if plan.wave_pods:
+        waves = {int(pod[1:pod.index("_")]) for _k, pod, _n, _t in log if pod[0] == "w"}
+        for k in waves:
+            classes.update(plan.wave(k))
+    return classes

@@ -283,16 +283,13 @@ def main(argv=None) -> int:
     compiles_in_window = compiles.between(w0, w1)
 
     # -- correct (outside the window) -----------------------------------------
-    node_capacity = (
-        svc_args.cores_per_machine * svc_args.pus_per_core * svc_args.max_tasks_per_pu
+    # one check for every guarantee the configuration states (correct.py)
+    ctx = correct.Context(
+        config=config, plan=plan, svc=svc, svc_args=svc_args, due=due,
+        bind_stamps=api.bind_stamps, log=api.log,
+        completions_refused=api.completions_refused, compiles_in_window=compiles_in_window,
     )
-    faults = correct.check_bindings(due, api.bind_stamps)
-    faults += correct.check_capacity(api.log, node_capacity)
-    faults += correct.check_service(svc, compiles_in_window)
-    objective_faults, objective = correct.check_closing_objective(svc)
-    faults += objective_faults
-    if api.completions_refused:
-        faults.append(f"{api.completions_refused} completions of pods that were not bound")
+    faults, checks = correct.run_checks(ctx)
     if not latency_ms:
         faults.append("no pod due in the window was bound")
 
@@ -367,6 +364,7 @@ def main(argv=None) -> int:
         }
     # not read by the driver: what a reader of the run wants to know
     result["facts"] = {
+        **ctx.facts,  # what each check compared; the harness's own keys win
         "workload": cell.name, "seed": args.seed, "seconds": args.seconds,
         "rehearsal": bool(args.rehearse_cpu), "faults": faults,
         "window_s": w1 - w0, "bind_rounds": bind_rounds,
@@ -375,7 +373,7 @@ def main(argv=None) -> int:
         "warmup_extensions": driver.warmup_extensions, "drain_s": driver.drain_s,
         "compile_events": compiles.count(), "compile_cache": dict(compiles.cache),
         "cache_dir": os.path.relpath(cache_dir, ROOT) if cache_dir.startswith(ROOT) else cache_dir,
-        "shapes": shapes, "closing": objective, "warnings": caught[:5],
+        "shapes": shapes, "checks": checks, "warnings": caught[:5],
         "run_s": time.perf_counter() - T_START,
         # where set-up went: process start -> JAX up -> service and topology
         # built, channel filled -> fill round bound -> class sweep -> window
@@ -387,6 +385,11 @@ def main(argv=None) -> int:
         },
         **facts,
     }
+    # what each check compared, beside its limit: a record of a run that is
+    # not correct keeps the end of stderr
+    print("correct: " + json.dumps(
+        {"correct": not faults, "checks": checks, "faults": faults, **ctx.facts}
+    ), file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

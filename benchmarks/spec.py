@@ -9,11 +9,18 @@ metric is one file under this directory:
                                class mix, guarantees, assumed, reduced
   traffic/<mix>.json           parameters of one traffic mix, read by the
                                one general generator (traffic.py)
+  checks/<guarantee>.py        one guarantee a configuration states:
+                               `check(ctx) -> faults`; `correct` is the
+                               conjunction over the configuration's
+                               `guarantees`, in the file's order
+                               (correct.py)
   layer_metrics/<name>.json    one per-layer metric: layer, unit, the
                                reader (readers/<reader>.py) and its
                                parameters, the reduction, `moves`
 
-A later PR adds files and entries; it edits none that is here.
+A later PR adds files and entries; it edits none that is here. A
+configuration that states a guarantee with no module, or none at all, is
+refused by `load_cell`: nothing can be stated and left unchecked.
 """
 
 from __future__ import annotations
@@ -70,6 +77,7 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     w = by_name[name]
     configs = {c["name"]: c for c in bench["configs"]}
     config = _load(os.path.join(root, configs[w["config"]]["file"]))
+    check_guarantees(config, configs[w["config"]]["file"])
     traffic = _load(os.path.join(HERE, "traffic", w["traffic"] + ".json"))
     e2e = [m for m in bench["end_to_end"] if _in_cell(m, name)]
     e2e_names = {m["name"] for m in e2e}
@@ -87,6 +95,19 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
                 )
         per_layer.append(own)
     return Cell(name, config, traffic, int(w["chips"]), e2e, per_layer)
+
+
+def check_guarantees(config: dict, file: str) -> None:
+    """Every guarantee the configuration states has its check module."""
+    guarantees = config.get("guarantees")
+    if not isinstance(guarantees, dict) or not guarantees:
+        raise SpecError(f"{file} states no `guarantees`: `correct` would hold it to nothing")
+    for key in guarantees:
+        if not NAME_RE.match(key) or not os.path.isfile(os.path.join(HERE, "checks", key + ".py")):
+            raise SpecError(
+                f"{file} states the guarantee {key!r} and there is no "
+                f"benchmarks/checks/{key}.py to hold a run to it"
+            )
 
 
 def rehearsal_config(config: dict) -> dict:

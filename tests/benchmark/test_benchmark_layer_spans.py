@@ -39,19 +39,23 @@ def test_a_new_metric_loads_in_its_cells_and_is_absent_from_the_others(name, cel
         assert callable(importlib.import_module(f"benchmarks.readers.{reader}").read)
 
 
-def test_the_new_entries_are_the_last_seven_and_each_has_its_file():
-    tail = [m["name"] for m in BENCH["per_layer"][-len(NEW):]]
-    assert sorted(tail) == sorted(NEW)
-    for m in BENCH["per_layer"][-len(NEW):]:
-        own = json.load(open(os.path.join(spec.HERE, "layer_metrics", m["name"] + ".json")))
+def test_each_of_the_seven_entries_is_there_by_name_and_equals_its_file():
+    """Looked up by name: where an entry stands in `per_layer`, and how
+    many follow it, is nobody's business (a later PR appends)."""
+    by_name = {m["name"]: m for m in BENCH["per_layer"]}
+    assert set(NEW) <= set(by_name)
+    assert len(by_name) == len(BENCH["per_layer"])  # no name twice
+    for name, (_reader, cells) in NEW.items():
+        m = by_name[name]
+        own = json.load(open(os.path.join(spec.HERE, "layer_metrics", name + ".json")))
         assert {k: own[k] for k in m if k != "workloads"} == {
             k: v for k, v in m.items() if k != "workloads"
         }
-        assert m.get("workloads", CELLS) == NEW[m["name"]][1]
+        assert m.get("workloads", CELLS) == cells
         assert (m["better"], m["unit"]) == (
-            ("higher", "%") if m["name"] == "round_accounted_share" else ("lower", "ms")
+            ("higher", "%") if name == "round_accounted_share" else ("lower", "ms")
         )
-        assert m["source"] == ("program_counter" if m["name"] == "queue_wait_ms" else "program_span")
+        assert m["source"] == ("program_counter" if name == "queue_wait_ms" else "program_span")
 
 
 def _round(spans_ms, pods=1):

@@ -1,12 +1,12 @@
 """The benchmark's yardstick, checked without a chip: the reduction from a
 trace and spans to busy, idle and blame (on a small synthetic trace), the
-percentile rule, and the checks that decide `correct`."""
+percentile rule (the checks that decide `correct`: test_benchmark_checks.py)."""
 
 import time
 
 import pytest
 
-from benchmarks import correct, observe, roofline, stats
+from benchmarks import observe, roofline, stats
 from benchmarks import trace_reduce as tr
 
 
@@ -180,41 +180,3 @@ def test_byte_functions_follow_their_shapes():
     assert roofline.scan_csr_superstep_bytes(nodes=10, arcs=100) == 12 * 200 + 400 + 160
     assert roofline.transport_superstep_bytes(rows=4, cols=1024) == 16 * 4096 + 20 * 1024 + 48
     assert roofline.transport_cols(1000) == 1024 and roofline.transport_cols(1024) == 1152
-
-
-# -- correct ------------------------------------------------------------------------
-
-
-def test_a_pod_without_a_binding_and_a_pod_bound_twice_are_faults():
-    assert correct.check_bindings(["a", "b"], {"a": [1.0], "b": [2.0]}) == []
-    (missing,) = correct.check_bindings(["a", "b"], {"a": [1.0]})
-    assert "got no Binding" in missing
-    (twice,) = correct.check_bindings(["a"], {"a": [1.0, 2.0]})
-    assert "more than one Binding" in twice
-
-
-def test_replay_trips_on_a_node_over_capacity_and_not_under_it():
-    log = [("bind", "a", "n0", 1.0), ("bind", "b", "n0", 1.0), ("done", "a", "", 2.0),
-           ("bind", "c", "n0", 3.0)]
-    assert correct.check_capacity(log, node_capacity=2) == []
-    (fault,) = correct.check_capacity(log + [("bind", "d", "n0", 4.0)], node_capacity=2)
-    assert "held 3 pods, capacity 2" in fault
-    (fault,) = correct.check_capacity([("done", "z", "", 1.0)], node_capacity=2)
-    assert "without a Binding" in fault
-
-
-class _Svc:
-    def __init__(self, noop=0, degradations=0):
-        self.noop_rounds = noop
-        self.ladder = type("L", (), {"degradations_total": degradations})()
-
-
-@pytest.mark.parametrize("svc, compiles, word", [
-    (_Svc(noop=1), 0, "NOOP"),
-    (_Svc(degradations=2), 0, "ladder"),
-    (_Svc(), 3, "compiled inside the window"),
-])
-def test_a_noop_round_a_degradation_and_a_compile_in_the_window_are_faults(svc, compiles, word):
-    assert correct.check_service(_Svc(), 0) == []
-    (fault,) = correct.check_service(svc, compiles)
-    assert word in fault

@@ -3,10 +3,9 @@ configuration is `trivial-10kx1k` plus the two flags and nothing else, both
 cells rehearse `correct` with no program compiled in the window, and what a
 resident round adds (three export spans, the two halves of a pipelined
 solve, three RoundRecord fields) is read by readers the benchmark has, from
-parameters alone. The six metrics are NOT entries of BENCHMARK.json: a PR
-that changes the program may only append, and test_benchmark_layer_spans.py
-pins the last seven `per_layer` entries; PROPOSED is what a `benchmark` PR
-adds as `layer_metrics/<name>.json` and an entry each."""
+parameters alone. The six metrics were a proposal (PROPOSED) until a
+`benchmark` PR could append them; they are entries of BENCHMARK.json now,
+each with its `layer_metrics/<name>.json`, for the two resident cells."""
 
 import importlib
 import json
@@ -21,7 +20,8 @@ from benchmarks import observe, spec
 ROOT = spec.ROOT
 BENCH = spec.load_benchmark()
 CELLS = ("trivial-10kx1k-resident.trickle", "trivial-10kx1k-resident.waves")
-#: metric -> (reader, params, unit, source, layer); each moves bind_p50_ms
+#: metric -> (reader, params, unit, source, layer); each moves bind_p50_ms.
+#: What PR 26 proposed, and what the committed entries and files now say.
 PROPOSED = {
     "upload_ms": ("span_sum", {"spans": ["delta_pack", "delta_upload", "plan_upload"],
                                "reduce": "p50"}, "ms", "program_span", "graph update / export"),
@@ -123,13 +123,22 @@ def test_a_cell_takes_one_chip_and_the_traffic_as_it_stands(cell):
 
 
 def test_per_layer_is_the_parents_and_the_new_cells_report_every_metric_without_a_list():
-    names = [m["name"] for m in BENCH["per_layer"]]
-    assert not set(PROPOSED) & set(names)
+    by_name = {m["name"]: m for m in BENCH["per_layer"]}
+    assert set(PROPOSED) <= set(by_name)
     for name in PROPOSED:
-        assert not os.path.exists(os.path.join(spec.HERE, "layer_metrics", name + ".json"))
-    everywhere = [m["name"] for m in BENCH["per_layer"] if "workloads" not in m]
+        assert by_name[name]["workloads"] == list(CELLS)
+        assert os.path.exists(os.path.join(spec.HERE, "layer_metrics", name + ".json"))
+    everywhere = {m["name"] for m in BENCH["per_layer"] if "workloads" not in m}
     for cell in CELLS:
-        assert [m["name"] for m in spec.load_cell(cell).per_layer] == everywhere
+        loaded = {m["name"] for m in spec.load_cell(cell).per_layer}
+        assert everywhere | set(PROPOSED) <= loaded
+        assert loaded - everywhere == {
+            m["name"] for m in BENCH["per_layer"] if cell in m.get("workloads", ())
+        }
+    # and no other cell reports one of the six
+    for w in BENCH["workloads"]:
+        if w["name"] not in CELLS:
+            assert not set(PROPOSED) & {m["name"] for m in spec.load_cell(w["name"]).per_layer}
 
 
 @pytest.mark.parametrize("name", sorted(PROPOSED))
@@ -137,8 +146,17 @@ def test_a_reader_that_exists_reads_what_a_resident_round_adds(name):
     reader, params, unit, source, layer = PROPOSED[name]
     assert os.path.exists(os.path.join(ROOT, "benchmarks", "readers", reader + ".py"))
     assert spec.UNIT_RE.match(unit) and source in spec.SOURCES
-    assert layer in {m["layer"] for m in BENCH["per_layer"]}
+    assert layer in {m["layer"] for m in BENCH["per_layer"] if m["name"] not in PROPOSED}
     assert _read(name, _obs(RESIDENT_SPANS, RESIDENT_RECORDS)) == pytest.approx(EXPECTED[name])
+    # the committed entry and its file are the proposal, word for word
+    entry = next(m for m in BENCH["per_layer"] if m["name"] == name)
+    with open(os.path.join(spec.HERE, "layer_metrics", name + ".json")) as f:
+        own = json.load(f)
+    assert (own["reader"], own["params"]) == (reader, params)
+    for m in (entry, own):
+        assert (m["unit"], m["source"], m["layer"], m["better"], m["moves"]) == (
+            unit, source, layer, "lower", "bind_p50_ms"
+        )
 
 
 @pytest.mark.parametrize("name", sorted(PROPOSED))
@@ -160,8 +178,16 @@ def test_the_traced_trickle_rehearsal_is_correct_and_compiles_nothing(traced_tri
 
 
 def test_the_traced_trickle_rehearsal_reports_what_its_control_reports(traced_trickle, control):
-    assert set(traced_trickle["metrics"]) == set(control["metrics"])
-    assert not set(PROPOSED) & set(traced_trickle["metrics"])
+    assert set(traced_trickle["metrics"]) == set(control["metrics"]) | set(PROPOSED)
+    assert not set(PROPOSED) & set(control["metrics"])
+    values = {k: v["value"] for k, v in traced_trickle["metrics"].items()}
+    for name in ("upload_ms", "upload_bytes", "solve_dispatch_ms", "solve_sync_ms", "post_defer_ms"):
+        assert values[name] > 0.0, name
+    assert values["full_uploads"] >= 0.0
+    assert traced_trickle["facts"]["checks"] == ["binding", "capacity", "answer", "resident"]
+    assert control["facts"]["checks"] == ["binding", "capacity", "answer"]
+    mirror = traced_trickle["facts"]["resident"]
+    assert mirror["differ"] == 0 and mirror["mirror_entries"] > 0 and mirror["refreshes"] > 10
 
 
 def test_the_untraced_waves_rehearsal_is_correct_and_compiles_nothing():
