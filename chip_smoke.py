@@ -53,6 +53,16 @@ speed:
            (benchmarks/reference_antiaffinity.check_anti_affinity) finds
            no node that ever held two pods of one workload, and nothing
            compiled after the first trickle round.
+  zonespread  the benchmark's `k8s-5000-zonespread` deployment from its
+           file's argv (the same cluster in three zones, `--fake-zones 3
+           --cost-model k8s_zonespread --backend jax`): the same fill and
+           20 rounds on the graph two EC hops deep (task -> EC(g) ->
+           ZONE(z) -> machine, the chain arcs capacity-bound). Per round
+           as above. At the end: the replay
+           (benchmarks/reference_zonespread.check_topology_spread, each
+           node's zone from the label the service holds) finds no round
+           that left a receiving zone more than maxSkew above the lowest,
+           and nothing compiled after the first trickle round.
 
 `--only PHASE` (repeatable) runs the named phases alone.
 
@@ -89,6 +99,7 @@ FULL = dict(
     general=dict(tasks=10_000, machines=1_000),
     resident=dict(scale=1, trickle=(26, 31, 12, 58, 40, 9, 60, 22), waves=4),
     antiaffinity=dict(scale=1, trickle=(30, 55, 12, 80, 41, 9, 64, 22, 50, 37) * 2),
+    zonespread=dict(scale=1, trickle=(30, 55, 12, 80, 41, 9, 64, 22, 50, 37) * 2),
 )
 TINY = dict(
     served=dict(machines=20, pods=200, churn=10),
@@ -97,6 +108,7 @@ TINY = dict(
     general=dict(tasks=400, machines=40),
     resident=dict(scale=40, trickle=(2, 5, 1, 9, 3), waves=3),
     antiaffinity=dict(scale=40, trickle=(3, 6, 1, 9, 4)),
+    zonespread=dict(scale=40, trickle=(3, 6, 1, 9, 4)),
 )
 
 
@@ -626,21 +638,21 @@ class Smoke:
         )
 
 
-    # -- required hostname anti-affinity, at the benchmark's size -----------
+    # -- the placement rules, at the benchmark's size -----------------------
 
-    def antiaffinity(self) -> str:
-        """`k8s-5000-antiaffinity` as cli.main builds it: the fill and
-        trickle-sized rounds (as many of the oldest pods complete as
-        arrive), every round's objective against native C++ on the same
-        problem, and the replay of the Binding log against the rule."""
+    def _serve_rule(self, phase: str, name: str, counters) -> dict:
+        """A benchmark deployment whose pods carry a placement rule, as
+        cli.main builds it: the fill and trickle-sized rounds (as many of
+        the oldest pods complete as arrive), every round's objective
+        against native C++ on the same problem; `counters` are the
+        RoundTiming fields each round's line shows. Returns what the
+        phase's replay needs."""
         from benchmarks.client import BenchClusterAPI
-        from benchmarks.reference_antiaffinity import check_anti_affinity
         from ksched_tpu.cluster.api import PodEvent
         from ksched_tpu.solver.select import make_backend
 
         compiles = self._compile_events()
-        sz = self.sizes["antiaffinity"]
-        name = "k8s-5000-antiaffinity"
+        sz = self.sizes[phase]
         config, args, api, svc = self._config_service(name, sz["scale"], BenchClusterAPI)
         api.svc = svc
         rng = np.random.default_rng([self.seed, 30])
@@ -676,27 +688,71 @@ class Smoke:
             check(ours == theirs, f"{name} round {r}: objective {ours} != native {theirs}")
             t = svc.scheduler.last_timing
             self.say(
-                f"antiaffinity round {r}: pods={arrivals} wall_ms={wall * 1e3:.1f} "
+                f"{phase} round {r}: pods={arrivals} wall_ms={wall * 1e3:.1f} "
                 f"graph_update_ms={t.graph_update_s * 1e3:.1f} solve_ms={t.solve_s * 1e3:.1f} "
-                f"supersteps={int(rung.last_supersteps)} objective={ours} ec_nodes={t.ec_nodes} "
-                f"ec_arcs={t.ec_arcs} ec_arcs_changed={t.ec_arcs_changed} "
-                f"unscheduled_by_rule={t.unscheduled_by_rule}"
+                f"supersteps={int(rung.last_supersteps)} objective={ours} "
+                + " ".join(f"{c}={getattr(t, c)}" for c in counters)
             )
         check(svc.ladder is None or svc.ladder.degradations_total == 0, f"{name}: a step down the ladder")
         check(late == 0, f"{name}: {late} programs compiled after the first trickle round")
-        fault = check_anti_affinity(api.log, group_of)
-        check(fault is None, f"{name}: {fault}")
         api.close()
         st = solver.state
+        return dict(
+            config=config, svc=svc, log=api.log, group_of=group_of, late=late,
+            shapes=f"machines={args.num_machines} nodes={st.n_cap} arcs={st.m_cap} "
+            f"entries={st.plan.entry_cap} rounds={len(plan)} objectives==native in every round",
+        )
+
+    def antiaffinity(self) -> str:
+        """`k8s-5000-antiaffinity`: the served rounds, and the replay of
+        the Binding log against the rule."""
+        from benchmarks.reference_antiaffinity import check_anti_affinity
+
+        name = "k8s-5000-antiaffinity"
+        run = self._serve_rule(
+            "antiaffinity", name, ("ec_nodes", "ec_arcs", "ec_arcs_changed", "unscheduled_by_rule")
+        )
+        fault = check_anti_affinity(run["log"], run["group_of"])
+        check(fault is None, f"{name}: {fault}")
         return (
-            f"machines={args.num_machines} nodes={st.n_cap} arcs={st.m_cap} "
-            f"entries={st.plan.entry_cap} rounds={len(plan)} objectives==native in every round; "
-            f"{len(api.log)} Bindings and completions replayed: no node held two pods of a workload; "
-            f"compiles after the first trickle round: {late}"
+            f"{run['shapes']}; {len(run['log'])} Bindings and completions replayed: "
+            f"no node held two pods of a workload; "
+            f"compiles after the first trickle round: {run['late']}"
+        )
+
+    def zonespread(self) -> str:
+        """`k8s-5000-zonespread`: the served rounds on the graph two EC
+        hops deep, and the replay of the Binding log against the rule,
+        each node's zone from the label the service holds."""
+        from benchmarks.reference_zonespread import check_topology_spread
+        from ksched_tpu.data import ZONE_LABEL
+
+        name = "k8s-5000-zonespread"
+        run = self._serve_rule(
+            "zonespread", name,
+            ("ec_nodes", "ec_arcs", "ec_arcs_changed", "ec_chain_arcs_changed",
+             "spread_fallback", "unscheduled_by_rule"),
+        )
+        svc, config = run["svc"], run["config"]
+        label_of = {
+            node: svc.resource_map.find(machine).descriptor.labels[ZONE_LABEL]
+            for node, machine in svc.node_to_machine.items()
+        }
+        zones = sorted(set(label_of.values()))
+        check(len(zones) == config["zones"], f"{name}: zones {zones}, the file says {config['zones']}")
+        zone_of = {node: zones.index(label) for node, label in label_of.items()}
+        fault, facts = check_topology_spread(run["log"], run["group_of"], zone_of, config["max_skew"])
+        check(fault is None, f"{name}: {fault}")
+        return (
+            f"{run['shapes']}; {facts['replayed']} Bindings and completions replayed over "
+            f"{facts['rounds']} rounds in {len(zones)} zones: largest skew {facts['largest_skew']} "
+            f"(maxSkew {config['max_skew']}); compiles after the first trickle round: {run['late']}"
         )
 
 
-PHASES = ("served", "array", "kernels", "general", "sharded", "resident", "antiaffinity")
+PHASES = (
+    "served", "array", "kernels", "general", "sharded", "resident", "antiaffinity", "zonespread",
+)
 
 
 def main(argv=None) -> int:

@@ -24,6 +24,7 @@ from typing import Dict, List, Optional, Tuple
 from .cluster import Binding, ClusterAPI, NodeEvent, PodEvent, SyntheticClusterAPI
 from .cluster.api import RETRY_STAT_KEYS
 from .costmodels import MODEL_REGISTRY, CostModelType
+from .data import ZONE_LABEL
 from .obs import metrics as obs_metrics
 from .obs.flight import FlightRecorder
 from .obs.spans import SpanTracer, active_tracer, span
@@ -83,9 +84,13 @@ class SchedulerService:
         device_resident: bool = False,
         tenant: str = "",
         audit_every: int = 0,
+        fake_zones: int = 0,
         _restored: Optional[Tuple] = None,
     ) -> None:
         self.api = api
+        #: --fake-zones: the fake machines of init_topology carry a zone
+        #: label, machine i that of zone i mod fake_zones (0: no label)
+        self.fake_zones = fake_zones
         self.injector = injector
         self.tracer = tracer
         self.flight = flight
@@ -208,6 +213,7 @@ class SchedulerService:
             pus_per_core=node.pus_per_core,
             task_capacity_per_pu=self.max_tasks_per_pu,
             machine_index=len(self.node_to_machine),
+            labels=dict(node.labels),
         )
         machine.resource_desc.capacity.net_bw = node.net_bw_capacity
         mid = resource_id_from_string(machine.resource_desc.uuid)
@@ -226,14 +232,18 @@ class SchedulerService:
         pus_per_core: int = 1,
     ) -> int:
         """Fabricate machines (-fakeMachines, reference :191-202) or poll
-        the control plane for nodes (:206-238)."""
+        the control plane for nodes (:206-238). With ``fake_zones`` the
+        fake machines are dealt round-robin over that many zones, as
+        scheduler_perf's labelNodePrepareStrategy deals its values."""
         if fake_machines > 0:
+            zones = self.fake_zones
             for i in range(fake_machines):
                 self.add_node(
                     NodeEvent(
                         node_id=f"fake_node_{i}",
                         num_cores=cores_per_machine,
                         pus_per_core=pus_per_core,
+                        labels=((ZONE_LABEL, f"zone-{i % zones}"),) if zones > 0 else (),
                     )
                 )
             return fake_machines
@@ -1166,6 +1176,9 @@ def build_arg_parser() -> argparse.ArgumentParser:
     ap.add_argument("--pod-chan-size", "-pcs", type=int, default=5000)
     ap.add_argument("--fake-machines", action="store_true")
     ap.add_argument("--num-machines", "-nm", type=int, default=10)
+    ap.add_argument("--fake-zones", type=int, default=0, metavar="N",
+                    help="label fake machine i with zone i mod N "
+                    "(topology.kubernetes.io/zone; 0 = no label)")
     ap.add_argument("--cores-per-machine", type=int, default=1)
     ap.add_argument("--pus-per-core", type=int, default=1)
     ap.add_argument(
@@ -1273,6 +1286,7 @@ def build_service(
         pipeline=args.pipeline,
         device_resident=args.device_resident,
         audit_every=args.audit_every,
+        fake_zones=args.fake_zones,
     )
 
 

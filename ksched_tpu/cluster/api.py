@@ -13,7 +13,7 @@ from __future__ import annotations
 import abc
 import time
 from dataclasses import dataclass, field
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 
 @dataclass(frozen=True)
@@ -41,6 +41,10 @@ class NodeEvent:
     num_cores: int = 1
     pus_per_core: int = 1
     net_bw_capacity: int = 0
+    #: the node's labels (`metadata.labels`), as sorted (key, value)
+    #: pairs so that the event stays hashable; they ride the machine's
+    #: resource descriptor (`ResourceDescriptor.labels`)
+    labels: Tuple[Tuple[str, str], ...] = ()
 
 
 @dataclass(frozen=True)

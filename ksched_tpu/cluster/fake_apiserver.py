@@ -84,11 +84,13 @@ class _State:
 
     # shared by the HTTP handlers and the Python side-door so the two
     # entry points cannot drift on object schema
-    def add_node(self, name: str, capacity: dict, unschedulable: bool) -> None:
+    def add_node(
+        self, name: str, capacity: dict, unschedulable: bool, labels: Optional[dict] = None
+    ) -> None:
         with self.lock:
             self.nodes.append(
                 {
-                    "metadata": {"name": name},
+                    "metadata": {"name": name, "labels": dict(labels or {})},
                     "spec": {"unschedulable": bool(unschedulable)},
                     "status": {"capacity": dict(capacity)},
                 }
@@ -227,7 +229,7 @@ class _Handler(BaseHTTPRequestHandler):
             body = self._read_body()
             st.add_node(
                 body["name"], body.get("capacity", {}),
-                bool(body.get("unschedulable")),
+                bool(body.get("unschedulable")), body.get("labels"),
             )
             return self._json(201, {"ok": True})
         self._json(404, {"error": f"no route {self.path}"})
@@ -322,9 +324,9 @@ class FakeAPIServer:
     # -- convenience for tests/demos (the podgen/node side-door) -----------
 
     def add_node(self, name: str, cores: int = 1, pus_per_core: int = 1,
-                 unschedulable: bool = False) -> None:
+                 unschedulable: bool = False, labels: Optional[dict] = None) -> None:
         self._state.add_node(
-            name, {"cores": cores, "pus_per_core": pus_per_core}, unschedulable
+            name, {"cores": cores, "pus_per_core": pus_per_core}, unschedulable, labels
         )
 
     def create_pods(self, count: int, prefix: str = "pod", **spec) -> None:

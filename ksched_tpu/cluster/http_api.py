@@ -265,6 +265,10 @@ class HTTPClusterAPI(ClusterAPI):
                         num_cores=int(cap.get("cores", 1)),
                         pus_per_core=int(cap.get("pus_per_core", 1)),
                         net_bw_capacity=int(cap.get("net_bw", 0)),
+                        labels=tuple(sorted(
+                            (str(k), str(v))
+                            for k, v in (item["metadata"].get("labels") or {}).items()
+                        )),
                     )
                 )
 

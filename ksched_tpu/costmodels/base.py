@@ -37,6 +37,9 @@ class CostModelType(enum.IntEnum):
     #: not of the reference's enumeration: required hostname
     #: anti-affinity (costmodels/k8s_antiaffinity.py)
     K8S_ANTIAFFINITY = 9
+    #: nor this: a hard zone topology-spread constraint
+    #: (costmodels/k8s_zonespread.py)
+    K8S_ZONESPREAD = 10
 
 
 # The wildcard equivalence class every task points at in aggregate-style
@@ -70,6 +73,12 @@ class CostModeler(abc.ABC):
     #: four methods has to say it again for itself; it does not inherit
     #: the claim.
     pinned_tasks_are_inert: bool = False
+
+    #: 1 while the graph update of the round in progress had to leave
+    #: the model's own allotment for a per-pod predicate (the zone
+    #: spread model, where a zone is short of room); the scheduler
+    #: stamps it on the round (RoundTiming.spread_fallback)
+    spread_fallback: int = 0
 
     def __init_subclass__(cls, **kwargs) -> None:
         super().__init_subclass__(**kwargs)
@@ -183,7 +192,8 @@ class CostModeler(abc.ABC):
             raise ValueError(
                 f"task_class {task_class} is not one of the {len(TaskType)} CoCo "
                 f"classes (0..{len(TaskType) - 1}) that {type(self).__name__} reads; "
-                "a workload index needs --cost-model k8s_antiaffinity"
+                "a workload index needs a model that takes one: --cost-model "
+                "k8s_antiaffinity or k8s_zonespread"
             )
         return {"task_type": TaskType(task_class)}
 

@@ -17,7 +17,12 @@ from __future__ import annotations
 
 import enum
 from dataclasses import dataclass, field
-from typing import List, Optional
+from typing import Dict, List, Optional
+
+
+#: the well-known node label that names a node's zone (Kubernetes,
+#: "Well-Known Labels, Annotations and Taints")
+ZONE_LABEL = "topology.kubernetes.io/zone"
 
 
 class TaskState(enum.IntEnum):
@@ -228,6 +233,10 @@ class ResourceDescriptor:
     whare_map_stats: WhareMapStats = field(default_factory=WhareMapStats)
     coco_interference_scores: CoCoInterferenceScores = field(default_factory=CoCoInterferenceScores)
     trace_machine_id: int = 0
+    #: the node's labels as the control plane has them (a machine's:
+    #: `NodeEvent.labels`; no reference counterpart). A cost model reads
+    #: them in `add_machine`, e.g. the zone under ZONE_LABEL
+    labels: Dict[str, str] = field(default_factory=dict)
 
 
 @dataclass

@@ -10,7 +10,7 @@ root task plus spawned children under one JobDescriptor.
 
 from __future__ import annotations
 
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 from ..data import (
     JobDescriptor,
@@ -66,10 +66,13 @@ def build_machine_topology(
     task_capacity_per_pu: int,
     parent: ResourceTopologyNodeDescriptor,
     machine_index: int = 0,
+    labels: Optional[Dict[str, str]] = None,
 ) -> ResourceTopologyNodeDescriptor:
     """machine → core* → PU* subtree attached under parent (reference:
-    schedule_iteration_test.go:257-331 createMachineNode)."""
+    schedule_iteration_test.go:257-331 createMachineNode). ``labels``
+    are the machine's (the node's, on the cluster API)."""
     machine_rd = make_resource_desc(ResourceType.MACHINE, f"machine_{machine_index}")
+    machine_rd.labels = dict(labels or {})
     machine = ResourceTopologyNodeDescriptor(
         resource_desc=machine_rd, parent_id=parent.resource_desc.uuid
     )
@@ -98,9 +101,10 @@ def add_machine(
     pus_per_core: int = 1,
     task_capacity_per_pu: int = 1,
     machine_index: int = 0,
+    labels: Optional[Dict[str, str]] = None,
 ) -> ResourceTopologyNodeDescriptor:
     machine = build_machine_topology(
-        num_cores, pus_per_core, task_capacity_per_pu, root, machine_index
+        num_cores, pus_per_core, task_capacity_per_pu, root, machine_index, labels
     )
     _register_subtree(machine, resource_map)
     scheduler.register_resource(machine)

@@ -81,6 +81,10 @@ class GraphManager:
         self._cur_traversal_counter = 0
         self._ec_purge_candidates: Set[int] = set()  # idle at the last purge
         self._ec_pointed_at: Set[int] = set()  # by a task's update since the last purge
+        #: EC -> the living ECs whose update ever listed it as a
+        #: preference (an EC -> EC arc): while one lives, the purge
+        #: leaves the listed EC alone though no arc enters it
+        self._ec_listed_by: Dict[int, Set[int]] = {}
         #: the cost model can neither re-price a pinned task's one arc
         #: nor learn anything from a task node in the statistics walk
         self._tasks_inert = cost_model.pinned_tasks_are_inert
@@ -97,8 +101,11 @@ class GraphManager:
         self.tasks_visited = 0
         self.tasks_skipped = 0
         #: the last add_or_update_job_nodes: arcs from an EC node to a
-        #: resource that it added, removed or re-priced
+        #: resource that it added, removed or re-priced; and arcs from
+        #: an EC node to an EC node that it added, removed or gave
+        #: another capacity or cost
         self.ec_arcs_changed = 0
+        self.ec_chain_arcs_changed = 0
         #: node id -> the task node is pinned: _pin_task_to_node left it
         #: one arc, to its PU, with lower bound 1, so every feasible
         #: flow carries its unit there and no solve can change its
@@ -204,6 +211,7 @@ class GraphManager:
         marked: Set[int] = set()
         visited = 0
         self.ec_arcs_changed = 0
+        self.ec_chain_arcs_changed = 0
         i, n = 0, len(events)
         while i < n or node_queue:
             if node_queue and (i == n or due[0] <= events[i][0]):
@@ -323,10 +331,18 @@ class GraphManager:
         purged EC) are dead for certain and cascade immediately — the
         reference's note about multi-call subgraph cleanup
         (graph_manager.go:348-351) without leaving chains behind if the
-        cluster quiesces."""
+        cluster quiesces. An EC that another EC's update listed (the
+        far end of an EC -> EC arc: no task ever points at it, and in a
+        round whose allotment passes it by no arc enters it) is idle
+        only once every EC that listed it is gone; then it is one of
+        the orphans and goes in the same call."""
+        listed_by = self._ec_listed_by
 
         def unconnected() -> set:
-            return {ec for ec, node in self.task_ec_to_node.items() if not node.incoming}
+            return {
+                ec for ec, node in self.task_ec_to_node.items()
+                if not node.incoming and not listed_by.get(ec)
+            }
 
         seen = unconnected()
         doomed = (seen & self._ec_purge_candidates) - self._ec_pointed_at
@@ -593,7 +609,11 @@ class GraphManager:
         return node
 
     def _remove_equiv_class_node(self, node: Node) -> None:
-        del self.task_ec_to_node[node.equiv_class]
+        ec = node.equiv_class
+        del self.task_ec_to_node[ec]
+        self._ec_listed_by.pop(ec, None)
+        for listers in self._ec_listed_by.values():
+            listers.discard(ec)
         self.cm.delete_node(node, ChangeType.DEL_EQUIV_CLASS_NODE, "RemoveEquivClassNode")
 
     def _remove_resource_node(self, node: Node) -> None:
@@ -743,27 +763,30 @@ class GraphManager:
         self._update_task_to_res_arcs(task_node, node_queue, marked)
 
     def _update_equiv_class_node(self, ec_node: Node, node_queue: Deque, marked: Set[int]) -> None:
-        self._update_equiv_to_equiv_arcs(ec_node, node_queue, marked)
+        with span("ec_chain_refresh"):
+            self._update_equiv_to_equiv_arcs(ec_node, node_queue, marked)
         self._update_equiv_to_res_arcs(ec_node, node_queue, marked)
 
     def _update_equiv_to_equiv_arcs(self, ec_node: Node, node_queue: Deque, marked: Set[int]) -> None:
         """Reference: graph_manager.go:939-970."""
-        pref_ecs = self.cost_model.get_equiv_class_to_equiv_classes_arcs(ec_node.equiv_class)
-        if not pref_ecs:
-            self._remove_invalid_ec_pref_arcs(ec_node, pref_ecs, ChangeType.DEL_ARC_BETWEEN_EQUIV_CLASS)
-            return
+        ec = ec_node.equiv_class
+        pref_ecs = self.cost_model.get_equiv_class_to_equiv_classes_arcs(ec)
         for pref_ec in pref_ecs:
             pref_node = self.task_ec_to_node.get(pref_ec)
             if pref_node is None:
                 pref_node = self._add_equiv_class_node(pref_ec)
-            cost, cap_upper = self.cost_model.equiv_class_to_equiv_class(ec_node.equiv_class, pref_ec)
+            self._ec_listed_by.setdefault(pref_ec, set()).add(ec)
+            cost, cap_upper = self.cost_model.equiv_class_to_equiv_class(ec, pref_ec)
             arc = self.cm.graph.get_arc(ec_node, pref_node)
             if arc is None:
                 self.cm.add_arc(
                     ec_node, pref_node, 0, cap_upper, cost, ArcType.OTHER,
                     ChangeType.ADD_ARC_BETWEEN_EQUIV_CLASS, "UpdateEquivClassNode",
                 )
+                self.ec_chain_arcs_changed += 1
             else:
+                if (arc.cap_upper, arc.cost) != (cap_upper, cost):
+                    self.ec_chain_arcs_changed += 1
                 self.cm.change_arc(
                     arc, arc.cap_lower, cap_upper, cost,
                     ChangeType.CHG_ARC_BETWEEN_EQUIV_CLASS, "UpdateEquivClassNode",
@@ -771,7 +794,12 @@ class GraphManager:
             if pref_node.id not in marked:
                 marked.add(pref_node.id)
                 node_queue.append((pref_node, pref_node.task))
-        self._remove_invalid_ec_pref_arcs(ec_node, pref_ecs, ChangeType.DEL_ARC_BETWEEN_EQUIV_CLASS)
+        # an EC that never listed another has no arc to one: its arcs to
+        # resources (a zone's 1,667 machines) are not looked through
+        if pref_ecs or any(ec in listers for listers in self._ec_listed_by.values()):
+            self.ec_chain_arcs_changed += self._remove_invalid_ec_pref_arcs(
+                ec_node, pref_ecs, ChangeType.DEL_ARC_BETWEEN_EQUIV_CLASS
+            )
 
     def _update_equiv_to_res_arcs(self, ec_node: Node, node_queue: Deque, marked: Set[int]) -> None:
         """Reference: graph_manager.go:974-1010, vectorized through the
@@ -984,8 +1012,8 @@ class GraphManager:
 
     # -- preference pruning ------------------------------------------------
 
-    def _remove_invalid_ec_pref_arcs(self, node: Node, pref_ecs: List[int], change_type: ChangeType) -> None:
-        """Reference: graph_manager.go:732-760."""
+    def _remove_invalid_ec_pref_arcs(self, node: Node, pref_ecs: List[int], change_type: ChangeType) -> int:
+        """Reference: graph_manager.go:732-760. Returns how many it pruned."""
         pref = set(pref_ecs)
         to_delete = [
             arc
@@ -994,6 +1022,7 @@ class GraphManager:
         ]
         for arc in to_delete:
             self.cm.delete_arc(arc, change_type, "RemoveInvalidECPrefArcs")
+        return len(to_delete)
 
     def _remove_invalid_pref_res_arcs(self, node: Node, pref_rids: List[int], change_type: ChangeType) -> int:
         """Reference: graph_manager.go:766-790 — prunes arcs to resources

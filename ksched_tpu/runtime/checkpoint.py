@@ -35,8 +35,9 @@ CHECKPOINT_VERSION = 1
 #: carries the pinned mask and the unpinned task nodes, and the
 #: scheduler the departures its next `deltas` phase drops. 4: it also
 #: carries the statistics pass's dirty set (the PUs whose lists changed
-#: since the last pass) and its flags
-WARM_MANIFEST_VERSION = 4
+#: since the last pass) and its flags. 5: and which
+#: ECs listed which as a preference (the purge)
+WARM_MANIFEST_VERSION = 5
 
 
 class CheckpointError(RuntimeError):

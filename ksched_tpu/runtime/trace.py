@@ -100,6 +100,11 @@ class RoundRecord:
     ec_arcs: int = 0
     ec_arcs_changed: int = 0
     unscheduled_by_rule: int = 0
+    #: EC -> EC arcs `graph_update` added, removed or gave another
+    #: capacity or cost, and 1 if the cost model left its allotment for
+    #: the per-pod predicate (a zone short of room)
+    ec_chain_arcs_changed: int = 0
+    spread_fallback: int = 0
     #: --pipeline: how long the PREVIOUS round's Bindings waited from
     #: their `bindings_collect` to the flush that POSTed them (this
     #: round's dispatch window, or an idle sweep in between); stamped on
@@ -263,6 +268,8 @@ class RoundTracer:
             ec_arcs=t.ec_arcs,
             ec_arcs_changed=t.ec_arcs_changed,
             unscheduled_by_rule=t.unscheduled_by_rule,
+            ec_chain_arcs_changed=t.ec_chain_arcs_changed,
+            spread_fallback=t.spread_fallback,
         )
         for k, v in (extra or {}).items():
             if not hasattr(rec, k):

@@ -87,6 +87,12 @@ class RoundTiming:
     ec_nodes: int = 0
     ec_arcs: int = 0
     ec_arcs_changed: int = 0
+    #: EC -> EC arcs `graph_update` added, removed or gave another
+    #: capacity or cost this round (GraphManager.ec_chain_arcs_changed);
+    #: 1 if the cost model left its allotment for the per-pod predicate
+    #: in this round (CostModeler.spread_fallback: a zone short of room)
+    ec_chain_arcs_changed: int = 0
+    spread_fallback: int = 0
     #: runnable tasks the round left unplaced while the cluster had a
     #: free slot for each: what a placement rule (or a cost above the
     #: unscheduled cost) kept out, not a full cluster
@@ -422,9 +428,12 @@ class FlowScheduler:
                 timing.ec_nodes = len(ec_nodes)
                 timing.ec_arcs = sum(len(node.outgoing) for node in ec_nodes)
                 timing.ec_arcs_changed = self.gm.ec_arcs_changed
+                timing.ec_chain_arcs_changed = self.gm.ec_chain_arcs_changed
+                timing.spread_fallback = self.cost_model.spread_fallback
                 sp.set("ec_nodes", timing.ec_nodes)
                 sp.set("ec_arcs", timing.ec_arcs)
                 sp.set("ec_arcs_changed", timing.ec_arcs_changed)
+                sp.set("ec_chain_arcs_changed", timing.ec_chain_arcs_changed)
             timing.graph_update_s = sp.dur_s
         except BaseException:
             round_span.__exit__(*sys.exc_info())

@@ -489,8 +489,8 @@ def try_collapse(problem) -> Tuple[Optional[GraphCollapse], str]:
         if len(viol):
             a = int(ee[viol[0]])
             return _refuse(
-                f"EC {int(src[a])}: interior EC arc cap {int(cap[a])} "
-                "can bind"
+                f"EC {int(src[a])} -> EC {int(dst[a])}: chain arc cap "
+                f"{int(cap[a])} can bind"
             )
         ee_by_row: Dict[int, list] = {}
         for a_, e_, d_ in zip(

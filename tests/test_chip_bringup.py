@@ -61,6 +61,12 @@ PHASE_FACTS = {
         r"\d+ Bindings and completions replayed: no node held two pods of a workload",
         r"compiles after the first trickle round: 0",
     ),
+    "zonespread": (
+        r"machines=125 nodes=2048 arcs=4096 ",
+        r"objectives==native in every round",
+        r"\d+ Bindings and completions replayed over 6 rounds in 3 zones: largest skew [01] \(maxSkew 5\)",
+        r"compiles after the first trickle round: 0",
+    ),
 }
 
 

@@ -42,7 +42,7 @@ from test_graph_worklist import (
 
 MODELS = {
     name: MODEL_REGISTRY[getattr(CostModelType, name.upper())]
-    for name in ("trivial", "coco", "whare", "net", "k8s_antiaffinity")
+    for name in ("trivial", "coco", "whare", "net", "k8s_antiaffinity", "k8s_zonespread")
 }
 CORES, PUS_PER_CORE, SLOTS = 2, 2, 3
 DEPTH = 4  # PU, core, machine, coordinator
