@@ -25,7 +25,7 @@ system, 1338 lines). Behavior parity notes:
 from __future__ import annotations
 
 from collections import deque
-from typing import Deque, Dict, List, Optional, Set, Tuple
+from typing import Deque, Dict, Iterable, List, Optional, Set, Tuple
 
 import numpy as np
 
@@ -132,6 +132,20 @@ class GraphManager:
         self.stats_pus_dirty = 0
         self.stats_nodes_visited = 0
         self.stats_full_walk = 0
+        #: the post-solve refresh of the tree (refresh_resource_topology)
+        #: has a baseline of its own: the PUs whose lists changed since
+        #: the last refresh, fed by the same call, drained by the refresh
+        self._apply_dirty_pus: Set[int] = set()
+        #: the next refresh owes a walk of every node: the topology
+        #: changed since the last one, or that one did not reach its end
+        self._apply_walk_owed = True
+        #: this round's `deltas` phase vouched for that set
+        self._apply_lists_kept = False
+        #: the last refresh_resource_topology: PUs on its dirty set,
+        #: resource nodes it refreshed, and whether it walked every node
+        self.apply_pus_dirty = 0
+        self.apply_nodes_visited = 0
+        self.apply_full_walk = 0
 
     def _set_pinned(self, task_node: Node, pinned: bool) -> None:
         node_id = task_node.id
@@ -254,7 +268,7 @@ class GraphManager:
     def add_resource_topology(self, rtnd: ResourceTopologyNodeDescriptor) -> None:
         """Reference: graph_manager.go:238-251."""
         rd = rtnd.resource_desc
-        self._stats_topology_changed = True
+        self._stats_topology_changed = self._apply_walk_owed = True
         self._add_resource_topology_dfs(rtnd)
         if rtnd.parent_id:
             curr = self.resource_to_node[resource_id_from_string(rtnd.parent_id)]
@@ -281,12 +295,93 @@ class GraphManager:
                 rd.num_running_tasks_below - old_running,
             )
 
+    def refresh_resource_topology(self, roots: Iterable[ResourceTopologyNodeDescriptor]) -> None:
+        """The post-solve refresh of the tree under ``roots`` (every
+        registered root): what update_resource_topology leaves on each
+        of them, by a visit of the PUs whose ``current_running_tasks``
+        changed since the last refresh and of their ancestors. The two
+        counts of a node and the capacity of the arc from its parent
+        are a function of the lists' lengths below it, so every other
+        node keeps what the last refresh left. Every node is walked
+        when that cannot be trusted (a walk is owed: the topology
+        changed since the last refresh, which covers the first one and
+        a cold restore's, or the last one did not reach its end; this
+        round's `deltas` phase did not vouch for the set; preemption,
+        whose delta walk rebuilds every list) and when the dirty PUs'
+        paths to the root would visit as many nodes as the tree has.
+        No cost-model hook is called, so no model is excepted."""
+        dirty = self._apply_dirty_pus
+        self.apply_pus_dirty = len(dirty)
+        walk_all = (
+            self._apply_walk_owed
+            or not self._apply_lists_kept
+            or self.preemption
+            or self._paths_span_the_tree(dirty)
+        )
+        self._apply_lists_kept = False
+        self._apply_walk_owed = True  # until this one has reached its end
+        self.apply_full_walk = int(walk_all)
+        if walk_all:
+            for rtnd in roots:
+                self.update_resource_topology(rtnd)
+            self.apply_nodes_visited = len(self.resource_to_node)
+        else:
+            self.apply_nodes_visited = self._refresh_dirty_topology(roots, dirty)
+        self._apply_walk_owed = False
+        dirty.clear()
+
+    def _refresh_dirty_topology(
+        self, roots: Iterable[ResourceTopologyNodeDescriptor], dirty_pus: Set[int]
+    ) -> int:
+        """_update_resource_topology_dfs, descending only into the
+        nodes on a path from a PU of ``dirty_pus`` (resource ids) to its
+        root: the same counts, and the same arc changes in the same
+        order, so the journal is the walk's. Returns the nodes visited."""
+        on_path: Set[str] = set()
+        parent_of = self.node_to_parent_node
+        for rid in dirty_pus:
+            node: Optional[Node] = self.resource_to_node[rid]
+            while node is not None:
+                uuid = node.resource_descriptor.uuid
+                if uuid in on_path:
+                    break  # the rest of the way up is on another PU's path
+                on_path.add(uuid)
+                node = parent_of.get(node.id)
+        for rtnd in roots:
+            if rtnd.resource_desc.uuid in on_path:
+                self._refresh_dirty_topology_dfs(rtnd, on_path)
+        return len(on_path)
+
+    def _refresh_dirty_topology_dfs(
+        self, rtnd: ResourceTopologyNodeDescriptor, on_path: Set[str]
+    ) -> None:
+        rd = rtnd.resource_desc
+        if rd.type == ResourceType.PU:
+            slots = self.max_tasks_per_pu
+            running = len(rd.current_running_tasks)
+        else:
+            slots = running = 0
+        for child in rtnd.children:
+            child_rd = child.resource_desc
+            if child_rd.uuid in on_path:
+                self._refresh_dirty_topology_dfs(child, on_path)
+            slots += child_rd.num_slots_below
+            running += child_rd.num_running_tasks_below
+        rd.num_slots_below = slots
+        rd.num_running_tasks_below = running
+        if rtnd.parent_id:
+            curr = self.resource_to_node[resource_id_from_string(rd.uuid)]
+            parent_arc = self.cm.graph.get_arc(self.node_to_parent_node[curr.id], curr)
+            self.cm.change_arc_capacity(
+                parent_arc, self._capacity_to_parent(rd), ChangeType.CHG_ARC_BETWEEN_RES, "UpdateResourceTopologyDFS"
+            )
+
     def remove_resource_topology(self, rd: ResourceDescriptor) -> List[int]:
         """Reference: graph_manager.go:362-387. Returns removed PU node ids."""
         r_node = self.resource_to_node.get(resource_id_from_string(rd.uuid))
         if r_node is None:
             raise KeyError(f"no node for resource {rd.uuid}")
-        self._stats_topology_changed = True
+        self._stats_topology_changed = self._apply_walk_owed = True
         removed_pus: List[int] = []
         cap_delta = 0
         for arc in list(r_node.outgoing.values()):
@@ -416,17 +511,23 @@ class GraphManager:
     def running_tasks_changed(self, resource_id: int) -> None:
         """The scheduler appended to or took from the
         ``current_running_tasks`` of the PU ``resource_id``: the next
-        statistics pass has to gather it, and what lies above it, again."""
+        statistics pass has to gather it, and what lies above it, again,
+        and so has the next refresh of the tree. Each drains a set of its
+        own: an eviction between two rounds is gathered by the pass that
+        opens the next round, and the capacities on its path, which only
+        the refresh writes, are still to come."""
         self._stats_dirty_pus.add(resource_id)
+        self._apply_dirty_pus.add(resource_id)
 
     def running_tasks_kept_by_events(self) -> None:
         """The scheduler's `deltas` phase left every PU's list as the
         events made it (each told through running_tasks_changed), where
-        the reference's rebuilds them all: the next statistics pass may
-        trust the dirty set. Without this word it walks every node, so a
-        round that rebuilt the lists, raised half-way or came from other
-        code than FlowScheduler's costs a full walk and no stale count."""
-        self._stats_lists_kept = True
+        the reference's rebuilds them all: this round's refresh of the
+        tree and the next statistics pass may trust their dirty sets.
+        Without this word each walks every node, so a round that rebuilt
+        the lists, raised half-way or came from other code than
+        FlowScheduler's costs a full walk and no stale count."""
+        self._stats_lists_kept = self._apply_lists_kept = True
 
     def compute_topology_statistics(self, start: Node) -> None:
         """Usage statistics of the resource tree, gathered from the PUs
@@ -452,18 +553,26 @@ class GraphManager:
             or start is not self.sink_node
         )
         self._stats_lists_kept = self._stats_topology_changed = False
-        if not walk_all and dirty:
-            depth = 1
-            node = self.resource_to_node[next(iter(dirty))]
-            while (node := self.node_to_parent_node.get(node.id)) is not None:
-                depth += 1
-            walk_all = len(dirty) * depth >= len(self.resource_to_node)
+        walk_all = walk_all or self._paths_span_the_tree(dirty)
         self.stats_full_walk = int(walk_all)
         if walk_all:
             self._walk_topology_statistics(start)
             self.stats_nodes_visited = len(self.resource_to_node)
         else:
             self.stats_nodes_visited = self._gather_dirty_statistics(dirty)
+
+    def _paths_span_the_tree(self, dirty_pus: Set[int]) -> bool:
+        """The paths from ``dirty_pus`` (resource ids) up to the root
+        would visit as many nodes as the tree has, reckoned as PUs times
+        the depth of one of them: a wave that touched most PUs is then
+        never slower than the walk of every node."""
+        if not dirty_pus:
+            return False
+        depth = 1
+        node = self.resource_to_node[next(iter(dirty_pus))]
+        while (node := self.node_to_parent_node.get(node.id)) is not None:
+            depth += 1
+        return len(dirty_pus) * depth >= len(self.resource_to_node)
 
     def _walk_topology_statistics(self, start: Node) -> None:
         """Reverse BFS from the sink over every node; correct only for

@@ -36,8 +36,11 @@ CHECKPOINT_VERSION = 1
 #: scheduler the departures its next `deltas` phase drops. 4: it also
 #: carries the statistics pass's dirty set (the PUs whose lists changed
 #: since the last pass) and its flags. 5: and which
-#: ECs listed which as a preference (the purge)
-WARM_MANIFEST_VERSION = 5
+#: ECs listed which as a preference (the purge). 6: and the dirty set
+#: and flags of the post-solve refresh of the resource tree (an older
+#: manifest's graph manager has none: restore falls back to the cold
+#: replay, whose first refresh walks every node)
+WARM_MANIFEST_VERSION = 6
 
 
 class CheckpointError(RuntimeError):

@@ -75,6 +75,14 @@ class RoundRecord:
     stats_pus_dirty: int = 0
     stats_nodes_visited: int = 0
     stats_full_walk: int = 0
+    #: the round's refresh of the resource tree, in `apply` (from its
+    #: RoundTiming; GraphManager.refresh_resource_topology): PUs whose
+    #: current_running_tasks changed since the refresh before, resource
+    #: nodes it visited (those PUs and their ancestors, or every
+    #: resource node), and 1 if it walked every node
+    apply_pus_dirty: int = 0
+    apply_nodes_visited: int = 0
+    apply_full_walk: int = 0
     #: the round's post-solve half (from its RoundTiming): unpinned task
     #: nodes handed to `decode` (the batch and the unscheduled backlog;
     #: every task under preemption), pinned tasks it left alone, and
@@ -258,6 +266,9 @@ class RoundTracer:
             stats_pus_dirty=t.stats_pus_dirty,
             stats_nodes_visited=t.stats_nodes_visited,
             stats_full_walk=t.stats_full_walk,
+            apply_pus_dirty=t.apply_pus_dirty,
+            apply_nodes_visited=t.apply_nodes_visited,
+            apply_full_walk=t.apply_full_walk,
             decode_tasks=t.decode_tasks,
             decode_pinned_skipped=t.decode_pinned_skipped,
             deltas_walked=t.deltas_walked,

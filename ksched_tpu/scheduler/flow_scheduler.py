@@ -68,6 +68,12 @@ class RoundTiming:
     stats_pus_dirty: int = 0
     stats_nodes_visited: int = 0
     stats_full_walk: int = 0
+    #: what `apply`'s refresh of the resource tree did: PUs whose lists
+    #: changed since the last refresh, resource nodes it visited, and 1
+    #: if it walked every node (GraphManager.refresh_resource_topology)
+    apply_pus_dirty: int = 0
+    apply_nodes_visited: int = 0
+    apply_full_walk: int = 0
     #: what the post-solve half worked on: unpinned task nodes handed
     #: to `decode`, pinned tasks it left alone (their arcs dropped by a
     #: mask), and mapping entries `deltas` turned into deltas
@@ -495,8 +501,13 @@ class FlowScheduler:
 
             with span("apply") as sp:
                 num_scheduled = self._apply_scheduling_deltas(deltas)
-                for rid in self.resource_roots:
-                    self.gm.update_resource_topology(self._root_rtnds[rid])
+                self.gm.refresh_resource_topology(self._root_rtnds.values())
+                timing.apply_pus_dirty = self.gm.apply_pus_dirty
+                timing.apply_nodes_visited = self.gm.apply_nodes_visited
+                timing.apply_full_walk = self.gm.apply_full_walk
+                sp.set("apply_pus_dirty", timing.apply_pus_dirty)
+                sp.set("apply_nodes_visited", timing.apply_nodes_visited)
+                sp.set("apply_full_walk", timing.apply_full_walk)
             timing.apply_s = sp.dur_s
             self.gm.purge_unconnected_equiv_class_nodes()
             # Policy feedback: which runnable tasks stayed unscheduled
