@@ -144,6 +144,12 @@ class ChangeStats:
     def reset(self) -> None:
         self.__init__()
 
+    @property
+    def arc_records(self) -> int:
+        """Arc records taken since the last reset, those merged into
+        an earlier record of the same arc included."""
+        return self.arcs_added + self.arcs_changed + self.arcs_removed
+
     def to_csv(self) -> str:
         """Reference: dimacs/change_stats.go:70-82."""
         totals = [

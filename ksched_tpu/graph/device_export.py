@@ -805,7 +805,8 @@ class DeviceResidentState:
         from ..obs.spans import span
 
         st = self.state
-        problem = st.problem()
+        with span("problem_snapshot"):
+            problem = st.problem()
         slots, nodes = st.drain_dirty()
         rebuilt = self._rebuild_count != st.rebuild_count
         arcs_stale = rebuilt or self._m_cap != st.m_cap or self.d_src is None

@@ -40,7 +40,7 @@ from ..data import (
     TaskDescriptor,
     TaskState,
 )
-from ..obs.spans import span
+from ..obs.spans import Span, span, start_span
 from ..utils import ResourceMap, job_id_from_string, resource_id_from_string
 from .changes import ChangeManager, ChangeStats, ChangeType
 from .flowgraph import Arc, ArcType, Node, NodeType, resource_node_type
@@ -51,6 +51,47 @@ TaskMapping = Dict[int, int]  # task node id -> PU node id (flowmanager/types.go
 def task_needs_node(td: TaskDescriptor) -> bool:
     """Reference: graph_manager.go:1333-1338."""
     return td.state in (TaskState.RUNNABLE, TaskState.RUNNING, TaskState.ASSIGNED)
+
+
+class _TurnRuns:
+    """The spans inside one update of the job nodes: one for each run
+    of consecutive turns of one kind in its FIFO, `task_refresh` for
+    task turns and `res_refresh` for resource-node turns, never one a
+    node. The clock is read where the kind of turn changes. The first
+    run is a task run and takes in the listing of the task turns. An
+    EC node's turn has spans of its own (`ec_chain_refresh`,
+    `ec_refresh`) and only ends a run."""
+
+    TASK, RES = "task_refresh", "res_refresh"
+    __slots__ = ("kind", "res_nodes", "res_arcs", "_stats", "_span", "_turn0", "_arcs0")
+
+    def __init__(self, stats: ChangeStats) -> None:
+        self.kind: Optional[str] = None  # of the open run; None: no run is open
+        self.res_nodes = 0  # resource-node turns of the runs closed so far
+        self.res_arcs = 0  # arc records those runs sent to the journal
+        self._stats = stats
+        self._span: Optional[Span] = None
+        self._turn0 = self._arcs0 = 0
+
+    def switch(self, kind: Optional[str], turn: int) -> None:
+        """After ``turn`` turns the next one is of another kind: close
+        the open run and open one of ``kind`` (None: none)."""
+        sp = self._span
+        if sp is not None:
+            turns = turn - self._turn0
+            if self.kind == self.RES:
+                arcs = self._stats.arc_records - self._arcs0
+                self.res_nodes += turns
+                self.res_arcs += arcs
+                sp.set("nodes", turns)
+                sp.set("arcs_changed", arcs)
+            else:
+                sp.set("tasks", turns)
+            sp.finish()
+        self.kind = kind
+        self._span = start_span(kind) if kind is not None else None
+        self._turn0 = turn
+        self._arcs0 = self._stats.arc_records
 
 
 class GraphManager:
@@ -106,6 +147,16 @@ class GraphManager:
         #: another capacity or cost
         self.ec_arcs_changed = 0
         self.ec_chain_arcs_changed = 0
+        #: the last add_or_update_job_nodes: resource nodes that took a
+        #: turn, and arcs out of them that it added or whose price
+        #: really changed (a record in the journal, new or merged into
+        #: one that was there; ChangeManager.change_arc drops a no-op)
+        self.res_nodes_visited = 0
+        self.res_arcs_changed = 0
+        #: the last purge_unconnected_equiv_class_nodes: EC nodes it
+        #: removed, and the arcs that went with them
+        self.ec_purged = 0
+        self.ec_arcs_dropped = 0
         #: node id -> the task node is pinned: _pin_task_to_node left it
         #: one arc, to its PU, with lower bound 1, so every feasible
         #: flow carries its unit there and no solve can change its
@@ -197,8 +248,62 @@ class GraphManager:
         the turn at key (d, j, p) entered the FIFO behind every child of
         the earlier turns and ahead of this turn's children, so it is
         due before the first event at or beyond (d + 1, j, p)."""
-        # (key, phase, index among its siblings, uid, descriptor): the
-        # uid only keeps the sort from ever comparing descriptors
+        node_queue: Deque[Tuple[Node, Optional[TaskDescriptor]]] = deque()
+        due: Deque[tuple] = deque()  # node_queue[i] comes before events at or beyond due[i]
+        marked: Set[int] = set()
+        visited = 0
+        self.ec_arcs_changed = 0
+        self.ec_chain_arcs_changed = 0
+        runs = _TurnRuns(self.cm.stats)
+        task_run, res_run = runs.TASK, runs.RES
+        turn = 0  # turns taken so far, of any kind
+        try:
+            # the first turn is a task's: its run takes in the listing
+            runs.switch(task_run, turn)
+            events, skipped = self._listed_task_turns(jobs)
+            i, n = 0, len(events)
+            while i < n or node_queue:
+                if node_queue and (i == n or due[0] <= events[i][0]):
+                    depth, jpos, path = due.popleft()
+                    node, _ = node_queue.popleft()
+                    if node.is_equiv_class_node:
+                        if runs.kind is not None:
+                            runs.switch(None, turn)
+                        self._update_equiv_class_node(node, node_queue, marked)
+                    elif node.is_resource_node:
+                        if runs.kind != res_run:
+                            runs.switch(res_run, turn)
+                        self._update_res_outgoing_arcs(node, node_queue, marked)
+                    else:
+                        raise ValueError(f"unexpected node type in worklist: {node.type}")
+                else:
+                    if runs.kind != task_run:
+                        runs.switch(task_run, turn)
+                    (depth, jpos, path), phase, _, _, td = events[i]
+                    i += 1
+                    if phase:
+                        self._add_listed_task_node(job_id_from_string(td.job_id), td)
+                    else:
+                        task_node = self.task_to_node.get(td.uid)
+                        if task_node is not None:
+                            self._update_task_node(task_node, node_queue, marked)
+                            visited += 1
+                if len(node_queue) > len(due):
+                    due.extend([(depth + 1, jpos, path)] * (len(node_queue) - len(due)))
+                turn += 1
+        finally:
+            runs.switch(None, turn)
+        self.tasks_visited = visited
+        self.tasks_skipped = skipped
+        self.res_nodes_visited = runs.res_nodes
+        self.res_arcs_changed = runs.res_arcs
+
+    def _listed_task_turns(self, jobs: List[JobDescriptor]) -> Tuple[list, int]:
+        """The task turns of an update of ``jobs`` in the order they
+        are taken, each (key, phase, index among its siblings, uid,
+        descriptor), where the uid only keeps the sort from ever
+        comparing descriptors; and the pinned tasks of those jobs that
+        are off the list."""
         events: List[Tuple[tuple, int, int, int, TaskDescriptor]] = []
         skipped = 0
         for jpos, job in enumerate(jobs):
@@ -220,37 +325,7 @@ class GraphManager:
                 if depth and td.uid not in self.task_to_node:
                     events.append(((depth - 1, jpos, path[:-1]), 1, path[-1], td.uid, td))
         events.sort()
-        node_queue: Deque[Tuple[Node, Optional[TaskDescriptor]]] = deque()
-        due: Deque[tuple] = deque()  # node_queue[i] comes before events at or beyond due[i]
-        marked: Set[int] = set()
-        visited = 0
-        self.ec_arcs_changed = 0
-        self.ec_chain_arcs_changed = 0
-        i, n = 0, len(events)
-        while i < n or node_queue:
-            if node_queue and (i == n or due[0] <= events[i][0]):
-                depth, jpos, path = due.popleft()
-                node, _ = node_queue.popleft()
-                if node.is_equiv_class_node:
-                    self._update_equiv_class_node(node, node_queue, marked)
-                elif node.is_resource_node:
-                    self._update_res_outgoing_arcs(node, node_queue, marked)
-                else:
-                    raise ValueError(f"unexpected node type in worklist: {node.type}")
-            else:
-                (depth, jpos, path), phase, _, _, td = events[i]
-                i += 1
-                if phase:
-                    self._add_listed_task_node(job_id_from_string(td.job_id), td)
-                else:
-                    task_node = self.task_to_node.get(td.uid)
-                    if task_node is not None:
-                        self._update_task_node(task_node, node_queue, marked)
-                        visited += 1
-            if len(node_queue) > len(due):
-                due.extend([(depth + 1, jpos, path)] * (len(node_queue) - len(due)))
-        self.tasks_visited = visited
-        self.tasks_skipped = skipped
+        return events, skipped
 
     def _add_listed_task_node(self, job_id: int, td: TaskDescriptor) -> None:
         """Reference: graph_manager.go:895-929, one child. A listed task
@@ -441,9 +516,13 @@ class GraphManager:
 
         seen = unconnected()
         doomed = (seen & self._ec_purge_candidates) - self._ec_pointed_at
+        self.ec_purged = self.ec_arcs_dropped = 0
         while doomed:
             for ec in doomed:
-                self._remove_equiv_class_node(self.task_ec_to_node[ec])
+                node = self.task_ec_to_node[ec]
+                self.ec_purged += 1
+                self.ec_arcs_dropped += len(node.outgoing) + len(node.incoming)
+                self._remove_equiv_class_node(node)
             now = unconnected()
             doomed = now - seen  # newly orphaned by this wave: cascade
             seen |= now

@@ -158,6 +158,9 @@ class SpanTracer:
         self._lock = threading.Lock()
         self._events: deque = deque(maxlen=capacity)
         self.capacity = capacity
+        #: of the process that made the tracer, read once: a syscall an
+        #: event is the larger part of a record under a sandboxed kernel
+        self._pid = os.getpid()
         self.total = 0  # spans ever recorded (ring may have dropped some)
         self.dropped = 0
         self._prev: Optional[SpanTracer] = None
@@ -174,7 +177,7 @@ class SpanTracer:
             "name": name,
             "ts": t0_s * 1e6,  # perf_counter base: monotonic, shared in-process
             "dur": dur_s * 1e6,
-            "pid": os.getpid(),
+            "pid": self._pid,
             "tid": threading.get_ident(),
             "args": args,
         }

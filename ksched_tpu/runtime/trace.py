@@ -66,6 +66,11 @@ class RoundRecord:
     #: round's RoundTiming, so 0 wherever the phase timings are)
     graph_tasks_visited: int = 0
     graph_tasks_skipped: int = 0
+    #: the resource half of the same update (from its RoundTiming):
+    #: resource nodes that took a turn in its FIFO, and arcs out of
+    #: them it added or whose price really changed
+    res_nodes_visited: int = 0
+    res_arcs_changed: int = 0
     #: the round's statistics pass (from its RoundTiming;
     #: GraphManager.compute_topology_statistics): PUs whose
     #: current_running_tasks changed since the pass before, resource
@@ -99,6 +104,11 @@ class RoundRecord:
     upload_bytes: int = 0
     upload_full: int = 0
     plan_relocations: int = 0
+    #: records of the change journal the round's export applied (from
+    #: its RoundTiming; 0 when it built the arrays whole), and EC nodes
+    #: the purge removed after `apply`
+    journal_changes: int = 0
+    ec_purged: int = 0
     #: the round's equivalence classes (from its RoundTiming): EC nodes
     #: and EC -> resource arcs live after `graph_update`, those arcs
     #: added, removed or re-priced by it, and runnable tasks the round
@@ -263,6 +273,8 @@ class RoundTracer:
             arcs_removed=stats.arcs_removed if stats else 0,
             graph_tasks_visited=t.graph_tasks_visited,
             graph_tasks_skipped=t.graph_tasks_skipped,
+            res_nodes_visited=t.res_nodes_visited,
+            res_arcs_changed=t.res_arcs_changed,
             stats_pus_dirty=t.stats_pus_dirty,
             stats_nodes_visited=t.stats_nodes_visited,
             stats_full_walk=t.stats_full_walk,
@@ -275,6 +287,8 @@ class RoundTracer:
             upload_bytes=t.upload_bytes,
             upload_full=t.upload_full,
             plan_relocations=t.plan_relocations,
+            journal_changes=t.journal_changes,
+            ec_purged=t.ec_purged,
             ec_nodes=t.ec_nodes,
             ec_arcs=t.ec_arcs,
             ec_arcs_changed=t.ec_arcs_changed,

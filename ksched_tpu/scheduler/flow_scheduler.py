@@ -62,6 +62,11 @@ class RoundTiming:
     #: the same jobs left alone (GraphManager.add_or_update_job_nodes)
     graph_tasks_visited: int = 0
     graph_tasks_skipped: int = 0
+    #: the resource half of the same update: resource nodes that took a
+    #: turn, and arcs out of them it added or whose price really
+    #: changed (GraphManager.res_nodes_visited, res_arcs_changed)
+    res_nodes_visited: int = 0
+    res_arcs_changed: int = 0
     #: what `stats` did: PUs whose running-task lists changed since the
     #: last pass, resource nodes it prepared, and 1 if it walked every
     #: node (GraphManager.compute_topology_statistics)
@@ -87,6 +92,12 @@ class RoundTiming:
     upload_bytes: int = 0
     upload_full: int = 0
     plan_relocations: int = 0
+    #: records of the change journal the round's export applied to the
+    #: flat arrays (0 when it built them whole; PlacementSolver)
+    journal_changes: int = 0
+    #: EC nodes the round's purge removed, after `apply`
+    #: (GraphManager.purge_unconnected_equiv_class_nodes)
+    ec_purged: int = 0
     #: equivalence classes in the graph after `graph_update`: EC nodes
     #: live, their arcs to resources live, and those arcs added, removed
     #: or re-priced this round (GraphManager.ec_arcs_changed)
@@ -371,7 +382,7 @@ class FlowScheduler:
             with span("solve_dispatch") as sp:
                 token = self.solver.solve_async()
             timing.solve_s = sp.dur_s  # dispatch only
-            self._note_upload(timing)
+            self._note_export(timing)
         except BaseException:
             round_span.__exit__(*sys.exc_info())
             raise
@@ -428,8 +439,12 @@ class FlowScheduler:
                 self.gm.add_or_update_job_nodes(jds)
                 timing.graph_tasks_visited = self.gm.tasks_visited
                 timing.graph_tasks_skipped = self.gm.tasks_skipped
+                timing.res_nodes_visited = self.gm.res_nodes_visited
+                timing.res_arcs_changed = self.gm.res_arcs_changed
                 sp.set("graph_tasks_visited", timing.graph_tasks_visited)
                 sp.set("graph_tasks_skipped", timing.graph_tasks_skipped)
+                sp.set("res_nodes_visited", timing.res_nodes_visited)
+                sp.set("res_arcs_changed", timing.res_arcs_changed)
                 ec_nodes = self.gm.task_ec_to_node.values()
                 timing.ec_nodes = len(ec_nodes)
                 timing.ec_arcs = sum(len(node.outgoing) for node in ec_nodes)
@@ -450,8 +465,10 @@ class FlowScheduler:
         rd = self.resource_topology.resource_desc
         return rd.num_slots_below - rd.num_running_tasks_below
 
-    def _note_upload(self, timing: RoundTiming) -> None:
-        """What the device-resident mirror shipped for this round."""
+    def _note_export(self, timing: RoundTiming) -> None:
+        """What the round's export applied, and what the device-resident
+        mirror shipped for it."""
+        timing.journal_changes = self.solver.journal_changes
         res = self.solver.resident
         if res is not None:
             timing.upload_bytes = res.last_upload_bytes
@@ -509,7 +526,11 @@ class FlowScheduler:
                 sp.set("apply_nodes_visited", timing.apply_nodes_visited)
                 sp.set("apply_full_walk", timing.apply_full_walk)
             timing.apply_s = sp.dur_s
-            self.gm.purge_unconnected_equiv_class_nodes()
+            with span("ec_purge") as sp:
+                self.gm.purge_unconnected_equiv_class_nodes()
+                timing.ec_purged = self.gm.ec_purged
+                sp.set("ec_purged", timing.ec_purged)
+                sp.set("ec_arcs_dropped", self.gm.ec_arcs_dropped)
             # Policy feedback: which runnable tasks stayed unscheduled
             # (drives e.g. Quincy's wait-cost starvation bound).
             unscheduled = [
@@ -551,7 +572,7 @@ class FlowScheduler:
             with span("solve") as sp:
                 task_mappings = self.solver.solve()
             timing.solve_s = sp.dur_s
-            self._note_upload(timing)
+            self._note_export(timing)
             return self._finish_round(task_mappings, timing, round_span)
         except BaseException:
             round_span.__exit__(*sys.exc_info())

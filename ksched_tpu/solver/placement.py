@@ -48,6 +48,9 @@ class PlacementSolver:
         #: pinned tasks it left alone (the dispatch's snapshot)
         self.decode_tasks = 0
         self.decode_pinned_skipped = 0
+        #: records of the change journal the last export applied to the
+        #: flat arrays (0 when it built them whole)
+        self.journal_changes = 0
         # ---- device-state integrity (runtime/integrity.py) -----------
         #: audit cadence in exports (0 = off); the service sets it from
         #: --audit-every. On due rounds the post-refresh mirror is
@@ -80,14 +83,19 @@ class PlacementSolver:
         with span("graph_export", kind="full_build" if full else "delta"):
             if full:
                 self._started = True
-                self.state.full_build(gm.cm.graph)
-                gm.cm.reset_changes()
+                with span("journal_apply", kind="full_build", changes=0):
+                    self.state.full_build(gm.cm.graph)
+                    gm.cm.reset_changes()
                 self.backend.reset()
             else:
-                gm.update_all_costs_to_unscheduled_aggs()
-                changes = gm.cm.get_optimized_graph_changes()
-                self.state.apply_changes(changes)
-                gm.cm.reset_changes()
+                with span("journal_collect") as sp:
+                    gm.update_all_costs_to_unscheduled_aggs()
+                    changes = gm.cm.get_optimized_graph_changes()
+                    sp.set("changes", len(changes))
+                with span("journal_apply", kind="delta", changes=len(changes)):
+                    self.state.apply_changes(changes)
+                    gm.cm.reset_changes()
+            self.journal_changes = len(changes) if changes is not None else 0
             # Sink excess is maintained outside the journal (reference:
             # graph_manager.go:636-640); sync it before each solve.
             self.state.set_excess(gm.sink_node.id, gm.sink_node.excess)
@@ -108,7 +116,8 @@ class PlacementSolver:
                 problem = self.resident.refresh()
                 problem = self._integrity_gate(problem)
             else:
-                problem = self.state.problem()
+                with span("problem_snapshot"):
+                    problem = self.state.problem()
         # Byte accounting: in device-resident mode the EXACT nbytes
         # that crossed the boundary (packed records, or the rebuild
         # upload); otherwise from the journal just applied — NOT from
