@@ -49,10 +49,19 @@ CLUSTER_AGGREGATOR_EC = equiv_class_from_bytes(b"CLUSTER_AGG")
 Cost = int
 
 
-#: the methods through which a model could reach a pinned task's node
-_PINNED_TASK_METHODS = (
-    "task_continuation_cost", "prepare_stats", "gather_stats", "update_stats",
-)
+#: what a model may say of itself -> the methods the claim is about: a
+#: subclass that overrides one of them and does not say it again loses
+#: the claim (CostModeler.__init_subclass__)
+_CLAIM_METHODS = {
+    # the methods through which a model could reach a pinned task's node
+    "pinned_tasks_are_inert": (
+        "task_continuation_cost", "prepare_stats", "gather_stats", "update_stats",
+    ),
+    # the two prices a resource node's turn asks for
+    "resource_arc_costs_are_fixed": (
+        "resource_node_to_resource_node_cost", "leaf_resource_node_to_sink_cost",
+    ),
+}
 
 
 class CostModeler(abc.ABC):
@@ -74,6 +83,24 @@ class CostModeler(abc.ABC):
     #: the claim.
     pinned_tasks_are_inert: bool = False
 
+    #: What a model says about itself so the graph manager can take no
+    #: resource-node turn in its per-round update (GraphManager.
+    #: _queue_res_turn): ``resource_node_to_resource_node_cost(src,
+    #: dst)`` and ``leaf_resource_node_to_sink_cost(rid)`` return, for
+    #: the same arguments, the same value in every round. A resource
+    #: node's turn does nothing but ask those two and re-price the arcs
+    #: that are there; it changes no capacity and adds no arc. So with
+    #: the claim the turn is a no-op in the journal and is not taken,
+    #: and this is the precondition: a machine that joins gets its arcs
+    #: priced by the same two hooks where _add_resource_topology_dfs
+    #: makes them, capacities move through update_resource_topology /
+    #: refresh_resource_topology, and a restored checkpoint carries the
+    #: arcs with their costs. False keeps the turn of every resource
+    #: node an EC or a task prefers, and of every node below it. A
+    #: subclass that overrides one of the two methods has to say it
+    #: again for itself; it does not inherit the claim.
+    resource_arc_costs_are_fixed: bool = False
+
     #: 1 while the graph update of the round in progress had to leave
     #: the model's own allotment for a per-pod predicate (the zone
     #: spread model, where a zone is short of room); the scheduler
@@ -82,10 +109,9 @@ class CostModeler(abc.ABC):
 
     def __init_subclass__(cls, **kwargs) -> None:
         super().__init_subclass__(**kwargs)
-        if "pinned_tasks_are_inert" not in cls.__dict__ and any(
-            m in cls.__dict__ for m in _PINNED_TASK_METHODS
-        ):
-            cls.pinned_tasks_are_inert = False
+        for claim, methods in _CLAIM_METHODS.items():
+            if claim not in cls.__dict__ and any(m in cls.__dict__ for m in methods):
+                setattr(cls, claim, False)
 
     # -- arc costs --------------------------------------------------------
 
@@ -211,8 +237,9 @@ class CostModeler(abc.ABC):
         for them on this EC's account. A model may answer only if the
         costs and capacities of its resource -> resource and PU -> sink
         arcs do not depend on the round (the trivial model's are
-        constants); one whose resource arcs follow a census must return
-        None."""
+        constants: ``resource_arc_costs_are_fixed``, under which no
+        resource is queued by anyone); one whose resource arcs follow a
+        census must return None."""
         return None
 
     # -- debug ------------------------------------------------------------

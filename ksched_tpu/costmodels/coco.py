@@ -92,6 +92,8 @@ class CocoCostModel(CostModeler):
     # continuation cost is the constant 0 and the census ignores a
     # non-resource accumulator (base.py)
     pinned_tasks_are_inert = True
+    # resource -> resource and PU -> sink arcs cost the constant 0 (base.py)
+    resource_arc_costs_are_fixed = True
 
     def __init__(
         self,

@@ -20,6 +20,8 @@ class TrivialCostModel(CostModeler):
     # continuation cost is the constant 0 and the three stats hooks
     # return at once for a non-resource accumulator (base.py)
     pinned_tasks_are_inert = True
+    # resource -> resource and PU -> sink arcs cost the constant 0 (base.py)
+    resource_arc_costs_are_fixed = True
 
     UNSCHEDULED_COST = 5  # reference: trivial_cost_modeler.go:41-43
     CLUSTER_AGG_COST = 2  # reference: trivial_cost_modeler.go:69-74

@@ -60,7 +60,9 @@ class _TurnRuns:
     node. The clock is read where the kind of turn changes. The first
     run is a task run and takes in the listing of the task turns. An
     EC node's turn has spans of its own (`ec_chain_refresh`,
-    `ec_refresh`) and only ends a run."""
+    `ec_refresh`) and only ends a run. An update that queues no
+    resource node (GraphManager._queue_res_turn) opens no
+    `res_refresh`."""
 
     TASK, RES = "task_refresh", "res_refresh"
     __slots__ = ("kind", "res_nodes", "res_arcs", "_stats", "_span", "_turn0", "_arcs0")
@@ -129,6 +131,10 @@ class GraphManager:
         #: the cost model can neither re-price a pinned task's one arc
         #: nor learn anything from a task node in the statistics walk
         self._tasks_inert = cost_model.pinned_tasks_are_inert
+        #: a resource node an update meets gets a turn of its own: not
+        #: where the model could only re-price its arcs at the price
+        #: they have (_queue_res_turn)
+        self._res_turns = not cost_model.resource_arc_costs_are_fixed
         #: job id -> task uid -> (tree path, descriptor): the tasks the
         #: per-round update visits. A task is listed while it has or
         #: needs a node, unless it is pinned and the model calls pinned
@@ -148,9 +154,10 @@ class GraphManager:
         self.ec_arcs_changed = 0
         self.ec_chain_arcs_changed = 0
         #: the last add_or_update_job_nodes: resource nodes that took a
-        #: turn, and arcs out of them that it added or whose price
-        #: really changed (a record in the journal, new or merged into
-        #: one that was there; ChangeManager.change_arc drops a no-op)
+        #: turn (_queue_res_turn), and arcs out of them that it added or
+        #: whose price really changed (a record in the journal, new or
+        #: merged into one that was there; ChangeManager.change_arc drops
+        #: a no-op)
         self.res_nodes_visited = 0
         self.res_arcs_changed = 0
         #: the last purge_unconnected_equiv_class_nodes: EC nodes it
@@ -241,7 +248,10 @@ class GraphManager:
 
         The reference walks one FIFO breadth-first: tasks level by
         level, a new child's node added in its parent's turn, and an EC
-        or resource node queued where it is first met. An event here is
+        or resource node queued where it is first met (a resource node
+        only for a model that may re-price its arcs, _queue_res_turn:
+        its `due` key is its own and its turn queues no task and no EC,
+        so the other turns keep their order without it). An event here is
         a listed task's turn (phase 0: update its arcs) or its parent's
         (phase 1: add its node), keyed (depth, job position, path), and
         the sorted events give the tasks' order. A node queued during
@@ -1027,9 +1037,7 @@ class GraphManager:
                 pref_node = self.resource_to_node.get(pref_rid)
                 assert pref_node is not None, "cost model preferred an unknown resource"
                 self._set_equiv_to_res_arc(ec_node, pref_node, cost, cap_upper)
-                if pref_node.id not in marked:
-                    marked.add(pref_node.id)
-                    node_queue.append((pref_node, pref_node.task))
+                self._queue_res_turn(pref_node, node_queue, marked)
         self.ec_arcs_changed += self._remove_invalid_pref_res_arcs(
             ec_node, pref_rids, ChangeType.DEL_ARC_EQUIV_CLASS_TO_RES
         )
@@ -1053,6 +1061,18 @@ class GraphManager:
                 self.cm.delete_arc(arc, ChangeType.DEL_ARC_EQUIV_CLASS_TO_RES, "UpdateEquivToResArcs")
                 self.ec_arcs_changed += 1
 
+    def _queue_res_turn(self, res_node: Node, node_queue: Deque, marked: Set[int]) -> None:
+        """An EC's sweep, a task's preference arcs or its parent's turn
+        met ``res_node``: queue it for a turn of its own, once an
+        update. Not where the model prices its resource arcs at
+        constants (CostModeler.resource_arc_costs_are_fixed): the turn
+        would re-price the arcs out of the node at the price they have,
+        which the journal drops, so nothing is queued and nothing
+        marked, and the update takes task and EC turns alone."""
+        if self._res_turns and res_node.id not in marked:
+            marked.add(res_node.id)
+            node_queue.append((res_node, res_node.task))
+
     def _update_res_outgoing_arcs(self, res_node: Node, node_queue: Deque, marked: Set[int]) -> None:
         """Reference: graph_manager.go:1094-1111."""
         for arc in list(res_node.outgoing.values()):
@@ -1063,9 +1083,7 @@ class GraphManager:
                 res_node.resource_descriptor, arc.dst_node.resource_descriptor
             )
             self.cm.change_arc_cost(arc, cost, ChangeType.CHG_ARC_BETWEEN_RES, "UpdateResOutgoingArcs")
-            if arc.dst_node.id not in marked:
-                marked.add(arc.dst_node.id)
-                node_queue.append((arc.dst_node, arc.dst_node.task))
+            self._queue_res_turn(arc.dst_node, node_queue, marked)
 
     def _update_res_to_sink_arc(self, res_node: Node) -> None:
         """Reference: graph_manager.go:1116-1129."""
@@ -1161,9 +1179,7 @@ class GraphManager:
             elif arc.type != ArcType.RUNNING:
                 # Running arcs are priced by TaskContinuationCost elsewhere.
                 self.cm.change_arc_cost(arc, cost, ChangeType.CHG_ARC_TASK_TO_RES, "UpdateTaskToResArcs")
-            if pref_node.id not in marked:
-                marked.add(pref_node.id)
-                node_queue.append((pref_node, pref_node.task))
+            self._queue_res_turn(pref_node, node_queue, marked)
         self._remove_invalid_pref_res_arcs(task_node, pref_rids, ChangeType.DEL_ARC_TASK_TO_RES)
 
     def _update_task_to_unscheduled_agg_arc(self, task_node: Node) -> Node:

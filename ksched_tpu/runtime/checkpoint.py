@@ -39,8 +39,10 @@ CHECKPOINT_VERSION = 1
 #: ECs listed which as a preference (the purge). 6: and the dirty set
 #: and flags of the post-solve refresh of the resource tree (an older
 #: manifest's graph manager has none: restore falls back to the cold
-#: replay, whose first refresh walks every node)
-WARM_MANIFEST_VERSION = 6
+#: replay, whose first refresh walks every node). 7: and whether its
+#: update gives resource nodes a turn (what the model said of its
+#: resource arcs' prices when the graph manager was built)
+WARM_MANIFEST_VERSION = 7
 
 
 class CheckpointError(RuntimeError):

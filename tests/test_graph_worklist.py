@@ -20,7 +20,7 @@ from ksched_tpu.cli import SchedulerService
 from ksched_tpu.cluster import PodEvent, SyntheticClusterAPI
 from ksched_tpu.costmodels import MODEL_REGISTRY, CostModelType, TrivialCostModel
 from ksched_tpu.data import JobDescriptor, JobState, TaskDescriptor, TaskState, TaskType
-from ksched_tpu.drivers import build_cluster
+from ksched_tpu.drivers import add_machine, build_cluster
 from ksched_tpu.graph.graph_manager import task_needs_node
 from ksched_tpu.obs.spans import SpanTracer
 from ksched_tpu.runtime.trace import RoundTracer
@@ -36,6 +36,7 @@ from ksched_tpu.utils import job_id_from_string, seed_rng
 def _root_down_add_or_update_job_nodes(gm, jobs):
     node_queue = deque()
     marked = set()
+    gm.root_down_res_turns = 0
     for job in jobs:
         jid = job_id_from_string(job.uuid)
         if jid not in gm.job_unsched_to_node:
@@ -64,6 +65,7 @@ def _root_down_add_or_update_job_nodes(gm, jobs):
             gm._update_equiv_class_node(node, node_queue, marked)
         else:
             assert node.is_resource_node
+            gm.root_down_res_turns += 1
             gm._update_res_outgoing_arcs(node, node_queue, marked)
 
 
@@ -104,6 +106,9 @@ def _every_node_statistics(gm, start):
 
 def _use_root_down_walk(sched):
     gm = sched.gm
+    # the walk it was: every resource node an EC or a task prefers takes a
+    # turn, and every node below it, whatever the model says of its prices
+    gm._res_turns = True
     gm.add_or_update_job_nodes = types.MethodType(_root_down_add_or_update_job_nodes, gm)
     gm.compute_topology_statistics = types.MethodType(_every_node_statistics, gm)
 
@@ -113,14 +118,14 @@ def _use_root_down_walk(sched):
 # ---------------------------------------------------------------------------
 
 
-def _admit(sched, jmap, tmap, job_id, uids, parent_uid=None, task_type=TaskType.SHEEP):
+def _admit(sched, jmap, tmap, job_id, uids, parent_uid=None, task_type=TaskType.SHEEP, workload=0):
     """New CREATED tasks under the job's root (the first becomes the
     root), or under the task `parent_uid`; the job is (re-)offered."""
     jd = jmap.find(job_id)
     for uid in uids:
         td = TaskDescriptor(
             uid=uid, name=f"t{uid}", state=TaskState.CREATED, job_id=str(job_id),
-            task_type=task_type,
+            task_type=task_type, workload=workload,
         )
         tmap.insert(uid, td)
         if jd is None:
@@ -176,8 +181,8 @@ class _World:
 
         cm.get_optimized_graph_changes = recording_changes
 
-    def admit(self, job_id, uid, parent_uid, task_type):
-        _admit(self.sched, self.jmap, self.tmap, job_id, [uid], parent_uid, task_type)
+    def admit(self, job_id, uid, parent_uid, task_type, workload=0):
+        _admit(self.sched, self.jmap, self.tmap, job_id, [uid], parent_uid, task_type, workload)
 
     def complete(self, uid):
         self.sched.handle_task_completion(self.tmap.find(uid))
@@ -188,6 +193,10 @@ class _World:
 
     def remove_machine(self, index):
         self.sched.deregister_resource(self.root.children[index])
+
+    def add_machine(self, index):
+        seed_rng(1000 + index)  # the same resource ids in both worlds
+        return add_machine(self.sched, self.rmap, self.root, 2, 2, 6, machine_index=index)
 
     def round(self):
         return self.sched.schedule_all_jobs()
@@ -252,6 +261,10 @@ def test_work_list_gives_the_root_down_walks_problem_and_journal(model, preempti
         assert new.journals == ref.journals and len(new.journals) == step  # round 0 is a full build
         assert new.sched.task_bindings == ref.sched.task_bindings
         gm = new.sched.gm
+        # the walk gave every resource node but the coordinator a turn, the
+        # work list none (these models price their resource arcs at constants)
+        assert (gm.res_nodes_visited, gm.res_arcs_changed) == (0, 0)
+        assert ref.sched.gm.root_down_res_turns == len(gm.resource_to_node) - 1
         assert [n.id for n in gm.task_to_node.values()] == [
             n.id for n in ref.sched.gm.task_to_node.values()
         ]

@@ -6,8 +6,11 @@ A small served cluster under a SpanTracer: every new span lies inside
 its parent in time and by `parent_sid`, the children never exceed the
 parent, a refused audit closes what it opened, each counter equals a
 count made another way, a round opens a `res_refresh` span for each run
-of resource-node turns and not for each node, and with no tracer
-installed nothing is recorded while `RoundTiming` is filled as before.
+of resource-node turns and not for each node (where it takes such turns:
+a model that prices its resource arcs at constants gets none, and the
+served models all do, so the walk is switched back on where its span is
+what is tested), and with no tracer installed nothing is recorded while
+`RoundTiming` is filled as before.
 Placements, problems and objectives under a tracer are those of the
 root-down walk, which opens none of these spans.
 """
@@ -77,15 +80,19 @@ def _pods(tag, n, classes=1):
     return [PodEvent(pod_id=f"{tag}_{i}", task_class=i % classes) for i in range(n)]
 
 
-@pytest.fixture(scope="module", params=["sync", "pipeline"])
+@pytest.fixture(scope="module", params=["sync", "pipeline", "as_served"])
 def traced(request):
     """Four solved rounds of a six-machine service whose every round
     collapses: a fill, two batches with a completion between, and a
-    batch after two idle rounds; then the events and the records."""
+    batch after two idle rounds; then the events and the records.
+    `as_served` is `sync` as the trivial model is served, with no
+    resource-node turn; the other two put every turn back, so that the
+    `res_refresh` span has something to time."""
     seed_rng(0)
     tracer = SpanTracer().install()
     try:
         svc, api = _auto_service(pipeline=request.param == "pipeline")
+        svc.scheduler.gm._res_turns = request.param != "as_served"
         bound = [svc.run_round(_pods("a", 9))]
         bound.append(svc.run_round(_pods("b", 4)))
         svc.complete_pod("a_0")
@@ -103,9 +110,12 @@ def traced(request):
 
 @pytest.mark.parametrize("name", sorted(PARENT))
 def test_a_new_span_lies_inside_its_parent_in_time_and_by_parent_sid(traced, name):
-    _svc, events, records = traced
+    svc, events, records = traced
     by_sid = {e["args"]["sid"]: e for e in events}
     got = _by_name(events).get(name, [])
+    if name == "res_refresh" and not svc.scheduler.gm._res_turns:
+        assert not got  # no turn, and no empty span kept in its place
+        return
     assert got, name
     for ev in got:
         parent = by_sid[ev["args"]["parent_sid"]]
@@ -186,7 +196,8 @@ def test_a_refused_audit_closes_every_span_it_opened():
 
 
 def test_a_round_opens_a_span_for_each_run_of_turns_not_for_each_node(traced):
-    _svc, events, records = traced
+    svc, events, records = traced
+    turns = svc.scheduler.gm._res_turns
     by_name = _by_name(events)
     updates = sorted(by_name["graph_update"], key=lambda e: e["ts"])
     for outer, rec in zip(updates, records):
@@ -195,9 +206,10 @@ def test_a_round_opens_a_span_for_each_run_of_turns_not_for_each_node(traced):
         tasks = [e for e in inside if e["name"] == "task_refresh"]
         # the batch's tasks (in the fill round the job's root, whose turn
         # queues the one EC ahead of its children's), the EC, then every
-        # resource node the sweep queued in one run
-        assert len(tasks) in (1, 2) and len(res) == 1
-        assert sum(e["args"]["nodes"] for e in res) == rec.res_nodes_visited == RES_NODES
+        # resource node the sweep queued in one run: none as served
+        assert len(tasks) in (1, 2) and len(res) == int(turns)
+        assert sum(e["args"]["nodes"] for e in res) == rec.res_nodes_visited
+        assert rec.res_nodes_visited == (RES_NODES if turns else 0)
         assert sum(e["args"]["tasks"] for e in tasks) >= rec.graph_tasks_visited >= 2
         assert sum(e["args"]["arcs_changed"] for e in res) == rec.res_arcs_changed
         assert (outer["args"]["res_nodes_visited"], outer["args"]["res_arcs_changed"]) == (
@@ -210,7 +222,11 @@ def test_a_round_opens_a_span_for_each_run_of_turns_not_for_each_node(traced):
 
 class _Interleaved(TrivialCostModel):
     """No cluster-wide EC: each task prefers two PUs directly, so a
-    deeper job's task turns and the resource turns they queue alternate."""
+    deeper job's task turns and the resource turns they queue alternate.
+    It disclaims the trivial model's constant resource prices: a model
+    that claims them has no resource turn to alternate with."""
+
+    resource_arc_costs_are_fixed = False
 
     def get_task_equiv_classes(self, task_id):
         return []
@@ -249,7 +265,8 @@ def test_runs_alternate_where_the_fifo_alternates_and_their_sizes_add_up():
 
 
 class _RisingSinkCost(TrivialCostModel):
-    """Re-prices the sink arc of every third PU each round."""
+    """Re-prices the sink arc of every third PU each round; overriding
+    the method drops the trivial model's claim of constant prices."""
 
     rounds = 0
 
@@ -286,12 +303,17 @@ def test_the_resource_counters_are_a_sweeps_nodes_and_the_arcs_that_really_chang
         placed, _ = sched.schedule_all_jobs()
         assert placed == 5
         t = sched.last_timing
-        # the EC's sweep queues every machine, and each node its children:
-        # every resource node but the coordinator
-        assert t.res_nodes_visited == gm.res_nodes_visited == len(gm.resource_to_node) - 1
+        # a model that may re-price: the EC's sweep queues every machine, and
+        # each node its children, every resource node but the coordinator;
+        # one whose prices are constants: no resource node takes a turn
+        rising = model is _RisingSinkCost
+        assert model.resource_arc_costs_are_fixed != rising
+        assert t.res_nodes_visited == gm.res_nodes_visited == (
+            len(gm.resource_to_node) - 1 if rising else 0
+        )
         assert t.res_arcs_changed == gm.res_arcs_changed == seen[-1]
     thirds = sum(rid % 3 == 0 for rid in gm.leaf_resource_ids)
-    assert seen[-1] == (thirds if model is _RisingSinkCost else 0)
+    assert seen[-1] == (thirds if rising else 0)
     assert thirds > 0
 
 
@@ -408,7 +430,7 @@ def test_with_no_tracer_installed_nothing_is_recorded_and_the_timing_is_filled()
     t = svc.scheduler.last_timing
     assert t.graph_update_s > 0 and t.solve_s > 0 and t.apply_s > 0
     assert t.total_s >= t.stats_s + t.graph_update_s + t.solve_s + t.deltas_s + t.apply_s
-    assert (t.graph_tasks_visited, t.res_nodes_visited, t.res_arcs_changed) == (3, RES_NODES, 0)
+    assert (t.graph_tasks_visited, t.res_nodes_visited, t.res_arcs_changed) == (3, 0, 0)
     assert t.journal_changes > 0 and t.ec_purged == 0
     rec = svc.tracer.records[-1]
     assert (rec.res_nodes_visited, rec.res_arcs_changed, rec.journal_changes, rec.ec_purged) == (
@@ -461,3 +483,6 @@ def test_under_a_tracer_placements_problems_and_objectives_are_the_root_down_wal
         assert new.sched.task_bindings == ref.sched.task_bindings
         names = {e["name"] for e in tracer.events()}
         assert {"task_refresh", "ec_purge", "journal_apply", "problem_snapshot"} <= names
+        # the walk's every resource turn, and none (and no span for none) here
+        assert ref.sched.gm.root_down_res_turns == len(new.sched.gm.resource_to_node) - 1
+        assert new.sched.gm.res_nodes_visited == 0 and "res_refresh" not in names
