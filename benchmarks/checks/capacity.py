@@ -1,10 +1,12 @@
 """`capacity`: no node ever holds more than cores x pus_per_core x
 max_tasks_per_pu pods.
 
-The run's log of ("bind", pod, node, t) / ("done", pod, "", t), replayed
-in the order the loop thread made them; the first instant a node is over
-its capacity, or a completion of a pod with no Binding on record, is the
-fault.
+The run's log of ("bind", pod, node, t) / ("done", pod, "", t) /
+("evict", pod, node, t), replayed in the order the loop thread made them;
+the first instant a node is over its capacity, a completion of a pod with
+no Binding on record, or an eviction from a node the pod is not on, is the
+fault. An evicted pod leaves its node: a Binding that fills the node again
+is none.
 """
 
 from typing import Dict, List
@@ -32,5 +34,10 @@ def check(ctx) -> List[str]:
             node = where.pop(pod, None)
             if node is None:
                 return [f"pod {pod} completed without a Binding on record"]
+            load[node] -= 1
+        elif kind == "evict":
+            on = where.pop(pod, None)
+            if on != node:
+                return [f"pod {pod} evicted from node {node}, the record has it on {on}"]
             load[node] -= 1
     return []

@@ -6,7 +6,7 @@ it stamps `time.perf_counter()` per pod in `assign_bindings`; it delivers
 the due pod completions through `svc.complete_pod` from inside
 `poll_pod_batch` (the loop thread, between rounds: the only point at which
 tools/soak.py and chip_smoke.py call it); and it keeps an ordered log of
-Bindings and completions for the replay in correct.py.
+Bindings, completions and evictions for the replay in correct.py.
 
 `TrafficDriver` submits what traffic.py planned: the class sweep, the
 warm-up, the window, the drain and the closing round; then it closes the
@@ -18,7 +18,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Set, Tuple
 
 from ksched_tpu.cluster import SyntheticClusterAPI
 from ksched_tpu.cluster.api import Binding, PodEvent
@@ -43,9 +43,12 @@ class BenchClusterAPI(SyntheticClusterAPI):
         self.svc = None  # set once the service is built
         self._due_completions: deque = deque()
         self._log_lock = threading.Lock()
-        #: ("bind", pod, node, t) and ("done", pod, "", t) in the order
-        #: the loop thread made them
+        #: ("bind", pod, node, t), ("done", pod, "", t) and
+        #: ("evict", pod, node, t) in the order the loop thread made them
         self.log: List[Tuple[str, str, str, float]] = []
+        #: the pods whose newest entry in the log is an "evict": pending
+        #: again, so their owner does not complete them
+        self.evicted: Set[str] = set()
         #: pod -> stamps of every Binding posted for it
         self.bind_stamps: Dict[str, List[float]] = {}
         self._outstanding = 0
@@ -109,8 +112,24 @@ class BenchClusterAPI(SyntheticClusterAPI):
                 if not stamps:
                     self._outstanding -= 1
                 stamps.append(t)
+            if self.evicted:
+                self.evicted.difference_update(b.pod_id for b in bindings)
             if self._outstanding <= 0:
                 self._all_bound.set()
+
+    def evict_pods(self, evictions: List[Binding]) -> None:
+        """The service took these pods off their nodes (preemption): each
+        `Binding` names the pod and the node it leaves. One log entry for
+        each, in the loop thread's order, then the parent's method where
+        the program's ClusterAPI has one."""
+        t = time.perf_counter()
+        with self._log_lock:
+            for e in evictions:
+                self.log.append(("evict", e.pod_id, e.node_id, t))
+                self.evicted.add(e.pod_id)
+        parent = getattr(super(), "evict_pods", None)
+        if parent is not None:
+            parent(evictions)
 
 
 class CompileWatch:
@@ -153,10 +172,12 @@ class TrafficDriver(threading.Thread):
     """Plays the plan against the API. One thread, no busy-waiting."""
 
     def __init__(self, api: BenchClusterAPI, plan: Plan, seconds: float,
-                 compiles: CompileWatch) -> None:
+                 compiles: CompileWatch, make_pod: Callable[[str, int], PodEvent]) -> None:
         super().__init__(name="bench-traffic", daemon=True)
         self.api = api
         self.plan = plan
+        #: the cell's pods module over (config, seed): (pod id, class) -> PodEvent
+        self.make_pod = make_pod
         self.seconds = seconds
         self.compiles = compiles
         self.victims: deque = deque(plan.victims)
@@ -174,12 +195,24 @@ class TrafficDriver(threading.Thread):
     # -- helpers -------------------------------------------------------------
 
     def _submit(self, pod: Pod) -> None:
-        self.api.submit_pod(PodEvent(pod_id=pod[0], task_class=pod[1]))
+        # built here, not ahead: the event stamps `received_s` as it is made
+        self.api.submit_pod(self.make_pod(pod[0], pod[1]))
         self.victims.append(pod[0])
 
     def _complete_next(self, n: int) -> None:
         if n:
-            self.api.complete_later([self.victims.popleft() for _ in range(n)])
+            self.api.complete_later([self._next_victim() for _ in range(n)])
+
+    def _next_victim(self) -> str:
+        """The head of `victims`; an evicted pod that is pending again goes
+        to the tail instead (its owner would delete it, not complete it)."""
+        for _ in range(len(self.victims)):
+            pod = self.victims.popleft()
+            if pod not in self.api.evicted:
+                return pod
+            self.victims.append(pod)
+        raise DriverError("no pod is left to complete" + (
+            ": every victim is evicted and pending" if self.victims else ""))
 
     def _wait_bound(self, what: str, timeout_s: float = PHASE_TIMEOUT_S,
                     must: bool = True) -> bool:

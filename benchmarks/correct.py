@@ -18,11 +18,14 @@ from __future__ import annotations
 import importlib
 import time
 from dataclasses import dataclass, field
-from typing import Dict, Iterable, List, Sequence, Tuple
+from typing import Callable, Dict, Iterable, List, Sequence, Tuple
 
 from .traffic import Plan
 
-LogEntry = Tuple[str, str, str, float]  # ("bind" | "done", pod, node, t)
+#: ("bind", pod, node, t): a Binding posted; ("done", pod, "", t): a
+#: completion the service took; ("evict", pod, node, t): the service took
+#: the pod off the node it was bound to, and it is pending again
+LogEntry = Tuple[str, str, str, float]
 
 
 @dataclass(frozen=True)
@@ -31,13 +34,16 @@ class Context:
     config: dict
     #: what traffic.build_plan drew from the seed: every pod's class
     plan: Plan
+    #: the cell's pods module over this run's (config, seed): (pod id, class)
+    #: -> the PodEvent that was submitted, but for its `received_s`
+    make_pod: Callable[[str, int], object]
     svc: object  # cli.SchedulerService, after `run` returned
     svc_args: object  # the configuration's argv, parsed
     #: pod -> (due, submitted) of every pod due in the window
     due: Dict[str, Tuple[float, float]]
     #: pod -> stamps of every Binding posted for it, the whole run
     bind_stamps: Dict[str, List[float]]
-    #: Bindings and completions of the whole run, in the loop's order
+    #: Bindings, completions and evictions of the whole run, in the loop's order
     log: Sequence[LogEntry]
     completions_refused: int
     compiles_in_window: int

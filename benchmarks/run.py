@@ -147,6 +147,24 @@ def build_service(config: dict, traced: bool):
     return svc, api, svc_args, span_tracer, round_tracer
 
 
+def service_shapes(svc, svc_args, config: dict) -> dict:
+    """What the service solved on, as far as it says: `machines` and
+    `task_classes` come from the argv and the configuration; `nodes`,
+    `arcs` (padded, as solved) and the `path` that answered come from the
+    graph path, and are left out for a service that has none."""
+    shapes = {
+        "machines": int(svc_args.num_machines), "task_classes": int(config["task_classes"]),
+    }
+    solver = getattr(getattr(svc, "scheduler", None), "solver", None)
+    if solver is None:
+        return shapes
+    rung = solver.backend.primary if svc.ladder is not None else solver.backend
+    return {
+        "nodes": int(solver.state.n_cap), "arcs": int(solver.state.m_cap), **shapes,
+        "path": getattr(rung, "last_path", None) or "csr",
+    }
+
+
 def end_to_end_values(latency_ms, in_window, w0: float) -> dict:
     from benchmarks import stats
 
@@ -201,7 +219,6 @@ def main(argv=None) -> int:
         fail(str(e))
     devices = require_device(args, cell, jax)
 
-    from ksched_tpu.cluster.api import PodEvent
     from ksched_tpu.utils import device_stamp, enable_compile_cache, seed_rng
 
     # the cache where JAX_COMPILATION_CACHE_DIR says, else <checkout>/.jax_cache
@@ -219,6 +236,8 @@ def main(argv=None) -> int:
         config = spec.rehearsal_config(config)
     seed_rng(args.seed)  # task and job ids are drawn from the framework's RNG
     plan = build_plan(cell.traffic, config, args.seed, args.seconds)
+    # what a pod carries is the configuration's to say (pods/<name>.py)
+    make_pod = spec.pod_maker(cell.pods, config, args.seed)
 
     # a degradation or a NOOP round warns; here they are counted by the
     # service and decide `correct`, so the warning is only kept for the log
@@ -235,10 +254,10 @@ def main(argv=None) -> int:
              f"of {len(plan.resident)} pods")
     api.expect(len(plan.resident))
     for pod_id, task_class in plan.resident:
-        api.submit_pod(PodEvent(pod_id=pod_id, task_class=task_class))
+        api.submit_pod(make_pod(pod_id, task_class))
 
     t_built = time.perf_counter()
-    driver = TrafficDriver(api, plan, args.seconds, compiles)
+    driver = TrafficDriver(api, plan, args.seconds, compiles, make_pod)
     capture = None
     if args.trace:
         trace_dir = os.path.join(SCRATCH, f"trace-{args.workload}")
@@ -285,7 +304,7 @@ def main(argv=None) -> int:
     # -- correct (outside the window) -----------------------------------------
     # one check for every guarantee the configuration states (correct.py)
     ctx = correct.Context(
-        config=config, plan=plan, svc=svc, svc_args=svc_args, due=due,
+        config=config, plan=plan, make_pod=make_pod, svc=svc, svc_args=svc_args, due=due,
         bind_stamps=api.bind_stamps, log=api.log,
         completions_refused=api.completions_refused, compiles_in_window=compiles_in_window,
     )
@@ -293,14 +312,7 @@ def main(argv=None) -> int:
     if not latency_ms:
         faults.append("no pod due in the window was bound")
 
-    backend = svc.scheduler.solver.backend
-    rung = backend.primary if svc.ladder is not None else backend
-    state = svc.scheduler.solver.state
-    shapes = {
-        "nodes": int(state.n_cap), "arcs": int(state.m_cap),
-        "machines": int(svc_args.num_machines), "task_classes": int(config["task_classes"]),
-        "path": getattr(rung, "last_path", None) or "csr",
-    }
+    shapes = service_shapes(svc, svc_args, config)
 
     # -- metrics ------------------------------------------------------------------
     facts = {}

@@ -6,7 +6,17 @@ metric is one file under this directory:
 
   configs/<config>.json        the deployment: source, argv for
                                cli.build_arg_parser(), resident pods,
-                               class mix, guarantees, assumed, reduced
+                               class mix, guarantees, assumed, reduced,
+                               and `pods` where a pod carries more than
+                               its class
+  pods/<name>.py               what a pod of the deployment carries:
+                               `make(pod_id, task_class, config, seed)
+                               -> PodEvent`, a pure function of its four
+                               arguments that draws from no shared
+                               generator (the framework's RNG feeds the
+                               task and job ids), called at the moment of
+                               submission; `class_only` where a
+                               configuration names none
   traffic/<mix>.json           parameters of one traffic mix, read by the
                                one general generator (traffic.py)
   checks/<guarantee>.py        one guarantee a configuration states:
@@ -20,16 +30,19 @@ metric is one file under this directory:
 
 A later PR adds files and entries; it edits none that is here. A
 configuration that states a guarantee with no module, or none at all, is
-refused by `load_cell`: nothing can be stated and left unchecked.
+refused by `load_cell`: nothing can be stated and left unchecked. So is
+one that names a `pods` module that is not there.
 """
 
 from __future__ import annotations
 
+import functools
+import importlib
 import json
 import os
 import re
 from dataclasses import dataclass
-from typing import Dict, List
+from typing import Callable, Dict, List
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -37,6 +50,9 @@ ROOT = os.path.dirname(HERE)
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+
+#: the pods of a configuration that names no `pods` module
+DEFAULT_PODS = "class_only"
 
 #: --rehearse-cpu divides machines, resident pods and wave sizes by this
 REHEARSE_DIVISOR = 40
@@ -61,6 +77,7 @@ class Cell:
     config: dict
     traffic: dict
     chips: int
+    pods: str  # the module under pods/ that builds this deployment's PodEvents
     end_to_end: List[dict]
     per_layer: List[dict]  # BENCHMARK.json entries merged over their own files
 
@@ -78,6 +95,7 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     configs = {c["name"]: c for c in bench["configs"]}
     config = _load(os.path.join(root, configs[w["config"]]["file"]))
     check_guarantees(config, configs[w["config"]]["file"])
+    pods = check_pods(config, configs[w["config"]]["file"])
     traffic = _load(os.path.join(HERE, "traffic", w["traffic"] + ".json"))
     e2e = [m for m in bench["end_to_end"] if _in_cell(m, name)]
     e2e_names = {m["name"] for m in e2e}
@@ -94,7 +112,7 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
                     f"BENCHMARK.json says {m[key]!r}"
                 )
         per_layer.append(own)
-    return Cell(name, config, traffic, int(w["chips"]), e2e, per_layer)
+    return Cell(name, config, traffic, int(w["chips"]), pods, e2e, per_layer)
 
 
 def check_guarantees(config: dict, file: str) -> None:
@@ -108,6 +126,25 @@ def check_guarantees(config: dict, file: str) -> None:
                 f"{file} states the guarantee {key!r} and there is no "
                 f"benchmarks/checks/{key}.py to hold a run to it"
             )
+
+
+def check_pods(config: dict, file: str) -> str:
+    """The name of the configuration's `pods` module, which is there."""
+    name = config.get("pods", DEFAULT_PODS)
+    path = os.path.join(HERE, "pods", f"{name}.py")
+    if not isinstance(name, str) or not NAME_RE.match(name) or not os.path.isfile(path):
+        raise SpecError(
+            f"{file} names the pods module {name!r} and there is no "
+            f"{os.path.relpath(path, os.path.dirname(HERE))} to build its pods"
+        )
+    return name
+
+
+def pod_maker(name: str, config: dict, seed: int) -> Callable:
+    """`make_pod(pod_id, task_class) -> PodEvent`: pods/<name>.py's `make`
+    over the configuration as it is run and the run's seed."""
+    make = importlib.import_module(f"benchmarks.pods.{name}").make
+    return functools.partial(make, config=config, seed=seed)
 
 
 def rehearsal_config(config: dict) -> dict:

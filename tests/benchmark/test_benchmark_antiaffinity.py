@@ -194,18 +194,18 @@ def test_a_stream_served_as_the_harness_serves_it_passes_the_replay():
     from benchmarks.checks import anti_affinity, binding, capacity
     from benchmarks.client import CompileWatch, TrafficDriver
     from benchmarks.traffic import build_plan
-    from ksched_tpu.cluster.api import PodEvent
     from ksched_tpu.utils import seed_rng
 
     cell = spec.load_cell(CELL)
     config = spec.rehearsal_config(cell.config)
     seed_rng(SEED)
     plan = build_plan(cell.traffic, config, SEED, 2.0)
+    make_pod = spec.pod_maker(cell.pods, config, SEED)
     svc, api, svc_args, _spans, _rounds = run.build_service(config, traced=False)
     api.expect(len(plan.resident))
     for pod_id, task_class in plan.resident:
-        api.submit_pod(PodEvent(pod_id=pod_id, task_class=task_class))
-    driver = TrafficDriver(api, plan, 2.0, CompileWatch())
+        api.submit_pod(make_pod(pod_id, task_class))
+    driver = TrafficDriver(api, plan, 2.0, CompileWatch(), make_pod)
     driver.start()
     watchdog = threading.Timer(120.0, api.close)  # a hung loop ends, and the test fails below
     watchdog.start()
@@ -223,8 +223,9 @@ def test_a_stream_served_as_the_harness_serves_it_passes_the_replay():
     assert len({group_of[e[1]] for e in binds}) == 16
     assert check_anti_affinity(api.log, group_of) is None
     ctx = correct.Context(
-        config=config, plan=plan, svc=svc, svc_args=svc_args, due=driver.due,
-        bind_stamps=api.bind_stamps, log=api.log, completions_refused=0, compiles_in_window=0,
+        config=config, plan=plan, make_pod=make_pod, svc=svc, svc_args=svc_args,
+        due=driver.due, bind_stamps=api.bind_stamps, log=api.log, completions_refused=0,
+        compiles_in_window=0,
     )
     assert anti_affinity.check(ctx) == [] and binding.check(ctx) == []
     assert capacity.check(ctx) == [] and ctx.facts["capacity"]["node_capacity"] == 110

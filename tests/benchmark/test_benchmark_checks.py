@@ -79,7 +79,8 @@ def _ctx(mix=TRICKLE, **over):
     first = plan.resident[0][0]
     log.append(("done", first, "", 100.0))
     fields = dict(
-        config=CONFIG, plan=plan, svc=_svc(mirror=_mirror()),
+        config=CONFIG, plan=plan, make_pod=spec.pod_maker(spec.DEFAULT_PODS, CONFIG, 11),
+        svc=_svc(mirror=_mirror()),
         svc_args=SimpleNamespace(cores_per_machine=1, pus_per_core=1, max_tasks_per_pu=2),
         due={pod: (0.0, 0.0) for pod, _g in pods[8:]},
         bind_stamps={pod: [1.0] for pod, _g in pods}, log=log,
@@ -103,10 +104,16 @@ def test_every_guarantee_a_configuration_states_resolves_to_a_check(config, guar
     assert callable(module.check) and guarantee in module.__doc__.split(":")[0]
 
 
-def test_the_guarantees_stated_today_are_these_five_and_each_cell_loads():
-    assert {g for _c, g in GUARANTEES} == {
-        "binding", "capacity", "answer", "resident", "anti_affinity",
+def test_the_guarantees_stated_today_are_the_check_modules_and_each_cell_loads():
+    """What the configurations state is what `checks/` holds a module for,
+    no more and no less: an equality a new guarantee, which comes with its
+    module, keeps true."""
+    modules = {
+        name[:-3] for name in os.listdir(os.path.join(spec.HERE, "checks"))
+        if name.endswith(".py") and not name.startswith("_")
     }
+    assert {g for _c, g in GUARANTEES} == modules
+    assert modules >= {"binding", "capacity", "answer", "resident", "anti_affinity"}
     for w in BENCH["workloads"]:
         assert spec.load_cell(w["name"]).config["guarantees"]
 

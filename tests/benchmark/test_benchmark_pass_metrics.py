@@ -19,9 +19,6 @@ from benchmarks import observe, spec
 BENCH = spec.load_benchmark()
 CELLS = [w["name"] for w in BENCH["workloads"]]
 COCO = [c for c in CELLS if c.startswith("coco-50kx1k.")]
-#: `res_refresh` opens only in a round whose update queues a resource node:
-#: where a model's record of what changed serves every EC, none does
-NO_RES_TURN = ["k8s-5000-zonespread.trickle"]
 GU, SD, DAP = "graph update / export", "solver dispatch", "decode / apply / post"
 SPAN, FIELD = ("span_sum", "program_span"), ("round_field", "program_counter")
 AUDIT = {
@@ -37,7 +34,6 @@ def _span(name, layer, cells, expected):
 #: metric -> (reader and source, params, unit, layer, cells, value on ROUNDS / RECORDS)
 NEW = {
     "task_refresh_ms": _span("task_refresh", GU, CELLS, 0.6),
-    "res_refresh_ms": _span("res_refresh", GU, [c for c in CELLS if c not in NO_RES_TURN], 15.0),
     "journal_collect_ms": _span("journal_collect", GU, CELLS, 0.3),
     "journal_apply_ms": _span("journal_apply", GU, CELLS, 0.5),
     "problem_snapshot_ms": _span("problem_snapshot", GU, CELLS, 0.2),
@@ -56,13 +52,14 @@ NEW = {
                          CELLS, 1.0),
 }
 #: two solved rounds and an idle sweep, as the tracers give them; a span that
-#: opens once a run or once an EC node arrives summed over the round
+#: opens once a run or once an EC node arrives summed over the round (no
+#: resource node takes a turn since PR 36: what is not the tasks' is the ECs')
 ROUNDS = [
-    {"round": 60.0, "graph_update": 17.0, "task_refresh": 0.4, "res_refresh": 14.0,
+    {"round": 60.0, "graph_update": 17.0, "task_refresh": 0.4, "ec_refresh": 14.0,
      "collapse_audit": 25.0, "graph_export": 1.0, "journal_collect": 0.2, "journal_apply": 0.4,
      "problem_snapshot": 0.1, "ec_purge": 0.04,
      **{name: value - 0.5 for name, value in AUDIT.items()}},
-    {"round": 62.0, "graph_update": 19.0, "task_refresh": 0.8, "res_refresh": 16.0,
+    {"round": 62.0, "graph_update": 19.0, "task_refresh": 0.8, "ec_refresh": 16.0,
      "collapse_audit": 27.0, "graph_export": 1.2, "journal_collect": 0.4, "journal_apply": 0.6,
      "problem_snapshot": 0.3, "ec_purge": 0.06,
      **{name: value + 0.5 for name, value in AUDIT.items()}},
@@ -138,8 +135,7 @@ def test_the_children_name_each_span_once_and_none_their_parents():
     """The parts of `graph_refresh_ms`, `collapse_audit_ms` and
     `graph_export_ms` name spans one level below those metrics' own, each
     once; the metrics that time the same work from one level up stay."""
-    refresh = _spans("task_refresh_ms") + _spans("res_refresh_ms") + _spans("ec_refresh_ms")
-    refresh += _spans("ec_chain_refresh_ms")
+    refresh = _spans("task_refresh_ms") + _spans("ec_refresh_ms") + _spans("ec_chain_refresh_ms")
     audit = [s for name in AUDIT for s in _spans(f"{name}_ms")]
     export = _spans("journal_collect_ms") + _spans("journal_apply_ms")
     export += _spans("problem_snapshot_ms")
@@ -165,5 +161,5 @@ def test_the_parts_close_on_the_synthetic_rounds():
     assert 0.95 * value("collapse_audit_ms") <= audit <= value("collapse_audit_ms") + 1e-9
     export = value("journal_collect_ms") + value("journal_apply_ms") + value("problem_snapshot_ms")
     assert 0.9 * value("graph_export_ms") <= export <= value("graph_export_ms")
-    refresh = value("task_refresh_ms") + value("res_refresh_ms")
+    refresh = value("task_refresh_ms") + value("ec_refresh_ms")
     assert 0.85 * value("graph_refresh_ms") <= refresh <= value("graph_refresh_ms")

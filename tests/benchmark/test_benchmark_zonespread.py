@@ -107,10 +107,9 @@ def test_the_cell_takes_one_chip_and_the_trickle_as_it_stands():
 
 
 def test_the_guarantees_stated_today_are_the_check_modules_and_each_cell_loads():
-    """Both halves of what `test_benchmark_checks.py` pinned as "these
-    five" (conftest.py), the first as an equality a seventh guarantee
-    keeps true: what the configurations state is what `checks/` holds a
-    module for, no more and no less, and the six of today are among them."""
+    """What the configurations state is what `checks/` holds a module for,
+    no more and no less (the form `test_benchmark_checks.py`'s test of this
+    name took from here, PR 37), and the six of today are among them."""
     stated = set()
     for entry in BENCH["configs"]:
         with open(os.path.join(ROOT, entry["file"])) as f:
