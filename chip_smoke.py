@@ -63,6 +63,19 @@ speed:
            node's zone from the label the service holds) finds no round
            that left a receiving zone more than maxSkew above the lowest,
            and nothing compiled after the first trickle round.
+  preemption  the benchmark's `k8s-5000-preemption` deployment from its
+           file's argv (5,000 machines x 4 slots, `--cost-model
+           k8s_priority --preemption --backend jax`): the fill of
+           20,000 low pods, exactly full, then rounds of high pods, each
+           placed only by an eviction, the later ones with completions
+           that hand evicted pods their slots back. Per round: the
+           objective equal to the native C++ solver's on
+           `state.problem()`, and the pods bound and evicted BY TIER
+           equal to the plain reference's greedy
+           (benchmarks/reference_preemption.reference_round) on the
+           smoke's own books. At the end: the replay of the log
+           (check_priority_preemption), no pod moved, no step down the
+           ladder, and nothing compiled after the first trickle round.
 
 `--only PHASE` (repeatable) runs the named phases alone.
 
@@ -100,6 +113,7 @@ FULL = dict(
     resident=dict(scale=1, trickle=(26, 31, 12, 58, 40, 9, 60, 22), waves=4),
     antiaffinity=dict(scale=1, trickle=(30, 55, 12, 80, 41, 9, 64, 22, 50, 37) * 2),
     zonespread=dict(scale=1, trickle=(30, 55, 12, 80, 41, 9, 64, 22, 50, 37) * 2),
+    preemption=dict(scale=1, trickle=(1, 30, 120, 55, 200, 9, 80, 0, 150, 41) * 2),
 )
 TINY = dict(
     served=dict(machines=20, pods=200, churn=10),
@@ -109,6 +123,7 @@ TINY = dict(
     resident=dict(scale=40, trickle=(2, 5, 1, 9, 3), waves=3),
     antiaffinity=dict(scale=40, trickle=(3, 6, 1, 9, 4)),
     zonespread=dict(scale=40, trickle=(3, 6, 1, 9, 4)),
+    preemption=dict(scale=40, trickle=(1, 3, 12, 0, 6, 20)),
 )
 
 
@@ -750,8 +765,112 @@ class Smoke:
         )
 
 
+    def preemption(self) -> str:
+        """`k8s-5000-preemption`: the first served rounds with
+        `--preemption` on the chip, against native C++ and the plain
+        reference's greedy, tier by tier."""
+        from benchmarks.client import BenchClusterAPI
+        from benchmarks.reference_preemption import check_priority_preemption, reference_round
+        from ksched_tpu.cluster.api import PodEvent
+        from ksched_tpu.solver.select import make_backend
+
+        name = "k8s-5000-preemption"
+        compiles = self._compile_events()
+        sz = self.sizes["preemption"]
+        config, args, api, svc = self._config_service(name, sz["scale"], BenchClusterAPI)
+        api.svc = svc
+        solver = svc.scheduler.solver
+        rung = solver.backend.primary
+        native = make_backend("native", warm_start=False, fallback=False)
+        capacity = args.cores_per_machine * args.pus_per_core * args.max_tasks_per_pu
+        total = args.num_machines * capacity
+        low, high = config["priority_by_role"]["fill"], config["priority_by_role"]["measured"]
+        tier_of: dict = {}
+        bound: dict = {}
+        pending: list = []
+        mark = late = 0
+
+        def by_tier(pods):
+            counts = [0] * (high + 1)
+            for p in pods:
+                counts[tier_of[p]] += 1
+            return counts
+
+        plan = [(config["resident_pods"] // sz["scale"], low)] + [(n, high) for n in sz["trickle"]]
+        for r, (arrivals, tier) in enumerate(plan):
+            # from the fourth trickle round on, a tenth as many of the
+            # oldest bound pods complete, five at the least: in the round
+            # without arrivals evicted pods get slots back
+            done = sorted(bound, key=lambda p: int(p[4:]))[: max(arrivals // 10, 5) if r > 3 else 0]
+            for p in done:
+                del bound[p]
+            api.complete_later(done)
+            for _ in range(arrivals):
+                pod = f"pod_{len(tier_of)}"
+                tier_of[pod] = tier
+                pending.append(pod)
+                api.submit_pod(PodEvent(pod_id=pod, priority=tier))
+            want = reference_round(total - len(bound), by_tier(bound), by_tier(pending))
+            pods = api.poll_pod_batch(0.2)
+            check(len(pods) == arrivals, f"{name}: {len(pods)} pods arrived, {arrivals} sent")
+            before = len(compiles)
+            t0 = time.perf_counter()
+            svc.run_round(pods)
+            wall = time.perf_counter() - t0
+            if r > 1:
+                late += len(compiles) - before
+            evicted, placed = [], []
+            for kind, pod, node, _t in api.log[mark:]:
+                if kind == "evict":
+                    check(bound.pop(pod, None) == node, f"{name} round {r}: {pod} evicted from {node}")
+                    pending.append(pod)
+                    evicted.append(pod)
+                elif kind == "bind":
+                    bound[pod] = node
+                    pending.remove(pod)
+                    placed.append(pod)
+            mark = len(api.log)
+            got = (by_tier(placed), by_tier(evicted))
+            check(got == want, f"{name} round {r}: bound, evicted by tier {got}, the greedy's {want}")
+            check(api.completions_refused == 0, f"{name} round {r}: a completion was refused")
+            check(svc.noop_rounds == 0, f"{name} round {r}: a NOOP round")
+            ours = int(solver.last_result.objective)
+            theirs = int(native.solve(solver.state.problem()).objective)
+            check(ours == theirs, f"{name} round {r}: objective {ours} != native {theirs}")
+            t = svc.scheduler.last_timing
+            migrated = len(set(evicted) & set(placed))
+            self.say(
+                f"preemption round {r}: pods={arrivals} completions={len(done)} "
+                f"bound={got[0]} evicted={got[1]} pending={by_tier(pending)} wall_ms={wall * 1e3:.1f} "
+                f"graph_update_ms={t.graph_update_s * 1e3:.1f} solve_ms={t.solve_s * 1e3:.1f} "
+                f"deltas_ms={t.deltas_s * 1e3:.1f} apply_ms={t.apply_s * 1e3:.1f} "
+                f"supersteps={int(rung.last_supersteps)} objective={ours} "
+                f"tasks_unpinned={t.tasks_unpinned} decode_tasks={t.decode_tasks} "
+                f"migrated={migrated}"
+            )
+            check(migrated == 0, f"{name} round {r}: {migrated} pods moved for nothing")
+        check(svc.ladder.degradations_total == 0, f"{name}: a step down the ladder")
+        check(late == 0, f"{name}: {late} programs compiled after the first trickle round")
+        faults, facts = check_priority_preemption(
+            api.log, tier_of, capacity, num_nodes=args.num_machines
+        )
+        check(not faults, f"{name}: {faults}")
+        check(facts["evicted_then_bound_again"] > 0, f"{name}: no evicted pod was bound again")
+        api.close()
+        st = solver.state
+        return (
+            f"machines={args.num_machines} nodes={st.n_cap} arcs={st.m_cap} "
+            f"entries={st.plan.entry_cap} rounds={len(plan)} objectives==native and "
+            f"bound / evicted by tier == the greedy's in every round; {facts['replayed']} entries "
+            f"replayed: bound {facts['bound_by_tier']}, evicted {facts['evicted_by_tier']}, "
+            f"{facts['evicted_then_bound_again']} evicted pods bound again, most evictions a "
+            f"round {facts['most_evictions_a_round']}; compiles after the first trickle round: {late}"
+        )
+
+
 PHASES = (
     "served", "array", "kernels", "general", "sharded", "resident", "antiaffinity", "zonespread",
+    "preemption",
 )
 
 

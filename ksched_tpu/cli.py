@@ -19,7 +19,7 @@ import threading
 import time
 import urllib.error
 import warnings
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 from .cluster import Binding, ClusterAPI, NodeEvent, PodEvent, SyntheticClusterAPI
 from .cluster.api import RETRY_STAT_KEYS
@@ -85,9 +85,37 @@ class SchedulerService:
         tenant: str = "",
         audit_every: int = 0,
         fake_zones: int = 0,
+        preemption: bool = False,
         _restored: Optional[Tuple] = None,
     ) -> None:
+        if preemption and backend_name in ("auto", "ell"):
+            raise ValueError(
+                f"--preemption is served by --backend jax (or native, ref): under "
+                f"--backend {backend_name} a round whose running tasks keep their arcs "
+                "would be solved by a rung without the global price update, which does "
+                "not end on a full cluster (solver/jax_solver.py, price_update_every)"
+            )
+        if preemption and (pipeline or device_resident):
+            raise ValueError(
+                "--preemption is served on the synchronous host path only: with "
+                "--pipeline a round's evictions would be posted a dispatch window "
+                "after the Bindings that take their slots, and --device-resident "
+                "has never carried a graph whose running tasks keep their arcs; "
+                "drop " + " and ".join(
+                    f for f, on in (("--pipeline", pipeline), ("--device-resident", device_resident)) if on
+                )
+            )
         self.api = api
+        #: --preemption: no running task is pinned, and a round may take
+        #: a pod off its node (an eviction, posted through
+        #: ClusterAPI.evict_pods before the round's Bindings)
+        self.preemption = preemption
+        #: tasks evicted and not bound again since
+        self._evicted_pending: set = set()
+        #: what the last collected round evicted, and how many of those
+        #: it bound elsewhere in the same round (RoundRecord)
+        self._pods_evicted = 0
+        self._pods_migrated = 0
         #: --fake-zones: the fake machines of init_topology carry a zone
         #: label, machine i that of zone i mod fake_zones (0: no label)
         self.fake_zones = fake_zones
@@ -148,6 +176,7 @@ class SchedulerService:
                 max_tasks_per_pu=max_tasks_per_pu,
                 cost_model_factory=MODEL_REGISTRY[cost_model],
                 backend=backend,
+                preemption=preemption,
                 device_resident=device_resident,
             )
         else:
@@ -294,6 +323,7 @@ class SchedulerService:
         self.pod_to_task.pop(pod_id, None)
         self.task_to_pod.pop(task_id, None)
         self.old_bindings.pop(task_id, None)
+        self._evicted_pending.discard(task_id)
         # freed capacity may admit waiting unbound pods: wake the
         # quiet-channel loop for a re-solve. Unconditional — a spurious
         # re-solve on the next quiet poll is near-free, while scanning
@@ -306,7 +336,11 @@ class SchedulerService:
     def _add_pod(self, pod: PodEvent) -> None:
         try:
             # what the class is on a descriptor is the cost model's to say
-            of_class = self.scheduler.cost_model.task_class_fields(pod.task_class)
+            model = self.scheduler.cost_model
+            of_class = {
+                **model.task_class_fields(pod.task_class),
+                **model.task_priority_fields(pod.priority),
+            }
         except ValueError as e:
             raise ValueError(f"pod {pod.pod_id}: {e}") from None
         existing = self.pod_to_task.get(pod.pod_id)
@@ -380,29 +414,66 @@ class SchedulerService:
 
     # -- the main loop ----------------------------------------------------
 
-    def _collect_bindings(self) -> List[Binding]:
-        """Diff the scheduler's bindings against what was last emitted
-        and translate new/changed ones into pod→node bindings."""
+    def _node_of(self, pu_rid: int) -> Optional[str]:
+        """The node a PU belongs to, as the control plane names it."""
+        machine_rid = self._find_parent_machine(pu_rid)
+        return None if machine_rid is None else self.machine_to_node[machine_rid]
+
+    def _collect_bindings(self) -> Tuple[List[Binding], List[Binding]]:
+        """Diff the scheduler's bindings against what was last emitted:
+        (evictions, Bindings). A new or changed binding is a Binding to
+        post. Under `--preemption` a task of `old_bindings` that the
+        service still knows and the scheduler no longer binds was
+        evicted, and one it binds elsewhere migrated: both are an
+        eviction from the node the pod leaves (the second with its
+        Binding). An evicted pod stays the service's, pending, the same
+        task; `complete_pod` of it is refused until it is bound again."""
         with span("bindings_collect") as sp:
             new_bindings = self.scheduler.get_task_bindings()
+            evictions: List[Binding] = []
+            migrated = 0
+            if self.preemption:
+                for task_id, pu_rid in self.old_bindings.items():
+                    now = new_bindings.get(task_id)
+                    if now == pu_rid:
+                        continue  # where it was
+                    pod_id = self.task_to_pod.get(task_id)
+                    if pod_id is None:
+                        continue  # completed, not evicted
+                    node_id = self._node_of(pu_rid)
+                    if node_id is None:
+                        continue  # the node left, and its pods with it
+                    evictions.append(Binding(pod_id=pod_id, node_id=node_id))
+                    if now is None:
+                        self._evicted_pending.add(task_id)
+                    else:
+                        migrated += 1
             out = []
             for task_id, pu_rid in new_bindings.items():
                 if self.old_bindings.get(task_id) == pu_rid:
                     continue
-                machine_rid = self._find_parent_machine(pu_rid)
-                if machine_rid is None:
+                node_id = self._node_of(pu_rid)
+                if node_id is None:
                     continue
                 pod_id = self.task_to_pod.get(task_id)
                 if pod_id is None:
                     continue
-                out.append(Binding(pod_id=pod_id, node_id=self.machine_to_node[machine_rid]))
+                out.append(Binding(pod_id=pod_id, node_id=node_id))
+                self._evicted_pending.discard(task_id)
             self.old_bindings = dict(new_bindings)
+            self._pods_evicted, self._pods_migrated = len(evictions), migrated
             sp.set("resident", len(new_bindings))
             sp.set("new", len(out))
-        return out
+            sp.set("evicted", len(evictions))
+        return evictions, out
 
-    def _post_bindings(self, out: List[Binding]) -> None:
-        """The POST, under the one name it has on every path."""
+    def _post_bindings(self, out: List[Binding], evictions: Sequence[Binding] = ()) -> None:
+        """The POST, under the one name it has on every path; before it,
+        the round's evictions in one call, so that the control plane
+        never sees a node over its capacity."""
+        if evictions:
+            with span("evictions_post", n=len(evictions)):
+                self.api.evict_pods(evictions)
         if out:
             with span("bindings_post", n=len(out)):
                 self.api.assign_bindings(out)
@@ -440,8 +511,8 @@ class SchedulerService:
         t0 = time.perf_counter()
         self.scheduler.schedule_all_jobs()
         self.round_latencies_s.append(time.perf_counter() - t0)
-        out = self._collect_bindings()
-        self._post_bindings(out)
+        evictions, out = self._collect_bindings()
+        self._post_bindings(out, evictions)
         return len(out)
 
     def _run_once_pipelined(self) -> int:
@@ -482,7 +553,7 @@ class SchedulerService:
                 raise flush_err from finish_err
             raise
         self.round_latencies_s.append(time.perf_counter() - t0)
-        out = self._collect_bindings()
+        _none, out = self._collect_bindings()  # no eviction: preemption is off
         self._defer_bindings(out)
         if flush_err is not None:
             raise flush_err
@@ -637,13 +708,13 @@ class SchedulerService:
         deadline_miss = self.watchdog.fired
         self.round_latencies_s.append(time.perf_counter() - st["t0"])
         if not noop:
-            out = self._collect_bindings()
+            evictions, out = self._collect_bindings()
             if self.pipeline:
                 # per-tenant dispatch window: the POSTs ride the NEXT
                 # round's batched-solve window (cell.post_window)
                 self._defer_bindings(out)
             else:
-                self._post_bindings(out)
+                self._post_bindings(out, evictions)
             bound = len(out)
         # a round with no runnable work (token None) dispatched no
         # solve: record it as an idle sweep (solver_rung -1, zeroed
@@ -734,12 +805,17 @@ class SchedulerService:
                         queue_wait_ms=queue_wait[0],
                         queue_wait_max_ms=queue_wait[1],
                         post_defer_ms=self._post_defer_ms if solve else 0.0,
+                        # consumed by the solved round's record, below
+                        pods_evicted=self._pods_evicted if solve else 0,
+                        pods_migrated=self._pods_migrated if solve else 0,
+                        pods_pending_evicted=len(self._evicted_pending),
                     ),
                 )
             if solve:
                 # consumed by the solved round's record: an idle sweep
                 # that flushed leaves it for the round that follows
                 self._post_defer_ms = 0.0
+                self._pods_evicted = self._pods_migrated = 0
         return rec
 
     def run(self, pod_batch_timeout_s: float = 2.0, max_rounds: Optional[int] = None) -> None:
@@ -1220,6 +1296,15 @@ def build_arg_parser() -> argparse.ArgumentParser:
                     "in round N+1's dispatch window (or by the idle sweep "
                     "after a quiet poll): a pod waits that much longer "
                     "for its Binding (RoundRecord.post_defer_ms)")
+    ap.add_argument("--preemption", action="store_true",
+                    help="no running pod is pinned: a round may take a pod "
+                    "off its node for one the cost model prices higher "
+                    "(--cost-model k8s_priority: PodEvent.priority), posted "
+                    "through ClusterAPI.evict_pods before the round's "
+                    "Bindings; the evicted pod stays pending and is bound "
+                    "again when a slot frees. Served by --backend jax, native "
+                    "or ref; not with --pipeline, --device-resident, "
+                    "--backend auto or ell")
     ap.add_argument("--device-resident", action="store_true",
                     help="keep the flow problem's arrays live on device "
                     "between rounds: after the first full upload only "
@@ -1276,7 +1361,7 @@ def build_service(
         api,
         max_tasks_per_pu=args.max_tasks_per_pu,
         cost_model=CostModelType[args.cost_model.upper()],
-        backend=make_backend(args.backend),
+        backend=make_backend(args.backend, preemption=args.preemption),
         backend_name=args.backend,
         degrade=not args.no_degrade,
         round_deadline_s=args.round_deadline,
@@ -1287,6 +1372,7 @@ def build_service(
         device_resident=args.device_resident,
         audit_every=args.audit_every,
         fake_zones=args.fake_zones,
+        preemption=args.preemption,
     )
 
 
@@ -1375,9 +1461,14 @@ def main(argv=None) -> int:
         )
     else:
         api = SyntheticClusterAPI(pod_chan_size=args.pod_chan_size)
-    svc = build_service(
-        args, api, tracer=tracer, flight=flight, span_tracer=span_tracer
-    )
+    try:
+        svc = build_service(
+            args, api, tracer=tracer, flight=flight, span_tracer=span_tracer
+        )
+    except ValueError as e:
+        # a pair of flags the service refuses (--preemption with
+        # --pipeline; --cost-model k8s_priority without --preemption)
+        ap.error(str(e))
     if args.machine_timeout > 0:
         svc.enable_heartbeats(machine_timeout_s=args.machine_timeout)
     n = svc.init_topology(

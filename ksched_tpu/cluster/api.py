@@ -26,6 +26,11 @@ class PodEvent:
     cpu_request: float = 0.0
     net_bw_request: int = 0
     task_class: int = 0
+    #: the pod's priority as the cost model reads it (`spec.priority`
+    #: brought down to a small whole tier by whoever surfaces the pod;
+    #: it rides the task as `TaskDescriptor.priority`). 0 everywhere
+    #: but under a model that prices it (--cost-model k8s_priority)
+    priority: int = 0
     #: perf_counter stamp of the moment the control plane surfaced the
     #: pod (every source constructs the event then); the service round
     #: that admits it reads its queue wait from this. Not part of the
@@ -98,6 +103,17 @@ class ClusterAPI(abc.ABC):
     @abc.abstractmethod
     def assign_bindings(self, bindings: List[Binding]) -> None:
         """Push pod→node placements to the control plane."""
+
+    def evict_pods(self, evictions: List[Binding]) -> None:
+        """The scheduler took these pods off their nodes (`--preemption`):
+        each `Binding` names a pod and the node it leaves. One call a
+        round, from the loop thread, BEFORE that round's
+        `assign_bindings`, so a control plane never sees a node over its
+        capacity; a pod that moves is an eviction from the old node and
+        a Binding to the new one. The evicted pod stays the scheduler's
+        (pending, the same task) and gets a new Binding when it is
+        placed again. Default: a no-op (an adapter that cannot evict
+        yet: the HTTP one, docs/PARITY.md)."""
 
     def close(self) -> None:
         """Stop delivering events; get_*_batch return [] afterwards."""

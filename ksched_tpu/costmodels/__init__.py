@@ -2,6 +2,7 @@ from .base import CLUSTER_AGGREGATOR_EC, Cost, CostModeler, CostModelType
 from .census import CLASS_ECS, NUM_TASK_CLASSES, ClassCensusKeeper, class_ec, ec_class
 from .coco import CocoCostModel, coco_cost_matrix
 from .k8s_antiaffinity import K8sAntiAffinityCostModel
+from .k8s_priority import K8sPriorityCostModel
 from .k8s_zonespread import K8sZoneSpreadCostModel
 from .net import NetCostModel
 from .quincy import BlockRegistry, QuincyCostModel
@@ -11,8 +12,8 @@ from .whare import WhareMapCostModel, whare_cost_matrix
 
 #: CostModelType -> implementation, the dispatch the reference plans in
 #: costmodel/interface.go:33-43 — here every enumerated model exists,
-#: and two the reference does not enumerate (K8S_ANTIAFFINITY,
-#: K8S_ZONESPREAD).
+#: and three the reference does not enumerate (K8S_ANTIAFFINITY,
+#: K8S_ZONESPREAD, K8S_PRIORITY).
 MODEL_REGISTRY = {
     CostModelType.TRIVIAL: TrivialCostModel,
     CostModelType.RANDOM: RandomCostModel,
@@ -25,6 +26,7 @@ MODEL_REGISTRY = {
     CostModelType.NET: NetCostModel,
     CostModelType.K8S_ANTIAFFINITY: K8sAntiAffinityCostModel,
     CostModelType.K8S_ZONESPREAD: K8sZoneSpreadCostModel,
+    CostModelType.K8S_PRIORITY: K8sPriorityCostModel,
 }
 
 __all__ = [
@@ -41,6 +43,7 @@ __all__ = [
     "BlockRegistry",
     "CocoCostModel",
     "K8sAntiAffinityCostModel",
+    "K8sPriorityCostModel",
     "K8sZoneSpreadCostModel",
     "coco_cost_matrix",
     "NetCostModel",

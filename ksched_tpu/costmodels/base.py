@@ -40,6 +40,9 @@ class CostModelType(enum.IntEnum):
     #: nor this: a hard zone topology-spread constraint
     #: (costmodels/k8s_zonespread.py)
     K8S_ZONESPREAD = 10
+    #: nor this: pod priority and preemption over slots
+    #: (costmodels/k8s_priority.py)
+    K8S_PRIORITY = 11
 
 
 # The wildcard equivalence class every task points at in aggregate-style
@@ -100,6 +103,12 @@ class CostModeler(abc.ABC):
     #: subclass that overrides one of the two methods has to say it
     #: again for itself; it does not inherit the claim.
     resource_arc_costs_are_fixed: bool = False
+
+    #: What a model says about itself where its prices only make sense
+    #: while running tasks keep their arcs (a preemption cost, an EC ->
+    #: machine capacity that counts every slot): a FlowScheduler built
+    #: without ``preemption`` refuses it.
+    needs_preemption: bool = False
 
     #: 1 while the graph update of the round in progress had to leave
     #: the model's own allotment for a per-pod predicate (the zone
@@ -222,6 +231,13 @@ class CostModeler(abc.ABC):
                 "k8s_antiaffinity or k8s_zonespread"
             )
         return {"task_type": TaskType(task_class)}
+
+    def task_priority_fields(self, priority: int) -> Dict[str, object]:
+        """What ``PodEvent.priority`` is on a ``TaskDescriptor`` for this
+        model, as the fields to set; ``ValueError`` where the model
+        prices priority and has no such tier. The default: carried as it
+        is, and read by nothing."""
+        return {"priority": priority}
 
     def equiv_class_pref_arc_changes(self, ec: int) -> Optional[List[int]]:
         """The resources whose arc from ``ec`` may have changed (come,

@@ -128,6 +128,16 @@ class RoundRecord:
     #: round's dispatch window, or an idle sweep in between); stamped on
     #: solved rounds, 0.0 on the synchronous path
     post_defer_ms: float = 0.0
+    #: --preemption: running tasks the round's solve could preempt or
+    #: migrate (from its RoundTiming; 0 without preemption); pods the
+    #: round took off their nodes and posted through `evict_pods`, those
+    #: of them that it bound to another node in the same round (an
+    #: eviction and a Binding), and pods evicted at some time and still
+    #: without a new Binding once the round was posted
+    tasks_unpinned: int = 0
+    pods_evicted: int = 0
+    pods_migrated: int = 0
+    pods_pending_evicted: int = 0
 
 
 class RoundTracer:
@@ -295,6 +305,7 @@ class RoundTracer:
             unscheduled_by_rule=t.unscheduled_by_rule,
             ec_chain_arcs_changed=t.ec_chain_arcs_changed,
             spread_fallback=t.spread_fallback,
+            tasks_unpinned=t.tasks_unpinned,
         )
         for k, v in (extra or {}).items():
             if not hasattr(rec, k):

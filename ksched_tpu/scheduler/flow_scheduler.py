@@ -114,6 +114,10 @@ class RoundTiming:
     #: free slot for each: what a placement rule (or a cost above the
     #: unscheduled cost) kept out, not a full cluster
     unscheduled_by_rule: int = 0
+    #: running tasks the round's solve could preempt or migrate: those
+    #: whose running arc is no pin (GraphManager.unpinned_running_tasks
+    #: at the solve: every running task under preemption, none without)
+    tasks_unpinned: int = 0
 
 
 class FlowScheduler:
@@ -146,6 +150,11 @@ class FlowScheduler:
         self.cost_model = cost_model or TrivialCostModel(
             resource_map, task_map, leaf_resource_ids, max_tasks_per_pu
         )
+        if self.cost_model.needs_preemption and not preemption:
+            raise ValueError(
+                f"{type(self.cost_model).__name__} prices preemption: it is served "
+                "only with preemption on (--preemption)"
+            )
         self.gm = GraphManager(
             self.cost_model,
             leaf_resource_ids,
@@ -456,6 +465,7 @@ class FlowScheduler:
                 sp.set("ec_arcs_changed", timing.ec_arcs_changed)
                 sp.set("ec_chain_arcs_changed", timing.ec_chain_arcs_changed)
             timing.graph_update_s = sp.dur_s
+            timing.tasks_unpinned = self.gm.unpinned_running_tasks
         except BaseException:
             round_span.__exit__(*sys.exc_info())
             raise
@@ -495,9 +505,11 @@ class FlowScheduler:
                     # have lost its place, and the reference's walk
                     # finds those: it empties every PU's list and the
                     # loop below refills it from the mapping.
-                    deltas = self.gm.scheduling_deltas_for_preempted_tasks(
-                        task_mappings, self.resource_map
-                    )
+                    with span("preempt_deltas") as psp:
+                        deltas = self.gm.scheduling_deltas_for_preempted_tasks(
+                            task_mappings, self.resource_map
+                        )
+                        psp.set("preempted", len(deltas))
                     self._drop_departed(lists_rebuilt=True)
                 else:
                     # Every running task is pinned and off the mapping:

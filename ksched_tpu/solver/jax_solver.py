@@ -202,8 +202,19 @@ def _seg_ends(node_first, node_last, node_nonempty, vals, cums, *at_last):
 
 _BIG_D = 1 << 28  # "unreachable" distance sentinel for price tightening
 
+#: a service with preemption (--preemption --backend jax): the discharge
+#: lowers its prices to the residual graph's exact ones after every this
+#: many supersteps (JaxSolver.price_update_every). Any value ends the round
+#: (prices only fall); an update costs about as many sweeps as a path has
+#: hops (arrival -> EC -> machine -> core -> PU -> back along a running
+#: arc -> unscheduled aggregator -> sink: 7), so a smaller value spends
+#: its time in updates and a larger one in unit relabels between them:
+#: on a full 125-machine cluster a round takes 14-61 supersteps at 4,
+#: 26-42 at 8, 20-91 at 16 (CPU runs, PR 38)
+PREEMPTION_PRICE_UPDATE_EVERY = 8
 
-@functools.partial(jax.jit, static_argnames=("alpha", "max_supersteps", "tighten_sweeps", "telemetry_cap", "use_warm_p", "slot_stable"))  # kschedlint: program=csr_solve
+
+@functools.partial(jax.jit, static_argnames=("alpha", "max_supersteps", "tighten_sweeps", "telemetry_cap", "use_warm_p", "slot_stable", "price_update_every"))  # kschedlint: program=csr_solve
 def _solve_mcmf(
     cap, cost, supply, flow0, eps_init,
     s_arc, s_sign, s_src, s_dst, s_segstart, s_isstart, inv_order,
@@ -215,6 +226,7 @@ def _solve_mcmf(
     telemetry_cap: int = 0,
     use_warm_p: bool = False,
     slot_stable: bool = False,
+    price_update_every: int = 0,
 ):
     """telemetry_cap > 0 appends a superstep-indexed int32 telemetry
     ring [telemetry_cap, SOLTEL_WIDTH] to the returned tuple (row
@@ -261,6 +273,24 @@ def _solve_mcmf(
     tensor), and what would arrive at it through its stale partner is
     masked. The default (False) is the tightly-packed build_csr_plan
     layout, where every row is live; both layouts run the same code.
+
+    price_update_every=G > 0 is the global price update for graphs whose
+    running tasks keep their arcs (`--preemption`): in the eps=1
+    discharge, after every G-th superstep every potential is lowered to
+    what `tighten` gives on the residual graph as it then stands (minus
+    the exact distance to the nodes still short of flow: at eps=1, on
+    costs scaled by the node count, no residual cycle is negative),
+    where that is lower than what the node has. Without it such a round
+    does not end: a full cluster hands out more units than PU -> sink
+    takes, the zero-flow prices of the prologue cannot know, and the units left over at the
+    PUs sink the whole plateau of machines, PUs and running tasks one
+    unit relabel at a time until a task's arc to its unscheduled
+    aggregator turns admissible (cost x nodes relabels for each node:
+    45,617 supersteps for ONE arrival on a full 125-machine cluster,
+    25,514 of them in one phase of the cold ladder with one unit of
+    excess wandering; CHANGES.md, PR 38). The default (0) traces no op
+    of it: the program of every service without preemption is the one
+    it was.
 
     Discharging DISPLACED excess through carried flow is structurally
     slow here, and no price seeding fixes it (measured, r12): with the
@@ -430,6 +460,21 @@ def _solve_mcmf(
 
         def do_superstep(_):
             r2, e2, p2, aux = superstep(r, excess, p, eps)
+            if price_update_every:
+                # the lower of the two prices at every node: both keep
+                # every residual arc's reduced cost >= -eps, so their
+                # minimum does (c + p(v) - p'(w) >= c + p(v) - p(w) for
+                # p' <= p), and prices still only fall, which is what
+                # ends the discharge: an update that could raise a price
+                # forgets where a unit came from, and a unit then walks
+                # a plateau of zero reduced cost for ever
+                p2 = lax.cond(
+                    (eps == 1) & ((steps + 1) % price_update_every == 0),
+                    lambda: jnp.minimum(
+                        p2, tighten(r2, d0=jnp.where(e2 < 0, i32(0), i32(_BIG_D)))
+                    ),
+                    lambda: p2,
+                )
             out = (r2, e2, p2, eps, steps + 1, jnp.bool_(False))
             if not telemetry_cap:
                 return out
@@ -587,10 +632,15 @@ class JaxSolver(FlowSolver):
     dirty-slot journal as the problem deltas. Plain array problems
     (no plan handle) keep the legacy host-built CsrPlan."""
 
-    def __init__(self, alpha: int = 8, max_supersteps: int = 50_000, warm_start: bool = True, telemetry: Optional[int] = None, warm_potentials: bool = True, restart_budget: Optional[int] = None, slot_stable: bool = True, journal_scoped_warm: bool = True):
+    def __init__(self, alpha: int = 8, max_supersteps: int = 50_000, warm_start: bool = True, telemetry: Optional[int] = None, warm_potentials: bool = True, restart_budget: Optional[int] = None, slot_stable: bool = True, journal_scoped_warm: bool = True, price_update_every: int = 0):
         from .layered import validate_alpha
 
         self.alpha = validate_alpha(alpha)
+        #: global price update of the eps=1 discharge, every this many
+        #: supersteps (0: never; _solve_mcmf's docstring): for graphs
+        #: whose running tasks keep their arcs, which hand out more
+        #: units than the cluster takes in every round
+        self.price_update_every = price_update_every
         self.max_supersteps = max_supersteps
         self.warm_start = warm_start
         self.warm_potentials = warm_potentials
@@ -896,6 +946,7 @@ class JaxSolver(FlowSolver):
             telemetry_cap=tel_cap,
             use_warm_p=warm_p_ok,
             slot_stable=slot_stable,
+            price_update_every=self.price_update_every,
         )
         cold = (np.zeros(m, dtype=np.int32), max(1, max_cost * n))
         rest = (dev_args, plan_dev, cold, tel_cap, warm, slot_stable, attempt1_budget)
@@ -962,6 +1013,7 @@ class JaxSolver(FlowSolver):
                 max_supersteps=min(4096, self.max_supersteps),
                 telemetry_cap=tel_cap,
                 slot_stable=slot_stable,
+                price_update_every=self.price_update_every,
             )
             if tel_cap:
                 flow, p, steps, converged, p_overflow, tel_buf = out
@@ -978,6 +1030,7 @@ class JaxSolver(FlowSolver):
                 max_supersteps=self.max_supersteps,
                 telemetry_cap=tel_cap,
                 slot_stable=slot_stable,
+                price_update_every=self.price_update_every,
             )
             if tel_cap:
                 flow, p, steps, converged, p_overflow, tel_buf = out

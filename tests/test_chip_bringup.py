@@ -67,6 +67,12 @@ PHASE_FACTS = {
         r"\d+ Bindings and completions replayed over 6 rounds in 3 zones: largest skew [01] \(maxSkew 5\)",
         r"compiles after the first trickle round: 0",
     ),
+    "preemption": (
+        r"machines=125 nodes=1024 arcs=4096 ",
+        r"objectives==native and bound / evicted by tier == the greedy's in every round",
+        r"\d+ entries replayed: bound \[\d+, 42\], evicted \[\d+, 0\], [1-9]\d* evicted pods bound again",
+        r"compiles after the first trickle round: 0",
+    ),
 }
 
 

@@ -358,11 +358,12 @@ def test_every_registered_model_says_what_its_methods_do():
     """The claim is checked against behaviour: on a task node the stats
     hooks change nothing, and the continuation cost does not move."""
     for kind, model in MODEL_REGISTRY.items():
-        sched, rmap, jmap, tmap = _filled_cluster(8, model=model)
+        # a model that prices preemption is built with it (k8s_priority)
+        sched, rmap, jmap, tmap = _filled_cluster(8, model=model, preemption=model.needs_preemption)
         assert model.pinned_tasks_are_inert, kind
         cm, gm = sched.cost_model, sched.gm
         task_node = next(iter(gm.task_to_node.values()))
-        pu = next(iter(task_node.outgoing.values())).dst_node
+        pu = gm.task_to_running_arc[task_node.task.uid].dst_node
         before = (vars(pu.resource_descriptor).copy(), cm.task_continuation_cost(task_node.task.uid))
         cm.prepare_stats(task_node)
         assert cm.gather_stats(task_node, pu) is task_node

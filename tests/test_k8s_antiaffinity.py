@@ -27,6 +27,7 @@ from ksched_tpu.obs.spans import SpanTracer
 from ksched_tpu.runtime.trace import RoundTracer
 from ksched_tpu.solver.graph_collapse import try_collapse
 from ksched_tpu.utils import seed_rng
+from test_k8s_priority import drain
 
 
 def _service(machines, slots, backend="native", cost_model="k8s_antiaffinity", **kw):
@@ -87,7 +88,9 @@ class Stream:
         runnable = [(p, self.group_of[p]) for p in self.backlog + new]
         held = [(self.group_of[p], m) for p, m in self.bound.items()] + lingering
         ref_objective, ref_placed = reference_round(runnable, self.slots, held)
-        batch = self.api.poll_pod_batch(0.002)
+        # in as many polls as the debounce takes (7 of 12 pods in one poll, under
+        # load, in the driver's run of PR 37): its quiet timer runs on the machine's clock
+        batch = drain(self.api, len(arrivals))
         assert len(batch) == len(arrivals)
         self.svc.run_round(batch)
         now = self.api.bindings()

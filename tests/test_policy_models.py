@@ -30,6 +30,8 @@ def _cluster(model_cls, machines=3, cores=1, pus=2, slots=1):
         pus_per_core=pus,
         max_tasks_per_pu=slots,
         cost_model_factory=model_cls,
+        # a model that prices preemption is served with it (k8s_priority)
+        preemption=model_cls.needs_preemption,
     )
 
 

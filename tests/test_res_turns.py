@@ -64,7 +64,9 @@ def test_a_registered_model_asks_the_same_two_prices_before_and_after_rounds(kin
     every arc out of a resource node carries it."""
     model = MODEL_REGISTRY[kind]
     assert model.resource_arc_costs_are_fixed, kind
-    sched, rmap, jmap, tmap = _filled_cluster(8, model=model, backend=ReferenceSolver())
+    sched, rmap, jmap, tmap = _filled_cluster(
+        8, model=model, backend=ReferenceSolver(), preemption=model.needs_preemption
+    )
     gm, cm = sched.gm, sched.cost_model
     assert not gm._res_turns
     before, carried = _asked_prices(gm, cm)
