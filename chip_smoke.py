@@ -251,7 +251,9 @@ class Smoke:
             check(svc.noop_rounds == 0, f"{backend} round {r + 1} was a NOOP round")
             out.append(dict(
                 bound=bound,
-                objective=int(solver.last_result.objective),
+                # the round's own (solver.last_result is a later solve's
+                # in a round that re-fitted the slot plan)
+                objective=int(svc.scheduler.last_timing.objective),
                 supersteps=int(getattr(solver.backend, "last_supersteps", 0) or 0),
                 path=getattr(solver.backend, "last_path", None),
                 mega_rung=getattr(solver.backend, "mega", None) is not None,

@@ -589,7 +589,8 @@ def slot_stable_entry_cap(m_pad: int) -> int:
     ._rebuild: max(2*m_cap, next_pow2(need)) — need exceeds 2*m_cap
     only when per-node slack rows outgrow the doubled entries, which
     next_pow2 then absorbs; either way a pow2 of the bucket, never the
-    raw size)."""
+    raw size). It is also the floor a re-fit returns a plan to once a
+    fill round's transient arcs are gone (SlotPlanState.refit)."""
     return 2 * m_pad
 
 

@@ -93,7 +93,24 @@ the same move `scheduler/bulk.py` makes by pre-wiring arc endpoints:
   a completed task's id refills in place, zero relocations, zero
   journal bytes. A full layout rebuild therefore survives ONLY
   full_build, pow2 bucket growth, and tail-pool exhaustion
-  (`region_overflows`, the rare compaction case).
+  (`region_overflows`, the rare compaction case);
+- the entry budget RE-FITS to the graph it holds. A layout built
+  while a fill round's transient arcs were live (every admitted pod
+  carries two or three pending arcs; a round later it is pinned and
+  keeps one) is sized for rows that are dead for good once the round
+  has been applied, and every superstep gathers and scans dead rows
+  like live ones. At the END of each round the owner asks
+  `refit_due()` (microseconds: the rows in use are twice the live
+  arcs): would a layout of the graph as it stands land in a smaller
+  bucket, the `2 * m_cap` floor and the arena's sixteenth included?
+  `refit()` then re-lays out from the LIVE degree (the marks are set
+  to it, not decayed halfway: the spike is over), slack and arena
+  funded from the bucket's surplus as in any rebuild. A re-fit that
+  growth undoes (an overflow or `m_cap` growth that raises
+  `entry_cap` again) doubles the rounds the next one waits, so a
+  workload whose peaks do not fit the smaller bucket pays a bounded
+  number of re-layouts and settles at the larger. The lifecycle is
+  build -> re-fit -> (relocate | overflow | grow).
 
 Entry position 0 is permanently reserved and dead: freed slots'
 `inv_order` rows are parked there, so a stale slot can never alias a
@@ -156,6 +173,18 @@ def shard_owner(node_ids, num_nodes: int, num_shards: int) -> np.ndarray:
     one source of truth for who owns what."""
     per = -(-num_nodes // max(num_shards, 1))
     return np.minimum(np.asarray(node_ids) // per, num_shards - 1)
+
+
+def entry_bucket(need: int, m_cap: int) -> int:
+    """The entry-table extent of an unsharded layout that must house
+    `need` rows: the pow2 above it, floored at `2 * m_cap`, and one
+    bucket more when less than a sixteenth of it (the relocation
+    arena) would be left — at production fill the `2 * m_cap` term
+    plus the dropped per-node spare row carry that floor comfortably."""
+    cap = max(2 * m_cap, next_pow2(need))
+    if cap - need < max(64, cap >> 4):
+        cap = max(2 * m_cap, next_pow2(need + max(64, cap >> 4)))
+    return cap
 
 
 _PLAN_APPLY = None
@@ -237,6 +266,15 @@ class SlotPlanState:
         self.layout_rebuilds = 0  # full rebuilds (telemetry)
         self.region_overflows = 0  # rebuilds forced by tail-pool exhaustion
         self.region_relocations = 0  # regions moved to the tail pool
+        self.refits = 0  # rebuilds that took a smaller bucket (refit)
+        self.regrowths = 0  # rebuilds that raised entry_cap
+        #: end-of-round tests a re-fit waits for, and those seen since
+        #: the layout was built or grew back; the wait doubles each
+        #: time growth undoes a re-fit (`_refit_standing`)
+        self._refit_wait = 1
+        self._refit_idle = 0
+        self._refit_standing = False
+        self._refit_asked = False  # the next build is a re-fit (refit)
         # ---- layout (static per layout_gen) --------------------------
         self.entry_cap = 0  # E: padded entry-table extent
         self.region_start: Optional[np.ndarray] = None  # int32[n_cap]
@@ -347,9 +385,12 @@ class SlotPlanState:
     def _rebuild(self) -> None:
         """Re-derive regions and entry placement from the current
         arrays (vectorized; the moral equivalent of build_csr_plan's
-        argsort, run only on full_build / growth / overflow)."""
+        argsort, run only on full_build / growth / overflow / re-fit;
+        a re-fit sizes from the live degree alone: see `refit`)."""
         st = self.state
         n_cap, m_cap = st.n_cap, st.m_cap
+        cap_before = self.entry_cap
+        refit, self._refit_asked = self._refit_asked, False
         slots = np.fromiter(st._arc_slot.values(), np.int64, len(st._arc_slot))  # kschedlint: host-only (host layout build)
         slots.sort()
         src_l = st.src[slots].astype(np.int64)  # kschedlint: host-only (host layout build)
@@ -374,8 +415,9 @@ class SlotPlanState:
         # fill-time spike of a since-bound task, or a recycled id's
         # past big tenant, must not inflate the entry budget forever);
         # the type-hinted relocation path catches whoever decays too
-        # far
-        hwm = np.maximum(deg, (self._deg_hwm[:n_cap] + deg + 1) // 2)
+        # far. A re-fit has decided that the spike is over: it drops
+        # the marks to the live degree at once
+        hwm = deg.copy() if refit else np.maximum(deg, (self._deg_hwm[:n_cap] + deg + 1) // 2)
         self._deg_hwm = hwm
         # RESET the per-TYPE degree records to the live peak (fresh-
         # region sizing hints: an id never predicts its next tenant —
@@ -410,17 +452,9 @@ class SlotPlanState:
         if D == 1:
             owner = np.zeros(n_cap, np.int64)  # kschedlint: host-only (host layout build)
             need = 1 + int(base.sum())
-            self.entry_cap = max(2 * m_cap, next_pow2(need))
-            # guarantee the relocation arena: when the pow2 lands so
-            # close to `need` that no real tail pool would remain, take
-            # the next bucket — at production fill the 2*m_cap term
-            # plus the dropped per-node spare row carry the floor
-            # comfortably
-            if self.entry_cap - need < max(64, self.entry_cap >> 4):
-                self.entry_cap = max(
-                    2 * m_cap,
-                    next_pow2(need + max(64, self.entry_cap >> 4)),
-                )
+            # the pow2 above `need`, with the relocation arena
+            # guaranteed (entry_bucket)
+            self.entry_cap = entry_bucket(need, m_cap)
             surplus = self.entry_cap - need
             grantable = max(surplus - max(64, self.entry_cap >> 4), 0)
             slack = want
@@ -564,6 +598,54 @@ class SlotPlanState:
         self.static_version += 1
         self.layout_rebuilds += 1
         self.needs_rebuild = False
+        if refit and self.entry_cap < cap_before:
+            self.refits += 1
+            self._refit_standing = True
+            return
+        if cap_before and self.entry_cap > cap_before:
+            self.regrowths += 1
+        if refit or (self.entry_cap > cap_before and self._refit_standing):
+            # growth undid a re-fit (the workload's peaks do not fit
+            # the smaller bucket), or a re-fit found no smaller bucket
+            # after all (the arc table grew under it): the next one
+            # waits twice as long
+            self._refit_standing = False
+            self._refit_wait *= 2
+            self._refit_idle = 0
+
+    # -- re-fit (module docstring) -----------------------------------------
+
+    @property
+    def rows_live(self) -> int:
+        """Plan rows in use: two for each arc the arrays hold
+        (`_occ.sum()` of a built layout)."""
+        return 2 * len(self.state._arc_slot)
+
+    def refit_due(self, rows_live: int) -> bool:
+        """The end-of-round test: would a layout of a graph with
+        `rows_live` rows in use (two for each arc of the graph as the
+        round left it: the journal `apply` wrote is not in the arrays
+        yet) land in a smaller bucket than `entry_cap`? One call a round
+        (it is also the back-off's clock). The sharded layout is left as
+        it is: its block extent follows the densest shard."""
+        if not self.enabled or not self.entry_cap or self._num_shards != 1:
+            return False
+        self._refit_idle += 1
+        if self._refit_idle < self._refit_wait:
+            return False
+        return entry_bucket(1 + rows_live, self.state.m_cap) < self.entry_cap
+
+    def refit(self) -> None:
+        """Ask for a re-layout at the bucket `refit_due` found, in ONE
+        step: the next build (the next export, once the journal is in
+        the arrays) sizes its regions from the live degree, with the
+        slack and the arena any rebuild grants from the bucket's
+        surplus. A node that outgrows its region is relocated, as after
+        any rebuild. A new `entry_cap` is a new solve program and new
+        upload shapes: the caller runs them once before it lets the
+        round go (FlowScheduler._refit_plan)."""
+        self._refit_asked = True
+        self.invalidate()
 
     # -- per-mutation hooks (called by DeviceGraphState._set_arc) ----------
 

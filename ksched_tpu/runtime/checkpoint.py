@@ -41,8 +41,11 @@ CHECKPOINT_VERSION = 1
 #: manifest's graph manager has none: restore falls back to the cold
 #: replay, whose first refresh walks every node). 7: and whether its
 #: update gives resource nodes a turn (what the model said of its
-#: resource arcs' prices when the graph manager was built)
-WARM_MANIFEST_VERSION = 7
+#: resource arcs' prices when the graph manager was built). 8: the
+#: pickled slot plan carries its re-fit state (refits, regrowths, the
+#: back-off's wait and clock) and the scheduler the plan counters its
+#: last record saw
+WARM_MANIFEST_VERSION = 8
 
 
 class CheckpointError(RuntimeError):

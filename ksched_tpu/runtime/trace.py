@@ -104,6 +104,13 @@ class RoundRecord:
     upload_bytes: int = 0
     upload_full: int = 0
     plan_relocations: int = 0
+    #: the slot plan (graph/slot_plan.py): rows at the solve and in
+    #: use, and the round's re-fits, regrowths and re-layouts
+    plan_rows: int = 0
+    plan_rows_live: int = 0
+    plan_refits: int = 0
+    plan_regrowths: int = 0
+    plan_relayouts: int = 0
     #: records of the change journal the round's export applied (from
     #: its RoundTiming; 0 when it built the arrays whole), and EC nodes
     #: the purge removed after `apply`
@@ -297,6 +304,11 @@ class RoundTracer:
             upload_bytes=t.upload_bytes,
             upload_full=t.upload_full,
             plan_relocations=t.plan_relocations,
+            plan_rows=t.plan_rows,
+            plan_rows_live=t.plan_rows_live,
+            plan_refits=t.plan_refits,
+            plan_regrowths=t.plan_regrowths,
+            plan_relayouts=t.plan_relayouts,
             journal_changes=t.journal_changes,
             ec_purged=t.ec_purged,
             ec_nodes=t.ec_nodes,

@@ -58,6 +58,10 @@ class RoundTiming:
     deltas_s: float = 0.0
     apply_s: float = 0.0
     total_s: float = 0.0
+    #: the min-cost objective of the round's solve. In a round that
+    #: re-fits the slot plan `solver.last_result` is the later solve's
+    #: (it pairs with `solver.state.problem()`); this stays the round's
+    objective: int = 0
     #: what `graph_update` did: task nodes updated, and pinned tasks of
     #: the same jobs left alone (GraphManager.add_or_update_job_nodes)
     graph_tasks_visited: int = 0
@@ -92,6 +96,17 @@ class RoundTiming:
     upload_bytes: int = 0
     upload_full: int = 0
     plan_relocations: int = 0
+    #: the slot plan of a scan-CSR service (graph/slot_plan.py; zeros
+    #: without one): its rows at the solve (`entry_cap`: what a
+    #: superstep pays for) and those in use; 1 if the round re-fitted
+    #: it to a smaller bucket, 1 if a rebuild of the round raised
+    #: `entry_cap`, and every re-layout of the round, whatever asked
+    #: for it (overflow, growth, re-fit)
+    plan_rows: int = 0
+    plan_rows_live: int = 0
+    plan_refits: int = 0
+    plan_regrowths: int = 0
+    plan_relayouts: int = 0
     #: records of the change journal the round's export applied to the
     #: flat arrays (0 when it built them whole; PlacementSolver)
     journal_changes: int = 0
@@ -184,6 +199,9 @@ class FlowScheduler:
         self.jobs_to_schedule: Dict[int, JobDescriptor] = {}
         self.runnable_tasks: Dict[int, Set[int]] = {}
         self.last_timing = RoundTiming()
+        #: the slot plan's (refits, regrowths, layout_rebuilds) as the
+        #: last round's record saw them (_note_plan)
+        self._plan_seen = (0, 0, 0)
         #: PU resource id -> tasks that finished, failed or were killed
         #: there since the last `deltas` phase. They stay in the PU's
         #: current_running_tasks until that phase drops them, so the
@@ -487,6 +505,48 @@ class FlowScheduler:
                 or res.last_plan_kind == "rebuild"
             )
             timing.plan_relocations = res.last_plan_relocations
+        plan = self.solver.state.plan
+        if plan.enabled:
+            timing.plan_rows = plan.entry_cap
+            timing.plan_rows_live = plan.rows_live
+
+    def _refit_plan(self, timing: RoundTiming) -> None:
+        """The slot plan re-fits to the graph the round left
+        (graph/slot_plan.py): when it would land in a smaller bucket it
+        is re-laid out there, and the round's own path runs once on it
+        (PlacementSolver.rehearse), because a new `entry_cap` is a new
+        solve program and new upload shapes: the round that re-fits
+        compiles them, not the next one. No runnable pod waits on that
+        graph; what the solve would bind (`would_place`) is dropped."""
+        plan = self.solver.state.plan
+        graph = self.gm.cm.graph
+        if not plan.refit_due(2 * graph.num_arcs):
+            return
+        with span("plan_refit", rows=plan.entry_cap) as sp:
+            plan.refit()
+            mapping = self.solver.rehearse() or {}
+            sp.set("rows_after", plan.entry_cap)
+            sp.set("rows_live", plan.rows_live)
+            sp.set("would_place", sum(
+                1 for task_node_id in mapping
+                if graph.node(task_node_id).task.uid not in self.task_bindings
+            ))
+        res = self.solver.resident
+        if res is not None:
+            # the new layout went up whole
+            timing.upload_bytes += res.last_upload_bytes
+            timing.upload_full = 1
+
+    def _note_plan(self, timing: RoundTiming) -> None:
+        """What happened to the slot plan's layout since the last round
+        said so: this round's export and re-fit, and whatever an event
+        between two rounds forced."""
+        plan = self.solver.state.plan
+        seen = (plan.refits, plan.regrowths, plan.layout_rebuilds)
+        timing.plan_refits, timing.plan_regrowths, timing.plan_relayouts = (
+            now - before for now, before in zip(seen, self._plan_seen)
+        )
+        self._plan_seen = seen
 
     def _finish_round(self, task_mappings, timing, round_span):
         """The post-solve half of a round, shared by the synchronous
@@ -496,6 +556,7 @@ class FlowScheduler:
         unscheduled-feedback hook. Closes the `round` span; its
         duration IS timing.total_s."""
         try:
+            timing.objective = int(self.solver.last_result.objective)
             timing.decode_tasks = self.solver.decode_tasks
             timing.decode_pinned_skipped = self.solver.decode_pinned_skipped
             with span("deltas") as sp:
@@ -543,6 +604,11 @@ class FlowScheduler:
                 timing.ec_purged = self.gm.ec_purged
                 sp.set("ec_purged", timing.ec_purged)
                 sp.set("ec_arcs_dropped", self.gm.ec_arcs_dropped)
+            # the round's transient arcs are gone: the slot plan re-fits
+            # to the graph it holds, and pays for the new program here,
+            # before the round's Bindings go out
+            self._refit_plan(timing)
+            self._note_plan(timing)
             # Policy feedback: which runnable tasks stayed unscheduled
             # (drives e.g. Quincy's wait-cost starvation bound).
             unscheduled = [

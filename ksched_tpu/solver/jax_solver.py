@@ -160,6 +160,16 @@ def build_csr_plan(src: np.ndarray, dst: np.ndarray, num_nodes: int) -> CsrPlan:
     )
 
 
+#: rows of a gather table (`_rows`) that XLA compiled into VMEM with
+#: its rows padded to the lane width (196,608 x 512 B = 96 MiB; at
+#: 229,376 part of it spills), and the fewest from which it tiles
+#: every table of two to four columns compactly (266,240 still pads,
+#: 274,432 does not). Read off programs compiled ahead of time for a
+#: v5e under jax 0.9.0, no chip; the ns a row are chip readings
+_TABLE_ROWS_IN_VMEM = 196_608
+_TABLE_ROWS_COMPACT = 278_528
+
+
 def _rows(idx, *cols):
     """``tuple(c[idx] for c in cols)`` for 1-D tables of one length,
     done as ONE gather of rows two or more wide (a lone column is
@@ -170,8 +180,24 @@ def _rows(idx, *cols):
     least 8 columns (PERF.md section 6, PR 29: 0.955 ms against 0.23
     ms for the 131,072 plan rows). The rows come back padded to the
     lane width, which is the temporary memory this costs (64 MB a
-    gather into the plan rows of the 10k x 1k cluster)."""
-    got = jnp.stack(cols * 2 if len(cols) == 1 else cols, axis=1)[idx]
+    gather into the plan rows of the 10k x 1k cluster).
+
+    The TABLE is padded to the lane width too while XLA's layout
+    assignment sees fit, and gathers that fast only while the padded
+    table (512 B a row) fits the v5e's 128 MiB of VMEM. Above
+    `_TABLE_ROWS_COMPACT` the compiler tiles a table compactly
+    instead (4.2 ns a gathered row); in between it still pads, the
+    table lies in HBM, and every table a superstep builds costs a
+    padded copy of the plan on top (12.2 ns a row at 262,144 rows: a
+    plan of 262,144 rows solved slower than the same graph on
+    524,288). A table in that gap is given dead rows, which no index
+    reads, up to where the compiler switches (PERF.md section 6, PR
+    41). The tables a plan meets have pow2 rows: 262,144 is the one."""
+    table = jnp.stack(cols * 2 if len(cols) == 1 else cols, axis=1)
+    rows = table.shape[0]
+    if _TABLE_ROWS_IN_VMEM < rows < _TABLE_ROWS_COMPACT:
+        table = jnp.pad(table, ((0, _TABLE_ROWS_COMPACT - rows), (0, 0)))
+    got = table[idx]
     return tuple(got[:, i] for i in range(len(cols)))
 
 

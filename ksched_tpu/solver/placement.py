@@ -10,7 +10,8 @@ subprocess; here it is a scatter into the flat device arrays
 
 from __future__ import annotations
 
-from typing import Dict
+import warnings
+from typing import Dict, Optional
 
 from ..graph.device_export import DeviceGraphState, DeviceResidentState
 from ..graph.graph_manager import GraphManager, TaskMapping
@@ -262,3 +263,26 @@ class PlacementSolver:
 
     def solve(self) -> TaskMapping:
         return self.complete(self.solve_async())
+
+    def rehearse(self) -> Optional[TaskMapping]:
+        """The round's own path once more, on the graph as the round
+        left it: the export of the journal `apply` just wrote, the
+        uploads, the rung, the decode. After a re-fit of the slot plan
+        (FlowScheduler._refit_plan) every program whose shape follows
+        `entry_cap` has then RUN in this process before the round
+        returns, and the next round's export finds an empty journal.
+        The mapping is the caller's to look at and drop, never to
+        apply; None if the solve failed (the round itself is done: the
+        next one then meets the new shapes first). `last_result` and
+        `state.problem()` stay a pair: both are this solve's now, and
+        the round's own objective is on its RoundTiming."""
+        try:
+            return self.solve()
+        except Exception as e:  # noqa: BLE001 — warned about, not raised
+            warnings.warn(
+                f"the solve that follows a plan re-fit failed ({e!r}); "
+                "the next round runs the re-fitted shapes first",
+                RuntimeWarning,
+                stacklevel=2,
+            )
+            return None

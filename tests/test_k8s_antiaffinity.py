@@ -98,7 +98,7 @@ class Stream:
         for p in placed:
             self.bound[p] = _machine(now[p])
         self.backlog = [p for p, _g in runnable if p not in now]
-        objective = int(self.svc.scheduler.solver.last_result.objective)
+        objective = int(self.svc.scheduler.last_timing.objective)
         return objective, ref_objective, len(placed), ref_placed
 
     def arcs_are_a_sweeps(self):

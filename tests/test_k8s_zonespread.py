@@ -147,7 +147,7 @@ class Stream:
                 key = (g, self.zone[self.bound[p]])
                 into[key] = into.get(key, 0) + 1
         self.backlog = [p for p, _g in runnable if p not in now]
-        objective = int(self.svc.scheduler.solver.last_result.objective)
+        objective = int(self.svc.scheduler.last_timing.objective)
         return (objective, sum(into.values()), into), reference
 
     def holds_the_guarantee(self):
