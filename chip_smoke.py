@@ -77,6 +77,19 @@ speed:
            (check_priority_preemption), no pod moved, no step down the
            ladder, and nothing compiled after the first trickle round.
 
+  quincy   the benchmark's `gtrace-12500-quincy` deployment from its
+           file's argv (12,500 machines x 12 slots in 250 racks,
+           `--fake-racks 250 --cost-model quincy --backend jax`): the
+           fill of 135,000 pods that read nothing and 20 trickle-sized
+           rounds of pods that read blocks (`pods/quincy_blocks.py`),
+           with completions. Per round: every pod bound, the objective
+           equal to the native C++ solver's on `state.problem()`. At the
+           end: the replay (benchmarks/reference_quincy.
+           check_data_locality, each node's rack from the label the
+           service holds) finds every round's Bindings at the optimum of
+           the round's transportation problem, no step down the ladder,
+           and nothing compiled after the first trickle round.
+
 `--only PHASE` (repeatable) runs the named phases alone.
 
 It refuses to start unless jax.devices()[0].platform == "tpu". No phase
@@ -114,6 +127,7 @@ FULL = dict(
     antiaffinity=dict(scale=1, trickle=(30, 55, 12, 80, 41, 9, 64, 22, 50, 37) * 2),
     zonespread=dict(scale=1, trickle=(30, 55, 12, 80, 41, 9, 64, 22, 50, 37) * 2),
     preemption=dict(scale=1, trickle=(1, 30, 120, 55, 200, 9, 80, 0, 150, 41) * 2),
+    quincy=dict(scale=1, trickle=(30, 55, 12, 80, 41, 9, 64, 22, 50, 37) * 2),
 )
 TINY = dict(
     served=dict(machines=20, pods=200, churn=10),
@@ -124,6 +138,7 @@ TINY = dict(
     antiaffinity=dict(scale=40, trickle=(3, 6, 1, 9, 4)),
     zonespread=dict(scale=40, trickle=(3, 6, 1, 9, 4)),
     preemption=dict(scale=40, trickle=(1, 3, 12, 0, 6, 20)),
+    quincy=dict(scale=40, trickle=(3, 6, 1, 9, 4)),
 )
 
 
@@ -657,13 +672,14 @@ class Smoke:
 
     # -- the placement rules, at the benchmark's size -----------------------
 
-    def _serve_rule(self, phase: str, name: str, counters) -> dict:
+    def _serve_rule(self, phase: str, name: str, counters, pod_maker=None) -> dict:
         """A benchmark deployment whose pods carry a placement rule, as
         cli.main builds it: the fill and trickle-sized rounds (as many of
         the oldest pods complete as arrive), every round's objective
         against native C++ on the same problem; `counters` are the
-        RoundTiming fields each round's line shows. Returns what the
-        phase's replay needs."""
+        RoundTiming fields each round's line shows. A pod carries its
+        class alone, or what `pod_maker(config, args)` makes of (round,
+        pod id, class). Returns what the phase's replay needs."""
         from benchmarks.client import BenchClusterAPI
         from ksched_tpu.cluster.api import PodEvent
         from ksched_tpu.solver.select import make_backend
@@ -672,6 +688,10 @@ class Smoke:
         sz = self.sizes[phase]
         config, args, api, svc = self._config_service(name, sz["scale"], BenchClusterAPI)
         api.svc = svc
+        if pod_maker is None:
+            make = lambda _r, pod, c: PodEvent(pod_id=pod, task_class=c)  # noqa: E731
+        else:
+            make = pod_maker(config, args)
         rng = np.random.default_rng([self.seed, 30])
         solver = svc.scheduler.solver
         rung = solver.backend.primary if svc.ladder is not None else solver.backend
@@ -688,7 +708,7 @@ class Smoke:
                 pod = f"pod_{len(group_of)}"
                 group_of[pod] = int(rng.integers(0, config["task_classes"]))
                 live.append(pod)
-                api.submit_pod(PodEvent(pod_id=pod, task_class=group_of[pod]))
+                api.submit_pod(make(r, pod, group_of[pod]))
             pods = api.poll_pod_batch(0.2)
             check(len(pods) == arrivals, f"{name}: {len(pods)} pods arrived, {arrivals} sent")
             mark = len(compiles)
@@ -715,7 +735,7 @@ class Smoke:
         api.close()
         st = solver.state
         return dict(
-            config=config, svc=svc, log=api.log, group_of=group_of, late=late,
+            config=config, svc=svc, log=api.log, polls=api.polls, group_of=group_of, late=late,
             shapes=f"machines={args.num_machines} nodes={st.n_cap} arcs={st.m_cap} "
             f"entries={st.plan.entry_cap} rounds={len(plan)} objectives==native in every round",
         )
@@ -766,6 +786,59 @@ class Smoke:
             f"(maxSkew {config['max_skew']}); compiles after the first trickle round: {run['late']}"
         )
 
+
+    def quincy(self) -> str:
+        """`gtrace-12500-quincy`: the served rounds under Quincy's
+        policy with its rack tier, and the replay of the Binding log
+        against the plain reference's optimum of every round, each
+        node's rack from the label the service holds."""
+        from benchmarks.pods.quincy_blocks import blocks_of
+        from benchmarks.reference_quincy import check_data_locality
+        from ksched_tpu.cluster.api import PodEvent
+        from ksched_tpu.data import RACK_LABEL
+
+        name = "gtrace-12500-quincy"
+        inputs_of: dict = {}
+
+        def pod_maker(config, args):
+            # the cluster the pods' blocks lie in is the one that was built
+            argv = list(config["argv"])
+            argv[argv.index("--num-machines") + 1] = str(args.num_machines)
+            built = dict(config, argv=argv)
+
+            def make(r, pod, c):
+                # the fill reads nothing, as the benchmark's resident pods
+                inputs_of[pod] = blocks_of(pod, built, self.seed) if r else ()
+                return PodEvent(pod_id=pod, task_class=c, inputs=inputs_of[pod])
+
+            return make
+
+        run = self._serve_rule(
+            "quincy", name,
+            ("pref_arcs_live", "pref_arcs_changed", "ec_chain_arcs_changed", "ec_arcs_changed",
+             "bound_via_machine", "bound_via_rack", "bound_via_cluster", "unscheduled_by_rule"),
+            pod_maker=pod_maker,
+        )
+        svc, config = run["svc"], run["config"]
+        rack_of = {
+            node: svc.resource_map.find(machine).descriptor.labels[RACK_LABEL]
+            for node, machine in svc.node_to_machine.items()
+        }
+        racks = len(set(rack_of.values()))
+        check(racks == min(config["racks"], len(rack_of)), f"{name}: {racks} racks, the file says {config['racks']}")
+        faults, facts = check_data_locality(
+            run["log"], inputs_of, rack_of, svc.max_tasks_per_pu,  # 1 core x 1 PU a node
+            admitted=[(t1, n) for _t0, t1, n in run["polls"] if n],
+        )
+        check(not faults, f"{name}: {faults}")
+        check(facts["rounds_compared"] == facts["rounds"], f"{name}: a round was short of room")
+        return (
+            f"{run['shapes']}; {facts['replayed']} Bindings and completions replayed over "
+            f"{facts['rounds']} rounds in {racks} racks: served cost {facts['served_cost']} == "
+            f"optimum {facts['optimum_cost']}, bound through a machine / rack / X arc "
+            f"{facts['bound_via']}, remote bytes {facts['remote_bytes_share']:.1f}%; "
+            f"compiles after the first trickle round: {run['late']}"
+        )
 
     def preemption(self) -> str:
         """`k8s-5000-preemption`: the first served rounds with
@@ -872,7 +945,7 @@ class Smoke:
 
 PHASES = (
     "served", "array", "kernels", "general", "sharded", "resident", "antiaffinity", "zonespread",
-    "preemption",
+    "preemption", "quincy",
 )
 
 

@@ -24,7 +24,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 from .cluster import Binding, ClusterAPI, NodeEvent, PodEvent, SyntheticClusterAPI
 from .cluster.api import RETRY_STAT_KEYS
 from .costmodels import MODEL_REGISTRY, CostModelType
-from .data import ZONE_LABEL
+from .data import RACK_LABEL, ZONE_LABEL
 from .obs import metrics as obs_metrics
 from .obs.flight import FlightRecorder
 from .obs.spans import SpanTracer, active_tracer, span
@@ -85,6 +85,7 @@ class SchedulerService:
         tenant: str = "",
         audit_every: int = 0,
         fake_zones: int = 0,
+        fake_racks: int = 0,
         preemption: bool = False,
         _restored: Optional[Tuple] = None,
     ) -> None:
@@ -119,6 +120,9 @@ class SchedulerService:
         #: --fake-zones: the fake machines of init_topology carry a zone
         #: label, machine i that of zone i mod fake_zones (0: no label)
         self.fake_zones = fake_zones
+        #: --fake-racks: the same for a rack label, machine i that of
+        #: rack i mod fake_racks
+        self.fake_racks = fake_racks
         self.injector = injector
         self.tracer = tracer
         self.flight = flight
@@ -263,16 +267,23 @@ class SchedulerService:
         """Fabricate machines (-fakeMachines, reference :191-202) or poll
         the control plane for nodes (:206-238). With ``fake_zones`` the
         fake machines are dealt round-robin over that many zones, as
-        scheduler_perf's labelNodePrepareStrategy deals its values."""
+        scheduler_perf's labelNodePrepareStrategy deals its values; with
+        ``fake_racks`` over that many racks, the same way."""
         if fake_machines > 0:
-            zones = self.fake_zones
+            dealt = [
+                (key, prefix, n)
+                for key, prefix, n in (
+                    (RACK_LABEL, "rack", self.fake_racks), (ZONE_LABEL, "zone", self.fake_zones),
+                )
+                if n > 0
+            ]
             for i in range(fake_machines):
                 self.add_node(
                     NodeEvent(
                         node_id=f"fake_node_{i}",
                         num_cores=cores_per_machine,
                         pus_per_core=pus_per_core,
-                        labels=((ZONE_LABEL, f"zone-{i % zones}"),) if zones > 0 else (),
+                        labels=tuple((key, f"{prefix}-{i % n}") for key, prefix, n in dealt),
                     )
                 )
             return fake_machines
@@ -341,6 +352,14 @@ class SchedulerService:
                 **model.task_class_fields(pod.task_class),
                 **model.task_priority_fields(pod.priority),
             }
+            if pod.inputs:
+                # so are its inputs; the nodes are the service's to resolve
+                # (a replica on a node it does not know is no replica here)
+                known = self.node_to_machine
+                of_class.update(model.task_input_fields([
+                    (block, size, [known[n] for n in nodes if n in known])
+                    for block, size, nodes in pod.inputs
+                ]))
         except ValueError as e:
             raise ValueError(f"pod {pod.pod_id}: {e}") from None
         existing = self.pod_to_task.get(pod.pod_id)
@@ -1255,6 +1274,10 @@ def build_arg_parser() -> argparse.ArgumentParser:
     ap.add_argument("--fake-zones", type=int, default=0, metavar="N",
                     help="label fake machine i with zone i mod N "
                     "(topology.kubernetes.io/zone; 0 = no label)")
+    ap.add_argument("--fake-racks", type=int, default=0, metavar="N",
+                    help="label fake machine i with rack i mod N "
+                    "(topology.kubernetes.io/rack, which --cost-model quincy "
+                    "reads; 0 = no label: one rack)")
     ap.add_argument("--cores-per-machine", type=int, default=1)
     ap.add_argument("--pus-per-core", type=int, default=1)
     ap.add_argument(
@@ -1357,11 +1380,16 @@ def build_service(
     (chip_smoke.py drives this same construction round by round)."""
     from .solver.select import make_backend
 
+    refuse_costs_that_cannot_fit(args)
+    cost_model = CostModelType[args.cost_model.upper()]
     return SchedulerService(
         api,
         max_tasks_per_pu=args.max_tasks_per_pu,
-        cost_model=CostModelType[args.cost_model.upper()],
-        backend=make_backend(args.backend, preemption=args.preemption),
+        cost_model=cost_model,
+        backend=make_backend(
+            args.backend, preemption=args.preemption,
+            price_updates=MODEL_REGISTRY[cost_model].routes_differ_in_cost,
+        ),
         backend_name=args.backend,
         degrade=not args.no_degrade,
         round_deadline_s=args.round_deadline,
@@ -1372,8 +1400,41 @@ def build_service(
         device_resident=args.device_resident,
         audit_every=args.audit_every,
         fake_zones=args.fake_zones,
+        fake_racks=args.fake_racks,
         preemption=args.preemption,
     )
+
+
+def refuse_costs_that_cannot_fit(args) -> None:
+    """`--backend jax` scales costs by the node count: a path whose cost
+    times the node count reaches 2^28 reads as unreachable to its price
+    tightening, and a round whose `max|cost| * nodes` reaches 2^30 raises
+    (solver/jax_solver.py), after which the ladder steps down in every
+    round. Where the model states its largest cost (the most a path of
+    its graph costs: one priced arc a path) and the flags give the
+    cluster (`--fake-machines`), that is known before the service
+    exists: the node bucket of the cluster when every slot holds a pod,
+    times the largest cost. ValueError with the numbers, where it
+    cannot fit."""
+    largest = MODEL_REGISTRY[CostModelType[args.cost_model.upper()]].largest_cost
+    if args.backend != "jax" or largest is None or not args.fake_machines:
+        return
+    from .solver.jax_solver import MAX_SCALED_PATH_COST
+    from .utils import next_pow2
+
+    pus = args.num_machines * args.cores_per_machine * args.pus_per_core
+    resources = 1 + args.num_machines * (1 + args.cores_per_machine) + pus
+    # sink, the job's unscheduled aggregator, the cluster aggregator, a rack each
+    nodes = resources + pus * args.max_tasks_per_pu + 3 + max(1, args.fake_racks)
+    bucket = next_pow2(nodes)
+    if largest * bucket >= MAX_SCALED_PATH_COST:
+        raise ValueError(
+            f"--cost-model {args.cost_model} states a largest cost of {largest}; with every "
+            f"slot of {args.num_machines} machines taken the graph has {nodes} nodes, a "
+            f"bucket of {bucket}, and --backend jax needs largest cost x bucket < "
+            f"{MAX_SCALED_PATH_COST} ({largest * bucket} is not): a coarser cost quantum, "
+            "a smaller cluster or --backend native"
+        )
 
 
 def main(argv=None) -> int:

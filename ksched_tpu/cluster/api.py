@@ -31,6 +31,13 @@ class PodEvent:
     #: it rides the task as `TaskDescriptor.priority`). 0 everywhere
     #: but under a model that prices it (--cost-model k8s_priority)
     priority: int = 0
+    #: what the pod reads, for a model that places by data locality
+    #: (--cost-model quincy): one (block id, bytes, the ids of the nodes
+    #: that hold a replica) for each input block, as tuples so that the
+    #: event stays hashable; the service resolves the nodes and hands
+    #: the blocks to the cost model (`CostModeler.task_input_fields`),
+    #: which fills `TaskDescriptor.dependencies` and its block registry
+    inputs: Tuple[Tuple[int, int, Tuple[str, ...]], ...] = ()
     #: perf_counter stamp of the moment the control plane surfaced the
     #: pod (every source constructs the event then); the service round
     #: that admits it reads its queue wait from this. Not part of the

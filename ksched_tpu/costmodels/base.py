@@ -110,6 +110,32 @@ class CostModeler(abc.ABC):
     #: without ``preemption`` refuses it.
     needs_preemption: bool = False
 
+    #: What a model says about itself where a task's turn lists arcs of
+    #: the task's own (to machines, to equivalence classes other than
+    #: the cluster aggregator): the graph manager then opens a
+    #: `pref_refresh` span around that half of every task's turn. False:
+    #: no such span (a span a task would cost the other models' waves).
+    lists_task_preferences: bool = False
+
+    #: What a model says about itself where the routes it gives one
+    #: task differ in cost (a preferred machine, its rack, the cluster):
+    #: two tasks that contend for one slot then settle, in the scan-CSR
+    #: rung's eps = 1 discharge, by unit relabels over the cost gap
+    #: times the node count (22,291 supersteps for 4 arrivals on 312
+    #: machines, one gap of 4; CHANGES.md, PR 42), which ends in no
+    #: round's time. `cli.build_service` then asks that rung for its
+    #: global price update (JaxSolver.price_update_every), as it does
+    #: under `--preemption`. False: the routes of a task cost alike.
+    routes_differ_in_cost: bool = False
+
+    #: The largest cost the model puts on any arc, where it states one
+    #: (None: it states none). The scan-CSR rung scales costs by the
+    #: node count and refuses `max|cost| * nodes >= 2^30` inside a round
+    #: (solver/jax_solver.py); `cli.build_service` holds a model that
+    #: states its largest cost, and a cluster whose size the flags give,
+    #: to that bound before the service exists.
+    largest_cost: Optional[int] = None
+
     #: 1 while the graph update of the round in progress had to leave
     #: the model's own allotment for a per-pod predicate (the zone
     #: spread model, where a zone is short of room); the scheduler
@@ -238,6 +264,24 @@ class CostModeler(abc.ABC):
         prices priority and has no such tier. The default: carried as it
         is, and read by nothing."""
         return {"priority": priority}
+
+    def task_input_fields(self, blocks: Sequence[Tuple[int, int, Sequence[int]]]) -> Dict[str, object]:
+        """What ``PodEvent.inputs`` is on a ``TaskDescriptor`` for this
+        model, as the fields to set: one (block id, bytes, machines that
+        hold a replica, as resource ids) a block, the nodes already
+        resolved by the service. The default: nothing (a model that
+        prices no locality reads no input)."""
+        return {}
+
+    def round_locality(self) -> Optional[Tuple[int, int, int, int, int]]:
+        """What the round in progress has bound so far, for a model that
+        places by data locality: tasks bound by the cheapest route they
+        had to their machine (a machine arc, a rack arc, the cluster
+        aggregator), and the bytes those tasks read and read from
+        another machine. None (the default): the model keeps no such
+        count. Read by the scheduler before ``note_round``, which starts
+        the counts again."""
+        return None
 
     def equiv_class_pref_arc_changes(self, ec: int) -> Optional[List[int]]:
         """The resources whose arc from ``ec`` may have changed (come,

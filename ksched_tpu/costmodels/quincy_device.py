@@ -28,7 +28,7 @@ worst-case transfer seen among overflowed signatures, so their
 reported cost is conservative (never under the true route cost); the
 overflow count is reported so callers can size G_cap properly.
 
-The wait-cost starvation bound (QuincyCostModel.note_round,
+The wait-cost starvation bound (QuincyCostModel.note_round; here
 WAIT_COST_PER_ROUND) ages at GROUP granularity here: bump_wait raises
 the escape cost of groups that still have backlog. Tasks of one group
 are admitted and aged together, which preserves the bound's purpose —
@@ -41,13 +41,16 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .quincy import (
-    COST_PER_MB,
-    MB,
-    PREFERENCE_FRACTION,
-    WAIT_COST_PER_ROUND,
-    BlockRegistry,
-)
+from .quincy import MB, BlockRegistry
+
+#: the twin prices ONE tier, as the served model did until PR 42 gave it
+#: the rack tier (costmodels/quincy.py): a cost unit a megabyte pulled from
+#: another machine, a direct arc to a machine that holds more than half the
+#: input, 10 a round waited. tests/test_quincy_device.py holds it to the
+#: host graph path under a model restated to these numbers
+COST_PER_MB = 1
+PREFERENCE_FRACTION = 0.5
+WAIT_COST_PER_ROUND = 10
 
 #: re-exported sentinel (scheduler/device_bulk.py) so callers need one import
 from ..scheduler.device_bulk import PREF_NONE  # noqa: F401

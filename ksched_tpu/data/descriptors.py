@@ -23,6 +23,11 @@ from typing import Dict, List, Optional
 #: the well-known node label that names a node's zone (Kubernetes,
 #: "Well-Known Labels, Annotations and Taints")
 ZONE_LABEL = "topology.kubernetes.io/zone"
+#: the node label that names a node's rack, for a policy that prices the
+#: switches between a task and its input (costmodels/quincy.py);
+#: Kubernetes has no well-known key for it, clusters that label racks
+#: use one of this shape
+RACK_LABEL = "topology.kubernetes.io/rack"
 
 
 class TaskState(enum.IntEnum):

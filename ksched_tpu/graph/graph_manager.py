@@ -29,7 +29,7 @@ from typing import Deque, Dict, Iterable, List, Optional, Set, Tuple
 
 import numpy as np
 
-from ..costmodels.base import CostModeler
+from ..costmodels.base import CLUSTER_AGGREGATOR_EC, CostModeler
 from ..data import (
     DeltaType,
     JobDescriptor,
@@ -51,6 +51,18 @@ TaskMapping = Dict[int, int]  # task node id -> PU node id (flowmanager/types.go
 def task_needs_node(td: TaskDescriptor) -> bool:
     """Reference: graph_manager.go:1333-1338."""
     return td.state in (TaskState.RUNNABLE, TaskState.RUNNING, TaskState.ASSIGNED)
+
+
+def _is_pref_arc(arc: Arc) -> bool:
+    """An arc of a task's own choosing: from the task to a resource, or
+    to an equivalence class other than the cluster aggregator; not its
+    running arc (the arc it is placed by), nor the two every task has."""
+    if arc.type == ArcType.RUNNING:
+        return False
+    dst = arc.dst_node
+    if dst.resource_id != 0:
+        return True
+    return dst.equiv_class is not None and dst.equiv_class != CLUSTER_AGGREGATOR_EC
 
 
 class _TurnRuns:
@@ -128,6 +140,9 @@ class GraphManager:
         #: preference (an EC -> EC arc): while one lives, the purge
         #: leaves the listed EC alone though no arc enters it
         self._ec_listed_by: Dict[int, Set[int]] = {}
+        #: the ECs among those listers: the ones whose arcs to other ECs
+        #: an update may have to prune
+        self._ec_listers: Set[int] = set()
         #: the cost model can neither re-price a pinned task's one arc
         #: nor learn anything from a task node in the statistics walk
         self._tasks_inert = cost_model.pinned_tasks_are_inert
@@ -135,6 +150,9 @@ class GraphManager:
         #: where the model could only re-price its arcs at the price
         #: they have (_queue_res_turn)
         self._res_turns = not cost_model.resource_arc_costs_are_fixed
+        #: the model lists arcs of a task's own: that half of a task's
+        #: turn gets a span (`pref_refresh`)
+        self._pref_spans = cost_model.lists_task_preferences
         #: job id -> task uid -> (tree path, descriptor): the tasks the
         #: per-round update visits. A task is listed while it has or
         #: needs a node, unless it is pinned and the model calls pinned
@@ -160,6 +178,13 @@ class GraphManager:
         #: a no-op)
         self.res_nodes_visited = 0
         self.res_arcs_changed = 0
+        #: a task's own preference arcs (_is_pref_arc: to a resource, or
+        #: to an EC other than the cluster aggregator; never its running
+        #: arc): how many the graph holds, and how many came or went
+        #: since take_pref_arcs_moved was last called (an update's adds
+        #: and prunes, the pins and removals after it)
+        self.pref_arcs_live = 0
+        self._pref_arcs_moved = 0
         #: the last purge_unconnected_equiv_class_nodes: EC nodes it
         #: removed, and the arcs that went with them
         self.ec_purged = 0
@@ -204,6 +229,16 @@ class GraphManager:
         self.apply_pus_dirty = 0
         self.apply_nodes_visited = 0
         self.apply_full_walk = 0
+
+    def _pref_arcs(self, delta: int) -> None:
+        """``delta`` preference arcs came (> 0) or went (< 0)."""
+        self.pref_arcs_live += delta
+        self._pref_arcs_moved += abs(delta)
+
+    def take_pref_arcs_moved(self) -> int:
+        """Preference arcs that came or went since the last call."""
+        moved, self._pref_arcs_moved = self._pref_arcs_moved, 0
+        return moved
 
     def _set_pinned(self, task_node: Node, pinned: bool) -> None:
         node_id = task_node.id
@@ -810,8 +845,10 @@ class GraphManager:
         ec = node.equiv_class
         del self.task_ec_to_node[ec]
         self._ec_listed_by.pop(ec, None)
-        for listers in self._ec_listed_by.values():
-            listers.discard(ec)
+        if ec in self._ec_listers:
+            self._ec_listers.discard(ec)
+            for listers in self._ec_listed_by.values():
+                listers.discard(ec)
         self.cm.delete_node(node, ChangeType.DEL_EQUIV_CLASS_NODE, "RemoveEquivClassNode")
 
     def _remove_resource_node(self, node: Node) -> None:
@@ -825,6 +862,8 @@ class GraphManager:
         node_id = node.id
         node.excess = 0
         self.sink_node.excess += 1
+        if self.pref_arcs_live:
+            self._pref_arcs(-sum(1 for arc in node.outgoing.values() if _is_pref_arc(arc)))
         del self.task_to_node[node.task.uid]
         # node ids are reused: the next holder of this one starts unpinned
         self._set_pinned(node, False)
@@ -957,6 +996,13 @@ class GraphManager:
             )
             return
         self._update_task_to_unscheduled_agg_arc(task_node)
+        if self._pref_spans:
+            # the task's own arcs, with the model's arithmetic over its
+            # input (made at the first of these questions)
+            with span("pref_refresh"):
+                self._update_task_to_equiv_arcs(task_node, node_queue, marked)
+                self._update_task_to_res_arcs(task_node, node_queue, marked)
+            return
         self._update_task_to_equiv_arcs(task_node, node_queue, marked)
         self._update_task_to_res_arcs(task_node, node_queue, marked)
 
@@ -974,6 +1020,7 @@ class GraphManager:
             if pref_node is None:
                 pref_node = self._add_equiv_class_node(pref_ec)
             self._ec_listed_by.setdefault(pref_ec, set()).add(ec)
+            self._ec_listers.add(ec)
             cost, cap_upper = self.cost_model.equiv_class_to_equiv_class(ec, pref_ec)
             arc = self.cm.graph.get_arc(ec_node, pref_node)
             if arc is None:
@@ -994,7 +1041,7 @@ class GraphManager:
                 node_queue.append((pref_node, pref_node.task))
         # an EC that never listed another has no arc to one: its arcs to
         # resources (a zone's 1,667 machines) are not looked through
-        if pref_ecs or any(ec in listers for listers in self._ec_listed_by.values()):
+        if pref_ecs or ec in self._ec_listers:
             self.ec_chain_arcs_changed += self._remove_invalid_ec_pref_arcs(
                 ec_node, pref_ecs, ChangeType.DEL_ARC_BETWEEN_EQUIV_CLASS
             )
@@ -1150,6 +1197,8 @@ class GraphManager:
                     task_node, pref_node, 0, 1, cost, ArcType.OTHER,
                     ChangeType.ADD_ARC_TASK_TO_EQUIV_CLASS, "UpdateTaskToEquivArcs",
                 )
+                if pref_ec != CLUSTER_AGGREGATOR_EC:
+                    self._pref_arcs(1)
             else:
                 self.cm.change_arc(
                     arc, arc.cap_lower, arc.cap_upper, cost,
@@ -1176,6 +1225,7 @@ class GraphManager:
                     task_node, pref_node, 0, 1, cost, ArcType.OTHER,
                     ChangeType.ADD_ARC_TASK_TO_RES, "UpdateTaskToResArcs",
                 )
+                self._pref_arcs(1)
             elif arc.type != ArcType.RUNNING:
                 # Running arcs are priced by TaskContinuationCost elsewhere.
                 self.cm.change_arc_cost(arc, cost, ChangeType.CHG_ARC_TASK_TO_RES, "UpdateTaskToResArcs")
@@ -1224,6 +1274,8 @@ class GraphManager:
             for arc in node.outgoing.values()
             if arc.dst_node.equiv_class is not None and arc.dst_node.equiv_class not in pref
         ]
+        if to_delete and node.is_task_node:
+            self._pref_arcs(-sum(1 for arc in to_delete if _is_pref_arc(arc)))
         for arc in to_delete:
             self.cm.delete_arc(arc, change_type, "RemoveInvalidECPrefArcs")
         return len(to_delete)
@@ -1240,6 +1292,8 @@ class GraphManager:
             for arc in node.outgoing.values()
             if arc.dst_node.resource_id != 0 and arc.dst_node.resource_id not in pref
         ]
+        if to_delete and node.is_task_node:
+            self._pref_arcs(-sum(1 for arc in to_delete if _is_pref_arc(arc)))
         for arc in to_delete:
             self.cm.delete_arc(arc, change_type, "RemoveInvalidPrefResArcs")
         return len(to_delete)
@@ -1259,6 +1313,8 @@ class GraphManager:
             # running arc (the graph doesn't support multi-arcs; reference
             # note at graph_manager.go:869-872).
             running_arc = self.cm.graph.get_arc(task_node, res_node)
+            if running_arc is not None and _is_pref_arc(running_arc):
+                self._pref_arcs(-1)
         if running_arc is not None:
             running_arc.type = ArcType.RUNNING
             self.cm.change_arc(running_arc, 0, 1, new_cost, ChangeType.CHG_ARC_RUNNING_TASK,
@@ -1279,6 +1335,9 @@ class GraphManager:
         running arc with lower bound 1 (reference: graph_manager.go:675-720)."""
         added_running_arc = False
         task_id = task_node.task.uid
+        # every arc of the task's own goes, or becomes its running arc
+        if self.pref_arcs_live:
+            self._pref_arcs(-sum(1 for arc in task_node.outgoing.values() if _is_pref_arc(arc)))
         for arc in list(task_node.outgoing.values()):
             if arc.dst != res_node.id:
                 self.cm.delete_arc(arc, ChangeType.DEL_ARC_TASK_TO_EQUIV_CLASS, "PinTaskToNode")

@@ -142,6 +142,20 @@ class RoundRecord:
     #: eviction and a Binding), and pods evicted at some time and still
     #: without a new Binding once the round was posted
     tasks_unpinned: int = 0
+    #: tasks' own preference arcs in the graph at the solve and those
+    #: the round added or removed; under a model that places by data
+    #: locality (--cost-model quincy) the tasks bound by their cheapest
+    #: route (machine arc, rack arc, cluster aggregator), the share of
+    #: them bound through an arc of their own and the share of their
+    #: input bytes on another machine than theirs, in percent (from the
+    #: round's RoundTiming)
+    pref_arcs_live: int = 0
+    pref_arcs_changed: int = 0
+    bound_via_machine: int = 0
+    bound_via_rack: int = 0
+    bound_via_cluster: int = 0
+    bound_on_preferred_share: float = 0.0
+    remote_bytes_share: float = 0.0
     pods_evicted: int = 0
     pods_migrated: int = 0
     pods_pending_evicted: int = 0
@@ -318,6 +332,13 @@ class RoundTracer:
             ec_chain_arcs_changed=t.ec_chain_arcs_changed,
             spread_fallback=t.spread_fallback,
             tasks_unpinned=t.tasks_unpinned,
+            pref_arcs_live=t.pref_arcs_live,
+            pref_arcs_changed=t.pref_arcs_changed,
+            bound_via_machine=t.bound_via_machine,
+            bound_via_rack=t.bound_via_rack,
+            bound_via_cluster=t.bound_via_cluster,
+            bound_on_preferred_share=t.bound_on_preferred_share,
+            remote_bytes_share=t.remote_bytes_share,
         )
         for k, v in (extra or {}).items():
             if not hasattr(rec, k):

@@ -133,6 +133,23 @@ class RoundTiming:
     #: whose running arc is no pin (GraphManager.unpinned_running_tasks
     #: at the solve: every running task under preemption, none without)
     tasks_unpinned: int = 0
+    #: tasks' own preference arcs (to a machine, to an EC other than the
+    #: cluster aggregator): in the graph at the solve, and those the
+    #: round added or removed (its update's adds and prunes, its pins)
+    pref_arcs_live: int = 0
+    pref_arcs_changed: int = 0
+    #: under a model that places by data locality
+    #: (CostModeler.round_locality; zeros elsewhere): the tasks the
+    #: round bound whose cheapest route to their machine was a machine
+    #: arc, a rack arc, the cluster aggregator; of those tasks the share
+    #: bound through an arc of their own, and the share of their input
+    #: bytes that lies on another machine than the one they were bound
+    #: to (the policy's own objective), both in percent
+    bound_via_machine: int = 0
+    bound_via_rack: int = 0
+    bound_via_cluster: int = 0
+    bound_on_preferred_share: float = 0.0
+    remote_bytes_share: float = 0.0
 
 
 class FlowScheduler:
@@ -484,6 +501,7 @@ class FlowScheduler:
                 sp.set("ec_chain_arcs_changed", timing.ec_chain_arcs_changed)
             timing.graph_update_s = sp.dur_s
             timing.tasks_unpinned = self.gm.unpinned_running_tasks
+            timing.pref_arcs_live = self.gm.pref_arcs_live
         except BaseException:
             round_span.__exit__(*sys.exc_info())
             raise
@@ -617,6 +635,18 @@ class FlowScheduler:
                 for t in tasks
                 if t not in self.task_bindings
             ]
+            timing.pref_arcs_changed = self.gm.take_pref_arcs_moved()
+            locality = self.cost_model.round_locality()
+            if locality is not None:
+                via_machine, via_rack, via_cluster, read, remote = locality
+                timing.bound_via_machine = via_machine
+                timing.bound_via_rack = via_rack
+                timing.bound_via_cluster = via_cluster
+                with_input = via_machine + via_rack + via_cluster
+                if with_input:
+                    timing.bound_on_preferred_share = 100.0 * (via_machine + via_rack) / with_input
+                if read:
+                    timing.remote_bytes_share = 100.0 * remote / read
             self.cost_model.note_round(unscheduled)
             timing.unscheduled_by_rule = max(
                 0, min(len(unscheduled), self._free_slots_at_solve - num_scheduled)

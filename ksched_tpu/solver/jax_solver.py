@@ -227,6 +227,12 @@ def _seg_ends(node_first, node_last, node_nonempty, vals, cums, *at_last):
 
 
 _BIG_D = 1 << 28  # "unreachable" distance sentinel for price tightening
+#: so a path's cost times the node count has to stay below it: a tightened
+#: distance of _BIG_D reads as unreachable (the refusal inside a round,
+#: `max|cost| * nodes >= 2^30`, is of one arc and comes later). A cost model
+#: that states its largest cost is held to this before the service exists
+#: (cli.refuse_costs_that_cannot_fit)
+MAX_SCALED_PATH_COST = _BIG_D
 
 #: a service with preemption (--preemption --backend jax): the discharge
 #: lowers its prices to the residual graph's exact ones after every this

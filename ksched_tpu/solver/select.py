@@ -14,7 +14,8 @@ from .base import FlowSolver
 
 
 def make_backend(
-    name: str, warm_start: bool = True, fallback: bool = True, preemption: bool = False
+    name: str, warm_start: bool = True, fallback: bool = True, preemption: bool = False,
+    price_updates: bool = False,
 ) -> FlowSolver:
     """name: "native" | "jax" | "ell" | "mega" | "sharded" | "ref" |
     "layered" | "auto". With fallback=True a failed native build degrades to the
@@ -23,7 +24,9 @@ def make_backend(
     ``preemption`` says the graphs to come keep their running tasks'
     arcs: the scan-CSR rung ("jax") then runs its global price update
     (JaxSolver.price_update_every), which no other rung has; every
-    other name ignores it."""
+    other name ignores it. ``price_updates`` asks for the same update
+    for another reason: a cost model whose routes differ in cost
+    (CostModeler.routes_differ_in_cost)."""
     if name == "native":
         try:
             from .native import NativeSolver
@@ -43,7 +46,9 @@ def make_backend(
 
         return JaxSolver(
             warm_start=warm_start,
-            price_update_every=PREEMPTION_PRICE_UPDATE_EVERY if preemption else 0,
+            price_update_every=(
+                PREEMPTION_PRICE_UPDATE_EVERY if preemption or price_updates else 0
+            ),
         )
     if name == "ell":
         # bucketed-ELL layout of the same push-relabel (ell_solver.py):

@@ -40,30 +40,61 @@ def _seeded_rng():
     yield
 
 
-#: Two accepted tests of tests/benchmark/test_benchmark_seams.py (PR 37) state
-#: that every cell's pods are `class_only` and that `benchmarks/pods/` holds
-#: that module alone. `k8s-5000-preemption` (PR 38) brings `pods/by_role.py`,
-#: so both sentences are false for it, and a PR that adds a configuration may
-#: not edit a file the benchmark has (nor `tests/benchmark/conftest.py`, where
-#: PR 33 kept such a mark). The tests are NOT taken out of the collection:
-#: they run and are reported `xfailed`, only their `AssertionError` is
-#: expected, and `strict` turns the run red once a `benchmark` PR has
-#: rewritten them (parametrize the first over `PLAN_DIGESTS`, list both
-#: modules in the second), which deletes this hook with that edit (PERF.md
-#: section 7). `test_benchmark_preemption.py` holds what stays true of both.
-_STALE_SINCE_PR38 = (
+#: Accepted tests under tests/benchmark/ that a later deployment made false,
+#: each with the PR that did; a PR that adds a configuration may edit no file
+#: the benchmark has (nor `tests/benchmark/conftest.py`, where PR 33 kept such
+#: a mark). The tests are NOT taken out of the collection: they run and are
+#: reported `xfailed`, only their `AssertionError` is expected, and `strict`
+#: turns the run red once a `benchmark` PR has rewritten them, which deletes
+#: this hook with that edit (PERF.md section 7, item (0)).
+#:
+#: PR 37's `test_benchmark_seams.py` states that every cell's pods are
+#: `class_only` and that `benchmarks/pods/` holds that module alone.
+#: `k8s-5000-preemption` (PR 38) brings `pods/by_role.py` and
+#: `gtrace-12500-quincy` (PR 42) `pods/quincy_blocks.py`: parametrize the first
+#: over `PLAN_DIGESTS`, list the three modules in the second.
+#: `test_benchmark_preemption.py` and `test_benchmark_quincy.py` hold what
+#: stays true of both.
+#:
+#: PR 38's `test_benchmark_preemption.py` pins its own cell to the LAST place
+#: of `configs`, of `workloads` (and their number to 9) and of thirteen
+#: metrics' lists; PR 42 appended a configuration, a cell and its name to
+#: eleven of those lists, as the contract asks ("put new entries at the end"):
+#: look the entries up by name there. `test_benchmark_quincy.py` holds what
+#: stays true of that cell (`test_what_stays_true_of_the_cell_before_it`).
+#:
+#: PR 33's `test_benchmark_zonespread.py` and PR 41's
+#: `test_benchmark_plan_refit.py` pin the cell lists of the two
+#: `ec_chain_*` and the five `plan_*` metrics to what those PRs left; PR 42's
+#: cell works the chain arcs and keeps a slot plan, and ISSUE 42 asks for
+#: its name on those seven lists (its section 5 column reads them): state
+#: the lists as "starts with" there.
+_STALE = {
     "test_benchmark_seams.py::test_class_only_is_the_old_expression_and_the_same_seed_"
-    "draws_the_same_plan[k8s-5000-preemption.rollout-",
+    "draws_the_same_plan[k8s-5000-preemption.rollout-": "PR 38 brings pods/by_role.py",
+    "test_benchmark_seams.py::test_class_only_is_the_old_expression_and_the_same_seed_"
+    "draws_the_same_plan[gtrace-12500-quincy.trickle-": "PR 42 brings pods/quincy_blocks.py",
     "test_benchmark_seams.py::test_class_only_stamps_each_event_as_it_is_made_and_draws_"
-    "nothing_from_the_frameworks_rng",
-)
+    "nothing_from_the_frameworks_rng": "PR 38 and PR 42 each bring a module under pods/",
+    "test_benchmark_zonespread.py::test_each_metric_it_brings_is_an_entry_with_its_file_for_"
+    "this_cell_alone[ec_chain_": "PR 42 appended its cell to the two ec_chain_* lists",
+    "test_benchmark_plan_refit.py::test_the_entry_equals_its_file_and_lists_the_scan_csr_"
+    "cells[": "PR 42 appended its cell to the five plan_* lists",
+    "test_benchmark_plan_refit.py::test_a_cell_loads_the_five_by_name_if_it_is_listed_and_"
+    "none_otherwise[gtrace-12500-quincy.trickle]": "PR 42's cell is on the five plan_* lists",
+    "test_benchmark_preemption.py::test_the_configuration_is_the_sources_shapes":
+        "PR 42 appended a configuration after it",
+    "test_benchmark_preemption.py::test_the_cell_takes_one_chip_and_the_new_mix_completes_"
+    "nothing": "PR 42 appended a tenth cell, and its name to eleven of the thirteen lists",
+}
 
 
 def pytest_collection_modifyitems(items):
     for item in items:
-        if any(stale in item.nodeid for stale in _STALE_SINCE_PR38):
-            item.add_marker(pytest.mark.xfail(
-                reason="PR 37's pin of every cell's pods to class_only; PR 38 brings "
-                "pods/by_role.py and may not edit the file: the next benchmark PR does",
-                raises=AssertionError, strict=True,
-            ))
+        for stale, why in _STALE.items():
+            if stale in item.nodeid:
+                item.add_marker(pytest.mark.xfail(
+                    reason=f"an accepted pin that a later deployment made false ({why}); no "
+                    "model_config PR may edit the file: the next benchmark PR does",
+                    raises=AssertionError, strict=True,
+                ))

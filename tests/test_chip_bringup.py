@@ -73,6 +73,13 @@ PHASE_FACTS = {
         r"\d+ entries replayed: bound \[\d+, 42\], evicted \[\d+, 0\], [1-9]\d* evicted pods bound again",
         r"compiles after the first trickle round: 0",
     ),
+    "quincy": (
+        r"machines=312 nodes=8192 arcs=16384 ",
+        r"objectives==native in every round",
+        r"\d+ Bindings and completions replayed over 6 rounds in 250 racks: "
+        r"served cost (?P<cost>\d+) == optimum (?P=cost), bound through a machine / rack / X arc ",
+        r"compiles after the first trickle round: 0",
+    ),
 }
 
 

@@ -22,6 +22,21 @@ from ksched_tpu.utils import resource_id_from_string
 MB = 1 << 20
 
 
+class OneTierQuincy(QuincyCostModel):
+    """The served model restated to the array twin's pricing
+    (costmodels/quincy_device.py): a cost unit a megabyte, the core
+    switch priced as a rack switch (so a rack arc costs what X does and
+    decides nothing), a direct arc to a machine that holds MORE than
+    half the input, no clamp in reach."""
+
+    QUANTUM = MB
+    XI = 1
+    largest_cost = 1 << 20  # two 512 MB blocks cost 1,024: beyond the served model's clamp
+
+    def _preferred(self, held: int, total: int) -> bool:
+        return 2 * held > total
+
+
 # ---------------------------------------------------------------------------
 # group table semantics
 # ---------------------------------------------------------------------------
@@ -207,9 +222,9 @@ def _host_quincy_realized_cost(num_machines, slots_per_machine, task_blocks,
         num_cores=1,
         pus_per_core=slots_per_machine,
         max_tasks_per_pu=1,
-        cost_model_factory=QuincyCostModel,
+        cost_model_factory=OneTierQuincy,
     )
-    model: QuincyCostModel = sched.cost_model
+    model: OneTierQuincy = sched.cost_model
     machines = list(model._machines.keys())  # resource ids, machine order
     for b, locs in block_locs.items():
         model.blocks.register(b, block_size, [machines[m] for m in locs])
@@ -228,11 +243,12 @@ def _host_quincy_realized_cost(num_machines, slots_per_machine, task_blocks,
     # realized cost: placed -> cheapest available route to the bound
     # machine (pref arc if wired there, else the EC route at worst);
     # unplaced -> escape cost
+    from ksched_tpu.costmodels import CLUSTER_AGGREGATOR_EC
+
     bindings = sched.get_task_bindings()
     total_cost = 0
     for tid in task_ids:
-        total, local = model._input_bytes(tid)
-        worst = model._transfer_cost(total, 0)
+        worst = model.task_to_equiv_class_aggregator(tid, CLUSTER_AGGREGATOR_EC)
         pu_rid = bindings.get(tid)
         if pu_rid is None:
             total_cost += worst + 1  # task_to_unscheduled_agg_cost, wait=0
@@ -243,8 +259,8 @@ def _host_quincy_realized_cost(num_machines, slots_per_machine, task_blocks,
                 resource_id_from_string(node.parent_id)
             ).topology_node
         m_rid = resource_id_from_string(node.resource_desc.uuid)
-        direct = model._transfer_cost(total, local.get(m_rid, 0))
-        prefs = set(model.get_task_preference_arcs(tid))
+        prefs = set(model.preferred_machines(tid))  # full or not: the round is over
+        direct = model.task_to_resource_node_cost(tid, m_rid)
         total_cost += min(worst, direct) if m_rid in prefs else worst
     return total_cost, n
 
