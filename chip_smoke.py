@@ -52,7 +52,11 @@ speed:
            replay of the Bindings and completions
            (benchmarks/reference_antiaffinity.check_anti_affinity) finds
            no node that ever held two pods of one workload, and nothing
-           compiled after the first trickle round.
+           compiled after the first trickle round (in this leg, the
+           zonespread and the quincy one: but in a round that re-fitted
+           the slot plan, which runs the re-fitted programs itself;
+           here that is the round in which the purge has taken the
+           sixteen ECs' arcs).
   zonespread  the benchmark's `k8s-5000-zonespread` deployment from its
            file's argv (the same cluster in three zones, `--fake-zones 3
            --cost-model k8s_zonespread --backend jax`): the same fill and
@@ -715,7 +719,11 @@ class Smoke:
             t0 = time.perf_counter()
             bound = svc.run_round(pods)
             wall = time.perf_counter() - t0
-            if r > 1:
+            t = svc.scheduler.last_timing
+            # a round that re-fits the slot plan runs the re-fitted
+            # programs itself (FlowScheduler._refit_plan) so that the
+            # next round compiles nothing: its compiles are the re-fit's
+            if r > 1 and not t.plan_refits:
                 late += len(compiles) - mark
             check(bound == arrivals, f"{name} round {r}: bound {bound} of {arrivals}")
             check(api.completions_refused == 0, f"{name} round {r}: a completion was refused")
@@ -723,15 +731,18 @@ class Smoke:
             ours = int(solver.last_result.objective)
             theirs = int(native.solve(solver.state.problem()).objective)
             check(ours == theirs, f"{name} round {r}: objective {ours} != native {theirs}")
-            t = svc.scheduler.last_timing
             self.say(
                 f"{phase} round {r}: pods={arrivals} wall_ms={wall * 1e3:.1f} "
                 f"graph_update_ms={t.graph_update_s * 1e3:.1f} solve_ms={t.solve_s * 1e3:.1f} "
                 f"supersteps={int(rung.last_supersteps)} objective={ours} "
+                f"plan_rows={t.plan_rows} plan_refits={t.plan_refits} "
                 + " ".join(f"{c}={getattr(t, c)}" for c in counters)
             )
         check(svc.ladder is None or svc.ladder.degradations_total == 0, f"{name}: a step down the ladder")
-        check(late == 0, f"{name}: {late} programs compiled after the first trickle round")
+        check(
+            late == 0,
+            f"{name}: {late} programs compiled after the first trickle round, outside a round that re-fitted the plan",
+        )
         api.close()
         st = solver.state
         return dict(

@@ -585,12 +585,15 @@ def trace_jax_warmp(n_raw: int, m_raw: int, seed: int = 0, telemetry_cap: int = 
 
 def slot_stable_entry_cap(m_pad: int) -> int:
     """The entry-table extent the slot-stable layout pads to for an
-    m_pad-arc bucket in the common case (graph/slot_plan.SlotPlanState
-    ._rebuild: max(2*m_cap, next_pow2(need)) — need exceeds 2*m_cap
-    only when per-node slack rows outgrow the doubled entries, which
-    next_pow2 then absorbs; either way a pow2 of the bucket, never the
-    raw size). It is also the floor a re-fit returns a plan to once a
-    fill round's transient arcs are gone (SlotPlanState.refit)."""
+    m_pad-arc bucket at BUILD time (graph/slot_plan.SlotPlanState
+    ._rebuild, a first build or one that m_cap / n_cap growth forced:
+    max(2*m_cap, next_pow2(need)) — need exceeds 2*m_cap only when
+    per-node slack rows outgrow the doubled entries, which next_pow2
+    then absorbs; either way a pow2 of the bucket, never the raw
+    size). A re-fit may take a plan to a smaller pow2 once a fill
+    round's transient arcs are gone (SlotPlanState.refit,
+    refit_bucket): the same program at another E, which nothing here
+    relates to m."""
     return 2 * m_pad
 
 

@@ -101,15 +101,32 @@ the same move `scheduler/bulk.py` makes by pre-wiring arc endpoints:
   has been applied, and every superstep gathers and scans dead rows
   like live ones. At the END of each round the owner asks
   `refit_due()` (microseconds: the rows in use are twice the live
-  arcs): would a layout of the graph as it stands land in a smaller
-  bucket, the `2 * m_cap` floor and the arena's sixteenth included?
-  `refit()` then re-lays out from the LIVE degree (the marks are set
-  to it, not decayed halfway: the spike is over), slack and arena
-  funded from the bucket's surplus as in any rebuild. A re-fit that
-  growth undoes (an overflow or `m_cap` growth that raises
-  `entry_cap` again) doubles the rounds the next one waits, so a
-  workload whose peaks do not fit the smaller bucket pays a bounded
-  number of re-layouts and settles at the larger. The lifecycle is
+  arcs): is there a smaller bucket that holds the graph as it stands
+  with room to drift, a quarter more rows and the arena's sixteenth
+  (`refit_bucket`)? `refit()` then re-lays out from the LIVE degree
+  (the marks are set to it, not decayed halfway: the spike is over),
+  slack and arena funded from the bucket's surplus as in any rebuild.
+  `2 * m_cap` is what a BUILD allows for (a first build, and one that
+  `m_cap` / `n_cap` growth forced: room for every slot the arc table
+  can hand out, so a fill grows the plan only with the table), not a
+  floor: the arc table never shrinks, and a re-fit goes below twice
+  it when the fill that sized it is over (nothing in the solve
+  relates E to m: the `[2m]` `inv_order` is read at E indices, the
+  `[E]` tables at m indices). Going down is stricter than staying: a
+  plan grows when less than a sixteenth of it is left (93.75% full)
+  and goes down only to a bucket it reaches at most ~75% full, so
+  rows that hover about a bucket's edge take it across neither way.
+  Rows that SWING by more than that margin (an equivalence class
+  purged in one round, re-listed rounds later) do take it down at a
+  trough, and back up if the peak returns: that is the back-off's
+  case below, and both extents' programs exist by then.
+  A rebuild that is no re-fit (an arena overflow) never lowers
+  `entry_cap`: a smaller bucket met inside a round is a program
+  nobody has run. A re-fit that growth undoes (an overflow or
+  `m_cap` growth that raises `entry_cap` again) doubles the rounds
+  the next one waits, so a workload whose peaks do not fit the
+  smaller bucket pays a bounded number of re-layouts and settles at
+  the larger. The lifecycle is
   build -> re-fit -> (relocate | overflow | grow).
 
 Entry position 0 is permanently reserved and dead: freed slots'
@@ -175,16 +192,28 @@ def shard_owner(node_ids, num_nodes: int, num_shards: int) -> np.ndarray:
     return np.minimum(np.asarray(node_ids) // per, num_shards - 1)
 
 
-def entry_bucket(need: int, m_cap: int) -> int:
+def entry_bucket(need: int, floor: int = 0) -> int:
     """The entry-table extent of an unsharded layout that must house
-    `need` rows: the pow2 above it, floored at `2 * m_cap`, and one
-    bucket more when less than a sixteenth of it (the relocation
-    arena) would be left — at production fill the `2 * m_cap` term
-    plus the dropped per-node spare row carry that floor comfortably."""
-    cap = max(2 * m_cap, next_pow2(need))
+    `need` rows: the pow2 above it, at least `floor`, and one bucket
+    more when less than a sixteenth of it (the relocation arena) would
+    be left. The floor is the caller's (`SlotPlanState._rebuild`):
+    `2 * m_cap` for a first build and for one that `m_cap` / `n_cap`
+    growth forced, the extent the plan has for any later rebuild, none
+    for the bucket a re-fit goes down to (`refit_bucket`)."""
+    cap = max(floor, next_pow2(need))
     if cap - need < max(64, cap >> 4):
-        cap = max(2 * m_cap, next_pow2(need + max(64, cap >> 4)))
+        cap = max(floor, next_pow2(need + max(64, cap >> 4)))
     return cap
+
+
+def refit_bucket(need: int) -> int:
+    """The extent a re-fit may take a plan of `need` live rows DOWN to:
+    the bucket that houses them with a quarter more (the drift room a
+    node's region is granted, `hwm >> 2`) and the arena's sixteenth, so
+    at most three quarters full on arrival, where a plan grows at
+    fifteen sixteenths. Going down is stricter than staying: rows that
+    hover about a bucket's edge never take the plan across it twice."""
+    return entry_bucket(need + (need >> 2))
 
 
 _PLAN_APPLY = None
@@ -453,8 +482,19 @@ class SlotPlanState:
             owner = np.zeros(n_cap, np.int64)  # kschedlint: host-only (host layout build)
             need = 1 + int(base.sum())
             # the pow2 above `need`, with the relocation arena
-            # guaranteed (entry_bucket)
-            self.entry_cap = entry_bucket(need, m_cap)
+            # guaranteed (entry_bucket): at least `2 * m_cap` for a
+            # first build and one that `m_cap` / `n_cap` growth forced
+            # (the plan's own arrays say under which caps it was laid
+            # out), at least the extent it has for any later rebuild;
+            # only a re-fit goes down (module docstring)
+            grew = (
+                self.inv_order is None
+                or len(self.inv_order) != 2 * m_cap
+                or len(self.region_start) != n_cap
+            )
+            self.entry_cap = entry_bucket(need, 2 * m_cap if grew else cap_before)
+            if refit and not grew:
+                self.entry_cap = min(self.entry_cap, refit_bucket(need))
             surplus = self.entry_cap - need
             grantable = max(surplus - max(64, self.entry_cap >> 4), 0)
             slack = want
@@ -622,18 +662,19 @@ class SlotPlanState:
         return 2 * len(self.state._arc_slot)
 
     def refit_due(self, rows_live: int) -> bool:
-        """The end-of-round test: would a layout of a graph with
-        `rows_live` rows in use (two for each arc of the graph as the
-        round left it: the journal `apply` wrote is not in the arrays
-        yet) land in a smaller bucket than `entry_cap`? One call a round
-        (it is also the back-off's clock). The sharded layout is left as
-        it is: its block extent follows the densest shard."""
+        """The end-of-round test: is there a smaller bucket than
+        `entry_cap` that holds a graph with `rows_live` rows in use (two
+        for each arc of the graph as the round left it: the journal
+        `apply` wrote is not in the arrays yet) with room to drift
+        (`refit_bucket`)? One call a round (it is also the back-off's
+        clock). The sharded layout is left as it is: its block extent
+        follows the densest shard."""
         if not self.enabled or not self.entry_cap or self._num_shards != 1:
             return False
         self._refit_idle += 1
         if self._refit_idle < self._refit_wait:
             return False
-        return entry_bucket(1 + rows_live, self.state.m_cap) < self.entry_cap
+        return refit_bucket(1 + rows_live) < self.entry_cap
 
     def refit(self) -> None:
         """Ask for a re-layout at the bucket `refit_due` found, in ONE

@@ -250,7 +250,8 @@ class FaultInjector:
         {"array", "index", "bit"} for integrity.apply_device_corruption.
         Node-space arrays index within n_cap, arc/plan-space within
         m_cap (the applier re-mods against the live buffer extent, so
-        plan tensors sized 2*m_cap stay in range). ``available`` narrows
+        plan tensors stay in range whatever `entry_cap` is: 2*m_cap or
+        more after a build, less after a re-fit). ``available`` narrows
         the targets to buffers that exist right now (the plan mirror is
         built lazily) — availability is state-driven and deterministic,
         so the schedule stays reproducible. Counted as

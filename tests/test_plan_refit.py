@@ -9,6 +9,8 @@ doubles the wait for the next one; and the round that re-fits runs the
 re-fitted shapes once itself, so the next round compiles nothing.
 """
 
+import pickle
+
 import jax
 import numpy as np
 import pytest
@@ -18,7 +20,7 @@ from test_resident_shapes import Served as _Served
 from ksched_tpu.graph.changes import ArcType, ChangeArcChange, NewArcChange, NodeType
 from ksched_tpu.graph.device_export import DeviceGraphState
 from ksched_tpu.graph.flowgraph import FlowGraph
-from ksched_tpu.graph.slot_plan import SlotPlanState, entry_bucket
+from ksched_tpu.graph.slot_plan import SlotPlanState, entry_bucket, refit_bucket
 from ksched_tpu.obs.spans import SpanTracer
 from ksched_tpu.solver.cpu_ref import ReferenceSolver
 from ksched_tpu.solver.jax_solver import JaxSolver
@@ -39,34 +41,35 @@ TASKS, MACHINES, PENDING = 2360, 100, 1440
 class Graph:
     """tasks -> machines -> sink in a DeviceGraphState with a built plan."""
 
-    def __init__(self):
+    def __init__(self, tasks=TASKS, machines=MACHINES):
         g = FlowGraph()
         sink = g.add_node()
         sink.type = NodeType.SINK
-        self.machines = [g.add_node().id for _ in range(MACHINES)]
-        self.tasks = [g.add_node().id for _ in range(TASKS)]
+        self.sink = sink.id
+        self.machines = [g.add_node().id for _ in range(machines)]
+        self.tasks = [g.add_node().id for _ in range(tasks)]
         for m in self.machines:
-            g.change_arc(g.add_arc(g.node(m), sink), 0, TASKS, 0)
+            g.change_arc(g.add_arc(g.node(m), sink), 0, tasks, 0)
         for i, t in enumerate(self.tasks):
             g.node(t).excess = 1
             g.change_arc(g.add_arc(g.node(t), g.node(self.home(i))), 0, 1, 1 + i % 7)
-        sink.excess = -TASKS
+        sink.excess = -tasks
         self.state = DeviceGraphState()
         self.state.full_build(g)
         self.pending = []
 
     def home(self, i, k=0):
-        return self.machines[(i + k) % MACHINES]
+        return self.machines[(i + k) % len(self.machines)]
 
     @property
     def plan(self) -> SlotPlanState:
         return self.state.plan
 
-    def fill(self, first=0):
-        """One pending arc, to the machine after its own, for each of the
-        PENDING tasks from `first` on."""
-        for i in range(first, first + PENDING):
-            self.pending.append((self.tasks[i], self.home(i, 1)))
+    def fill(self, first=0, count=PENDING, k=1):
+        """One pending arc, to the `k`-th machine after its own, for each
+        of the `count` tasks from `first` on."""
+        for i in range(first, first + count):
+            self.pending.append((self.tasks[i], self.home(i, k)))
             self.state.apply_changes(
                 [NewArcChange(*self.pending[-1], 0, 1, 9, ArcType.OTHER)]
             )
@@ -75,6 +78,16 @@ class Graph:
         for src, dst in self.pending:
             self.state.apply_changes([ChangeArcChange(src, dst, 0, 0, 0, ArcType.OTHER, 0)])
         self.pending.clear()
+
+    def retire(self, first, stop):
+        """Tasks `first` to `stop` leave: their arc goes, and their unit
+        of supply with it."""
+        for i in range(first, stop):
+            self.state.apply_changes(
+                [ChangeArcChange(self.tasks[i], self.home(i), 0, 0, 0, ArcType.OTHER, 0)]
+            )
+            self.state.set_excess(self.tasks[i], 0)
+        self.state.set_excess(self.sink, int(self.state.excess[self.sink]) + stop - first)
 
     def end_of_round(self) -> bool:
         """What FlowScheduler._refit_plan does with the plan, less the solve."""
@@ -163,18 +176,186 @@ def test_a_refitted_plan_holds_the_rows_of_one_built_from_scratch_and_solves_ali
     assert g.plan.entry_cap == 8192 and len(np.asarray(g.plan.host_args()[0])) == 8192
 
 
-def test_no_refit_below_twice_the_arc_table():
+def test_a_refit_goes_below_twice_the_arc_table_only_with_room_to_drift():
+    """`2 * m_cap` is what a build allows for, not a floor: a re-fit goes
+    below it, but only to a bucket that holds the rows with a quarter more
+    and the arena's sixteenth (`refit_bucket`)."""
     g = _filled()
     g.drain()
-    assert g.end_of_round() and g.plan.entry_cap == 2 * g.state.m_cap
-    # what is left would fit a sixteenth of it; the floor holds
-    for i in range(100, TASKS):
-        g.state.apply_changes(
-            [ChangeArcChange(g.tasks[i], g.home(i), 0, 0, 0, ArcType.OTHER, 0)]
-        )
-    assert entry_bucket(1 + g.plan.rows_live, 0) <= 1024
-    assert not g.plan.refit_due(g.plan.rows_live)
-    assert not g.end_of_round() and g.plan.entry_cap == 2 * g.state.m_cap == 8192
+    # 3,400 rows would fit 4,096 as a build sizes (the arena is left), but
+    # would arrive there 83% full: down to twice the arc table and no further
+    g.retire(1600, TASKS)
+    assert g.plan.rows_live == 3400 and entry_bucket(1 + g.plan.rows_live) == 4096
+    assert refit_bucket(1 + g.plan.rows_live) == 8192
+    assert g.end_of_round() and g.plan.entry_cap == 2 * g.state.m_cap == 8192
+    assert not g.end_of_round()
+    # 3,000 rows arrive 73% full: down, below twice the arc table
+    g = _filled()
+    g.drain()
+    g.retire(1400, TASKS)
+    assert g.plan.rows_live == 3000 and refit_bucket(1 + g.plan.rows_live) == 4096
+    assert g.end_of_round() and g.plan.entry_cap == 4096 == g.state.m_cap
+    assert (g.plan.refits, g.plan.regrowths) == (1, 0)
+    g.plan.check_invariants()
+    assert not g.end_of_round()
+
+
+def test_rows_that_swing_past_the_margin_go_down_at_a_trough_and_the_back_off_settles_them():
+    """`k8s-5000-antiaffinity`'s shape at 1/75: the fill round ends on rows
+    that a purge takes two rounds later and arrivals bring back. The margin
+    is for rows that hover; a swing wider than it takes the plan down at the
+    trough, below `2 * m_cap`, and the peak that returns takes it back to the
+    extent it had (a program that has run), which doubles the wait."""
+    g = Graph()
+    g.plan.ensure_built()
+    assert g.plan.entry_cap == 2 * g.state.m_cap == 8192
+    g.retire(1400, TASKS)
+    g.fill(count=960)  # the fill round ends on 4,920 rows: nothing smaller would do
+    assert g.plan.rows_live == 4920 and not g.end_of_round()
+    g.drain()  # the trough: 3,000 rows go down to 4,096
+    assert g.plan.rows_live == 3000 and refit_bucket(1 + g.plan.rows_live) == 4096
+    assert g.end_of_round() and g.plan.entry_cap == 4096 < 2 * g.state.m_cap
+    caps = set()
+    for _ in range(16):
+        g.fill(count=960)  # the peak is back: 4,920 rows do not fit 4,096
+        g.plan.ensure_built()
+        g.end_of_round()
+        caps.add(g.plan.entry_cap)
+        g.drain()
+        g.end_of_round()
+        caps.add(g.plan.entry_cap)
+    # between the two extents and nowhere else, a bounded number of times
+    assert caps == {4096, 8192} and g.state.m_cap == 4096
+    assert 2 <= g.plan.refits <= 5 and g.plan.refits - 1 <= g.plan.regrowths <= g.plan.refits
+    assert g.plan._refit_wait == 2 ** g.plan.regrowths >= 8
+    g.plan.check_invariants()
+
+
+def _below(keep):
+    """A plan re-fitted below `2 * m_cap`: the fill is over and all but
+    `keep` of the tasks have left."""
+    g = _filled()
+    g.drain()
+    g.retire(keep, TASKS)
+    assert g.end_of_round()
+    assert g.plan.entry_cap == refit_bucket(1 + g.plan.rows_live) < 2 * g.state.m_cap == 8192
+    return g
+
+
+def _objectives(state):
+    problem = state.problem()
+    return JaxSolver(warm_start=False).solve(problem).objective, ReferenceSolver().solve(problem).objective
+
+
+@pytest.mark.parametrize("keep, rows", [(1400, 4096), (600, 2048), (150, 1024)])
+def test_a_plan_below_twice_the_arc_table_is_a_fresh_build_row_for_row_and_solves(keep, rows):
+    g = _below(keep)
+    assert g.plan.entry_cap == rows and len(np.asarray(g.plan.host_args()[0])) == rows
+    assert len(g.plan.inv_order) == 2 * g.state.m_cap  # the [2m] table is read at E indices
+    fresh = _fresh_plan(g.state)
+    assert fresh.entry_cap == 2 * g.state.m_cap  # a build keeps the allowance
+    assert _live_rows(g.plan) == _live_rows(fresh)
+    g.plan.check_invariants()
+    ours, reference = _objectives(g.state)
+    assert ours == reference
+    # incremental churn under it: pending arcs come and half of them go
+    before = g.plan.rows_live
+    g.fill(count=keep // 8)
+    g.pending, kept = g.pending[: keep // 16], g.pending[keep // 16 :]
+    g.drain()
+    assert g.plan.rows_live == before + 2 * len(kept) > before
+    assert not g.plan.needs_rebuild and (g.plan.entry_cap, g.plan.regrowths) == (rows, 0)
+    g.plan.check_invariants()
+    ours, reference = _objectives(g.state)
+    assert ours == reference
+
+
+@pytest.mark.parametrize("refitted", [True, False], ids=["refitted", "never-refitted"])
+def test_an_arena_overflow_keeps_the_extent_the_plan_has(refitted):
+    """A rebuild that is no re-fit never lowers `entry_cap`, whatever the
+    marks would fit: a smaller bucket met inside a round is a program
+    nobody has run. (The parent had `2 * m_cap` to stop at.)"""
+    if refitted:
+        g = _below(1400)
+        g.retire(100, 1400)
+    else:
+        g = _filled()
+        g.drain()
+        g.retire(300, TASKS)
+    cap = g.plan.entry_cap
+    assert cap == (4096 if refitted else 16384)
+    for _ in range(3):  # the marks decay halfway at each rebuild
+        g.plan._overflow()
+        g.plan.ensure_built()
+        assert g.plan.entry_cap == cap
+    assert entry_bucket(1 + int(g.plan._deg_hwm.sum())) < cap
+    assert (g.plan.region_overflows, g.plan.regrowths) == (3, 0)
+    g.plan.check_invariants()
+    # the way down is the re-fit's, at the end of a round
+    assert g.end_of_round() and g.plan.entry_cap == (1024 if refitted else 2048)
+
+
+@pytest.mark.parametrize(
+    "tasks, machines, peak, swing",
+    [(414, 20, 200, 60), (1850, 50, 400, 90), (1865, 50, 400, 125)],
+    ids=["868-of-1024", "3974-of-4096", "4078-of-4096"],
+)
+def test_rows_that_hover_about_a_bucket_s_edge_never_take_the_plan_across_it(
+    tasks, machines, peak, swing
+):
+    """The 1/40 rehearsals' shapes: as a build sizes, the rows fit the
+    smaller bucket in one round and not in the next. Going down is stricter
+    than staying, so the plan does neither."""
+    g = Graph(tasks, machines)
+    g.fill(count=peak)
+    g.plan.ensure_built()
+    cap = g.plan.entry_cap
+    assert cap == 2 * g.state.m_cap
+    low, high = 2 * (tasks + machines), 2 * (tasks + machines + swing)
+    assert entry_bucket(1 + low) == cap // 2 and entry_bucket(1 + high) == cap
+    for rnd in range(24):
+        g.drain()
+        if rnd % 2:
+            g.fill(first=rnd, count=swing)
+        assert g.plan.rows_live == (high if rnd % 2 else low)
+        assert not g.end_of_round()
+    assert (g.plan.entry_cap, g.plan.refits, g.plan.regrowths) == (cap, 0, 0)
+    assert not g.plan.needs_rebuild and g.plan.layout_rebuilds == 1
+    g.plan.check_invariants()
+
+
+def test_growth_of_the_arc_table_after_a_refit_restores_what_a_build_allows_for():
+    g = _below(1400)
+    assert (g.plan.entry_cap, g.state.m_cap) == (4096, 4096)
+    g.fill(count=1400)
+    g.fill(count=1400, k=2)  # a second pending arc each: 4,300 arcs
+    g.plan.ensure_built()
+    assert g.state.m_cap == 8192 and g.plan.entry_cap == 2 * g.state.m_cap
+    # growth undid a re-fit: the next one waits twice as long
+    assert (g.plan.regrowths, g.plan._refit_wait, g.plan._refit_standing) == (1, 2, False)
+    g.plan.check_invariants()
+    ours, reference = _objectives(g.state)
+    assert ours == reference
+
+
+def test_a_plan_below_twice_the_arc_table_survives_a_pickle_round_trip():
+    g = _below(600)
+    state = pickle.loads(pickle.dumps(g.state))
+    plan = state.plan
+    assert plan.entry_cap == g.plan.entry_cap == 2048 < 2 * state.m_cap
+    for ours, theirs in zip(plan.host_args(), g.plan.host_args()):
+        assert np.array_equal(ours, theirs)
+    plan.check_invariants()
+    # the restored plan knows under which caps it was laid out: a rebuild
+    # that is no re-fit keeps its extent, and arcs wire as before
+    plan._overflow()
+    plan.ensure_built()
+    assert plan.entry_cap == 2048
+    state.apply_changes([NewArcChange(g.tasks[0], g.home(0, 1), 0, 1, 9, ArcType.OTHER)])
+    assert not plan.needs_rebuild and plan.rows_live == g.plan.rows_live + 2
+    plan.check_invariants()
+    ours, reference = _objectives(state)
+    assert ours == reference
 
 
 def test_growth_after_a_refit_doubles_the_wait_and_peaks_that_do_not_fit_settle():
@@ -385,6 +566,81 @@ def test_under_preemption_the_graph_grows_after_its_fill_and_nothing_refits():
     assert (after.plan_refits, after.plan_regrowths, after.plan_relayouts) == (0, 1, 1)
     assert (s.plan.refits, s.plan._refit_wait) == (0, 1)
     assert not [e for e in s.spans.events() if e["name"] == "plan_refit"]
+
+
+# ---------------------------------------------------------------------------
+# a fill that peaks at twice the steady arcs: the re-fit lands below
+# `2 * m_cap`, in the fill round
+# ---------------------------------------------------------------------------
+
+#: a fill of 300 pods peaks at 1,081 arcs, so m_cap is 2,048 and the build
+#: takes 4,096 rows; the steady graph's ~1,050 rows fit 2,048 with room to drift
+FILL_BELOW = 300
+DELTAS = (3, 5, 1, 8, 2)
+
+
+def _mirror_is_the_host_s(s):
+    """The ten plan tensors on the device against the host's, and that the
+    mirror is not merely behind (`plan_parity_check` returns early then)."""
+    res, plan = s.resident, s.plan
+    assert (res._plan_gen, res._plan_ver) == (plan.layout_gen, plan.value_version)
+    res.plan_parity_check()
+    return res.last_plan_kind
+
+
+@pytest.fixture(scope="module")
+def below():
+    out = {}
+    for name in ("plain", "resident"):
+        jax.clear_caches()
+        s = Served(SERVICES[name])
+        rounds, kinds = [s.round(FILL_BELOW)], []
+        for k in (0,) + DELTAS:
+            if k:
+                rounds.append(s.round(k, k))
+            if name == "resident":
+                kinds.append(_mirror_is_the_host_s(s))
+        s.close()
+        out[name] = (s, rounds, kinds)
+    return out
+
+
+@pytest.mark.parametrize("name", ["plain", "resident"])
+def test_a_fill_at_twice_the_steady_arcs_refits_below_twice_the_arc_table(below, name):
+    s, rounds, _kinds = below[name]
+    fill, compiled = rounds[0]
+    m_cap = s.svc.scheduler.solver.state.m_cap
+    assert (fill.num_scheduled, m_cap) == (FILL_BELOW, 2048)
+    assert (fill.plan_rows, fill.plan_refits, fill.plan_regrowths) == (2 * m_cap, 1, 0)
+    assert compiled >= 2  # the build's program and the re-fitted one, both in the fill round
+    assert s.plan.entry_cap == 2048 < 2 * m_cap
+    for (rec, compiled), k in zip(rounds[1:], DELTAS):
+        assert (rec.num_scheduled, compiled) == (k, 0)
+        assert (rec.plan_rows, rec.plan_refits, rec.plan_regrowths) == (2048, 0, 0)
+        assert 0 < rec.plan_rows_live <= 0.75 * rec.plan_rows
+        assert rec.solver_rung == 0 and not rec.noop_round
+    s.plan.check_invariants()
+
+
+def test_below_twice_the_arc_table_resident_and_not_bind_the_same_pods(below):
+    plain, resident = below["plain"][0], below["resident"][0]
+    assert plain.bindings() == resident.bindings()
+    assert len(plain.bindings()) == FILL_BELOW + sum(DELTAS)
+    for ours, theirs in zip(plain.plan.host_args(), resident.plan.host_args()):
+        assert np.array_equal(ours, theirs)
+
+
+def test_below_twice_the_arc_table_the_mirror_is_the_host_s_whole_and_scattered(below):
+    """After the fill round's full upload of the re-fitted layout, and after
+    rounds that scattered records into buffers of `entry_cap` rows."""
+    s, rounds, kinds = below["resident"]
+    assert rounds[0][0].upload_full == 1 and kinds[0] == "rebuild"
+    assert "delta" in kinds[1:]
+    scattered = [rec for (rec, _c), kind in zip(rounds[1:], kinds[1:]) if kind == "delta"]
+    assert scattered and all(r.upload_full == 0 and r.plan_relayouts == 0 for r in scattered)
+    res = s.resident
+    assert all(len(np.asarray(t)) == 2048 for t in (res.d_p_arc, res.d_p_sign, res.d_seg))
+    assert len(np.asarray(res.d_inv)) == 2 * s.svc.scheduler.solver.state.m_cap
 
 
 # ---------------------------------------------------------------------------
