@@ -388,7 +388,9 @@ class SchedulerService:
                     self.scheduler.handle_task_eviction(td, rs.descriptor)
             self.old_bindings.pop(existing, None)
             return
-        td = add_task_to_job(self.job_id, self.job_map, self.task_map, name=pod.pod_id)
+        td = add_task_to_job(
+            self.job_id, self.job_map, self.task_map, name=pod.pod_id, scheduler=self.scheduler
+        )
         td.resource_request.cpu_cores = pod.cpu_request
         td.resource_request.net_bw = pod.net_bw_request
         for k, v in of_class.items():

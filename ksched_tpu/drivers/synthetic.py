@@ -112,10 +112,17 @@ def add_machine(
 
 
 def add_task_to_job(
-    job_id: int, job_map: JobMap, task_map: TaskMap, name: str = ""
+    job_id: int,
+    job_map: JobMap,
+    task_map: TaskMap,
+    name: str = "",
+    scheduler: Optional[FlowScheduler] = None,
 ) -> TaskDescriptor:
     """Create a task under the job's root task (first task becomes the
-    root; reference: schedule_iteration_test.go:212-253)."""
+    root; reference: schedule_iteration_test.go:212-253). ``scheduler``
+    is the one the job was offered to, if it was: it records the new
+    child (FlowScheduler.add_task), where a root grown without a word
+    costs it a walk of the whole tree."""
     jd = job_map.find(job_id)
     task_id = rand_uint64()
     td = TaskDescriptor(
@@ -132,6 +139,8 @@ def add_task_to_job(
             root_task=td,
         )
         job_map.insert(job_id, jd)
+    elif scheduler is not None:
+        scheduler.add_task(jd.root_task, td)
     else:
         jd.root_task.spawned.append(td)
     task_map.insert(task_id, td)

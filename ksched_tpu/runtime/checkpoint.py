@@ -44,8 +44,10 @@ CHECKPOINT_VERSION = 1
 #: resource arcs' prices when the graph manager was built). 8: the
 #: pickled slot plan carries its re-fit state (refits, regrowths, the
 #: back-off's wait and clock) and the scheduler the plan counters its
-#: last record saw
-WARM_MANIFEST_VERSION = 8
+#: last record saw. 9: the scheduler carries the count of descriptors
+#: its scans looked at since the last record; what it keeps of the jobs
+#: it has walked stays out (it walks them again at its first scan)
+WARM_MANIFEST_VERSION = 9
 
 
 class CheckpointError(RuntimeError):
@@ -434,8 +436,9 @@ def load_device_checkpoint(path: str, class_cost_fn=None):
 # contain them by falling back to the cold event replay.
 
 #: scheduler attributes excluded from the core pickle (rebuilt fresh:
-#: the solver holds the backend/ladder and live device buffers)
-_SCHED_CORE_EXCLUDE = ("solver", "_round_in_flight")
+#: the solver holds the backend/ladder and live device buffers; the
+#: paths of every descriptor come back with the first scan's walk)
+_SCHED_CORE_EXCLUDE = ("solver", "_round_in_flight", "_met_jobs")
 
 
 def find_jax_solver(backend):
@@ -516,6 +519,7 @@ def load_warm_manifest(
     scheduler = FlowScheduler.__new__(FlowScheduler)
     scheduler.__dict__.update(payload["scheduler"])
     scheduler._round_in_flight = None
+    scheduler._met_jobs = {}
     st = payload["device_state"]
     # the uid feeds plan_key identity; a fresh process must never let a
     # LATER DeviceGraphState collide with the restored one's key

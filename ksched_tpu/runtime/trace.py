@@ -66,6 +66,12 @@ class RoundRecord:
     #: round's RoundTiming, so 0 wherever the phase timings are)
     graph_tasks_visited: int = 0
     graph_tasks_skipped: int = 0
+    #: descriptors the scheduler looked at to find the round's runnable
+    #: tasks (from its RoundTiming; FlowScheduler._runnable_jobs): the
+    #: pods admitted since the last round, or every descriptor of a job
+    #: it met for the first time (a first offer, the round after a
+    #: restore)
+    runnable_tasks_scanned: int = 0
     #: the resource half of the same update (from its RoundTiming):
     #: resource nodes that took a turn in its FIFO, and arcs out of
     #: them it added or whose price really changed
@@ -304,6 +310,7 @@ class RoundTracer:
             arcs_removed=stats.arcs_removed if stats else 0,
             graph_tasks_visited=t.graph_tasks_visited,
             graph_tasks_skipped=t.graph_tasks_skipped,
+            runnable_tasks_scanned=t.runnable_tasks_scanned,
             res_nodes_visited=t.res_nodes_visited,
             res_arcs_changed=t.res_arcs_changed,
             stats_pus_dirty=t.stats_pus_dirty,

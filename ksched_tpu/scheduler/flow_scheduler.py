@@ -39,6 +39,39 @@ from ..utils import JobMap, ResourceMap, TaskMap, job_id_from_string, resource_i
 
 #: the states _compute_runnable_tasks_for_job promotes to RUNNABLE
 _PROMOTED_STATES = (TaskState.CREATED, TaskState.BLOCKING)
+#: the states of a job's root under which its tree is looked at at all
+_WALKED_ROOT_STATES = (
+    TaskState.CREATED,
+    TaskState.RUNNING,
+    TaskState.RUNNABLE,
+    TaskState.COMPLETED,
+)
+
+
+@dataclass
+class _MetJob:
+    """What the scheduler keeps of a job whose tree it has walked."""
+
+    #: uid -> place in the tree (the index in each `spawned` list from
+    #: the root down, () for the root) of every descriptor seen there:
+    #: a new child's path is its parent's and its own index
+    paths: Dict[int, tuple]
+    #: the root's children the walk and the events account for
+    root_children: int
+    #: (path, descriptor) of the children added since the last scan
+    arrived: List[tuple] = field(default_factory=list)
+    #: `arrived` stands in the order the walk would meet its tasks: the
+    #: order of arrival, while every one is a child of the root
+    in_walk_order: bool = True
+
+
+def _walk_order(arrival: tuple) -> tuple:
+    """Sorts (path, descriptor) as _walk_job_tree meets the tasks: a
+    parent's children by index, the parents root first and then, among
+    siblings, the last with its whole subtree before the one ahead of
+    it (the walk keeps its parents on a stack)."""
+    path = arrival[0]
+    return tuple(-i for i in path[:-1]), path[-1]
 
 
 @dataclass
@@ -66,6 +99,11 @@ class RoundTiming:
     #: the same jobs left alone (GraphManager.add_or_update_job_nodes)
     graph_tasks_visited: int = 0
     graph_tasks_skipped: int = 0
+    #: descriptors the scan for runnable tasks looked at since the last
+    #: round said so (`runnable_scan`, and a restore's walk before it):
+    #: the children added to trees the scheduler had walked, and every
+    #: descriptor of a tree it walked (_compute_runnable_tasks_for_job)
+    runnable_tasks_scanned: int = 0
     #: the resource half of the same update: resource nodes that took a
     #: turn, and arcs out of them it added or whose price really
     #: changed (GraphManager.res_nodes_visited, res_arcs_changed)
@@ -215,6 +253,12 @@ class FlowScheduler:
         self.resource_bindings: Dict[int, Set[int]] = {}
         self.jobs_to_schedule: Dict[int, JobDescriptor] = {}
         self.runnable_tasks: Dict[int, Set[int]] = {}
+        #: job id -> the jobs whose trees the scheduler has walked; what
+        #: is added to one after that comes through add_task
+        self._met_jobs: Dict[int, _MetJob] = {}
+        #: descriptors looked at for promotion since a round last took
+        #: the count (RoundTiming.runnable_tasks_scanned)
+        self._tasks_scanned = 0
         self.last_timing = RoundTiming()
         #: the slot plan's (refits, regrowths, layout_rebuilds) as the
         #: last round's record saw them (_note_plan)
@@ -241,7 +285,35 @@ class FlowScheduler:
         return self.task_bindings
 
     def add_job(self, jd: JobDescriptor) -> None:
+        """Offer a job. One the scheduler has not met is walked from
+        its root by the next scan; a tree it has walked grows through
+        add_task."""
         self.jobs_to_schedule[job_id_from_string(jd.uuid)] = jd
+
+    def add_task(self, parent: TaskDescriptor, td: TaskDescriptor) -> None:
+        """``td`` becomes the last child of ``parent``. Whoever grows
+        the tree of a job that was offered does it here: under a tree
+        the scheduler has walked the child is recorded with its path,
+        and the next scan looks at what was recorded, not at the tree.
+        (A job it has not met yet needs no record: its walk is still to
+        come.) A parent that walk never saw means the tree grew behind
+        the scheduler's back: the job is walked again."""
+        parent.spawned.append(td)
+        job_id = job_id_from_string(td.job_id)
+        met = self._met_jobs.get(job_id)
+        if met is None:
+            return
+        path = met.paths.get(parent.uid)
+        if path is None:
+            del self._met_jobs[job_id]
+            return
+        if path:
+            met.in_walk_order = False
+        else:
+            met.root_children += 1
+        path += (len(parent.spawned) - 1,)
+        met.paths[td.uid] = path
+        met.arrived.append((path, td))
 
     def handle_job_completion(self, job_id: int) -> None:
         """Reference: flowscheduler/scheduler.go:93-104."""
@@ -251,6 +323,7 @@ class FlowScheduler:
         assert jd is not None, f"job {job_id} must exist"
         self.jobs_to_schedule.pop(job_id, None)
         self.runnable_tasks.pop(job_id, None)
+        self._met_jobs.pop(job_id, None)
         jd.state = JobState.COMPLETED
 
     def handle_task_completion(self, td: TaskDescriptor) -> None:
@@ -390,15 +463,21 @@ class FlowScheduler:
         return self.schedule_jobs(self._runnable_jobs())
 
     def _runnable_jobs(self):
-        """The jobs with at least one runnable task: a walk of every
-        job's whole task tree, before `round` opens."""
+        """The jobs with at least one runnable task, before `round`
+        opens: what was added to each job since the last scan is
+        promoted, and a job met for the first time is walked whole."""
         with span("runnable_scan") as sp:
             jds = [
                 jd for jd in self.jobs_to_schedule.values()
                 if len(self._compute_runnable_tasks_for_job(jd)) > 0
             ]
             sp.set("jobs", len(jds))
+            sp.set("runnable_tasks_scanned", self._tasks_scanned)
         return jds
+
+    def _take_tasks_scanned(self) -> int:
+        scanned, self._tasks_scanned = self._tasks_scanned, 0
+        return scanned
 
     # ------------------------------------------------------------------
     # Pipelined rounds: dispatch the solve, overlap host work, finish
@@ -461,7 +540,7 @@ class FlowScheduler:
         and pipelined paths: mutation-counter reset, topology stats
         refresh, and the job/task graph update. Opens the `round` span
         (closed by _finish_round — or here, on an exception)."""
-        timing = RoundTiming()
+        timing = RoundTiming(runnable_tasks_scanned=self._take_tasks_scanned())
         round_span = start_span("round", jobs=len(jds))
         try:
             # Reset the mutation counters at round START (the reference
@@ -671,8 +750,7 @@ class FlowScheduler:
         """Reference: flowscheduler/scheduler.go:321-338."""
         self._check_not_in_flight("schedule_jobs")
         if not jds:
-            timing = RoundTiming()
-            self.last_timing = timing
+            self.last_timing = RoundTiming(runnable_tasks_scanned=self._take_tasks_scanned())
             return 0, []
         timing, round_span = self._begin_round(jds)
         try:
@@ -783,29 +861,52 @@ class FlowScheduler:
 
     def _compute_runnable_tasks_for_job(self, jd: JobDescriptor) -> Set[int]:
         """Dependency-free lazy graph reduction (reference:
-        flowscheduler/scheduler.go:493-529). A promoted task is handed
-        to the graph manager with its place in the tree, which is why
-        a child is looked at from its parent: that is where its index
-        is known."""
+        flowscheduler/scheduler.go:493-529), which walks the job's tree
+        every round. Here the tree is walked when the scheduler meets
+        the job (its first offer, a restore, a job offered again after
+        it completed) and when its root holds other children than the
+        scheduler was told of; after that a scan looks at the children
+        add_task recorded since the last one, in the walk's order."""
         job_id = job_id_from_string(jd.uuid)
-        root = jd.root_task
-        if root.state in (
-            TaskState.CREATED,
-            TaskState.RUNNING,
-            TaskState.RUNNABLE,
-            TaskState.COMPLETED,
-        ):
-            if root.state == TaskState.CREATED:
-                self._promote_task(root, ())
-            parents = [(root, ())]
-            while parents:
-                parent, path = parents.pop()
-                for i, child in enumerate(parent.spawned):
-                    if child.state in _PROMOTED_STATES:
-                        self._promote_task(child, path + (i,))
-                    if child.spawned:
-                        parents.append((child, path + (i,)))
+        met = self._met_jobs.get(job_id)
+        if met is None or met.root_children != len(jd.root_task.spawned):
+            self._tasks_scanned += self._walk_job_tree(jd)
+        elif met.arrived and jd.root_task.state in _WALKED_ROOT_STATES:
+            arrived, met.arrived = met.arrived, []
+            if not met.in_walk_order:
+                arrived.sort(key=_walk_order)
+                met.in_walk_order = True
+            for path, td in arrived:
+                if td.state in _PROMOTED_STATES:
+                    self._promote_task(td, path)
+            self._tasks_scanned += len(arrived)
         return self.runnable_tasks.setdefault(job_id, set())
+
+    def _walk_job_tree(self, jd: JobDescriptor) -> int:
+        """The reference's walk of a job's whole tree: every CREATED or
+        BLOCKING descriptor under the root is promoted. A promoted task
+        is handed to the graph manager with its place in the tree, which
+        is why a child is looked at from its parent: that is where its
+        index is known. The scheduler has met the job from here on, and
+        keeps every descriptor's path for the children to come. Returns
+        the descriptors visited."""
+        root = jd.root_task
+        if root.state not in _WALKED_ROOT_STATES:
+            return 0
+        if root.state == TaskState.CREATED:
+            self._promote_task(root, ())
+        paths = {root.uid: ()}
+        parents = [(root, ())]
+        while parents:
+            parent, path = parents.pop()
+            for i, child in enumerate(parent.spawned):
+                paths[child.uid] = child_path = path + (i,)
+                if child.state in _PROMOTED_STATES:
+                    self._promote_task(child, child_path)
+                if child.spawned:
+                    parents.append((child, child_path))
+        self._met_jobs[job_id_from_string(jd.uuid)] = _MetJob(paths, len(root.spawned))
+        return len(paths)
 
     def _promote_task(self, td: TaskDescriptor, path: tuple) -> None:
         td.state = TaskState.RUNNABLE

@@ -132,9 +132,9 @@ def _admit(sched, jmap, tmap, job_id, uids, parent_uid=None, task_type=TaskType.
             jd = JobDescriptor(uuid=str(job_id), name=f"j{job_id}", state=JobState.CREATED, root_task=td)
             jmap.insert(job_id, jd)
         elif parent_uid is None:
-            jd.root_task.spawned.append(td)
+            sched.add_task(jd.root_task, td)
         else:
-            tmap.find(parent_uid).spawned.append(td)
+            sched.add_task(tmap.find(parent_uid), td)
     sched.add_job(jd)
     return jd
 

@@ -133,7 +133,7 @@ def _churn_rounds(sched, jmap, tmap, job_id, rounds, k=2, seed=11):
             ):
                 sched.handle_task_completion(tmap.find(bound[i][0]))
         for _ in range(k):
-            add_task_to_job(job_id, jmap, tmap)
+            add_task_to_job(job_id, jmap, tmap, scheduler=sched)
         sched.add_job(jmap.find(job_id))
         sched.schedule_all_jobs()
         yield

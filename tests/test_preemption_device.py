@@ -166,7 +166,7 @@ def _add_tasks_with_classes(sched, jmap, tmap, jid, classes):
 
     tds = []
     for c in classes:
-        td = add_task_to_job(jid, jmap, tmap)
+        td = add_task_to_job(jid, jmap, tmap, scheduler=sched)
         td.task_type = TaskType(c)
         tds.append(td)
     jd = jmap.find(jid)

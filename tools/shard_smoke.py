@@ -98,7 +98,7 @@ def _drive_arm(label, backend, *, machines, tasks, rounds, warmup,
         for i in reversed(idx):
             sched.handle_task_completion(tmap.find(bound[i][0]))
         for _ in range(k):
-            add_task_to_job(job_id, jmap, tmap)
+            add_task_to_job(job_id, jmap, tmap, scheduler=sched)
         sched.add_job(jmap.find(job_id))
         gen0 = sched.solver.state.generation
         overflow0 = sched.solver.state.plan.region_overflows
