@@ -12,11 +12,10 @@ Three levels (docs/static_analysis.md has the full rule catalog):
 - Level 2, `jaxpr_contracts`: abstract traces (`jax.make_jaxpr` over
   `ShapeDtypeStruct`s — no device, no compile) of every registered
   solver backend, asserting the invariants that live in the *traced
-  program* — no 64-bit `convert_element_type` anywhere, the
-  megakernel's zero-HBM-gather/zero-scatter budget, jaxpr-hash
-  stability across raw sizes sharing a pow2 padding bucket (the
-  recompile-hazard detector), and a VMEM estimate from the kernel's
-  actual operands cross-checked against the `mega_fits_vmem` gate.
+  program* — no 64-bit `convert_element_type` anywhere, no scatter
+  in a solve, exact gather budgets, and jaxpr-hash stability across
+  raw sizes sharing a pow2 padding bucket (the recompile-hazard
+  detector).
 - Level 3, `program_registry` + `engine`: a declarative registry where
   every compiled program in the tree registers once with its full
   contract spec (scatter policy, collective budget, dtype policy,

@@ -274,41 +274,6 @@ def check_declared(spec: ProgramSpec):
                     f"it (declared: {sorted(declared) or 'nothing'})")
 
 
-def check_vmem_gate(spec: ProgramSpec):
-    """Mega-only: the VMEM estimate counted from the traced
-    pallas_call's block mappings must agree with the dispatch gate's
-    budget, telemetry off (extra_tiles 0) and on (exactly 1 ring
-    tile)."""
-    if not spec.vmem_gate:
-        return
-    jc = _contracts()
-    from ..ops.mcmf_pallas import MEGA_LANES
-
-    est = jc.estimate_mega_vmem(trace_call(spec))
-    if est.L != MEGA_LANES:
-        _fail(spec, f"kernel lane extent {est.L} != MEGA_LANES {MEGA_LANES}")
-    if not est.all_operands_on_chip:
-        _fail(spec, "mega kernel has an operand outside VMEM/SMEM")
-    if est.extra_tiles != 0:
-        _fail(spec, f"telemetry-off kernel carries {est.extra_tiles} extra "
-                    "VMEM tiles (the ring must be absent when disabled)")
-    if not est.gate_is_safe:
-        _fail(spec, f"dispatch gate budgets {est.gate_tiles} tiles < "
-                    f"counted live set {est.est_tiles}")
-    if not est.gate_is_tight:
-        _fail(spec, f"dispatch gate {est.gate_tiles} tiles drifted above "
-                    f"counted {est.est_tiles} + slack")
-    if spec.telemetry_knob:
-        est_on = jc.estimate_mega_vmem(
-            trace_call(spec, **{spec.telemetry_knob: 512})
-        )
-        if est_on.extra_tiles != 1:
-            _fail(spec, f"telemetry-ON ring occupies {est_on.extra_tiles} "
-                        "tile-equivalents, expected exactly 1 (clamped ring)")
-        if not est_on.gate_is_safe:
-            _fail(spec, "telemetry-ON live set exceeds the gate's +1 budget")
-
-
 # ---------------------------------------------------------------------------
 # the donation/aliasing audit
 # ---------------------------------------------------------------------------
@@ -399,7 +364,6 @@ CHECKS = {
     "telemetry_knob": check_telemetry_knob,
     "distinct": check_distinct,
     "donation": check_donation,
-    "vmem_gate": check_vmem_gate,
     "declared": check_declared,
 }
 
@@ -420,8 +384,6 @@ def applicable_checks(spec: ProgramSpec) -> Tuple[str, ...]:
         names.append("distinct")
     if spec.donation is not None:
         names.append("donation")
-    if spec.vmem_gate:
-        names.append("vmem_gate")
     return tuple(names)
 
 

@@ -1,7 +1,7 @@
 """Level-2 contracts: invariants checked on the TRACED program.
 
-Every registered solver backend (solver/select.py: jax, ell, mega,
-layered, plus parallel/sharded_*) is traced abstractly with
+Every registered solver backend (solver/select.py: jax, layered,
+plus parallel/sharded_*) is traced abstractly with
 `jax.make_jaxpr` over `ShapeDtypeStruct`s — no device arrays, no
 compile, CPU-safe — and the resulting jaxpr is walked recursively
 (pjit / while / cond / scan / pallas_call sub-jaxprs included) to
@@ -29,30 +29,19 @@ assert:
   refit (`trace_jax_warmp`), and the slot-stable SHARDED solve
   (`trace_sharded_slot`, additionally hash-stable per shard-count
   bucket at 2/4/8 devices).
-- **mega gather budget** (locking in the megakernel's zero-HBM-gather
-  claim, ops/mcmf_pallas.py): inside the mega `pallas_call` body every
-  operand is VMEM/SMEM-resident by BlockSpec construction, the only
-  gathers are the pinned partner-permutation reads, and OUTSIDE the
-  kernel no gather sits inside a loop body — so per-superstep HBM
-  gather traffic is exactly zero; the one-shot entry materialization
-  runs once per solve.
 - **pow2-bucket stability** (recompile-hazard detector): two raw
   problem sizes sharing a pow2 padding bucket must produce
   byte-identical jaxprs — if a raw size leaks into a static argument
   or a host-derived shape, the hash splits and the gate names the
   recompile before a production cluster discovers it as a per-round
   compile stall.
-- **VMEM estimate**: the megakernel's live set, counted from the
-  actual `pallas_call` block mappings, must agree with the
-  `_MEGA_LIVE_TILES` constant behind `mega_fits_vmem` — the dispatch
-  gate can never drift from the kernel it guards.
 
-The ELL and sharded backends build entry tables whose SHAPES depend on
-graph structure (degree buckets / per-shard maxima), not only on
-(n, m); they get the dtype/scatter contracts via plans built from a
-deterministic generator graph, and are exempt from the bucket-hash
-contract (their recompile unit is the plan rebuild, which existing
-tests cover). See docs/static_analysis.md.
+The sharded backends build entry tables whose SHAPES depend on graph
+structure (per-shard maxima), not only on (n, m); they get the
+dtype/scatter contracts via plans built from a deterministic
+generator graph, and are exempt from the bucket-hash contract (their
+recompile unit is the plan rebuild, which existing tests cover). See
+docs/static_analysis.md.
 """
 
 from __future__ import annotations
@@ -68,30 +57,13 @@ import jax.numpy as jnp
 
 #: the backend names this suite traces, mirroring solver/select.py
 #: ("native" is C++, "ref" is pure numpy, "auto" composes the others)
-REGISTERED_BACKENDS = ("jax", "ell", "mega", "layered", "sharded")
+REGISTERED_BACKENDS = ("jax", "layered", "sharded")
 
 #: backends whose traced shapes are a function of the padded (n, m)
 #: alone — the pow2-bucket hash contract applies to exactly these
-HASH_STABLE_BACKENDS = ("jax", "mega", "layered")
+HASH_STABLE_BACKENDS = ("jax", "layered")
 
 _64BIT = frozenset({"int64", "uint64", "float64", "complex128"})
-
-#: gathers inside the mega kernel body: one per `perm()` site in the
-#: traced program (tighten body, post-tighten saturate, and the phase
-#: loop's saturate + superstep rc/delta/relabel reads). All read the
-#: VMEM-resident partner tables. A changed count means the kernel's
-#: data-movement structure changed — re-derive, re-measure, re-pin.
-MEGA_KERNEL_PERM_GATHERS = 6
-
-#: VMEM tiles the kernel holds live beyond its I/O operands (loop
-#: state flow/potential + excess/residual/admissibility temporaries +
-#: the segmented-scan value/flag pair), matching the accounting that
-#: sized _MEGA_LIVE_TILES in ops/mcmf_pallas.py
-MEGA_SCAN_TEMP_TILES = 8
-
-#: slack allowed between the counted estimate and the gate constant
-#: before the contract demands the gate be re-derived
-MEGA_VMEM_GATE_SLACK_TILES = 4
 
 
 # ---------------------------------------------------------------------------
@@ -219,89 +191,6 @@ def check_jaxpr(backend: str, closed, shape_key: Tuple = ()) -> ContractReport:
     )
 
 
-@dataclass
-class MegaVmemEstimate:
-    R: int
-    L: int
-    io_tiles: int  # VMEM [R, L] operands (inputs + outputs) of the kernel
-    smem_operands: int
-    io_bytes: int
-    est_tiles: int  # io_tiles + MEGA_SCAN_TEMP_TILES + extra_tiles
-    est_bytes: int
-    gate_tiles: int  # _MEGA_LIVE_TILES, what mega_fits_vmem budgets with
-    all_operands_on_chip: bool  # no ANY/HBM-spec'd kernel operands
-    #: tile-equivalents of VMEM operands that are NOT [R, L] entry
-    #: tiles (the solver-telemetry ring), rounded up — with telemetry
-    #: on this is exactly 1 (the ring is clamped to one tile)
-    extra_tiles: int = 0
-
-    @property
-    def gate_is_safe(self) -> bool:
-        """The gate budgets at least the kernel's real live set (the
-        telemetry ring's +1 tile is charged by
-        mega_fits_vmem(telemetry=True), mirrored here)."""
-        return self.gate_tiles + (1 if self.extra_tiles else 0) >= self.est_tiles
-
-    @property
-    def gate_is_tight(self) -> bool:
-        """...and not so conservatively that it has clearly drifted."""
-        return self.gate_tiles <= self.est_tiles + MEGA_VMEM_GATE_SLACK_TILES
-
-
-def find_pallas_calls(closed) -> List:
-    return [e for e, _, _ in walk_eqns(closed.jaxpr) if e.primitive.name == "pallas_call"]
-
-
-def estimate_mega_vmem(closed) -> MegaVmemEstimate:
-    from ..ops.mcmf_pallas import _MEGA_LIVE_TILES
-
-    calls = find_pallas_calls(closed)
-    assert len(calls) == 1, f"expected exactly one pallas_call, found {len(calls)}"
-    grid_mapping = calls[0].params["grid_mapping"]
-    vmem_shapes = []
-    smem = 0
-    on_chip = True
-    for bm in grid_mapping.block_mappings:
-        space = str(getattr(bm, "block_aval", "")).lower()
-        if "vmem" in space:
-            # each dim is a pallas `Blocked(block_size=n)`
-            vmem_shapes.append(tuple(int(b.block_size) for b in bm.block_shape))
-        elif "smem" in space:
-            smem += 1
-        else:
-            on_chip = False
-    assert vmem_shapes, "mega kernel has no VMEM operands?"
-    # the [R, L] entry tiling is the DOMINANT 2-D shape; any other VMEM
-    # operand (the clamped solver-telemetry ring) is charged in
-    # tile-equivalents, rounded up — mega_telemetry_cap bounds the ring
-    # to one tile, so extra_tiles is 0 (telemetry off) or 1 (on)
-    from collections import Counter as _Counter
-
-    shape_counts = _Counter(s for s in vmem_shapes if len(s) == 2)
-    (R, L), _n = shape_counts.most_common(1)[0]
-    tile_bytes = int(R) * int(L) * 4
-    io_tiles = 0
-    extra_bytes = 0
-    for s in vmem_shapes:
-        if tuple(s) == (R, L):
-            io_tiles += 1
-        else:
-            extra_bytes += int(np.prod(s)) * 4
-    extra_tiles = -(-extra_bytes // tile_bytes) if extra_bytes else 0
-    est_tiles = io_tiles + MEGA_SCAN_TEMP_TILES + extra_tiles
-    return MegaVmemEstimate(
-        R=int(R), L=int(L),
-        io_tiles=io_tiles,
-        smem_operands=smem,
-        io_bytes=io_tiles * tile_bytes,
-        est_tiles=est_tiles,
-        est_bytes=est_tiles * tile_bytes,
-        gate_tiles=_MEGA_LIVE_TILES,
-        all_operands_on_chip=on_chip,
-        extra_tiles=extra_tiles,
-    )
-
-
 # ---------------------------------------------------------------------------
 # per-backend abstract tracing
 # ---------------------------------------------------------------------------
@@ -320,8 +209,8 @@ def _sds(shape, dtype=jnp.int32):
 
 
 def _generator_graph(n: int, m: int, seed: int = 0):
-    """Deterministic connected-ish multigraph with skewed degrees (so
-    the ELL plan exercises both the small and hub buckets)."""
+    """Deterministic connected-ish multigraph with skewed degrees (node
+    0 is a hub: a third of the arcs leave it)."""
     rng = np.random.default_rng(seed)
     src = np.where(
         np.arange(m) % 3 == 0, 0, rng.integers(0, n, m)
@@ -344,46 +233,6 @@ def trace_jax(n_raw: int, m_raw: int, seed: int = 0, telemetry_cap: int = 0):
         _sds((e,)), _sds((e,)), _sds((e,)), _sds((e,)), _sds((e,)),
         _sds((e,), jnp.bool_), _sds((e,)),
         _sds((n,)), _sds((n,)), _sds((n,), jnp.bool_),
-    )
-
-
-def trace_ell(n_raw: int, m_raw: int, seed: int = 0, telemetry_cap: int = 0):
-    from ..solver.ell_solver import _solve_mcmf_ell, build_ell_plan, _plan_args
-
-    n, m = bucketed_sizes(n_raw, m_raw)
-    src, dst = _generator_graph(n, m, seed)
-    plan_args = build_ell_plan(src, dst, n)
-    fn = functools.partial(
-        _solve_mcmf_ell, alpha=8, max_supersteps=4096, tighten_sweeps=32,
-        telemetry_cap=telemetry_cap,
-    )
-    plan_sds = tuple(_sds(np.shape(x), np.asarray(x).dtype) for x in _plan_args(plan_args))
-    return jax.make_jaxpr(fn)(
-        _sds((m,)), _sds((m,)), _sds((n,)), _sds((m,)), _sds(()),
-        *plan_sds,
-    )
-
-
-def trace_mega(n_raw: int, m_raw: int, seed: int = 0, telemetry_cap: int = 0):
-    from ..ops.mcmf_pallas import MEGA_LANES, mcmf_loop_pallas, mega_entry_rows
-    from ..utils import next_pow2
-
-    n, m = bucketed_sizes(n_raw, m_raw)
-    # mirrors MegaSolver's host prep: cap/cost/flow0/fwd_pos padded by
-    # _pad_pow2 (floor 256), entry tables tiled [R, MEGA_LANES]
-    mp = max(256, next_pow2(m))
-    npad = max(256, next_pow2(n))
-    R = mega_entry_rows(2 * m)
-    L = MEGA_LANES
-    e = R * L
-    fn = functools.partial(
-        mcmf_loop_pallas, R=R, L=L, alpha=8, max_supersteps=4096,
-        tighten_sweeps=32, interpret=False, telemetry_cap=telemetry_cap,
-    )
-    return jax.make_jaxpr(fn)(
-        _sds((mp,)), _sds((mp,)), _sds((npad,)), _sds((mp,)), _sds(()),
-        _sds((e,)), _sds((e,)), _sds((e,)), _sds((e,)), _sds((e,)),
-        _sds((e,)), _sds((e,)), _sds((mp,)),
     )
 
 
@@ -803,8 +652,6 @@ def trace_corrupt_flip(n_raw: int = 20, m_raw: int = 100):
 
 TRACERS = {
     "jax": trace_jax,
-    "ell": trace_ell,
-    "mega": trace_mega,
     "layered": trace_layered,
     "sharded": trace_sharded,
 }
@@ -903,8 +750,8 @@ def aot_replicated_plan_apply(
 def traced(backend: str, n_raw: int, m_raw: int, seed: int = 0,
            telemetry_cap: int = 0):
     """Cached abstract trace: the contract tests revisit the same
-    (backend, bucket) pairs, and tracing (the megakernel especially)
-    dominates the suite's tier-1 cost. telemetry_cap traces the
+    (backend, bucket) pairs, and tracing dominates the suite's tier-1
+    cost. telemetry_cap traces the
     solver-telemetry-ON program (obs/soltel.py); 0 is the baseline
     pre-telemetry program."""
     return TRACERS[backend](n_raw, m_raw, seed, telemetry_cap=telemetry_cap)

@@ -172,8 +172,6 @@ class ProgramSpec:
     #: names of other registered programs whose default trace must
     #: hash differently (variant non-vacuity)
     distinct_from: Tuple[str, ...] = ()
-    #: run the mega VMEM-estimate-vs-dispatch-gate cross-check
-    vmem_gate: bool = False
     #: annotation name used at the call site (several variant specs
     #: share one physical jit site); defaults to `name`
     site: Optional[str] = None
@@ -205,7 +203,6 @@ _CSR_SAME = (
     (call(40, 220), call(60, 200)),
 )
 _CSR_CROSS = ((call(12, 40), call(12, 200)),)
-_MEGA_CROSS = ((call(12, 40), call(12, 2000)),)
 _LAYERED_SAME = (
     (call(4, 40), call(4, 100)),
     (call(4, 130), call(4, 250)),
@@ -302,30 +299,6 @@ _SPECS = (
         trace=call(4, 20, 100, use_warm_p=True), site="stacked_solve",
         distinct_from=("stacked_solve",),
         notes="lane-batched dirty-frontier refit (the warm seed is a real invar)",
-    ),
-    ProgramSpec(
-        name="ell_solve", module="ksched_tpu.solver.ell_solver", kind="solve",
-        tracer="trace_ell", trace=call(20, 100),
-        extra=(call(12, 40), call(40, 220)),
-        telemetry_off_hash="3e06106007252062", telemetry_knob="telemetry_cap",
-        hash_stability=HashStability(
-            "exempt",
-            reason="entry-table shapes depend on degree buckets; the "
-            "recompile unit is the ELL plan rebuild (tests/test_ell_solver.py)",
-        ),
-        collectives=CollectiveBudget(forbidden=_ALL_COLLECTIVES),
-    ),
-    ProgramSpec(
-        name="mega_solve", module="ksched_tpu.ops.mcmf_pallas", kind="solve",
-        tracer="trace_mega", trace=call(20, 100),
-        extra=(call(12, 40), call(40, 220)),
-        telemetry_off_hash="39ad760141b7be72", telemetry_knob="telemetry_cap",
-        hash_stability=HashStability("pow2-bucket", same=_CSR_SAME, cross=_MEGA_CROSS),
-        gathers=GatherBudget(hbm_loop=0, kernel=6),
-        collectives=CollectiveBudget(forbidden=_ALL_COLLECTIVES),
-        vmem_gate=True,
-        notes="single-pallas_call megakernel; kernel=6 pins the partner-"
-        "permutation reads, hbm_loop=0 locks the zero-HBM-gather claim",
     ),
     ProgramSpec(
         name="layered_solve", module="ksched_tpu.solver.layered", kind="solve",

@@ -89,7 +89,7 @@ class SchedulerService:
         preemption: bool = False,
         _restored: Optional[Tuple] = None,
     ) -> None:
-        if preemption and backend_name in ("auto", "ell"):
+        if preemption and backend_name == "auto":
             raise ValueError(
                 f"--preemption is served by --backend jax (or native, ref): under "
                 f"--backend {backend_name} a round whose running tasks keep their arcs "
@@ -1288,7 +1288,7 @@ def build_arg_parser() -> argparse.ArgumentParser:
         default="trivial",
     )
     ap.add_argument(
-        "--backend", choices=["ref", "native", "jax", "ell", "auto"],
+        "--backend", choices=["ref", "native", "jax", "auto"],
         default="native",
         help="MCMF backend (native C++ is the CPU production default; "
         "auto = per-solve dense-vs-CSR dispatch, solver/graph_collapse.py)",
@@ -1328,8 +1328,8 @@ def build_arg_parser() -> argparse.ArgumentParser:
                     "through ClusterAPI.evict_pods before the round's "
                     "Bindings; the evicted pod stays pending and is bound "
                     "again when a slot frees. Served by --backend jax, native "
-                    "or ref; not with --pipeline, --device-resident, "
-                    "--backend auto or ell")
+                    "or ref; not with --pipeline, --device-resident or "
+                    "--backend auto")
     ap.add_argument("--device-resident", action="store_true",
                     help="keep the flow problem's arrays live on device "
                     "between rounds: after the first full upload only "

@@ -64,7 +64,7 @@ class FlowProblem:
     #: slot-stable CSR plan handle (graph/slot_plan.SlotPlanState) when
     #: the problem came from a DeviceGraphState; None for plain
     #: array-built problems (bulk, tests) — consumers that don't know
-    #: about it (cpu_ref, native, ell, mega, sharded) just ignore it
+    #: about it (cpu_ref, native, sharded) just ignore it
     plan: object = None
     #: cheap endpoint-structure generation key
     #: (state uid, rebuild_count, n_cap, m_cap, endpoint_gen): two
@@ -607,8 +607,8 @@ class DeviceResidentProblem(FlowProblem):
 
 
 def resident_solver_inputs(problem, prev_flow, prev_src, prev_dst, warm_start):
-    """The shared device-resident solve prologue for the general-graph
-    backends (jax/ell/mega): the dispatch args read straight from the
+    """The device-resident solve prologue of the general-graph
+    backend (jax): the dispatch args read straight from the
     persistent buffers, and the warm flow is derived ON DEVICE from the
     solver's previous flow, masked against the endpoint buffers the
     solver captured at its last successful solve. Returns

@@ -3,8 +3,8 @@ opt-in `jax.profiler` capture.
 
 The solvers run their superstep loops *inside* jit (a `lax.while_loop`
 in solver/jax_solver.py, a single fused `pallas_call` in
-ops/mcmf_pallas.py), so per-superstep host spans do not exist — what
-the host can observe, this module records:
+ops/transport_pallas.py), so per-superstep host spans do not exist —
+what the host can observe, this module records:
 
 - per-solve effort (supersteps / iterations / augmentations) as a
   log-bucketed histogram and a per-backend solve counter, labeled with

@@ -5,12 +5,11 @@ thing the <10 ms p50 target lives or dies on — stayed a black box once
 jit'd: a `backend_solve` span carried one superstep COUNT and nothing
 about the convergence shape inside it. This module is the host side of
 the solver-interior instrument: every compiled general-graph backend
-(scan-CSR `jax_solver`, the `mega` Pallas kernel, `layered`, `ell`,
-and the sharded solver) can emit a fixed-size, superstep-indexed
-telemetry buffer alongside its flows, written ON DEVICE (carried
-through the solve loop / written from inside the `pallas_call`), with
-zero extra host syncs — the buffer rides back with the flow fetch —
-and bit-identical flows when disabled (the counters read state the
+(scan-CSR `jax_solver`, `layered` and the sharded solver) can emit a
+fixed-size, superstep-indexed telemetry buffer alongside its flows,
+written ON DEVICE (carried through the solve loop), with zero extra
+host syncs — the buffer rides back with the flow fetch — and
+bit-identical flows when disabled (the counters read state the
 superstep already computed; they never feed back into it).
 
 Buffer layout (`SOLTEL_COLS`, int32 `[cap, SOLTEL_WIDTH]`):
@@ -75,8 +74,8 @@ SOLTEL_COLS = (
 )
 SOLTEL_WIDTH = 8
 
-#: default ring capacity (supersteps kept); solvers may clamp it down
-#: (the megakernel bounds the buffer to one VMEM tile)
+#: default ring capacity (supersteps kept): 512 rows of SOLTEL_WIDTH
+#: int32 are 16 KiB carried through the solve loop
 SOLTEL_DEFAULT_CAP = 512
 
 #: supersteps of telemetry attached to structured stall/failure events
@@ -126,9 +125,8 @@ def resolve_cap(override: Optional[int]) -> int:
 # One implementation of the ring scheme for every XLA backend — the
 # counter SEMANTICS per column live in each solver (they read different
 # per-backend intermediates), but the row layout and the ring write are
-# shared here so they cannot drift. The mega Pallas kernel keeps its
-# own write (a lane-iota construct; jnp.stack of scalars doesn't lower
-# there). jax is imported lazily: obs stays importable host-only.
+# shared here so they cannot drift. jax is imported lazily: obs stays
+# importable host-only.
 
 
 def device_rows_iota(cap: int):
@@ -607,22 +605,3 @@ def _synthesize_spans(tel: SolveTelemetry, sp) -> None:
                 "parent": sp.name,
             },
         )
-
-
-def publish_round_supersteps(supersteps, backend: str) -> None:
-    """Per-round superstep counts from a device-fused path (the
-    DeviceBulkCluster scan, trace replay) onto the registry — the
-    interior of those solves stays on device, but the per-round
-    superstep series is solver telemetry too: a driver of that path
-    publishes it after its clock stops."""
-    ss = np.asarray(supersteps).reshape(-1)
-    if ss.size == 0:
-        return
-    hist = get_registry().histogram(
-        "ksched_solve_supersteps",
-        "supersteps per solve, from solver-interior telemetry",
-        labelnames=("backend",),
-        buckets=COUNT_BUCKETS,
-    ).labels(backend=backend)
-    for v in ss:
-        hist.observe(int(v))

@@ -142,12 +142,6 @@ def __getattr__(name):
         from .transport_pallas import transport_loop_pallas_tiered
 
         return transport_loop_pallas_tiered
-    if name == "mcmf_loop_pallas":
-        # the general-graph MCMF megakernel (mcmf_pallas.py): the whole
-        # CSR push-relabel loop in one kernel, tables VMEM-resident
-        from .mcmf_pallas import mcmf_loop_pallas
-
-        return mcmf_loop_pallas
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 

@@ -721,8 +721,7 @@ def make_sharded_slot_solver(
 #: superstep: the 6 resident entry tables (arc/sign/src/dst/segstart/
 #: isstart) plus ~8 superstep temporaries (a_flow, residual, signed
 #: cost, reduced cost, per-entry excess, admissible residual, the
-#: prefix cumsum and its exclusive form) — the same live-set
-#: accounting style as ops/mcmf_pallas._MEGA_LIVE_TILES, at HBM scale
+#: prefix cumsum and its exclusive form)
 _CSR_LIVE_EVECS = 14
 #: [N] node-space vectors live per superstep (supply, excess, p,
 #: relabel candidates, boundary statics)
@@ -755,9 +754,9 @@ def csr_working_set_bytes(n_cap: int, m_cap: int) -> int:
 def scan_csr_fits_hbm(
     n_cap: int, m_cap: int, budget_bytes: int = DEFAULT_HBM_BUDGET_BYTES
 ) -> bool:
-    """Whether one chip's budget holds the scan-CSR working set —
-    mirror of `mega_fits_vmem`'s live-set arithmetic one rung up the
-    memory hierarchy. False is what escalates dispatch to the sharded
+    """Whether one chip's budget holds the scan-CSR working set
+    (`csr_working_set_bytes`: 4 bytes times the live entry, node and
+    arc vectors). False is what escalates dispatch to the sharded
     rung (solver/graph_collapse.AutoSolver)."""
     return csr_working_set_bytes(n_cap, m_cap) <= budget_bytes
 

@@ -78,7 +78,7 @@ class FlowSolver(abc.ABC):
         reads when no tracer is installed.
 
         Backends that emit solver-interior telemetry (``last_telemetry``
-        after a solve — the compiled jax/ell/mega/layered/sharded
+        after a solve — the compiled jax/layered/sharded
         loops) additionally get their buffer decoded here: superstep
         histograms onto the registry, per-superstep child spans under
         this span, or under the backend's own ``last_solve_span`` when

@@ -15,9 +15,8 @@ predicate so the choice is automatic per solve:
          arc arrays. Collapsible -> group tasks into signature rows,
          solve ONE dense transport, reconstruct exact per-arc flows.
          Any refusal (with a reason, kept for observability) -> the
-         general-graph backends, unchanged semantics: the VMEM-resident
-         Pallas megakernel (solver/mega_solver.py) when the graph fits
-         its tiling budget, else the scan-based CSR backend.
+         general-graph backend, unchanged semantics: the scan-based
+         CSR backend (or the sharded one, past one chip's HBM).
 
 Soundness: every refusal is conservative (routing to CSR can only cost
 time, never correctness), and the collapse itself is exact by the
@@ -671,37 +670,30 @@ def try_collapse(problem) -> Tuple[Optional[GraphCollapse], str]:
 
 
 class AutoSolver(FlowSolver):
-    """The automatic policy-dispatch seam, now a FOUR-rung ladder by
-    graph size: dense transport when the graph is collapsible, the
-    VMEM-resident Pallas megakernel (solver/mega_solver.py) when a
-    general graph fits the kernel's VMEM tiling budget, the scan-based
-    CSR backend while its HBM working set fits one chip, and the
-    SHARDED multi-chip backend (parallel/sharded_solver.py) beyond
-    that. Drop-in FlowSolver (PlacementSolver/FlowScheduler-
-    compatible); `last_path` ("dense" | "mega" | "csr" | "sharded") /
-    `last_refusal` / `last_mega_refusal` expose which way each solve
-    went and why.
+    """The automatic policy-dispatch seam, a three-rung ladder:
+    dense transport when the graph is collapsible, the general-graph
+    CSR backend it was given (scan-CSR or native) while the scan-CSR
+    HBM working set fits one chip, and the SHARDED multi-chip backend
+    (parallel/sharded_solver.py) beyond that. Drop-in FlowSolver
+    (PlacementSolver/FlowScheduler-compatible); `last_path`
+    ("dense" | "csr" | "sharded") / `last_refusal` expose which way
+    each solve went and why.
 
-    `mega` and `sharded` are optional: without them the ladder is the
-    historical dense -> CSR dispatch. The cost model behind the mega
-    rung is the kernel's live-set arithmetic (ops/mcmf_pallas.py
-    mega_fits_vmem); the sharded rung mirrors it one level up the
-    memory hierarchy (`scan_csr_fits_hbm` / `sharded_fits_hbm`,
-    parallel/sharded_solver.py): escalation to the sharded rung
-    happens exactly when the scan-CSR live set outgrows the per-chip
-    HBM working-set budget AND the per-shard slice fits it — a graph
-    too big even per-shard falls back to scan-CSR, the guaranteed-
-    correct (if memory-risky) total rung. The budget resolves from
+    `sharded` is optional: without it the ladder is the dense -> CSR
+    dispatch. Escalation to the sharded rung (`scan_csr_fits_hbm` /
+    `sharded_fits_hbm`, parallel/sharded_solver.py) happens exactly
+    when the scan-CSR live set outgrows the per-chip HBM working-set
+    budget AND the per-shard slice fits it — a graph too big even
+    per-shard falls back to scan-CSR, the guaranteed-correct (if
+    memory-risky) total rung. The budget resolves from
     `hbm_budget_bytes`, else the KSCHED_HBM_BUDGET env var, else
     DEFAULT_HBM_BUDGET_BYTES (docs/sharding.md derives it)."""
 
     def __init__(self, csr_backend: FlowSolver,
                  alpha: int = 8, max_supersteps: int = 1 << 17,
-                 mega: Optional[FlowSolver] = None,
                  sharded=None,
                  hbm_budget_bytes: Optional[int] = None):
         self.csr = csr_backend
-        self.mega = mega
         #: sharded rung: a FlowSolver, or a zero-arg factory resolved
         #: lazily on the first escalation (mesh construction and
         #: shard_map compiles cost nothing until a graph needs them)
@@ -716,7 +708,6 @@ class AutoSolver(FlowSolver):
         self.max_supersteps = max_supersteps
         self.last_path = ""
         self.last_refusal = ""
-        self.last_mega_refusal = ""
         self.last_supersteps = 0
         #: solver-interior telemetry of the rung that produced the last
         #: solve (obs/soltel.py); solve_traced publishes it
@@ -741,8 +732,6 @@ class AutoSolver(FlowSolver):
 
     def reset(self) -> None:
         self.csr.reset()
-        if self.mega is not None:
-            self.mega.reset()
         if isinstance(self._sharded, FlowSolver):
             self._sharded.reset()
 
@@ -750,7 +739,7 @@ class AutoSolver(FlowSolver):
         """The HBM fitting gate: True when the single-chip scan-CSR
         working set exceeds the per-chip budget AND the per-shard
         slice fits it (parallel/sharded_solver.py live-set
-        arithmetic, mirroring mega_fits_vmem one memory level up)."""
+        arithmetic)."""
         if self._sharded is None:
             return False
         from ..parallel.sharded_solver import (
@@ -778,20 +767,6 @@ class AutoSolver(FlowSolver):
             if collapse is None:
                 sp.set("reason", reason)
         if collapse is None:
-            mega = self.mega
-            if mega is not None and mega.fits(problem):
-                self.last_path, self.last_refusal = "mega", reason
-                self.last_mega_refusal = ""
-                res = mega.solve(problem)
-                self.last_supersteps = getattr(
-                    mega, "last_supersteps", res.iterations
-                )
-                self.last_telemetry = getattr(mega, "last_telemetry", None)
-                return res
-            self.last_mega_refusal = (
-                getattr(mega, "last_refusal", "") if mega is not None
-                else "no megakernel attached"
-            )
             if self._escalates_to_sharded(problem):
                 sharded = self.sharded
                 self.last_path, self.last_refusal = "sharded", reason
@@ -811,7 +786,6 @@ class AutoSolver(FlowSolver):
             self.last_telemetry = getattr(self.csr, "last_telemetry", None)
             return res
         self.last_path, self.last_refusal = "dense", ""
-        self.last_mega_refusal = ""
         return self._solve_dense(problem, collapse)
 
     def _solve_dense(self, problem, gc: GraphCollapse) -> FlowResult:

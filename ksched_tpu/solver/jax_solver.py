@@ -279,8 +279,7 @@ def _solve_mcmf(
     neither the prologue nor a phase change of a cold ladder converts
     to arc space. Held bit for bit to the arc-state program it replaced
     (flow, p, steps, converged, p_overflow, every soltel row) by
-    tests/test_csr_entry_state.py, and through that to the ELL and mega
-    solvers' parity suites.
+    tests/test_csr_entry_state.py.
 
     use_warm_p=True REFITS the caller-supplied ``warm_p`` potentials
     (the previous round's device-resident prices) instead of running

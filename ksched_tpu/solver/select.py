@@ -17,9 +17,9 @@ def make_backend(
     name: str, warm_start: bool = True, fallback: bool = True, preemption: bool = False,
     price_updates: bool = False,
 ) -> FlowSolver:
-    """name: "native" | "jax" | "ell" | "mega" | "sharded" | "ref" |
-    "layered" | "auto". With fallback=True a failed native build degrades to the
-    JAX solver with a RuntimeWarning (capturable by callers/tests via
+    """name: "native" | "jax" | "sharded" | "ref" | "layered" | "auto".
+    With fallback=True a failed native build degrades to the JAX solver
+    with a RuntimeWarning (capturable by callers/tests via
     warnings.catch_warnings, unlike the stderr print it replaced).
     ``preemption`` says the graphs to come keep their running tasks'
     arcs: the scan-CSR rung ("jax") then runs its global price update
@@ -50,40 +50,6 @@ def make_backend(
                 PREEMPTION_PRICE_UPDATE_EVERY if preemption or price_updates else 0
             ),
         )
-    if name == "ell":
-        # bucketed-ELL layout of the same push-relabel (ell_solver.py):
-        # measured within ~2% of the CSR layout on TPU at 10k x 1k —
-        # both are bound by gather/iteration costs, not the scans —
-        # kept selectable for degree-skewed graphs where the dense
-        # row ops pay off
-        from .ell_solver import EllSolver
-
-        return EllSolver(warm_start=warm_start)
-    if name == "mega":
-        # the Pallas megakernel (ops/mcmf_pallas.py): the whole
-        # push-relabel loop in one kernel launch, tables VMEM-resident
-        # for the solve. Compiled unless the interpreter is asked for
-        # by name (set_pallas_mode("interpret")); a kernel the Pallas
-        # TPU compiler refuses is an error here, with its message —
-        # never a hand-over to the interpreter or to scan-CSR. Graphs
-        # beyond the VMEM tiling budget delegate to the scan-based CSR
-        # solver so the backend stays total.
-        from ..ops import get_pallas_mode
-        from .jax_solver import JaxSolver
-        from .mega_solver import MegaSolver
-
-        if get_pallas_mode() != "interpret":
-            from ..ops.mcmf_pallas import mega_compiler_refusal
-
-            refusal = mega_compiler_refusal()
-            if refusal:
-                raise RuntimeError(
-                    f"megakernel refused by the Pallas TPU compiler: {refusal}"
-                )
-        return MegaSolver(
-            warm_start=warm_start,
-            fallback=JaxSolver(warm_start=warm_start),
-        )
     if name == "sharded":
         # the multi-chip slot-stable backend over the full device mesh
         # (parallel/sharded_solver.py); under AutoSolver ("auto") it is
@@ -108,39 +74,14 @@ def make_backend(
         return LayeredTransportSolver()
     if name == "auto":
         # the policy-dispatch seam (docs/solver_coverage.md): dense
-        # transport whenever the graph audits as collapsible, then the
-        # megakernel for general graphs inside its VMEM budget, the
+        # transport whenever the graph audits as collapsible, the
         # scan-based CSR backend while its HBM working set fits one
         # chip, the sharded multi-chip backend beyond that — per
-        # solve, automatically. The mega rung is attached only when
-        # Pallas dispatch is live (TPU backend, or a forced
-        # "on"/"interpret" mode) and, where it would run compiled, the
-        # compiler takes the kernel (a refusal detaches the rung with
-        # a RuntimeWarning carrying the compiler's words). The
-        # sharded rung is attached (lazily — no mesh or shard_map
-        # compile until the fitting gate escalates) whenever the
-        # process sees more than one device.
-        from ..ops import resolve_pallas
+        # solve, automatically. The sharded rung is attached (lazily —
+        # no mesh or shard_map compile until the fitting gate
+        # escalates) whenever the process sees more than one device.
         from .graph_collapse import AutoSolver
 
-        mega = None
-        use_pallas, interpret = resolve_pallas()
-        if use_pallas and not interpret:
-            from ..ops.mcmf_pallas import mega_compiler_refusal
-
-            refusal = mega_compiler_refusal()
-            if refusal:
-                warnings.warn(
-                    "megakernel rung not attached — refused by the "
-                    f"Pallas TPU compiler: {refusal}",
-                    RuntimeWarning,
-                    stacklevel=2,
-                )
-                use_pallas = False
-        if use_pallas:
-            from .mega_solver import MegaSolver
-
-            mega = MegaSolver(warm_start=warm_start)
         sharded = None
         import jax
 
@@ -159,10 +100,9 @@ def make_backend(
             sharded = _make_sharded
         return AutoSolver(
             make_backend("native", warm_start=warm_start, fallback=fallback),
-            mega=mega,
             sharded=sharded,
         )
     raise ValueError(
-        f"unknown backend {name!r}; want native | jax | ell | mega | "
-        "sharded | ref | layered | auto"
+        f"unknown backend {name!r}; want native | jax | sharded | ref | "
+        "layered | auto"
     )

@@ -12,7 +12,7 @@ from .ids import (
     seed_rng,
 )
 from .maps import JobMap, ResourceMap, ResourceStatus, TaskMap
-from .platform import device_stamp, enable_compile_cache, require_accelerator
+from .platform import device_stamp, enable_compile_cache
 
 __all__ = [
     "ExpBackoff",
@@ -32,5 +32,4 @@ __all__ = [
     "TaskMap",
     "device_stamp",
     "enable_compile_cache",
-    "require_accelerator",
 ]

@@ -5,9 +5,8 @@ __graft_entry__, tools/soak.py) calls `enable_compile_cache()` before
 its first trace so that processes of one checkout share one persistent
 XLA cache. A measurement entry point that finds no chip fails, so a
 CPU reading never goes under a device metric's name: benchmarks/run.py
-and chip_smoke.py check the platform themselves, `require_accelerator()`
-is the same check for a caller that does not. The CPU path needs only
-`JAX_PLATFORMS=cpu` set before `import jax`.
+and chip_smoke.py check the platform themselves. The CPU path needs
+only `JAX_PLATFORMS=cpu` set before `import jax`.
 """
 
 from __future__ import annotations
@@ -49,14 +48,3 @@ def device_stamp() -> dict:
         "kind": devs[0].device_kind,
         "count": len(devs),
     }
-
-
-def require_accelerator(what: str) -> None:
-    """Exit non-zero (printing no record) unless an accelerator backs
-    JAX. There is no CPU fallback: a host reading is taken only by an
-    explicit request (`--cpu`)."""
-    if device_stamp()["platform"] == "cpu":
-        raise SystemExit(
-            f"{what}: no accelerator (jax.devices()[0].platform == 'cpu'); "
-            "refusing to run a device measurement on the host"
-        )

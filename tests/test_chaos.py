@@ -181,9 +181,8 @@ def test_nan_cost_rejected_by_backends():
     poisoned cost model would commit nonsense placements instead of
     triggering the degradation ladder."""
     from ksched_tpu.runtime.chaos import poison_costs
-    from ksched_tpu.solver.ell_solver import EllSolver
     from ksched_tpu.solver.jax_solver import JaxSolver
-    from ksched_tpu.solver.mega_solver import MegaSolver
+    from ksched_tpu.solver.native import NativeSolver
     from ksched_tpu.solver.placement import PlacementSolver
 
     sched = _tiny_cluster(ReferenceSolver())
@@ -197,7 +196,7 @@ def test_nan_cost_rejected_by_backends():
     problem = ps.state.problem()
     bad = poison_costs(problem)
     assert bad.cost.dtype.kind == "f" and np.isnan(bad.cost).any()
-    for backend in (ReferenceSolver(), JaxSolver(), EllSolver(), MegaSolver()):
+    for backend in (ReferenceSolver(), JaxSolver(), NativeSolver()):
         with pytest.raises(ValueError, match="non-finite arc costs"):
             backend.solve(bad)
     # the clean problem still solves (the check has no false positives)

@@ -3,16 +3,37 @@
 MCMF optima are non-unique, so parity = identical objective cost (the
 well-defined invariant); scheduler-level placement parity is asserted in
 test_scheduler_backends.py under a deterministic tie-break.
+
+The parity matrices run two rungs: `jax` is JaxSolver alone, `auto` is
+the ladder `make_backend("auto")` builds, on problems whose nodes are
+untyped, so the collapse audit refuses them and the general-graph rung
+answers (`last_path == "csr"`, with the audit's reason).
 """
+
+import functools
 
 import numpy as np
 import pytest
 
 from ksched_tpu.graph.device_export import FlowProblem
 from ksched_tpu.solver import ReferenceSolver
+from ksched_tpu.solver.graph_collapse import AutoSolver
 from ksched_tpu.solver.jax_solver import JaxSolver
+from ksched_tpu.solver.select import make_backend
 
 from test_solver_oracle import make_problem
+
+RUNGS = ["jax", "auto"]
+
+
+def make_rung(rung):
+    return JaxSolver() if rung == "jax" else make_backend("auto")
+
+
+def assert_general_rung_answered(rung, solver):
+    if rung == "auto":
+        assert solver.last_path == "csr"
+        assert solver.last_refusal
 
 
 def assert_valid_flow(p: FlowProblem, flow: np.ndarray):
@@ -25,8 +46,11 @@ def assert_valid_flow(p: FlowProblem, flow: np.ndarray):
     assert ((p.excess - out_ + in_) == 0).all()
 
 
-@pytest.mark.parametrize("case", ["single", "cheap", "split", "assign", "escape"])
-def test_small_parity(case):
+@pytest.mark.parametrize(
+    "case", ["single", "cheap", "split", "assign", "escape", "negative", "lower_bound"]
+)
+@pytest.mark.parametrize("rung", RUNGS)
+def test_small_parity(rung, case):
     problems = {
         "single": make_problem(4, {1: 1, 3: -1}, [(1, 2, 0, 1, 2), (2, 3, 0, 1, 3)]),
         "cheap": make_problem(
@@ -63,12 +87,22 @@ def test_small_parity(case):
                 (7, 6, 0, 2, 0),
             ],
         ),
+        # test_solver_oracle.py's two: a negative cost (optimum 1), and a
+        # running arc whose lower bound forces the dearer path (optimum 7)
+        "negative": make_problem(
+            4, {1: 1, 3: -1}, [(1, 2, 0, 1, -2), (2, 3, 0, 1, 3), (1, 3, 0, 1, 5)]
+        ),
+        "lower_bound": make_problem(
+            4, {1: 1, 3: -1}, [(1, 2, 1, 1, 7), (2, 3, 0, 1, 0), (1, 3, 0, 1, 1)]
+        ),
     }
     p = problems[case]
     ref = ReferenceSolver().solve(p)
-    jx = JaxSolver().solve(p)
-    assert_valid_flow(p, jx.flow)
-    assert jx.objective == ref.objective
+    solver = make_rung(rung)
+    got = solver.solve(p)
+    assert_general_rung_answered(rung, solver)
+    assert_valid_flow(p, got.flow)
+    assert got.objective == ref.objective
 
 
 def random_scheduling_problem(rng, num_tasks, num_machines, slots_per_machine, num_jobs=3):
@@ -108,7 +142,7 @@ def random_scheduling_problem(rng, num_tasks, num_machines, slots_per_machine, n
     return make_problem(nid, excess, arcs)
 
 
-def test_random_parity():
+def test_random_parity_seed0():
     rng = np.random.default_rng(0)
     for trial in range(8):
         p = random_scheduling_problem(
@@ -123,10 +157,37 @@ def test_random_parity():
         assert_valid_flow(p, jx.flow)
 
 
-def test_warm_start_incremental():
+@functools.lru_cache(maxsize=None)
+def _seed11_problems():
+    rng = np.random.default_rng(11)
+    return [
+        random_scheduling_problem(
+            rng,
+            num_tasks=int(rng.integers(3, 40)),
+            num_machines=int(rng.integers(1, 6)),
+            slots_per_machine=int(rng.integers(1, 4)),
+        )
+        for _ in range(8)
+    ]
+
+
+@pytest.mark.parametrize("trial", range(8))
+@pytest.mark.parametrize("rung", RUNGS)
+def test_random_parity(rung, trial):
+    p = _seed11_problems()[trial]
+    ref = ReferenceSolver().solve(p)
+    solver = make_rung(rung)
+    got = solver.solve(p)
+    assert_general_rung_answered(rung, solver)
+    assert got.objective == ref.objective
+    assert_valid_flow(p, got.flow)
+
+
+@pytest.mark.parametrize("rung", RUNGS)
+def test_warm_start_incremental(rung):
     rng = np.random.default_rng(1)
     p = random_scheduling_problem(rng, num_tasks=10, num_machines=3, slots_per_machine=2)
-    solver = JaxSolver()
+    solver = make_rung(rung)
     r1 = solver.solve(p)
     ref1 = ReferenceSolver().solve(p)
     assert r1.objective == ref1.objective
@@ -150,3 +211,16 @@ def test_warm_start_incremental():
     assert r2.objective == ref2.objective
     # warm restart should not be wildly more expensive than cold
     assert solver.last_supersteps <= max(cold_steps * 2, 50)
+
+
+@pytest.mark.parametrize("ladder", [False, True], ids=["jax", "auto_over_jax"])
+def test_cost_whose_scaling_overflows_int32_is_refused_by_name(ladder):
+    """A cost of 2^28 on a 4-node graph: scan-CSR scales costs by the
+    node count in int32 and says so instead of wrapping; the ladder
+    routes the graph to that rung and lets its refusal through."""
+    p = make_problem(4, {1: 1, 3: -1}, [(1, 2, 0, 1, 1 << 28), (2, 3, 0, 1, 1)])
+    solver = AutoSolver(JaxSolver()) if ladder else JaxSolver()
+    with pytest.raises(OverflowError, match="scaled costs overflow int32"):
+        solver.solve(p)
+    if ladder:
+        assert solver.last_path == "csr"

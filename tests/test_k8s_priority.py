@@ -302,9 +302,8 @@ def test_the_pairs_that_are_not_served_are_refused_with_a_message():
     ):
         with pytest.raises(ValueError, match=word):
             cli.build_service(_args(2, 2, "jax", extra), api)
-    for backend in ("auto", "ell"):
-        with pytest.raises(ValueError, match="global price update"):
-            cli.build_service(_args(2, 2, backend), api)
+    with pytest.raises(ValueError, match="global price update"):
+        cli.build_service(_args(2, 2, "auto"), api)
     with pytest.raises(SystemExit) as e:  # the CLI says it as a usage error
         cli.main("--fake-machines --cost-model k8s_priority --podgen 1 --one-shot".split())
     assert e.value.code == 2

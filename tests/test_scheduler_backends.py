@@ -341,14 +341,3 @@ def test_auto_solver_reports_csr_supersteps_of_zero():
     assert auto.solve(p) == "fake-result"
     assert auto.last_path == "csr"
     assert auto.last_supersteps == 0
-
-
-def test_ell_backend_matches_oracle_through_scheduler():
-    """The bucketed-ELL layout (solver/ell_solver.py) through the full
-    event loop: same placement counts and binding totals as the oracle
-    every round — the graph-path drop-in contract for `--backend ell`."""
-    from ksched_tpu.solver.ell_solver import EllSolver
-
-    ref_trace = drive(None)
-    ell_trace = drive(EllSolver(w_hub=16))
-    assert ref_trace == ell_trace

@@ -133,7 +133,7 @@ def test_single_chip_solver_consumes_sharded_mirror(mesh2):
 
 
 def test_autosolver_escalates_by_fitting_gate(mesh2):
-    """dense -> mega -> csr -> sharded: with a budget between the
+    """dense -> csr -> sharded: with a budget between the
     per-shard and single-chip working sets the general-graph solve
     escalates to the sharded rung and stays bit-identical to the CSR
     arm; with the default budget this small bucket never escalates."""
@@ -200,9 +200,9 @@ def test_sharded_layout_tolerates_empty_shards():
 
 
 def test_fitting_gate_arithmetic():
-    """The estimators mirror mega_fits_vmem's shape: monotone in the
-    graph bucket, per-shard strictly below single-chip for D > 1, and
-    a graph that fits nobody escalates nowhere (falls back to CSR)."""
+    """The estimators are monotone in the graph bucket, per-shard
+    strictly below single-chip for D > 1, and a graph that fits nobody
+    escalates nowhere (falls back to CSR)."""
     assert csr_working_set_bytes(1 << 10, 1 << 12) < csr_working_set_bytes(
         1 << 10, 1 << 14
     )

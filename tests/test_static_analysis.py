@@ -12,8 +12,8 @@ Level 3 (ISSUE 18): the declarative program registry drives the whole
 per-program sweep — one parametrized test runs every applicable check
 (dtype/scatter/gather/collective contracts, telemetry-off hash pin,
 pow2-bucket hash stability, telemetry-knob semantics, variant
-distinctness, the compiled donation/aliasing audit, the mega VMEM
-gate, module ownership) for every registered program. The hand-written
+distinctness, the compiled donation/aliasing audit, module
+ownership) for every registered program. The hand-written
 per-program test functions this replaces live on as registry data.
 """
 
@@ -397,7 +397,7 @@ def test_collect_program_sites_classifies_kinds():
         from jax.experimental import pallas as pl
 
         a = jax.jit(lambda x: x)  # kschedlint: program=csr_solve
-        b = pl.pallas_call(lambda r, o: None)  # kschedlint: program=mega_solve
+        b = pl.pallas_call(lambda r, o: None)  # kschedlint: program=layered_solve
     """))
     kinds = {s.kind for s in collect_program_sites(ctx)}
     assert kinds == {"jit", "pallas_call"}
@@ -414,8 +414,7 @@ def test_registry_matches_select():
     with open(os.path.join(REPO_ROOT, "ksched_tpu", "solver", "select.py")) as fh:
         select_src = fh.read()
     for rung, program in (
-        ("jax", "csr_solve"), ("ell", "ell_solve"), ("mega", "mega_solve"),
-        ("layered", "layered_solve"),
+        ("jax", "csr_solve"), ("layered", "layered_solve"),
     ):
         assert f'name == "{rung}"' in select_src
         assert program in PROGRAMS
@@ -440,7 +439,7 @@ def test_registry_policies_are_coherent():
 
 
 def test_registry_pins_are_the_pretelemetry_baselines():
-    """The five telemetry-off hash pins (telemetry-off traces, derived
+    """The three telemetry-off hash pins (telemetry-off traces, derived
     on jax 0.9.0) live in the registry; this literal copy guards
     against an accidental registry edit re-pinning them.
     A jax upgrade that changes jaxpr printing re-pins BOTH in the same
@@ -453,8 +452,6 @@ def test_registry_pins_are_the_pretelemetry_baselines():
         for n, s in PROGRAMS.items() if s.telemetry_off_hash
     } == {
         "csr_solve": "c3cd4c121a78d56a",  # PR 29: state carried in entry space
-        "ell_solve": "3e06106007252062",
-        "mega_solve": "39ad760141b7be72",
         # sharded traces over the conftest 8-virtual-device mesh; its
         # hash is mesh-size-dependent (the others' are not)
         "sharded_solve": "3d9cf1c3ee42486b",
@@ -494,32 +491,14 @@ def test_every_program_gets_contract_and_ownership_checks():
 
 
 def test_csr_backend_shows_the_contrast():
-    """The scan-CSR backend pays per-superstep HBM gathers (that is
-    the megakernel's whole reason to exist) — if this ever reads 0 the
+    """The scan-CSR backend pays per-superstep HBM gathers (they are
+    most of a superstep on the v5e) — if this ever reads 0 the
     gather classifier is broken, not the solver fixed. (The registry
     pins csr_solve's exact count, 12 since PR 29; asserted directly
     here so a GatherBudget refactor can't drop it.)"""
     report = engine.report(PROGRAMS["csr_solve"])
     assert report.hbm_loop_gathers > 0
     assert PROGRAMS["csr_solve"].gathers.hbm_loop == report.hbm_loop_gathers
-
-
-def test_mega_gate_refuses_exactly_where_estimate_exceeds_budget():
-    """Beyond check_vmem_gate's safety/tightness: the dispatch gate's
-    refusal boundary must coincide with the counted estimate across
-    entry counts spanning tiny to beyond-budget."""
-    from ksched_tpu.ops.mcmf_pallas import (
-        _MEGA_VMEM_BUDGET_BYTES,
-        MEGA_LANES,
-        mega_entry_rows,
-        mega_fits_vmem,
-    )
-
-    est = jc.estimate_mega_vmem(engine.trace_call(PROGRAMS["mega_solve"]))
-    for entries in (512, 1 << 15, 1 << 18, 1 << 20, 1 << 22):
-        padded = mega_entry_rows(entries) * MEGA_LANES
-        counted_fits = est.gate_tiles * padded * 4 <= _MEGA_VMEM_BUDGET_BYTES
-        assert mega_fits_vmem(entries) == counted_fits
 
 
 # ---------------------------------------------------------------------------
