@@ -24,7 +24,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 from .cluster import Binding, ClusterAPI, NodeEvent, PodEvent, SyntheticClusterAPI
 from .cluster.api import RETRY_STAT_KEYS
 from .costmodels import MODEL_REGISTRY, CostModelType
-from .data import RACK_LABEL, ZONE_LABEL
+from .data import PLATFORM_LABEL, RACK_LABEL, ZONE_LABEL
 from .obs import metrics as obs_metrics
 from .obs.flight import FlightRecorder
 from .obs.spans import SpanTracer, active_tracer, span
@@ -58,6 +58,58 @@ from .utils import (
 SERVICE_CHECKPOINT_VERSION = 2
 
 
+#: one type of fake machine (--fake-machine-types): its name, which is
+#: its platform label; its cores; its share of the machines, per mille
+MachineType = Tuple[str, int, int]
+
+#: machine i is dealt its type by (_DEAL_STRIDE * i) mod 1000: the stride
+#: is coprime to 1000, so every thousand consecutive machines hold each
+#: type exactly its share, spread over the thousand and not in one run
+_DEAL_STRIDE = 619
+
+
+def parse_machine_types(text: str) -> Tuple[MachineType, ...]:
+    """``A:1:10,B:2:930,C:4:60`` -> ((name, cores, per mille), ...), the
+    shares summing to 1000; ``argparse.ArgumentTypeError`` otherwise."""
+    types = []
+    try:
+        for part in text.split(","):
+            name, cores, share = part.split(":")
+            types.append((name, int(cores), int(share)))
+    except ValueError:
+        raise argparse.ArgumentTypeError(
+            f"{text!r}: want NAME:CORES:PERMILLE,... (e.g. A:1:10,B:2:930,C:4:60)"
+        ) from None
+    names = [name for name, _c, _s in types]
+    if (
+        not all(names) or len(set(names)) != len(names)
+        or any(cores < 1 or share < 0 for _n, cores, share in types)
+        or sum(share for _n, _c, share in types) != 1000
+    ):
+        raise argparse.ArgumentTypeError(
+            f"{text!r}: distinct names, at least one core a type, shares that sum to 1000"
+        )
+    return tuple(types)
+
+
+def machine_type_of(index: int, types: Sequence[MachineType]) -> MachineType:
+    """The type of fake machine ``index``: a pure function of the index."""
+    r = (_DEAL_STRIDE * index) % 1000
+    for mtype in types:
+        if r < mtype[2]:
+            return mtype
+        r -= mtype[2]
+    raise ValueError("the shares of the machine types do not sum to 1000")
+
+
+def fake_cores(num_machines: int, cores_per_machine: int, types: Sequence[MachineType]) -> int:
+    """The cores of ``num_machines`` fake machines: every machine's
+    ``cores_per_machine``, or what ``types`` deals each."""
+    if not types:
+        return num_machines * cores_per_machine
+    return sum(machine_type_of(i, types)[1] for i in range(num_machines))
+
+
 class SchedulerService:
     """The long-running scheduler process state (reference:
     cmd/k8sscheduler/scheduler.go:44-87), hardened: the configured
@@ -87,6 +139,7 @@ class SchedulerService:
         fake_zones: int = 0,
         fake_racks: int = 0,
         preemption: bool = False,
+        fake_machine_types: Sequence[MachineType] = (),
         _restored: Optional[Tuple] = None,
     ) -> None:
         if preemption and backend_name == "auto":
@@ -123,6 +176,11 @@ class SchedulerService:
         #: --fake-racks: the same for a rack label, machine i that of
         #: rack i mod fake_racks
         self.fake_racks = fake_racks
+        #: --fake-machine-types: the fake machines of init_topology are
+        #: of these types, machine i of the type `machine_type_of` deals
+        #: it; each carries its type's name as its platform label and
+        #: its type's cores (empty: every machine alike, no label)
+        self.fake_machine_types = tuple(fake_machine_types)
         self.injector = injector
         self.tracer = tracer
         self.flight = flight
@@ -268,7 +326,10 @@ class SchedulerService:
         the control plane for nodes (:206-238). With ``fake_zones`` the
         fake machines are dealt round-robin over that many zones, as
         scheduler_perf's labelNodePrepareStrategy deals its values; with
-        ``fake_racks`` over that many racks, the same way."""
+        ``fake_racks`` over that many racks, the same way. With
+        ``fake_machine_types`` machine i is of the type
+        ``machine_type_of`` deals it: that type's cores, and its name
+        under the platform label."""
         if fake_machines > 0:
             dealt = [
                 (key, prefix, n)
@@ -277,13 +338,19 @@ class SchedulerService:
                 )
                 if n > 0
             ]
+            types = self.fake_machine_types
             for i in range(fake_machines):
+                labels = [(key, f"{prefix}-{i % n}") for key, prefix, n in dealt]
+                cores = cores_per_machine
+                if types:
+                    name, cores, _share = machine_type_of(i, types)
+                    labels.append((PLATFORM_LABEL, name))
                 self.add_node(
                     NodeEvent(
                         node_id=f"fake_node_{i}",
-                        num_cores=cores_per_machine,
+                        num_cores=cores,
                         pus_per_core=pus_per_core,
-                        labels=tuple((key, f"{prefix}-{i % n}") for key, prefix, n in dealt),
+                        labels=tuple(labels),
                     )
                 )
             return fake_machines
@@ -1282,6 +1349,13 @@ def build_arg_parser() -> argparse.ArgumentParser:
                     "reads; 0 = no label: one rack)")
     ap.add_argument("--cores-per-machine", type=int, default=1)
     ap.add_argument("--pus-per-core", type=int, default=1)
+    ap.add_argument("--fake-machine-types", type=parse_machine_types, default=(),
+                    metavar="NAME:CORES:PERMILLE,...",
+                    help="fake machines of several types, e.g. A:1:10,B:2:930,C:4:60: "
+                    "of every thousand consecutive machines exactly PERMILLE are of "
+                    "type NAME, with CORES cores and the label ksched.io/platform=NAME "
+                    "(which --cost-model whare reads); the shares sum to 1000; "
+                    "instead of --cores-per-machine")
     ap.add_argument(
         "--cost-model",
         choices=[m.name.lower() for m in CostModelType],
@@ -1382,6 +1456,11 @@ def build_service(
     (chip_smoke.py drives this same construction round by round)."""
     from .solver.select import make_backend
 
+    if args.fake_machine_types and args.cores_per_machine != 1:
+        raise ValueError(
+            "--fake-machine-types gives every type its cores: it is not served "
+            f"together with --cores-per-machine {args.cores_per_machine}"
+        )
     refuse_costs_that_cannot_fit(args)
     cost_model = CostModelType[args.cost_model.upper()]
     return SchedulerService(
@@ -1404,6 +1483,7 @@ def build_service(
         fake_zones=args.fake_zones,
         fake_racks=args.fake_racks,
         preemption=args.preemption,
+        fake_machine_types=args.fake_machine_types,
     )
 
 
@@ -1424,8 +1504,9 @@ def refuse_costs_that_cannot_fit(args) -> None:
     from .solver.jax_solver import MAX_SCALED_PATH_COST
     from .utils import next_pow2
 
-    pus = args.num_machines * args.cores_per_machine * args.pus_per_core
-    resources = 1 + args.num_machines * (1 + args.cores_per_machine) + pus
+    cores = fake_cores(args.num_machines, args.cores_per_machine, args.fake_machine_types)
+    pus = cores * args.pus_per_core
+    resources = 1 + args.num_machines + cores + pus
     # sink, the job's unscheduled aggregator, the cluster aggregator, a rack each
     nodes = resources + pus * args.max_tasks_per_pu + 3 + max(1, args.fake_racks)
     bucket = next_pow2(nodes)

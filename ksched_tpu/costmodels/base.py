@@ -283,6 +283,13 @@ class CostModeler(abc.ABC):
         the counts again."""
         return None
 
+    def take_census_machines_dirty(self) -> int:
+        """Machines whose class census the round's statistics pass
+        gathered again, for a model that keeps one (costmodels/census.py):
+        every machine in a pass that walked every node. 0 (the default):
+        the model keeps no census. Read by the scheduler after `stats`."""
+        return 0
+
     def equiv_class_pref_arc_changes(self, ec: int) -> Optional[List[int]]:
         """The resources whose arc from ``ec`` may have changed (come,
         gone, another cost or capacity) since the arcs of ``ec`` were

@@ -25,7 +25,7 @@ class-dependent interference costs.
 
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
@@ -80,15 +80,34 @@ class ClassCensusKeeper:
         self.task_map = task_map
         self.max_tasks_per_pu = max_tasks_per_pu
         self.machines: Dict[int, ResourceTopologyNodeDescriptor] = {}
+        #: bumped when a machine joins or leaves: what is kept in the
+        #: order of `machines` (machine_arrays; a model's own vectors)
+        #: is made again
+        self.machines_version = 0
+        #: machines the statistics pass prepared, so gathered again,
+        #: since take_machines_dirty was last called
+        self._prepared = 0
+        #: those of them machine_arrays has not read again yet
+        self._dirty: Set[int] = set()
+        self._arrays_version = -1
+        self._rids: List[int] = []
+        self._row: Dict[int, int] = {}
+        self._census = np.zeros((0, NUM_TASK_CLASSES), np.int64)
+        self._idle = np.zeros(0, np.int64)
+        self._slots = np.zeros(0, np.int64)
+        self._free = np.zeros(0, np.int64)
 
     # -- machine registry (cost models' add/remove_machine hooks) ---------
 
     def add_machine(self, rtnd: ResourceTopologyNodeDescriptor) -> None:
         rid = resource_id_from_string(rtnd.resource_desc.uuid)
-        self.machines.setdefault(rid, rtnd)
+        if rid not in self.machines:
+            self.machines[rid] = rtnd
+            self.machines_version += 1
 
     def remove_machine(self, resource_id: int) -> None:
-        self.machines.pop(resource_id, None)
+        if self.machines.pop(resource_id, None) is not None:
+            self.machines_version += 1
 
     # -- stats traversal ---------------------------------------------------
 
@@ -101,6 +120,9 @@ class ClassCensusKeeper:
         rd.num_running_tasks_below = 0
         rd.num_slots_below = 0
         rd.whare_map_stats = WhareMapStats()
+        if accumulator.type == NodeType.MACHINE:
+            self._prepared += 1
+            self._dirty.add(accumulator.resource_id)
 
     def gather(self, accumulator: Node, other: Node) -> Node:
         if not accumulator.is_resource_node:
@@ -140,6 +162,41 @@ class ClassCensusKeeper:
         aw.num_devils += ow.num_devils
         aw.num_turtles += ow.num_turtles
         return accumulator
+
+    # -- the census as arrays ------------------------------------------------
+
+    def take_machines_dirty(self) -> int:
+        """Machines whose census the statistics pass gathered again since
+        the last call (every machine, in a pass that walked every node)."""
+        n, self._prepared = self._prepared, 0
+        return n
+
+    def machine_arrays(self) -> Tuple[List[int], np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+        """(machine ids in the order of `machines`, census [M, 4], idle
+        slots [M], slots [M], free slots [M]) as the descriptors have
+        them: read again for the machines the statistics pass prepared
+        since the last call, for every machine after one joined or left.
+        The arrays are the keeper's: a caller does not write them."""
+        if self._arrays_version != self.machines_version:
+            self._arrays_version = self.machines_version
+            self._rids = list(self.machines)
+            self._row = {rid: i for i, rid in enumerate(self._rids)}
+            m = len(self._rids)
+            self._census = np.zeros((m, NUM_TASK_CLASSES), np.int64)
+            self._idle, self._slots, self._free = (np.zeros(m, np.int64) for _ in range(3))
+            dirty = self._rids
+        else:
+            dirty = [rid for rid in self._dirty if rid in self._row]
+        self._dirty.clear()
+        for rid in dirty:
+            rd = self.machines[rid].resource_desc
+            w = rd.whare_map_stats
+            i = self._row[rid]
+            self._census[i] = (w.num_sheep, w.num_rabbits, w.num_devils, w.num_turtles)
+            self._idle[i] = w.num_idle
+            self._slots[i] = rd.num_slots_below
+            self._free[i] = rd.num_slots_below - rd.num_running_tasks_below
+        return self._rids, self._census, self._idle, self._slots, self._free
 
     # -- convenience -------------------------------------------------------
 

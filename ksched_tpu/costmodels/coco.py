@@ -107,6 +107,9 @@ class CocoCostModel(CostModeler):
         self.leaf_resource_ids = leaf_resource_ids
         self.census = ClassCensusKeeper(resource_map, task_map, max_tasks_per_pu)
 
+    def take_census_machines_dirty(self) -> int:
+        return self.census.take_machines_dirty()
+
     # -- arc costs --------------------------------------------------------
 
     def task_to_unscheduled_agg_cost(self, task_id: int) -> Cost:

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .coco import INTERFERENCE, MAX_COST as COCO_MAX_COST
-from .whare import IDLE_BONUS, MAX_COST as WHARE_MAX_COST, PSI_PRIOR
+from .whare import DEFAULT_PLATFORM, IDLE_BONUS, MAX_COST as WHARE_MAX_COST, psi_prior
 
 
 def coco_device_cost_fn(penalties: Optional[np.ndarray] = None):
@@ -46,30 +46,32 @@ def coco_device_cost_fn(penalties: Optional[np.ndarray] = None):
 def whare_device_cost_fn(
     slots_per_machine: int,
     psi: Optional[np.ndarray] = None,
-    platform_factor: Optional[np.ndarray] = None,
+    platform: Optional[np.ndarray] = None,
 ):
     """class_cost_fn for Whare-Map: census [M, 4] -> cost [4, M] int32.
 
-    slots_per_machine: total slots per machine (homogeneous topology, so
-    idle(m) = slots - census row sum — the device round has no separate
-    idle input).
-    psi: optional [4, 4] slowdown map (default: the learning prior).
-    platform_factor: optional [M] percentage multiplier (100 = neutral)
-    modelling heterogeneous machine platforms (the "heterogeneity in
-    homogeneous WSCs" axis of Whare-Map); applied to the expected
-    slowdown before the idle bonus.
+    slots_per_machine: total slots per machine (the array path's
+    machines are alike in size, so idle(m) = slots - census row sum —
+    the device round has no separate idle input).
+    psi: optional [4, P, 5] slowdown map (default: the learning prior).
+    platform: optional [M] indices into costmodels.whare.PLATFORMS, the
+    platform of each machine (the "heterogeneity in homogeneous WSCs"
+    axis of Whare-Map); default: one platform, the neutral one.
     """
-    psi_d = jnp.asarray(PSI_PRIOR if psi is None else psi, jnp.int32)
-    plat = None if platform_factor is None else jnp.asarray(platform_factor, jnp.int32)
+    psi_np = np.asarray(psi_prior() if psi is None else psi, np.int32)
+    # each machine against the map of its platform: [4, M, 5], or [4, 1, 5]
+    # for every machine alike
+    where = [DEFAULT_PLATFORM] if platform is None else np.asarray(platform, np.int32)
+    psi_m = jnp.asarray(psi_np[:, where, :])
     slots = int(slots_per_machine)
 
     def fn(census):
         c32 = census.astype(jnp.int32)
-        tot = jnp.maximum(1, jnp.sum(c32, axis=1))  # [M]
-        expected = (psi_d @ c32.T) // tot[None, :]  # [4, M]
-        if plat is not None:
-            expected = (expected * plat[None, :]) // 100
-        idle = jnp.maximum(0, slots - jnp.sum(c32, axis=1))
+        running = jnp.sum(c32, axis=1)  # [M]
+        # an empty machine's one co-runner is ALONE: the fifth count
+        beside = jnp.concatenate([c32, (running == 0).astype(jnp.int32)[:, None]], axis=1)
+        expected = jnp.sum(psi_m * beside[None, :, :], axis=2) // jnp.sum(beside, axis=1)[None, :]  # [4, M]
+        idle = jnp.maximum(0, slots - running)
         bonus = (IDLE_BONUS * idle) // slots
         cost = expected - bonus[None, :]
         return jnp.clip(cost, 0, WHARE_MAX_COST).astype(jnp.int32)

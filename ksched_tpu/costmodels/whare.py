@@ -3,29 +3,51 @@
 The reference declares WHARE (costmodel/interface.go:37) and carries its
 input — the per-machine `WhareMapStats` census (whare_map_stats.proto:
 12-18) — without implementing the model. This implements the Whare-MCs
-idea (Mars et al., "Whare-Map: heterogeneity in 'homogeneous' warehouse-
-scale computers", ISCA'13): score each (task class, machine) pair by the
-*observed* slowdown of that class when running on that machine with its
-current co-runner mix, and prefer placements with low expected slowdown.
+idea (Mars and Tang, "Whare-Map: heterogeneity in 'homogeneous'
+warehouse-scale computers", ISCA'13): score each (task class, machine)
+pair by the slowdown of that class on that machine's platform
+(microarchitecture) next to its current co-runners, and prefer
+placements with a low expected slowdown.
 
-The "map" is a 4×4 matrix psi[c, k]: EWMA-learned normalized slowdown
-(scaled ×100) of class c co-located with class k. It starts from a
-neutral prior and is refined online via `record_runtime` as task final
-reports arrive (TaskFinalReport, task_final_report.proto:10-19, carries
-the runtimes the reference would feed this with).
+The map is psi[c, p, k] (x100, 100 = no slowdown): class c on a machine
+of platform p beside a co-runner k, k one of the four census classes or
+ALONE (the last index: the task has the machine to itself). It starts
+from the prior
 
-EC(c) → machine cost = expected slowdown of class c against the
-machine's census, census-weighted:
+    psi[c, p, k] = PSI_PRIOR[c, k] * PLATFORM_PRIOR[c, p] // 100
 
-    cost(c, m) = Σ_k census_k(m) · psi[c, k] / max(1, Σ_k census_k(m))
-                 − IDLE_BONUS · idle(m)/slots(m)
+(co-runner interference, 100 for ALONE, times how much the class gains
+or loses on the platform; platform B is neutral) and is refined online
+by `record_runtime(c, p, k, slowdown)`, an EWMA over the cell of the
+machine the task ran on (TaskFinalReport, task_final_report.proto:10-19,
+carries the runtimes the reference would feed this with; nothing on the
+served path reports runtimes yet, so there the map stays at its prior).
+A machine's platform is the value of its `ksched.io/platform` label
+(`data.PLATFORM_LABEL`), one of PLATFORMS; a machine without the label,
+or with another value, is platform B.
 
-so an idle machine costs its prior, a crowded noisy machine costs its
-measured co-runner slowdown. Capacity = free slots below, as in the
-trivial model (trivial_cost_modeler.go:76-83).
+EC(c) -> machine m, of platform p(m), census n_k(m) over the four
+classes, idle(m) free slots of slots(m); an EMPTY machine's one
+co-runner is ALONE (n_ALONE(m) = 1 where no task runs on m, else 0). In
+integer arithmetic:
 
-Vectorized form for the array fast path: `whare_cost_matrix(census,
-idle, psi)` returns the [4, M] matrix in one shot.
+    cost(c, m) = clip( sum_k n_k(m) * psi[c, p(m), k] // sum_k n_k(m)
+                       - IDLE_BONUS * idle(m) // max(1, slots(m)), 0, MAX_COST )
+
+so an empty machine costs what its PLATFORM does to a lone task of the
+class, psi[c, p, ALONE] less the whole bonus (Whare-Map's premise: the
+machines of a "homogeneous" cluster differ before any co-runner does;
+on platform B, 100 - 20 = 80 for every class), and a machine that runs
+one task or more costs its census-weighted slowdown less the bonus for
+the share of it that is idle. Capacity = free slots below, as in the
+trivial model (trivial_cost_modeler.go:76-83). Leaving a task
+unscheduled costs UNSCHEDULED_COST, more than any machine.
+
+`whare_cost_matrix(census, idle, slots, psi, platform)` is the equation,
+over every machine at once, [4, M]: the program's one copy of it. The
+model's batch hook (`ec_to_resource_batch`, one call an EC a round)
+computes its class's row with it from the census keeper's arrays, inside
+a `platform_costs` span; the scalar hooks ask it for one machine.
 """
 
 from __future__ import annotations
@@ -34,25 +56,49 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
-from ..data import ResourceDescriptor, ResourceTopologyNodeDescriptor
+from ..data import PLATFORM_LABEL, ResourceDescriptor, ResourceTopologyNodeDescriptor
 from ..graph.flowgraph import Node
+from ..obs.spans import span
 from ..utils import ResourceMap, TaskMap
 from .base import Cost, CostModeler
 from .census import CLASS_ECS, ClassCensusKeeper, ec_class
 
 # Prior psi[c, k] ×100: neutral 100 = no slowdown; devils degrade
-# co-runners, rabbits are the most sensitive.
+# co-runners, rabbits are the most sensitive; alone, nothing slows a task.
 PSI_PRIOR = np.array(
     [
-        # co-runner: S    R    D    T
-        [105, 103, 140, 100],  # sheep
-        [115, 110, 200, 101],  # rabbit
-        [120, 130, 150, 105],  # devil
-        [100, 100, 102, 100],  # turtle
+        # co-runner: S    R    D    T  alone
+        [105, 103, 140, 100, 100],  # sheep
+        [115, 110, 200, 101, 100],  # rabbit
+        [120, 130, 150, 105, 100],  # devil
+        [100, 100, 102, 100, 100],  # turtle
     ],
     # int32: psi values stay O(10^4) (slowdown x100), census counts
     # O(slots), so products sit far below 2^31 — and the matrix feeds
     # device-bound int32 cost arrays anyway
+    dtype=np.int32,
+)
+
+#: the co-runner index of a task that has its machine to itself
+ALONE = 4
+
+#: the platforms (microarchitecture generations) the map knows, oldest
+#: first: the values of a machine's PLATFORM_LABEL
+PLATFORMS = ("A", "B", "C")
+#: the platform of a machine that carries no such label
+DEFAULT_PLATFORM = PLATFORMS.index("B")
+
+# Prior PLATFORM_PRIOR[c, p] x100: what a class loses on the oldest
+# platform and gains on the newest, B neutral; most for the class most
+# sensitive to its machine (rabbit), least for the turtle.
+PLATFORM_PRIOR = np.array(
+    [
+        # platform: A    B    C
+        [110, 100, 95],  # sheep
+        [130, 100, 85],  # rabbit
+        [115, 100, 90],  # devil
+        [102, 100, 99],  # turtle
+    ],
     dtype=np.int32,
 )
 
@@ -62,19 +108,38 @@ UNSCHEDULED_COST = MAX_COST + 500
 EWMA_WEIGHT = 0.25  # weight of a new observation
 
 
+def psi_prior() -> np.ndarray:
+    """The map before any runtime was recorded: int32 [4, P, 5]."""
+    return (PSI_PRIOR[:, None, :] * PLATFORM_PRIOR[:, :, None] // 100).astype(np.int32)
+
+
+def platform_index(labels) -> int:
+    """The platform a machine's labels name, as an index into PLATFORMS."""
+    name = labels.get(PLATFORM_LABEL)
+    return PLATFORMS.index(name) if name in PLATFORMS else DEFAULT_PLATFORM
+
+
 def whare_cost_matrix(
-    census: np.ndarray, idle: np.ndarray, slots: np.ndarray, psi: Optional[np.ndarray] = None
+    census: np.ndarray, idle: np.ndarray, slots: np.ndarray,
+    psi: Optional[np.ndarray] = None, platform: Optional[np.ndarray] = None,
 ) -> np.ndarray:
     """Vectorized Whare-MCs costs.
 
     census: [M, 4] running-class counts; idle: [M] idle slots;
-    slots: [M] total slots; psi: [4, 4] slowdown map (default prior).
-    Returns [4, M] int32.
+    slots: [M] total slots; psi: [C, P, 5] slowdown map (default: the
+    prior, C = 4); platform: [M] indices into PLATFORMS (default: every
+    machine DEFAULT_PLATFORM). Returns [C, M] int32.
     """
     if psi is None:
-        psi = PSI_PRIOR
-    tot = np.maximum(1, census.sum(axis=1))  # [M]
-    expected = (psi @ census.T.astype(np.int64)) // tot  # [4, M]
+        psi = psi_prior()
+    census = census.astype(np.int64)
+    if platform is None:
+        platform = np.full(len(census), DEFAULT_PLATFORM)
+    # an empty machine's one co-runner is ALONE: the fifth count
+    beside = np.concatenate([census, (census.sum(axis=1) == 0)[:, None]], axis=1)
+    # psi[:, platform, :] is [C, M, 5]: each machine against the map of its platform
+    weighted = (psi[:, platform, :].astype(np.int64) * beside[None, :, :]).sum(axis=2)
+    expected = weighted // beside.sum(axis=1)
     bonus = (IDLE_BONUS * idle.astype(np.int64)) // np.maximum(1, slots.astype(np.int64))
     cost = expected - bonus[None, :]
     return np.clip(cost, 0, MAX_COST).astype(np.int32)
@@ -103,21 +168,35 @@ class WhareMapCostModel(CostModeler):
         self.census = ClassCensusKeeper(resource_map, task_map, max_tasks_per_pu)
         # float32 is ample for an EWMA over x100 slowdowns (24-bit
         # mantissa vs values O(10^4)); 64-bit buys nothing here
-        self.psi = PSI_PRIOR.astype(np.float32).copy()
+        self.psi = psi_prior().astype(np.float32)
+        self._psi_int = psi_prior()
+        #: the platform of every machine, in the order of the census
+        #: keeper's machines, made again when one joins or leaves
+        self._platform = np.zeros(0, np.int64)
+        self._platform_version = -1
 
     # -- the map (online learning) ----------------------------------------
 
-    def record_runtime(self, task_class: int, corunner_class: int, slowdown_x100: float) -> None:
-        """Fold an observed slowdown sample (×100; 100 = baseline) into
-        the map — fed from TaskFinalReport runtimes in the reference's
-        intended pipeline."""
-        old = self.psi[task_class, corunner_class]
-        self.psi[task_class, corunner_class] = (
+    def record_runtime(
+        self, task_class: int, platform: int, corunner_class: int, slowdown_x100: float
+    ) -> None:
+        """Fold an observed slowdown sample (×100; 100 = baseline) of a
+        task of ``task_class`` that ran on a machine of ``platform``
+        (an index into PLATFORMS) beside ``corunner_class`` (a census
+        class, or ALONE) into that cell of the map — fed from TaskFinalReport runtimes in the
+        reference's intended pipeline."""
+        old = self.psi[task_class, platform, corunner_class]
+        self.psi[task_class, platform, corunner_class] = (
             (1.0 - EWMA_WEIGHT) * old + EWMA_WEIGHT * slowdown_x100
         )
+        self._psi_int = np.rint(self.psi).astype(np.int32)
 
     def psi_int(self) -> np.ndarray:
-        return np.rint(self.psi).astype(np.int32)
+        """The map as the costs read it: int32 [4, P, 5]."""
+        return self._psi_int
+
+    def take_census_machines_dirty(self) -> int:
+        return self.census.take_machines_dirty()
 
     # -- arc costs --------------------------------------------------------
 
@@ -157,17 +236,43 @@ class WhareMapCostModel(CostModeler):
         return 0, 0
 
     def _machine_cost(self, task_class: int, resource_id: int) -> int:
+        """One cell of `whare_cost_matrix`: the equation has one copy."""
         rs = self.resource_map.find(resource_id)
         if rs is None:
             raise KeyError(f"no resource status for {resource_id}")
         rd = rs.descriptor
-        census = self.census.machine_census(resource_id)
-        tot = max(1, int(census.sum()))
-        expected = int(self.psi_int()[task_class] @ census) // tot
-        slots = max(1, rd.num_slots_below)
-        idle = rd.whare_map_stats.num_idle
-        cost = expected - (IDLE_BONUS * idle) // slots
-        return int(np.clip(cost, 0, MAX_COST))
+        return int(whare_cost_matrix(
+            self.census.machine_census(resource_id)[None, :],
+            np.array([rd.whare_map_stats.num_idle]), np.array([rd.num_slots_below]),
+            self._psi_int[task_class : task_class + 1],
+            np.array([platform_index(rd.labels)]),
+        )[0, 0])
+
+    def ec_to_resource_batch(self, ec: int, resource_ids) -> Tuple[List[Cost], List[int]]:
+        """An EC's arcs to every machine in one call: the class's row of
+        `whare_cost_matrix` over the census keeper's arrays. Resources
+        other than the keeper's machines in its order are asked one by
+        one (the base class's loop): the span then says `scalar=True`,
+        so that a drop to 12,500 calls an EC does not pass for the
+        batch."""
+        c = ec_class(ec)
+        if c is None:
+            return super().ec_to_resource_batch(ec, resource_ids)
+        with span("platform_costs", machines=len(resource_ids)) as sp:
+            rids, census, idle, slots, free = self.census.machine_arrays()
+            if resource_ids != rids:
+                sp.set("scalar", True)
+                return super().ec_to_resource_batch(ec, resource_ids)
+            if self._platform_version != self.census.machines_version:
+                self._platform_version = self.census.machines_version
+                self._platform = np.array(
+                    [platform_index(self.census.machines[r].resource_desc.labels) for r in rids],
+                    np.int64,
+                )
+            row = whare_cost_matrix(
+                census, idle, slots, self._psi_int[c : c + 1], self._platform
+            )[0]
+            return row.tolist(), free.tolist()
 
     # -- preference enumeration -------------------------------------------
 

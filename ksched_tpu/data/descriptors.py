@@ -28,6 +28,11 @@ ZONE_LABEL = "topology.kubernetes.io/zone"
 #: Kubernetes has no well-known key for it, clusters that label racks
 #: use one of this shape
 RACK_LABEL = "topology.kubernetes.io/rack"
+#: the node label that names a node's platform (its microarchitecture
+#: generation), for a policy that prices a task by the machine it runs
+#: on (costmodels/whare.py); Kubernetes has no well-known key for it
+#: (node.kubernetes.io/instance-type names a cloud's machine shape)
+PLATFORM_LABEL = "ksched.io/platform"
 
 
 class TaskState(enum.IntEnum):

@@ -171,6 +171,10 @@ class GraphManager:
         #: another capacity or cost
         self.ec_arcs_changed = 0
         self.ec_chain_arcs_changed = 0
+        #: the last add_or_update_job_nodes: arcs from an EC node to a
+        #: resource whose cost and capacity it wrote, changed or not
+        #: (what a sweep of every preferred resource works through)
+        self.ec_arcs_repriced = 0
         #: the last add_or_update_job_nodes: resource nodes that took a
         #: turn (_queue_res_turn), and arcs out of them that it added or
         #: whose price really changed (a record in the journal, new or
@@ -299,6 +303,7 @@ class GraphManager:
         visited = 0
         self.ec_arcs_changed = 0
         self.ec_chain_arcs_changed = 0
+        self.ec_arcs_repriced = 0
         runs = _TurnRuns(self.cm.stats)
         task_run, res_run = runs.TASK, runs.RES
         turn = 0  # turns taken so far, of any kind
@@ -1061,6 +1066,7 @@ class GraphManager:
                 self._patch_equiv_to_res_arcs(ec_node, changed)
 
     def _set_equiv_to_res_arc(self, ec_node: Node, res_node: Node, cost: int, cap_upper: int) -> None:
+        self.ec_arcs_repriced += 1
         arc = self.cm.graph.get_arc(ec_node, res_node)
         if arc is None:
             self.cm.add_arc(

@@ -131,6 +131,16 @@ class RoundRecord:
     ec_arcs: int = 0
     ec_arcs_changed: int = 0
     unscheduled_by_rule: int = 0
+    #: EC -> resource arcs whose cost and capacity `graph_update` wrote,
+    #: changed or not, and machines whose class census `stats` gathered
+    #: again (0 under a model that keeps none); where the dense collapse
+    #: answered the round (zeros elsewhere): the tasks its rows pass
+    #: grouped, the rows of the dense problem and its padded columns
+    ec_arcs_repriced: int = 0
+    census_machines_dirty: int = 0
+    audit_tasks_grouped: int = 0
+    collapse_rows: int = 0
+    collapse_cols: int = 0
     #: EC -> EC arcs `graph_update` added, removed or gave another
     #: capacity or cost, and 1 if the cost model left its allotment for
     #: the per-pod predicate (a zone short of room)
@@ -336,6 +346,11 @@ class RoundTracer:
             ec_arcs=t.ec_arcs,
             ec_arcs_changed=t.ec_arcs_changed,
             unscheduled_by_rule=t.unscheduled_by_rule,
+            ec_arcs_repriced=t.ec_arcs_repriced,
+            census_machines_dirty=t.census_machines_dirty,
+            audit_tasks_grouped=t.audit_tasks_grouped,
+            collapse_rows=t.collapse_rows,
+            collapse_cols=t.collapse_cols,
             ec_chain_arcs_changed=t.ec_chain_arcs_changed,
             spread_fallback=t.spread_fallback,
             tasks_unpinned=t.tasks_unpinned,

@@ -157,6 +157,20 @@ class RoundTiming:
     ec_nodes: int = 0
     ec_arcs: int = 0
     ec_arcs_changed: int = 0
+    #: EC -> resource arcs whose cost and capacity `graph_update` wrote,
+    #: changed or not (GraphManager.ec_arcs_repriced: what a model whose
+    #: ECs sweep every machine every round pays for), and machines whose
+    #: class census `stats` gathered again
+    #: (CostModeler.take_census_machines_dirty; 0 for a model without one)
+    ec_arcs_repriced: int = 0
+    census_machines_dirty: int = 0
+    #: the dense problem of a round the collapse answered (zeros on any
+    #: other rung; PlacementSolver.collapse_shape): the tasks its rows
+    #: pass grouped, the rows they made, and the transport's padded
+    #: columns
+    audit_tasks_grouped: int = 0
+    collapse_rows: int = 0
+    collapse_cols: int = 0
     #: EC -> EC arcs `graph_update` added, removed or gave another
     #: capacity or cost this round (GraphManager.ec_chain_arcs_changed);
     #: 1 if the cost model left its allotment for the per-pod predicate
@@ -553,6 +567,7 @@ class FlowScheduler:
                 timing.stats_pus_dirty = self.gm.stats_pus_dirty
                 timing.stats_nodes_visited = self.gm.stats_nodes_visited
                 timing.stats_full_walk = self.gm.stats_full_walk
+                timing.census_machines_dirty = self.cost_model.take_census_machines_dirty()
                 sp.set("stats_pus_dirty", timing.stats_pus_dirty)
                 sp.set("stats_nodes_visited", timing.stats_nodes_visited)
                 sp.set("stats_full_walk", timing.stats_full_walk)
@@ -572,6 +587,7 @@ class FlowScheduler:
                 timing.ec_nodes = len(ec_nodes)
                 timing.ec_arcs = sum(len(node.outgoing) for node in ec_nodes)
                 timing.ec_arcs_changed = self.gm.ec_arcs_changed
+                timing.ec_arcs_repriced = self.gm.ec_arcs_repriced
                 timing.ec_chain_arcs_changed = self.gm.ec_chain_arcs_changed
                 timing.spread_fallback = self.cost_model.spread_fallback
                 sp.set("ec_nodes", timing.ec_nodes)
@@ -656,6 +672,9 @@ class FlowScheduler:
             timing.objective = int(self.solver.last_result.objective)
             timing.decode_tasks = self.solver.decode_tasks
             timing.decode_pinned_skipped = self.solver.decode_pinned_skipped
+            (
+                timing.audit_tasks_grouped, timing.collapse_rows, timing.collapse_cols,
+            ) = self.solver.collapse_shape
             with span("deltas") as sp:
                 if self.gm.unpinned_running_tasks:
                     # Some running task is not pinned (preemption): the

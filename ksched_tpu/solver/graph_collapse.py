@@ -137,6 +137,40 @@ def _csr_arcs(dec_src, dec_arc, dec_child, v: int):
     return zip(dec_arc[lo:hi].tolist(), dec_child[lo:hi].tolist())
 
 
+def _group_tasks_by_arcs(T, u_eff, mac_t, mac_col, mac_cost, ect_t, ect_ec, ect_cost):
+    """Classes of tasks whose effective cost rows cannot differ: those
+    with one escape cost and one multiset of placement arcs (target,
+    cost), a target being a machine column or an EC row. Returns
+    (class of every task [T], a representative task of every class),
+    the classes numbered as they are first met. Exact: a task's key is
+    the tuple of its arcs, sorted. Over plain lists: a microsecond a
+    task, which a trickle round's handful and a fill's 142,000 both
+    afford, and no array the size of the batch times anything."""
+    arcs = [() for _ in range(T)]
+    for t, col, c in zip(mac_t.tolist(), mac_col.tolist(), mac_cost.tolist()):
+        arcs[t] += ((col, c),)  # a machine column is a target >= 0
+    for t, ec, c in zip(ect_t.tolist(), ect_ec.tolist(), ect_cost.tolist()):
+        arcs[t] += ((-1 - ec, c),)  # an EC row one < 0
+    seen: Dict[tuple, int] = {}
+    reps: List[int] = []
+    cls = np.empty(T, np.int64)
+    for t, (u, a) in enumerate(zip(u_eff.tolist(), arcs)):
+        key = (u, a if len(a) < 2 else tuple(sorted(a)))
+        k = seen.setdefault(key, len(reps))
+        if k == len(reps):
+            reps.append(t)
+        cls[t] = k
+    return cls, np.array(reps, np.int64)
+
+
+#: the dense problem's rows are padded to a multiple of this with rows of
+#: no supply: a transport program is compiled for every row count, and
+#: a service whose rounds hold two, three or four classes of tasks then
+#: runs one program where it ran three (the first round with all four
+#: classes compiled for seconds in the middle of served traffic)
+_ROW_BUCKET = 4
+
+
 def try_collapse(problem) -> Tuple[Optional[GraphCollapse], str]:
     """Audit a FlowProblem against the dense-collapsibility predicate.
 
@@ -589,48 +623,66 @@ def try_collapse(problem) -> Tuple[Optional[GraphCollapse], str]:
                 )
 
     with span("audit_rows", tasks=T) as sp:
-        # ---- effective cost rows: min over direct arcs and EC routes ----
-        crow = np.full((T, M), _BIG, np.int64)
-
         mac_arcs = ta[is_mac]
         mac_t = tpos[src[mac_arcs]]
         mac_col = owner[dst[mac_arcs]]
         mac_cost = cost[mac_arcs].astype(np.int64)
-        if len(mac_arcs):
-            np.minimum.at(crow, (mac_t, mac_col), mac_cost)
-
         ect_t = tpos[src[ect_arcs]]
         ect_ec = ec_pos[dst[ect_arcs]]
         ect_cost = cost[ect_arcs].astype(np.int64)
-        if len(ect_arcs):
-            o = np.argsort(ect_t, kind="stable")
-            owner_t = ect_t[o]
-            child = ec_cost_row[ect_ec[o]]  # [De, M]
+
+        # ---- tasks grouped by what makes their rows differ, BEFORE any
+        # row exists: the escape cost and the (target, cost) of every
+        # placement arc. A fill of T tasks over M machines then costs
+        # [classes, M], not [T, M] (142,000 x 12,500 x 8 B = 14 GB). ----
+        cls_of, reps = _group_tasks_by_arcs(
+            T, u_eff, mac_t, mac_col, mac_cost, ect_t, ect_ec, ect_cost
+        )
+        S = len(reps)
+        rep_row = np.full(T, -1, np.int64)  # representative task -> its class
+        rep_row[reps] = np.arange(S)
+
+        # ---- effective cost rows of the representatives: min over
+        # direct arcs and EC routes ----
+        crow = np.full((S, M), _BIG, np.int64)
+        sel = np.nonzero(rep_row[mac_t] >= 0)[0]
+        if len(sel):
+            np.minimum.at(crow, (rep_row[mac_t[sel]], mac_col[sel]), mac_cost[sel])
+        sel = np.nonzero(rep_row[ect_t] >= 0)[0]
+        if len(sel):
+            o = sel[np.argsort(rep_row[ect_t[sel]], kind="stable")]
+            owner_r = rep_row[ect_t[o]]
+            child = ec_cost_row[ect_ec[o]]  # [arcs of the representatives, M]
             cand = np.where(child >= _BIG, _BIG, ect_cost[o, None] + child)
-            starts = np.nonzero(np.r_[True, np.diff(owner_t) > 0])[0]
+            starts = np.nonzero(np.r_[True, np.diff(owner_r) > 0])[0]
             red = np.minimum.reduceat(cand, starts, axis=0)
-            rows = owner_t[starts]
+            rows = owner_r[starts]
             crow[rows] = np.minimum(crow[rows], red)
 
         crow = np.where(crow >= _BIG, _BIG, crow + col_path[None, :])
 
-        # ---- signature grouping: byte-view unique over (row, escape) ----
+        # ---- signature grouping: byte-view unique over (row, escape).
+        # Two classes whose arcs differ may still make one row (a dearer
+        # arc beside a cheaper one to the same machine): they merge here,
+        # and the rows come out in the order of their bytes, as a pass
+        # over every task's own row would give them. ----
         if T:
             key = np.ascontiguousarray(
-                np.concatenate([crow, u_eff[:, None]], axis=1)
+                np.concatenate([crow, u_eff[reps, None]], axis=1)
             )
             kv = key.view(
                 np.dtype((np.void, key.shape[1] * key.itemsize))
-            ).reshape(T)
-            _, first_idx, inv = np.unique(
+            ).reshape(S)
+            _, first_idx, inv_s = np.unique(
                 kv, return_index=True, return_inverse=True
             )
+            inv = inv_s[cls_of]
             supply = np.bincount(inv).astype(np.int32)
             order = np.argsort(inv, kind="stable")
             starts = np.nonzero(np.r_[True, np.diff(inv[order]) > 0])[0]
             rows_tasks = np.split(order, starts[1:])
             row_cost = crow[first_idx]
-            row_u = u_eff[first_idx]
+            row_u = u_eff[reps[first_idx]]
         else:
             supply = np.zeros(0, np.int32)
             rows_tasks = []
@@ -709,6 +761,10 @@ class AutoSolver(FlowSolver):
         self.last_path = ""
         self.last_refusal = ""
         self.last_supersteps = 0
+        #: the dense problem of the last solve, where the collapse took
+        #: it: (tasks the rows pass grouped, rows, padded columns of the
+        #: transport tile); zeros where it refused
+        self.last_collapse_shape = (0, 0, 0)
         #: solver-interior telemetry of the rung that produced the last
         #: solve (obs/soltel.py); solve_traced publishes it
         self.last_telemetry = None
@@ -761,6 +817,7 @@ class AutoSolver(FlowSolver):
 
     def solve(self, problem) -> FlowResult:
         self.last_solve_span = None
+        self.last_collapse_shape = (0, 0, 0)
         with span("collapse_audit") as sp:
             collapse, reason = try_collapse(problem)
             sp.set("collapsed", collapse is not None)
@@ -789,8 +846,14 @@ class AutoSolver(FlowSolver):
         return self._solve_dense(problem, collapse)
 
     def _solve_dense(self, problem, gc: GraphCollapse) -> FlowResult:
-        from .layered import LayeredProblem, LayeredTransportSolver
+        from .layered import LayeredProblem, LayeredTransportSolver, pad_geometry
 
+        # rows after grouping; the tile's columns as solved (its rows are
+        # padded to the row bucket)
+        self.last_collapse_shape = (
+            len(gc.task_ids), len(gc.supply),
+            pad_geometry(len(gc.col_cap), len(gc.supply))[0] if len(gc.supply) else 0,
+        )
         if not len(gc.supply):
             # nothing unplaced: only the folded pins' continuation flow
             flow = np.zeros(len(problem.src), np.int64)
@@ -811,26 +874,35 @@ class AutoSolver(FlowSolver):
         with span(
             "transport", rows=len(gc.supply), cols=len(gc.col_cap)
         ) as sp:
+            # rows of no supply up to the row bucket, each a copy of the
+            # first row (rows that are all alike stay all alike: the
+            # solver's closed forms still see them); one row stays one
+            G = len(gc.supply)
+            pad = 0 if G == 1 else -G % _ROW_BUCKET
             res = solver.solve_layered(LayeredProblem(
-                supply=gc.supply,
+                supply=np.concatenate([gc.supply, np.zeros(pad, np.int32)]),
                 col_cap=gc.col_cap,
-                cost_cm=gc.cost_cm.astype(np.int32),
+                cost_cm=np.concatenate(
+                    [gc.cost_cm, np.repeat(gc.cost_cm[:1], pad, axis=0)]
+                ).astype(np.int32),
                 unsched_cost=0,
                 ec_cost=0,
-                row_unsched_cost=gc.row_unsched,
+                row_unsched_cost=np.concatenate(
+                    [gc.row_unsched, np.repeat(gc.row_unsched[:1], pad)]
+                ),
             ))
+            y = np.asarray(res.y, np.int64)[:G]
             sp.set("supersteps", int(res.supersteps))
         self.last_solve_span = sp
         self.last_supersteps = res.supersteps
         self.last_telemetry = solver.last_telemetry
         with span("flow_reconstruct", tasks=len(gc.task_ids)):
-            return self._reconstruct_flow(problem, gc, res)
+            return self._reconstruct_flow(problem, gc, y, res.supersteps)
 
     @staticmethod
-    def _reconstruct_flow(problem, gc: GraphCollapse, res) -> FlowResult:
+    def _reconstruct_flow(problem, gc: GraphCollapse, y, supersteps) -> FlowResult:
         """The per-arc flow of the original graph from the transport's
-        granted cells `res.y`, task by task."""
-        y = np.asarray(res.y, np.int64)
+        granted cells `y` ([rows, machines]), task by task."""
 
         # ---- exact per-arc flow reconstruction ----
         flow = np.zeros(len(problem.src), np.int64)
@@ -943,5 +1015,5 @@ class AutoSolver(FlowSolver):
             (flow * np.asarray(problem.cost, np.int64)).sum()
         ) + lower_bound_cost(problem)
         return FlowResult(
-            flow=flow, objective=objective, iterations=int(res.supersteps)
+            flow=flow, objective=objective, iterations=int(supersteps)
         )

@@ -69,6 +69,15 @@ class PlacementSolver:
 
         self.integrity_counts = _Counter()
 
+    @property
+    def collapse_shape(self):
+        """(tasks grouped, rows, padded columns) of the dense problem the
+        configured rung's last solve made of the graph; zeros where that
+        rung has no dense collapse or the collapse refused the graph
+        (AutoSolver.last_collapse_shape)."""
+        rung = getattr(self.backend, "primary", self.backend)
+        return getattr(rung, "last_collapse_shape", (0, 0, 0))
+
     def solve_async(self):
         """Phase 1 of a pipelined round: export the journal, snapshot
         the problem, and DISPATCH the backend solve, returning before

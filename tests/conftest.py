@@ -75,6 +75,20 @@ def _seeded_rng():
 #: appended `runnable_tasks_scanned` after them, as ISSUE 44 asks, with that
 #: cell on its list (the scan walked 139,000 descriptors there, the most of
 #: any cell): look the five up by name, state the set as "at least".
+#:
+#: PR 46 (`gtrace-12500-wharemap`, the second deployment on the dense rung)
+#: appended a configuration, an eleventh cell, and its name to twenty-four
+#: lists, as ISSUE 46 asks. `test_benchmark_quincy.py` pins its configuration
+#: to the last place of `configs` (two tests); `test_benchmark_layer_spans.py`
+#: and `test_benchmark_pass_metrics.py` pin the three dense-rung lists and the
+#: seven `audit_*_ms` lists to the two `coco-50kx1k` cells;
+#: `test_benchmark_runnable_scan.py` pins its list to nine cells;
+#: `test_benchmark_seams.py` draws its cases once for each cell of
+#: `BENCHMARK.json` and states that no configuration names `pods` (this one
+#: says `class_only` by name, as ISSUE 46 fixes it; `PLAN_DIGESTS` has no
+#: entry for it). `test_benchmark_wharemap.py` holds what stays true of each:
+#: the lists as "what they had, then this cell", the plan's digests of the new
+#: cell, `class_only` by name equal to `class_only` by default.
 _STALE = {
     "test_benchmark_seams.py::test_class_only_is_the_old_expression_and_the_same_seed_"
     "draws_the_same_plan[k8s-5000-preemption.rollout-": "PR 38 brings pods/by_role.py",
@@ -94,6 +108,30 @@ _STALE = {
     "nothing": "PR 42 appended a tenth cell, and its name to eleven of the thirteen lists",
     "test_benchmark_quincy.py::test_the_cell_takes_one_chip_and_the_mix_it_shares_is_"
     "unchanged": "PR 44 appended runnable_tasks_scanned after its five entries, its cell listed",
+    "test_benchmark_quincy.py::test_the_configuration_is_the_sources_shapes":
+        "PR 46 appended a configuration after it",
+    "test_benchmark_quincy.py::test_what_stays_true_of_the_cell_before_it":
+        "PR 46 appended a configuration after the two it pins to the end",
+    "test_benchmark_layer_spans.py::test_a_new_metric_loads_in_its_cells_and_is_absent_from_"
+    "the_others[collapse_audit_ms-gtrace-12500-wharemap.trickle]":
+        "PR 46's cell is answered by the dense rung",
+    "test_benchmark_layer_spans.py::test_a_new_metric_loads_in_its_cells_and_is_absent_from_"
+    "the_others[transport_ms-gtrace-12500-wharemap.trickle]":
+        "PR 46's cell is answered by the dense rung",
+    "test_benchmark_layer_spans.py::test_a_new_metric_loads_in_its_cells_and_is_absent_from_"
+    "the_others[flow_reconstruct_ms-gtrace-12500-wharemap.trickle]":
+        "PR 46's cell is answered by the dense rung",
+    "test_benchmark_layer_spans.py::test_each_of_the_seven_entries_is_there_by_name_and_"
+    "equals_its_file": "PR 46 appended its cell to the three dense-rung lists",
+    "test_benchmark_pass_metrics.py::test_a_pass_metric_is_its_file_loads_in_its_cells_and_"
+    "reads_what_it_names[audit_": "PR 46 appended its cell to the seven audit_*_ms lists",
+    "test_benchmark_seams.py::test_class_only_is_the_old_expression_and_the_same_seed_"
+    "draws_the_same_plan[gtrace-12500-wharemap.trickle-":
+        "PR 46's configuration names pods class_only, and PLAN_DIGESTS has no entry for its cell",
+    "test_benchmark_runnable_scan.py::test_the_entry_equals_its_file_and_lists_the_nine_"
+    "cells": "PR 46 appended its cell to the list",
+    "test_benchmark_runnable_scan.py::test_a_cell_loads_it_by_name_if_it_is_listed_and_not_"
+    "otherwise[gtrace-12500-wharemap.trickle]": "PR 46's cell is on the list",
 }
 
 

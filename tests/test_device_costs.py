@@ -41,14 +41,20 @@ def test_whare_device_matches_numpy_homogeneous():
         np.testing.assert_array_equal(got, want)
 
 
-def test_whare_platform_factor_scales_expected_slowdown():
-    """Heterogeneity: a slower platform (factor > 100) must never be
-    cheaper than a faster one with the same census."""
-    census = np.full((2, 4), 2, np.int64)
-    fast_slow = np.asarray([90, 130], np.int64)
+def test_whare_platform_scales_expected_slowdown():
+    """Heterogeneity: the oldest platform must never be cheaper than the
+    newest with the same census, and the device form is the numpy form."""
+    census = np.full((3, 4), 2, np.int64)
+    platform = np.asarray([2, 0, 1], np.int64)  # C, A, B
     cost = np.asarray(
-        whare_device_cost_fn(slots_per_machine=16, platform_factor=fast_slow)(
-            jnp.asarray(census)
-        )
+        whare_device_cost_fn(slots_per_machine=16, platform=platform)(jnp.asarray(census))
     )
-    assert (cost[:, 1] >= cost[:, 0]).all()
+    assert (cost[:, 1] >= cost[:, 2]).all() and (cost[:, 2] >= cost[:, 0]).all()
+    assert (cost[:, 1] > cost[:, 0]).any()
+    idle = np.full(3, 8, np.int64)
+    want = whare_cost_matrix(census, idle, np.full(3, 16, np.int64), platform=platform)
+    np.testing.assert_array_equal(cost, want)
+    # a machine of the neutral platform costs what it costs with no platform given
+    np.testing.assert_array_equal(
+        want[:, 2], whare_cost_matrix(census, idle, np.full(3, 16, np.int64))[:, 2]
+    )

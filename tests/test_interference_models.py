@@ -64,11 +64,15 @@ def test_whare_online_map_update():
     from ksched_tpu.utils import ResourceMap, TaskMap
 
     m = WhareMapCostModel(ResourceMap(), TaskMap(), set(), 4)
-    before = m.psi_int()[1, 2]
+    prior = m.psi_int().copy()
     for _ in range(10):
-        m.record_runtime(1, 2, 300.0)
-    after = m.psi_int()[1, 2]
-    assert after > before  # learned that rabbits suffer next to devils
+        m.record_runtime(1, 0, 2, 400.0)
+    after = m.psi_int()
+    # learned that rabbits suffer next to devils on platform A: that cell of
+    # the [class, platform, co-runner] map, and no other
+    assert after[1, 0, 2] > prior[1, 0, 2]
+    changed = after != prior
+    assert changed.sum() == 1 and changed[1, 0, 2]
 
 
 def _bulk(class_cost_fn, C=4, M=4, P=2, S=2, J=2, cap=256):

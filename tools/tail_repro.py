@@ -45,13 +45,13 @@ def build_config(name: str):
     rng = np.random.default_rng(7)
     if name == "whare":
         tasks, machines = 20_000, 1_000
-        platform_factor = rng.integers(80, 140, machines).astype(np.int64)
+        platform = rng.integers(0, 3, machines).astype(np.int64)
         dev = DeviceBulkCluster(
             num_machines=machines, pus_per_machine=4, slots_per_pu=8,
             num_jobs=20, num_task_classes=4,
             task_capacity=next_pow2(tasks + 4096),
             class_cost_fn=whare_device_cost_fn(
-                slots_per_machine=32, platform_factor=platform_factor
+                slots_per_machine=32, platform=platform
             ),
             unsched_cost=_whare_unsched(), ec_cost=0,
             supersteps=1 << 17, decode_width=2048,
