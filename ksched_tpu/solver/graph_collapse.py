@@ -46,9 +46,11 @@ Performance (round 5): the audit is vectorized end to end —
    grouping replaces the per-task loop that iterated every EC route
    dict (measured 46 ms/round of the 57 ms audit at 10k x 1k).
 Routes are realized lazily at decode, only for granted cells. The
-remaining scalar loops (pin routing, EC chain build) run over plain
-Python lists, not numpy scalars. See docs/NOTES.md round-5 section
-for the before/after anatomy.
+remaining scalar loop (EC chain build) runs over plain Python lists,
+not numpy scalars; the folded pins are routed with whole-array
+operations wherever they sit on leaves (PR 47), and a pin on an
+interior node falls to a scalar walk. See docs/NOTES.md round-5
+section for the before/after anatomy.
 """
 
 from __future__ import annotations
@@ -99,7 +101,8 @@ class GraphCollapse:
     cost_cm: np.ndarray  # int64[G, M] full placement cost per unit
     row_unsched: np.ndarray  # int64[G] full escape cost per unit
     machine_node: np.ndarray  # int64[M] machine node id per column
-    pre_flows: List[Tuple[int, int]]  # folded pinned units (arc, units)
+    # folded pinned units routed to the sink: (arc ids, units), int64 each
+    pre_flows: Tuple[np.ndarray, np.ndarray]
     # interior arcs sorted by (src, arc id): child == -1 -> sink
     dec_src: np.ndarray  # int64[A]
     dec_arc: np.ndarray  # int64[A]
@@ -130,7 +133,8 @@ def _refuse(reason: str):
 def _csr_arcs(dec_src, dec_arc, dec_child, v: int):
     """(arc, child) pairs leaving node v in ascending-arc order, from
     the (src, arc)-sorted interior CSR; child == -1 means the sink.
-    Shared by the audit's pin router and the decode's tree pushes so
+    Shared by the scalar walk the audit's pin router falls back to
+    (a pin on a node that is no leaf) and the decode's tree pushes, so
     the two walkers cannot drift."""
     lo = np.searchsorted(dec_src, v)
     hi = np.searchsorted(dec_src, v, side="right")
@@ -204,9 +208,10 @@ def try_collapse(problem) -> Tuple[Optional[GraphCollapse], str]:
         task_lut = np.zeros(_n_types, bool)
         task_lut[[t + 1 for t in _TASK_TYPES if t + 1 < _n_types]] = True
 
-        # arc-wise scalar access below is confined to SMALL loops (pin
-        # routing, EC chain build, agg arcs) — numpy scalar extraction is
-        # fine there; the big sections are whole-array ops
+        # arc-wise scalar access below is confined to SMALL loops (EC
+        # chain build, agg arcs, the pins that sit on no leaf) — numpy
+        # scalar extraction is fine there; the big sections are
+        # whole-array ops
 
         # no dict adjacency anywhere: EC arcs are classified with whole-
         # array ops, interior nodes get a sorted-CSR view below
@@ -215,9 +220,10 @@ def try_collapse(problem) -> Tuple[Optional[GraphCollapse], str]:
         out_arcs = live[nt_src_live == _EC_T]
 
         # interior arcs (live arcs leaving a machine or below-machine
-        # node), as a (src, arc-id)-sorted CSR: the pin router and the
-        # decode's greedy pushes walk it per node via binary search, in
-        # the same ascending-arc order the old adjacency dict preserved
+        # node), as a (src, arc-id)-sorted CSR: the pin router takes its
+        # segments whole, and the decode's greedy pushes walk it per node
+        # via binary search, in the same ascending-arc order the old
+        # adjacency dict preserved
         is_int_src = (nt_src_live == _MACH_T) | bm_lut[ntp[src[live]]]
         int_arcs = live[is_int_src]
         ia_src = src[int_arcs]
@@ -234,7 +240,7 @@ def try_collapse(problem) -> Tuple[Optional[GraphCollapse], str]:
         # nodes — the latter are lower-bound-FOLDED pinned running tasks
         # (preemption-off pins with cap_lower=1, graph_manager.go:675-720).
         # Folded units are greedily routed to the sink against residual
-        # caps before the transport (see _route / pre_flows below); that
+        # caps before the transport (the audit_pins pass below); that
         # routing is cost-exact because the audit below proves every
         # leaf->sink path under a machine has one uniform cost, so the
         # greedy path's cost equals any other's. Their cost and flow are
@@ -257,31 +263,74 @@ def try_collapse(problem) -> Tuple[Optional[GraphCollapse], str]:
         # excess to the sink FIRST (the pinned task occupies its slot; the
         # occupancy-reduced interior caps — graph_manager.go:662-667 — mean
         # the unit typically has exactly its own leaf->sink hop left).
-        # Machine capacities below are computed on the remaining caps. ----
-        pre_flows: List[Tuple[int, int]] = []
+        # Machine capacities below are computed on the remaining caps.
+        #
+        # A pinned node every one of whose arcs ends at the sink is a
+        # LEAF (the PUs of a preemption-off service): its routing is a
+        # greedy fill of its own CSR segment in ascending arc order, a
+        # cumulative sum, and all leaves are routed at once. Distinct
+        # leaves own disjoint arcs, so that is the walk's result for
+        # them, arc for arc. A pin on any other node (a machine, a core
+        # with children) keeps the scalar walk, AFTER the leaves, in
+        # node order, on the residual caps they left. Leaves first is
+        # never worse than plain node order: a leaf can use only its own
+        # sink arcs and takes the same from them whenever it is served,
+        # so an ancestor sees at least the residual it saw before, and
+        # one path cost under a machine (audit_subtrees proves it next)
+        # makes any such routing cost the same. Leaves are refused first
+        # too: the refusal names the lowest failing leaf, and a failing
+        # node of the walk only where every leaf fits. ----
         cap_res = cap.astype(np.int64)  # owned copy; pin routing mutates
+        pinned = pos[~task_mask[pos]]  # ascending node ids
+        e_pin = excess[pinned].astype(np.int64)
+        lo = np.searchsorted(dec_src, pinned)
+        hi = np.searchsorted(dec_src, pinned, side="right")
+        below = np.concatenate([[0], np.cumsum(dec_child != -1)])
+        is_leaf = below[lo] == below[hi]  # a node with no live arc too
 
-        def _route(v: int, units: int) -> int:
-            routed = 0
-            for a, d in _csr_arcs(dec_src, dec_arc, dec_child, v):
-                if units == 0:
-                    break
-                if d == -1:  # sink
-                    take = min(units, int(cap_res[a]))
-                elif int(nt[d]) in _ROUTABLE:
-                    take = _route(d, min(units, int(cap_res[a])))
-                else:
-                    continue
-                if take:
-                    cap_res[a] -= take
-                    pre_flows.append((a, take))
-                    units -= take
-                    routed += take
-            return routed
+        leaf = np.nonzero(is_leaf)[0]
+        e_leaf = e_pin[leaf]
+        n_arcs = hi[leaf] - lo[leaf]
+        starts = np.cumsum(n_arcs) - n_arcs  # of each leaf's segment in `arcs`
+        seg = np.repeat(np.arange(len(leaf)), n_arcs)
+        arcs = dec_arc[np.arange(len(seg)) + (lo[leaf] - starts)[seg]]
+        room = cap_res[arcs]
+        over = np.nonzero(np.bincount(seg, room, len(leaf)) < e_leaf)[0]
+        if len(over):
+            return _refuse(
+                f"resource {int(pinned[leaf[over[0]]])}: "
+                "folded pinned units exceed capacity"
+            )
+        # what the segment's earlier arcs hold is taken before this arc
+        earlier = np.cumsum(room) - room
+        earlier -= earlier[starts[seg]]
+        fill = np.clip(e_leaf[seg] - earlier, 0, room)
+        cap_res[arcs] -= fill
+        pre_arc, pre_units = arcs[fill > 0], fill[fill > 0]
 
-        for v in pos.tolist():
-            if int(nt[v]) in _ROUTABLE:
-                e = int(excess[v])
+        walk = np.nonzero(~is_leaf)[0]
+        if len(walk):
+            walk_flows: List[Tuple[int, int]] = []
+
+            def _route(v: int, units: int) -> int:
+                routed = 0
+                for a, d in _csr_arcs(dec_src, dec_arc, dec_child, v):
+                    if units == 0:
+                        break
+                    if d == -1:  # sink
+                        take = min(units, int(cap_res[a]))
+                    elif int(nt[d]) in _ROUTABLE:
+                        take = _route(d, min(units, int(cap_res[a])))
+                    else:
+                        continue
+                    if take:
+                        cap_res[a] -= take
+                        walk_flows.append((a, take))
+                        units -= take
+                        routed += take
+                return routed
+
+            for v, e in zip(pinned[walk].tolist(), e_pin[walk].tolist()):
                 try:
                     ok = _route(v, e) == e
                 except RecursionError:
@@ -290,7 +339,12 @@ def try_collapse(problem) -> Tuple[Optional[GraphCollapse], str]:
                     return _refuse(
                         f"resource {v}: folded pinned units exceed capacity"
                     )
-        sp.set("pins", len(pre_flows))
+            if walk_flows:
+                w = np.array(walk_flows, np.int64)
+                pre_arc = np.concatenate([pre_arc, w[:, 0]])
+                pre_units = np.concatenate([pre_units, w[:, 1]])
+        sp.set("pins", len(pre_arc))
+        sp.set("walked", len(walk))
 
     with span("audit_subtrees", nodes=N):
         # ---- machine subtrees: vectorized level-BFS over interior arcs.
@@ -706,7 +760,7 @@ def try_collapse(problem) -> Tuple[Optional[GraphCollapse], str]:
         cost_cm=row_cost,
         row_unsched=row_u,
         machine_node=machine_nodes.astype(np.int64),
-        pre_flows=pre_flows,
+        pre_flows=(pre_arc, pre_units),
         dec_src=dec_src, dec_arc=dec_arc.astype(np.int64),
         dec_child=dec_child,
         task_ids=task_ids.astype(np.int64),
@@ -857,8 +911,7 @@ class AutoSolver(FlowSolver):
         if not len(gc.supply):
             # nothing unplaced: only the folded pins' continuation flow
             flow = np.zeros(len(problem.src), np.int64)
-            for a, units in gc.pre_flows:
-                flow[a] += units
+            np.add.at(flow, *gc.pre_flows)
             self.last_supersteps = 0
             self.last_telemetry = None
             return FlowResult(
@@ -908,8 +961,7 @@ class AutoSolver(FlowSolver):
         flow = np.zeros(len(problem.src), np.int64)
         # folded pinned units first: they consumed tree capacity at
         # audit time, so the greedy pushes below see the same residuals
-        for a, units in gc.pre_flows:
-            flow[a] += units
+        np.add.at(flow, *gc.pre_flows)
 
         # per-task candidate arcs (only granted cells realize a route)
         cands: Dict[int, list] = {}

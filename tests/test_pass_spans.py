@@ -43,11 +43,11 @@ PARENT = {
     "journal_collect": "graph_export", "journal_apply": "graph_export",
     "problem_snapshot": "graph_export", "ec_purge": "round",
 }
-#: the size each pass of the audit carries
+#: the sizes each pass of the audit carries
 AUDIT_ARG = {
-    "audit_index": "arcs", "audit_pins": "pins", "audit_subtrees": "nodes",
-    "audit_task_arcs": "tasks", "audit_ec_routes": "ecs", "audit_escapes": "tasks",
-    "audit_rows": "rows",
+    "audit_index": ("arcs",), "audit_pins": ("pins", "walked"), "audit_subtrees": ("nodes",),
+    "audit_task_arcs": ("tasks",), "audit_ec_routes": ("ecs",), "audit_escapes": ("tasks",),
+    "audit_rows": ("rows",),
 }
 MACHINES, PUS = 6, 2
 #: what the cluster EC's sweep queues, and each node its children:
@@ -148,7 +148,7 @@ def test_the_children_of_a_span_follow_one_another_and_do_not_exceed_it(traced, 
         assert sum(e["dur"] for e in kids) <= outer["dur"] + 1e-3
         if parent == "collapse_audit":
             assert [e["name"] for e in kids] == list(AUDIT)
-            assert all(AUDIT_ARG[e["name"]] in e["args"] for e in kids)
+            assert all(arg in e["args"] for e in kids for arg in AUDIT_ARG[e["name"]])
 
 
 def test_the_audits_sizes_are_the_problems(traced):
@@ -162,6 +162,17 @@ def test_the_audits_sizes_are_the_problems(traced):
     assert (last["audit_task_arcs"]["tasks"], last["audit_escapes"]["tasks"]) == (2, 2)
     assert (last["audit_ec_routes"]["ecs"], last["audit_rows"]["rows"]) == (1, 1)
     assert last["audit_pins"]["pins"] >= 1
+
+
+def test_no_served_round_walks_a_pin(traced):
+    """A preemption-off service pins its pods to PUs, whose arcs all end
+    at the sink: the array path routes every one, in every round, and
+    `pins` is the flow records, one a PU that holds pods."""
+    _svc, events, _records = traced
+    pins = sorted(_by_name(events)["audit_pins"], key=lambda e: e["ts"])
+    assert [e["args"]["walked"] for e in pins] == [0, 0, 0, 0]
+    assert pins[0]["args"]["pins"] == 0  # the fill round finds an empty cluster
+    assert all(1 <= e["args"]["pins"] <= MACHINES * PUS for e in pins[1:])
 
 
 def test_a_refused_audit_closes_every_span_it_opened():
