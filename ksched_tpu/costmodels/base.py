@@ -64,6 +64,8 @@ _CLAIM_METHODS = {
     "resource_arc_costs_are_fixed": (
         "resource_node_to_resource_node_cost", "leaf_resource_node_to_sink_cost",
     ),
+    # the listing that decides which arcs of an EC a sweep keeps
+    "full_resources_stay_listed": ("get_outgoing_equiv_class_pref_arcs",),
 }
 
 
@@ -103,6 +105,16 @@ class CostModeler(abc.ABC):
     #: subclass that overrides one of the two methods has to say it
     #: again for itself; it does not inherit the claim.
     resource_arc_costs_are_fixed: bool = False
+
+    #: What a model says about itself where
+    #: ``get_outgoing_equiv_class_pref_arcs`` lists a resource whatever
+    #: room it has, so that a sweep leaves an arc of capacity 0 to a full
+    #: one (the census-priced models list every machine). The patch of
+    #: the arcs ``equiv_class_pref_arc_changes`` names then writes
+    #: capacity 0 on the arc as the sweep would, and the graph is the
+    #: sweep's, arc for arc. False: the listing leaves a full resource
+    #: out, the sweep deletes its arc, and so does the patch.
+    full_resources_stay_listed: bool = False
 
     #: What a model says about itself where its prices only make sense
     #: while running tasks keep their arcs (a preemption cost, an EC ->
@@ -295,9 +307,11 @@ class CostModeler(abc.ABC):
         gone, another cost or capacity) since the arcs of ``ec`` were
         last listed, by this method or by
         ``get_outgoing_equiv_class_pref_arcs``; the graph manager then
-        asks ``equiv_class_to_resource_node`` about those alone, where
-        capacity 0 means no arc. None (the default): the model keeps no
-        such record and every preferred resource is visited.
+        asks ``ec_to_resource_batch`` about those alone, in one call,
+        where capacity 0 means no arc (or, under
+        ``full_resources_stay_listed``, an arc of capacity 0). None (the
+        default): the model keeps no such record, or cannot trust it
+        this time, and every preferred resource is visited.
 
         Precondition of answering: the graph manager does not queue the
         listed resources, so ``_update_res_outgoing_arcs`` does not run
@@ -305,8 +319,14 @@ class CostModeler(abc.ABC):
         costs and capacities of its resource -> resource and PU -> sink
         arcs do not depend on the round (the trivial model's are
         constants: ``resource_arc_costs_are_fixed``, under which no
-        resource is queued by anyone); one whose resource arcs follow a
-        census must return None."""
+        resource is queued by anyone); one whose resource -> resource
+        arcs follow a census must return None. That is about the arcs
+        between resources. A model whose EC -> resource arcs follow a
+        census may answer once the census says which machines it
+        gathered again since the listing (costmodels/census.py:
+        every change of a machine's counts reaches the statistics pass
+        through ``GraphManager.running_tasks_changed`` before the next
+        graph update reads them)."""
         return None
 
     # -- debug ------------------------------------------------------------

@@ -26,22 +26,28 @@ Policy:
 - Capacity on EC→machine arcs = free slots below, the same rule the
   trivial model uses (trivial_cost_modeler.go:76-83).
 
-The vectorized form used by the array fast path is
-`coco_cost_matrix(census, penalties)`: one [4, M] int32 matrix per
-round from an [M, 4] census — pure numpy, no per-arc callbacks.
+The vectorized form is `coco_cost_matrix(census, penalties)`: a [4, M]
+int32 matrix from an [M, 4] census — pure numpy, no per-arc callbacks:
+the program's one copy of the arithmetic over many machines. The array
+fast path makes one per round; on the served path the batch hook
+(`ClassCensusCostModel.ec_to_resource_batch`, census.py) takes a class
+EC's row of it over the census keeper's arrays, for every machine where
+the EC lists its arcs (the fill, a round whose statistics pass walked
+every node) and otherwise for the machines the census gathered again
+since the EC last listed them: the few a round's Bindings and
+completions touched. The scalar hooks (`_machine_cost`) are the
+definition the batch is tested against.
 """
 
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+from typing import List, Optional
 
 import numpy as np
 
-from ..data import ResourceDescriptor, ResourceTopologyNodeDescriptor
-from ..graph.flowgraph import Node
-from ..utils import ResourceMap, TaskMap
-from .base import Cost, CostModeler
-from .census import CLASS_ECS, ClassCensusKeeper, ec_class
+from ..data import ResourceDescriptor
+from .base import Cost
+from .census import NUM_TASK_CLASSES, ClassCensusCostModel
 
 # Class-interaction weights W[c, k]: marginal cost of placing a class-c
 # task next to one resident class-k task. Order: Sheep, Rabbit, Devil,
@@ -85,69 +91,16 @@ def coco_cost_matrix(census: np.ndarray, penalties: Optional[np.ndarray] = None)
     return np.minimum(cost, MAX_COST).astype(np.int32)
 
 
-class CocoCostModel(CostModeler):
+class CocoCostModel(ClassCensusCostModel):
     """Interference-aware placement (TPU-rebuild implementation of the
-    reference's planned COCO model, costmodel/interface.go:39)."""
-
-    # continuation cost is the constant 0 and the census ignores a
-    # non-resource accumulator (base.py)
-    pinned_tasks_are_inert = True
-    # resource -> resource and PU -> sink arcs cost the constant 0 (base.py)
-    resource_arc_costs_are_fixed = True
-
-    def __init__(
-        self,
-        resource_map: ResourceMap,
-        task_map: TaskMap,
-        leaf_resource_ids,
-        max_tasks_per_pu: int,
-    ) -> None:
-        self.resource_map = resource_map
-        self.task_map = task_map
-        self.leaf_resource_ids = leaf_resource_ids
-        self.census = ClassCensusKeeper(resource_map, task_map, max_tasks_per_pu)
-
-    def take_census_machines_dirty(self) -> int:
-        return self.census.take_machines_dirty()
-
-    # -- arc costs --------------------------------------------------------
+    reference's planned COCO model, costmodel/interface.go:39). The
+    class ECs, their arcs and the census: ClassCensusCostModel."""
 
     def task_to_unscheduled_agg_cost(self, task_id: int) -> Cost:
         return UNSCHEDULED_COST
 
-    def unscheduled_agg_to_sink_cost(self, job_id: int) -> Cost:
-        return 0
-
-    def task_to_resource_node_cost(self, task_id: int, resource_id: int) -> Cost:
-        c = self.census.task_class(task_id)
-        return int(self._machine_cost(c, resource_id))
-
-    def resource_node_to_resource_node_cost(
-        self, source: Optional[ResourceDescriptor], destination: ResourceDescriptor
-    ) -> Cost:
-        return 0
-
-    def leaf_resource_node_to_sink_cost(self, resource_id: int) -> Cost:
-        return 0
-
-    def task_continuation_cost(self, task_id: int) -> Cost:
-        # Continuing in place is free of *new* interference.
-        return 0
-
     def task_preemption_cost(self, task_id: int) -> Cost:
         return MAX_COST // 2
-
-    def task_to_equiv_class_aggregator(self, task_id: int, ec: int) -> Cost:
-        return 0
-
-    def equiv_class_to_resource_node(self, ec: int, resource_id: int) -> Tuple[Cost, int]:
-        c = ec_class(ec)
-        if c is None:
-            return 0, 0
-        return int(self._machine_cost(c, resource_id)), self.census.free_slots(resource_id)
-
-    def equiv_class_to_equiv_class(self, ec1: int, ec2: int) -> Tuple[Cost, int]:
-        return 0, 0
 
     def _machine_cost(self, task_class: int, resource_id: int) -> int:
         census = self.census.machine_census(resource_id)
@@ -156,43 +109,14 @@ class CocoCostModel(CostModeler):
         raw = int(INTERFERENCE[task_class] @ census) + int(pen)
         return min(raw, MAX_COST)
 
-    # -- preference enumeration -------------------------------------------
+    def _machine_constants(self, machines: List[ResourceDescriptor]) -> np.ndarray:
+        """Each machine's penalty vector, [M, 4]."""
+        return np.array(
+            [machine_penalty_matrix(rd) for rd in machines], np.int64
+        ).reshape(len(machines), NUM_TASK_CLASSES)
 
-    def get_task_equiv_classes(self, task_id: int) -> List[int]:
-        return [CLASS_ECS[self.census.task_class(task_id)]]
-
-    def get_outgoing_equiv_class_pref_arcs(self, ec: int) -> List[int]:
-        if ec_class(ec) is None:
-            return []
-        return list(self.census.machines.keys())
-
-    def get_task_preference_arcs(self, task_id: int) -> List[int]:
-        return []
-
-    def get_equiv_class_to_equiv_classes_arcs(self, ec: int) -> List[int]:
-        return []
-
-    # -- lifecycle --------------------------------------------------------
-
-    def add_machine(self, rtnd: ResourceTopologyNodeDescriptor) -> None:
-        self.census.add_machine(rtnd)
-
-    def add_task(self, task_id: int) -> None:
-        pass
-
-    def remove_machine(self, resource_id: int) -> None:
-        self.census.remove_machine(resource_id)
-
-    def remove_task(self, task_id: int) -> None:
-        pass
-
-    # -- stats traversal --------------------------------------------------
-
-    def gather_stats(self, accumulator: Node, other: Node) -> Node:
-        return self.census.gather(accumulator, other)
-
-    def prepare_stats(self, accumulator: Node) -> None:
-        self.census.prepare(accumulator)
-
-    def update_stats(self, accumulator: Node, other: Node) -> Node:
-        return accumulator
+    def _class_cost_row(
+        self, task_class: int, census: np.ndarray, idle: np.ndarray, slots: np.ndarray,
+        constants: np.ndarray,
+    ) -> np.ndarray:
+        return coco_cost_matrix(census, constants)[task_class]

@@ -44,24 +44,29 @@ trivial model (trivial_cost_modeler.go:76-83). Leaving a task
 unscheduled costs UNSCHEDULED_COST, more than any machine.
 
 `whare_cost_matrix(census, idle, slots, psi, platform)` is the equation,
-over every machine at once, [4, M]: the program's one copy of it. The
-model's batch hook (`ec_to_resource_batch`, one call an EC a round)
-computes its class's row with it from the census keeper's arrays, inside
-a `platform_costs` span; the scalar hooks ask it for one machine.
+over many machines at once, [4, M]: the program's one copy of it. The
+batch hook (`ClassCensusCostModel.ec_to_resource_batch`, census.py; one
+call an EC a round, inside a `platform_costs` span whose `machines` is
+the number priced) computes its class's row with it over the census
+keeper's arrays: for every machine where the EC lists its arcs (the
+fill, a round whose statistics pass walked every node, the round after
+`record_runtime` moved the map or a machine joined or left), otherwise
+for the machines the census gathered again since the EC last listed
+them: the few a round's Bindings and completions touched. The scalar
+hooks ask the equation for one machine.
 """
 
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..data import PLATFORM_LABEL, ResourceDescriptor, ResourceTopologyNodeDescriptor
-from ..graph.flowgraph import Node
+from ..data import PLATFORM_LABEL, ResourceDescriptor
 from ..obs.spans import span
 from ..utils import ResourceMap, TaskMap
-from .base import Cost, CostModeler
-from .census import CLASS_ECS, ClassCensusKeeper, ec_class
+from .base import Cost
+from .census import ClassCensusCostModel, ec_class
 
 # Prior psi[c, k] ×100: neutral 100 = no slowdown; devils degrade
 # co-runners, rabbits are the most sensitive; alone, nothing slows a task.
@@ -145,15 +150,10 @@ def whare_cost_matrix(
     return np.clip(cost, 0, MAX_COST).astype(np.int32)
 
 
-class WhareMapCostModel(CostModeler):
+class WhareMapCostModel(ClassCensusCostModel):
     """Observed-slowdown placement (TPU-rebuild implementation of the
-    reference's planned WHARE model, costmodel/interface.go:37)."""
-
-    # continuation cost is the constant 0 and the census ignores a
-    # non-resource accumulator (base.py)
-    pinned_tasks_are_inert = True
-    # resource -> resource and PU -> sink arcs cost the constant 0 (base.py)
-    resource_arc_costs_are_fixed = True
+    reference's planned WHARE model, costmodel/interface.go:37). The
+    class ECs, their arcs and the census: ClassCensusCostModel."""
 
     def __init__(
         self,
@@ -162,18 +162,11 @@ class WhareMapCostModel(CostModeler):
         leaf_resource_ids,
         max_tasks_per_pu: int,
     ) -> None:
-        self.resource_map = resource_map
-        self.task_map = task_map
-        self.leaf_resource_ids = leaf_resource_ids
-        self.census = ClassCensusKeeper(resource_map, task_map, max_tasks_per_pu)
+        super().__init__(resource_map, task_map, leaf_resource_ids, max_tasks_per_pu)
         # float32 is ample for an EWMA over x100 slowdowns (24-bit
         # mantissa vs values O(10^4)); 64-bit buys nothing here
         self.psi = psi_prior().astype(np.float32)
         self._psi_int = psi_prior()
-        #: the platform of every machine, in the order of the census
-        #: keeper's machines, made again when one joins or leaves
-        self._platform = np.zeros(0, np.int64)
-        self._platform_version = -1
 
     # -- the map (online learning) ----------------------------------------
 
@@ -184,56 +177,27 @@ class WhareMapCostModel(CostModeler):
         task of ``task_class`` that ran on a machine of ``platform``
         (an index into PLATFORMS) beside ``corunner_class`` (a census
         class, or ALONE) into that cell of the map — fed from TaskFinalReport runtimes in the
-        reference's intended pipeline."""
+        reference's intended pipeline. Every cost of the class may have
+        moved, on machines no census gathered again: its EC lists anew
+        (and so do the others: one record of listings serves all)."""
         old = self.psi[task_class, platform, corunner_class]
         self.psi[task_class, platform, corunner_class] = (
             (1.0 - EWMA_WEIGHT) * old + EWMA_WEIGHT * slowdown_x100
         )
         self._psi_int = np.rint(self.psi).astype(np.int32)
+        self.census.forget_listings()
 
     def psi_int(self) -> np.ndarray:
         """The map as the costs read it: int32 [4, P, 5]."""
         return self._psi_int
-
-    def take_census_machines_dirty(self) -> int:
-        return self.census.take_machines_dirty()
 
     # -- arc costs --------------------------------------------------------
 
     def task_to_unscheduled_agg_cost(self, task_id: int) -> Cost:
         return UNSCHEDULED_COST
 
-    def unscheduled_agg_to_sink_cost(self, job_id: int) -> Cost:
-        return 0
-
-    def task_to_resource_node_cost(self, task_id: int, resource_id: int) -> Cost:
-        return int(self._machine_cost(self.census.task_class(task_id), resource_id))
-
-    def resource_node_to_resource_node_cost(
-        self, source: Optional[ResourceDescriptor], destination: ResourceDescriptor
-    ) -> Cost:
-        return 0
-
-    def leaf_resource_node_to_sink_cost(self, resource_id: int) -> Cost:
-        return 0
-
-    def task_continuation_cost(self, task_id: int) -> Cost:
-        return 0
-
     def task_preemption_cost(self, task_id: int) -> Cost:
         return MAX_COST // 2
-
-    def task_to_equiv_class_aggregator(self, task_id: int, ec: int) -> Cost:
-        return 0
-
-    def equiv_class_to_resource_node(self, ec: int, resource_id: int) -> Tuple[Cost, int]:
-        c = ec_class(ec)
-        if c is None:
-            return 0, 0
-        return int(self._machine_cost(c, resource_id)), self.census.free_slots(resource_id)
-
-    def equiv_class_to_equiv_class(self, ec1: int, ec2: int) -> Tuple[Cost, int]:
-        return 0, 0
 
     def _machine_cost(self, task_class: int, resource_id: int) -> int:
         """One cell of `whare_cost_matrix`: the equation has one copy."""
@@ -248,69 +212,25 @@ class WhareMapCostModel(CostModeler):
             np.array([platform_index(rd.labels)]),
         )[0, 0])
 
-    def ec_to_resource_batch(self, ec: int, resource_ids) -> Tuple[List[Cost], List[int]]:
-        """An EC's arcs to every machine in one call: the class's row of
-        `whare_cost_matrix` over the census keeper's arrays. Resources
-        other than the keeper's machines in its order are asked one by
-        one (the base class's loop): the span then says `scalar=True`,
-        so that a drop to 12,500 calls an EC does not pass for the
-        batch."""
-        c = ec_class(ec)
-        if c is None:
-            return super().ec_to_resource_batch(ec, resource_ids)
-        with span("platform_costs", machines=len(resource_ids)) as sp:
-            rids, census, idle, slots, free = self.census.machine_arrays()
-            if resource_ids != rids:
-                sp.set("scalar", True)
-                return super().ec_to_resource_batch(ec, resource_ids)
-            if self._platform_version != self.census.machines_version:
-                self._platform_version = self.census.machines_version
-                self._platform = np.array(
-                    [platform_index(self.census.machines[r].resource_desc.labels) for r in rids],
-                    np.int64,
-                )
-            row = whare_cost_matrix(
-                census, idle, slots, self._psi_int[c : c + 1], self._platform
-            )[0]
-            return row.tolist(), free.tolist()
+    def _machine_constants(self, machines: List[ResourceDescriptor]) -> np.ndarray:
+        """Each machine's platform, [M]."""
+        return np.array([platform_index(rd.labels) for rd in machines], np.int64)
 
-    # -- preference enumeration -------------------------------------------
+    def _class_cost_row(
+        self, task_class: int, census: np.ndarray, idle: np.ndarray, slots: np.ndarray,
+        constants: np.ndarray,
+    ) -> np.ndarray:
+        return whare_cost_matrix(
+            census, idle, slots, self._psi_int[task_class : task_class + 1], constants
+        )[0]
 
-    def get_task_equiv_classes(self, task_id: int) -> List[int]:
-        return [CLASS_ECS[self.census.task_class(task_id)]]
-
-    def get_outgoing_equiv_class_pref_arcs(self, ec: int) -> List[int]:
+    def ec_to_resource_batch(
+        self, ec: int, resource_ids: Sequence[int]
+    ) -> Tuple[List[Cost], List[int]]:
+        """The shared batch inside a `platform_costs` span: the keeper's
+        arrays read again for the machines `stats` gathered, and the
+        class's row of the matrix over the ``machines`` priced."""
         if ec_class(ec) is None:
-            return []
-        return list(self.census.machines.keys())
-
-    def get_task_preference_arcs(self, task_id: int) -> List[int]:
-        return []
-
-    def get_equiv_class_to_equiv_classes_arcs(self, ec: int) -> List[int]:
-        return []
-
-    # -- lifecycle --------------------------------------------------------
-
-    def add_machine(self, rtnd: ResourceTopologyNodeDescriptor) -> None:
-        self.census.add_machine(rtnd)
-
-    def add_task(self, task_id: int) -> None:
-        pass
-
-    def remove_machine(self, resource_id: int) -> None:
-        self.census.remove_machine(resource_id)
-
-    def remove_task(self, task_id: int) -> None:
-        pass
-
-    # -- stats traversal --------------------------------------------------
-
-    def gather_stats(self, accumulator: Node, other: Node) -> Node:
-        return self.census.gather(accumulator, other)
-
-    def prepare_stats(self, accumulator: Node) -> None:
-        self.census.prepare(accumulator)
-
-    def update_stats(self, accumulator: Node, other: Node) -> Node:
-        return accumulator
+            return super().ec_to_resource_batch(ec, resource_ids)
+        with span("platform_costs", machines=len(resource_ids)):
+            return super().ec_to_resource_batch(ec, resource_ids)

@@ -1056,10 +1056,11 @@ class GraphManager:
         batch cost-model hook so wide fan-outs (EC → every machine) cost
         one call. Where the EC has arcs and the model kept a record of
         what changed since it listed them, only those resources are
-        looked at."""
-        with span("ec_refresh"):
+        looked at; the span says which it was (`swept`)."""
+        with span("ec_refresh") as sp:
             ec = ec_node.equiv_class
             changed = self.cost_model.equiv_class_pref_arc_changes(ec) if ec_node.outgoing else None
+            sp.set("swept", int(changed is None))
             if changed is None:
                 self._sweep_equiv_to_res_arcs(ec_node, node_queue, marked)
             else:
@@ -1097,16 +1098,20 @@ class GraphManager:
 
     def _patch_equiv_to_res_arcs(self, ec_node: Node, changed: List[int]) -> None:
         """The arcs from ``ec_node`` to the resources of ``changed``,
-        each as the model now has it (capacity 0: none). The resources
-        are not queued for a visit: nothing about them is known to have
-        changed but this arc."""
-        ec = ec_node.equiv_class
-        for rid in changed:
-            res_node = self.resource_to_node.get(rid)
-            if res_node is None:
-                continue  # the resource left, and the arc with its node
-            cost, cap_upper = self.cost_model.equiv_class_to_resource_node(ec, rid)
-            if cap_upper > 0:
+        each as the model now has it, priced in one call. Capacity 0:
+        no arc, or an arc of capacity 0 where the model's listing keeps
+        a full resource (CostModeler.full_resources_stay_listed): what a
+        sweep would leave. The resources are not queued for a visit:
+        nothing about them is known to have changed but this arc."""
+        # a resource that left took its node, and the arc, with it
+        live = [rid for rid in changed if rid in self.resource_to_node]
+        if not live:
+            return
+        costs, caps = self.cost_model.ec_to_resource_batch(ec_node.equiv_class, live)
+        keep_full = self.cost_model.full_resources_stay_listed
+        for rid, cost, cap_upper in zip(live, costs, caps):
+            res_node = self.resource_to_node[rid]
+            if cap_upper > 0 or keep_full:
                 self._set_equiv_to_res_arc(ec_node, res_node, cost, cap_upper)
                 continue
             arc = self.cm.graph.get_arc(ec_node, res_node)

@@ -571,6 +571,7 @@ class FlowScheduler:
                 sp.set("stats_pus_dirty", timing.stats_pus_dirty)
                 sp.set("stats_nodes_visited", timing.stats_nodes_visited)
                 sp.set("stats_full_walk", timing.stats_full_walk)
+                sp.set("census_machines_dirty", timing.census_machines_dirty)
             timing.stats_s = sp.dur_s
             self._free_slots_at_solve = self._free_slots()
             with span("graph_update") as sp:
@@ -593,6 +594,7 @@ class FlowScheduler:
                 sp.set("ec_nodes", timing.ec_nodes)
                 sp.set("ec_arcs", timing.ec_arcs)
                 sp.set("ec_arcs_changed", timing.ec_arcs_changed)
+                sp.set("ec_arcs_repriced", timing.ec_arcs_repriced)
                 sp.set("ec_chain_arcs_changed", timing.ec_chain_arcs_changed)
             timing.graph_update_s = sp.dur_s
             timing.tasks_unpinned = self.gm.unpinned_running_tasks

@@ -89,6 +89,16 @@ def _seeded_rng():
 #: entry for it). `test_benchmark_wharemap.py` holds what stays true of each:
 #: the lists as "what they had, then this cell", the plan's digests of the new
 #: cell, `class_only` by name equal to `class_only` by default.
+#:
+#: PR 48 (the class ECs of `coco` and `whare` re-price the machines the census
+#: gathered again, not every machine) made one sentence of
+#: `test_benchmark_wharemap.py`'s traced rehearsal false: "every class EC of
+#: the batch sweeps every machine; few of those arcs change"
+#: (`ec_arcs_repriced % 312 == 0`, `ec_arcs_changed < ec_arcs_repriced`).
+#: Restate it as "`ec_arcs_repriced` is visited ECs x `census_machines_dirty`
+#: in a round that patched, and at most the arcs written change".
+#: `tests/test_wharemap_rehearsal.py` holds every other assertion of that
+#: case, on the same run, and that sentence round by round.
 _STALE = {
     "test_benchmark_seams.py::test_class_only_is_the_old_expression_and_the_same_seed_"
     "draws_the_same_plan[k8s-5000-preemption.rollout-": "PR 38 brings pods/by_role.py",
@@ -132,6 +142,9 @@ _STALE = {
     "cells": "PR 46 appended its cell to the list",
     "test_benchmark_runnable_scan.py::test_a_cell_loads_it_by_name_if_it_is_listed_and_not_"
     "otherwise[gtrace-12500-wharemap.trickle]": "PR 46's cell is on the list",
+    "test_benchmark_wharemap.py::test_the_traced_rehearsal_is_correct_and_every_metric_reads_"
+    "a_number": "PR 48: `ec_arcs_repriced` is visited ECs x `census_machines_dirty` in a round "
+                "that patched, and nearly every arc written changes",
 }
 
 

@@ -108,6 +108,7 @@ def _build_graph_cluster(num_machines, slots, interference, base_scale):
     device twin: cost[c, m] = interference * other_class_running(m)
     + (1 + c) * base_scale * machine_index(m); continuation = current
     machine's cost - DISCOUNT; escape/preemption = UNSCHED."""
+    from ksched_tpu.costmodels.base import CostModeler
     from ksched_tpu.costmodels.census import CLASS_ECS
     from ksched_tpu.costmodels.coco import CocoCostModel
     from ksched_tpu.drivers import build_cluster
@@ -115,6 +116,8 @@ def _build_graph_cluster(num_machines, slots, interference, base_scale):
 
     class ShiftModel(CocoCostModel):
         machine_index = {}  # rid -> index, filled after build
+        # the scalar hooks below are the model: no row of `coco_cost_matrix`
+        ec_to_resource_batch = CostModeler.ec_to_resource_batch
 
         def _machine_cost(self, task_class, resource_id):
             census = self.census.machine_census(resource_id)

@@ -133,8 +133,9 @@ def test_a_subclass_that_overrides_a_claims_method_loses_that_claim_alone(cls):
     assert (cls.pinned_tasks_are_inert, cls.resource_arc_costs_are_fixed) == CLAIMS[cls]
 
 
-def test_one_table_of_claim_and_methods_serves_both_claims():
-    assert set(_CLAIM_METHODS) == {"pinned_tasks_are_inert", "resource_arc_costs_are_fixed"}
+def test_one_table_of_claim_and_methods_serves_every_claim_about_methods():
+    assert set(_CLAIM_METHODS) == {
+        "pinned_tasks_are_inert", "resource_arc_costs_are_fixed", "full_resources_stay_listed"}
     for claim, methods in _CLAIM_METHODS.items():
         assert getattr(CostModeler, claim) is False  # nothing is claimed by default
         assert all(callable(getattr(CostModeler, m)) for m in methods)
@@ -240,10 +241,17 @@ def test_no_resource_turn_gives_the_every_node_walks_journal_problem_deltas_and_
         ref_turns.append(ref_gm.res_nodes_visited)
         purged.append(gm.ec_purged)
         every_node_but_the_root = len(gm.resource_to_node) - 1
-        if model != "k8s_antiaffinity":
+        if model == "trivial" or (model == "coco" and preemption):
             # a cluster-wide EC's sweep queues every machine, each node its children
+            # (`coco`'s class ECs sweep after a pass that walked every node: under
+            # preemption, every pass)
             assert ref_turns[-1] == every_node_but_the_root
     assert sum(len(j) for j in new.journals) > 100
+    if model == "coco" and not preemption:
+        # a class EC with arcs is patched from the census keeper's record and queues
+        # nothing; it sweeps, and the sweep queues every machine, where it lists: in the
+        # first round, and after a machine came or went
+        assert ref_turns[0] > 0 and ref_turns[REMOVE] > 0 and ref_turns[ADD] > 0 and 0 in ref_turns
     if model == "k8s_antiaffinity":
         # an EC with arcs is patched from the model's record and queues
         # nothing; one listed for the first time, or anew after its purge,
@@ -267,7 +275,10 @@ def test_no_resource_turn_gives_the_every_node_walks_journal_problem_deltas_and_
     assert new.sched.task_bindings == ref.sched.task_bindings
     assert new.journals[-1] == ref.journals[-1]
     assert pus & set(new.sched.task_bindings.values())
-    assert new.sched.gm.res_nodes_visited == 0 < ref.sched.gm.res_nodes_visited
+    assert new.sched.gm.res_nodes_visited == 0
+    # `coco`'s class ECs patch the machines the last round touched, and a patch queues none
+    patches = model == "coco" and not preemption
+    assert (ref.sched.gm.res_nodes_visited == 0) == patches
 
 
 def test_a_model_that_re_prices_a_resource_arc_keeps_every_turn_and_its_prices_land():

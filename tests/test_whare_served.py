@@ -299,15 +299,19 @@ def test_the_batch_hook_prices_what_the_scalar_hook_prices():
         costs, caps = model.ec_to_resource_batch(ec, rids)
         assert list(zip(costs, caps)) == [model.equiv_class_to_resource_node(ec, r) for r in rids]
         assert all(type(v) is int for v in costs + caps)
-    # any other list of resources is asked one by one, and the span says so
+    # any other list of the keeper's machines is priced by row, and the span says how many
+    some = [rids[i] for i in (17, 2, 2, 9, 23)]
     tracer = SpanTracer().install()
     try:
         costs, caps = model.ec_to_resource_batch(CLASS_ECS[1], rids[::-1])
-        model.ec_to_resource_batch(CLASS_ECS[1], rids)
+        few = model.ec_to_resource_batch(CLASS_ECS[1], some)
+        model.ec_to_resource_batch(12345, some)  # no class's EC: no arc, and no span
     finally:
         tracer.uninstall()
     assert list(zip(costs, caps)) == [model.equiv_class_to_resource_node(CLASS_ECS[1], r) for r in rids[::-1]]
-    assert [e["args"].get("scalar", False) for e in tracer.events() if e["name"] == "platform_costs"] == [True, False]
+    assert list(zip(*few)) == [model.equiv_class_to_resource_node(CLASS_ECS[1], r) for r in some]
+    priced = [e["args"] for e in tracer.events() if e["name"] == "platform_costs"]
+    assert [a["machines"] for a in priced] == [24, 5] and not any("scalar" in a for a in priced)
     # a runtime recorded for (rabbit, C, devil) moves that cell's cost and no other platform's
     before = [model.equiv_class_to_resource_node(CLASS_ECS[1], r)[0] for r in rids]
     for _ in range(20):
@@ -336,27 +340,49 @@ def test_the_round_stamps_the_counters_and_opens_the_span():
     try:
         svc, api = _service(24, tracer=RoundTracer())
         rng = np.random.default_rng(2)
-        for k, n in enumerate((150, 8, 5)):
-            for i in range(n):
-                api.submit_pod(PodEvent(pod_id=f"r{k}_{i}", task_class=int(rng.integers(0, 4))))
-            svc.run_round(drain(api, n))
+        classes = [rng.integers(0, 4, n).tolist() for n in (150, 8, 5, 6)]
+        for k, batch in enumerate(classes):
+            for i, c in enumerate(batch):
+                api.submit_pod(PodEvent(pod_id=f"r{k}_{i}", task_class=c))
+            svc.run_round(drain(api, len(batch)))
     finally:
         tracer.uninstall()
-    fill, second, third = [r for r in svc.tracer.records if r.solver_rung >= 0]
+    fill, second, third, fourth = [r for r in svc.tracer.records if r.solver_rung >= 0]
     # the fill: every machine empty, and its platform costs each class its own: four rows
     assert (fill.audit_tasks_grouped, fill.collapse_rows, fill.collapse_cols) == (150, 4, 128)
+    # no class EC has listed: each sweeps every machine
     assert fill.census_machines_dirty == 24 and fill.ec_arcs_repriced == 4 * 24
     assert (second.audit_tasks_grouped, second.collapse_cols) == (8, 128) and 1 <= second.collapse_rows <= 4
-    # the second round gathers again the machines the fill bound pods on
-    assert 0 < second.census_machines_dirty <= 24 and 0 < third.census_machines_dirty <= 8
-    assert second.ec_arcs_repriced in (24, 48, 72, 96) and second.ec_arcs_changed <= second.ec_arcs_repriced
+    # the second round gathers again the machines the fill bound pods on: all of them, which
+    # is a sweep's worth, so its ECs sweep, and so does, at its next turn, one that sat that
+    # round out; otherwise a class EC the batch reaches prices the machines gathered again
+    # since its last turn, and no other
+    turned = [set(batch) for batch in classes]
+    assert second.census_machines_dirty == 24 and second.ec_arcs_repriced == 24 * len(turned[1])
+    assert 0 < third.census_machines_dirty <= 8 and 0 < fourth.census_machines_dirty <= 5
+    late3, late4 = turned[2] - turned[1], turned[3] - turned[2] - turned[1]
+    assert third.ec_arcs_repriced == 24 * len(late3) + third.census_machines_dirty * len(turned[2] - late3)
+    # an EC that sat the third round out owes its machines too
+    patched4 = len(turned[3] - late4)
+    assert fourth.census_machines_dirty * patched4 <= fourth.ec_arcs_repriced - 24 * len(late4) <= (
+        (third.census_machines_dirty + fourth.census_machines_dirty) * patched4)
+    for r in (second, third, fourth):
+        assert r.ec_arcs_changed <= r.ec_arcs_repriced
     by_sid = {e["args"]["sid"]: e for e in tracer.events() if "sid" in e["args"]}
     spans = [e for e in by_sid.values() if e["name"] == "platform_costs"]
-    assert len(spans) == sum(r.ec_arcs_repriced for r in (fill, second, third)) // 24
+    # one span a class EC that priced a machine, inside that EC's refresh, with the number priced
+    assert sum(e["args"]["machines"] for e in spans) == sum(
+        r.ec_arcs_repriced for r in (fill, second, third, fourth))
+    swept = 4 + len(turned[1]) + len(late3) + len(late4)
+    assert {e["args"]["machines"] for e in spans} > {24} and len(spans) > swept
     for e in spans:
         parent = by_sid[e["args"]["parent_sid"]]
-        assert parent["name"] == "ec_refresh" and e["args"]["machines"] == 24
+        assert parent["name"] == "ec_refresh"
+        assert parent["args"]["swept"] == int(e["args"]["machines"] == 24)
         assert parent["ts"] <= e["ts"] and e["ts"] + e["dur"] <= parent["ts"] + parent["dur"]
+    # every class EC's turn says whether it swept; one that had nothing to price opens no span
+    turns = [e["args"]["swept"] for e in by_sid.values() if e["name"] == "ec_refresh"]
+    assert turns.count(1) == swept and turns.count(0) >= len(spans) - swept
 
 
 # -- the flag -------------------------------------------------------------------------------------
