@@ -102,6 +102,20 @@ def machine_type_of(index: int, types: Sequence[MachineType]) -> MachineType:
     raise ValueError("the shares of the machine types do not sum to 1000")
 
 
+def parse_allocatable(text: str) -> Tuple[int, int]:
+    """``4000:32768`` -> (CPU millicores, memory MiB), both above 0;
+    ``argparse.ArgumentTypeError`` otherwise."""
+    try:
+        cpu, mem = (int(part) for part in text.split(":"))
+    except ValueError:
+        cpu = mem = 0
+    if cpu <= 0 or mem <= 0:
+        raise argparse.ArgumentTypeError(
+            f"{text!r}: want CPU_MILLIS:MEM_MIB, two whole numbers above 0 (e.g. 4000:32768)"
+        )
+    return cpu, mem
+
+
 def fake_cores(num_machines: int, cores_per_machine: int, types: Sequence[MachineType]) -> int:
     """The cores of ``num_machines`` fake machines: every machine's
     ``cores_per_machine``, or what ``types`` deals each."""
@@ -140,6 +154,7 @@ class SchedulerService:
         fake_racks: int = 0,
         preemption: bool = False,
         fake_machine_types: Sequence[MachineType] = (),
+        fake_node_allocatable: Tuple[int, int] = (0, 0),
         _restored: Optional[Tuple] = None,
     ) -> None:
         if preemption and backend_name == "auto":
@@ -181,6 +196,10 @@ class SchedulerService:
         #: it; each carries its type's name as its platform label and
         #: its type's cores (empty: every machine alike, no label)
         self.fake_machine_types = tuple(fake_machine_types)
+        #: --fake-node-allocatable: what every fake machine of
+        #: init_topology can give to pods, (CPU millicores, memory MiB)
+        #: ((0, 0): not said)
+        self.fake_node_allocatable = tuple(fake_node_allocatable)
         self.injector = injector
         self.tracer = tracer
         self.flight = flight
@@ -296,6 +315,16 @@ class SchedulerService:
     # -- topology ---------------------------------------------------------
 
     def add_node(self, node: NodeEvent) -> None:
+        allocatable = (node.cpu_allocatable_millis, node.memory_allocatable_mib)
+        if self.scheduler.cost_model.reads_machine_allocatable and min(allocatable) <= 0:
+            # no request fits such a node: it would join with no arc and
+            # its pods wait for ever, with no word
+            raise ValueError(
+                f"node {node.node_id}: allocatable {allocatable} (CPU millicores, memory MiB): "
+                "the cost model fits requests into what a node can give, and this one gives "
+                "nothing (fake machines: --fake-node-allocatable CPU_MILLIS:MEM_MIB; a control "
+                "plane: NodeEvent.cpu_allocatable_millis / memory_allocatable_mib)"
+            )
         machine = add_machine(
             self.scheduler,
             self.resource_map,
@@ -305,6 +334,7 @@ class SchedulerService:
             task_capacity_per_pu=self.max_tasks_per_pu,
             machine_index=len(self.node_to_machine),
             labels=dict(node.labels),
+            allocatable=allocatable,
         )
         machine.resource_desc.capacity.net_bw = node.net_bw_capacity
         mid = resource_id_from_string(machine.resource_desc.uuid)
@@ -329,7 +359,8 @@ class SchedulerService:
         ``fake_racks`` over that many racks, the same way. With
         ``fake_machine_types`` machine i is of the type
         ``machine_type_of`` deals it: that type's cores, and its name
-        under the platform label."""
+        under the platform label. With ``fake_node_allocatable`` every
+        fake machine says it can give that much CPU and memory to pods."""
         if fake_machines > 0:
             dealt = [
                 (key, prefix, n)
@@ -339,6 +370,7 @@ class SchedulerService:
                 if n > 0
             ]
             types = self.fake_machine_types
+            cpu_millis, memory_mib = self.fake_node_allocatable
             for i in range(fake_machines):
                 labels = [(key, f"{prefix}-{i % n}") for key, prefix, n in dealt]
                 cores = cores_per_machine
@@ -350,6 +382,8 @@ class SchedulerService:
                         node_id=f"fake_node_{i}",
                         num_cores=cores,
                         pus_per_core=pus_per_core,
+                        cpu_allocatable_millis=cpu_millis,
+                        memory_allocatable_mib=memory_mib,
                         labels=tuple(labels),
                     )
                 )
@@ -440,13 +474,14 @@ class SchedulerService:
             # refresh the descriptor, and evict any stale placement so
             # the next round reschedules under the new request.
             td = self.task_map.find(existing)
+            asked = (pod.cpu_request, pod.memory_request, pod.net_bw_request)
             if td is not None and (
-                (td.resource_request.cpu_cores, td.resource_request.net_bw)
-                != (pod.cpu_request, pod.net_bw_request)
+                (td.resource_request.cpu_cores, td.resource_request.ram_cap,
+                 td.resource_request.net_bw) != asked
                 or any(getattr(td, k) != v for k, v in of_class.items())
             ):
-                td.resource_request.cpu_cores = pod.cpu_request
-                td.resource_request.net_bw = pod.net_bw_request
+                request = td.resource_request
+                request.cpu_cores, request.ram_cap, request.net_bw = asked
                 for k, v in of_class.items():
                     setattr(td, k, v)
                 rid = self.scheduler.task_bindings.get(existing)
@@ -459,6 +494,7 @@ class SchedulerService:
             self.job_id, self.job_map, self.task_map, name=pod.pod_id, scheduler=self.scheduler
         )
         td.resource_request.cpu_cores = pod.cpu_request
+        td.resource_request.ram_cap = pod.memory_request
         td.resource_request.net_bw = pod.net_bw_request
         for k, v in of_class.items():
             setattr(td, k, v)
@@ -831,12 +867,20 @@ class SchedulerService:
                 for rid in lost:
                     self._forget_machine(rid)
             # NOOP rounds and evictions leave runnable work behind; a clean
-            # full solve clears it. An idle sweep must not clear the flag —
-            # it did not schedule anything.
+            # full solve clears it, unless it bound pods and left others
+            # waiting under a model that offers a machine fewer places a
+            # round than it has slots (CostModeler.bounds_machine_intake:
+            # the Bindings moved the books, so the next round's offer is
+            # another and a quiet poll is the moment to make it). An idle
+            # sweep must not clear the flag — it did not schedule anything.
             if noop or lost or failed:
                 self.backlog_dirty = True
             elif solve:
-                self.backlog_dirty = False
+                self.backlog_dirty = bool(
+                    bound
+                    and self.scheduler.cost_model.bounds_machine_intake
+                    and self.scheduler.last_timing.unscheduled_by_rule
+                )
             self._g_pods.set(len(self.pod_to_task))
             self._g_bound.set(len(self.scheduler.task_bindings))
             self._g_machines.set(len(self.node_to_machine))
@@ -1356,6 +1400,12 @@ def build_arg_parser() -> argparse.ArgumentParser:
                     "type NAME, with CORES cores and the label ksched.io/platform=NAME "
                     "(which --cost-model whare reads); the shares sum to 1000; "
                     "instead of --cores-per-machine")
+    ap.add_argument("--fake-node-allocatable", type=parse_allocatable, default=(0, 0),
+                    metavar="CPU_MILLIS:MEM_MIB",
+                    help="what every fake machine can give to pods, e.g. 4000:32768 "
+                    "(4 CPUs, 32 Gi): NodeEvent.cpu_allocatable_millis / "
+                    "memory_allocatable_mib, which --cost-model k8s_requests fits "
+                    "the pods' CPU and memory requests into")
     ap.add_argument(
         "--cost-model",
         choices=[m.name.lower() for m in CostModelType],
@@ -1484,6 +1534,7 @@ def build_service(
         fake_racks=args.fake_racks,
         preemption=args.preemption,
         fake_machine_types=args.fake_machine_types,
+        fake_node_allocatable=args.fake_node_allocatable,
     )
 
 
@@ -1615,12 +1666,17 @@ def main(argv=None) -> int:
         ap.error(str(e))
     if args.machine_timeout > 0:
         svc.enable_heartbeats(machine_timeout_s=args.machine_timeout)
-    n = svc.init_topology(
-        fake_machines=args.num_machines if args.fake_machines else 0,
-        node_batch_timeout_s=args.node_batch_timeout,
-        cores_per_machine=args.cores_per_machine,
-        pus_per_core=args.pus_per_core,
-    )
+    try:
+        n = svc.init_topology(
+            fake_machines=args.num_machines if args.fake_machines else 0,
+            node_batch_timeout_s=args.node_batch_timeout,
+            cores_per_machine=args.cores_per_machine,
+            pus_per_core=args.pus_per_core,
+        )
+    except ValueError as e:
+        # a node the service refuses (--cost-model k8s_requests and no
+        # allocatable: --fake-node-allocatable, or the control plane's word)
+        ap.error(str(e))
     print(f"topology: {n} machines", file=sys.stderr)
 
     if args.podgen > 0:

@@ -24,6 +24,11 @@ class PodEvent:
     # Optional scheduling inputs (the reference's Pod carries only the
     # id; the rebuild forwards resource requests when the source has them)
     cpu_request: float = 0.0
+    #: memory the pod asks for, in MiB (`resources.requests.memory`);
+    #: beside `cpu_request` (in CPUs: 0.25 is 250m) it rides the task as
+    #: `TaskDescriptor.resource_request` (`cpu_cores`, `ram_cap`) and is
+    #: read by a model that fits pods by size (--cost-model k8s_requests)
+    memory_request: int = 0
     net_bw_request: int = 0
     task_class: int = 0
     #: the pod's priority as the cost model reads it (`spec.priority`
@@ -53,6 +58,12 @@ class NodeEvent:
     num_cores: int = 1
     pus_per_core: int = 1
     net_bw_capacity: int = 0
+    #: what the node can give to pods (`status.allocatable`): CPU in
+    #: millicores and memory in MiB; they ride the machine's
+    #: `ResourceDescriptor.capacity` (`cpu_cores`, `ram_cap`). 0: the
+    #: control plane did not say (only --cost-model k8s_requests reads them)
+    cpu_allocatable_millis: int = 0
+    memory_allocatable_mib: int = 0
     #: the node's labels (`metadata.labels`), as sorted (key, value)
     #: pairs so that the event stays hashable; they ride the machine's
     #: resource descriptor (`ResourceDescriptor.labels`)

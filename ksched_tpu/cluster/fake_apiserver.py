@@ -324,12 +324,19 @@ class FakeAPIServer:
     # -- convenience for tests/demos (the podgen/node side-door) -----------
 
     def add_node(self, name: str, cores: int = 1, pus_per_core: int = 1,
-                 unschedulable: bool = False, labels: Optional[dict] = None) -> None:
-        self._state.add_node(
-            name, {"cores": cores, "pus_per_core": pus_per_core}, unschedulable, labels
-        )
+                 unschedulable: bool = False, labels: Optional[dict] = None,
+                 cpu_millis: int = 0, memory_mib: int = 0) -> None:
+        """``cpu_millis`` / ``memory_mib``: the node's allocatable, as the
+        HTTP adapter reads it from `status.capacity`."""
+        capacity = {"cores": cores, "pus_per_core": pus_per_core}
+        if cpu_millis or memory_mib:
+            capacity.update(cpu_millis=cpu_millis, memory_mib=memory_mib)
+        self._state.add_node(name, capacity, unschedulable, labels)
 
     def create_pods(self, count: int, prefix: str = "pod", **spec) -> None:
+        """``spec`` is the pods' spec as the HTTP adapter reads it:
+        `task_class`, `cpu_request` (CPUs), `memory_request` (MiB),
+        `net_bw_request`."""
         self._state.add_pods(count, prefix, spec)
 
     def bindings(self) -> Dict[str, str]:

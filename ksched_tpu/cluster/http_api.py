@@ -231,6 +231,7 @@ class HTTPClusterAPI(ClusterAPI):
                 event = PodEvent(
                     pod_id=name,
                     cpu_request=float(spec.get("cpu_request", 0.0)),
+                    memory_request=int(spec.get("memory_request", 0)),
                     net_bw_request=int(spec.get("net_bw_request", 0)),
                     task_class=int(spec.get("task_class", 0)),
                 )
@@ -265,6 +266,8 @@ class HTTPClusterAPI(ClusterAPI):
                         num_cores=int(cap.get("cores", 1)),
                         pus_per_core=int(cap.get("pus_per_core", 1)),
                         net_bw_capacity=int(cap.get("net_bw", 0)),
+                        cpu_allocatable_millis=int(cap.get("cpu_millis", 0)),
+                        memory_allocatable_mib=int(cap.get("memory_mib", 0)),
                         labels=tuple(sorted(
                             (str(k), str(v))
                             for k, v in (item["metadata"].get("labels") or {}).items()

@@ -43,6 +43,9 @@ class CostModelType(enum.IntEnum):
     #: nor this: pod priority and preemption over slots
     #: (costmodels/k8s_priority.py)
     K8S_PRIORITY = 11
+    #: nor this: CPU and memory requests against a node's allocatable
+    #: (costmodels/k8s_requests.py)
+    K8S_REQUESTS = 12
 
 
 # The wildcard equivalence class every task points at in aggregate-style
@@ -139,6 +142,30 @@ class CostModeler(abc.ABC):
     #: global price update (JaxSolver.price_update_every), as it does
     #: under `--preemption`. False: the routes of a task cost alike.
     routes_differ_in_cost: bool = False
+
+    #: What a model says about itself where a machine takes, in one
+    #: round, fewer new tasks than it has free slots: pods that differ in
+    #: size (costmodels/k8s_requests.py), where the number that fit
+    #: together follows from the machine's books. ``machine_intake`` is
+    #: that bound, and the graph manager writes it on the arcs from the
+    #: machine node to its children (each child's free slots, over all
+    #: of them no more than the bound; GraphManager._write_machine_intake),
+    #: so the ECs together cannot send more; it reads the bound where it
+    #: refreshes the tree, and asks ``take_machine_intake_changes`` at
+    #: the end of every graph update for the machines whose bound moved
+    #: since the last one, so that no solve sees a stale bound. The
+    #: service reads the same fact in `_round_accounting`: a round that
+    #: bound pods and left others waiting was cut by the offer, and the
+    #: Bindings moved it, so it owes another round on a quiet poll. False:
+    #: every capacity below a machine is its free slots.
+    bounds_machine_intake: bool = False
+
+    #: The model fits what a task asks for into what its machine says it
+    #: can give (`ResourceDescriptor.capacity.cpu_cores`, `ram_cap`):
+    #: the service refuses, by name, a node whose control plane gave
+    #: neither (cli.SchedulerService.add_node), where it would else join
+    #: with no arc and its pods wait with no word. False: no arc reads them.
+    reads_machine_allocatable: bool = False
 
     #: The largest cost the model puts on any arc, where it states one
     #: (None: it states none). The scan-CSR rung scales costs by the
@@ -301,6 +328,26 @@ class CostModeler(abc.ABC):
         every machine in a pass that walked every node. 0 (the default):
         the model keeps no census. Read by the scheduler after `stats`."""
         return 0
+
+    def machine_intake(self, resource_id: int) -> int:
+        """Under ``bounds_machine_intake``: the most new tasks the machine
+        takes in the round, whatever their classes."""
+        raise NotImplementedError
+
+    def take_machine_intake_changes(self) -> List[int]:
+        """Under ``bounds_machine_intake``: the machines whose bound moved
+        since the last call, whatever moved it."""
+        return []
+
+    def round_books(self) -> Optional[Tuple[int, int, int]]:
+        """For a model that keeps books of what its machines have
+        reserved (costmodels/k8s_requests.py), as they stand at the
+        round's solve: machines whose books moved since the last call,
+        machines that some size class cannot use (a hole in their
+        column, or no room at all), and the sum of the machines' bounds.
+        None (the default): the model keeps no books. Read by the
+        scheduler after `graph_update`."""
+        return None
 
     def equiv_class_pref_arc_changes(self, ec: int) -> Optional[List[int]]:
         """The resources whose arc from ``ec`` may have changed (come,

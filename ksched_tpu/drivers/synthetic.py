@@ -10,7 +10,7 @@ root task plus spawned children under one JobDescriptor.
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from ..data import (
     JobDescriptor,
@@ -67,12 +67,17 @@ def build_machine_topology(
     parent: ResourceTopologyNodeDescriptor,
     machine_index: int = 0,
     labels: Optional[Dict[str, str]] = None,
+    allocatable: Tuple[int, int] = (0, 0),
 ) -> ResourceTopologyNodeDescriptor:
     """machine → core* → PU* subtree attached under parent (reference:
     schedule_iteration_test.go:257-331 createMachineNode). ``labels``
-    are the machine's (the node's, on the cluster API)."""
+    are the machine's (the node's, on the cluster API), ``allocatable``
+    its (CPU millicores, memory MiB), on its `capacity` before any cost
+    model's `add_machine` sees it."""
     machine_rd = make_resource_desc(ResourceType.MACHINE, f"machine_{machine_index}")
     machine_rd.labels = dict(labels or {})
+    machine_rd.capacity.cpu_cores = allocatable[0] / 1000.0
+    machine_rd.capacity.ram_cap = allocatable[1]
     machine = ResourceTopologyNodeDescriptor(
         resource_desc=machine_rd, parent_id=parent.resource_desc.uuid
     )
@@ -102,9 +107,10 @@ def add_machine(
     task_capacity_per_pu: int = 1,
     machine_index: int = 0,
     labels: Optional[Dict[str, str]] = None,
+    allocatable: Tuple[int, int] = (0, 0),
 ) -> ResourceTopologyNodeDescriptor:
     machine = build_machine_topology(
-        num_cores, pus_per_core, task_capacity_per_pu, root, machine_index, labels
+        num_cores, pus_per_core, task_capacity_per_pu, root, machine_index, labels, allocatable
     )
     _register_subtree(machine, resource_map)
     scheduler.register_resource(machine)

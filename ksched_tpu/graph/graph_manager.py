@@ -150,6 +150,10 @@ class GraphManager:
         #: where the model could only re-price its arcs at the price
         #: they have (_queue_res_turn)
         self._res_turns = not cost_model.resource_arc_costs_are_fixed
+        #: the model bounds what a machine takes in a round: the arcs
+        #: from a machine node to its children carry, together, no more
+        #: than the bound (_write_machine_intake)
+        self._intake_bounded = cost_model.bounds_machine_intake
         #: the model lists arcs of a task's own: that half of a task's
         #: turn gets a span (`pref_refresh`)
         self._pref_spans = cost_model.lists_task_preferences
@@ -343,6 +347,14 @@ class GraphManager:
                 turn += 1
         finally:
             runs.switch(None, turn)
+        if self._intake_bounded:
+            # the bounds that moved since the last update, whatever moved
+            # them (a larger pod than any before, a placement or an
+            # eviction outside a round): none is stale at the solve
+            for rid in self.cost_model.take_machine_intake_changes():
+                node = self.resource_to_node.get(rid)
+                if node is not None:
+                    self._write_machine_intake(node)
         self.tasks_visited = visited
         self.tasks_skipped = skipped
         self.res_nodes_visited = runs.res_nodes
@@ -496,10 +508,7 @@ class GraphManager:
         rd.num_running_tasks_below = running
         if rtnd.parent_id:
             curr = self.resource_to_node[resource_id_from_string(rd.uuid)]
-            parent_arc = self.cm.graph.get_arc(self.node_to_parent_node[curr.id], curr)
-            self.cm.change_arc_capacity(
-                parent_arc, self._capacity_to_parent(rd), ChangeType.CHG_ARC_BETWEEN_RES, "UpdateResourceTopologyDFS"
-            )
+            self._write_capacity_from_parent(curr, rd)
 
     def remove_resource_topology(self, rd: ResourceDescriptor) -> List[int]:
         """Reference: graph_manager.go:362-387. Returns removed PU node ids."""
@@ -890,6 +899,38 @@ class GraphManager:
             return rd.num_slots_below
         return rd.num_slots_below - rd.num_running_tasks_below
 
+    def _write_capacity_from_parent(self, curr: Node, rd: ResourceDescriptor) -> None:
+        """The refresh's write of the arc into ``curr`` (the node of
+        ``rd``), and, where ``curr`` is a machine whose intake the model
+        bounds, of the arcs out of it, once its children are done."""
+        parent = self.node_to_parent_node[curr.id]
+        if not (self._intake_bounded and parent.type == NodeType.MACHINE):
+            # (the arcs below a bounded machine are written in its own turn)
+            self.cm.change_arc_capacity(
+                self.cm.graph.get_arc(parent, curr), self._capacity_to_parent(rd),
+                ChangeType.CHG_ARC_BETWEEN_RES, "UpdateResourceTopologyDFS",
+            )
+        if self._intake_bounded and curr.type == NodeType.MACHINE:
+            self._write_machine_intake(curr)
+
+    def _write_machine_intake(self, machine: Node) -> None:
+        """The arcs from ``machine`` to its children under a model that
+        bounds what the machine takes in a round
+        (CostModeler.bounds_machine_intake): each child's free slots, and
+        over all of them no more than the bound, dealt to the children in
+        the order they were added. They are the machine's own path to
+        the sink: every arc INTO the machine shares them, so the ECs
+        together cannot send more than the bound."""
+        left = self.cost_model.machine_intake(machine.resource_id)
+        for arc in machine.outgoing.values():
+            child = arc.dst_node
+            if child.resource_id != 0:
+                capacity = min(self._capacity_to_parent(child.resource_descriptor), left)
+                left -= capacity
+                self.cm.change_arc_capacity(
+                    arc, capacity, ChangeType.CHG_ARC_BETWEEN_RES, "MachineIntake"
+                )
+
     def _add_resource_topology_dfs(self, rtnd: ResourceTopologyNodeDescriptor) -> None:
         """Reference: graph_manager.go:557-630."""
         rd = rtnd.resource_desc
@@ -918,6 +959,8 @@ class GraphManager:
             self._add_resource_topology_dfs(child)
             rd.num_slots_below += child.resource_desc.num_slots_below
             rd.num_running_tasks_below += child.resource_desc.num_running_tasks_below
+        if self._intake_bounded and node.type == NodeType.MACHINE:
+            self._write_machine_intake(node)
 
         if not rtnd.parent_id:
             if rd.type != ResourceType.COORDINATOR:
@@ -952,11 +995,7 @@ class GraphManager:
             rd.num_running_tasks_below += child.resource_desc.num_running_tasks_below
         if rtnd.parent_id:
             curr = self.resource_to_node[resource_id_from_string(rd.uuid)]
-            parent = self.node_to_parent_node[curr.id]
-            parent_arc = self.cm.graph.get_arc(parent, curr)
-            self.cm.change_arc_capacity(
-                parent_arc, self._capacity_to_parent(rd), ChangeType.CHG_ARC_BETWEEN_RES, "UpdateResourceTopologyDFS"
-            )
+            self._write_capacity_from_parent(curr, rd)
 
     def _update_resource_stats_up_to_root(
         self, curr: Node, cap_delta: int, slots_delta: int, running_delta: int

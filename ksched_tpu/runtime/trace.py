@@ -138,6 +138,13 @@ class RoundRecord:
     #: grouped, the rows of the dense problem and its padded columns
     ec_arcs_repriced: int = 0
     census_machines_dirty: int = 0
+    #: under a model that keeps books of reserved CPU and memory (zeros
+    #: elsewhere), at the solve: machines whose books moved since the
+    #: round before, machines some size class cannot use, and the sum of
+    #: what each machine takes this round
+    books_machines_dirty: int = 0
+    machines_gated: int = 0
+    columns_offered: int = 0
     audit_tasks_grouped: int = 0
     collapse_rows: int = 0
     collapse_cols: int = 0
@@ -348,6 +355,9 @@ class RoundTracer:
             unscheduled_by_rule=t.unscheduled_by_rule,
             ec_arcs_repriced=t.ec_arcs_repriced,
             census_machines_dirty=t.census_machines_dirty,
+            books_machines_dirty=t.books_machines_dirty,
+            machines_gated=t.machines_gated,
+            columns_offered=t.columns_offered,
             audit_tasks_grouped=t.audit_tasks_grouped,
             collapse_rows=t.collapse_rows,
             collapse_cols=t.collapse_cols,

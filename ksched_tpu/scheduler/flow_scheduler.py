@@ -164,6 +164,15 @@ class RoundTiming:
     #: (CostModeler.take_census_machines_dirty; 0 for a model without one)
     ec_arcs_repriced: int = 0
     census_machines_dirty: int = 0
+    #: under a model that keeps books of what its machines have reserved
+    #: (CostModeler.round_books; zeros elsewhere), at the solve: machines
+    #: whose books a bind or an unbind moved since the last round read
+    #: them, machines that some size class cannot use (holes in the dense
+    #: problem's rows), and the sum over machines of what each takes this
+    #: round
+    books_machines_dirty: int = 0
+    machines_gated: int = 0
+    columns_offered: int = 0
     #: the dense problem of a round the collapse answered (zeros on any
     #: other rung; PlacementSolver.collapse_shape): the tasks its rows
     #: pass grouped, the rows they made, and the transport's padded
@@ -596,6 +605,13 @@ class FlowScheduler:
                 sp.set("ec_arcs_changed", timing.ec_arcs_changed)
                 sp.set("ec_arcs_repriced", timing.ec_arcs_repriced)
                 sp.set("ec_chain_arcs_changed", timing.ec_chain_arcs_changed)
+                books = self.cost_model.round_books()
+                if books is not None:
+                    (
+                        timing.books_machines_dirty, timing.machines_gated,
+                        timing.columns_offered,
+                    ) = books
+                    sp.set("books_machines_dirty", timing.books_machines_dirty)
             timing.graph_update_s = sp.dur_s
             timing.tasks_unpinned = self.gm.unpinned_running_tasks
             timing.pref_arcs_live = self.gm.pref_arcs_live

@@ -99,6 +99,19 @@ def _seeded_rng():
 #: in a round that patched, and at most the arcs written change".
 #: `tests/test_wharemap_rehearsal.py` holds every other assertion of that
 #: case, on the same run, and that sentence round by round.
+#:
+#: PR 49 (`k8s-5000-requests`: CPU and memory requests, the source's one pod
+#: size, so the scan-CSR rung) appended a configuration, a twelfth cell,
+#: `pods/by_request.py`, four entries after PR 46's six, and its cell's name to
+#: twenty lists, as ISSUE 49 asks. `test_benchmark_plan_refit.py` pins the five
+#: plan lists to their cells case by case and `test_benchmark_quincy.py` to
+#: "theirs, then quincy's"; `test_benchmark_runnable_scan.py` its
+#: list; `test_benchmark_seams.py` draws a case for every cell and wants
+#: `class_only`; `test_benchmark_quincy.py` lists three modules under
+#: `benchmarks/pods/`; `test_benchmark_wharemap.py` pins each of its six entries
+#: to its cell alone and to the end of `per_layer`. `test_benchmark_requests.py`
+#: holds what stays true of each: the lists as "what they had, then this cell",
+#: the four modules' purity and stamps, the six entries before the four new ones.
 _STALE = {
     "test_benchmark_seams.py::test_class_only_is_the_old_expression_and_the_same_seed_"
     "draws_the_same_plan[k8s-5000-preemption.rollout-": "PR 38 brings pods/by_role.py",
@@ -142,6 +155,18 @@ _STALE = {
     "cells": "PR 46 appended its cell to the list",
     "test_benchmark_runnable_scan.py::test_a_cell_loads_it_by_name_if_it_is_listed_and_not_"
     "otherwise[gtrace-12500-wharemap.trickle]": "PR 46's cell is on the list",
+    "test_benchmark_plan_refit.py::test_a_cell_loads_the_five_by_name_if_it_is_listed_and_"
+    "none_otherwise[k8s-5000-requests.trickle]": "PR 49's cell is on the five plan_* lists",
+    "test_benchmark_quincy.py::test_what_stays_true_of_the_seven_lists_two_earlier_tests_pin":
+        "PR 49 appended its cell to the five plan_* lists",
+    "test_benchmark_runnable_scan.py::test_a_cell_loads_it_by_name_if_it_is_listed_and_not_"
+    "otherwise[k8s-5000-requests.trickle]": "PR 49's cell is on the list",
+    "test_benchmark_seams.py::test_class_only_is_the_old_expression_and_the_same_seed_"
+    "draws_the_same_plan[k8s-5000-requests.trickle-": "PR 49 brings pods/by_request.py",
+    "test_benchmark_quincy.py::test_a_pods_module_stamps_each_event_as_it_is_made_and_draws_"
+    "nothing_from_the_frameworks_rng[": "PR 49 brings a fourth module under pods/",
+    "test_benchmark_wharemap.py::test_each_metric_it_brings_is_an_entry_with_its_file_for_"
+    "this_cell_alone[": "PR 49 appended four entries after the six, and its cell to one of them",
     "test_benchmark_wharemap.py::test_the_traced_rehearsal_is_correct_and_every_metric_reads_"
     "a_number": "PR 48: `ec_arcs_repriced` is visited ECs x `census_machines_dirty` in a round "
                 "that patched, and nearly every arc written changes",

@@ -132,3 +132,55 @@ def test_seen_pods_reconciled_and_recreated_pod_resurfaces(server):
         assert [p.pod_id for p in batch] == ["pod_0"]  # re-surfaced
     finally:
         api.close()
+
+
+def test_requests_and_allocatable_ride_the_http_adapter_into_the_requests_model(server):
+    """`cpu_request` and `memory_request` of a pod's spec and `cpu_millis` /
+    `memory_mib` of a node's capacity reach the descriptors, and
+    `--cost-model k8s_requests` fits the one into the other: three 4 GiB
+    pods on two nodes of 8 GiB bind two a node at most."""
+    from ksched_tpu.costmodels import CostModelType
+
+    api = HTTPClusterAPI(server.base_url, poll_interval_s=0.05)
+    try:
+        for name in ("node_a", "node_b"):
+            server.add_node(name, cpu_millis=2000, memory_mib=8192)
+        server.create_pods(3, cpu_request=0.25, memory_request=4096)
+        nodes = api.get_node_batch(timeout_s=0.3)
+        assert [(n.cpu_allocatable_millis, n.memory_allocatable_mib) for n in nodes] == [(2000, 8192)] * 2
+        pods = api.get_pod_batch(timeout_s=0.3)
+        assert [(p.cpu_request, p.memory_request) for p in pods] == [(0.25, 4096)] * 3
+        svc = SchedulerService(api, max_tasks_per_pu=10, cost_model=CostModelType.K8S_REQUESTS)
+        for node in nodes:
+            svc.add_node(node)
+        svc.run_once(pods)
+        while svc.backlog_dirty:
+            svc.run_round([], solve=True)
+        books = sorted(svc.scheduler.cost_model.books().values())
+        assert books == [(250, 4096, 1), (500, 8192, 2)]
+        td = svc.task_map.find(svc.pod_to_task["pod_0"])
+        assert (td.resource_request.cpu_cores, td.resource_request.ram_cap) == (0.25, 4096)
+        # a node whose control plane says nothing of its allocatable surfaces with zeros,
+        # and the service refuses it by name where the model fits requests into them: it
+        # is not taken in with no arc, its pods waiting at 500 for ever
+        server.add_node("node_c")
+        (bare,) = api.get_node_batch(timeout_s=0.3)
+        assert (bare.cpu_allocatable_millis, bare.memory_allocatable_mib) == (0, 0)
+        with pytest.raises(ValueError, match=r"node node_c: .*allocatable \(0, 0\)"):
+            svc.add_node(bare)
+        assert "node_c" not in svc.node_to_machine
+        assert len(svc.scheduler.cost_model.books()) == 2
+    finally:
+        api.close()
+
+
+def test_a_model_that_reads_no_allocatable_takes_a_node_that_says_none(server):
+    api = HTTPClusterAPI(server.base_url, poll_interval_s=0.05)
+    try:
+        server.add_node("node_c")
+        (bare,) = api.get_node_batch(timeout_s=0.3)
+        svc = SchedulerService(api, max_tasks_per_pu=10)
+        svc.add_node(bare)
+        assert "node_c" in svc.node_to_machine
+    finally:
+        api.close()

@@ -341,7 +341,7 @@ def test_the_constants_meet_the_two_conditions_and_fit_the_scaled_int32():
 
 def test_the_model_is_registered_and_its_docstring_holds_the_semantics():
     assert MODEL_REGISTRY[CostModelType.K8S_PRIORITY] is K8sPriorityCostModel
-    assert int(CostModelType.K8S_PRIORITY) == 11 and len(MODEL_REGISTRY) == 12
+    assert int(CostModelType.K8S_PRIORITY) == 11 and len(MODEL_REGISTRY) == 13
     assert "k8s_priority" in cli.build_arg_parser().format_help()
     assert K8sPriorityCostModel.pinned_tasks_are_inert
     assert K8sPriorityCostModel.resource_arc_costs_are_fixed

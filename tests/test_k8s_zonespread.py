@@ -503,7 +503,7 @@ def test_the_http_watch_reads_metadata_labels():
 
 def test_the_model_is_registered_and_its_docstring_holds_the_equations():
     assert MODEL_REGISTRY[CostModelType.K8S_ZONESPREAD] is K8sZoneSpreadCostModel
-    assert int(CostModelType.K8S_ZONESPREAD) == 10 and len(MODEL_REGISTRY) == 12
+    assert int(CostModelType.K8S_ZONESPREAD) == 10 and len(MODEL_REGISTRY) == 13
     assert "k8s_zonespread" in cli.build_arg_parser().format_help()
     assert (K8sZoneSpreadCostModel.CLUSTER_AGG_COST, K8sZoneSpreadCostModel.UNSCHEDULED_COST) == (
         EC_COST, UNSCHEDULED_COST,
