@@ -129,6 +129,13 @@ def _check_one(spec: ProgramSpec, tc: TraceCall, exact_collectives: bool = True,
         if rep.scatter_eqns:
             _fail(spec, f"scatter primitives {rep.scatter_eqns} {where} but policy "
                         "is 'forbidden' (TPU serializes scatter-adds)")
+    elif spec.scatter_policy == "active-set":
+        if len(rep.scatter_eqns) != spec.scatters:
+            _fail(spec, f"{len(rep.scatter_eqns)} scatter primitives {where}, the "
+                        f"active-set superstep was admitted {spec.scatters}")
+        faults = jc.active_set_faults(trace_call(spec, tc, **overrides))
+        if faults:
+            _fail(spec, f"active-set policy broken {where}: {'; '.join(faults)}")
     else:  # scoped-exempt / chaos-only must actually scatter
         if not rep.scatter_eqns:
             _fail(spec, f"scatter policy {spec.scatter_policy!r} is VACUOUS {where}: "

@@ -447,7 +447,7 @@ def slot_stable_entry_cap(m_pad: int) -> int:
 
 
 def trace_jax_slot_stable(n_raw: int, m_raw: int, seed: int = 0,
-                          telemetry_cap: int = 0):
+                          telemetry_cap: int = 0, active_rows_share: int = 0):
     """The slot-stable variant of the CSR solve: entry rows live in
     fixed per-node regions with slack and liveness rides the sign
     column (graph/slot_plan.py), so the residual formula masks dead
@@ -457,17 +457,82 @@ def trace_jax_slot_stable(n_raw: int, m_raw: int, seed: int = 0,
     from ..solver.jax_solver import _solve_mcmf
 
     n, m = bucketed_sizes(n_raw, m_raw)
+    e = slot_stable_entry_cap(m)
     fn = functools.partial(
         _solve_mcmf, alpha=8, max_supersteps=4096, tighten_sweeps=32,
         telemetry_cap=telemetry_cap, slot_stable=True,
+        # `trace_jax_active`: caps of 4 nodes and a share of the rows
+        active_set=(4, e // active_rows_share) if active_rows_share else None,
     )
-    e = slot_stable_entry_cap(m)
     return jax.make_jaxpr(fn)(
         _sds((m,)), _sds((m,)), _sds((n,)), _sds((m,)), _sds(()),
         _sds((e,)), _sds((e,)), _sds((e,)), _sds((e,)), _sds((e,)),
         _sds((e,), jnp.bool_), _sds((2 * m,)),
         _sds((n,)), _sds((n,)), _sds((n,), jnp.bool_),
     )
+
+
+def trace_jax_active(n_raw: int, m_raw: int, seed: int = 0,
+                     telemetry_cap: int = 0):
+    """The program `JaxSolver` dispatches on a slot-stable plan since
+    PR 50: `trace_jax_slot_stable`'s with the active-set superstep
+    traced beside the dense one (`active_set`: caps of 4 nodes and a
+    sixteenth of the rows here, where `active_set_caps` would find the
+    plan too small to bother). Its scatter-adds are admitted by a chip
+    reading and counted (program_registry, "active-set")."""
+    from ..solver.jax_solver import _ACTIVE_ROWS_SHARE
+
+    return trace_jax_slot_stable(
+        n_raw, m_raw, seed, telemetry_cap, active_rows_share=_ACTIVE_ROWS_SHARE
+    )
+
+
+def active_set_branches(closed):
+    """(dense, sparse): the two branches of the `cond` by which a
+    superstep of an `active_set` program chooses its form, found as the
+    one `cond` nested in a branch of the phase loop's `cond`; None for
+    a program that holds no such choice."""
+    for eqn, _in_pallas, in_loop in walk_eqns(closed.jaxpr):
+        if not in_loop or eqn.primitive.name != "cond":
+            continue
+        for branch in eqn.params["branches"]:
+            inner = [e for e in branch.jaxpr.eqns if e.primitive.name == "cond"]
+            if len(inner) == 1 and len(inner[0].params["branches"]) == 2:
+                dense, sparse = (b.jaxpr for b in inner[0].params["branches"])
+                return dense, sparse
+    return None
+
+
+def active_set_faults(closed) -> List[str]:
+    """What breaks the "active-set" scatter policy in a traced program:
+    a scatter outside the sparse branch, or a gather, prefix sum or
+    top_k in it whose result is as long as the plan (the longest array
+    the dense branch gathers)."""
+    found = active_set_branches(closed)
+    if found is None:
+        return ["no superstep chooses between two forms"]
+    dense, sparse = found
+
+
+    def eqns(jaxpr):
+        return [e for e, _p, _l in walk_eqns(jaxpr)]
+
+    faults = []
+    inside = sum(e.primitive.name.startswith("scatter") for e in eqns(sparse))
+    total = sum(e.primitive.name.startswith("scatter") for e in eqns(closed.jaxpr))
+    if inside != total:
+        faults.append(f"{total - inside} scatter(s) outside the sparse branch")
+    rows = max(
+        e.outvars[0].aval.shape[0] for e in eqns(dense) if e.primitive.name == "gather"
+    )
+    for e in eqns(sparse):
+        name = e.primitive.name
+        if name in ("gather", "cumsum", "cummax", "cummin", "top_k") or name.startswith(
+            "reduce_window"
+        ):
+            if e.outvars[0].aval.shape and e.outvars[0].aval.shape[0] >= rows:
+                faults.append(f"{name} over {e.outvars[0].aval.shape} in the sparse branch")
+    return faults
 
 
 def trace_plan_apply(

@@ -55,7 +55,14 @@ from typing import Dict, Mapping, Optional, Set, Tuple
 #: - "chaos-only": allowed to scatter, never dispatched in production
 #:   (the corruption-injection poison used to prove the fingerprint
 #:   audit catches bit flips).
-SCATTER_POLICIES = ("forbidden", "scoped-exempt", "chaos-only")
+#: - "active-set": a solve program that holds scan-CSR's active-set
+#:   superstep (solver/jax_solver.py `active_superstep`): exactly
+#:   `ProgramSpec.scatters` scatter-adds, every one inside the branch a
+#:   superstep takes when the nodes that hold excess and their rows fit
+#:   the caps, where an update costs ~9 ns on a v5e (PERF.md section 6,
+#:   PR 50) and they are the whole write-back; that branch holds no
+#:   gather, cumsum or top_k over the plan's rows.
+SCATTER_POLICIES = ("forbidden", "scoped-exempt", "chaos-only", "active-set")
 
 #: hash-stability classes:
 #: - "pow2-bucket": raw sizes sharing a pow2 padding bucket trace
@@ -157,6 +164,8 @@ class ProgramSpec:
     #: extra shape buckets the dtype/scatter/gather checks also sweep
     extra: Tuple[TraceCall, ...] = ()
     scatter_policy: str = "forbidden"
+    #: under "active-set": the exact number of scatter primitives
+    scatters: Optional[int] = None
     dtype_policy: str = "int32"  # the only policy: no 64-bit anywhere
     collectives: Optional[CollectiveBudget] = None
     donation: Optional[DonationSpec] = None
@@ -182,6 +191,8 @@ class ProgramSpec:
             raise ValueError(f"{self.name}: bad scatter policy {self.scatter_policy!r}")
         if self.dtype_policy != "int32":
             raise ValueError(f"{self.name}: bad dtype policy {self.dtype_policy!r}")
+        if (self.scatter_policy == "active-set") != (self.scatters is not None):
+            raise ValueError(f"{self.name}: `scatters` goes with the active-set policy")
 
     @property
     def site_name(self) -> str:
@@ -227,6 +238,13 @@ _RECORD_GRAPH_CROSS = ((call(3, 2, n_raw=20, m_raw=100), call(3, 2, n_raw=20, m_
 #: had 24 / 15 (11 for the refit), all of scalars.
 _CSR_GATHERS = GatherBudget(hbm_loop=12, oneshot=9)
 _CSR_REFIT_GATHERS = GatherBudget(hbm_loop=12, oneshot=7)
+#: with the active-set superstep traced beside the dense one (its
+#: branch of the superstep's `cond`): seven more in the loop, none of
+#: them over the plan's rows (at the active nodes their regions, price
+#: and excess; at the compacted rows their node's values, the row's
+#: cost / far end / partner / residual, the far end's price, the
+#: prefix base; at the nodes' first and last compacted rows)
+_CSR_ACTIVE_GATHERS = GatherBudget(hbm_loop=19, oneshot=9)
 
 #: every collective family jaxpr_contracts counts — "forbid all"
 _ALL_COLLECTIVES = ("psum", "pmin", "pmax", "all_gather", "all_to_all", "ppermute")
@@ -276,6 +294,25 @@ _SPECS = (
         gathers=_CSR_REFIT_GATHERS,
         notes="the production event-path program: refit ON TOP of the "
         "slot-stable plan",
+    ),
+    ProgramSpec(
+        name="csr_solve_active", module="ksched_tpu.solver.jax_solver", kind="solve",
+        tracer="trace_jax_active", trace=call(20, 100), site="csr_solve",
+        extra=(call(12, 40), call(40, 220)),
+        telemetry_knob="telemetry_cap",
+        hash_stability=HashStability(
+            "pow2-bucket", same=((call(20, 100), call(24, 110)),),
+            cross=((call(20, 100), call(20, 300)),),
+        ),
+        distinct_from=("csr_solve_slot",),
+        scatter_policy="active-set", scatters=4,
+        gathers=_CSR_ACTIVE_GATHERS,
+        collectives=CollectiveBudget(forbidden=_ALL_COLLECTIVES),
+        notes="what JaxSolver dispatches (PR 50): the slot-stable program with "
+        "the active-set superstep beside the dense one, chosen per superstep "
+        "from the loop state; its four scatter-adds (the span marks, r, "
+        "excess, p) are the chip reading's, and the dense branch is "
+        "csr_solve_slot's superstep",
     ),
     ProgramSpec(
         name="stacked_solve", module="ksched_tpu.solver.jax_solver", kind="solve",

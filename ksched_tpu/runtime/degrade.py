@@ -268,6 +268,14 @@ class DegradingSolver(FlowSolver):
         b = self._rungs[self.last_rung][1]
         return getattr(b, "last_iterations", 0) or getattr(b, "last_supersteps", 0)
 
+    @property
+    def last_sparse_supersteps(self) -> int:
+        """Of that rung's supersteps, the ones that took scan-CSR's
+        active-set form (0 on a rung that has no such form)."""
+        if self.last_rung < 0:
+            return 0
+        return getattr(self._rungs[self.last_rung][1], "last_sparse_supersteps", 0)
+
 
 def build_degradation_ladder(
     configured: FlowSolver,

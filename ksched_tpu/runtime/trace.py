@@ -35,6 +35,10 @@ class RoundRecord:
     phases_ms: Dict[str, float]
     num_scheduled: int = 0
     solver_work: int = 0  # supersteps / iterations / augmentations
+    #: of `solver_work`, the supersteps the scan-CSR rung ran over the
+    #: rows of the nodes that held excess alone (JaxSolver.
+    #: last_sparse_supersteps; 0 on any other rung)
+    supersteps_sparse: int = 0
     nodes_added: int = 0
     arcs_added: int = 0
     arcs_changed: int = 0
@@ -321,6 +325,7 @@ class RoundTracer:
             num_scheduled=num_scheduled,
             solver_work=getattr(backend, "last_iterations", 0)
             or getattr(backend, "last_supersteps", 0),
+            supersteps_sparse=getattr(backend, "last_sparse_supersteps", 0),
             nodes_added=stats.nodes_added if stats else 0,
             arcs_added=stats.arcs_added if stats else 0,
             arcs_changed=stats.arcs_changed if stats else 0,

@@ -102,6 +102,11 @@ class FlowSolver(abc.ABC):
             )
             if work:
                 sp.set("supersteps", work)
+            sparse = getattr(self, "last_sparse_supersteps", None)
+            if sparse is not None:
+                # of those, the ones over the active nodes' rows alone
+                # (scan-CSR's active-set superstep)
+                sp.set("supersteps_sparse", int(sparse))
             tel = getattr(self, "last_telemetry", None)
             if tel is not None:
                 # a backend that timed its own kernel call (AutoSolver's

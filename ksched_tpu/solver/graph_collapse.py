@@ -815,6 +815,8 @@ class AutoSolver(FlowSolver):
         self.last_path = ""
         self.last_refusal = ""
         self.last_supersteps = 0
+        #: of those, scan-CSR's active-set supersteps (0 on another path)
+        self.last_sparse_supersteps = 0
         #: the dense problem of the last solve, where the collapse took
         #: it: (tasks the rows pass grouped, rows, padded columns of the
         #: transport tile); zeros where it refused
@@ -872,6 +874,7 @@ class AutoSolver(FlowSolver):
     def solve(self, problem) -> FlowResult:
         self.last_solve_span = None
         self.last_collapse_shape = (0, 0, 0)
+        self.last_sparse_supersteps = 0
         with span("collapse_audit") as sp:
             collapse, reason = try_collapse(problem)
             sp.set("collapsed", collapse is not None)
@@ -895,6 +898,7 @@ class AutoSolver(FlowSolver):
                 else getattr(self.csr, "last_iterations", 0)
             )
             self.last_telemetry = getattr(self.csr, "last_telemetry", None)
+            self.last_sparse_supersteps = getattr(self.csr, "last_sparse_supersteps", 0)
             return res
         self.last_path, self.last_refusal = "dense", ""
         return self._solve_dense(problem, collapse)

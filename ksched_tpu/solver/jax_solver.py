@@ -19,11 +19,38 @@ solved by a synchronous Goldberg–Tarjan cost-scaling push-relabel:
 
 TPU-shaped implementation notes:
 
-- NO scatters. TPU serializes scatter-adds (a 64k segment_sum measured
-  ~68 ms), so all segment reductions are expressed over a host-prebuilt
-  CSR ordering of the residual entries as cumsum + gather
+- Scatters only where they are few. A DENSE superstep has none: all
+  its segment reductions are expressed over a host-prebuilt CSR
+  ordering of the residual entries as cumsum + gather
   (diff-at-row-boundaries) and a segmented max via
-  lax.associative_scan.
+  lax.associative_scan. The rule came from a round-5 reading ("TPU
+  serializes scatter-adds": a 64k segment_sum ~68 ms, ~1 us an
+  update) on a chip and a jax that are gone. Read again on a v5e under
+  jax 0.9.0 (2026-10-02, PERF.md section 6, PR 50): `.at[idx].add`
+  into s32[524288] or s32[262144] costs ~9 ns an update (4,096
+  updates 37 us, 16,384 ~130 us; 64 are lost in the launch), `.set`
+  ~5 ns; `unique_indices` / `indices_are_sorted` change nothing, and
+  updates sent out of range under `mode="drop"` cost no more than the
+  rest. What stays dear is a scatter of as many updates as a table
+  has rows (`jnp.nonzero(size=K)`'s bincount: 1.85 ms over 262,144
+  nodes, whatever K). So the ACTIVE-SET superstep (below) writes its
+  few changed rows back with four scatter-adds, and finds its nodes
+  with a `top_k`, not a `nonzero`.
+- The superstep has two forms, chosen per superstep inside the loop
+  from what the loop state shows (`active_set_fits`). A served round
+  re-wires an arc, restarts fresh, and then moves a handful of units
+  down the tree: after the first two supersteps one node holds excess
+  at a time (the class node, then a machine, its core, its PU), and
+  the dense form sweeps every plan row for it: 524,288 rows ten times
+  to move 28 pods. `active_superstep` computes the same integers over
+  the compacted rows of the nodes that hold excess (the regions of at
+  most `_ACTIVE_NODES` nodes, within `1 / _ACTIVE_ROWS_SHARE` of the
+  plan), so its cost follows the work and not the plan. A superstep
+  outside the caps (a fill, the two bulk supersteps of a trickle round
+  on a large cluster) runs the dense form, op for op what it was.
+  `JaxSolver` asks for both forms (`active_set_caps`); the stacked
+  lanes (`stacked_solve_fn`: under vmap a `cond` is a select and both
+  would run), the sharded program and every direct caller keep the one.
 - Gathers are the price (78% of the device time before PR 29), so the
   loop does as few as the algorithm needs and does each as a gather of
   ROWS. Measured on a v5e (PERF.md section 6, PR 29): XLA's gather of
@@ -245,8 +272,42 @@ MAX_SCALED_PATH_COST = _BIG_D
 #: 26-42 at 8, 20-91 at 16 (CPU runs, PR 38)
 PREEMPTION_PRICE_UPDATE_EVERY = 8
 
+#: the caps of the active-set superstep (`_solve_mcmf`, `active_set`),
+#: from readings on a v5e under jax 0.9.0 (PERF.md section 6, PR 50;
+#: one node active a superstep, 200 supersteps a solve, ms a superstep,
+#: dense against sparse): 11.0 / 2.1 at 524,288 plan rows, 5.5 / 1.25
+#: at 262,144, 1.2 / 0.6 at 65,536. A sparse superstep costs ~0.45 ms
+#: whatever the plan (a `top_k` over the nodes, a dozen launches) and
+#: then follows its caps:
+#: the most nodes that may hold excess (+0.1 ms from 4,096 to 8,192 at
+#: 524,288 rows: their reads, span marks and write-back; 8,192 holds the
+#: ~5,030 PUs and arrivals of a 5,000-node cluster's two bulk
+#: supersteps)
+_ACTIVE_NODES = 8_192
+#: and the most plan rows their regions may span, as a share of the
+#: plan: ~55 ns a compacted row (one gather of the row's four values at
+#: 4.3 ns, the far end's price, two reads by owner, three scatter
+#: updates at ~7 ns each), so a sixteenth of the plan costs a fifth of
+#: a dense superstep and an eighth would cost a third. At 524,288 rows
+#: it is 32,768: `gtrace-12500-quincy`'s cluster aggregator (12,500
+#: arcs and a quarter of slack) with room to spare
+_ACTIVE_ROWS_SHARE = 16
+#: plans below this many rows keep the one form: the two costs cross
+#: near 30,000 rows (a dense superstep ~18 ns a plan row, read down from
+#: 65,536; nothing smaller was read on the chip)
+_ACTIVE_MIN_PLAN_ROWS = 32_768
 
-@functools.partial(jax.jit, static_argnames=("alpha", "max_supersteps", "tighten_sweeps", "telemetry_cap", "use_warm_p", "slot_stable", "price_update_every"))  # kschedlint: program=csr_solve
+
+def active_set_caps(plan_rows: int) -> Optional[Tuple[int, int]]:
+    """`_solve_mcmf`'s `active_set` for a plan of this many rows: the
+    caps within which a superstep takes the sparse form, or None where
+    the plan is too small for one to pay."""
+    if plan_rows < _ACTIVE_MIN_PLAN_ROWS:
+        return None
+    return _ACTIVE_NODES, plan_rows // _ACTIVE_ROWS_SHARE
+
+
+@functools.partial(jax.jit, static_argnames=("alpha", "max_supersteps", "tighten_sweeps", "telemetry_cap", "use_warm_p", "slot_stable", "price_update_every", "active_set"))  # kschedlint: program=csr_solve
 def _solve_mcmf(
     cap, cost, supply, flow0, eps_init,
     s_arc, s_sign, s_src, s_dst, s_segstart, s_isstart, inv_order,
@@ -259,6 +320,7 @@ def _solve_mcmf(
     use_warm_p: bool = False,
     slot_stable: bool = False,
     price_update_every: int = 0,
+    active_set: Optional[Tuple[int, int]] = None,
 ):
     """telemetry_cap > 0 appends a superstep-indexed int32 telemetry
     ring [telemetry_cap, SOLTEL_WIDTH] to the returned tuple (row
@@ -322,6 +384,17 @@ def _solve_mcmf(
     excess wandering; CHANGES.md, PR 38). The default (0) traces no op
     of it: the program of every service without preemption is the one
     it was.
+
+    active_set=(K, R) traces the superstep's second form beside the
+    first (module docstring; `active_set_caps`): a superstep whose
+    nodes with excess > 0 number at most K and whose regions span at
+    most R plan rows runs `active_superstep`, any other `superstep`.
+    The state, the results and every soltel row are what they were,
+    bit for bit (tests/test_active_superstep.py); the supersteps that
+    took the sparse form are counted and returned sixth, before the
+    telemetry ring. The default (None) traces no op of it and returns
+    the five outputs it always did: the program of the stacked lanes,
+    of `__graft_entry__` and of every caller that is not `JaxSolver`.
 
     Discharging DISPLACED excess through carried flow is structurally
     slow here, and no price seeding fixes it (measured, r12): with the
@@ -464,6 +537,95 @@ def _solve_mcmf(
         )
         return new_r, new_excess, new_p, aux
 
+    if active_set:
+        k_cap, r_cap = min(active_set[0], supply.shape[0]), active_set[1]
+
+        def active_set_fits(excess):
+            """Whether this superstep's work lies within the caps: the
+            nodes that hold excess, and the plan rows of their regions
+            (dead rows counted: they ride along, inert, as in `superstep`).
+            Two sums over the nodes."""
+            act = excess > 0
+            extent = jnp.where(act & node_nonempty, node_last - node_first + 1, i32(0))
+            return (jnp.sum(act.astype(i32)) <= k_cap) & (jnp.sum(extent) <= r_cap)
+
+        def active_superstep(r, excess, p, eps):
+            """`superstep` over the rows of the nodes that hold excess and
+            nothing else: the same integers by the same formulas, read at
+            `r_cap` compacted rows and `k_cap` nodes and written back where
+            they change (only called where `active_set_fits`). A region's
+            rows keep their plan order and the regions follow each other in
+            node order without a gap, so the compacted rows are a packed
+            plan of their own: segment k starts where k - 1 ended, and the
+            front-to-back allocation gives each row the `delta` it had."""
+            n, e = excess.shape[0], r.shape[0]
+
+            # the active nodes in node order (the k_cap lowest ids: top_k of
+            # a key that falls with the id), and where each one's region
+            # lands among the compacted rows
+            key, _ = lax.top_k(
+                jnp.where(excess > 0, n - jnp.arange(n, dtype=i32), i32(0)), k_cap
+            )
+            held = key > 0
+            ids = jnp.where(held, n - key, i32(0))
+            first_k, last_k, nonempty_k, p_k, e_k = _rows(
+                ids, node_first, node_last, node_nonempty.astype(i32), p, excess
+            )
+            nonempty_k = nonempty_k != 0
+            ext = jnp.where(held & nonempty_k, last_k - first_k + 1, i32(0))
+            end = jnp.cumsum(ext)
+            start = end - ext
+
+            # slot s belongs to the node whose span holds it: the nodes
+            # whose span ends at or before s, counted
+            slot = jnp.arange(r_cap, dtype=i32)
+            owner = jnp.cumsum(jnp.zeros(r_cap, i32).at[end].add(1, mode="drop"))
+            owner = jnp.minimum(owner, k_cap - 1)
+            used = slot < end[-1]
+            shift, seg0, p_src, e_at = _rows(owner, first_k - start, start, p_k, e_k)
+            row = jnp.where(used, slot + shift, i32(0))
+            cost_r, dst_r, partner_r, r_at = _rows(row, s_cost, s_dst, s_partner, r)
+            r_at = jnp.where(used, r_at, i32(0))
+            (p_dst,) = _rows(dst_r, p)
+
+            # `superstep`'s allocation, relabel candidate and per-node sums
+            rc = cost_r + p_src - p_dst
+            r_adm = jnp.where((r_at > 0) & (rc < 0), r_at, i32(0))
+            cum = jnp.cumsum(r_adm)
+            cand = jnp.where(r_at > 0, p_dst - cost_r, -_BIG)
+            adm, sum_r, best = _seg_ends(
+                jnp.minimum(start, r_cap - 1), jnp.clip(end - 1, 0, r_cap - 1), ext > 0,
+                (r_adm, r_at), (cum, jnp.cumsum(r_at)),
+                _seg_scan(jnp.maximum, cand, slot == seg0),
+            )
+            (base,) = _rows(owner, jnp.cumsum(adm) - adm)
+            delta = jnp.clip(e_at - (cum - r_adm - base), 0, r_adm)
+            pushed = jnp.where(held, jnp.clip(e_k, 0, adm), i32(0))
+            best = jnp.where(ext > 0, best, -_BIG)
+            relabel = held & (pushed == 0) & (sum_r > 0)
+
+            # write back: a push leaves its row and arrives at its partner
+            # and at its far end (far ends repeat); an active node gives
+            # what it pushed and, relabelled, takes its new price. A row
+            # that pushed nothing goes out of range and is dropped.
+            moved = delta > 0
+            new_r = r.at[
+                jnp.concatenate([jnp.where(moved, row, i32(e)), jnp.where(moved, partner_r, i32(e))])
+            ].add(jnp.concatenate([-delta, delta]), mode="drop")
+            new_excess = excess.at[
+                jnp.concatenate([jnp.where(pushed > 0, ids, i32(n)), jnp.where(moved, dst_r, i32(n))])
+            ].add(jnp.concatenate([-pushed, delta]), mode="drop")
+            new_p = p.at[jnp.where(relabel, ids, i32(n))].add(best - eps - p_k, mode="drop")
+            if not telemetry_cap:
+                return new_r, new_excess, new_p, ()
+            aux = (
+                jnp.sum(pushed),
+                jnp.sum(relabel.astype(i32)),
+                jnp.sum(((s_sign > 0) & (r == 0)).astype(i32)),
+                jnp.sum((r_adm > 0).astype(i32)),
+            )
+            return new_r, new_excess, new_p, aux
+
     if telemetry_cap:
         from ..obs import soltel as _soltel
 
@@ -479,18 +641,27 @@ def _solve_mcmf(
             tel, steps, row, telemetry_cap, _tel_rows_iota
         )
 
-    # loop state: (r, excess, p, eps, steps, done[, tel])
+    # loop state: (r, excess, p, eps, steps, done[, sparse][, tel])
     def phase_cond(state):
         steps, done = state[4], state[5]
         return ~done & (steps < max_supersteps)
 
     def phase_body(state):
         r, excess, p, eps, steps, done = state[:6]
-        tel = state[6:]
+        sparse, tel = (state[6:7], state[7:]) if active_set else ((), state[6:])
         any_active = jnp.any(excess > 0)
 
         def do_superstep(_):
-            r2, e2, p2, aux = superstep(r, excess, p, eps)
+            if active_set:
+                # per superstep, from the loop state: one solve holds
+                # both kinds (a trickle round's two bulk supersteps,
+                # then eight of one node)
+                fits = active_set_fits(excess)
+                r2, e2, p2, aux = lax.cond(
+                    fits, active_superstep, superstep, r, excess, p, eps
+                )
+            else:
+                r2, e2, p2, aux = superstep(r, excess, p, eps)
             if price_update_every:
                 # the lower of the two prices at every node: both keep
                 # every residual arc's reduced cost >= -eps, so their
@@ -507,6 +678,8 @@ def _solve_mcmf(
                     lambda: p2,
                 )
             out = (r2, e2, p2, eps, steps + 1, jnp.bool_(False))
+            if active_set:
+                out = out + (sparse[0] + fits.astype(i32),)
             if not telemetry_cap:
                 return out
             return out + (tel_write(tel[0], steps, tel_row(eps, excess, aux)),)
@@ -516,7 +689,7 @@ def _solve_mcmf(
             new_eps = jnp.maximum(i32(1), eps // alpha)
             r2 = jnp.where(finished, r, saturate(r, p))
             out = (r2, excess_of(r2), p, jnp.where(finished, eps, new_eps), steps, finished)
-            return out + tel
+            return out + sparse + tel
 
         return lax.cond(any_active, do_superstep, next_phase, operand=None)
 
@@ -529,16 +702,20 @@ def _solve_mcmf(
         p0 = tighten(r0)
     r1 = saturate(r0, p0)  # mop up any residual violations
     state = (r1, excess_of(r1), p0, eps_init, i32(0), jnp.bool_(False))
+    if active_set:
+        state = state + (i32(0),)
     if telemetry_cap:
         state = state + (jnp.zeros((telemetry_cap, SOLTEL_WIDTH), i32),)
-    r, excess, p, _eps, steps, done, *tel = lax.while_loop(
+    r, excess, p, _eps, steps, done, *rest = lax.while_loop(
         phase_cond, phase_body, state
     )
     # the flow, read back once: an arc's flow is its backward row's residual
     (flow,) = _rows(inv_order[m:], r)
     converged = done & (jnp.max(jnp.abs(excess)) == 0)
     p_overflow = jnp.max(jnp.abs(p)) >= _P_GUARD
-    return (flow, p, steps, converged, p_overflow, *tel)
+    # with `active_set`, the supersteps that took the sparse form come
+    # sixth, before the telemetry ring
+    return (flow, p, steps, converged, p_overflow, *rest)
 
 
 # ---------------------------------------------------------------------------
@@ -731,6 +908,9 @@ class JaxSolver(FlowSolver):
         #: problem's key matches (no endpoint churn since that solve)
         self._key_solved = None
         self.last_supersteps = 0
+        #: of those, the supersteps that worked on the rows of the
+        #: active nodes alone (`active_superstep`), over every attempt
+        self.last_sparse_supersteps = 0
         self.last_telemetry = None  # SolveTelemetry of the last solve
         self.last_warm_scope = "cold"  # warm | fresh | cold (see solve_async)
 
@@ -961,6 +1141,7 @@ class JaxSolver(FlowSolver):
             and self._prev_p.shape[0] == n
         )
         attempt1_budget = min(4096, self.max_supersteps)
+        active_set = active_set_caps(plan_dev[0].shape[0])
         if warm and self.restart_budget is not None:
             # budgeted warm attempt: a price-war round escapes to the
             # fresh-restart attempt in complete() instead of burning
@@ -978,9 +1159,10 @@ class JaxSolver(FlowSolver):
             use_warm_p=warm_p_ok,
             slot_stable=slot_stable,
             price_update_every=self.price_update_every,
+            active_set=active_set,
         )
         cold = (np.zeros(m, dtype=np.int32), max(1, max_cost * n))
-        rest = (dev_args, plan_dev, cold, tel_cap, warm, slot_stable, attempt1_budget)
+        rest = (dev_args, plan_dev, cold, tel_cap, warm, slot_stable, attempt1_budget, active_set)
         return (problem, fut, rest, resident)
 
     def complete(self, pending) -> FlowResult:
@@ -990,17 +1172,24 @@ class JaxSolver(FlowSolver):
         problem, fut, rest, resident = pending
         if fut is None:
             self.last_telemetry = None
+            self.last_sparse_supersteps = 0
             return FlowResult(
                 flow=np.zeros(len(problem.src), dtype=np.int64),  # kschedlint: host-only (FlowResult contract is int64)
                 objective=0, iterations=0,
             )
-        dev_args, plan_dev, (f0_cold, eps_cold), tel_cap, warm, slot_stable, attempt1_budget = rest
-        tel_buf = None
-        if tel_cap:
-            flow, p, steps, converged, p_overflow, tel_buf = fut
-        else:
-            flow, p, steps, converged, p_overflow = fut
+        dev_args, plan_dev, (f0_cold, eps_cold), tel_cap, warm, slot_stable, attempt1_budget, active_set = rest
+
+        def unpack(out):
+            """One attempt's outputs; the supersteps that took the
+            sparse form (0 where the plan is too small for one) and
+            the telemetry ring are there or not."""
+            tail = list(out[5:])
+            took = int(tail.pop(0)) if active_set else 0
+            return (*out[:5], took, tail[0] if tail else None)
+
+        flow, p, steps, converged, p_overflow, took, tel_buf = unpack(fut)
         spent = int(steps)  # device work across ALL attempts this solve
+        spent_sparse = took
         warm_failed = warm and not (bool(converged) and not bool(p_overflow))
         if warm_failed and not bool(converged):
             # A warm attempt that exhausted its budget is a price war,
@@ -1045,12 +1234,11 @@ class JaxSolver(FlowSolver):
                 telemetry_cap=tel_cap,
                 slot_stable=slot_stable,
                 price_update_every=self.price_update_every,
+                active_set=active_set,
             )
-            if tel_cap:
-                flow, p, steps, converged, p_overflow, tel_buf = out
-            else:
-                flow, p, steps, converged, p_overflow = out
+            flow, p, steps, converged, p_overflow, took, tel_buf = unpack(out)
             spent += int(steps)
+            spent_sparse += took
         if not (bool(converged) and not bool(p_overflow)):
             out = _solve_mcmf(
                 *dev_args,
@@ -1062,17 +1250,17 @@ class JaxSolver(FlowSolver):
                 telemetry_cap=tel_cap,
                 slot_stable=slot_stable,
                 price_update_every=self.price_update_every,
+                active_set=active_set,
             )
-            if tel_cap:
-                flow, p, steps, converged, p_overflow, tel_buf = out
-            else:
-                flow, p, steps, converged, p_overflow = out
+            flow, p, steps, converged, p_overflow, took, tel_buf = unpack(out)
             spent += int(steps)
+            spent_sparse += took
         # work accounting covers every attempt (a budget-blown warm
         # attempt's burn included) — the supersteps the DEVICE ran this
         # round, not just the attempt that won; telemetry decode below
         # stays attempt-local (the ring indexes the final attempt)
         self.last_supersteps = spent
+        self.last_sparse_supersteps = spent_sparse
         # the telemetry budget is the SOLVER's budget (max_supersteps),
         # not the warm attempt's internal 4096 cap: a warm solve that
         # converges near 4096 steps is escalated to the cold fallback,
@@ -1136,5 +1324,6 @@ from ..analysis.program_registry import declare_programs as _declare_programs
 _declare_programs(
     __name__,
     "csr_solve", "csr_solve_warmp", "csr_solve_slot", "csr_refit_slot",
+    "csr_solve_active",
     "stacked_solve", "stacked_solve_warmp",
 )

@@ -239,6 +239,9 @@ class PlacementSolver:
             try:
                 with span("backend_solve", backend=type(self.backend).__name__) as sp:
                     result = self.backend.complete(pending)
+                    sparse = getattr(self.backend, "last_sparse_supersteps", None)
+                    if sparse is not None:
+                        sp.set("supersteps_sparse", int(sparse))
                     # async dispatches bypass solve_traced; publish the
                     # solver-interior telemetry here instead (registry
                     # histograms + per-superstep child spans + stall

@@ -112,6 +112,8 @@ def _seeded_rng():
 #: to its cell alone and to the end of `per_layer`. `test_benchmark_requests.py`
 #: holds what stays true of each: the lists as "what they had, then this cell",
 #: the four modules' purity and stamps, the six entries before the four new ones.
+#: `test_benchmark_sparse_supersteps.py` (PR 50) holds what stays true of the
+#: three pins of `test_benchmark_requests.py` that one more appended entry broke.
 _STALE = {
     "test_benchmark_seams.py::test_class_only_is_the_old_expression_and_the_same_seed_"
     "draws_the_same_plan[k8s-5000-preemption.rollout-": "PR 38 brings pods/by_role.py",
@@ -167,6 +169,12 @@ _STALE = {
     "nothing_from_the_frameworks_rng[": "PR 49 brings a fourth module under pods/",
     "test_benchmark_wharemap.py::test_each_metric_it_brings_is_an_entry_with_its_file_for_"
     "this_cell_alone[": "PR 49 appended four entries after the six, and its cell to one of them",
+    "test_benchmark_requests.py::test_the_cell_takes_one_chip_and_the_mix_it_shares_is_"
+    "unchanged": "PR 50 appended supersteps_sparse_p50, its cell listed",
+    "test_benchmark_requests.py::test_each_metric_it_brings_is_an_entry_with_its_file_for_"
+    "this_cell_alone[": "PR 50 appended an entry after the four",
+    "test_benchmark_requests.py::test_what_stays_true_of_the_six_entries_before_them[":
+        "PR 50 appended an entry after the four that follow the six",
     "test_benchmark_wharemap.py::test_the_traced_rehearsal_is_correct_and_every_metric_reads_"
     "a_number": "PR 48: `ec_arcs_repriced` is visited ECs x `census_machines_dirty` in a round "
                 "that patched, and nearly every arc written changes",

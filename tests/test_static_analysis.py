@@ -422,11 +422,16 @@ def test_registry_matches_select():
 
 
 def test_registry_policies_are_coherent():
-    """Solve and audit programs never scatter; every scoped exemption
-    is a maintenance program; chaos programs are never donation-audited
-    (they are never dispatched in production)."""
+    """Solve and audit programs never scatter, but for the one that
+    holds scan-CSR's active-set superstep, whose four scatter-adds a
+    chip reading admitted (PR 50) and the engine counts and confines to
+    the sparse branch; every scoped exemption is a maintenance program;
+    chaos programs are never donation-audited (they are never
+    dispatched in production)."""
     for spec in PROGRAMS.values():
-        if spec.kind in ("solve", "audit"):
+        if spec.name == "csr_solve_active":
+            assert (spec.kind, spec.scatter_policy, spec.scatters) == ("solve", "active-set", 4)
+        elif spec.kind in ("solve", "audit"):
             assert spec.scatter_policy == "forbidden", spec.name
         if spec.scatter_policy == "scoped-exempt":
             assert spec.kind == "maintenance", spec.name
@@ -698,6 +703,25 @@ def test_engine_flags_undeclared_ownership():
     spec = dataclasses.replace(PROGRAMS["csr_solve"], module="ksched_tpu.solver.base")
     with pytest.raises(engine.ContractError, match="declare_programs"):
         engine.check_declared(spec)
+
+
+def test_engine_counts_the_active_set_scatters_and_confines_them():
+    """One scatter more or fewer than the chip reading admitted fails;
+    so does the policy on a program with no sparse branch (its tracer
+    swapped for the slot-stable one's, which never scatters)."""
+    active = PROGRAMS["csr_solve_active"]
+    with pytest.raises(engine.ContractError, match="was admitted 3"):
+        engine.check_contracts(dataclasses.replace(active, scatters=3))
+    with pytest.raises(engine.ContractError, match="was admitted 4"):
+        engine.check_contracts(
+            dataclasses.replace(active, tracer="trace_jax_slot_stable", gathers=None)
+        )
+    with pytest.raises(engine.ContractError, match="'forbidden'"):
+        engine.check_contracts(
+            dataclasses.replace(active, scatter_policy="forbidden", scatters=None)
+        )
+    with pytest.raises(ValueError, match="active-set policy"):
+        dataclasses.replace(PROGRAMS["csr_solve"], scatters=4)
 
 
 def test_engine_flags_missing_tracer():
