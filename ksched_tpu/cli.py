@@ -27,7 +27,7 @@ from .costmodels import MODEL_REGISTRY, CostModelType
 from .data import PLATFORM_LABEL, RACK_LABEL, ZONE_LABEL
 from .obs import metrics as obs_metrics
 from .obs.flight import FlightRecorder
-from .obs.spans import SpanTracer, active_tracer, span
+from .obs.spans import SpanTracer, active_tracer, gc_pause_total_s, span
 from .drivers.synthetic import (
     add_machine,
     add_task_to_job,
@@ -227,6 +227,11 @@ class SchedulerService:
         #: the last batch POSTed had waited (RoundRecord.post_defer_ms)
         self._pending_since = 0.0
         self._post_defer_ms = 0.0
+        #: the collector's total pause when the round in hand began
+        #: (obs/spans.py: counted while a tracer is installed): the
+        #: round's record takes what was added since
+        #: (RoundRecord.gc_pause_ms)
+        self._gc_mark = 0.0
         # service-level gauges (inert singletons when obs is disabled)
         reg = obs_metrics.get_registry()
         self._g_pods = reg.gauge("ksched_live_pods", "pods the service tracks")
@@ -708,6 +713,7 @@ class SchedulerService:
         if self.tenant:
             span_args["tenant"] = self.tenant
         rec = None
+        self._gc_mark = gc_pause_total_s()
         with span("service_round", **span_args) as sp:
             queue_wait = self._queue_wait_ms(pods, sp.t0_s)
             sp.set("queue_wait_ms", queue_wait[0])
@@ -781,6 +787,7 @@ class SchedulerService:
             "pods": len(pods),
         }
         st["queue_wait"] = self._queue_wait_ms(pods, st["t0"])
+        self._gc_mark = gc_pause_total_s()
         self.watchdog.__enter__()
         try:
             self._admit_pods(pods)
@@ -937,6 +944,7 @@ class SchedulerService:
                         queue_wait_ms=queue_wait[0],
                         queue_wait_max_ms=queue_wait[1],
                         post_defer_ms=self._post_defer_ms if solve else 0.0,
+                        gc_pause_ms=(gc_pause_total_s() - self._gc_mark) * 1e3,
                         # consumed by the solved round's record, below
                         pods_evicted=self._pods_evicted if solve else 0,
                         pods_migrated=self._pods_migrated if solve else 0,

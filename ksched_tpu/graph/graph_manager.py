@@ -219,10 +219,13 @@ class GraphManager:
         #: round, nor after one that raised or rebuilt the lists.
         self._stats_lists_kept = False
         #: the last compute_topology_statistics: PUs on the dirty set,
-        #: resource nodes it prepared, and whether it walked every node
+        #: resource nodes it prepared, whether it walked every node, and
+        #: the children its nodes iterated (counted as arcs a node, so
+        #: the count costs one add a node)
         self.stats_pus_dirty = 0
         self.stats_nodes_visited = 0
         self.stats_full_walk = 0
+        self.stats_children_gathered = 0
         #: the post-solve refresh of the tree (refresh_resource_topology)
         #: has a baseline of its own: the PUs whose lists changed since
         #: the last refresh, fed by the same call, drained by the refresh
@@ -694,10 +697,12 @@ class GraphManager:
         walk_all = walk_all or self._paths_span_the_tree(dirty)
         self.stats_full_walk = int(walk_all)
         if walk_all:
-            self._walk_topology_statistics(start)
+            self.stats_children_gathered = self._walk_topology_statistics(start)
             self.stats_nodes_visited = len(self.resource_to_node)
         else:
-            self.stats_nodes_visited = self._gather_dirty_statistics(dirty)
+            self.stats_nodes_visited, self.stats_children_gathered = (
+                self._gather_dirty_statistics(dirty)
+            )
 
     def _paths_span_the_tree(self, dirty_pus: Set[int]) -> bool:
         """The paths from ``dirty_pus`` (resource ids) up to the root
@@ -712,19 +717,22 @@ class GraphManager:
             depth += 1
         return len(dirty_pus) * depth >= len(self.resource_to_node)
 
-    def _walk_topology_statistics(self, start: Node) -> None:
+    def _walk_topology_statistics(self, start: Node) -> int:
         """Reverse BFS from the sink over every node; correct only for
         tree topologies (reference: graph_manager.go:478-511). Where the
         model calls tasks inert, a task node is passed over: the three
         hooks would return at once for it, and it has no incoming arc,
-        so nothing lies behind it."""
+        so nothing lies behind it. Returns the arcs it went over: the
+        incoming arcs of every node it visited."""
         self._cur_traversal_counter += 1
         counter = self._cur_traversal_counter
         skip_tasks = self._tasks_inert
         to_visit: Deque[Node] = deque([start])
         start.visited = counter
+        iterated = 0
         while to_visit:
             cur = to_visit.popleft()
+            iterated += len(cur.incoming)
             for arc in cur.incoming.values():
                 src = arc.src_node
                 if skip_tasks and src.task is not None:
@@ -735,14 +743,18 @@ class GraphManager:
                     src.visited = counter
                 self.cost_model.gather_stats(src, cur)
                 self.cost_model.update_stats(src, cur)
+        return iterated
 
-    def _gather_dirty_statistics(self, dirty_pus: Set[int]) -> int:
+    def _gather_dirty_statistics(self, dirty_pus: Set[int]) -> Tuple[int, int]:
         """The walk's three hooks for the PUs of ``dirty_pus`` (resource
         ids) and, level by level up the tree, for each of their
         ancestors once all its dirty children are done: an ancestor is
         prepared and gathers from every resource child (the tree's
         children, so a machine's arcs from EC nodes are not looked at).
-        Returns the resource nodes prepared."""
+        Returns the resource nodes prepared and the children iterated:
+        one for each dirty PU (the sink), and every outgoing arc of each
+        ancestor, dirty below or not: the coordinator of 12,500 machines
+        re-reads them all for one dirty path."""
         prepare = self.cost_model.prepare_stats
         gather = self.cost_model.gather_stats
         update = self.cost_model.update_stats
@@ -752,7 +764,7 @@ class GraphManager:
             prepare(pu)
             gather(pu, sink)
             update(pu, sink)
-        visited = len(level)
+        visited = iterated = len(level)
         parent_of = self.node_to_parent_node
         while level:
             parents: Dict[int, Node] = {}
@@ -763,13 +775,14 @@ class GraphManager:
             level = list(parents.values())
             for parent in level:
                 prepare(parent)
+                iterated += len(parent.outgoing)
                 for arc in parent.outgoing.values():
                     child = arc.dst_node
                     if child.resource_id != 0:
                         gather(parent, child)
                         update(parent, child)
             visited += len(level)
-        return visited
+        return visited, iterated
 
     # ------------------------------------------------------------------
     # Delta generation (reference: graph_manager.go:253-339)

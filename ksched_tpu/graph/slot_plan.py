@@ -157,6 +157,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..obs.spans import span
 from ..utils import next_pow2
 
 #: int32 columns of one packed plan-row record:
@@ -364,6 +365,10 @@ class SlotPlanState:
         # ---- device caches (non-resident full-upload path) -----------
         self._static_dev: Optional[Tuple] = None  # (layout_gen, tensors)
         self._values_dev: Optional[Tuple] = None  # (layout_gen, version, tensors)
+        #: exact bytes the last device_args() shipped: the value tensors
+        #: and, after a relocation, the boundary tensors; 0 on a clean
+        #: round that re-used both cached uploads
+        self.last_ship_bytes = 0
 
     # -- pickling (the warm-restore manifest, runtime/checkpoint.py) -------
 
@@ -1191,21 +1196,38 @@ class SlotPlanState:
         cached by (layout_gen, value_version): a clean round re-uses
         the previous upload outright; a dirty round re-ships the
         maintained host arrays wholesale (the non-resident path — the
-        device-resident mirror scatters records instead)."""
+        device-resident mirror scatters records instead). What it ships
+        goes up inside a `plan_upload` span, the name the mirror's
+        scatter has, `bytes` exact (`last_ship_bytes`); a clean round
+        opens none."""
         self.ensure_built()
         key = (self.layout_gen, self.value_version)
-        if self._values_dev is None or self._values_dev[:2] != key:
+        static_key = (self.layout_gen, self.static_version)
+        ship_values = self._values_dev is None or self._values_dev[:2] != key
+        ship_static = self._static_dev is None or self._static_dev[0] != static_key
+        self.last_ship_bytes = 0
+        if ship_values or ship_static:
             import jax.numpy as jnp
 
-            self._values_dev = key + (
-                tuple(
-                    jnp.asarray(x)
-                    for x in (self.p_arc, self.p_sign, self.p_src, self.p_dst)
-                ),
-                jnp.asarray(self.inv_order),
+            self.last_ship_bytes = (self.values_nbytes() if ship_values else 0) + (
+                self.static_nbytes() if ship_static else 0
             )
+            with span(
+                "plan_upload", kind="static_and_values" if ship_static else "values",
+                bytes=self.last_ship_bytes, rows=self.entry_cap,
+            ):
+                if ship_values:
+                    self._values_dev = key + (
+                        tuple(
+                            jnp.asarray(x)
+                            for x in (self.p_arc, self.p_sign, self.p_src, self.p_dst)
+                        ),
+                        jnp.asarray(self.inv_order),
+                    )
+                seg, isstart, first, last, nonempty = self.device_static()
+        else:
+            seg, isstart, first, last, nonempty = self._static_dev[1]
         values, inv = self._values_dev[2], self._values_dev[3]
-        seg, isstart, first, last, nonempty = self.device_static()
         return values + (seg, isstart, inv, first, last, nonempty)
 
     # -- invariants (tests / debug) ----------------------------------------

@@ -67,6 +67,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from .metrics import get_registry, log_buckets
+from .spans import span
 
 #: counter taxonomy; column 7 is reserved padding (pow2-wide rows)
 SOLTEL_COLS = (
@@ -521,10 +522,18 @@ def publish(tel: Optional[SolveTelemetry], sp=None) -> Optional[dict]:
     a tracer is recording), and the stall detector. Returns the stall
     event when one was noted. Called from `solver/base.solve_traced`
     (and the bulk scheduler's layered path) right after the solve —
-    entirely host-side, after the device work is already fetched."""
-    global _last_tel
+    entirely host-side, after the device work is already fetched, and
+    inside a `soltel_publish` span of its own (`rows`: the supersteps
+    kept, one synthesized event each), so that every rung shows what
+    its telemetry costs the round."""
     if tel is None or tel.steps == 0:
         return None
+    with span("soltel_publish", rows=len(tel.rows), backend=tel.backend):
+        return _publish(tel, sp)
+
+
+def _publish(tel: SolveTelemetry, sp) -> Optional[dict]:
+    global _last_tel
     _last_tel = tel
     reg = get_registry()
     reg.histogram(

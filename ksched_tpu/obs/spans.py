@@ -32,6 +32,7 @@ depends on that).
 from __future__ import annotations
 
 import contextvars
+import gc
 import itertools
 import json
 import os
@@ -124,6 +125,77 @@ def active_tracer() -> Optional["SpanTracer"]:
     return _active
 
 
+def _chrome_event(name: str, t0_s: float, dur_s: float, args: Dict, pid: int) -> dict:
+    """The one construction of a Chrome complete event, stamped with the
+    calling thread: recorded spans, synthesized events and the
+    collector's pauses must never fork the schema."""
+    return {
+        "ph": "X",
+        "cat": "ksched",
+        "name": name,
+        "ts": t0_s * 1e6,  # perf_counter base: monotonic, shared in-process
+        "dur": dur_s * 1e6,
+        "pid": pid,
+        "tid": threading.get_ident(),
+        "args": args,
+    }
+
+
+# -- collector pauses --------------------------------------------------------
+#
+# A collection of the oldest generation stops every thread for as long
+# as the heap is large (0.3-0.5 s over a 150,000-pod graph), and without
+# a name it is charged to whichever span it fell into. While a tracer is
+# installed a `gc.callbacks` hook times each collection; one that held
+# the process for GC_PAUSE_FLOOR_S or longer becomes a `gc_pause` event
+# (args `generation`, `collected`) of the tracer active at its end, on
+# the thread that collected, parented to the span open there. Every
+# pause, short or long, is summed (`gc_pause_total_s`): a young
+# collection takes tens of microseconds and a busy round has hundreds,
+# which is a number worth having and not an event each. The hook runs
+# between two bytecodes of ANY code, the tracer's own locked sections
+# included, so it takes no lock: it leaves the event on the tracer's
+# `_gc_pending` (a list append) and that tracer's next record or read
+# moves it into the ring.
+
+GC_PAUSE_FLOOR_S = 1e-3
+_gc_t0_s = 0.0
+_gc_total_s = 0.0
+
+
+def _gc_hook(phase: str, info: Dict) -> None:
+    global _gc_t0_s, _gc_total_s
+    if phase == "start":
+        _gc_t0_s = time.perf_counter()
+        return
+    if not _gc_t0_s:
+        return  # installed while a collection ran
+    t0, _gc_t0_s = _gc_t0_s, 0.0
+    dur = time.perf_counter() - t0
+    _gc_total_s += dur
+    tracer = _active
+    if dur < GC_PAUSE_FLOOR_S or tracer is None:
+        return
+    args = {
+        "generation": info.get("generation", -1),
+        "collected": info.get("collected", 0),
+        "sid": next(_ids),
+    }
+    parent = _current.get()
+    if parent is not None:
+        args["parent_sid"] = parent.sid
+        args["parent"] = parent.name
+    tracer._gc_pending.append(_chrome_event("gc_pause", t0, dur, args, tracer._pid))
+
+
+def gc_pause_total_s() -> float:
+    """Seconds the collector has held the process while a tracer was
+    installed, all threads' collections together (each holds the GIL,
+    so every thread waited): a round takes the difference between its
+    start and its end (RoundRecord.gc_pause_ms)."""
+    return _gc_total_s
+
+
 def unwind(outer: Span, exc_type, exc, tb) -> None:
     """Error-path close for manual-span regions: close every open span
     from the current innermost up to and including `outer`, so the
@@ -163,29 +235,33 @@ class SpanTracer:
         self._pid = os.getpid()
         self.total = 0  # spans ever recorded (ring may have dropped some)
         self.dropped = 0
+        #: collector pauses that ended while this tracer was the active
+        #: one, until the next record or read takes them into the ring
+        self._gc_pending: List[dict] = []
         self._prev: Optional[SpanTracer] = None
 
     # -- recording ---------------------------------------------------------
 
     def _append(self, name: str, t0_s: float, dur_s: float, args: Dict) -> None:
         """One Chrome-event construction + locked ring append for both
-        recorded spans and synthesized events — the schema must never
-        fork between the two."""
-        event = {
-            "ph": "X",
-            "cat": "ksched",
-            "name": name,
-            "ts": t0_s * 1e6,  # perf_counter base: monotonic, shared in-process
-            "dur": dur_s * 1e6,
-            "pid": self._pid,
-            "tid": threading.get_ident(),
-            "args": args,
-        }
+        recorded spans and synthesized events."""
+        self._push(_chrome_event(name, t0_s, dur_s, args, self._pid))
+
+    def _push(self, event: dict) -> None:
         with self._lock:
             if len(self._events) == self._events.maxlen:
                 self.dropped += 1
             self._events.append(event)
             self.total += 1
+
+    def _take_gc_pauses(self) -> None:
+        """Move the collector's pauses (_gc_hook) into the ring."""
+        while self._gc_pending:
+            try:
+                event = self._gc_pending.pop(0)
+            except IndexError:  # another thread took the last one
+                return
+            self._push(event)
 
     def _record(self, sp: Span) -> None:
         args = dict(sp.args) if sp.args else {}
@@ -193,6 +269,8 @@ class SpanTracer:
         if sp.parent_sid:
             args["parent_sid"] = sp.parent_sid
             args["parent"] = sp.parent_name
+        if self._gc_pending:
+            self._take_gc_pauses()
         self._append(sp.name, sp.t0_s, sp.t1_s - sp.t0_s, args)
 
     def record_event(self, name: str, t0_s: float, dur_s: float, args: Optional[Dict] = None) -> None:
@@ -207,6 +285,7 @@ class SpanTracer:
     # -- slicing (flight recorder) -----------------------------------------
 
     def mark(self) -> int:
+        self._take_gc_pauses()
         with self._lock:
             return self.total
 
@@ -215,6 +294,7 @@ class SpanTracer:
         what remains is returned). islice, not a full-ring copy: the
         flight recorder calls this every round to slice out the last
         ~dozen events of a ring that may hold 64k."""
+        self._take_gc_pauses()
         with self._lock:
             want = self.total - mark
             skip = max(0, len(self._events) - want)
@@ -223,6 +303,7 @@ class SpanTracer:
     # -- export ------------------------------------------------------------
 
     def events(self) -> List[dict]:
+        self._take_gc_pauses()
         with self._lock:
             return list(self._events)
 
@@ -237,10 +318,14 @@ class SpanTracer:
 
     def install(self) -> "SpanTracer":
         """Make this the process-active tracer (stacking: uninstall
-        restores the previous one)."""
+        restores the previous one). The first tracer installed hooks
+        the collector (`gc_pause`); the last one uninstalled takes the
+        hook away."""
         global _active
         self._prev = _active
         _active = self
+        if _gc_hook not in gc.callbacks:
+            gc.callbacks.append(_gc_hook)
         return self
 
     def uninstall(self) -> None:
@@ -248,6 +333,8 @@ class SpanTracer:
         if _active is self:
             _active = self._prev
         self._prev = None
+        if _active is None and _gc_hook in gc.callbacks:
+            gc.callbacks.remove(_gc_hook)
 
     def __enter__(self) -> "SpanTracer":
         return self.install()

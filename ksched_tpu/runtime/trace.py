@@ -85,11 +85,16 @@ class RoundRecord:
     #: GraphManager.compute_topology_statistics): PUs whose
     #: current_running_tasks changed since the pass before, resource
     #: nodes it prepared (those PUs and their ancestors, or every
-    #: resource node), and 1 if it walked every node (that method's
-    #: docstring says when)
+    #: resource node), 1 if it walked every node (that method's
+    #: docstring says when), and the children those nodes iterated to
+    #: gather from: a patched pass counts one for each dirty PU and
+    #: every outgoing arc of each ancestor (a coordinator re-reads all
+    #: its machines for one dirty path), a full walk the arcs it went
+    #: over
     stats_pus_dirty: int = 0
     stats_nodes_visited: int = 0
     stats_full_walk: int = 0
+    stats_children_gathered: int = 0
     #: the round's refresh of the resource tree, in `apply` (from its
     #: RoundTiming; GraphManager.refresh_resource_topology): PUs whose
     #: current_running_tasks changed since the refresh before, resource
@@ -100,11 +105,9 @@ class RoundRecord:
     apply_full_walk: int = 0
     #: the round's post-solve half (from its RoundTiming): unpinned task
     #: nodes handed to `decode` (the batch and the unscheduled backlog;
-    #: every task under preemption), pinned tasks it left alone, and
-    #: mapping entries the `deltas` phase turned into deltas
+    #: every task under preemption), and pinned tasks it left alone
     decode_tasks: int = 0
     decode_pinned_skipped: int = 0
-    deltas_walked: int = 0
     #: the device-resident export of the round (from its RoundTiming; 0
     #: on a service without --device-resident): exact host-to-device
     #: bytes (problem records or arrays, plus plan records or plan), 1
@@ -114,6 +117,18 @@ class RoundRecord:
     upload_bytes: int = 0
     upload_full: int = 0
     plan_relocations: int = 0
+    #: exact bytes the round's solves moved where the scan-CSR solver is
+    #: the configured rung (from its RoundTiming; zeros elsewhere): up,
+    #: `upload_bytes` plus what the solver itself uploaded (problem
+    #: arrays, warm flow, eps, the plan's re-ship; next to nothing over
+    #: a device-resident problem); down, the attempts' scalars, the
+    #: telemetry ring and the flow (prices stay on the device)
+    solve_h2d_bytes: int = 0
+    solve_d2h_bytes: int = 0
+    #: time the Python collector held the process during the round, all
+    #: generations and threads (obs/spans.py `gc_pause`); counted while
+    #: a SpanTracer is installed, 0.0 otherwise
+    gc_pause_ms: float = 0.0
     #: the slot plan (graph/slot_plan.py): rows at the solve and in
     #: use, and the round's re-fits, regrowths and re-layouts
     plan_rows: int = 0
@@ -338,15 +353,17 @@ class RoundTracer:
             stats_pus_dirty=t.stats_pus_dirty,
             stats_nodes_visited=t.stats_nodes_visited,
             stats_full_walk=t.stats_full_walk,
+            stats_children_gathered=t.stats_children_gathered,
             apply_pus_dirty=t.apply_pus_dirty,
             apply_nodes_visited=t.apply_nodes_visited,
             apply_full_walk=t.apply_full_walk,
             decode_tasks=t.decode_tasks,
             decode_pinned_skipped=t.decode_pinned_skipped,
-            deltas_walked=t.deltas_walked,
             upload_bytes=t.upload_bytes,
             upload_full=t.upload_full,
             plan_relocations=t.plan_relocations,
+            solve_h2d_bytes=t.solve_h2d_bytes,
+            solve_d2h_bytes=t.solve_d2h_bytes,
             plan_rows=t.plan_rows,
             plan_rows_live=t.plan_rows_live,
             plan_refits=t.plan_refits,

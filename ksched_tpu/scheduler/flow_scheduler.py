@@ -110,11 +110,15 @@ class RoundTiming:
     res_nodes_visited: int = 0
     res_arcs_changed: int = 0
     #: what `stats` did: PUs whose running-task lists changed since the
-    #: last pass, resource nodes it prepared, and 1 if it walked every
-    #: node (GraphManager.compute_topology_statistics)
+    #: last pass, resource nodes it prepared, 1 if it walked every
+    #: node, and the children its nodes iterated to gather from (a
+    #: patched pass: one for each dirty PU and every outgoing arc of
+    #: each ancestor, so a coordinator re-reads all its machines for one
+    #: dirty path; GraphManager.compute_topology_statistics)
     stats_pus_dirty: int = 0
     stats_nodes_visited: int = 0
     stats_full_walk: int = 0
+    stats_children_gathered: int = 0
     #: what `apply`'s refresh of the resource tree did: PUs whose lists
     #: changed since the last refresh, resource nodes it visited, and 1
     #: if it walked every node (GraphManager.refresh_resource_topology)
@@ -122,11 +126,10 @@ class RoundTiming:
     apply_nodes_visited: int = 0
     apply_full_walk: int = 0
     #: what the post-solve half worked on: unpinned task nodes handed
-    #: to `decode`, pinned tasks it left alone (their arcs dropped by a
-    #: mask), and mapping entries `deltas` turned into deltas
+    #: to `decode`, and pinned tasks it left alone (their arcs dropped
+    #: by a mask)
     decode_tasks: int = 0
     decode_pinned_skipped: int = 0
-    deltas_walked: int = 0
     #: what the device-resident export shipped (0 without a mirror):
     #: exact host-to-device bytes of the round (problem plus plan),
     #: 1 if the arrays or the plan went up whole, and the plan regions
@@ -134,6 +137,15 @@ class RoundTiming:
     upload_bytes: int = 0
     upload_full: int = 0
     plan_relocations: int = 0
+    #: exact bytes the round's solves moved, where the scan-CSR solver
+    #: is the configured rung (zeros elsewhere): up, the export's
+    #: `upload_bytes` plus what the solver itself handed to
+    #: `jnp.asarray` (problem arrays, warm flow, eps, the plan's
+    #: re-ship; the eps scalar alone over a device-resident problem);
+    #: down, the attempts' scalars, the telemetry ring and the flow
+    #: (JaxSolver.last_h2d_bytes / last_d2h_bytes)
+    solve_h2d_bytes: int = 0
+    solve_d2h_bytes: int = 0
     #: the slot plan of a scan-CSR service (graph/slot_plan.py; zeros
     #: without one): its rows at the solve (`entry_cap`: what a
     #: superstep pays for) and those in use; 1 if the round re-fitted
@@ -576,10 +588,12 @@ class FlowScheduler:
                 timing.stats_pus_dirty = self.gm.stats_pus_dirty
                 timing.stats_nodes_visited = self.gm.stats_nodes_visited
                 timing.stats_full_walk = self.gm.stats_full_walk
+                timing.stats_children_gathered = self.gm.stats_children_gathered
                 timing.census_machines_dirty = self.cost_model.take_census_machines_dirty()
                 sp.set("stats_pus_dirty", timing.stats_pus_dirty)
                 sp.set("stats_nodes_visited", timing.stats_nodes_visited)
                 sp.set("stats_full_walk", timing.stats_full_walk)
+                sp.set("stats_children_gathered", timing.stats_children_gathered)
                 sp.set("census_machines_dirty", timing.census_machines_dirty)
             timing.stats_s = sp.dur_s
             self._free_slots_at_solve = self._free_slots()
@@ -641,6 +655,13 @@ class FlowScheduler:
             timing.plan_rows = plan.entry_cap
             timing.plan_rows_live = plan.rows_live
 
+    def _note_solve_bytes(self, timing: RoundTiming, export_bytes: int) -> None:
+        """Adds a completed solve's transfers, and the device-resident
+        export's that fed it, to the round's."""
+        up, down = self.solver.solve_bytes
+        timing.solve_h2d_bytes += export_bytes + up
+        timing.solve_d2h_bytes += down
+
     def _refit_plan(self, timing: RoundTiming) -> None:
         """The slot plan re-fits to the graph the round left
         (graph/slot_plan.py): when it would land in a smaller bucket it
@@ -667,6 +688,7 @@ class FlowScheduler:
             # the new layout went up whole
             timing.upload_bytes += res.last_upload_bytes
             timing.upload_full = 1
+        self._note_solve_bytes(timing, res.last_upload_bytes if res is not None else 0)
 
     def _note_plan(self, timing: RoundTiming) -> None:
         """What happened to the slot plan's layout since the last round
@@ -690,6 +712,7 @@ class FlowScheduler:
             timing.objective = int(self.solver.last_result.objective)
             timing.decode_tasks = self.solver.decode_tasks
             timing.decode_pinned_skipped = self.solver.decode_pinned_skipped
+            self._note_solve_bytes(timing, timing.upload_bytes)
             (
                 timing.audit_tasks_grouped, timing.collapse_rows, timing.collapse_cols,
             ) = self.solver.collapse_shape
@@ -719,8 +742,6 @@ class FlowScheduler:
                     )
                     if delta is not None:
                         deltas.append(delta)
-                timing.deltas_walked = len(task_mappings)
-                sp.set("deltas_walked", timing.deltas_walked)
             timing.deltas_s = sp.dur_s
 
             with span("apply") as sp:

@@ -127,6 +127,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..graph.device_export import FlowProblem
+from ..obs.spans import span
 from .base import FlowResult, FlowSolver, check_finite_costs, lower_bound_cost
 
 _BIG = jnp.int32(1 << 30)
@@ -913,6 +914,14 @@ class JaxSolver(FlowSolver):
         self.last_sparse_supersteps = 0
         self.last_telemetry = None  # SolveTelemetry of the last solve
         self.last_warm_scope = "cold"  # warm | fresh | cold (see solve_async)
+        #: exact bytes of the last solve's own transfers, every attempt:
+        #: what solve_async and a retry handed to `jnp.asarray` (problem
+        #: arrays, warm flow, eps, the plan's re-ship; a resident problem
+        #: sends the eps scalar alone), and what complete() fetched (the
+        #: attempts' scalars, the telemetry ring, the flow; prices stay
+        #: on the device)
+        self.last_h2d_bytes = 0
+        self.last_d2h_bytes = 0
 
     def reset(self) -> None:
         self._prev = None
@@ -983,26 +992,31 @@ class JaxSolver(FlowSolver):
             self._prev_dst_dev = None
 
     def _plan_for(self, src: np.ndarray, dst: np.ndarray, n: int, plan_key=None) -> tuple:
+        """The host-built CsrPlan of a plain array problem on the
+        device, and the bytes this call shipped for it (0 when the
+        cached upload stands)."""
         plan = self._plan
+        shipped = 0
         if plan_key is not None and self._plan_key == plan_key and plan is not None:
-            return self._plan_dev  # generation key match: no scans at all
+            return self._plan_dev, 0  # generation key match: no scans at all
         if plan is None or len(plan.src) != len(src) or len(plan.node_first) != n or plan_key is not None or not (
             np.array_equal(plan.src, src) and np.array_equal(plan.dst, dst)
         ):
-            plan = build_csr_plan(src, dst, n)
-            self._plan = plan
-            self._plan_dev = tuple(
-                jnp.asarray(x)
-                for x in (
+            with span("plan_upload", kind="csr_build") as sp:
+                plan = build_csr_plan(src, dst, n)
+                self._plan = plan
+                host = (
                     plan.s_arc, plan.s_sign, plan.s_src, plan.s_dst,
                     plan.s_segstart, plan.s_isstart, plan.inv_order,
                     plan.node_first, plan.node_last, plan.node_nonempty,
                 )
-            )
+                self._plan_dev = tuple(jnp.asarray(x) for x in host)
+                shipped = sum(x.nbytes for x in host)
+                sp.set("bytes", shipped)
             # Structure changed: stale flows are only reusable per-slot if
             # endpoints match, checked in solve().
         self._plan_key = plan_key
-        return self._plan_dev
+        return self._plan_dev, shipped
 
     def solve_async(self, problem: FlowProblem):
         """Dispatch the warm attempt WITHOUT synchronizing and return an
@@ -1011,122 +1025,143 @@ class JaxSolver(FlowSolver):
         seam the reference's daemon-mode solver implies
         (placement/solver.go:60-90): its subprocess crunches DIMACS
         concurrently with the Go process, and here the asynchronous
-        dispatch gives the same overlap in-process."""
+        dispatch gives the same overlap in-process.
+
+        Three leaf spans, in order, each with the size it worked on:
+        `solve_prepare` (the host's checks, scaling, casts and warm-flow
+        mask), `problem_upload` (every `jnp.asarray` of the dispatch,
+        the plan's re-ship as its child `plan_upload`) and
+        `solve_launch` (the call of `_solve_mcmf` until it returns its
+        future). `last_h2d_bytes` is the exact `nbytes` handed to
+        `jnp.asarray` here and by a retry in complete()."""
+        self.last_h2d_bytes = 0
+        self.last_d2h_bytes = 0
         n = problem.num_nodes
         m = len(problem.src)
         if m == 0 or problem.num_arcs == 0:
             if (problem.excess > 0).any():
                 raise RuntimeError("infeasible flow problem: supply but no arcs")
             return (problem, None, None, None)
-        check_finite_costs(problem)
-        src = np.asarray(problem.src, np.int32)
-        dst = np.asarray(problem.dst, np.int32)
+        from ..obs import soltel
 
-        # Pre-scale costs by the node count so eps = 1 implies exactness;
-        # the scaled range must fit int32 comfortably.
-        max_cost = int(np.abs(problem.cost).max()) if m else 0
-        if max_cost * n >= (1 << 30):
-            raise OverflowError(
-                f"scaled costs overflow int32: max|cost|={max_cost} at {n} nodes; "
-                "rescale cost-model outputs or shrink the graph padding"
+        resident = getattr(problem, "d_cap", None) is not None
+        plan_key = getattr(problem, "plan_key", None)
+        with span("solve_prepare", arcs=m, nodes=n) as sp:
+            check_finite_costs(problem)
+            src = np.asarray(problem.src, np.int32)
+            dst = np.asarray(problem.dst, np.int32)
+
+            # Pre-scale costs by the node count so eps = 1 implies exactness;
+            # the scaled range must fit int32 comfortably.
+            max_cost = int(np.abs(problem.cost).max()) if m else 0
+            if max_cost * n >= (1 << 30):
+                raise OverflowError(
+                    f"scaled costs overflow int32: max|cost|={max_cost} at {n} nodes; "
+                    "rescale cost-model outputs or shrink the graph padding"
+                )
+            # Journal-scoped warm restart: the endpoint generation key says
+            # whether this round's journal re-wired any arc. If it did, the
+            # optimum displaces carried flow and the warm discharge is the
+            # measured unit-relabel price war — dispatch the fresh-restart
+            # program (~10 supersteps) up front instead. Carried PRICES
+            # survive either way (the refit repairs them on clean rounds).
+            keep_flow = True
+            if self.journal_scoped_warm and plan_key is not None:
+                keep_flow = (
+                    self._key_solved is not None and plan_key == self._key_solved
+                )
+            if resident:
+                # Device-resident problem: the folded arrays are already on
+                # device (only this round's delta records crossed the
+                # boundary); the warm flow is last round's device output,
+                # masked ON the device against the last successful solve's
+                # endpoints — the same values the host mask below computes,
+                # without the flow round-trip.
+                from ..graph.device_export import resident_solver_inputs
+
+                dev_args, flow0_dev, warm = resident_solver_inputs(
+                    problem, self._prev_dev, self._prev_src_dev,
+                    self._prev_dst_dev, self.warm_start and keep_flow,
+                )
+            else:
+                cap = problem.cap.astype(np.int32)
+                supply = problem.excess.astype(np.int32)
+                cost = problem.cost.astype(np.int32) * np.int32(n)
+                warm = (
+                    self.warm_start
+                    and keep_flow
+                    and self._prev is not None
+                    and len(self._prev) == m
+                    and self._prev_src_host is not None
+                    and len(self._prev_src_host) == m
+                )
+                flow0 = np.zeros(m, dtype=np.int32)
+                if warm:
+                    # Reuse prior flow where the arc endpoints are unchanged
+                    # since the last SUCCESSFUL solve; the refit/tighten
+                    # prologue inside the solve restores consistent prices.
+                    # (With a matched plan_key the mask is all-ones by
+                    # construction; plain-array problems carry no key, so
+                    # the journal-scoped policy falls back to this compare.)
+                    same = (self._prev_src_host == src) & (self._prev_dst_host == dst)
+                    if self.journal_scoped_warm and plan_key is None and not same.all():
+                        warm = False
+                    else:
+                        flow0 = np.where(same, np.minimum(self._prev, cap), 0).astype(np.int32)
+            had_state = self._prev is not None or self._prev_dev is not None
+            #: per-solve warm scope, for bench/obs accounting: "warm" =
+            #: carried flow + refit prices, "fresh" = journal-scoped
+            #: restart (endpoint churn; zero flow, tightened prices),
+            #: "cold" = no carried state at all (first round / post-reset)
+            self.last_warm_scope = (
+                "warm" if warm else ("fresh" if had_state else "cold")
             )
+            sp.set("warm", self.last_warm_scope)
+            tel_cap = soltel.resolve_cap(self.telemetry)
+            eps1 = np.int32(1)
 
         plan_state = getattr(problem, "plan", None) if self.slot_stable else None
         slot_stable = plan_state is not None
-        if slot_stable:
-            # slot-stable plan: endpoint churn was already folded into
-            # the maintained layout — no argsort, no endpoint scans.
-            # Prefer the device-resident scatter-maintained mirror;
-            # otherwise the plan's own cached full upload (re-shipped
-            # only when its value_version moved).
-            d_plan = getattr(problem, "d_plan", None)
-            if d_plan is not None and getattr(d_plan[0], "ndim", 1) == 2:
-                # sharded-mode mirror: the entry tensors are [D, Es]
-                # stacked per-shard tables. The stacking is a lossless
-                # reshape of the global layout (graph/slot_plan.py
-                # sharded block form), so flattening them recovers the
-                # exact single-chip tensors — this is the degradation
-                # ladder's jax rung (and AutoSolver's too-big-even-
-                # per-shard CSR fallback) consuming a sharded mirror.
-                # On a real mesh the reshape gathers the shards; a
-                # degraded round may pay that once.
-                d_plan = tuple(
-                    x.reshape(-1) if getattr(x, "ndim", 1) == 2 else x
-                    for x in d_plan
-                )
-            plan_dev = d_plan if d_plan is not None else plan_state.device_args()
-        else:
-            plan_dev = self._plan_for(
-                src, dst, n, plan_key=getattr(problem, "plan_key", None)
-            )
-
-        from ..obs import soltel
-
-        tel_cap = soltel.resolve_cap(self.telemetry)
-        resident = getattr(problem, "d_cap", None) is not None
-        # Journal-scoped warm restart: the endpoint generation key says
-        # whether this round's journal re-wired any arc. If it did, the
-        # optimum displaces carried flow and the warm discharge is the
-        # measured unit-relabel price war — dispatch the fresh-restart
-        # program (~10 supersteps) up front instead. Carried PRICES
-        # survive either way (the refit repairs them on clean rounds).
-        plan_key = getattr(problem, "plan_key", None)
-        keep_flow = True
-        if self.journal_scoped_warm and plan_key is not None:
-            keep_flow = (
-                self._key_solved is not None and plan_key == self._key_solved
-            )
-        if resident:
-            # Device-resident problem: the folded arrays are already on
-            # device (only this round's delta records crossed the
-            # boundary); the warm flow is last round's device output,
-            # masked against the last successful solve's endpoints —
-            # the same values the host mask below computes, without the
-            # flow round-trip.
-            from ..graph.device_export import resident_solver_inputs
-
-            dev_args, flow0_dev, warm = resident_solver_inputs(
-                problem, self._prev_dev, self._prev_src_dev,
-                self._prev_dst_dev, self.warm_start and keep_flow,
-            )
-        else:
-            cap = problem.cap.astype(np.int32)
-            supply = problem.excess.astype(np.int32)
-            cost = problem.cost.astype(np.int32) * np.int32(n)
-            dev_args = (
-                jnp.asarray(cap), jnp.asarray(cost), jnp.asarray(supply),
-            )
-            warm = (
-                self.warm_start
-                and keep_flow
-                and self._prev is not None
-                and len(self._prev) == m
-                and self._prev_src_host is not None
-                and len(self._prev_src_host) == m
-            )
-            flow0 = np.zeros(m, dtype=np.int32)
-            if warm:
-                # Reuse prior flow where the arc endpoints are unchanged
-                # since the last SUCCESSFUL solve; the refit/tighten
-                # prologue inside the solve restores consistent prices.
-                # (With a matched plan_key the mask is all-ones by
-                # construction; plain-array problems carry no key, so
-                # the journal-scoped policy falls back to this compare.)
-                same = (self._prev_src_host == src) & (self._prev_dst_host == dst)
-                if self.journal_scoped_warm and plan_key is None and not same.all():
-                    warm = False
-                    flow0 = np.zeros(m, dtype=np.int32)
+        with span("problem_upload") as sp:
+            sent = eps1.nbytes
+            if slot_stable:
+                # slot-stable plan: endpoint churn was already folded into
+                # the maintained layout — no argsort, no endpoint scans.
+                # Prefer the device-resident scatter-maintained mirror;
+                # otherwise the plan's own cached full upload (re-shipped
+                # only when its value_version moved: `plan_upload`).
+                d_plan = getattr(problem, "d_plan", None)
+                if d_plan is not None and getattr(d_plan[0], "ndim", 1) == 2:
+                    # sharded-mode mirror: the entry tensors are [D, Es]
+                    # stacked per-shard tables. The stacking is a lossless
+                    # reshape of the global layout (graph/slot_plan.py
+                    # sharded block form), so flattening them recovers the
+                    # exact single-chip tensors — this is the degradation
+                    # ladder's jax rung (and AutoSolver's too-big-even-
+                    # per-shard CSR fallback) consuming a sharded mirror.
+                    # On a real mesh the reshape gathers the shards; a
+                    # degraded round may pay that once.
+                    d_plan = tuple(
+                        x.reshape(-1) if getattr(x, "ndim", 1) == 2 else x
+                        for x in d_plan
+                    )
+                if d_plan is not None:
+                    plan_dev = d_plan
                 else:
-                    flow0 = np.where(same, np.minimum(self._prev, cap), 0).astype(np.int32)
-            flow0_dev = jnp.asarray(flow0)
-        had_state = self._prev is not None or self._prev_dev is not None
-        #: per-solve warm scope, for bench/obs accounting: "warm" =
-        #: carried flow + refit prices, "fresh" = journal-scoped
-        #: restart (endpoint churn; zero flow, tightened prices),
-        #: "cold" = no carried state at all (first round / post-reset)
-        self.last_warm_scope = (
-            "warm" if warm else ("fresh" if had_state else "cold")
-        )
+                    plan_dev = plan_state.device_args()
+                    sent += plan_state.last_ship_bytes
+            else:
+                plan_dev, shipped = self._plan_for(src, dst, n, plan_key=plan_key)
+                sent += shipped
+            if not resident:
+                dev_args = (
+                    jnp.asarray(cap), jnp.asarray(cost), jnp.asarray(supply),
+                )
+                flow0_dev = jnp.asarray(flow0)
+                sent += cap.nbytes + cost.nbytes + supply.nbytes + flow0.nbytes
+            eps_dev = jnp.asarray(eps1)
+            sp.set("bytes", sent)
+        self.last_h2d_bytes = sent
 
         # Attempt 1: warm flow, tightened prices (or, with
         # warm_potentials, the previous round's device-resident prices)
@@ -1147,26 +1182,77 @@ class JaxSolver(FlowSolver):
             # fresh-restart attempt in complete() instead of burning
             # the full attempt-1 budget first
             attempt1_budget = min(attempt1_budget, self.restart_budget)
-        fut = _solve_mcmf(
-            *dev_args,
-            flow0_dev,
-            jnp.asarray(np.int32(1)),
-            *plan_dev,
-            warm_p=self._prev_p if warm_p_ok else None,
-            alpha=self.alpha,
-            max_supersteps=attempt1_budget,
-            telemetry_cap=tel_cap,
-            use_warm_p=warm_p_ok,
-            slot_stable=slot_stable,
-            price_update_every=self.price_update_every,
-            active_set=active_set,
-        )
+        with span(
+            "solve_launch", attempt="1", rows=int(plan_dev[0].shape[0])
+        ):
+            fut = _solve_mcmf(
+                *dev_args,
+                flow0_dev,
+                eps_dev,
+                *plan_dev,
+                warm_p=self._prev_p if warm_p_ok else None,
+                alpha=self.alpha,
+                max_supersteps=attempt1_budget,
+                telemetry_cap=tel_cap,
+                use_warm_p=warm_p_ok,
+                slot_stable=slot_stable,
+                price_update_every=self.price_update_every,
+                active_set=active_set,
+            )
         cold = (np.zeros(m, dtype=np.int32), max(1, max_cost * n))
         rest = (dev_args, plan_dev, cold, tel_cap, warm, slot_stable, attempt1_budget, active_set)
         return (problem, fut, rest, resident)
 
+    def _retry(self, attempt: str, rest, eps: int, budget: int):
+        """One more attempt from zero flow, launched and awaited: a
+        second `solve_launch` (the zero flow and eps go up inside it)
+        and `solve_wait` pair, `attempt` in their args."""
+        dev_args, plan_dev, (f0_cold, _eps_cold), tel_cap, _warm, slot_stable, _b, active_set = rest
+        eps_host = np.int32(eps)
+        with span("solve_launch", attempt=attempt, rows=int(plan_dev[0].shape[0])):
+            self.last_h2d_bytes += f0_cold.nbytes + eps_host.nbytes
+            out = _solve_mcmf(
+                *dev_args,
+                jnp.asarray(f0_cold),
+                jnp.asarray(eps_host),
+                *plan_dev,
+                alpha=self.alpha,
+                max_supersteps=budget,
+                telemetry_cap=tel_cap,
+                slot_stable=slot_stable,
+                price_update_every=self.price_update_every,
+                active_set=active_set,
+            )
+        return self._await(out, attempt, active_set)
+
+    def _await(self, out, attempt: str, active_set):
+        """Block until one attempt's scalars are on the host
+        (`solve_wait`: from here on the device has finished, so with
+        `solve_launch`'s start it brackets the device's time). Returns
+        (flow, p, steps, ok, p_overflow, sparse supersteps, telemetry
+        ring); the supersteps that took the sparse form (0 where the
+        plan is too small for one) and the ring are there or not."""
+        with span("solve_wait", attempt=attempt) as sp:
+            flow, p, steps, converged, p_overflow = out[:5]
+            tail = list(out[5:])
+            took = tail.pop(0) if active_set else None
+            scalars = [np.asarray(x) for x in (steps, converged, p_overflow)]
+            if took is not None:
+                scalars.append(np.asarray(took))
+            self.last_d2h_bytes += sum(x.nbytes for x in scalars)
+            steps_i = int(scalars[0])
+            sp.set("supersteps", steps_i)
+        return (
+            flow, p, steps_i, bool(scalars[1]), bool(scalars[2]),
+            int(scalars[3]) if took is not None else 0, tail[0] if tail else None,
+        )
+
     def complete(self, pending) -> FlowResult:
-        """Synchronize a solve_async dispatch into a FlowResult."""
+        """Synchronize a solve_async dispatch into a FlowResult. Inside
+        the caller's `backend_solve`: `solve_wait` (and a `solve_launch`
+        / `solve_wait` pair for each retry), `result_readback` (the
+        telemetry ring and the flow come down: `last_d2h_bytes`),
+        `result_unpack` (warm-state copies, objective, FlowResult)."""
         from ..obs import soltel
 
         problem, fut, rest, resident = pending
@@ -1177,21 +1263,13 @@ class JaxSolver(FlowSolver):
                 flow=np.zeros(len(problem.src), dtype=np.int64),  # kschedlint: host-only (FlowResult contract is int64)
                 objective=0, iterations=0,
             )
-        dev_args, plan_dev, (f0_cold, eps_cold), tel_cap, warm, slot_stable, attempt1_budget, active_set = rest
+        _dev_args, _plan_dev, (_f0_cold, eps_cold), tel_cap, warm, _slot_stable, attempt1_budget, active_set = rest
 
-        def unpack(out):
-            """One attempt's outputs; the supersteps that took the
-            sparse form (0 where the plan is too small for one) and
-            the telemetry ring are there or not."""
-            tail = list(out[5:])
-            took = int(tail.pop(0)) if active_set else 0
-            return (*out[:5], took, tail[0] if tail else None)
-
-        flow, p, steps, converged, p_overflow, took, tel_buf = unpack(fut)
-        spent = int(steps)  # device work across ALL attempts this solve
+        flow, p, steps, converged, p_overflow, took, tel_buf = self._await(fut, "1", active_set)
+        spent = steps  # device work across ALL attempts this solve
         spent_sparse = took
-        warm_failed = warm and not (bool(converged) and not bool(p_overflow))
-        if warm_failed and not bool(converged):
+        warm_failed = warm and not (converged and not p_overflow)
+        if warm_failed and not converged:
             # A warm attempt that exhausted its budget is a price war,
             # not a hard instance (the fresh restart below converges in
             # ~10 supersteps): report it as a structured soltel event so
@@ -1201,15 +1279,15 @@ class JaxSolver(FlowSolver):
             # must not masquerade as one on the stall ring.
             soltel.warm_price_war(
                 "jax",
-                supersteps=int(steps),
+                supersteps=steps,
                 budget=attempt1_budget,
                 escaped_to=(
                     "fresh_restart" if self.restart_budget is not None
                     else "cost_scaling"
                 ),
                 tel=(
-                    soltel.decode(
-                        tel_buf, int(steps), tel_cap, "jax", attempt1_budget,
+                    soltel.decode(  # not counted in last_d2h_bytes: a failure's evidence
+                        tel_buf, steps, tel_cap, "jax", attempt1_budget,
                         converged=False,
                         nodes=problem.num_nodes, arcs=len(problem.src),
                     )
@@ -1224,36 +1302,16 @@ class JaxSolver(FlowSolver):
             # of the ~20k-superstep full cost-scaling below. Exact
             # either way; the cost-scaling attempt remains the backstop
             # for genuinely hard instances.
-            out = _solve_mcmf(
-                *dev_args,
-                jnp.asarray(f0_cold),
-                jnp.asarray(np.int32(1)),
-                *plan_dev,
-                alpha=self.alpha,
-                max_supersteps=min(4096, self.max_supersteps),
-                telemetry_cap=tel_cap,
-                slot_stable=slot_stable,
-                price_update_every=self.price_update_every,
-                active_set=active_set,
+            flow, p, steps, converged, p_overflow, took, tel_buf = self._retry(
+                "1b", rest, 1, min(4096, self.max_supersteps)
             )
-            flow, p, steps, converged, p_overflow, took, tel_buf = unpack(out)
-            spent += int(steps)
+            spent += steps
             spent_sparse += took
-        if not (bool(converged) and not bool(p_overflow)):
-            out = _solve_mcmf(
-                *dev_args,
-                jnp.asarray(f0_cold),
-                jnp.asarray(np.int32(eps_cold)),
-                *plan_dev,
-                alpha=self.alpha,
-                max_supersteps=self.max_supersteps,
-                telemetry_cap=tel_cap,
-                slot_stable=slot_stable,
-                price_update_every=self.price_update_every,
-                active_set=active_set,
+        if not (converged and not p_overflow):
+            flow, p, steps, converged, p_overflow, took, tel_buf = self._retry(
+                "cold", rest, eps_cold, self.max_supersteps
             )
-            flow, p, steps, converged, p_overflow, took, tel_buf = unpack(out)
-            spent += int(steps)
+            spent += steps
             spent_sparse += took
         # work accounting covers every attempt (a budget-blown warm
         # attempt's burn included) — the supersteps the DEVICE ran this
@@ -1261,25 +1319,33 @@ class JaxSolver(FlowSolver):
         # stays attempt-local (the ring indexes the final attempt)
         self.last_supersteps = spent
         self.last_sparse_supersteps = spent_sparse
-        # the telemetry budget is the SOLVER's budget (max_supersteps),
-        # not the warm attempt's internal 4096 cap: a warm solve that
-        # converges near 4096 steps is escalated to the cold fallback,
-        # not failed, so cap-proximity against the warm cap would be a
-        # spurious stall event (and would spam the flight ring)
-        self.last_telemetry = (
-            soltel.decode(
-                tel_buf, int(steps), tel_cap, "jax", self.max_supersteps,
-                converged=bool(converged) and not bool(p_overflow),
-                nodes=problem.num_nodes, arcs=len(problem.src),
+        ok = converged and not p_overflow
+        with span("result_readback") as sp:
+            # the telemetry budget is the SOLVER's budget (max_supersteps),
+            # not the warm attempt's internal 4096 cap: a warm solve that
+            # converges near 4096 steps is escalated to the cold fallback,
+            # not failed, so cap-proximity against the warm cap would be a
+            # spurious stall event (and would spam the flight ring)
+            self.last_telemetry = (
+                soltel.decode(
+                    tel_buf, steps, tel_cap, "jax", self.max_supersteps,
+                    converged=ok,
+                    nodes=problem.num_nodes, arcs=len(problem.src),
+                )
+                if tel_buf is not None
+                else None
             )
-            if tel_buf is not None
-            else None
-        )
-        if bool(p_overflow) or not bool(converged):
+            flow_np = np.asarray(flow) if ok else None  # fetched ONCE, for the decode
+            down = (tel_buf.nbytes if tel_buf is not None else 0) + (
+                flow_np.nbytes if ok else 0
+            )
+            self.last_d2h_bytes += down
+            sp.set("bytes", down)
+        if not ok:
             self.reset()  # never reuse the state that failed
-        if bool(p_overflow):
+        if p_overflow:
             raise OverflowError("push-relabel potentials approached int32 range")
-        if not bool(converged):
+        if not converged:
             # non-convergence now carries its interior evidence: the
             # stall detector's structured reason + the decoded ring
             # (the degradation ladder forwards both to flight dumps)
@@ -1290,28 +1356,28 @@ class JaxSolver(FlowSolver):
                 reason=soltel.detect_stall(tel) if tel is not None else None,
                 telemetry=tel,
             )
-        flow_np = np.asarray(flow)  # fetched ONCE, for the decode
-        if self.warm_start:
-            self._prev = flow_np.astype(np.int32)
-            # flow and potentials stay device-resident between rounds:
-            # the next warm attempt consumes the handles directly
-            # instead of re-uploading what the device just produced,
-            # masked against THIS solve's endpoint buffers
-            self._prev_dev = flow if resident else None
-            self._prev_src_dev = problem.d_src if resident else None
-            self._prev_dst_dev = problem.d_dst if resident else None
-            # host-side endpoints at this (successful) solve, for the
-            # non-resident warm mask; problem arrays are snapshots
-            self._prev_src_host = np.asarray(problem.src, np.int32)
-            self._prev_dst_host = np.asarray(problem.dst, np.int32)
-            # endpoint key at this solve: the journal-scoped warm
-            # policy compares the next round's key against it
-            self._key_solved = getattr(problem, "plan_key", None)
-            self._prev_p = p
-        objective = int(
-            (flow_np.astype(np.int64) * problem.cost.astype(np.int64)).sum()  # kschedlint: host-only (int64 objective math on host)
-        ) + lower_bound_cost(problem)
-        return FlowResult(flow=flow_np.astype(np.int64), objective=objective, iterations=spent)  # kschedlint: host-only (FlowResult contract is int64)
+        with span("result_unpack", arcs=len(flow_np)):
+            if self.warm_start:
+                self._prev = flow_np.astype(np.int32)
+                # flow and potentials stay device-resident between rounds:
+                # the next warm attempt consumes the handles directly
+                # instead of re-uploading what the device just produced,
+                # masked against THIS solve's endpoint buffers
+                self._prev_dev = flow if resident else None
+                self._prev_src_dev = problem.d_src if resident else None
+                self._prev_dst_dev = problem.d_dst if resident else None
+                # host-side endpoints at this (successful) solve, for the
+                # non-resident warm mask; problem arrays are snapshots
+                self._prev_src_host = np.asarray(problem.src, np.int32)
+                self._prev_dst_host = np.asarray(problem.dst, np.int32)
+                # endpoint key at this solve: the journal-scoped warm
+                # policy compares the next round's key against it
+                self._key_solved = getattr(problem, "plan_key", None)
+                self._prev_p = p
+            objective = int(
+                (flow_np.astype(np.int64) * problem.cost.astype(np.int64)).sum()  # kschedlint: host-only (int64 objective math on host)
+            ) + lower_bound_cost(problem)
+            return FlowResult(flow=flow_np.astype(np.int64), objective=objective, iterations=spent)  # kschedlint: host-only (FlowResult contract is int64)
 
     def solve(self, problem: FlowProblem) -> FlowResult:
         return self.complete(self.solve_async(problem))

@@ -128,29 +128,19 @@ class PlacementSolver:
             else:
                 with span("problem_snapshot"):
                     problem = self.state.problem()
-        # Byte accounting: in device-resident mode the EXACT nbytes
-        # that crossed the boundary (packed records, or the rebuild
-        # upload); otherwise from the journal just applied — NOT from
-        # the per-round ChangeStats, which miss the previous round's
-        # post-solve mutations (journaled after the round-start stats
-        # reset but shipped in this scatter).
-        if self.resident is not None:
-            get_profiler().note_export(
-                problem,
-                full=self.resident.last_upload_kind == "full_build",
-                exact_bytes=self.resident.last_upload_bytes,
-            )
-        else:
-            get_profiler().note_export(problem, full=full, changes=changes)
+            self._account_export(problem, full, changes)
         # What the decode works on, captured NOW: it must map the
         # snapshot's tasks, not tasks added while the solve is in
         # flight. Pinned tasks are known without it (their mask drops
         # their arcs), so the set holds the unpinned task nodes only.
-        decode_set = (
-            set(gm.unpinned_task_nodes),
-            gm.pinned_mask(problem.num_nodes),
-            gm.num_pinned,
-        )
+        with span(
+            "decode_set", tasks=len(gm.unpinned_task_nodes), nodes=int(problem.num_nodes)
+        ):
+            decode_set = (
+                set(gm.unpinned_task_nodes),
+                gm.pinned_mask(problem.num_nodes),
+                gm.num_pinned,
+            )
         get_profiler().solve_starting()
         try:
             if hasattr(self.backend, "solve_async"):
@@ -160,6 +150,40 @@ class PlacementSolver:
         except BaseException:
             get_profiler().solve_failed()  # stop an Nth-solve capture
             raise
+
+    def _account_export(self, problem, full: bool, changes) -> None:
+        """Byte accounting of the export, inside `graph_export`: in
+        device-resident mode the EXACT nbytes that crossed the boundary
+        (packed records, or the rebuild upload); otherwise from the
+        journal just applied, record by record — NOT from the per-round
+        ChangeStats, which miss the previous round's post-solve
+        mutations (journaled after the round-start stats reset but
+        shipped in this scatter)."""
+        with span(
+            "export_accounting", changes=len(changes) if changes is not None else 0
+        ):
+            if self.resident is not None:
+                get_profiler().note_export(
+                    problem,
+                    full=self.resident.last_upload_kind == "full_build",
+                    exact_bytes=self.resident.last_upload_bytes,
+                )
+            else:
+                get_profiler().note_export(problem, full=full, changes=changes)
+
+    @property
+    def solve_bytes(self):
+        """(host-to-device, device-to-host) bytes of the last solve's
+        own transfers where the configured rung is the scan-CSR solver
+        (JaxSolver.last_h2d_bytes / last_d2h_bytes); zeros on any other
+        rung. The device-resident export's bytes are not in it
+        (`resident.last_upload_bytes`)."""
+        from ..runtime.checkpoint import find_jax_solver
+
+        jaxs = find_jax_solver(self.backend)
+        if jaxs is None:
+            return 0, 0
+        return jaxs.last_h2d_bytes, jaxs.last_d2h_bytes
 
     def _integrity_gate(self, problem):
         """The post-refresh integrity seam: apply any injected device
@@ -233,7 +257,11 @@ class PlacementSolver:
         return problem
 
     def complete(self, token) -> TaskMapping:
-        """Phase 2: synchronize the solve and decode the task mapping."""
+        """Phase 2: synchronize the solve and decode the task mapping.
+        `backend_solve` is the WAIT (and the read-back, the unpacking,
+        the telemetry's publication: its children): the rung starts at
+        `solve_prepare`, in solve_async, and the device has been
+        running since `solve_launch`."""
         problem, (task_node_ids, pinned, num_pinned), pending, is_async = token
         if is_async:
             try:

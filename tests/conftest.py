@@ -114,6 +114,14 @@ def _seeded_rng():
 #: the four modules' purity and stamps, the six entries before the four new ones.
 #: `test_benchmark_sparse_supersteps.py` (PR 50) holds what stays true of the
 #: three pins of `test_benchmark_requests.py` that one more appended entry broke.
+#:
+#: PR 51 (the solve's split, `stats_children_gathered`, `gc_pause_ms`,
+#: `round_unnamed_ms`) appended twelve entries after `supersteps_sparse_p50`, as
+#: ISSUE 51 asks. `test_benchmark_sparse_supersteps.py` pins that entry to the
+#: last place of `per_layer`, and PR 49's four and PR 46's six to "nothing but
+#: `supersteps_sparse_p50` stands after them" (eleven cases in all): state each
+#: as "in one run, in the order they were appended". `test_benchmark_solve_split.py`
+#: holds what stays true of each, a case an entry.
 _STALE = {
     "test_benchmark_seams.py::test_class_only_is_the_old_expression_and_the_same_seed_"
     "draws_the_same_plan[k8s-5000-preemption.rollout-": "PR 38 brings pods/by_role.py",
@@ -175,6 +183,12 @@ _STALE = {
     "this_cell_alone[": "PR 50 appended an entry after the four",
     "test_benchmark_requests.py::test_what_stays_true_of_the_six_entries_before_them[":
         "PR 50 appended an entry after the four that follow the six",
+    "test_benchmark_sparse_supersteps.py::test_the_entry_equals_its_file_and_lists_the_cells_"
+    "of_plan_rows": "PR 51 appended twelve entries after it",
+    "test_benchmark_sparse_supersteps.py::test_each_metric_pr_49_brought_is_still_its_file_"
+    "for_its_cell_alone[": "PR 51 appended twelve entries after supersteps_sparse_p50",
+    "test_benchmark_sparse_supersteps.py::test_the_six_entries_of_pr_46_still_stand_right_"
+    "before_pr_49s_four[": "PR 51 appended twelve entries after supersteps_sparse_p50",
     "test_benchmark_wharemap.py::test_the_traced_rehearsal_is_correct_and_every_metric_reads_"
     "a_number": "PR 48: `ec_arcs_repriced` is visited ECs x `census_machines_dirty` in a round "
                 "that patched, and nearly every arc written changes",

@@ -368,8 +368,8 @@ def test_deltas_are_the_multiset_the_full_walk_produced(model):
         assert all(d[0] == int(DeltaType.PLACE) for d in r["deltas"][0])
         # and they come from the batch, not from every resident task
         t = r["timing"]
-        assert t.deltas_walked == len(r["mapping"]) == r["placed"][0]
-        assert r["ref_delta_calls"] == t.deltas_walked + t.decode_pinned_skipped
+        assert len(r["mapping"]) == r["placed"][0] <= t.decode_tasks
+        assert r["ref_delta_calls"] == len(r["mapping"]) + t.decode_pinned_skipped
 
 
 @PINNED
@@ -411,7 +411,7 @@ def test_under_preemption_mapping_and_deltas_are_as_they_were_order_included(mod
         t = r["timing"]
         assert t.decode_pinned_skipped == 0 and r["pinned_now"] == 0
         assert t.decode_tasks == len(r["unpinned_at_dispatch"]) >= len(r["running_nodes_before"])
-        assert t.deltas_walked == r["ref_delta_calls"]
+        assert len(r["mapping"]) == r["ref_delta_calls"] <= t.decode_tasks
         _same_problem(*r["problems"])
     assert any(r["timing"].decode_tasks > 20 for r in rounds)  # the full walk, by itself
 
@@ -465,11 +465,11 @@ def test_decode_and_deltas_follow_the_batch_whatever_is_resident(resident):
         placed, deltas = sched.schedule_all_jobs()
     assert placed == batch == len(deltas)
     t = sched.last_timing
-    assert (t.decode_tasks, t.decode_pinned_skipped, t.deltas_walked) == (batch, resident, batch)
+    assert (t.decode_tasks, t.decode_pinned_skipped) == (batch, resident)
     (dec,) = [e for e in tracer.events() if e["name"] == "decode"]
     (dlt,) = [e for e in tracer.events() if e["name"] == "deltas"]
     assert (dec["args"]["decode_tasks"], dec["args"]["decode_pinned_skipped"]) == (batch, resident)
-    assert dlt["args"]["deltas_walked"] == batch
+    assert dlt["args"]["parent"] == "round"  # the span stays; the mapping's length is no arg of it
     done = min(500, resident // 2)
     for uid in range(2, 2 + done):
         sched.handle_task_completion(tmap.find(uid))
@@ -478,9 +478,7 @@ def test_decode_and_deltas_follow_the_batch_whatever_is_resident(resident):
     placed, _ = sched.schedule_all_jobs()
     t = sched.last_timing
     assert placed == batch
-    assert (t.decode_tasks, t.decode_pinned_skipped, t.deltas_walked) == (
-        batch, resident + batch - done, batch,
-    )
+    assert (t.decode_tasks, t.decode_pinned_skipped) == (batch, resident + batch - done)
     assert sum(len(v) for v in _lists(rmap).values()) == resident + 2 * batch - done
 
 
@@ -492,7 +490,7 @@ def test_under_preemption_nothing_is_skipped_and_every_running_task_is_decoded()
         placed, _ = sched.schedule_all_jobs()
     t = sched.last_timing
     assert placed == 5
-    assert (t.decode_tasks, t.decode_pinned_skipped, t.deltas_walked) == (65, 0, 65)
+    assert (t.decode_tasks, t.decode_pinned_skipped) == (65, 0)
     (dec,) = [e for e in tracer.events() if e["name"] == "decode"]
     assert (dec["args"]["decode_tasks"], dec["args"]["decode_pinned_skipped"]) == (65, 0)
     assert sum(len(v) for v in _lists(rmap).values()) == 65
@@ -507,11 +505,11 @@ def test_an_unscheduled_backlog_is_decoded_every_round_and_yields_no_delta():
     _admit(sched, jmap, tmap, 7, range(1, 8))  # 7 tasks, 4 slots
     placed, _ = sched.schedule_all_jobs()
     t = sched.last_timing
-    assert (placed, t.decode_tasks, t.decode_pinned_skipped, t.deltas_walked) == (4, 7, 0, 4)
+    assert (placed, t.decode_tasks, t.decode_pinned_skipped) == (4, 7, 0)
     placed, deltas = sched.schedule_all_jobs()
     t = sched.last_timing
     assert (placed, deltas) == (0, [])
-    assert (t.decode_tasks, t.decode_pinned_skipped, t.deltas_walked) == (3, 4, 0)
+    assert (t.decode_tasks, t.decode_pinned_skipped) == (3, 4)
 
 
 def test_a_task_admitted_while_a_pipelined_round_is_in_flight_is_not_decoded_by_it():
@@ -521,11 +519,11 @@ def test_a_task_admitted_while_a_pipelined_round_is_in_flight_is_not_decoded_by_
     _admit(sched, jmap, tmap, 7, range(2001, 2004))
     placed, _ = sched.finish_scheduling()
     t = sched.last_timing
-    assert (placed, t.decode_tasks, t.decode_pinned_skipped, t.deltas_walked) == (5, 5, 50, 5)
+    assert (placed, t.decode_tasks, t.decode_pinned_skipped) == (5, 5, 50)
     assert sched.schedule_all_jobs_async() is not None
     placed, _ = sched.finish_scheduling()
     t = sched.last_timing
-    assert (placed, t.decode_tasks, t.decode_pinned_skipped, t.deltas_walked) == (3, 3, 55, 3)
+    assert (placed, t.decode_tasks, t.decode_pinned_skipped) == (3, 3, 55)
 
 
 def test_a_reused_node_id_starts_unpinned():
@@ -595,7 +593,7 @@ def test_an_evicted_task_with_nowhere_to_go_is_neither_preempted_nor_a_keyerror(
     td = tmap.find(winner)
     assert td.state == TaskState.RUNNABLE and winner in sched.gm.task_to_node
     t = sched.last_timing
-    assert (t.decode_tasks, t.decode_pinned_skipped, t.deltas_walked) == (1, 1, 0)
+    assert (t.decode_tasks, t.decode_pinned_skipped) == (1, 1)
     # and it is placed when the slot frees (a completion frees it one round late)
     sched.handle_task_completion(tmap.find(loser))
     assert sched.schedule_all_jobs()[0] == 0
@@ -610,21 +608,21 @@ def test_an_evicted_task_with_nowhere_to_go_is_neither_preempted_nor_a_keyerror(
 
 
 def _counts(rec):
-    return (rec.decode_tasks, rec.decode_pinned_skipped, rec.deltas_walked)
+    return (rec.decode_tasks, rec.decode_pinned_skipped)
 
 
-def test_the_round_record_carries_the_three_counts():
+def test_the_round_record_carries_the_two_counts():
     seed_rng(0)
     api = SyntheticClusterAPI()
     svc = _service(api, RoundTracer())
     bound, rec = _serve(svc, api, "a", 9)
-    assert (bound, _counts(rec)) == (9, (9, 0, 9))
+    assert (bound, _counts(rec)) == (9, (9, 0))
     bound, rec = _serve(svc, api, "b", 4)
-    assert (bound, _counts(rec)) == (4, (4, 9, 4))
+    assert (bound, _counts(rec)) == (4, (4, 9))
     svc.run_round([], solve=False)
     svc.run_round([])
     for rec in svc.tracer.records[-2:]:
-        assert _counts(rec) == (0, 0, 0)
+        assert _counts(rec) == (0, 0)
 
 
 @pytest.mark.parametrize("kind", ["warm", "cold"])
@@ -635,7 +633,7 @@ def test_a_restore_yields_the_lists_and_counts_of_the_uninterrupted_run(tmp_path
     _serve(svc, api, "a", 9)
     svc.complete_pod("a_0")
     bound, rec = _serve(svc, api, "b", 4)
-    assert (bound, _counts(rec)) == (4, (4, 8, 4))
+    assert (bound, _counts(rec)) == (4, (4, 8))
     ck = str(tmp_path / "svc.ckpt")
     svc.save_checkpoint(ck)
     if kind == "cold":
@@ -661,7 +659,7 @@ def test_a_restore_yields_the_lists_and_counts_of_the_uninterrupted_run(tmp_path
         bound, rec = _serve(s, a, "c", 5)
         assert bound == 5
         recs.append(rec)
-    assert _counts(recs[0]) == _counts(recs[1]) == (5, 11, 5)
+    assert _counts(recs[0]) == _counts(recs[1]) == (5, 11)
     for s in (s1, s2):
         lists = _lists(s.resource_map)
         assert sorted(t for v in lists.values() for t in v) == sorted(s.task_bindings)
