@@ -50,6 +50,12 @@ class FlowProblem:
     Arc lower bounds are already folded into ``excess`` via the standard
     transformation; ``flow_offset`` holds the folded lower bound per arc so
     decoded flows can be restored (decoded_flow = solver_flow + flow_offset).
+    It holds a second thing on the one arc of a leaf (a node whose only
+    live out-arc ends at the sink, the PUs of a served graph): the
+    supply the fold left on that node, which every feasible flow sends
+    over that arc, so it is a lower bound of the arc too and is folded
+    like one (``DeviceGraphState.routed``): the leaf holds no excess,
+    the sink that much more, ``cap`` that much less.
     """
 
     num_nodes: int  # dense extent including padding row
@@ -154,6 +160,22 @@ class DeviceGraphState:
         #: ``excess + fold`` (replaces the O(M) scatter fold the old
         #: problem() ran every round)
         self.fold: Optional[np.ndarray] = None
+        #: per-slot forced supply, folded once more: on the one live
+        #: out-arc of a leaf (a node whose only out-arc ends at the
+        #: sink) the folded supply of the leaf, up to the arc's room.
+        #: It is a lower bound of that arc in every feasible flow, so
+        #: the folded view treats it as one: ``fold`` carries it from
+        #: the leaf to the sink, the folded cap is ``cap - low -
+        #: routed``, ``flow_offset`` is ``low + routed``. Kept by
+        #: `_route` wherever `_set_arc` moves a fold, an out-arc or a
+        #: capacity; ``supply_prerouted`` is its sum
+        self.routed: Optional[np.ndarray] = None
+        self.supply_prerouted = 0
+        #: live out-arcs per node and the sum of their slots: the one
+        #: out-arc of a node of out-degree 1 is slot ``out_sum[node]``
+        self.out_deg: Optional[np.ndarray] = None
+        self.out_sum: Optional[np.ndarray] = None
+        self.sink = -1  # the SINK node's id, once a node says it is one
         self._arc_slot: Dict[Tuple[int, int], int] = {}
         self._free_slots: List[int] = []
         self._num_slots = 0
@@ -229,6 +251,11 @@ class DeviceGraphState:
         self.low = np.zeros(self.m_cap, dtype=np.int32)
         self.cost = np.zeros(self.m_cap, dtype=np.int32)
         self.fold = np.zeros(self.n_cap, dtype=np.int64)  # kschedlint: host-only (host graph arrays; the device mirror is int32)
+        self.routed = np.zeros(self.m_cap, dtype=np.int32)
+        self.supply_prerouted = 0
+        self.out_deg = np.zeros(self.n_cap, dtype=np.int32)
+        self.out_sum = np.zeros(self.n_cap, dtype=np.int64)  # kschedlint: host-only (host graph arrays; the device mirror is int32)
+        self.sink = -1
         self.generation += 1
         self.plan.invalidate()
 
@@ -247,7 +274,7 @@ class DeviceGraphState:
         self.num_nodes = n
         for node in graph.nodes():
             self.excess[node.id] = node.excess
-            self.node_type[node.id] = int(node.type)
+            self._set_node_type(node.id, int(node.type))
         for arc in graph.arcs():
             self._set_arc(arc.src, arc.dst, arc.cap_lower, arc.cap_upper, arc.cost)
         self.rebuild_count += 1  # slot table reassigned: device mirrors resync
@@ -264,6 +291,8 @@ class DeviceGraphState:
             [self.node_type, np.full(new_cap - self.n_cap, -1, np.int8)]
         )
         self.fold = np.concatenate([self.fold, np.zeros(new_cap - self.n_cap, np.int64)])  # kschedlint: host-only (host graph arrays; the device mirror is int32)
+        self.out_deg = np.concatenate([self.out_deg, np.zeros(new_cap - self.n_cap, np.int32)])
+        self.out_sum = np.concatenate([self.out_sum, np.zeros(new_cap - self.n_cap, np.int64)])  # kschedlint: host-only (host graph arrays; the device mirror is int32)
         self.n_cap = new_cap
         self.generation += 1
         self.plan.invalidate()  # regions must cover the new rows
@@ -277,7 +306,7 @@ class DeviceGraphState:
         if new_cap <= self.m_cap:
             return
         pad = new_cap - self.m_cap
-        for name in ("src", "dst", "cap", "low", "cost"):
+        for name in ("src", "dst", "cap", "low", "cost", "routed"):
             arr = getattr(self, name)
             setattr(self, name, np.concatenate([arr, np.zeros(pad, arr.dtype)]))
         self.m_cap = new_cap
@@ -295,12 +324,55 @@ class DeviceGraphState:
         self._num_slots += 1
         return slot
 
+    def _set_node_type(self, node: int, node_type: int) -> None:
+        self.node_type[node] = node_type
+        if node_type == int(NodeType.SINK):
+            self.sink = node
+
+    def _hold(self, slot: int, node: int, units: int) -> None:
+        """Set what the leaf `node` holds routed on its one arc `slot`:
+        the folded view moves the difference from the node to the sink
+        and from the arc's cap to its offset."""
+        moved = units - int(self.routed[slot])
+        self.routed[slot] = units
+        self.fold[node] -= moved
+        self.fold[self.sink] += moved
+        self.supply_prerouted += moved
+        self._touch_slot(slot)
+        self._touch_node(node)
+        self._touch_node(self.sink)
+
+    def _route(self, node: int) -> None:
+        """Keep `routed` at what is forced of `node`: if its only live
+        out-arc ends at the sink, the supply the fold leaves on it (its
+        excess and the lower bounds of the arcs into it) up to the
+        arc's room; a node with another way out, or supply past the
+        arc's capacity, keeps it for the solver. Called after `_set_arc`
+        (or an excess write) moved the node's fold, out-arcs or the
+        arc's capacity; a no-op where nothing of that changed."""
+        if self.out_deg[node] != 1:
+            return
+        slot = int(self.out_sum[node])
+        if self.dst[slot] != self.sink:
+            return
+        held = int(self.routed[slot])
+        # `fold` already carries `held` away from the node
+        supply = int(self.excess[node]) + int(self.fold[node]) + held
+        room = int(self.cap[slot]) - int(self.low[slot])
+        units = max(min(supply, room), 0)
+        if units != held:
+            self._hold(slot, node, units)
+
     def _set_arc(self, src: int, dst: int, low: int, cap: int, cost: int) -> None:
         key = (src, dst)
         slot = self._arc_slot.get(key)
         low0 = int(self.low[slot]) if slot is not None else 0
         if cap == 0 and low == 0:
             if slot is not None:
+                if self.routed[slot]:
+                    self._hold(slot, src, 0)
+                self.out_deg[src] -= 1
+                self.out_sum[src] -= slot
                 self.plan.slot_freed(slot, src, dst)
                 self.endpoint_gen += 1
                 self.cap[slot] = 0
@@ -316,10 +388,19 @@ class DeviceGraphState:
                     self.fold[dst] -= low0
                     self._touch_node(src)
                     self._touch_node(dst)
+                    self._route(dst)
+                self._route(src)  # the arc it has left may be its one
             return
         if slot is None:
+            if self.out_deg[src] == 1:
+                # a second way out: nothing is forced any more
+                only = int(self.out_sum[src])
+                if self.routed[only]:
+                    self._hold(only, src, 0)
             slot = self._take_slot()
             self._arc_slot[key] = slot
+            self.out_deg[src] += 1
+            self.out_sum[src] += slot
             self.plan.slot_assigned(slot, src, dst)
             self.endpoint_gen += 1
         if low != low0:
@@ -335,19 +416,24 @@ class DeviceGraphState:
         self.low[slot] = low
         self.cost[slot] = cost
         self._touch_slot(slot)
+        self._route(src)
+        if low != low0:
+            self._route(dst)
 
     def apply_changes(self, changes: List[Change]) -> None:
         for ch in changes:
             if isinstance(ch, AddNodeChange):
                 self._grow_nodes(ch.node_id + 1)
                 self.excess[ch.node_id] = ch.excess
-                self.node_type[ch.node_id] = int(ch.node_type)
+                self._set_node_type(ch.node_id, int(ch.node_type))
                 self.num_nodes = max(self.num_nodes, ch.node_id + 1)
                 self._touch_node(ch.node_id)
+                self._route(ch.node_id)
             elif isinstance(ch, RemoveNodeChange):
                 self.excess[ch.node_id] = 0
                 self.node_type[ch.node_id] = -1
                 self._touch_node(ch.node_id)
+                self._route(ch.node_id)
             elif isinstance(ch, (NewArcChange, ChangeArcChange)):
                 self._set_arc(ch.src, ch.dst, ch.cap_lower, ch.cap_upper, ch.cost)
             else:  # pragma: no cover
@@ -361,8 +447,15 @@ class DeviceGraphState:
         if int(self.excess[node_id]) != excess:
             self.excess[node_id] = excess
             self._touch_node(node_id)
+            self._route(node_id)
 
     # -- solver view ------------------------------------------------------
+
+    def folded_offset(self, slots=slice(None)) -> np.ndarray:
+        """What the folded view takes off each arc's ``cap`` and hands
+        back as ``flow_offset``: the arc's lower bound and, on a leaf's
+        one arc, the supply routed over it."""
+        return self.low[slots] + self.routed[slots]
 
     def problem(self) -> FlowProblem:
         """Materialize the lower-bound-folded FlowProblem view.
@@ -374,7 +467,8 @@ class DeviceGraphState:
         the arc side (src/dst/cap/cost/flow_offset) invalidate
         independently, and a mutation-free round returns the cached
         FlowProblem outright. The lower-bound fold is the incrementally
-        maintained ``fold`` array (one vector add), not a scatter pass.
+        maintained ``fold`` array (one vector add), not a scatter pass,
+        and so is the leaves' forced supply (``routed``).
         """
         cache = self._cache
         if cache is not None and self._cache_nodes_ok and self._cache_arcs_ok:
@@ -384,12 +478,11 @@ class DeviceGraphState:
             src, dst, cap = cache.src, cache.dst, cache.cap
             cost, flow_offset = cache.cost, cache.flow_offset
         else:
-            low = self.low[:m]
+            flow_offset = self.folded_offset()  # new array
             src = self.src[:m].copy()
             dst = self.dst[:m].copy()
-            cap = self.cap[:m] - low  # folded residual bound (new array)
+            cap = self.cap[:m] - flow_offset  # folded residual bound (new array)
             cost = self.cost[:m].copy()
-            flow_offset = low.astype(np.int32)
         if cache is not None and self._cache_nodes_ok:
             excess, node_type = cache.excess, cache.node_type
         else:
@@ -720,17 +813,16 @@ class DeviceResidentState:
         ka = len(slots)
         rec = np.zeros((bucket, ARC_RECORD_COLS), np.int32)
         if ka:
-            low = st.low[slots]
             rec[:ka, 0] = slots
             rec[:ka, 1] = st.src[slots]
             rec[:ka, 2] = st.dst[slots]
-            rec[:ka, 3] = st.cap[slots] - low
+            rec[:ka, 3] = st.cap[slots] - st.folded_offset(slots)
             rec[:ka, 4] = st.cost[slots]
             rec[ka:] = rec[0]  # idempotent pad: repeat a real record
         else:
             rec[:, 1] = st.src[0]
             rec[:, 2] = st.dst[0]
-            rec[:, 3] = st.cap[0] - st.low[0]
+            rec[:, 3] = st.cap[0] - st.folded_offset(0)
             rec[:, 4] = st.cost[0]
         return rec
 

@@ -46,8 +46,10 @@ CHECKPOINT_VERSION = 1
 #: back-off's wait and clock) and the scheduler the plan counters its
 #: last record saw. 9: the scheduler carries the count of descriptors
 #: its scans looked at since the last record; what it keeps of the jobs
-#: it has walked stays out (it walks them again at its first scan)
-WARM_MANIFEST_VERSION = 9
+#: it has walked stays out (it walks them again at its first scan). 10:
+#: the pickled DeviceGraphState carries the leaves' forced supply
+#: (`routed`, its sum, the out-arc counts and the sink's id)
+WARM_MANIFEST_VERSION = 10
 
 
 class CheckpointError(RuntimeError):

@@ -137,9 +137,12 @@ class RoundRecord:
     plan_regrowths: int = 0
     plan_relayouts: int = 0
     #: records of the change journal the round's export applied (from
-    #: its RoundTiming; 0 when it built the arrays whole), and EC nodes
-    #: the purge removed after `apply`
+    #: its RoundTiming; 0 when it built the arrays whole), units of
+    #: folded supply the export held routed leaf -> sink when it made
+    #: the round's problem (the pinned pods, whose PU has one way out;
+    #: 0 under preemption), and EC nodes the purge removed after `apply`
     journal_changes: int = 0
+    supply_prerouted: int = 0
     ec_purged: int = 0
     #: the round's equivalence classes (from its RoundTiming): EC nodes
     #: and EC -> resource arcs live after `graph_update`, those arcs
@@ -370,6 +373,7 @@ class RoundTracer:
             plan_regrowths=t.plan_regrowths,
             plan_relayouts=t.plan_relayouts,
             journal_changes=t.journal_changes,
+            supply_prerouted=t.supply_prerouted,
             ec_purged=t.ec_purged,
             ec_nodes=t.ec_nodes,
             ec_arcs=t.ec_arcs,

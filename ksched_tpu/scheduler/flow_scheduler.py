@@ -160,6 +160,11 @@ class RoundTiming:
     #: records of the change journal the round's export applied to the
     #: flat arrays (0 when it built them whole; PlacementSolver)
     journal_changes: int = 0
+    #: units of folded supply the export held routed leaf -> sink when
+    #: it made the round's problem: the pinned pods whose PU has no
+    #: other way out (DeviceGraphState.supply_prerouted; 0 under
+    #: preemption, which pins nothing)
+    supply_prerouted: int = 0
     #: EC nodes the round's purge removed, after `apply`
     #: (GraphManager.purge_unconnected_equiv_class_nodes)
     ec_purged: int = 0
@@ -642,6 +647,7 @@ class FlowScheduler:
         """What the round's export applied, and what the device-resident
         mirror shipped for it."""
         timing.journal_changes = self.solver.journal_changes
+        timing.supply_prerouted = self.solver.state.supply_prerouted
         res = self.solver.resident
         if res is not None:
             timing.upload_bytes = res.last_upload_bytes

@@ -281,9 +281,12 @@ PREEMPTION_PRICE_UPDATE_EVERY = 8
 #: whatever the plan (a `top_k` over the nodes, a dozen launches) and
 #: then follows its caps:
 #: the most nodes that may hold excess (+0.1 ms from 4,096 to 8,192 at
-#: 524,288 rows: their reads, span marks and write-back; 8,192 holds the
-#: ~5,030 PUs and arrivals of a 5,000-node cluster's two bulk
-#: supersteps)
+#: 524,288 rows: their reads, span marks and write-back). Since PR 52
+#: no PU holds excess when a round starts (the export routes a pinned
+#: pod's unit PU -> sink itself, `DeviceGraphState.routed`), so a
+#: trickle round's supersteps hold its arrivals, tens of nodes, and
+#: 8,192 is room for a wave of thousands, or for the PUs of a problem
+#: that comes with its pins unrouted (a plain array-built one)
 _ACTIVE_NODES = 8_192
 #: and the most plan rows their regions may span, as a share of the
 #: plan: ~55 ns a compacted row (one gather of the row's four values at

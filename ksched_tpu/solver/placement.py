@@ -90,7 +90,7 @@ class PlacementSolver:
         gm = self.gm
         full = not self._started or not self.incremental
         changes = None
-        with span("graph_export", kind="full_build" if full else "delta"):
+        with span("graph_export", kind="full_build" if full else "delta") as export_span:
             if full:
                 self._started = True
                 with span("journal_apply", kind="full_build", changes=0):
@@ -109,6 +109,7 @@ class PlacementSolver:
             # Sink excess is maintained outside the journal (reference:
             # graph_manager.go:636-640); sync it before each solve.
             self.state.set_excess(gm.sink_node.id, gm.sink_node.excess)
+            export_span.set("supply_prerouted", self.state.supply_prerouted)
             if self.resident is not None:
                 if full:
                     # a slot-stable rung enables the plan at its first

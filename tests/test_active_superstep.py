@@ -305,6 +305,37 @@ def test_a_trickle_round_of_the_served_path_counts_its_sparse_supersteps(monkeyp
     assert solves[-1]["args"]["supersteps_sparse"] == rec.supersteps_sparse
 
 
+def test_every_superstep_of_a_trickle_round_is_sparse_since_no_pu_holds_excess(monkeypatch):
+    """The same service with the telemetry ring on (PR 52). The export
+    routes the 2,000 pins' units PU -> sink itself, so the round's first
+    two supersteps hold its five arrivals and not 50 PUs beside them:
+    `active` starts at the arrivals' count, `pushed` never holds the
+    pins' 2,000 (the parent read [55, 55, 1, ...] and [0, 2005, 0, 5,
+    ...]), and every superstep takes the sparse form."""
+    monkeypatch.setattr(jax_solver, "_ACTIVE_MIN_PLAN_ROWS", 4_096)
+    from ksched_tpu.runtime.trace import RoundTracer
+    from test_k8s_requests_model import Stream
+
+    s = Stream(100, 9, backend="jax", tracer=RoundTracer())
+    rung = s.svc.ladder.primary
+    assert isinstance(rung, JaxSolver)
+    rung.telemetry = 64
+    s.round(np.zeros(2000, int))
+    for k in range(3):
+        objective, served, want, native = s.round([0] * 5, 3)
+        assert objective == served == want == native
+        assert rung.last_sparse_supersteps == rung.last_supersteps == 10
+        tel = rung.last_telemetry
+        assert tel.col("active").tolist() == [5, 5, 1, 1, 1, 1, 1, 1, 1, 1]
+        assert tel.col("pushed").tolist() == [0, 5, 0, 5, 0, 5, 0, 5, 0, 5]
+        rec = s.svc.tracer.records[-1]
+        assert (rec.supersteps_sparse, rec.solver_work) == (10, 10)
+        # the pods bound before the round, less the three that left in it
+        assert rec.supply_prerouted == 2000 + 2 * k - 3
+    state = s.svc.scheduler.solver.state
+    assert not state.problem().excess[sorted(s.svc.scheduler.gm.leaf_node_ids)].any()
+
+
 # ---------------------------------------------------------------------------
 # who gets the sparse form, and what it may hold
 # ---------------------------------------------------------------------------

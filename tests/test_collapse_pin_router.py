@@ -28,6 +28,8 @@ from ksched_tpu.solver.cpu_ref import ReferenceSolver
 from ksched_tpu.solver.graph_collapse import AutoSolver, try_collapse
 from ksched_tpu.utils import seed_rng
 
+from test_export_forced_supply import plain_fold
+
 T = NodeType
 SINK, AGG = 1, 2
 #: the node types of a machine's levels, by the depth of its tree
@@ -321,7 +323,24 @@ def test_a_served_cluster_pins_leaves_only_and_binds_as_the_reference_does():
     assert len(sched.get_task_bindings()) == len(ref.get_task_bindings()) == 15
     pins = [e["args"] for e in tracer.events() if e["name"] == "audit_pins"]
     assert [p["walked"] for p in pins] == [0, 0, 0]
-    # a record a PU that holds pods: its pins share its one sink arc
-    assert [p["pins"] for p in pins] == [busy for _placed, _objective, busy in got]
-    assert pins[0]["pins"] == 0 < pins[1]["pins"] < pins[2]["pins"]
+    # since PR 52 the export has routed a PU's pins over its one sink arc
+    # before the audit looks (`DeviceGraphState.routed`): the audit finds
+    # no resource node with excess, and the export's span says how many
+    # units it holds, the pods bound so far
+    assert [p["pins"] for p in pins] == [0, 0, 0]
+    held = [e["args"]["supply_prerouted"] for e in tracer.events() if e["name"] == "graph_export"]
+    assert held == [0, 5, 9] and len(sched.get_task_bindings()) == 15
+    # the audit routes the same pins, a record a PU that holds pods, where
+    # a problem comes with them on the PUs (the fold of lower bounds alone)
+    problem = sched.solver.state.problem()
+    plain = plain_fold(sched.solver.state)
+    # as the last round's export left it: nine pins on their PUs, six pods that wait
+    assert (plain.total_supply, problem.total_supply) == (15, 6)
+    busy = int(((plain.excess > 0) & (plain.node_type == int(T.PU))).sum())
+    assert not ((problem.excess > 0) & (problem.node_type == int(T.PU))).any()
+    with SpanTracer() as again:
+        collapse, _why = try_collapse(plain)
+    assert collapse is not None
+    (args,) = [e["args"] for e in again.events() if e["name"] == "audit_pins"]
+    assert (args["walked"], args["pins"]) == (0, busy) and busy >= 2
 

@@ -158,21 +158,29 @@ def test_the_audits_sizes_are_the_problems(traced):
     assert last["audit_index"]["arcs"] == len(problem.src)
     assert last["audit_subtrees"]["nodes"] == problem.num_nodes
     # the last round's two pods, one row (one class, one escape cost), the
-    # trivial model's one EC; every resident pod is a folded pin
+    # trivial model's one EC; every resident pod is a folded pin, which the
+    # export has routed to the sink before the audit looks (PR 52)
     assert (last["audit_task_arcs"]["tasks"], last["audit_escapes"]["tasks"]) == (2, 2)
     assert (last["audit_ec_routes"]["ecs"], last["audit_rows"]["rows"]) == (1, 1)
-    assert last["audit_pins"]["pins"] >= 1
+    assert last["audit_pins"]["pins"] == 0
+    held = sorted(_by_name(events)["graph_export"], key=lambda e: e["ts"])[-1]["args"]["supply_prerouted"]
+    assert held == svc.scheduler.solver.state.supply_prerouted >= 1
 
 
 def test_no_served_round_walks_a_pin(traced):
-    """A preemption-off service pins its pods to PUs, whose arcs all end
-    at the sink: the array path routes every one, in every round, and
-    `pins` is the flow records, one a PU that holds pods."""
-    _svc, events, _records = traced
+    """A preemption-off service pins its pods to PUs, whose one arc ends
+    at the sink: since PR 52 the export routes every one (`graph_export`'s
+    `supply_prerouted`, the pods bound so far), so the audit finds no
+    resource node with excess in any round and walks none."""
+    _svc, events, records = traced
     pins = sorted(_by_name(events)["audit_pins"], key=lambda e: e["ts"])
     assert [e["args"]["walked"] for e in pins] == [0, 0, 0, 0]
-    assert pins[0]["args"]["pins"] == 0  # the fill round finds an empty cluster
-    assert all(1 <= e["args"]["pins"] <= MACHINES * PUS for e in pins[1:])
+    assert [e["args"]["pins"] for e in pins] == [0, 0, 0, 0]
+    exports = sorted(_by_name(events)["graph_export"], key=lambda e: e["ts"])
+    held = [e["args"]["supply_prerouted"] for e in exports]
+    assert held[0] == 0  # the fill round finds an empty cluster
+    assert all(1 <= a <= b for a, b in zip(held[1:], held[2:])) and len(held) == 4
+    assert held == [r.supply_prerouted for r in records]
 
 
 def test_a_refused_audit_closes_every_span_it_opened():

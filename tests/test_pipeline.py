@@ -179,8 +179,11 @@ def test_resident_delta_bytes_track_churn_not_graph():
     from ksched_tpu.solver.cpu_ref import ReferenceSolver
 
     seed_rng(3)
+    # eight machines: a round's records (two completions, two binds, and
+    # since PR 52 the PU -> sink slot of each, whose folded bound moves
+    # with the pin) stay one bucket of sixteen whatever the cluster holds
     sched, rmap, jmap, tmap, root = build_cluster(
-        num_machines=4, num_cores=1, pus_per_core=2, max_tasks_per_pu=2,
+        num_machines=8, num_cores=1, pus_per_core=2, max_tasks_per_pu=2,
         backend=ReferenceSolver(),
     )
     sched.solver.device_resident = True
