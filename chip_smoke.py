@@ -3,9 +3,10 @@
 
 One process drives the main paths once, through the entry points a user
 would call, at the sizes BASELINE.json names, and checks what comes out
-by the repo's own means. It is the only on-chip driver of the array,
-kernel, general and sharded paths (the benchmark, benchmarks/run.py,
-times the served path); it states correctness facts and claims no
+by the repo's own means. It is the only on-chip driver of the scanned
+array replays, the kernels, the general and the sharded paths (the
+benchmark, benchmarks/run.py, times the served paths, the array round
+among them since PR 53); it states correctness facts and claims no
 speed:
 
   served   cli's SchedulerService over SyntheticClusterAPI, 1,000 fake
@@ -14,7 +15,12 @@ speed:
            warm churn round; objective equal to `--backend native` (the
            C++ solver, the independent reference) on the same seeded
            input. Then round 1 again under `--backend auto`, recording
-           which path answered.
+           which path answered. Then `--array-round` through the same
+           `cli.build_service`, at the `array` phase's geometry: the
+           fill and two rounds of arrivals and completions, every round
+           converged and its Bindings at the optimum of the plain
+           reference (benchmarks/reference_coco.py), the host's mirror
+           equal to the device's table.
   array    DeviceBulkCluster at the coco50k geometry (50,000 tasks on
            1,000 machines x 4 PUs x 16 slots, decode width 1024): fill
            round + 3 x 32 steady rounds, tools/soak.py's invariants, and
@@ -319,7 +325,78 @@ class Smoke:
             f"round2[bound={jax_rounds[1]['bound']} supersteps={jax_rounds[1]['supersteps']} "
             f"objective={jax_rounds[1]['objective']}==native wall={jax_rounds[1]['wall_s']}s] "
             f"noop_rounds=0 | backend=auto round1[last_path={auto['path']} "
-            f"supersteps={auto['supersteps']}]"
+            f"supersteps={auto['supersteps']}] | {self._serve_array()}"
+        )
+
+    def _serve_array(self) -> str:
+        """`--array-round` through `cli.build_service` at the `array` leg's
+        geometry: the fill and two served rounds with arrivals and
+        completions; every round converged, its Bindings at the plain
+        reference's optimum on a census kept from the Bindings alone, and
+        the device's table equal to the service's mirror at the end."""
+        from benchmarks.reference_coco import cost_matrix, reference_round
+        from ksched_tpu import cli
+        from ksched_tpu.cluster import SyntheticClusterAPI
+        from ksched_tpu.cluster.api import PodEvent
+
+        sz = self.sizes["array"]
+        tasks, machines = sz["tasks"], sz["machines"]
+        churn = max(8, tasks // 100)
+        args = cli.build_arg_parser().parse_args([
+            "--fake-machines", "--num-machines", str(machines), "--pus-per-core", "4",
+            "--max-tasks-per-pu", "16", "--cost-model", "coco", "--array-round",
+            "--pod-chan-size", str(tasks + churn), "--pod-batch-timeout", "0.2",
+        ])
+        api = SyntheticClusterAPI(pod_chan_size=args.pod_chan_size)
+        svc = cli.build_service(args, api)
+        svc.init_topology(
+            fake_machines=machines, cores_per_machine=args.cores_per_machine,
+            pus_per_core=args.pus_per_core,
+        )
+        rng = np.random.default_rng(self.seed + 2)
+        census = np.zeros((machines, 4), np.int64)
+        class_of, where, walls, costs = {}, {}, [], []
+        seen: set = set()  # pods whose Binding an earlier round posted
+        for r in range(3):
+            n = churn if r else tasks
+            if r:
+                for pod in sorted(where)[:churn]:
+                    check(svc.complete_pod(pod), f"array service: {pod} was not bound")
+                    census[where.pop(pod), class_of[pod]] -= 1
+            pods = [PodEvent(f"a{r}_{i}", task_class=int(c))
+                    for i, c in enumerate(rng.integers(0, 4, n))]
+            class_of.update((p.pod_id, p.task_class) for p in pods)
+            before = len(api.bindings())
+            t0 = time.perf_counter()
+            bound = svc.run_round(pods)
+            walls.append(round(time.perf_counter() - t0, 3))
+            check(bound == n, f"array service round {r + 1}: bound {bound} of {n}")
+            check(svc.unconverged_rounds == 0, f"array service round {r + 1} did not converge")
+            new = {pod: node for pod, node in api.bindings().items() if pod not in seen}
+            seen.update(new)
+            check(len(new) == n and len(api.bindings()) == before + n,
+                  f"array service round {r + 1}: {len(new)} Bindings posted, want {n}")
+            at = {pod: int(node.rsplit("_", 1)[1]) for pod, node in new.items()}
+            cost = cost_matrix(census)
+            served = sum(int(cost[class_of[pod], m]) for pod, m in at.items())
+            want = reference_round(np.bincount([class_of[p] for p in at], minlength=4), census, 64)
+            check(served == want, f"array service round {r + 1}: Bindings cost {served}, optimum {want}")
+            where.update(at)
+            for pod, m in at.items():
+                census[m, class_of[pod]] += 1
+            costs.append(served)
+        svc.flush_pending_bindings()
+        st = svc.cluster.fetch_state()
+        live, pu = np.asarray(st["live"]), np.asarray(st["pu"])
+        check(set(np.flatnonzero(live).tolist()) == set(svc.row_of.values()),
+              "array service: the mirror's rows are not the device's live rows")
+        check(bool((np.where(live, pu, -1) == svc.pu_of_row).all()),
+              "array service: the mirror's PUs are not the device's")
+        api.close()
+        return (
+            f"array-round[tasks={tasks} machines={machines}x4x16 rows={svc.cluster.Tcap} "
+            f"fill+2x{churn}: every round converged, cost=={costs} the reference's optimum, "
+            f"mirror==table, walls={walls}s]"
         )
 
     # -- array path --------------------------------------------------------

@@ -715,6 +715,25 @@ def trace_corrupt_flip(n_raw: int = 20, m_raw: int = 100):
     return jax.make_jaxpr(corrupt_fn())(_sds((m,)), _sds(()), _sds(()))
 
 
+def trace_array_served_round(machines: int, rows: int, decode_width=None):
+    """Abstract trace of the served array round
+    (scheduler/device_bulk.py `served_round`): CoCo's cost function over
+    `machines` machines of 4 PUs x 16 slots and a table of `rows` task
+    rows, the decode `decode_width` rows wide (None: every row)."""
+    from ..costmodels import coco
+    from ..costmodels.device_costs import coco_device_cost_fn
+    from ..scheduler.device_bulk import DeviceBulkCluster
+
+    dev = DeviceBulkCluster(
+        num_machines=machines, pus_per_machine=4, slots_per_pu=16, num_jobs=1,
+        num_task_classes=4, task_capacity=rows, class_cost_fn=coco_device_cost_fn(),
+        unsched_cost=coco.UNSCHEDULED_COST, ec_cost=0,
+    )
+    return jax.make_jaxpr(
+        dev._served_round_jit.__wrapped__, static_argnums=(2,)
+    )(dev.state, dev.groups, decode_width)
+
+
 TRACERS = {
     "jax": trace_jax,
     "layered": trace_layered,

@@ -480,6 +480,24 @@ _SPECS = (
         collectives=CollectiveBudget(forbidden=_ALL_COLLECTIVES),
         notes="cost pre-scaling (cost * n) before a device solve",
     ),
+    ProgramSpec(
+        name="array_served_round", module="ksched_tpu.scheduler.device_bulk",
+        kind="solve", tracer="trace_array_served_round", trace=call(12, 1024),
+        extra=(call(12, 1024, decode_width=256),),
+        scatter_policy="scoped-exempt",
+        hash_stability=HashStability(
+            "exempt", reason="one table, one set of decode widths a service: "
+            "the shapes are fixed when the cluster is built and no size is bucketed",
+        ),
+        distinct_from=(),
+        collectives=CollectiveBudget(forbidden=_ALL_COLLECTIVES),
+        notes="the array round as `--array-round` serves it (PR 53): the class "
+        "census, CoCo's costs, the dense transport and the rank-match decode over "
+        "a window of the table's unplaced rows, then the rows it placed, "
+        "compacted. It scatters by design, once a round and outside the "
+        "transport's loop: the census and the supply are scatter-adds over the "
+        "table's rows, the window's placements a scatter back",
+    ),
     # -- audit programs (integrity fingerprints — normal round cadence,
     #    so NO scatter exemption) ---------------------------------------
     ProgramSpec(

@@ -124,7 +124,136 @@ def fake_cores(num_machines: int, cores_per_machine: int, types: Sequence[Machin
     return sum(machine_type_of(i, types)[1] for i in range(num_machines))
 
 
-class SchedulerService:
+class ServiceLoop:
+    """What every service's loop shares, whatever runs inside its round:
+    `run` (poll, round or idle sweep, until the control plane closes),
+    `run_round` (the `service_round` span with the batch's queue wait,
+    the collector's mark, the flight ring), the POST, and the control
+    plane's retries since the round before. A service brings
+    `_run_round_body(pods, now, solve, queue_wait) -> (RoundRecord or
+    None, pods bound)`, `flush_pending_bindings()` for what the loop's
+    end must not strand, and the attributes read here: `api`, `tracer`,
+    `flight`, `span_tracer`, `tenant`, `injector`, `backlog_dirty`,
+    `_gc_mark`, `_api_stats_mark`. `SchedulerService` (the graph path)
+    and `scheduler/array_service.ArrayRoundService` (`--array-round`)
+    are the two."""
+
+    def _queue_wait_ms(self, pods, round_t0_s: float) -> Tuple[float, float]:
+        """Mean and max (ms) of how long the batch's pods sat in the
+        channel before their round opened. A re-delivered pod counts
+        like any other: it waited there too. One pass over the batch,
+        and only when someone reads the result (a RoundTracer attached
+        or a SpanTracer installed); 0.0 for a round without pods."""
+        if not pods or (self.tracer is None and active_tracer() is None):
+            return 0.0, 0.0
+        waits = [round_t0_s - pod.received_s for pod in pods]
+        return sum(waits) / len(waits) * 1e3, max(waits) * 1e3
+
+    def _post_bindings(self, out: List[Binding], evictions: Sequence[Binding] = ()) -> None:
+        """The POST, under the one name it has on every path; before it,
+        the round's evictions in one call, so that the control plane
+        never sees a node over its capacity."""
+        if evictions:
+            with span("evictions_post", n=len(evictions)):
+                self.api.evict_pods(evictions)
+        if out:
+            with span("bindings_post", n=len(out)):
+                self.api.assign_bindings(out)
+
+    def run_round(
+        self, pods, now: Optional[float] = None, solve: bool = True
+    ) -> int:
+        """One hardened round: run_once under the deadline watchdog with
+        the degradation ladder's NOOP backstop, then a heartbeat sweep,
+        then trace attribution (faults / retries / degradations /
+        expiries → this round's RoundRecord). ``now`` is the heartbeat
+        sweep's injected clock (the chaos soak drives logical time).
+
+        ``solve=False`` is the idle sweep: heartbeat check + trace
+        attribution only, no graph rebuild/solve — run() uses it on
+        quiet polls while the backlog is clean, so a steady-state
+        service costs a sweep per batch timeout, not a full MCMF
+        solve. Recorded with ``solver_rung`` -1 and ``noop_round``
+        False (a NOOP is a *failed* solve; this is a skipped one).
+
+        With a span tracer and flight recorder attached, the whole
+        round runs under a ``service_round`` span and the round's
+        record + span slice are deposited in the flight ring (which
+        auto-dumps on a deadline miss or NOOP round)."""
+        span_mark = self.span_tracer.mark() if self.span_tracer is not None else 0
+        span_args = dict(pods=len(pods), solve=solve)
+        if self.tenant:
+            span_args["tenant"] = self.tenant
+        rec = None
+        self._gc_mark = gc_pause_total_s()
+        with span("service_round", **span_args) as sp:
+            queue_wait = self._queue_wait_ms(pods, sp.t0_s)
+            sp.set("queue_wait_ms", queue_wait[0])
+            sp.set("queue_wait_max_ms", queue_wait[1])
+            rec, bound = self._run_round_body(pods, now, solve, queue_wait)
+        self._note_flight(rec, span_mark)
+        return bound
+
+    def _note_flight(self, rec, span_mark: int, span_prefix=None) -> None:
+        if self.flight is not None and rec is not None:
+            events = (
+                self.span_tracer.events_since(span_mark)
+                if self.span_tracer is not None
+                else None
+            )
+            if span_prefix:
+                events = list(span_prefix) + (events or [])
+            self.flight.note_round(rec, events)
+
+    def run(self, pod_batch_timeout_s: float = 2.0, max_rounds: Optional[int] = None) -> None:
+        """The hardened main loop. Exits only when the control plane is
+        actually closed; an empty batch with the channel still open —
+        the signature of a transient API-server outage (or plain quiet)
+        — idles through a sweep-only round instead of exiting, so the
+        scheduler rides out outages and still detects silent machines
+        while no pods arrive. Idle rounds do not count against
+        ``max_rounds`` (which counts scheduling rounds, as before)."""
+        rounds = 0
+        tick = 0  # injector rounds: one per loop iteration, idle or not
+        while max_rounds is None or rounds < max_rounds:
+            if self.injector is not None:
+                # `tick`, not `rounds`: an idle round is still one full
+                # pass (poll + run_round), so outage windows must count
+                # down and fault draws advance exactly once per
+                # iteration — re-passing a stale index would re-roll the
+                # same round's draws every poll during an outage.
+                self.injector.begin_round(tick)
+            tick += 1
+            pods = self.api.poll_pod_batch(pod_batch_timeout_s)
+            if not pods:
+                if self.api.is_closed():
+                    break  # control plane closed: clean shutdown
+                # Transient outage / quiet channel: sweep-only idle
+                # round — unless a NOOP round or an eviction left
+                # runnable backlog behind, in which case this quiet
+                # poll is the moment to re-solve it.
+                self.run_round([], solve=self.backlog_dirty)
+                continue
+            self.run_round(pods)
+            rounds += 1
+        # pipelined loops defer each round's POSTs into the next
+        # dispatch window; the last round's must not be stranded
+        self.flush_pending_bindings()
+
+    def _retries_since_last_round(self) -> int:
+        """Retry and re-post attempts of the control plane since the
+        round before. Only those counters: the stats surface also
+        carries drop counters (binding_drops), a different signal that
+        would silently inflate it."""
+        api_stats = self.api.stats() if hasattr(self.api, "stats") else {}
+        retries = sum(
+            api_stats.get(k, 0) - self._api_stats_mark.get(k, 0) for k in RETRY_STAT_KEYS
+        )
+        self._api_stats_mark = api_stats
+        return retries
+
+
+class SchedulerService(ServiceLoop):
     """The long-running scheduler process state (reference:
     cmd/k8sscheduler/scheduler.go:44-87), hardened: the configured
     backend rides a degradation ladder (configured → scan-CSR jax →
@@ -519,17 +648,6 @@ class SchedulerService:
             if jd is not None:
                 self.scheduler.add_job(jd)
 
-    def _queue_wait_ms(self, pods, round_t0_s: float) -> Tuple[float, float]:
-        """Mean and max (ms) of how long the batch's pods sat in the
-        channel before their round opened. A re-delivered pod counts
-        like any other: it waited there too. One pass over the batch,
-        and only when someone reads the result (a RoundTracer attached
-        or a SpanTracer installed); 0.0 for a round without pods."""
-        if not pods or (self.tracer is None and active_tracer() is None):
-            return 0.0, 0.0
-        waits = [round_t0_s - pod.received_s for pod in pods]
-        return sum(waits) / len(waits) * 1e3, max(waits) * 1e3
-
     def _find_parent_machine(self, pu_rid: int) -> Optional[int]:
         """Walk a PU up the topology to its machine (reference :379-398)."""
         rs = self.resource_map.find(pu_rid)
@@ -595,17 +713,6 @@ class SchedulerService:
             sp.set("new", len(out))
             sp.set("evicted", len(evictions))
         return evictions, out
-
-    def _post_bindings(self, out: List[Binding], evictions: Sequence[Binding] = ()) -> None:
-        """The POST, under the one name it has on every path; before it,
-        the round's evictions in one call, so that the control plane
-        never sees a node over its capacity."""
-        if evictions:
-            with span("evictions_post", n=len(evictions)):
-                self.api.evict_pods(evictions)
-        if out:
-            with span("bindings_post", n=len(out)):
-                self.api.assign_bindings(out)
 
     def flush_pending_bindings(self) -> int:
         """POST the previous pipelined round's bindings. Called inside
@@ -687,51 +794,6 @@ class SchedulerService:
         if flush_err is not None:
             raise flush_err
         return len(out)
-
-    def run_round(
-        self, pods, now: Optional[float] = None, solve: bool = True
-    ) -> int:
-        """One hardened round: run_once under the deadline watchdog with
-        the degradation ladder's NOOP backstop, then a heartbeat sweep,
-        then trace attribution (faults / retries / degradations /
-        expiries → this round's RoundRecord). ``now`` is the heartbeat
-        sweep's injected clock (the chaos soak drives logical time).
-
-        ``solve=False`` is the idle sweep: heartbeat check + trace
-        attribution only, no graph rebuild/solve — run() uses it on
-        quiet polls while the backlog is clean, so a steady-state
-        service costs a sweep per batch timeout, not a full MCMF
-        solve. Recorded with ``solver_rung`` -1 and ``noop_round``
-        False (a NOOP is a *failed* solve; this is a skipped one).
-
-        With a span tracer and flight recorder attached, the whole
-        round runs under a ``service_round`` span and the round's
-        record + span slice are deposited in the flight ring (which
-        auto-dumps on a deadline miss or NOOP round)."""
-        span_mark = self.span_tracer.mark() if self.span_tracer is not None else 0
-        span_args = dict(pods=len(pods), solve=solve)
-        if self.tenant:
-            span_args["tenant"] = self.tenant
-        rec = None
-        self._gc_mark = gc_pause_total_s()
-        with span("service_round", **span_args) as sp:
-            queue_wait = self._queue_wait_ms(pods, sp.t0_s)
-            sp.set("queue_wait_ms", queue_wait[0])
-            sp.set("queue_wait_max_ms", queue_wait[1])
-            rec, bound = self._run_round_body(pods, now, solve, queue_wait)
-        self._note_flight(rec, span_mark)
-        return bound
-
-    def _note_flight(self, rec, span_mark: int, span_prefix=None) -> None:
-        if self.flight is not None and rec is not None:
-            events = (
-                self.span_tracer.events_since(span_mark)
-                if self.span_tracer is not None
-                else None
-            )
-            if span_prefix:
-                events = list(span_prefix) + (events or [])
-            self.flight.note_round(rec, events)
 
     def _run_round_body(self, pods, now, solve, queue_wait):
         deg_mark = self.ladder.degradations_total if self.ladder is not None else 0
@@ -908,15 +970,7 @@ class SchedulerService:
                     snap = self.injector.snapshot()
                     faults = delta_counters(self._fault_mark, snap)
                     self._fault_mark = snap
-                api_stats = self.api.stats() if hasattr(self.api, "stats") else {}
-                # Only retry/re-post counters belong in `retries`; the stats
-                # surface also carries drop counters (binding_drops), which
-                # are a different signal and would silently inflate it.
-                retries = sum(
-                    api_stats.get(k, 0) - self._api_stats_mark.get(k, 0)
-                    for k in RETRY_STAT_KEYS
-                )
-                self._api_stats_mark = api_stats
+                retries = self._retries_since_last_round()
                 rec = self.tracer.record_flow_round(
                     self.scheduler,
                     bound,
@@ -957,41 +1011,6 @@ class SchedulerService:
                 self._post_defer_ms = 0.0
                 self._pods_evicted = self._pods_migrated = 0
         return rec
-
-    def run(self, pod_batch_timeout_s: float = 2.0, max_rounds: Optional[int] = None) -> None:
-        """The hardened main loop. Exits only when the control plane is
-        actually closed; an empty batch with the channel still open —
-        the signature of a transient API-server outage (or plain quiet)
-        — idles through a sweep-only round instead of exiting, so the
-        scheduler rides out outages and still detects silent machines
-        while no pods arrive. Idle rounds do not count against
-        ``max_rounds`` (which counts scheduling rounds, as before)."""
-        rounds = 0
-        tick = 0  # injector rounds: one per loop iteration, idle or not
-        while max_rounds is None or rounds < max_rounds:
-            if self.injector is not None:
-                # `tick`, not `rounds`: an idle round is still one full
-                # pass (poll + run_round), so outage windows must count
-                # down and fault draws advance exactly once per
-                # iteration — re-passing a stale index would re-roll the
-                # same round's draws every poll during an outage.
-                self.injector.begin_round(tick)
-            tick += 1
-            pods = self.api.poll_pod_batch(pod_batch_timeout_s)
-            if not pods:
-                if self.api.is_closed():
-                    break  # control plane closed: clean shutdown
-                # Transient outage / quiet channel: sweep-only idle
-                # round — unless a NOOP round or an eviction left
-                # runnable backlog behind, in which case this quiet
-                # poll is the moment to re-solve it.
-                self.run_round([], solve=self.backlog_dirty)
-                continue
-            self.run_round(pods)
-            rounds += 1
-        # pipelined loops defer each round's POSTs into the next
-        # dispatch window; the last round's must not be stranded
-        self.flush_pending_bindings()
 
     # -- service checkpoint (scheduler state + the id maps) ----------------
 
@@ -1467,6 +1486,13 @@ def build_arg_parser() -> argparse.ArgumentParser:
                     "between rounds: after the first full upload only "
                     "packed delta records cross the host/device boundary "
                     "(graph/device_export.DeviceResidentState)")
+    ap.add_argument("--array-round", action="store_true",
+                    help="keep the cluster in device arrays and run each "
+                    "scheduling round as one device program "
+                    "(scheduler/array_service.py over DeviceBulkCluster): "
+                    "fake or polled machines that are alike, --cost-model "
+                    "coco, one job, no preemption; every other flag that "
+                    "changes what a round does is refused with a sentence")
     ap.add_argument("--audit-every", type=int, default=0, metavar="N",
                     help="device-state integrity audit cadence: every Nth "
                     "round, fingerprint the persistent device buffers "
@@ -1507,13 +1533,59 @@ def build_arg_parser() -> argparse.ArgumentParser:
     return ap
 
 
+#: what `--array-round` does not serve yet (ROADMAP R1's later steps):
+#: (the flag, whether `args` sets it, why the array round cannot honour it)
+_ARRAY_ROUND_REFUSES = (
+    ("--preemption", lambda a: a.preemption,
+     "its round pins a pod where it places it and posts no eviction"),
+    ("--pipeline", lambda a: a.pipeline,
+     "its round is one device program with nothing to overlap a POST with"),
+    ("--device-resident", lambda a: a.device_resident,
+     "that flag keeps the GRAPH path's arrays on the device; the array round has no graph"),
+    ("--audit-every", lambda a: a.audit_every,
+     "it audits the graph path's device mirror, which the array round does not keep"),
+    ("--tenants", lambda a: a.tenants, "it keeps one table for one cluster"),
+    ("--fake-machine-types", lambda a: a.fake_machine_types,
+     "the machines of its table are alike"),
+    ("--fake-zones", lambda a: a.fake_zones, "its cost function reads no node label"),
+    ("--fake-racks", lambda a: a.fake_racks, "its cost function reads no node label"),
+    ("--fake-node-allocatable", lambda a: a.fake_node_allocatable != (0, 0),
+     "its pods are alike in size: a slot a pod"),
+    ("--machine-timeout", lambda a: a.machine_timeout > 0,
+     "no heartbeat reaches its table yet"),
+    ("--cost-model {cost_model}", lambda a: a.cost_model != "coco",
+     "the one cost function it emits into device buffers is coco's"),
+    ("--backend {backend}", lambda a: a.backend != "native",
+     "its round is the dense transport on the device and dispatches to no backend: "
+     "leave --backend out"),
+)
+
+
+def refuse_unserved_by_array_round(args) -> None:
+    """ValueError with one sentence naming the flag, for the first flag
+    of `args` that `--array-round` does not serve."""
+    for flag, is_set, why in _ARRAY_ROUND_REFUSES:
+        if is_set(args):
+            raise ValueError(
+                f"--array-round is not served together with {flag.format(**vars(args))}: {why}"
+            )
+
+
 def build_service(
     args, api: ClusterAPI, *, tracer=None, flight=None, span_tracer=None
-) -> SchedulerService:
+) -> ServiceLoop:
     """The service for parsed flags `args`, exactly as `main` serves it
     (chip_smoke.py drives this same construction round by round)."""
     from .solver.select import make_backend
 
+    if args.array_round:
+        from .scheduler.array_service import ArrayRoundService
+
+        refuse_unserved_by_array_round(args)
+        return ArrayRoundService(
+            api, max_tasks_per_pu=args.max_tasks_per_pu, tracer=tracer, flight=flight,
+            span_tracer=span_tracer, round_deadline_s=args.round_deadline,
+        )
     if args.fake_machine_types and args.cores_per_machine != 1:
         raise ValueError(
             "--fake-machine-types gives every type its cores: it is not served "
@@ -1651,6 +1723,12 @@ def main(argv=None) -> int:
             )
         )
 
+    if args.array_round:
+        try:
+            # before the branch below, which builds no service through build_service
+            refuse_unserved_by_array_round(args)
+        except ValueError as e:
+            ap.error(str(e))
     if args.tenants > 0:
         return _run_multi_tenant(args, span_tracer, metrics_server)
 

@@ -204,6 +204,19 @@ class RoundRecord:
     pods_evicted: int = 0
     pods_migrated: int = 0
     pods_pending_evicted: int = 0
+    #: --array-round (scheduler/array_service.py; zeros on the graph
+    #: path): rows of the device's task table that hold a pod after the
+    #: round, exact bytes the round shipped to the device (completed
+    #: rows, the batch's classes, the counts) and read back from it (the
+    #: round's scalars, and the rows it placed with their PUs where it
+    #: placed any), pods that hold a row and no PU after the round, and
+    #: 1 if the round's transport hit its superstep bound before it
+    #: converged (its placements are then not an optimum's)
+    array_rows_live: int = 0
+    array_h2d_bytes: int = 0
+    array_d2h_bytes: int = 0
+    array_pods_waiting: int = 0
+    array_unconverged: int = 0
 
 
 class RoundTracer:
@@ -398,10 +411,7 @@ class RoundTracer:
             bound_on_preferred_share=t.bound_on_preferred_share,
             remote_bytes_share=t.remote_bytes_share,
         )
-        for k, v in (extra or {}).items():
-            if not hasattr(rec, k):
-                raise ValueError(f"unknown RoundRecord field {k!r}")
-            setattr(rec, k, v)
+        self._set_extra(rec, extra)
         self._append(rec)
         return rec
 
@@ -421,12 +431,15 @@ class RoundTracer:
         total_ms: Optional[float] = None,
         num_scheduled: int = 0,
         solver_work: int = 0,
+        extra: Optional[Dict] = None,
     ) -> RoundRecord:
         """Capture an externally timed round from a `{phase}_s` dict.
         `total_ms` overrides the summed-phases total with a measured
         wall time. This is the one place the timing-key → phase-name
         mapping lives, so a caller that times its own rounds records
-        exactly the series the service publishes."""
+        exactly the series the service publishes. ``extra`` sets further
+        fields of the record, as in `record_flow_round`: an unknown key
+        is rejected."""
         phases_ms = {k[:-2]: v * 1e3 for k, v in timing.items()}
         phases_ms["total"] = (
             total_ms if total_ms is not None else sum(phases_ms.values())
@@ -438,8 +451,16 @@ class RoundTracer:
             num_scheduled=num_scheduled,
             solver_work=solver_work,
         )
+        self._set_extra(rec, extra)
         self._append(rec)
         return rec
+
+    @staticmethod
+    def _set_extra(rec: RoundRecord, extra: Optional[Dict]) -> None:
+        for k, v in (extra or {}).items():
+            if not hasattr(rec, k):
+                raise ValueError(f"unknown RoundRecord field {k!r}")
+            setattr(rec, k, v)
 
     def _append(self, rec: RoundRecord) -> None:
         self._publish(rec)

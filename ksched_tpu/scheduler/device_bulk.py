@@ -82,6 +82,11 @@ class GroupSpec(NamedTuple):
     #                      PREF_NONE where the group has no preference
 
 
+#: the scalars of a round in the order `serve_round`'s summary holds them
+SERVED_SUMMARY = (
+    "placed", "unscheduled", "converged", "cost_overflow", "supersteps", "live", "objective",
+)
+
 #: pref_w fill for "no preference": large enough to never win the min
 #: against any guarded route cost, small enough that min() arithmetic
 #: cannot overflow int32
@@ -393,6 +398,7 @@ class DeviceBulkCluster:
         #: steady-round arrival group draw map (see set_arrival_groups)
         self._arrival_map = jnp.arange(max(self.G, 1), dtype=jnp.int32)
         self._arrival_n = jnp.int32(max(self.G, 1))
+        self._zeros_by_width: dict = {}
         self._build_programs()
         self.last_stats: Optional[dict] = None
         self.last_admitted = None  # device i32 from the latest add_tasks
@@ -1378,13 +1384,15 @@ class DeviceBulkCluster:
 
         def admit(state: DeviceClusterState, jobs, classes, groups, count):
             """Occupy the first `count` free rows with the first `count`
-            entries of (jobs, classes, groups). Returns (state,
-            admitted): admitted < count when the task pool is exhausted
-            — the host BulkCluster raises for this; here the shortfall
-            is reported so add_tasks can check it after fetch."""
+            entries of (jobs, classes, groups), which are as wide as the
+            caller shipped them (Tcap, or a served batch's bucket).
+            Returns (state, admitted): admitted < count when the task
+            pool is exhausted — the host BulkCluster raises for this;
+            here the shortfall is reported so add_tasks can check it
+            after fetch."""
             free_rank = jnp.cumsum(~state.live) - 1  # rank among free rows
             newmask = ~state.live & (free_rank < count)
-            src_idx = jnp.clip(free_rank, 0, Tcap - 1)
+            src_idx = jnp.clip(free_rank, 0, classes.shape[0] - 1)
             admitted = jnp.sum(newmask, dtype=i32)
             return state._replace(
                 live=state.live | newmask,
@@ -1395,8 +1403,9 @@ class DeviceBulkCluster:
             ), admitted
 
         def complete(state: DeviceClusterState, rows, count):
-            """Retire `count` task rows (first `count` entries of `rows`)."""
-            k = jnp.arange(Tcap, dtype=jnp.int32)
+            """Retire `count` task rows (first `count` entries of `rows`,
+            which is as wide as the caller shipped it)."""
+            k = jnp.arange(rows.shape[0], dtype=jnp.int32)
             sel = k < count
             idx = jnp.where(sel, rows, Tcap)
             done = jnp.zeros(Tcap + 1, jnp.bool_).at[idx].set(True)[:Tcap]
@@ -1625,6 +1634,42 @@ class DeviceBulkCluster:
         self._set_machine_jit = jax.jit(set_machine, static_argnums=(2,))  # kschedlint: disable=unregistered-program -- device-bulk replay machinery, bit-parity gated by tests/test_device_bulk.py
         self._census_jit = jax.jit(census_of)  # kschedlint: disable=unregistered-program -- device-bulk replay machinery, bit-parity gated by tests/test_device_bulk.py
 
+        def served_round(state: DeviceClusterState, gspec, decode_width):
+            """The one-shot round as a service runs it, with what it
+            moved: `round()`'s program over a decode window of
+            `decode_width` unplaced rows (static; None: every row. The
+            caller takes a window that holds the whole backlog, where
+            the bounded decode is bit-identical to the full one), then
+            the rows it placed, in row order: `rows` i32[K] and the PU
+            of each, `pus` i32[K], K the window's width, compacted to the
+            front with Tcap past the last one; over every row (K = Tcap)
+            row r stands at r, Tcap where it did not move: the caller
+            keeps `rows < Tcap` either way. The round's scalars come as ONE array,
+            `summary` i32[len(SERVED_SUMMARY)], so that the host waits
+            for one transfer. What a service reads back a round is sized
+            to its batch, not to the table."""
+            new, stats = round_core(state, gspec, decode_width=decode_width)
+            moved = state.live & (state.pu < 0) & (new.pu >= 0)
+            if decode_width is None:
+                # every row is in the window: nothing to compact
+                rows = jnp.where(moved, jnp.arange(Tcap, dtype=i32), i32(Tcap))
+                pus = new.pu
+            else:
+                # the k-th row that moved, by binary search in the running
+                # count, as the decode window finds its rows: K searches
+                # where `jnp.nonzero(size=K)` scatters an update a row of
+                # the table (PERF.md section 6, PR 50: ~7 ns a row, 0.46 ms
+                # at 65,536 rows whatever K)
+                ranks = jnp.arange(1, int(decode_width) + 1, dtype=i32)
+                rows = jnp.searchsorted(jnp.cumsum(moved.astype(i32)), ranks).astype(i32)
+                pus = new.pu[jnp.clip(rows, 0, Tcap - 1)]
+            summary = jnp.stack([stats[k].astype(i32) for k in SERVED_SUMMARY])
+            return new, summary, rows, pus
+
+        self._served_round_jit = jax.jit(  # kschedlint: program=array_served_round
+            served_round, static_argnames=("decode_width",)
+        )
+
         def steady_scan(carry, gspec, key0, churn_prob, arrivals, num_rounds,
                         arrival_map, arrival_n):
             keys = jax.random.split(key0, num_rounds)
@@ -1641,17 +1686,28 @@ class DeviceBulkCluster:
     # host API
     # ------------------------------------------------------------------
 
-    def add_tasks(self, count, job_ids=None, classes=None, groups=None) -> None:
+    def add_tasks(self, count, job_ids=None, classes=None, groups=None,
+                  width: Optional[int] = None) -> None:
         """Admit up to `count` tasks. The admitted count is kept on
         device in ``last_admitted`` (fetching it mid-run would sync the
         host into the chain of rounds); callers that
         need the host BulkCluster's pool-exhausted error should check
         ``int(jax.device_get(self.last_admitted)) == count`` at a safe
         point. In group mode, `groups` assigns each task its
-        interchangeability group (see GroupSpec / set_groups)."""
-        jobs = np.zeros(self.Tcap, np.int32)
-        cls = np.zeros(self.Tcap, np.int32)
-        grp = np.zeros(self.Tcap, np.int32)
+        interchangeability group (see GroupSpec / set_groups).
+
+        `width` is how wide the sources go up (Tcap when None): a
+        service that admits a handful of pods a round ships arrays of
+        its batch's bucket, one compiled program a width. A source that
+        was not given (`job_ids`, `groups`) is then a zero array kept on
+        the device and is not shipped at all."""
+        resident_zeros = width is not None
+        width = self.Tcap if width is None else int(width)
+        if count > width:
+            raise ValueError(f"{count} tasks do not fit sources {width} wide")
+        jobs = np.zeros(width, np.int32)
+        cls = np.zeros(width, np.int32)
+        grp = np.zeros(width, np.int32)
         if job_ids is not None:
             jobs[: len(job_ids)] = job_ids
         if classes is not None:
@@ -1687,9 +1743,18 @@ class DeviceBulkCluster:
                         f"group {g[bad]}'s class {derived[bad]}"
                     )
         self.state, self.last_admitted = self._admit_jit(
-            self.state, jnp.asarray(jobs), jnp.asarray(cls),
-            jnp.asarray(grp), jnp.int32(count)
+            self.state,
+            self._zeros(width) if resident_zeros and job_ids is None else jnp.asarray(jobs),
+            jnp.asarray(cls),
+            self._zeros(width) if resident_zeros and groups is None else jnp.asarray(grp),
+            jnp.int32(count),
         )
+
+    def _zeros(self, width: int):
+        """int32 zeros [width], made once a width and kept on the device."""
+        if width not in self._zeros_by_width:
+            self._zeros_by_width[width] = jnp.zeros(width, jnp.int32)
+        return self._zeros_by_width[width]
 
     def set_groups(
         self, cls=None, job=None, e=None, u=None, pref_w=None
@@ -1746,8 +1811,10 @@ class DeviceBulkCluster:
         if cls is not None:
             self._groups_cls_host = np.asarray(cls, np.int32).copy()
 
-    def complete_tasks(self, rows) -> None:
-        pad = np.full(self.Tcap, self.Tcap, np.int32)
+    def complete_tasks(self, rows, width: Optional[int] = None) -> None:
+        """Retire the tasks in `rows`, shipped `width` wide (Tcap when
+        None; a served round's bucket otherwise)."""
+        pad = np.full(self.Tcap if width is None else int(width), self.Tcap, np.int32)
         pad[: len(rows)] = rows
         self.state = self._complete_jit(
             self.state, jnp.asarray(pad), jnp.int32(len(rows))
@@ -1787,6 +1854,26 @@ class DeviceBulkCluster:
             self._hyb_kg = jnp.int32(0)
         self.last_stats = stats
         return stats
+
+    def serve_round(self, decode_width: Optional[int] = None):
+        """`round()` for a service: the same round over a decode window
+        of `decode_width` unplaced rows (None: all Tcap), and the rows
+        it placed. Returns un-fetched (summary, rows, pus): `rows` i32[K],
+        the rows newly placed in row order among entries that read Tcap
+        (keep `rows < Tcap`), and `pus` i32[K], the PU of each, K the
+        window's width; `summary`
+        i32[7], `round()`'s stats in the order of SERVED_SUMMARY (the
+        flags as 0 / 1). A window that holds every unplaced row
+        decodes exactly what the full width does, so the caller, who
+        admitted them, takes the smallest of its widths that does (one
+        compiled program a width). Reading back costs 8 K bytes and the
+        scalars, not the table."""
+        if self.preemption:
+            raise ValueError("serve_round is the pin-on-place round: preemption is not served")
+        self.state, summary, rows, pus = self._served_round_jit(
+            self.state, self.groups, decode_width=decode_width
+        )
+        return summary, rows, pus
 
     def run_steady_rounds(
         self, num_rounds: int, churn_prob: float, arrivals: int, seed: int = 0
@@ -1876,3 +1963,10 @@ class DeviceBulkCluster:
     @property
     def num_placed_tasks(self) -> int:
         return int(jax.device_get(jnp.sum(self.state.live & (self.state.pu >= 0))))
+
+
+# -- Level-3 registry hook: the program this module owns (the other jit
+# sites are the scanned replays' machinery, waived where they stand) -------
+from ..analysis.program_registry import declare_programs as _declare_programs  # noqa: E402
+
+_declare_programs(__name__, "array_served_round")

@@ -122,6 +122,24 @@ def _seeded_rng():
 #: `supersteps_sparse_p50` stands after them" (eleven cases in all): state each
 #: as "in one run, in the order they were appended". `test_benchmark_solve_split.py`
 #: holds what stays true of each, a case an entry.
+#:
+#: PR 53 (`coco-50kx1k-array`: the array round served, a service with no graph
+#: path) appended a configuration, a thirteenth cell, ten entries and its cell's
+#: name to three lists, as ISSUE 53 asks; and it gave the ten entries that had no
+#: cell list and read nothing without a graph path the list of the twelve cells
+#: that were there, which is how the driver wants "not in the new cell" said.
+#: Twenty-eight cases pinned what that made false: `test_benchmark_requests.py`
+#: its configuration to the last place and two lists to "this cell last";
+#: `test_benchmark_solve_split.py`, `test_benchmark_pass_metrics.py` and
+#: `test_benchmark_appended_metrics.py` an entry's list to "every cell of the
+#: benchmark (but the rollout)" or to no list at all, a case an entry;
+#: `test_benchmark_seams.py`, `test_benchmark_runnable_scan.py` and
+#: `test_benchmark_solve_split.py` draw a case for every cell and want a plan
+#: digest on file, or every all-cell metric loaded; `test_benchmark_sparse_supersteps.py`
+#: the twelfth cell to the last place. `test_benchmark_array.py` holds what stays
+#: true of each: every such entry still equals its file and lists accepted cells
+#: only, this cell's plan is its control's, the ten silent entries load wherever
+#: they loaded.
 _STALE = {
     "test_benchmark_seams.py::test_class_only_is_the_old_expression_and_the_same_seed_"
     "draws_the_same_plan[k8s-5000-preemption.rollout-": "PR 38 brings pods/by_role.py",
@@ -189,6 +207,42 @@ _STALE = {
     "for_its_cell_alone[": "PR 51 appended twelve entries after supersteps_sparse_p50",
     "test_benchmark_sparse_supersteps.py::test_the_six_entries_of_pr_46_still_stand_right_"
     "before_pr_49s_four[": "PR 51 appended twelve entries after supersteps_sparse_p50",
+    "test_benchmark_requests.py::test_the_configuration_is_the_sources_shapes":
+        "PR 53 appended a configuration after it",
+    "test_benchmark_requests.py::test_a_list_it_joined_holds_what_it_held_and_this_cell_last["
+    "bind_tail_ms]": "PR 53 appended its cell to the list",
+    "test_benchmark_requests.py::test_a_list_it_joined_holds_what_it_held_and_this_cell_last["
+    "bindings_post_ms]": "PR 53 appended its cell to the list",
+    "test_benchmark_solve_split.py::test_the_entry_is_there_by_name_equals_its_file_and_lists_"
+    "its_cells[round_unnamed_ms]": "PR 53's cell opens spans the reader's leaves do not name",
+    "test_benchmark_solve_split.py::test_the_entry_is_there_by_name_equals_its_file_and_lists_"
+    "its_cells[stats_children_gathered]": "PR 53's cell has no statistics pass",
+    "test_benchmark_solve_split.py::test_the_lists_are_the_cells_the_issue_names_and_the_file_"
+    "holds_together": "PR 53 appended a thirteenth cell that two of the lists leave out",
+    "test_benchmark_solve_split.py::test_a_cell_loads_each_by_name_if_it_is_listed_and_not_"
+    "otherwise[coco-50kx1k-array.trickle]": "PR 53's cell is on gc_pause_ms's list alone",
+    "test_benchmark_seams.py::test_class_only_is_the_old_expression_and_the_same_seed_"
+    "draws_the_same_plan[coco-50kx1k-array.trickle-":
+        "PLAN_DIGESTS has no entry for PR 53's cell, whose plan is coco-50kx1k.trickle's",
+    "test_benchmark_sparse_supersteps.py::test_the_requests_cell_is_still_the_twelfth_and_"
+    "reads_what_it_read_and_this": "PR 53 appended a thirteenth cell",
+    **{
+        "test_benchmark_appended_metrics.py::test_an_appended_metric_is_its_file_loads_in_its_"
+        f"cells_and_reads_what_it_names[{name}]": "PR 53 gave it the list of the twelve accepted "
+        "cells: it reads nothing without a graph path"
+        for name in ("apply_walk_ms", "decode_deltas_ms", "ec_refresh_ms", "graph_refresh_ms",
+                     "runnable_scan_ms", "stats_ms")
+    },
+    **{
+        "test_benchmark_pass_metrics.py::test_a_pass_metric_is_its_file_loads_in_its_cells_and_"
+        f"reads_what_it_names[{name}]": "PR 53 appended a thirteenth cell that its list leaves out"
+        for name in ("apply_full_walks", "apply_nodes_visited", "ec_purge_ms", "ec_purges",
+                     "journal_apply_ms", "journal_changes", "journal_collect_ms",
+                     "problem_snapshot_ms", "res_arcs_changed", "res_nodes_visited",
+                     "task_refresh_ms")
+    },
+    "test_benchmark_runnable_scan.py::test_a_cell_loads_it_by_name_if_it_is_listed_and_not_"
+    "otherwise[coco-50kx1k-array.trickle]": "PR 53's cell loads no runnable_scan_ms",
     "test_benchmark_wharemap.py::test_the_traced_rehearsal_is_correct_and_every_metric_reads_"
     "a_number": "PR 48: `ec_arcs_repriced` is visited ECs x `census_machines_dirty` in a round "
                 "that patched, and nearly every arc written changes",
@@ -202,5 +256,6 @@ def pytest_collection_modifyitems(items):
                 item.add_marker(pytest.mark.xfail(
                     reason=f"an accepted pin that a later deployment made false ({why}); no "
                     "model_config PR may edit the file: the next benchmark PR does",
-                    raises=AssertionError, strict=True,
+                    # KeyError: a table of the test's own with no row for a later cell
+                    raises=(AssertionError, KeyError), strict=True,
                 ))

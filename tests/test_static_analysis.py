@@ -425,15 +425,20 @@ def test_registry_policies_are_coherent():
     """Solve and audit programs never scatter, but for the one that
     holds scan-CSR's active-set superstep, whose four scatter-adds a
     chip reading admitted (PR 50) and the engine counts and confines to
-    the sparse branch; every scoped exemption is a maintenance program;
-    chaos programs are never donation-audited (they are never
-    dispatched in production)."""
+    the sparse branch, and for the served array round (PR 53), which
+    keeps the cluster's TABLE on the device and counts its census and
+    its supply by scatter-adds over the table's rows, once a round and
+    outside the transport's loop; every other scoped exemption is a
+    maintenance program; chaos programs are never donation-audited (they
+    are never dispatched in production)."""
     for spec in PROGRAMS.values():
         if spec.name == "csr_solve_active":
             assert (spec.kind, spec.scatter_policy, spec.scatters) == ("solve", "active-set", 4)
+        elif spec.name == "array_served_round":
+            assert (spec.kind, spec.scatter_policy) == ("solve", "scoped-exempt")
         elif spec.kind in ("solve", "audit"):
             assert spec.scatter_policy == "forbidden", spec.name
-        if spec.scatter_policy == "scoped-exempt":
+        elif spec.scatter_policy == "scoped-exempt":
             assert spec.kind == "maintenance", spec.name
         if spec.kind == "chaos":
             assert spec.donation is None, spec.name
