@@ -255,7 +255,7 @@ _SPECS = (
         name="csr_solve", module="ksched_tpu.solver.jax_solver", kind="solve",
         tracer="trace_jax", trace=call(20, 100),
         extra=(call(12, 40), call(40, 220)),
-        telemetry_off_hash="c3cd4c121a78d56a", telemetry_knob="telemetry_cap",
+        telemetry_off_hash="81f926532b54d878", telemetry_knob="telemetry_cap",
         hash_stability=HashStability("pow2-bucket", same=_CSR_SAME, cross=_CSR_CROSS),
         gathers=_CSR_GATHERS,
         collectives=CollectiveBudget(forbidden=_ALL_COLLECTIVES),
@@ -351,7 +351,7 @@ _SPECS = (
         name="sharded_solve", module="ksched_tpu.parallel.sharded_solver",
         kind="solve", tracer="trace_sharded", trace=call(20, 100),
         extra=(call(12, 40), call(40, 220)),
-        telemetry_off_hash="3d9cf1c3ee42486b", telemetry_knob="telemetry_cap",
+        telemetry_off_hash="5fe7fb5d9c0a5982", telemetry_knob="telemetry_cap",
         hash_stability=HashStability(
             "exempt",
             reason="legacy ShardedPlan shapes depend on per-shard maxima; "

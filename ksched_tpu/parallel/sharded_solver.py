@@ -230,7 +230,7 @@ def make_sharded_solver(mesh: Mesh, axis: str, alpha: int, max_supersteps: int, 
 
             def t_body(state):
                 d, _, it = state
-                cand = jnp.where(r > 0, s_cost + d[s_dst], i32(_BIG_D))
+                cand = jnp.where(r > 0, s_cost + 1 + d[s_dst], i32(_BIG_D))
                 scanned = _seg_scan(cand, s_isstart, jnp.minimum)
                 best = jnp.where(node_nonempty, scanned[node_last], i32(_BIG_D))
                 best = jnp.where(owned, best, i32(_BIG_D))
@@ -285,11 +285,12 @@ def make_sharded_solver(mesh: Mesh, axis: str, alpha: int, max_supersteps: int, 
             )
             return new_flow, new_p, aux
 
-        def sat_full(flow, p):
+        def sat_full(flow, p, eps):
+            # rows within [-eps, +eps] stay as they stand: `tighten`
+            # leaves the shortest-path tree at -1 (solver/jax_solver.py)
             rc = s_cost + p[s_src] - p[s_dst]
-            r = residual(flow)
-            want = jnp.where((rc < 0) & s_valid & (s_sign > 0), cap[s_arc], i32(-1))
-            want = jnp.where((rc < 0) & s_valid & (s_sign < 0), i32(0), want)
+            want = jnp.where((rc < -eps) & s_valid & (s_sign > 0), cap[s_arc], i32(-1))
+            want = jnp.where((rc < -eps) & s_valid & (s_sign < 0), i32(0), want)
             # translate per-entry wishes to per-arc flow targets
             wz = jnp.where(s_valid, want, i32(-1))
             tgt_f = wz[pos_fwd]
@@ -339,7 +340,7 @@ def make_sharded_solver(mesh: Mesh, axis: str, alpha: int, max_supersteps: int, 
             def next_phase(_):
                 finished = eps <= 1
                 new_eps = jnp.maximum(i32(1), eps // alpha)
-                f2 = jnp.where(finished, flow, sat_full(flow, p))
+                f2 = jnp.where(finished, flow, sat_full(flow, p, new_eps))
                 out = (
                     f2, p, jnp.where(finished, eps, new_eps), steps, finished
                 )
@@ -348,7 +349,7 @@ def make_sharded_solver(mesh: Mesh, axis: str, alpha: int, max_supersteps: int, 
             return lax.cond(any_active, do_superstep, next_phase, operand=None)
 
         p0 = tighten(flow0)
-        flow1 = sat_full(flow0, p0)
+        flow1 = sat_full(flow0, p0, eps_init)
         state = (flow1, p0, eps_init, i32(0), jnp.bool_(False))
         if telemetry_cap:
             state = state + (jnp.zeros((telemetry_cap, SOLTEL_WIDTH), i32),)
@@ -575,7 +576,7 @@ def make_sharded_slot_solver(
 
             def t_body(state):
                 d, _, it = state
-                cand = jnp.where(r > 0, s_cost + d[s_dst], i32(_BIG_D))
+                cand = jnp.where(r > 0, s_cost + 1 + d[s_dst], i32(_BIG_D))
                 scanned = _seg_scan(cand, isstart, jnp.minimum)
                 best = jnp.where(nonempty, scanned[node_last], i32(_BIG_D))
                 best = jnp.where(owned, best, i32(_BIG_D))
@@ -619,10 +620,11 @@ def make_sharded_slot_solver(
             )
             return new_flow, new_p, aux
 
-        def sat_full(flow, p):
+        def sat_full(flow, p, eps):
+            # as the single-chip `saturate`: rows within [-eps, +eps] stay
             rc = s_cost + p[s_src] - p[s_dst]
-            want = jnp.where((rc < 0) & (s_sign > 0), cap[s_arc], i32(-1))
-            want = jnp.where((rc < 0) & (s_sign < 0), i32(0), want)
+            want = jnp.where((rc < -eps) & (s_sign > 0), cap[s_arc], i32(-1))
+            want = jnp.where((rc < -eps) & (s_sign < 0), i32(0), want)
             tgt = jnp.maximum(
                 lax.pmax(want[pf], axis), lax.pmax(want[pb], axis)
             )
@@ -668,7 +670,7 @@ def make_sharded_slot_solver(
             def next_phase(_):
                 finished = eps <= 1
                 new_eps = jnp.maximum(i32(1), eps // alpha)
-                f2 = jnp.where(finished, flow, sat_full(flow, p))
+                f2 = jnp.where(finished, flow, sat_full(flow, p, new_eps))
                 out = (
                     f2, p, jnp.where(finished, eps, new_eps), steps, finished
                 )
@@ -684,7 +686,7 @@ def make_sharded_slot_solver(
             )
         else:
             p0 = tighten(flow0)
-        flow1 = sat_full(flow0, p0)
+        flow1 = sat_full(flow0, p0, eps_init)
         state = (flow1, p0, eps_init, i32(0), jnp.bool_(False))
         if telemetry_cap:
             state = state + (jnp.zeros((telemetry_cap, SOLTEL_WIDTH), i32),)

@@ -268,13 +268,24 @@ class DegradingSolver(FlowSolver):
         b = self._rungs[self.last_rung][1]
         return getattr(b, "last_iterations", 0) or getattr(b, "last_supersteps", 0)
 
+    def _count_of_last_rung(self, name: str) -> int:
+        """A counter only some rungs keep, of the rung that produced the
+        round (0 on a rung without it, or before any round)."""
+        if self.last_rung < 0:
+            return 0
+        return getattr(self._rungs[self.last_rung][1], name, 0)
+
     @property
     def last_sparse_supersteps(self) -> int:
         """Of that rung's supersteps, the ones that took scan-CSR's
-        active-set form (0 on a rung that has no such form)."""
-        if self.last_rung < 0:
-            return 0
-        return getattr(self._rungs[self.last_rung][1], "last_sparse_supersteps", 0)
+        active-set form."""
+        return self._count_of_last_rung("last_sparse_supersteps")
+
+    @property
+    def last_price_updates(self) -> int:
+        """The global price updates that fired in that rung's solve
+        (scan-CSR with `price_update_every`)."""
+        return self._count_of_last_rung("last_price_updates")
 
 
 def build_degradation_ladder(

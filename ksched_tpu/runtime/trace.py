@@ -39,6 +39,10 @@ class RoundRecord:
     #: rows of the nodes that held excess alone (JaxSolver.
     #: last_sparse_supersteps; 0 on any other rung)
     supersteps_sparse: int = 0
+    #: the global price updates that fired in the round's solve
+    #: (JaxSolver.last_price_updates: `steps // price_update_every` of
+    #: the attempts at eps 1; 0 where the rung runs none)
+    price_updates: int = 0
     nodes_added: int = 0
     arcs_added: int = 0
     arcs_changed: int = 0
@@ -357,6 +361,7 @@ class RoundTracer:
             solver_work=getattr(backend, "last_iterations", 0)
             or getattr(backend, "last_supersteps", 0),
             supersteps_sparse=getattr(backend, "last_sparse_supersteps", 0),
+            price_updates=getattr(backend, "last_price_updates", 0),
             nodes_added=stats.nodes_added if stats else 0,
             arcs_added=stats.arcs_added if stats else 0,
             arcs_changed=stats.arcs_changed if stats else 0,

@@ -15,7 +15,21 @@ solved by a synchronous Goldberg–Tarjan cost-scaling push-relabel:
   bounds were computed against neighbor potentials that only decrease),
   and opposite-direction pushes on one arc are mutually exclusive;
 - phases shrink eps by alpha until eps = 1 on costs pre-scaled by the
-  node count, at which point the flow is exactly optimal.
+  node count, at which point the flow is exactly optimal;
+- the prologue's prices are 1-optimal with the shortest-path tree
+  ADMISSIBLE (PR 54). `tighten` runs its Bellman sweeps on every row's
+  cost + 1, so p = -(d + h): the exact distance to a node short of
+  flow plus the fewest hops among the shortest paths, one integer
+  (costs are multiples of the node count, a path has fewer hops than
+  that). Under p and the true costs a tree arc has reduced cost -1 and
+  every other residual arc at least -1: a unit moves a hop in every
+  superstep from the first, where exact distances (0 on the tree, not
+  admissible) cost each node on its path a superstep of lowering its
+  price by eps before it pushed, ten supersteps for five hops. The
+  optimum is as exact as before (the discharge needs a 1-optimal
+  start, no more). `saturate` therefore leaves a row within
+  [-eps, +eps] as it stands, at the prologue and at a phase change: a
+  test of `rc < 0` would saturate the whole tree.
 
 TPU-shaped implementation notes:
 
@@ -107,7 +121,7 @@ discharging displaced excess is the measured unit-relabel price war
 price quality: exact entry prices, deeper Bellman budgets, warm eps
 ladders, and periodic global relabels all leave or worsen it, see
 _solve_mcmf). Those rounds dispatch the fresh-restart program up
-front — zero flow, tightened prices, eps=1, ~10 supersteps on these
+front — zero flow, tightened prices, eps=1, a superstep a hop on these
 graphs — the same program the old `restart_budget` escape reached
 only after burning a doomed warm attempt. Cost-scaling from max-cost
 remains the final fallback, and `restart_budget` still backstops the
@@ -255,7 +269,8 @@ def _seg_ends(node_first, node_last, node_nonempty, vals, cums, *at_last):
 
 
 _BIG_D = 1 << 28  # "unreachable" distance sentinel for price tightening
-#: so a path's cost times the node count has to stay below it: a tightened
+#: so a path's cost times the node count, and its hops on top (`tighten`
+#: counts a row one dearer), has to stay below it: a tightened
 #: distance of _BIG_D reads as unreachable (the refusal inside a round,
 #: `max|cost| * nodes >= 2^30`, is of one arc and comes later). A cost model
 #: that states its largest cost is held to this before the service exists
@@ -343,7 +358,10 @@ def _solve_mcmf(
     stated on rows (a row with negative reduced cost is emptied, its
     partner, whose reduced cost is the negative, takes the capacity), so
     neither the prologue nor a phase change of a cold ladder converts
-    to arc space. Held bit for bit to the arc-state program it replaced
+    to arc space. `tighten` and `saturate` are the prologue of PR 54
+    (module docstring: 1-optimal prices, the tree admissible, rows
+    within [-eps, +eps] kept). Held bit for bit to the arc-state
+    program it replaced
     (flow, p, steps, converged, p_overflow, every soltel row) by
     tests/test_csr_entry_state.py.
 
@@ -354,7 +372,7 @@ def _solve_mcmf(
     a violated residual out-arc — exactly the journal-touched dirty
     frontier — and later sweeps expand that frontier until the prices
     are consistent again (or the sweep budget runs out; the saturate
-    step then restores 0-optimality regardless, so the result is an
+    step then restores eps-optimality regardless, so the result is an
     exact optimum either way). Because last round's converged prices
     certify last round's flow, violations exist only around the churn,
     which is what kills the warm-start price war: the discharge starts
@@ -375,9 +393,10 @@ def _solve_mcmf(
     running tasks keep their arcs (`--preemption`): in the eps=1
     discharge, after every G-th superstep every potential is lowered to
     what `tighten` gives on the residual graph as it then stands (minus
-    the exact distance to the nodes still short of flow: at eps=1, on
-    costs scaled by the node count, no residual cycle is negative),
-    where that is lower than what the node has. Without it such a round
+    the distance, a hop counted one, to the nodes still short of flow:
+    at eps=1, on costs scaled by the node count, no residual cycle is
+    negative), where that is lower than what the node has. Without it
+    such a round
     does not end: a full cluster hands out more units than PU -> sink
     takes, the zero-flow prices of the prologue cannot know, and the units left over at the
     PUs sink the whole plateau of machines, PUs and running tasks one
@@ -438,22 +457,29 @@ def _solve_mcmf(
         (out,) = _seg_ends(*seg, (signed,), (jnp.cumsum(signed),))
         return supply - out
 
-    def saturate(r, p):
-        """Refine step: saturate every residual entry with negative
-        reduced cost (its partner, whose reduced cost is the negative,
-        takes the whole capacity), making the pseudoflow 0-optimal for
-        the phase."""
+    def saturate(r, p, eps):
+        """Refine step: empty every residual entry whose reduced cost
+        lies below -eps (its partner, whose reduced cost is the negative,
+        takes the whole capacity), making the pseudoflow eps-optimal for
+        the phase. A row within [-eps, +eps] stays as it stands: `tighten`
+        leaves the shortest-path tree at -1 (module docstring)."""
         (p_src,), (p_dst,) = _rows(s_src, p), _rows(s_dst, p)
         rc = s_cost + p_src - p_dst
-        return jnp.where(rc < 0, i32(0), jnp.where(rc > 0, s_cap, r))
+        return jnp.where(rc < -eps, i32(0), jnp.where(rc > eps, s_cap, r))
 
     def tighten(r, d0=None):
         """Price tightening: p = -(shortest residual-cost distance to a
-        demand node), via synchronous Bellman-Ford sweeps over the sorted
-        entries. Afterwards every residual arc between reachable nodes
-        has nonnegative reduced cost, so the discharge can run at eps=1
-        regardless of how flows/capacities changed since the last round —
-        this is what makes warm restarts cheap and drift-free.
+        demand node, every row counted ONE DEARER than it costs), via
+        synchronous Bellman-Ford sweeps over the sorted entries: the
+        exact distance plus the fewest hops among the shortest paths
+        (module docstring). Afterwards every residual arc between
+        reachable nodes has reduced cost >= -1 and every arc of the
+        shortest-path tree exactly -1, so the discharge can run at eps=1
+        regardless of how flows/capacities changed since the last round
+        (this is what makes warm restarts cheap and drift-free) and moves
+        a unit a hop in its first superstep. `largest cost x nodes <
+        _BIG_D` leaves the room the hops take: a pow2 node bucket divides
+        _BIG_D, so the product stays a bucket below it.
 
         With an explicit ``d0`` this is the warm-prologue REFIT instead:
         seeded from the carried prices, the relaxation only moves nodes
@@ -471,7 +497,7 @@ def _solve_mcmf(
         def t_body(state):
             d, _, it = state
             (d_dst,) = _rows(s_dst, d)
-            cand = jnp.where(r > 0, s_cost + d_dst, i32(_BIG_D))
+            cand = jnp.where(r > 0, s_cost + 1 + d_dst, i32(_BIG_D))
             (best,) = _rows(node_last, _seg_scan(jnp.minimum, cand, s_isstart))
             best = jnp.where(node_nonempty, best, i32(_BIG_D))
             # Clamp from below: a negative-cost residual cycle (possible
@@ -657,9 +683,8 @@ def _solve_mcmf(
 
         def do_superstep(_):
             if active_set:
-                # per superstep, from the loop state: one solve holds
-                # both kinds (a trickle round's two bulk supersteps,
-                # then eight of one node)
+                # per superstep, from the loop state: one solve may hold
+                # both kinds (a wave's bulk supersteps, then its stragglers')
                 fits = active_set_fits(excess)
                 r2, e2, p2, aux = lax.cond(
                     fits, active_superstep, superstep, r, excess, p, eps
@@ -691,7 +716,7 @@ def _solve_mcmf(
         def next_phase(_):
             finished = eps <= 1
             new_eps = jnp.maximum(i32(1), eps // alpha)
-            r2 = jnp.where(finished, r, saturate(r, p))
+            r2 = jnp.where(finished, r, saturate(r, p, new_eps))
             out = (r2, excess_of(r2), p, jnp.where(finished, eps, new_eps), steps, finished)
             return out + sparse + tel
 
@@ -704,7 +729,7 @@ def _solve_mcmf(
         p0 = tighten(r0, d0=jnp.clip(-warm_p, -i32(_BIG_D), i32(_BIG_D)))
     else:
         p0 = tighten(r0)
-    r1 = saturate(r0, p0)  # mop up any residual violations
+    r1 = saturate(r0, p0, eps_init)  # mop up any residual violations
     state = (r1, excess_of(r1), p0, eps_init, i32(0), jnp.bool_(False))
     if active_set:
         state = state + (i32(0),)
@@ -869,14 +894,14 @@ class JaxSolver(FlowSolver):
         #: budgets, eps ladders, and periodic global relabels all
         #: measured NOT to fix it — see _solve_mcmf's docstring).
         #: Those rounds dispatch the fresh-restart program up front
-        #: (zero flow, tightened prices, eps=1: ~10 supersteps on
+        #: (zero flow, tightened prices, eps=1: a superstep a hop on
         #: these graphs) instead of burning a doomed warm attempt.
         #: False restores the r11 policy (always attempt the carried
         #: flow; rely on restart_budget to escape).
         self.journal_scoped_warm = journal_scoped_warm
         #: superstep budget for the WARM attempt before escaping to a
         #: fresh-restart solve (flow0=0, tightened prices, eps=1 — the
-        #: ~10-superstep machine on these graphs) instead of burning
+        #: few-superstep machine on these graphs) instead of burning
         #: the full 4096-step attempt-1 budget. None keeps the original
         #: two-attempt ladder. Since the dirty-frontier refit landed
         #: this is a BACKSTOP, not the fix: refitted warm attempts
@@ -915,6 +940,12 @@ class JaxSolver(FlowSolver):
         #: of those, the supersteps that worked on the rows of the
         #: active nodes alone (`active_superstep`), over every attempt
         self.last_sparse_supersteps = 0
+        #: the global price updates that fired in the last solve: host
+        #: arithmetic, `steps // price_update_every` of each attempt that
+        #: ran at eps 1 from its first superstep (attempt 1 and the
+        #: restart escape; a cold ladder reaches eps 1 at a step the host
+        #: does not see, and its updates are not counted)
+        self.last_price_updates = 0
         self.last_telemetry = None  # SolveTelemetry of the last solve
         self.last_warm_scope = "cold"  # warm | fresh | cold (see solve_async)
         #: exact bytes of the last solve's own transfers, every attempt:
@@ -1066,7 +1097,7 @@ class JaxSolver(FlowSolver):
             # whether this round's journal re-wired any arc. If it did, the
             # optimum displaces carried flow and the warm discharge is the
             # measured unit-relabel price war — dispatch the fresh-restart
-            # program (~10 supersteps) up front instead. Carried PRICES
+            # program (a superstep a hop) up front instead. Carried PRICES
             # survive either way (the refit repairs them on clean rounds).
             keep_flow = True
             if self.journal_scoped_warm and plan_key is not None:
@@ -1228,6 +1259,11 @@ class JaxSolver(FlowSolver):
             )
         return self._await(out, attempt, active_set)
 
+    def _updates_fired(self, steps: int) -> int:
+        """Global price updates of an attempt that ran `steps` supersteps
+        at eps 1 from its first: one after every `price_update_every`-th."""
+        return steps // self.price_update_every if self.price_update_every else 0
+
     def _await(self, out, attempt: str, active_set):
         """Block until one attempt's scalars are on the host
         (`solve_wait`: from here on the device has finished, so with
@@ -1262,6 +1298,7 @@ class JaxSolver(FlowSolver):
         if fut is None:
             self.last_telemetry = None
             self.last_sparse_supersteps = 0
+            self.last_price_updates = 0
             return FlowResult(
                 flow=np.zeros(len(problem.src), dtype=np.int64),  # kschedlint: host-only (FlowResult contract is int64)
                 objective=0, iterations=0,
@@ -1271,11 +1308,12 @@ class JaxSolver(FlowSolver):
         flow, p, steps, converged, p_overflow, took, tel_buf = self._await(fut, "1", active_set)
         spent = steps  # device work across ALL attempts this solve
         spent_sparse = took
+        updates = self._updates_fired(steps)
         warm_failed = warm and not (converged and not p_overflow)
         if warm_failed and not converged:
             # A warm attempt that exhausted its budget is a price war,
             # not a hard instance (the fresh restart below converges in
-            # ~10 supersteps): report it as a structured soltel event so
+            # a superstep a hop): report it as a structured soltel event so
             # flight dumps distinguish it from genuine non-convergence.
             # A CONVERGED attempt that tripped the potential-overflow
             # guard still escapes below, but is NOT a price war — and
@@ -1301,7 +1339,7 @@ class JaxSolver(FlowSolver):
         if warm_failed and self.restart_budget is not None:
             # Attempt 1b (restart escape): a warm attempt that blew its
             # budget re-solves FRESH — zero flow, tightened prices,
-            # eps=1 — the ~10-superstep path on these graphs, instead
+            # eps=1 — the few-superstep path on these graphs, instead
             # of the ~20k-superstep full cost-scaling below. Exact
             # either way; the cost-scaling attempt remains the backstop
             # for genuinely hard instances.
@@ -1310,6 +1348,7 @@ class JaxSolver(FlowSolver):
             )
             spent += steps
             spent_sparse += took
+            updates += self._updates_fired(steps)
         if not (converged and not p_overflow):
             flow, p, steps, converged, p_overflow, took, tel_buf = self._retry(
                 "cold", rest, eps_cold, self.max_supersteps
@@ -1322,6 +1361,7 @@ class JaxSolver(FlowSolver):
         # stays attempt-local (the ring indexes the final attempt)
         self.last_supersteps = spent
         self.last_sparse_supersteps = spent_sparse
+        self.last_price_updates = updates
         ok = converged and not p_overflow
         with span("result_readback") as sp:
             # the telemetry budget is the SOLVER's budget (max_supersteps),

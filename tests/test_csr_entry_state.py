@@ -86,9 +86,12 @@ def _solve_mcmf_frozen(
     use_warm_p: bool = False,
     slot_stable: bool = False,
 ):
-    """PR 28's `_solve_mcmf`, verbatim but for this docstring: per-arc
-    flow carried through the loop, every row value gathered again each
-    iteration."""
+    """PR 28's `_solve_mcmf`, verbatim but for this docstring and PR
+    54's prologue (`tighten` counts every row one dearer, `saturate`
+    leaves an arc within [-eps, +eps] alone: the algorithm's change,
+    taken here too so that what this file compares stays the two STATE
+    layouts): per-arc flow carried through the loop, every row value
+    gathered again each iteration."""
     from ksched_tpu.obs.soltel import SOLTEL_WIDTH
 
     m = cap.shape[0]
@@ -109,11 +112,12 @@ def _solve_mcmf_frozen(
         flow_signed = s_sign * flow[s_arc]
         return supply - _seg_sum(flow_signed, node_first, node_last, node_nonempty)
 
-    def saturate(flow, p):
-        """Refine step: saturate every residual entry with negative
-        reduced cost, making the pseudoflow 0-optimal for the phase."""
+    def saturate(flow, p, eps):
+        """Refine step: saturate every residual entry whose reduced cost
+        lies below -eps, making the pseudoflow eps-optimal for the phase
+        (PR 54: an arc within [-eps, +eps] keeps its flow)."""
         rc_fwd = cost + p[cap_src] - p[cap_dst]
-        return jnp.where(rc_fwd < 0, cap, jnp.where(rc_fwd > 0, i32(0), flow))
+        return jnp.where(rc_fwd < -eps, cap, jnp.where(rc_fwd > eps, i32(0), flow))
 
     # Per-arc endpoints for the saturate step, recovered from the sorted
     # entries to avoid shipping src/dst twice: arc j's forward entry sits
@@ -149,7 +153,7 @@ def _solve_mcmf_frozen(
 
         def t_body(state):
             d, _, it = state
-            cand = jnp.where(r > 0, s_cost + d[s_dst], i32(_BIG_D))
+            cand = jnp.where(r > 0, s_cost + 1 + d[s_dst], i32(_BIG_D))
             best = _seg_min(cand, s_isstart, node_last, node_nonempty, i32(_BIG_D))
             # Clamp from below: a negative-cost residual cycle (possible
             # transiently with warm flows + changed costs) must not run d
@@ -247,7 +251,7 @@ def _solve_mcmf_frozen(
         def next_phase(_):
             finished = eps <= 1
             new_eps = jnp.maximum(i32(1), eps // alpha)
-            f2 = jnp.where(finished, flow, saturate(flow, p))
+            f2 = jnp.where(finished, flow, saturate(flow, p, new_eps))
             out = (f2, p, jnp.where(finished, eps, new_eps), steps, finished)
             return out + ((tel,) if telemetry_cap else ())
 
@@ -262,7 +266,7 @@ def _solve_mcmf_frozen(
         )
     else:
         p0 = tighten(flow0)
-    flow1 = saturate(flow0, p0)  # mop up any residual violations
+    flow1 = saturate(flow0, p0, eps_init)  # mop up any residual violations
     state = (flow1, p0, eps_init, i32(0), jnp.bool_(False))
     if telemetry_cap:
         state = state + (jnp.zeros((telemetry_cap, SOLTEL_WIDTH), i32),)

@@ -248,7 +248,7 @@ def test_ladder_degrades_sharded_to_jax(mesh2):
 #: the multi-chip rung (the SOLTEL_OFF_BASELINE_HASHES convention of
 #: tests/test_static_analysis.py: normalized jaxpr hash, jax 0.9.0;
 #: re-capture in the same commit as any jax upgrade)
-SHARDED_SLOT_OFF_HASH_2DEV = "45257a8ad63fe611"
+SHARDED_SLOT_OFF_HASH_2DEV = "dfaba9760e69eada"
 
 
 def test_sharded_slot_telemetry_off_hash_pinned():

@@ -461,10 +461,10 @@ def test_registry_pins_are_the_pretelemetry_baselines():
         n: s.telemetry_off_hash
         for n, s in PROGRAMS.items() if s.telemetry_off_hash
     } == {
-        "csr_solve": "c3cd4c121a78d56a",  # PR 29: state carried in entry space
+        "csr_solve": "81f926532b54d878",  # PR 54: the prologue leaves the tree admissible (PR 29: state carried in entry space)
         # sharded traces over the conftest 8-virtual-device mesh; its
         # hash is mesh-size-dependent (the others' are not)
-        "sharded_solve": "3d9cf1c3ee42486b",
+        "sharded_solve": "5fe7fb5d9c0a5982",
         "layered_solve": "d9971a009af01491",
     }
 
