@@ -159,6 +159,24 @@ def check(cond, msg: str) -> None:
         raise SmokeFailure(msg)
 
 
+def _table_is_the_mirror(svc, what: str) -> None:
+    """The device's table of an `ArrayRoundService` against its host
+    mirror and its own books: the live rows and their PUs are the
+    mirror's, `pu_running` is a recount of the `pu` column, no PU holds
+    more than its own slots (0 for a PU its machine does not have)."""
+    st = svc.cluster.fetch_state()
+    live, pu = np.asarray(st["live"]), np.asarray(st["pu"])
+    running = np.asarray(st["pu_running"])
+    check(set(np.flatnonzero(live).tolist()) == set(svc.row_of.values()),
+          f"{what}: the mirror's rows are not the device's live rows")
+    check(bool((np.where(live, pu, -1) == svc.pu_of_row).all()),
+          f"{what}: the mirror's PUs are not the device's")
+    check(bool((np.bincount(pu[live & (pu >= 0)], minlength=running.size) == running).all()),
+          f"{what}: pu_running is not a recount of the pu column")
+    check(bool((running <= svc.cluster.pu_slots).all()),
+          f"{what}: a PU holds more than its own slots")
+
+
 class Smoke:
     def __init__(self, rehearse: bool, seed: int) -> None:
         import jax
@@ -386,12 +404,7 @@ class Smoke:
                 census[m, class_of[pod]] += 1
             costs.append(served)
         svc.flush_pending_bindings()
-        st = svc.cluster.fetch_state()
-        live, pu = np.asarray(st["live"]), np.asarray(st["pu"])
-        check(set(np.flatnonzero(live).tolist()) == set(svc.row_of.values()),
-              "array service: the mirror's rows are not the device's live rows")
-        check(bool((np.where(live, pu, -1) == svc.pu_of_row).all()),
-              "array service: the mirror's PUs are not the device's")
+        _table_is_the_mirror(svc, "array service")
         api.close()
         return (
             f"array-round[tasks={tasks} machines={machines}x4x16 rows={svc.cluster.Tcap} "
@@ -467,6 +480,64 @@ class Smoke:
             placed=int(placed.sum()),
         )
 
+    def _array_three_types(self) -> str:
+        """The layout `gtrace-12500-wharemap-array` runs: `--array-round
+        --cost-model whare` on machines of three types (2 / 4 / 8 PUs in
+        one table padded to 8), the fill to nine tenths and two rounds of
+        arrivals and completions. Every round converged and at the plain
+        reference's optimum (benchmarks/reference_wharemap_array.py), the
+        device's table equal to the books: the mirror's rows and PUs,
+        `pu_running` a recount, no PU above its own slots (0 for a PU its
+        machine does not have)."""
+        from benchmarks.reference_wharemap_array import check_interference_map_array
+        from ksched_tpu import cli
+        from ksched_tpu.cluster import SyntheticClusterAPI
+        from ksched_tpu.cluster.api import PodEvent
+
+        machines = max(40, self.sizes["array"]["machines"])
+        types = "A:1:10,B:2:930,C:4:60"
+        args = cli.build_arg_parser().parse_args([
+            "--fake-machines", "--num-machines", str(machines), "--pus-per-core", "2",
+            "--max-tasks-per-pu", "3", "--fake-machine-types", types, "--cost-model", "whare",
+            "--array-round", "--pod-chan-size", "200000", "--pod-batch-timeout", "0.2",
+        ])
+        api = SyntheticClusterAPI(pod_chan_size=args.pod_chan_size)
+        svc = cli.build_service(args, api)
+        svc.init_topology(fake_machines=machines, pus_per_core=args.pus_per_core)
+        slots = int(svc.machine_slots.sum())
+        rng = np.random.default_rng(self.seed + 3)
+        class_of, log, seen, batches = {}, [], set(), []
+        churn = max(6, slots // 200)
+        for r, n in enumerate((slots * 9 // 10, churn, churn)):
+            if r:
+                for pod in sorted(seen)[(r - 1) * churn: r * churn]:
+                    check(svc.complete_pod(pod), f"three types: {pod} was not bound")
+                    log.append(("done", pod, "", float(r)))
+            pods = [PodEvent(f"t{r}_{i}", task_class=int(c))
+                    for i, c in enumerate(rng.integers(0, 4, n))]
+            class_of.update((p.pod_id, p.task_class) for p in pods)
+            batches.append((r + 0.5, [p.pod_id for p in pods]))
+            check(svc.run_round(pods) == n, f"three types, round {r + 1}: not every pod bound")
+            new = {pod: node for pod, node in api.bindings().items() if pod not in seen}
+            seen.update(new)
+            log += [("bind", pod, node, r + 0.5) for pod, node in new.items()]
+        check(svc.unconverged_rounds == 0 and svc.cost_overflows == 0,
+              "three types: a round did not converge, or its costs overflowed")
+        faults, facts = check_interference_map_array(
+            log, class_of, svc.nodes, cli.parse_machine_types(types), args.pus_per_core,
+            args.max_tasks_per_pu, batches=batches,
+        )
+        check(not faults, f"three types: {faults}")
+        svc.flush_pending_bindings()
+        _table_is_the_mirror(svc, "three types")
+        api.close()
+        by_pus = np.bincount((svc.cluster.pu_slots.reshape(machines, -1) > 0).sum(axis=1)).tolist()
+        return (
+            f"three-types[whare machines={machines} by PUs={by_pus} slots={slots} rows={svc.cluster.Tcap} "
+            f"fill+2x{churn}: 0 unconverged, cost {facts['served_cost']}=={facts['optimum_cost']} the "
+            f"reference's optimum, table==books]"
+        )
+
     def array(self) -> str:
         kernel_mode = "interpret" if self.rehearse else "on"
         a = self._array_run(kernel_mode)
@@ -484,7 +555,8 @@ class Smoke:
             f"all converged, invariants hold, placed={a['placed']} "
             f"supersteps[fill={int(a['supersteps'][0])} steady p50={int(np.median(steady))} "
             f"min={int(steady.min())} max={int(steady.max())}] "
-            f"pallas({kernel_mode})==xla: placements, supersteps, pu_running identical"
+            f"pallas({kernel_mode})==xla: placements, supersteps, pu_running identical | "
+            f"{self._array_three_types()}"
         )
 
     # -- the two transport kernels, directly -----------------------------

@@ -124,6 +124,43 @@ def fake_cores(num_machines: int, cores_per_machine: int, types: Sequence[Machin
     return sum(machine_type_of(i, types)[1] for i in range(num_machines))
 
 
+def fake_node_events(
+    num_machines: int, cores_per_machine: int, pus_per_core: int,
+    types: Sequence[MachineType] = (), zones: int = 0, racks: int = 0,
+    allocatable: Tuple[int, int] = (0, 0),
+) -> List[NodeEvent]:
+    """The fake machines of `--fake-machines`, as every service builds
+    them: `fake_node_<i>`, dealt round-robin over `racks` racks and
+    `zones` zones under their labels; with `types`, machine i is of the
+    type `machine_type_of` deals it, that type's cores and its name under
+    the platform label; `allocatable` is the (CPU millicores, memory MiB)
+    every machine says it can give to pods."""
+    dealt = [
+        (key, prefix, n)
+        for key, prefix, n in ((RACK_LABEL, "rack", racks), (ZONE_LABEL, "zone", zones))
+        if n > 0
+    ]
+    cpu_millis, memory_mib = allocatable
+    events = []
+    for i in range(num_machines):
+        labels = [(key, f"{prefix}-{i % n}") for key, prefix, n in dealt]
+        cores = cores_per_machine
+        if types:
+            name, cores, _share = machine_type_of(i, types)
+            labels.append((PLATFORM_LABEL, name))
+        events.append(
+            NodeEvent(
+                node_id=f"fake_node_{i}",
+                num_cores=cores,
+                pus_per_core=pus_per_core,
+                cpu_allocatable_millis=cpu_millis,
+                memory_allocatable_mib=memory_mib,
+                labels=tuple(labels),
+            )
+        )
+    return events
+
+
 class ServiceLoop:
     """What every service's loop shares, whatever runs inside its round:
     `run` (poll, round or idle sweep, until the control plane closes),
@@ -496,31 +533,11 @@ class SchedulerService(ServiceLoop):
         under the platform label. With ``fake_node_allocatable`` every
         fake machine says it can give that much CPU and memory to pods."""
         if fake_machines > 0:
-            dealt = [
-                (key, prefix, n)
-                for key, prefix, n in (
-                    (RACK_LABEL, "rack", self.fake_racks), (ZONE_LABEL, "zone", self.fake_zones),
-                )
-                if n > 0
-            ]
-            types = self.fake_machine_types
-            cpu_millis, memory_mib = self.fake_node_allocatable
-            for i in range(fake_machines):
-                labels = [(key, f"{prefix}-{i % n}") for key, prefix, n in dealt]
-                cores = cores_per_machine
-                if types:
-                    name, cores, _share = machine_type_of(i, types)
-                    labels.append((PLATFORM_LABEL, name))
-                self.add_node(
-                    NodeEvent(
-                        node_id=f"fake_node_{i}",
-                        num_cores=cores,
-                        pus_per_core=pus_per_core,
-                        cpu_allocatable_millis=cpu_millis,
-                        memory_allocatable_mib=memory_mib,
-                        labels=tuple(labels),
-                    )
-                )
+            for node in fake_node_events(
+                fake_machines, cores_per_machine, pus_per_core, types=self.fake_machine_types,
+                zones=self.fake_zones, racks=self.fake_racks, allocatable=self.fake_node_allocatable,
+            ):
+                self.add_node(node)
             return fake_machines
         nodes = self.api.get_node_batch(node_batch_timeout_s)
         for node in nodes:
@@ -1533,6 +1550,10 @@ def build_arg_parser() -> argparse.ArgumentParser:
     return ap
 
 
+#: the cost models `--array-round` has a device cost function for
+#: (costmodels/device_costs.py)
+ARRAY_ROUND_COST_MODELS = ("coco", "whare")
+
 #: what `--array-round` does not serve yet (ROADMAP R1's later steps):
 #: (the flag, whether `args` sets it, why the array round cannot honour it)
 _ARRAY_ROUND_REFUSES = (
@@ -1545,16 +1566,14 @@ _ARRAY_ROUND_REFUSES = (
     ("--audit-every", lambda a: a.audit_every,
      "it audits the graph path's device mirror, which the array round does not keep"),
     ("--tenants", lambda a: a.tenants, "it keeps one table for one cluster"),
-    ("--fake-machine-types", lambda a: a.fake_machine_types,
-     "the machines of its table are alike"),
     ("--fake-zones", lambda a: a.fake_zones, "its cost function reads no node label"),
     ("--fake-racks", lambda a: a.fake_racks, "its cost function reads no node label"),
     ("--fake-node-allocatable", lambda a: a.fake_node_allocatable != (0, 0),
      "its pods are alike in size: a slot a pod"),
     ("--machine-timeout", lambda a: a.machine_timeout > 0,
      "no heartbeat reaches its table yet"),
-    ("--cost-model {cost_model}", lambda a: a.cost_model != "coco",
-     "the one cost function it emits into device buffers is coco's"),
+    ("--cost-model {cost_model}", lambda a: a.cost_model not in ARRAY_ROUND_COST_MODELS,
+     "the cost functions it emits into device buffers are coco's and whare's"),
     ("--backend {backend}", lambda a: a.backend != "native",
      "its round is the dense transport on the device and dispatches to no backend: "
      "leave --backend out"),
@@ -1582,14 +1601,16 @@ def build_service(
         from .scheduler.array_service import ArrayRoundService
 
         refuse_unserved_by_array_round(args)
-        return ArrayRoundService(
-            api, max_tasks_per_pu=args.max_tasks_per_pu, tracer=tracer, flight=flight,
-            span_tracer=span_tracer, round_deadline_s=args.round_deadline,
-        )
     if args.fake_machine_types and args.cores_per_machine != 1:
         raise ValueError(
             "--fake-machine-types gives every type its cores: it is not served "
             f"together with --cores-per-machine {args.cores_per_machine}"
+        )
+    if args.array_round:
+        return ArrayRoundService(
+            api, max_tasks_per_pu=args.max_tasks_per_pu, cost_model=args.cost_model,
+            fake_machine_types=args.fake_machine_types, tracer=tracer, flight=flight,
+            span_tracer=span_tracer, round_deadline_s=args.round_deadline,
         )
     refuse_costs_that_cannot_fit(args)
     cost_model = CostModelType[args.cost_model.upper()]

@@ -44,26 +44,27 @@ def coco_device_cost_fn(penalties: Optional[np.ndarray] = None):
 
 
 def whare_device_cost_fn(
-    slots_per_machine: int,
-    psi: Optional[np.ndarray] = None,
+    slots,
     platform: Optional[np.ndarray] = None,
+    psi: Optional[np.ndarray] = None,
 ):
     """class_cost_fn for Whare-Map: census [M, 4] -> cost [4, M] int32.
 
-    slots_per_machine: total slots per machine (the array path's
-    machines are alike in size, so idle(m) = slots - census row sum —
-    the device round has no separate idle input).
-    psi: optional [4, P, 5] slowdown map (default: the learning prior).
+    slots: [M] total slots of each machine, its own (machines may differ
+    in size; one number where they are alike). The device round has no
+    separate idle input: idle(m) = slots(m) - census row sum.
     platform: optional [M] indices into costmodels.whare.PLATFORMS, the
     platform of each machine (the "heterogeneity in homogeneous WSCs"
-    axis of Whare-Map); default: one platform, the neutral one.
+    axis of Whare-Map: what its `ksched.io/platform` label names);
+    default: one platform, the neutral one.
+    psi: optional [4, P, 5] slowdown map (default: the learning prior).
     """
     psi_np = np.asarray(psi_prior() if psi is None else psi, np.int32)
     # each machine against the map of its platform: [4, M, 5], or [4, 1, 5]
     # for every machine alike
     where = [DEFAULT_PLATFORM] if platform is None else np.asarray(platform, np.int32)
     psi_m = jnp.asarray(psi_np[:, where, :])
-    slots = int(slots_per_machine)
+    slots_m = jnp.asarray(np.maximum(1, np.asarray(slots, np.int32)))  # [M], or a scalar
 
     def fn(census):
         c32 = census.astype(jnp.int32)
@@ -71,8 +72,8 @@ def whare_device_cost_fn(
         # an empty machine's one co-runner is ALONE: the fifth count
         beside = jnp.concatenate([c32, (running == 0).astype(jnp.int32)[:, None]], axis=1)
         expected = jnp.sum(psi_m * beside[None, :, :], axis=2) // jnp.sum(beside, axis=1)[None, :]  # [4, M]
-        idle = jnp.maximum(0, slots - running)
-        bonus = (IDLE_BONUS * idle) // slots
+        idle = jnp.maximum(0, slots_m - running)
+        bonus = (IDLE_BONUS * idle) // slots_m
         cost = expected - bonus[None, :]
         return jnp.clip(cost, 0, WHARE_MAX_COST).astype(jnp.int32)
 

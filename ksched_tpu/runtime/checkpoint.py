@@ -327,6 +327,8 @@ def save_device_checkpoint(cluster, path: str) -> None:
         arrays.update({f"g_{name}": got[name] for name in _DEVICE_GROUPS})
     if cluster.per_job:
         arrays["job_unsched_cost"] = np.asarray(cluster.job_unsched_cost)
+    # what each PU holds: machines may differ in size (0: no such PU)
+    arrays["pu_slots"] = np.asarray(cluster.pu_slots)
     # meta rides as JSON, not a single int64 array: a future float knob
     # (fractional discount, alpha) must keep its type on round-trip
     # instead of truncating silently
@@ -397,6 +399,8 @@ def load_device_checkpoint(path: str, class_cost_fn=None):
         active_groups_cap=meta["active_groups_cap"],
         refine_waves=meta["refine_waves"],
         two_stage_eps0=meta.get("two_stage_eps0", "one"),
+        # absent from checkpoints of machines that were alike
+        pu_slots=data["pu_slots"] if "pu_slots" in data else None,
     )
     cluster.state = DeviceClusterState(
         **{name: jnp.asarray(data[f"s_{name}"]) for name in _DEVICE_STATE}

@@ -221,6 +221,12 @@ class RoundRecord:
     array_d2h_bytes: int = 0
     array_pods_waiting: int = 0
     array_unconverged: int = 0
+    #: the decode window the round's program ran at (a bucket, 256 or
+    #: 4,096, or the table's rows; 0: the round launched no program), and
+    #: the machines with a free slot when the round began (after the
+    #: completions it retired): the columns the transport chose among
+    array_decode_width: int = 0
+    array_machines_open: int = 0
 
 
 class RoundTracer:

@@ -7,7 +7,12 @@ placements, the per-PU occupancy and the machine set are device arrays,
 built once by `init_topology` with every shape fixed for the service's
 life, and a scheduling round (capacity refresh -> class census -> the
 cost model's `class_cost_fn` -> dense transport -> rank-match decode ->
-apply) is one jitted program, `DeviceBulkCluster.serve_round`. The host
+apply) is one jitted program, `DeviceBulkCluster.serve_round`. The
+machines may differ: every machine is padded to the widest one's PUs, a
+PU a machine does not have holds 0 slots (`DeviceBulkCluster.pu_slots`),
+and the cost function follows `--cost-model`: `coco`'s, or `whare`'s over
+each machine's own slots and the platform its `ksched.io/platform` label
+names. The host
 keeps a mirror that costs O(batch) a round: which row of the table each
 pod holds (`admit` occupies "the first `count` free rows", so a min-heap
 of the free rows names them without asking the device), the PU of each
@@ -61,10 +66,11 @@ from typing import Deque, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from ..cli import ServiceLoop
+from ..cli import ARRAY_ROUND_COST_MODELS, ServiceLoop, fake_node_events
 from ..cluster import Binding, ClusterAPI, NodeEvent, PodEvent
-from ..costmodels import coco
-from ..costmodels.device_costs import coco_device_cost_fn
+from ..costmodels import coco, whare
+from ..costmodels.census import NUM_TASK_CLASSES
+from ..costmodels.device_costs import coco_device_cost_fn, whare_device_cost_fn
 from ..obs import metrics as obs_metrics
 from ..obs.spans import gc_pause_total_s, span
 from ..runtime.failure import RoundWatchdog
@@ -91,6 +97,8 @@ class ArrayRoundService(ServiceLoop):
         self,
         api: ClusterAPI,
         max_tasks_per_pu: int,
+        cost_model: str = "coco",
+        fake_machine_types=(),
         tracer=None,
         flight=None,
         span_tracer=None,
@@ -107,6 +115,12 @@ class ArrayRoundService(ServiceLoop):
         self._api_stats_mark: Dict[str, int] = api.stats() if hasattr(api, "stats") else {}
         self.watchdog = RoundWatchdog(round_deadline_s)
         self.max_tasks_per_pu = max_tasks_per_pu
+        if cost_model not in ARRAY_ROUND_COST_MODELS:
+            raise ValueError(
+                f"--array-round has no device cost function for --cost-model {cost_model}"
+            )
+        self.cost_model = cost_model
+        self.fake_machine_types = tuple(fake_machine_types)
         #: read by `init_topology`, which builds the table: a test lowers it
         self.supersteps = SUPERSTEPS
         self.cluster: Optional[DeviceBulkCluster] = None
@@ -114,7 +128,13 @@ class ArrayRoundService(ServiceLoop):
         self.nodes: List[str] = []
         #: the buckets below the table's width, then the table's (init_topology)
         self.widths: Tuple[int, ...] = ()
-        self._node_shape: Optional[Tuple[int, int]] = None
+        #: the nodes as they came, until the table is built from them
+        self._node_events: List[NodeEvent] = []
+        #: machine m's slots, and how many of them hold a pod (the mirror)
+        self.machine_slots = np.zeros(0, np.int32)
+        #: machine m's platform, an index into costmodels.whare.PLATFORMS
+        self.machine_platform = np.zeros(0, np.int32)
+        self._machine_load = np.zeros(0, np.int32)
         # -- the host mirror of the table -------------------------------
         self.row_of: Dict[str, int] = {}  # pod -> its row
         self.pod_at: List[Optional[str]] = []  # row -> its pod
@@ -144,23 +164,21 @@ class ArrayRoundService(ServiceLoop):
     # -- topology ---------------------------------------------------------
 
     def add_node(self, node: NodeEvent) -> None:
-        """A node of the cluster the table is about to be built for. The
-        table's machines are alike and fixed once built: a node of another
-        shape, or one that comes after `init_topology`, is refused."""
-        shape = (node.num_cores, node.pus_per_core)
+        """A node of the cluster the table is about to be built for, of
+        any cores x PUs. The table's machines are fixed once built: a node
+        that comes after `init_topology` is refused."""
         if self.cluster is not None:
             raise ValueError(
                 f"node {node.node_id}: --array-round builds its table for the "
                 f"{len(self.nodes)} machines init_topology saw; a node that joins "
                 "later is not served yet (ROADMAP R1)"
             )
-        if self._node_shape not in (None, shape):
+        if node.num_cores < 1 or node.pus_per_core < 1:
             raise ValueError(
-                f"node {node.node_id}: {shape[0]} cores x {shape[1]} PUs, the nodes before "
-                f"it {self._node_shape[0]} x {self._node_shape[1]}: --array-round serves "
-                "machines that are alike"
+                f"node {node.node_id}: {node.num_cores} cores x {node.pus_per_core} PUs: "
+                "--array-round wants a PU or more a node"
             )
-        self._node_shape = shape
+        self._node_events.append(node)
         self.nodes.append(node.node_id)
 
     def init_topology(
@@ -171,32 +189,48 @@ class ArrayRoundService(ServiceLoop):
         pus_per_core: int = 1,
     ) -> int:
         """Fabricate the machines or poll the control plane for them, as
-        `SchedulerService.init_topology` does, then build the ONE table
+        `SchedulerService.init_topology` does (the same function deals the
+        fake machines their types and labels), then build the ONE table
         the service keeps: a row for every slot and `WAITING_ROWS` more,
-        rounded up to a power of two, and compile every program a round
-        can run (three a bucket), so that no served round compiles."""
+        rounded up to a power of two; every machine padded to the widest
+        one's PUs, a PU a machine does not have holding no slot; each
+        machine's PUs and platform read from its NodeEvent. Every program
+        a round can run is compiled here (three a bucket), so that no
+        served round compiles."""
         if fake_machines > 0:
-            events = [
-                NodeEvent(f"fake_node_{i}", num_cores=cores_per_machine, pus_per_core=pus_per_core)
-                for i in range(fake_machines)
-            ]
+            events = fake_node_events(
+                fake_machines, cores_per_machine, pus_per_core, types=self.fake_machine_types
+            )
         else:
             events = self.api.get_node_batch(node_batch_timeout_s)
         for node in events:
             self.add_node(node)
         if not self.nodes:
             raise ValueError("--array-round: no node to build the table for")
-        pus = self._node_shape[0] * self._node_shape[1]
-        slots = len(self.nodes) * pus * self.max_tasks_per_pu
+        pus = np.array([n.num_cores * n.pus_per_core for n in self._node_events], np.int32)
+        widest = int(pus.max())
+        pu_slots = np.where(
+            np.arange(widest)[None, :] < pus[:, None], self.max_tasks_per_pu, 0
+        ).astype(np.int32)  # [M, widest]
+        self.machine_slots = pu_slots.sum(axis=1).astype(np.int32)
+        self._machine_load = np.zeros(len(self.nodes), np.int32)
+        self.machine_platform = np.array(
+            [whare.platform_index(dict(n.labels)) for n in self._node_events], np.int32
+        )
+        if self.cost_model == "whare":
+            cost_fn = whare_device_cost_fn(self.machine_slots, self.machine_platform)
+            unsched_cost = whare.UNSCHEDULED_COST
+        else:
+            cost_fn, unsched_cost = coco_device_cost_fn(), coco.UNSCHEDULED_COST
         self.cluster = DeviceBulkCluster(
-            num_machines=len(self.nodes), pus_per_machine=pus,
-            slots_per_pu=self.max_tasks_per_pu, num_jobs=1,
-            num_task_classes=coco.NUM_TASK_CLASSES,
-            task_capacity=next_pow2(slots + WAITING_ROWS),
-            class_cost_fn=coco_device_cost_fn(),
-            unsched_cost=coco.UNSCHEDULED_COST, ec_cost=0,
+            num_machines=len(self.nodes), pus_per_machine=widest,
+            slots_per_pu=self.max_tasks_per_pu, pu_slots=pu_slots.reshape(-1), num_jobs=1,
+            num_task_classes=NUM_TASK_CLASSES,
+            task_capacity=next_pow2(int(self.machine_slots.sum()) + WAITING_ROWS),
+            class_cost_fn=cost_fn, unsched_cost=unsched_cost, ec_cost=0,
             supersteps=self.supersteps,
         )
+        self._node_events = []
         rows = self.cluster.Tcap
         self.pod_at = [None] * rows
         self.pu_of_row = np.full(rows, -1, np.int32)
@@ -238,6 +272,7 @@ class ArrayRoundService(ServiceLoop):
             return False
         del self.row_of[pod_id]
         self.pod_at[row] = None
+        self._machine_load[self.pu_of_row[row] // self.cluster.P] -= 1
         self.pu_of_row[row] = -1
         self._done_rows.append(row)
         heapq.heappush(self._free_rows, row)
@@ -273,10 +308,10 @@ class ArrayRoundService(ServiceLoop):
         for pod in pods:
             row = self.row_of.get(pod.pod_id)
             if row is None:
-                if not 0 <= pod.task_class < coco.NUM_TASK_CLASSES:
+                if not 0 <= pod.task_class < NUM_TASK_CLASSES:
                     raise ValueError(
-                        f"pod {pod.pod_id}: task class {pod.task_class}, CoCo has "
-                        f"{coco.NUM_TASK_CLASSES}"
+                        f"pod {pod.pod_id}: task class {pod.task_class}, the census has "
+                        f"{NUM_TASK_CLASSES}"
                     )
                 fresh.append(pod)
             elif self.pu_of_row[row] >= 0:
@@ -313,8 +348,15 @@ class ArrayRoundService(ServiceLoop):
     # -- the round ------------------------------------------------------------
 
     def _run_round_body(self, pods, now, solve, queue_wait):
+        # the columns a transport chooses among: the completions taken
+        # before this round have left, its own placements have not come
+        # (O(machines); counted for the RoundRecord alone)
+        machines_open = 0
+        if self.tracer is not None:
+            machines_open = int(np.count_nonzero(self._machine_load < self.machine_slots))
         if not solve:
-            return self._record(0, 0, False, queue_wait, solved=False), 0
+            rec = self._record(0, 0, False, queue_wait, solved=False, machines_open=machines_open)
+            return rec, 0
         t0 = time.perf_counter()
         with self.watchdog as wd:
             h2d = self._retire_completed()
@@ -325,6 +367,7 @@ class ArrayRoundService(ServiceLoop):
             d2h = 0
             supersteps = 0
             unconverged = False
+            width = 0
             if self._waiting_rows:
                 with span("round"):
                     t_round = time.perf_counter()
@@ -350,10 +393,12 @@ class ArrayRoundService(ServiceLoop):
                         rows, pus = rows[moved], pus[moved]
                         self.pu_of_row[rows] = pus
                         self._waiting_rows.difference_update(rows.tolist())
-                        P, nodes, pod_at = self.cluster.P, self.nodes, self.pod_at
+                        machines = pus // self.cluster.P
+                        np.add.at(self._machine_load, machines, 1)
+                        nodes, pod_at = self.nodes, self.pod_at
                         out += [
-                            Binding(pod_at[row], nodes[pu // P])
-                            for row, pu in zip(rows.tolist(), pus.tolist())
+                            Binding(pod_at[row], nodes[m])
+                            for row, m in zip(rows.tolist(), machines.tolist())
                         ]
                         sp.set("resident", len(self.row_of))
                         sp.set("new", placed)
@@ -363,6 +408,7 @@ class ArrayRoundService(ServiceLoop):
         rec = self._record(
             len(out), supersteps, unconverged, queue_wait, deadline_miss=wd.fired,
             total_ms=(time.perf_counter() - t0) * 1e3, h2d=h2d, d2h=d2h,
+            decode_width=width, machines_open=machines_open,
         )
         return rec, len(out)
 
@@ -392,7 +438,8 @@ class ArrayRoundService(ServiceLoop):
             )
 
     def _record(self, bound, supersteps, unconverged, queue_wait, solved=True,
-                deadline_miss=False, total_ms=0.0, h2d=0, d2h=0):
+                deadline_miss=False, total_ms=0.0, h2d=0, d2h=0, decode_width=0,
+                machines_open=0):
         """The round's RoundRecord (None without a tracer), the gauges."""
         with span("round_accounting"):
             self._g_pods.set(len(self.row_of))
@@ -411,6 +458,7 @@ class ArrayRoundService(ServiceLoop):
                     array_d2h_bytes=d2h,
                     array_pods_waiting=len(self._waiting_rows) + len(self._deferred),
                     array_unconverged=int(unconverged),
+                    array_decode_width=decode_width, array_machines_open=machines_open,
                 ),
             )
 

@@ -17,10 +17,16 @@ the API server after the timed region — cmd/k8sscheduler/scheduler.go:
 The solve is the dense layered transport kernel — dispatched via
 ops.transport_solve: the fused Pallas kernel on TPU, the XLA phase loop
 elsewhere; both exit on convergence under a safety bound (`supersteps`),
-and each round reports a `converged` flag that callers assert on fetch. The decode is fully vectorized and gather-free:
-rank-matching placed tasks to machine grants via compare-matrix
-reductions ([Tcap, M] masks) and a tiny [Tcap,M]x[M,P] matmul for the
-within-machine PU split — MXU/VPU work instead of serialized gathers.
+and each round reports a `converged` flag that callers assert on fetch. The decode is fully vectorized:
+placed tasks are rank-matched to machine grants by a binary search of
+each row's rank in its group's cumulative grants, and to a PU by a
+search in that machine's cumulative room (`place_by_search`): O(W log M)
+for a window of W rows, and no array of [W, M], which at 262,144 rows x
+12,500 machines would be 13 GB.
+
+Machines may differ in size: every machine is padded to `pus_per_machine`
+PUs and `pu_slots` says what each PU holds (`slots_per_pu`, or 0 for a
+PU its machine does not have). PU p belongs to machine p // P either way.
 
 Graph semantics are identical to BulkCluster (same aggregate topology,
 same pin-on-place preemption-off accounting, same unscheduled-escape
@@ -93,6 +99,52 @@ SERVED_SUMMARY = (
 PREF_NONE = 1 << 30
 
 
+def count_le(table, start, length: int, x):
+    """For each of the W rows: how many of the `length` (static) entries
+    of `table` from `start[t]` on are <= `x[t]`. Every such segment is
+    nondecreasing, so this is a binary search of ceil(log2(length + 1))
+    steps, each one gather of W scalars. (`jnp.searchsorted` searches one
+    sorted array: the segments would have to be keyed apart, group x the
+    largest count, which overflows int32 at thousands of groups.)"""
+    lo = jnp.zeros_like(x)
+    hi = jnp.full_like(x, length)
+    for _ in range(int(length).bit_length()):
+        mid = (lo + hi) // 2
+        at = table[start + jnp.minimum(mid, length - 1)]
+        right = (lo < hi) & (at <= x)
+        lo, hi = jnp.where(right, mid + 1, lo), jnp.where(right, hi, jnp.minimum(mid, hi))
+    return lo
+
+
+def place_by_search(g, rank, grants_gm, pu_free, pus_per_machine: int):
+    """The PU of the row that is the `rank`-th (0-based, i32[W]) of group
+    `g` (i32[W], within [0, G)) under the solver's grants `grants_gm`
+    i32[G, M], over PUs with `pu_free` i32[M * P] slots to give: its
+    machine by a search of the rank in its group's cumulative grants, its
+    slot there after the groups before its own, its PU by a search of the
+    slot in that machine's cumulative room. Returns pu_abs i32[W]; a row
+    whose rank is beyond its group's grants gets an index that means
+    nothing (the caller masks it). O(W log M), and no [W, M] array: at a
+    table of 262,144 rows over 12,500 machines one would be 13 GB."""
+    i32 = jnp.int32
+    M, P = grants_gm.shape[1], int(pus_per_machine)
+    flat_cum = jnp.cumsum(grants_gm, axis=1).reshape(-1)  # each group's row nondecreasing
+    row0 = g * i32(M)
+    machine = count_le(flat_cum, row0, M, rank)  # the grant's machine; M when beyond
+    m_at = jnp.minimum(machine, i32(M - 1))
+    excl_at = jnp.where(machine > 0, flat_cum[row0 + jnp.maximum(machine, 1) - 1], i32(0))
+    offs = (jnp.cumsum(grants_gm, axis=0) - grants_gm).reshape(-1)
+    slot = offs[row0 + m_at] + (rank - excl_at)  # within-machine slot
+
+    # split each machine's grant across its PUs in slot order
+    t_m = jnp.sum(grants_gm, axis=0)
+    pf2 = pu_free.reshape(M, P)
+    exclg = jnp.cumsum(pf2, axis=1) - pf2
+    grants_pu = jnp.clip(t_m[:, None] - exclg, 0, pf2)
+    cumg = jnp.cumsum(grants_pu, axis=1).reshape(-1)
+    return machine * i32(P) + count_le(cumg, m_at * i32(P), P, slot)
+
+
 class DeviceBulkCluster:
     """Flat device-array cluster; one jitted program per scheduling round."""
 
@@ -124,6 +176,7 @@ class DeviceBulkCluster:
         active_groups_cap: int = 256,
         refine_waves: int = 8,
         two_stage_eps0: str = "one",
+        pu_slots: Optional[np.ndarray] = None,  # int[num_pus]: slots_per_pu, or 0 (no such PU)
     ) -> None:
         self.M = num_machines
         self.P = pus_per_machine
@@ -131,6 +184,19 @@ class DeviceBulkCluster:
         self.J = num_jobs
         self.C = num_task_classes
         self.num_pus = num_machines * pus_per_machine
+        # Machines that differ in size share one table: each is padded to
+        # `pus_per_machine` PUs, and a PU its machine does not have holds
+        # 0 slots. Every program reads a PU's or a machine's room from
+        # this vector, never from the scalar.
+        if pu_slots is None:
+            pu_slots = np.full(self.num_pus, slots_per_pu, np.int32)
+        pu_slots = np.asarray(pu_slots, np.int32)
+        if pu_slots.shape != (self.num_pus,) or not np.isin(pu_slots, (0, slots_per_pu)).all():
+            raise ValueError(
+                f"pu_slots must hold {self.num_pus} entries, each {slots_per_pu} (a PU) "
+                "or 0 (a PU its machine does not have)"
+            )
+        self.pu_slots = pu_slots
         self.Tcap = int(task_capacity)
         self.unsched_cost = int(unsched_cost)
         self.ec_cost = int(ec_cost)
@@ -408,8 +474,10 @@ class DeviceBulkCluster:
     # ------------------------------------------------------------------
 
     def _build_programs(self) -> None:
-        M, P, S, C, Tcap, Mp = self.M, self.P, self.S, self.C, self.Tcap, self.Mp
+        M, P, C, Tcap, Mp = self.M, self.P, self.C, self.Tcap, self.Mp
         num_pus, J = self.num_pus, self.J
+        pu_slots = jnp.asarray(self.pu_slots)  # [num_pus]: what each PU holds
+        machine_slots = jnp.asarray(self.pu_slots.reshape(M, P).sum(axis=1))  # [M]
         u_cost, e_cost = self.unsched_cost, self.ec_cost
         n_scale = self.n_scale
         supersteps = self.supersteps
@@ -464,13 +532,11 @@ class DeviceBulkCluster:
             [num_pus] the slots these grants may occupy. Returns
             (granted bool[W], pu_abs i32[W]).
 
-            Each group's cumulative-grant row is gathered per task via
-            a one-hot [W, Gn] x [Gn, M] matmul (MXU), and in-group
-            ranks come from one one-hot cumsum — no per-group Python
-            loop. precision=HIGHEST throughout: TPU f32 matmuls default
-            to bf16 passes, whose 8-bit mantissa corrupts counts beyond
-            256; all counts here are < 2^24, so f32 at HIGHEST is
-            exact."""
+            In-group ranks come from one one-hot [W, Gn] cumsum — no
+            per-group Python loop. precision=HIGHEST: TPU f32 matmuls
+            default to bf16 passes, whose 8-bit mantissa corrupts counts
+            beyond 256; all counts here are < 2^24, so f32 at HIGHEST is
+            exact. The machine and the PU of a rank: `place_by_search`."""
             hi = jax.lax.Precision.HIGHEST
             part = g_safe < i32(Gn)
             onehot = (
@@ -483,46 +549,18 @@ class DeviceBulkCluster:
                 jnp.sum(grants_gm, axis=1).astype(jnp.float32), precision=hi,
             )
             granted = part & (rank_f < quota)
-
-            # group-row -> machine via cumulative-grant comparisons
-            offs = jnp.cumsum(grants_gm, axis=0) - grants_gm  # [Gn, M]
-            cum_all = jnp.cumsum(grants_gm, axis=1).astype(jnp.float32)
-            cum_sel = jnp.einsum("tc,cm->tm", onehot, cum_all, precision=hi)
-            off_sel = jnp.einsum(
-                "tc,cm->tm", onehot, offs.astype(jnp.float32), precision=hi
+            pu_abs = place_by_search(
+                jnp.minimum(g_safe, i32(Gn - 1)), rank_f.astype(i32), grants_gm, pu_free, P
             )
-            cols = jnp.arange(M, dtype=i32)[None, :]
-            cmp = cum_sel <= rank_f[:, None]  # [W, M]
-            machine = jnp.sum(cmp, axis=1, dtype=i32)  # grant machine
-            excl_at = jnp.max(jnp.where(cmp, cum_sel, 0.0), axis=1)
-            oh = machine[:, None] == cols  # [W, M]
-            off_at = jnp.sum(jnp.where(oh, off_sel, 0.0), axis=1)
-            slot = off_at + (rank_f - excl_at)  # within-machine slot
-
-            # split each machine's grant across its PUs in slot order
-            t_m = jnp.sum(grants_gm, axis=0)
-            pf2 = pu_free.reshape(M, P)
-            exclg = jnp.cumsum(pf2, axis=1) - pf2
-            grants_pu = jnp.clip(t_m[:, None] - exclg, 0, pf2)
-            cumg = jnp.cumsum(grants_pu, axis=1).astype(jnp.float32)
-            cg_at = jnp.einsum(
-                "tm,mp->tp", oh.astype(jnp.float32), cumg, precision=hi
-            )  # [W, P]
-            pu_in = jnp.sum(cg_at <= slot[:, None], axis=1)
-            pu_abs = machine * P + pu_in.astype(i32)
             return granted, pu_abs
 
         def rank_match_decode_grouped(g_safe, grants_gm, pu_free):
             """Group-mode twin of rank_match_decode for LARGE group
-            counts: the one-hot path's [W, Gn] x [Gn, M] matmuls scale
-            as W*Gn*M MACs — prohibitive at thousands of groups. This
-            variant computes in-group ranks with ONE stable sort and
-            selects each row's cumulative-grant rows by gather (two
-            [W, M] ROW gathers — rows are lane-contiguous slices, the
-            fast gather direction on TPU). Same output contract as
+            counts: the one-hot path's [W, Gn] arrays scale as W*Gn —
+            prohibitive at thousands of groups. This variant computes
+            in-group ranks with ONE stable sort. Same output contract as
             rank_match_decode: (granted bool[W], pu_abs i32[W])."""
             W = g_safe.shape[0]
-            hi = jax.lax.Precision.HIGHEST
             part = g_safe < i32(Gn)
             # in-group exclusive rank via one stable sort (same trick
             # as the preempt decode's per-cell resident ranks)
@@ -534,30 +572,7 @@ class DeviceBulkCluster:
             quota = jnp.sum(grants_gm, axis=1)  # [Gn]
             quota_t = jnp.concatenate([quota, jnp.zeros(1, i32)])[g_safe]
             granted = part & (rank < quota_t)
-
-            # group-row -> machine via the row's cumulative grants
-            g_clip = jnp.clip(g_safe, 0, Gn - 1)
-            cum_t = jnp.cumsum(grants_gm, axis=1)[g_clip]  # [W, M]
-            offs_t = (jnp.cumsum(grants_gm, axis=0) - grants_gm)[g_clip]
-            cmp = cum_t <= rank[:, None]  # [W, M]
-            machine = jnp.sum(cmp, axis=1, dtype=i32)
-            excl_at = jnp.max(jnp.where(cmp, cum_t, i32(0)), axis=1)
-            cols = jnp.arange(M, dtype=i32)[None, :]
-            oh = machine[:, None] == cols  # [W, M]
-            off_at = jnp.sum(jnp.where(oh, offs_t, i32(0)), axis=1)
-            slot = off_at + (rank - excl_at)  # within-machine slot
-
-            # split each machine's grant across its PUs in slot order
-            t_m = jnp.sum(grants_gm, axis=0)
-            pf2 = pu_free.reshape(M, P)
-            exclg = jnp.cumsum(pf2, axis=1) - pf2
-            grants_pu = jnp.clip(t_m[:, None] - exclg, 0, pf2)
-            cumg = jnp.cumsum(grants_pu, axis=1).astype(jnp.float32)
-            cg_at = jnp.einsum(
-                "tm,mp->tp", oh.astype(jnp.float32), cumg, precision=hi
-            )  # [W, P]
-            pu_in = jnp.sum(cg_at <= slot[:, None].astype(jnp.float32), axis=1)
-            pu_abs = machine * P + pu_in.astype(i32)
+            pu_abs = place_by_search(jnp.clip(g_safe, 0, Gn - 1), rank, grants_gm, pu_free, P)
             return granted, pu_abs
 
         def group_costs(gspec: GroupSpec, cost_cm):
@@ -613,7 +628,7 @@ class DeviceBulkCluster:
             )
             pu_free = jnp.where(
                 jnp.repeat(state.machine_enabled, P),
-                S - state.pu_running,
+                pu_slots - state.pu_running,
                 i32(0),
             )
             machine_free = pu_free.reshape(M, P).sum(axis=1)
@@ -1057,7 +1072,7 @@ class DeviceBulkCluster:
             forced_m = jnp.where(forced, cur_m, i32(M))
             F_m = jnp.zeros(M + 1, i32).at[forced_m].add(1)[:M]
             col_cap_m = jnp.where(
-                state.machine_enabled, i32(P * S) - F_m, i32(0)
+                state.machine_enabled, machine_slots - F_m, i32(0)
             )
 
             if cost_fn is not None:
@@ -1139,7 +1154,7 @@ class DeviceBulkCluster:
             mover = live & ~stay
             stay_pu = jnp.where(stay, cur_pu, num_pus)
             pu_stay = jnp.zeros(num_pus + 1, i32).at[stay_pu].add(1)[:num_pus]
-            pu_free_mv = jnp.where(enabled_pu, i32(S) - pu_stay, i32(0))
+            pu_free_mv = jnp.where(enabled_pu, pu_slots - pu_stay, i32(0))
             decode = (rank_match_decode_grouped if use_sorted_decode
                       else rank_match_decode)
             if decode_width is None:

@@ -140,6 +140,21 @@ def _seeded_rng():
 #: true of each: every such entry still equals its file and lists accepted cells
 #: only, this cell's plan is its control's, the ten silent entries load wherever
 #: they loaded.
+#:
+#: PR 55 (`gtrace-12500-wharemap-array`: Whare-Map on the array round over machines
+#: that differ) appended a configuration, a fourteenth cell, its name to the ten
+#: `array_*` lists and to `bind_tail_ms`, `bindings_post_ms`, `gc_pause_ms`, and two
+#: entries (`array_decode_width`, `array_machines_open`) that list both array cells,
+#: as ISSUE 55 asks. `test_benchmark_array.py` states the three lists it joined as
+#: "what they had, then this cell" over the first twelve cells (three cases) and that
+#: no other list names its cell (one case: the two new entries do);
+#: `test_benchmark_seams.py`, `test_benchmark_solve_split.py` and
+#: `test_benchmark_runnable_scan.py` draw a case for every cell and want no `pods`
+#: key, a plan digest on file, or the graph path's metrics loaded (four cases, none
+#: of which existed before this cell). `test_benchmark_wharemap_array.py` holds what
+#: stays true of each: the three lists as "what they had, PR 53's cell, then this
+#: one", every list but the fifteen leaves both array cells out, this cell's plan is
+#: its control's seed for seed and `class_only` by name is `class_only` by default.
 _STALE = {
     "test_benchmark_seams.py::test_class_only_is_the_old_expression_and_the_same_seed_"
     "draws_the_same_plan[k8s-5000-preemption.rollout-": "PR 38 brings pods/by_role.py",
@@ -243,6 +258,18 @@ _STALE = {
     },
     "test_benchmark_runnable_scan.py::test_a_cell_loads_it_by_name_if_it_is_listed_and_not_"
     "otherwise[coco-50kx1k-array.trickle]": "PR 53's cell loads no runnable_scan_ms",
+    "test_benchmark_array.py::test_a_list_it_was_appended_to_holds_what_it_had_then_this_cell[":
+        "PR 55 appended its cell after PR 53's to the list",
+    "test_benchmark_array.py::test_the_cell_loads_its_metrics_by_name_and_the_control_loads_none_"
+    "of_them": "PR 55's two entries list PR 53's cell too",
+    "test_benchmark_solve_split.py::test_a_cell_loads_each_by_name_if_it_is_listed_and_not_"
+    "otherwise[gtrace-12500-wharemap-array.trickle]": "PR 55's cell is on gc_pause_ms's list alone",
+    "test_benchmark_seams.py::test_class_only_is_the_old_expression_and_the_same_seed_"
+    "draws_the_same_plan[gtrace-12500-wharemap-array.trickle-":
+        "PR 55's configuration names pods class_only, as its control does, and PLAN_DIGESTS has "
+        "no entry for its cell, whose plan is gtrace-12500-wharemap.trickle's",
+    "test_benchmark_runnable_scan.py::test_a_cell_loads_it_by_name_if_it_is_listed_and_not_"
+    "otherwise[gtrace-12500-wharemap-array.trickle]": "PR 55's cell loads no runnable_scan_ms",
     "test_benchmark_wharemap.py::test_the_traced_rehearsal_is_correct_and_every_metric_reads_"
     "a_number": "PR 48: `ec_arcs_repriced` is visited ECs x `census_machines_dirty` in a round "
                 "that patched, and nearly every arc written changes",

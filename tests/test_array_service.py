@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from benchmarks import reference_coco as ref
+from benchmarks import reference_wharemap_array as ref_array
 from ksched_tpu import cli
 from ksched_tpu.cluster import SyntheticClusterAPI
 from ksched_tpu.cluster.api import Binding, NodeEvent, PodEvent
@@ -94,7 +95,9 @@ def mirror_is_the_table(svc):
     placed = live & (pu >= 0)
     running = np.asarray(st["pu_running"])
     assert (np.bincount(pu[placed], minlength=running.size) == running).all()
-    assert running.max(initial=0) <= 16
+    assert (running <= svc.cluster.pu_slots).all()  # 0 for a PU its machine does not have
+    load = running.reshape(len(svc.nodes), -1).sum(axis=1)
+    assert (load == svc._machine_load).all() and (load <= svc.machine_slots).all()
 
 
 @pytest.fixture
@@ -237,7 +240,7 @@ def test_a_pod_delivered_again_keeps_its_row_and_gets_its_binding_again():
 
 def test_a_pod_of_no_coco_class_is_refused_by_name():
     svc, _api = build()
-    with pytest.raises(ValueError, match="pod odd: task class 4, CoCo has 4"):
+    with pytest.raises(ValueError, match="pod odd: task class 4, the census has 4"):
         svc.run_round([PodEvent("odd", task_class=4)])
 
 
@@ -271,6 +274,11 @@ def test_the_round_opens_its_spans_and_stamps_its_record_with_exact_bytes():
     assert served.solver_work > 0 and served.array_pods_waiting == 0 == served.array_unconverged
     assert served.phases_ms["total"] > 0
     assert (idle.solver_rung, idle.num_scheduled, idle.array_h2d_bytes) == (-1, 0, 0)
+    # the bucket each round's program ran at, and the machines with a free slot at its start
+    assert (fill.array_decode_width, served.array_decode_width, idle.array_decode_width) == (4096, 256, 0)
+    load = np.bincount([int(n.rsplit("_", 1)[1]) for p, n in api.bindings().items() if p[0] == "r" and p != "r0"],
+                       minlength=MACHINES)
+    assert fill.array_machines_open == MACHINES > served.array_machines_open == int((load < SLOTS).sum())
     assert len(api.bindings()) == 303
 
 
@@ -280,12 +288,11 @@ REFUSED = {
     "--device-resident": ["--device-resident"],
     "--audit-every": ["--audit-every", "4"],
     "--tenants": ["--tenants", "2"],
-    "--fake-machine-types": ["--fake-machine-types", "A:1:500,B:2:500"],
     "--fake-zones": ["--fake-zones", "3"],
     "--fake-racks": ["--fake-racks", "5"],
     "--fake-node-allocatable": ["--fake-node-allocatable", "4000:32768"],
     "--machine-timeout": ["--machine-timeout", "30"],
-    "--cost-model whare": ["--cost-model", "whare"],
+    "--cost-model quincy": ["--cost-model", "quincy"],
     "--cost-model trivial": ["--cost-model", "trivial"],
     "--backend jax": ["--backend", "jax"],
     "--backend auto": ["--backend", "auto"],
@@ -325,22 +332,175 @@ def test_checkpoints_heartbeats_and_late_or_unlike_nodes_are_refused_with_a_sent
         svc.add_node(NodeEvent("late", num_cores=1, pus_per_core=4))
 
 
-def test_polled_nodes_that_are_alike_are_served_under_their_own_names_and_unlike_ones_refused():
+def test_polled_nodes_of_several_shapes_are_served_under_their_own_names_each_to_its_own_slots():
     args = cli.build_arg_parser().parse_args(
         "--max-tasks-per-pu 16 --cost-model coco --array-round --node-batch-timeout 0.05".split()
     )
-    api = SyntheticClusterAPI(pod_chan_size=100)
-    for name in ("alpha", "beta", "gamma"):
-        api.submit_node(NodeEvent(name, num_cores=1, pus_per_core=2))
+    api = SyntheticClusterAPI(pod_chan_size=200)
+    shapes = {"alpha": (1, 2), "beta": (2, 2), "gamma": (1, 1)}
+    for name, (cores, pus) in shapes.items():
+        api.submit_node(NodeEvent(name, num_cores=cores, pus_per_core=pus))
     svc = cli.build_service(args, api)
-    assert svc.init_topology(node_batch_timeout_s=0.05) == 3 and svc.cluster.P == 2
-    svc.run_round([PodEvent(f"r{i}", task_class=i % 4) for i in range(70)])
-    assert set(api.bindings().values()) == {"alpha", "beta", "gamma"}
-    api2 = SyntheticClusterAPI(pod_chan_size=100)
-    api2.submit_node(NodeEvent("one", num_cores=1, pus_per_core=2))
-    api2.submit_node(NodeEvent("two", num_cores=2, pus_per_core=2))
-    with pytest.raises(ValueError, match="node two: 2 cores x 2 PUs, the nodes before it 1 x 2"):
-        cli.build_service(args, api2).init_topology(node_batch_timeout_s=0.05)
+    assert svc.init_topology(node_batch_timeout_s=0.05) == 3
+    # one table, every machine padded to the widest one's 4 PUs; a PU that is not there holds 0
+    assert svc.cluster.P == 4 and svc.machine_slots.tolist() == [32, 64, 16]
+    assert svc.cluster.pu_slots.reshape(3, 4).tolist() == [[16, 16, 0, 0], [16] * 4, [16, 0, 0, 0]]
+    assert svc.run_round([PodEvent(f"r{i}", task_class=i % 4) for i in range(120)]) == 112
+    load = np.bincount([list(shapes).index(n) for n in api.bindings().values()], minlength=3)
+    assert load.tolist() == [32, 64, 16] and len(svc._waiting_rows) == 8
+    running = np.asarray(svc.cluster.fetch_state()["pu_running"])
+    assert (running <= svc.cluster.pu_slots).all() and running.sum() == 112
+    with pytest.raises(ValueError, match="node none: 0 cores x 2 PUs"):
+        cli.build_service(args, SyntheticClusterAPI(pod_chan_size=1)).add_node(
+            NodeEvent("none", num_cores=0, pus_per_core=2))
+
+
+# -- Whare-Map on machines of three types -----------------------------------------------------
+
+TYPES = "A:1:10,B:2:930,C:4:60"
+WHARE_MACHINES = 312  # the fortieth of 12,500: 1 / 284 / 27 machines of 2 / 4 / 8 PUs
+WHARE_ARGV = (
+    f"--fake-machines --num-machines {WHARE_MACHINES} --pus-per-core 2 --max-tasks-per-pu 3 "
+    f"--fake-machine-types {TYPES} --cost-model whare --array-round --pod-batch-timeout 0.002 "
+    "--pod-chan-size 8000"
+).split()
+
+
+def build_whare(tracer=None):
+    args = cli.build_arg_parser().parse_args(WHARE_ARGV)
+    api = SyntheticClusterAPI(pod_chan_size=args.pod_chan_size)
+    svc = cli.build_service(args, api, tracer=tracer)
+    svc.init_topology(fake_machines=args.num_machines, pus_per_core=args.pus_per_core)
+    return svc, api, args
+
+
+class Record:
+    """The harness's record of a run, kept by the test: Bindings and completions in the loop's
+    order, and what each round was handed; replayed by the plain reference."""
+
+    def __init__(self, api):
+        self.api, self.log, self.batches, self.class_of, self.t = api, [], [], {}, 0.0
+        self.posted, self.ever = {}, set()  # bound now; bound at any time
+
+    def pods(self, rng, n, tag):
+        pods = [PodEvent(f"{tag}_{i}", task_class=int(c)) for i, c in enumerate(rng.integers(0, 4, n))]
+        self.class_of.update((p.pod_id, p.task_class) for p in pods)
+        return pods
+
+    def complete(self, svc, pod):
+        assert svc.complete_pod(pod)
+        self.log.append(("done", pod, "", self.t))
+        del self.posted[pod]
+
+    def round(self, svc, batch, solve=True):
+        self.t += 1.0
+        self.batches.append((self.t - 0.5, [p.pod_id for p in batch]))
+        bound = svc.run_round(batch, solve=solve)
+        new = {p: n for p, n in self.api.bindings().items() if p not in self.ever}
+        self.posted.update(new)
+        self.ever.update(new)
+        self.log += [("bind", pod, node, self.t) for pod, node in new.items()]
+        assert bound == len(new)
+        return new
+
+    def replay(self, args, **kw):
+        return ref_array.check_interference_map_array(
+            self.log, self.class_of, [f"fake_node_{i}" for i in range(args.num_machines)],
+            cli.parse_machine_types(TYPES), args.pus_per_core, args.max_tasks_per_pu,
+            batches=self.batches, **kw,
+        )
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_whare_on_three_types_costs_the_references_optimum_round_by_round(seed):
+    """Fill, arrivals and completions, a burst beyond the idle slots (a full cluster, pods that
+    wait), the quiet-channel round after completions: every round at the plain reference's
+    optimum on a census of the reference's own, the table equal to the mirror after each."""
+    rng = np.random.default_rng(seed)
+    tracer = RoundTracer()
+    svc, api, args = build_whare(tracer)
+    slots = int(svc.machine_slots.sum())
+    assert (slots, svc.cluster.P, svc.cluster.Tcap, svc.widths) == (4062, 8, 8192, (256, 4096, 8192))
+    assert np.bincount(svc.machine_slots).nonzero()[0].tolist() == [6, 12, 24]
+    assert np.bincount(svc.machine_platform, minlength=3).tolist() == [1, 284, 27]
+    rec = Record(api)
+    assert len(rec.round(svc, rec.pods(rng, 3600, "r"))) == 3600
+    mirror_is_the_table(svc)
+    for r in range(6):
+        for pod in list(rec.posted)[: int(rng.integers(0, 6))]:
+            rec.complete(svc, pod)
+        batch = rec.pods(rng, int(rng.integers(1, 9)), f"p{r}")
+        assert len(rec.round(svc, batch)) == len(batch)
+        mirror_is_the_table(svc)
+    idle = slots - len(rec.posted)
+    assert len(rec.round(svc, rec.pods(rng, idle + 5, "b"))) == idle  # the cluster is full
+    assert len(svc._waiting_rows) == 5 and tracer.records[-1].array_machines_open > 0
+    mirror_is_the_table(svc)
+    for pod in list(rec.posted)[:3]:
+        rec.complete(svc, pod)
+    assert svc.backlog_dirty and len(rec.round(svc, [], solve=True)) == 3  # the quiet-channel round
+    assert 1 <= tracer.records[-1].array_machines_open <= 3  # the machines the three left
+    mirror_is_the_table(svc)
+    faults, facts = rec.replay(args)
+    assert faults == [], faults
+    assert facts["rounds"] == 9 and facts["rounds_costing_zero"] == 0
+    assert facts["served_cost"] == facts["optimum_cost"] > 2500 * 7
+    assert facts["rounds_that_left_pods_waiting"] == 2 and facts["pods_left_waiting_at_most"] == 5
+    assert facts["nodes_by_platform"] == [1, 284, 27] and facts["slots"] == slots
+    # the incremental replay is the plain one: every machine priced every round, reference_round
+    plain_faults, plain = rec.replay(args, plain=True)
+    assert plain_faults == [] and plain["machines_priced"] == 9 * WHARE_MACHINES > facts["machines_priced"]
+    for key in ("served_cost", "optimum_cost", "rounds", "pods_bound", "bound_by_class_and_platform"):
+        assert plain[key] == facts[key], key
+    # the graph path's rule (a completed pod leaves after the round) does not describe this service
+    assert any("the optimum of the round is" in f for f in rec.replay(args, completions_leave_after_the_round=True)[0])
+    assert (svc.unconverged_rounds, svc.admissions_short, svc.cost_overflows) == (0, 0, 0)
+    assert [r.array_decode_width for r in tracer.records] == [4096] + [256] * 6 + [4096, 256]
+
+
+def test_whare_on_machines_alike_is_served_too_and_main_serves_three_types_one_shot(capsys):
+    args = cli.build_arg_parser().parse_args(
+        f"--fake-machines --num-machines {MACHINES} --pus-per-core 4 --max-tasks-per-pu 16 "
+        "--cost-model whare --array-round".split()
+    )
+    api = SyntheticClusterAPI(pod_chan_size=2000)
+    svc = cli.build_service(args, api)
+    svc.init_topology(fake_machines=MACHINES, cores_per_machine=1, pus_per_core=4)
+    assert svc.cluster.unsched_cost == 2500 and (svc.machine_platform == 1).all()  # no label: B
+    assert svc.run_round([PodEvent(f"r{i}", task_class=i % 4) for i in range(200)]) == 200
+    load = np.bincount([int(n.rsplit("_", 1)[1]) for n in api.bindings().values()], minlength=MACHINES)
+    assert load.sum() == 200 and load.max() <= SLOTS
+    assert cli.main(WHARE_ARGV + ["--podgen", "60", "--one-shot"]) == 0
+    assert "scheduled 60/60 pods" in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_the_device_cost_function_is_the_hosts_and_the_references_on_three_types(seed):
+    import jax.numpy as jnp
+
+    from benchmarks import reference_wharemap as ref_w
+    from ksched_tpu.costmodels.device_costs import whare_device_cost_fn
+    from ksched_tpu.costmodels.whare import PLATFORM_PRIOR, PSI_PRIOR, whare_cost_matrix
+
+    rng = np.random.default_rng(seed)
+    M = 400
+    kind = rng.choice(3, M, p=[0.05, 0.8, 0.15])
+    slots, platform = np.array([6, 12, 24])[kind], kind.astype(np.int64)
+    running = np.where(rng.random(M) < 0.15, 0, rng.integers(0, slots + 1))  # some empty, some full
+    census = np.stack([rng.multinomial(n, [0.25] * 4) for n in running]).astype(np.int64)
+    got = np.asarray(whare_device_cost_fn(slots, platform)(jnp.asarray(census, jnp.int32)))
+    idle = slots - running
+    assert (idle == 0).any() and (running == 0).any()
+    np.testing.assert_array_equal(got, whare_cost_matrix(census, idle, slots, platform=platform))
+    np.testing.assert_array_equal(got, ref_w.cost_matrix(census, idle, slots, platform))
+    assert got.dtype == np.int32 and got.min() >= 0 and got.max() <= 2000
+    # the constants the reference states are the model's
+    assert np.array_equal(np.asarray(ref_w.PSI_PRIOR), PSI_PRIOR)
+    assert np.array_equal(np.asarray(ref_w.PLATFORM_PRIOR), PLATFORM_PRIOR)
+    # an empty machine: ALONE on its platform, less the whole bonus
+    empty = np.zeros((3, 4), np.int32)
+    alone = np.asarray(whare_device_cost_fn(np.array([6, 12, 24]), np.arange(3))(jnp.asarray(empty)))
+    assert alone.tolist() == [[90, 80, 75], [110, 80, 65], [95, 80, 70], [82, 80, 79]]
 
 
 # -- scheduler/device_bulk.py: what serving added ---------------------------------------
@@ -412,3 +572,141 @@ def test_a_preempting_cluster_serves_no_round():
     )
     with pytest.raises(ValueError, match="preemption is not served"):
         dev.serve_round()
+
+
+# -- the decode without a [W, M] array, machines that differ ------------------------------------
+
+
+def _decode_by_comparison(g, rank, grants, pu_free, P):
+    """The decode as it stood before PR 55, in numpy: a row's machine by comparing its rank with
+    its group's cumulative grants over every machine ([W, M]), its PU by comparing its slot with
+    its machine's cumulative room."""
+    G, M = grants.shape
+    cum = np.cumsum(grants, axis=1)[g]  # [W, M]
+    cmp = cum <= rank[:, None]
+    machine = cmp.sum(axis=1)
+    excl_at = np.where(cmp, cum, 0).max(axis=1)
+    offs = (np.cumsum(grants, axis=0) - grants)[g]  # [W, M]
+    oh = machine[:, None] == np.arange(M)[None, :]
+    slot = np.where(oh, offs, 0).sum(axis=1) + rank - excl_at
+    pf2 = pu_free.reshape(M, P)
+    grants_pu = np.clip(grants.sum(axis=0)[:, None] - (np.cumsum(pf2, axis=1) - pf2), 0, pf2)
+    cg_at = oh.astype(np.int64) @ np.cumsum(grants_pu, axis=1)  # [W, P]
+    return machine * P + (cg_at <= slot[:, None]).sum(axis=1)
+
+
+@pytest.mark.parametrize("width", [7, 256, 4096], ids=["7", "256", "the-table"])
+@pytest.mark.parametrize("shape", [(4, 25, 4, 16), (4, 312, 8, 3), (37, 60, 2, 5)],
+                         ids=["coco-25x4x16", "three-types-312x8x3", "37-groups"])
+def test_the_search_places_a_row_where_the_comparison_over_every_machine_placed_it(shape, width):
+    import jax.numpy as jnp
+
+    from ksched_tpu.scheduler.device_bulk import place_by_search
+
+    G, M, P, S = shape
+    rng = np.random.default_rng(G * M + width)
+    pus = rng.integers(1, P + 1, M) if P == 8 else np.full(M, P)
+    pu_free = np.where(np.arange(P)[None, :] < pus[:, None], rng.integers(0, S + 1, (M, P)), 0)
+    room = pu_free.sum(axis=1)
+    # grants that fit each machine's room, split over the groups; many machines get none
+    take = np.minimum(room, rng.integers(0, 2 * S, M) * (rng.random(M) < 0.6))
+    grants = np.stack([rng.multinomial(n, np.ones(G) / G) for n in take], axis=1).astype(np.int32)
+    quota = grants.sum(axis=1)
+    g = rng.integers(0, G, width)
+    rank = np.array([rng.integers(0, max(1, quota[k])) for k in g])
+    granted = rank < quota[g]
+    want = _decode_by_comparison(g, rank, grants.astype(np.int64), pu_free.astype(np.int64), P)
+    got = np.asarray(place_by_search(
+        jnp.asarray(g, jnp.int32), jnp.asarray(rank, jnp.int32), jnp.asarray(grants),
+        jnp.asarray(pu_free.reshape(-1), jnp.int32), P,
+    ))
+    assert granted.sum() > width // 3
+    assert np.array_equal(got[granted], want[granted])
+    # a granted row lands on a PU that exists and had room
+    assert (pu_free.reshape(-1)[got[granted]] > 0).all()
+
+
+def _avals(jaxpr):
+    """Every array a program's equations produce, sub-programs included."""
+    for eqn in jaxpr.eqns:
+        yield from (v.aval for v in eqn.outvars)
+        for sub in eqn.params.values():
+            for inner in (sub if isinstance(sub, (list, tuple)) else [sub]):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _avals(inner)
+
+
+def test_no_program_of_the_full_size_table_holds_an_array_of_rows_by_machines():
+    """262,144 rows x 12,500 machines of three types, as `gtrace-12500-wharemap-array` builds
+    them: traced, nothing run. One `[Tcap, M]` int32 would be 13.1 GB of a 16 GB chip."""
+    import jax
+
+    from ksched_tpu.costmodels import whare
+    from ksched_tpu.costmodels.device_costs import whare_device_cost_fn
+
+    M = 12500
+    events = cli.fake_node_events(M, 1, 2, types=cli.parse_machine_types(TYPES))
+    pus = np.array([n.num_cores * n.pus_per_core for n in events])
+    pu_slots = np.where(np.arange(8)[None, :] < pus[:, None], 3, 0).astype(np.int32)
+    slots = pu_slots.sum(axis=1)
+    assert (np.bincount(pus)[[2, 4, 8]].tolist(), int(slots.sum())) == ([121, 11623, 756], 158346)
+    platform = np.array([whare.platform_index(dict(n.labels)) for n in events])
+    dev = DeviceBulkCluster(
+        num_machines=M, pus_per_machine=8, slots_per_pu=3, pu_slots=pu_slots.reshape(-1), num_jobs=1,
+        num_task_classes=4, task_capacity=262144, class_cost_fn=whare_device_cost_fn(slots, platform),
+        unsched_cost=whare.UNSCHEDULED_COST, ec_cost=0, supersteps=1 << 17,
+    )
+    shapes = jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), dev.state)
+    for width in (256, 4096, None):
+        jaxpr = jax.make_jaxpr(
+            lambda st: dev._served_round_jit.__wrapped__(st, None, width))(shapes).jaxpr
+        sizes = [int(np.prod(a.shape)) for a in _avals(jaxpr) if hasattr(a, "shape")]
+        assert len(sizes) > 200
+        # the largest array is a few columns of the table, or [rows, classes]; never rows x machines
+        assert max(sizes) <= 8 * dev.Tcap, (width, max(sizes))
+    # the scaled costs fit: 2,500 x n_scale under the limit, so `cost_overflow` stays 0
+    from ksched_tpu.solver.layered import COST_SCALE_LIMIT
+
+    assert dev.n_scale == 16384 and whare.UNSCHEDULED_COST * dev.n_scale < COST_SCALE_LIMIT
+
+
+@pytest.mark.parametrize("mode", ["pin-on-place", "preemption", "steady-scan"])
+def test_every_round_flavour_reads_a_pus_room_from_the_slot_vector(mode):
+    """Machines of 1, 2 and 4 PUs padded to 4: no mode of the class may fill a PU that is not there."""
+    pu_slots = np.array([[2, 0, 0, 0], [2, 2, 0, 0], [2, 2, 2, 2]] * 4, np.int32)
+    dev = DeviceBulkCluster(
+        num_machines=12, pus_per_machine=4, slots_per_pu=2, pu_slots=pu_slots.reshape(-1), num_jobs=1,
+        num_task_classes=2, task_capacity=256, preemption=mode == "preemption",
+        continuation_discount=1, decode_width=64 if mode == "steady-scan" else None,
+    )
+    dev.add_tasks(80, classes=np.arange(80, dtype=np.int32) % 2)
+    if mode == "steady-scan":
+        dev.fetch_stats(dev.run_steady_rounds(3, 0.2, 10, seed=1))
+    else:
+        dev.fetch_stats(dev.round())
+        dev.complete_tasks([0, 1, 2])
+        dev.fetch_stats(dev.round())
+    st = dev.fetch_state()
+    running = np.asarray(st["pu_running"])
+    assert (running <= pu_slots.reshape(-1)).all() and running.sum() == pu_slots.sum() == 56
+    with pytest.raises(ValueError, match="pu_slots must hold 48 entries, each 2"):
+        DeviceBulkCluster(num_machines=12, pus_per_machine=4, slots_per_pu=2, num_jobs=1,
+                          pu_slots=np.full(48, 3, np.int32))
+
+
+def test_a_checkpoint_carries_the_slot_vector(tmp_path):
+    from ksched_tpu.runtime.checkpoint import load_device_checkpoint, save_device_checkpoint
+
+    pu_slots = np.array([3, 0, 3, 3] * 5, np.int32)
+    dev = DeviceBulkCluster(num_machines=10, pus_per_machine=2, slots_per_pu=3, pu_slots=pu_slots,
+                            num_jobs=1, task_capacity=64)
+    dev.add_tasks(40)
+    dev.fetch_stats(dev.round())
+    save_device_checkpoint(dev, str(tmp_path / "ck.npz"))
+    back = load_device_checkpoint(str(tmp_path / "ck.npz"))
+    assert np.array_equal(back.pu_slots, pu_slots)
+    back.add_tasks(10)
+    back.fetch_stats(back.round())
+    assert (np.asarray(back.fetch_state()["pu_running"]) <= pu_slots).all()
+    assert back.num_placed_tasks == 45 == pu_slots.sum()

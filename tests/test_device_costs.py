@@ -36,7 +36,7 @@ def test_whare_device_matches_numpy_homogeneous():
         idle = np.maximum(0, slots - census.sum(axis=1))
         want = whare_cost_matrix(census, idle, np.full(M, slots, np.int64))
         got = np.asarray(
-            whare_device_cost_fn(slots_per_machine=slots)(jnp.asarray(census))
+            whare_device_cost_fn(slots)(jnp.asarray(census))
         )
         np.testing.assert_array_equal(got, want)
 
@@ -47,7 +47,7 @@ def test_whare_platform_scales_expected_slowdown():
     census = np.full((3, 4), 2, np.int64)
     platform = np.asarray([2, 0, 1], np.int64)  # C, A, B
     cost = np.asarray(
-        whare_device_cost_fn(slots_per_machine=16, platform=platform)(jnp.asarray(census))
+        whare_device_cost_fn(16, platform=platform)(jnp.asarray(census))
     )
     assert (cost[:, 1] >= cost[:, 2]).all() and (cost[:, 2] >= cost[:, 0]).all()
     assert (cost[:, 1] > cost[:, 0]).any()

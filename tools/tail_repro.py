@@ -51,7 +51,7 @@ def build_config(name: str):
             num_jobs=20, num_task_classes=4,
             task_capacity=next_pow2(tasks + 4096),
             class_cost_fn=whare_device_cost_fn(
-                slots_per_machine=32, platform=platform
+                32, platform=platform
             ),
             unsched_cost=_whare_unsched(), ec_cost=0,
             supersteps=1 << 17, decode_width=2048,
