@@ -347,10 +347,12 @@ class SchedulerService(ServiceLoop):
         self.preemption = preemption
         #: tasks evicted and not bound again since
         self._evicted_pending: set = set()
-        #: what the last collected round evicted, and how many of those
-        #: it bound elsewhere in the same round (RoundRecord)
+        #: what the last collected round evicted, how many of those it
+        #: bound elsewhere in the same round, and how many tasks its
+        #: collection looked at (RoundRecord)
         self._pods_evicted = 0
         self._pods_migrated = 0
+        self._bindings_examined = 0
         #: --fake-zones: the fake machines of init_topology carry a zone
         #: label, machine i that of zone i mod fake_zones (0: no label)
         self.fake_zones = fake_zones
@@ -465,7 +467,12 @@ class SchedulerService(ServiceLoop):
         self.machine_to_node: Dict[int, str] = {}
         # One job shelters every pod-task (reference :118, :241-257).
         self.job_id = rand_uint64()
+        #: task -> PU as the service last emitted it (the checkpoint's
+        #: record); kept in place by _collect_bindings, task by task
         self.old_bindings: Dict[int, int] = {}
+        #: tasks whose emitted Binding the service forgot since the last
+        #: collection, for it to look at beside those the scheduler changed
+        self._bindings_forgotten: Dict[int, None] = {}
         self.round_latencies_s: list = []
         self.noop_rounds = 0
         #: whether the runnable backlog may need a re-solve on a quiet
@@ -618,7 +625,7 @@ class SchedulerService(ServiceLoop):
         if existing is not None:
             # Re-delivered pod: keep the existing task — a duplicate
             # would double-occupy capacity — and forget the emitted
-            # binding so the next round's diff re-posts it. Two causes:
+            # binding so the next collection re-posts it. Two causes:
             # a failed binding POST (spec unchanged), or a pod deleted
             # and re-created under the same name (the watch reconcile
             # re-surfaces it). For the latter the new spec must win:
@@ -640,6 +647,7 @@ class SchedulerService(ServiceLoop):
                     rs = self.resource_map.find(rid)
                     self.scheduler.handle_task_eviction(td, rs.descriptor)
             self.old_bindings.pop(existing, None)
+            self._bindings_forgotten[existing] = None
             return
         td = add_task_to_job(
             self.job_id, self.job_map, self.task_map, name=pod.pod_id, scheduler=self.scheduler
@@ -684,49 +692,67 @@ class SchedulerService(ServiceLoop):
         return None if machine_rid is None else self.machine_to_node[machine_rid]
 
     def _collect_bindings(self) -> Tuple[List[Binding], List[Binding]]:
-        """Diff the scheduler's bindings against what was last emitted:
-        (evictions, Bindings). A new or changed binding is a Binding to
-        post. Under `--preemption` a task of `old_bindings` that the
-        service still knows and the scheduler no longer binds was
-        evicted, and one it binds elsewhere migrated: both are an
-        eviction from the node the pod leaves (the second with its
-        Binding). An evicted pod stays the service's, pending, the same
-        task; `complete_pod` of it is refused until it is bound again."""
+        """What changed since the last collection: (evictions, Bindings).
+        Looks only at the tasks whose binding the scheduler set or
+        deleted since (`FlowScheduler.take_changed_bindings`) and those
+        whose emitted Binding the service forgot (a re-delivered pod),
+        never at every resident one; `old_bindings` is what was last
+        emitted, and is brought up to date in place, task by task.
+
+        A task bound where it was not is a Binding to post (one unbound
+        and bound back where it was is nothing). Under `--preemption` a
+        task of `old_bindings` that the service still knows and the
+        scheduler no longer binds was evicted, and one it binds
+        elsewhere migrated: both are an eviction from the node the pod
+        leaves (the second with its Binding). An evicted pod stays the
+        service's, pending, the same task; `complete_pod` of it is
+        refused until it is bound again.
+
+        Order: the re-posts of re-delivered pods whose task the
+        scheduler left alone come first, in the order the pods were
+        re-delivered; then the tasks the scheduler changed, in the order
+        `task_bindings` last gained them (placements in bind order, a
+        migrated task where it was bound again). Evictions likewise."""
         with span("bindings_collect") as sp:
-            new_bindings = self.scheduler.get_task_bindings()
+            now_bindings = self.scheduler.get_task_bindings()
+            old_bindings = self.old_bindings
+            changed = self.scheduler.take_changed_bindings()
+            forgotten, self._bindings_forgotten = self._bindings_forgotten, {}
+            examine = [t for t in forgotten if t not in changed]
+            examine.extend(changed)
             evictions: List[Binding] = []
+            out: List[Binding] = []
             migrated = 0
-            if self.preemption:
-                for task_id, pu_rid in self.old_bindings.items():
-                    now = new_bindings.get(task_id)
-                    if now == pu_rid:
-                        continue  # where it was
-                    pod_id = self.task_to_pod.get(task_id)
-                    if pod_id is None:
-                        continue  # completed, not evicted
-                    node_id = self._node_of(pu_rid)
-                    if node_id is None:
-                        continue  # the node left, and its pods with it
-                    evictions.append(Binding(pod_id=pod_id, node_id=node_id))
-                    if now is None:
-                        self._evicted_pending.add(task_id)
-                    else:
-                        migrated += 1
-            out = []
-            for task_id, pu_rid in new_bindings.items():
-                if self.old_bindings.get(task_id) == pu_rid:
-                    continue
-                node_id = self._node_of(pu_rid)
-                if node_id is None:
-                    continue
+            for task_id in examine:
+                old = old_bindings.get(task_id)
+                now = now_bindings.get(task_id)
+                if old == now:
+                    continue  # where it was, or as unbound as it was
+                if now is None:
+                    del old_bindings[task_id]
+                else:
+                    old_bindings[task_id] = now
                 pod_id = self.task_to_pod.get(task_id)
                 if pod_id is None:
-                    continue
-                out.append(Binding(pod_id=pod_id, node_id=node_id))
-                self._evicted_pending.discard(task_id)
-            self.old_bindings = dict(new_bindings)
+                    continue  # completed, not evicted; or never the service's
+                if old is not None and self.preemption:
+                    node_id = self._node_of(old)
+                    # None: the node left, and its pods with it
+                    if node_id is not None:
+                        evictions.append(Binding(pod_id=pod_id, node_id=node_id))
+                        if now is None:
+                            self._evicted_pending.add(task_id)
+                        else:
+                            migrated += 1
+                if now is not None:
+                    node_id = self._node_of(now)
+                    if node_id is not None:
+                        out.append(Binding(pod_id=pod_id, node_id=node_id))
+                        self._evicted_pending.discard(task_id)
             self._pods_evicted, self._pods_migrated = len(evictions), migrated
-            sp.set("resident", len(new_bindings))
+            self._bindings_examined = len(examine)
+            sp.set("resident", len(now_bindings))
+            sp.set("examined", len(examine))
             sp.set("new", len(out))
             sp.set("evicted", len(evictions))
         return evictions, out
@@ -1019,6 +1045,7 @@ class SchedulerService(ServiceLoop):
                         # consumed by the solved round's record, below
                         pods_evicted=self._pods_evicted if solve else 0,
                         pods_migrated=self._pods_migrated if solve else 0,
+                        bindings_examined=self._bindings_examined if solve else 0,
                         pods_pending_evicted=len(self._evicted_pending),
                     ),
                 )
@@ -1027,6 +1054,7 @@ class SchedulerService(ServiceLoop):
                 # that flushed leaves it for the round that follows
                 self._post_defer_ms = 0.0
                 self._pods_evicted = self._pods_migrated = 0
+                self._bindings_examined = 0
         return rec
 
     # -- service checkpoint (scheduler state + the id maps) ----------------
@@ -1265,6 +1293,12 @@ class SchedulerService(ServiceLoop):
         svc.restored_warm = restored_warm
         svc.job_id = state["job_id"]
         svc.old_bindings = dict(state["old_bindings"])
+        # the first collection looks at every task once: the bound ones
+        # (a cold replay's re-pins name them too; a warm manifest carries
+        # no record) and those emitted before the kill and unbound at it
+        svc._bindings_forgotten = dict.fromkeys(
+            [*svc.scheduler.task_bindings, *svc.old_bindings]
+        )
         # counters ride the sidecar (v2): ladder/NOOP continuity
         svc.noop_rounds = state.get("noop_rounds", 0)
         if svc.ladder is not None:

@@ -443,8 +443,10 @@ def load_device_checkpoint(path: str, class_cost_fn=None):
 
 #: scheduler attributes excluded from the core pickle (rebuilt fresh:
 #: the solver holds the backend/ladder and live device buffers; the
-#: paths of every descriptor come back with the first scan's walk)
-_SCHED_CORE_EXCLUDE = ("solver", "_round_in_flight", "_met_jobs")
+#: paths of every descriptor come back with the first scan's walk; a
+#: restored service looks at every task's binding once, whatever had
+#: changed: cli.SchedulerService.restore)
+_SCHED_CORE_EXCLUDE = ("solver", "_round_in_flight", "_met_jobs", "_bindings_changed")
 
 
 def find_jax_solver(backend):
@@ -526,6 +528,7 @@ def load_warm_manifest(
     scheduler.__dict__.update(payload["scheduler"])
     scheduler._round_in_flight = None
     scheduler._met_jobs = {}
+    scheduler._bindings_changed = {}
     st = payload["device_state"]
     # the uid feeds plan_key identity; a fresh process must never let a
     # LATER DeviceGraphState collide with the restored one's key

@@ -208,6 +208,10 @@ class RoundRecord:
     pods_evicted: int = 0
     pods_migrated: int = 0
     pods_pending_evicted: int = 0
+    #: tasks the round's collection of Bindings looked at: those whose
+    #: binding the scheduler changed since the last collection and the
+    #: re-delivered pods' (cli.SchedulerService._collect_bindings)
+    bindings_examined: int = 0
     #: --array-round (scheduler/array_service.py; zeros on the graph
     #: path): rows of the device's task table that hold a pod after the
     #: round, exact bytes the round shipped to the device (completed

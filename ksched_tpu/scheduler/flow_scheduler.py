@@ -291,6 +291,11 @@ class FlowScheduler:
         self._root_rtnds[root_rid] = root
         self.task_bindings: Dict[int, int] = {}
         self.resource_bindings: Dict[int, Set[int]] = {}
+        #: ids of the tasks whose entry in task_bindings was set or
+        #: deleted since take_changed_bindings last emptied this, oldest
+        #: change first (a dict as an ordered set: a task touched again
+        #: moves to the end, as its re-insertion does in task_bindings)
+        self._bindings_changed: Dict[int, None] = {}
         self.jobs_to_schedule: Dict[int, JobDescriptor] = {}
         self.runnable_tasks: Dict[int, Set[int]] = {}
         #: job id -> the jobs whose trees the scheduler has walked; what
@@ -323,6 +328,22 @@ class FlowScheduler:
 
     def get_task_bindings(self) -> Dict[int, int]:
         return self.task_bindings
+
+    def take_changed_bindings(self) -> Dict[int, None]:
+        """Hand over, and forget, the ids of the tasks whose binding was
+        set or deleted since the last call, in the order of each task's
+        last change: the bound ones among them stand in task_bindings in
+        this order. A task bound back where it was is in it too. For ONE
+        consumer (cli.SchedulerService._collect_bindings), which emits
+        from it what a diff of all of task_bindings would; a scheduler
+        nobody collects from keeps one key for every task it ever bound."""
+        changed, self._bindings_changed = self._bindings_changed, {}
+        return changed
+
+    def _note_binding_changed(self, task_id: int) -> None:
+        changed = self._bindings_changed
+        changed.pop(task_id, None)  # a task touched again moves to the end
+        changed[task_id] = None
 
     def add_job(self, jd: JobDescriptor) -> None:
         """Offer a job. One the scheduler has not met is walked from
@@ -864,6 +885,7 @@ class FlowScheduler:
         rd.current_running_tasks.append(task_id)
         assert task_id not in self.task_bindings, f"task {task_id} already bound"
         self.task_bindings[task_id] = rid
+        self._note_binding_changed(task_id)
         self.resource_bindings.setdefault(rid, set()).add(task_id)
         self.gm.running_tasks_changed(rid)
         self.cost_model.task_bound(td, rid)
@@ -886,6 +908,7 @@ class FlowScheduler:
         if task_id not in task_set:
             return False
         del self.task_bindings[task_id]
+        self._note_binding_changed(task_id)
         task_set.discard(task_id)
         if departed:
             self._departed.setdefault(rid, set()).add(task_id)
